@@ -33,7 +33,8 @@ def _parse_args(argv=None):
     p.add_argument("--nproc_per_node", type=int, default=1,
                    help="processes per host (reference launch_utils "
                         "get_cluster_from_args parity; >1 spawns ranked "
-                        "children that jax.distributed-join one world)")
+                        "children that jax.distributed-join one world — "
+                        "CPU platform plane only, see --dist_platform)")
     p.add_argument("--dist_platform", default=None,
                    help="force jax platform in ranked children "
                         "(cpu = virtual-device CI mode with gloo "
@@ -88,6 +89,19 @@ def _launch_collective_multiproc(args, hosts, nproc, world):
     ``paddle_tpu.distributed.init_parallel_env()``. Children are watched
     pod-style: any non-zero exit terminates the rest (launch.py:188-226).
     """
+    platform = args.dist_platform or os.getenv("PADDLE_DIST_PLATFORM", "")
+    if not platform.startswith("cpu"):
+        # decided from the arguments alone: this parent never asks JAX
+        # what devices exist (a parent that has touched JAX holds the
+        # chips its children need)
+        sys.exit(
+            f"paddle_tpu.distributed.launch: refusing --nproc_per_node "
+            f"{nproc} on an accelerator host. One process drives all "
+            "local chips (a chip belongs to one process at a time; N "
+            "children that each open every chip fail or hang). Run the "
+            "script once per host (--nproc_per_node 1), or pass "
+            "--dist_platform cpu (or PADDLE_DIST_PLATFORM=cpu) for the "
+            "virtual-device CPU mode.")
     coordinator = args.coordinator or f"{hosts[0]}:8476"
     procs: List[subprocess.Popen] = []
     for i in range(nproc):
@@ -101,8 +115,7 @@ def _launch_collective_multiproc(args, hosts, nproc, world):
                        for j in range(nproc)),
                    PADDLE_CURRENT_ENDPOINT=f"{hosts[args.host_rank]}:"
                                            f"{8910 + i}")
-        if args.dist_platform:
-            env["PADDLE_DIST_PLATFORM"] = args.dist_platform
+        env["PADDLE_DIST_PLATFORM"] = platform
         if args.devices_per_proc:
             env["PADDLE_DIST_DEVICES_PER_PROC"] = str(args.devices_per_proc)
         procs.append(subprocess.Popen(

@@ -7,10 +7,10 @@ the HOST around one compiled device step: the sparse rows are pulled
 from the table tier before the step and fed as DENSE inputs, and the
 rows' gradients come back as fetched ``@GRAD`` outputs and are pushed
 after. Nothing inside the compiled computation touches the host, so
-this transport works on ANY device attachment — including tunneled
-remote TPUs, where the in-graph ``distributed_lookup_table``
-io_callback never completes (PERF.md) — at the cost of staging the
-rows through the feed path each step.
+this transport needs nothing of the runtime but feed and fetch (the
+in-graph ``distributed_lookup_table`` needs one that services ordered
+io_callbacks) — at the cost of staging the rows through the feed path
+each step.
 
 Overlap: batches stream through ``PullPrefetcher``, so batch k+1's PS
 round-trip rides under batch k's device step (the same +35% lever the

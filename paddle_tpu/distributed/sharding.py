@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import dataclasses
 import re
+from collections import OrderedDict
 from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple
 
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
@@ -229,27 +230,34 @@ def state_shardings(spec, mesh: Mesh, rules: ShardingRules):
     """
     p_specs = param_partition_specs(spec, mesh, rules)
     p_sh = [NamedSharding(mesh, s) for s in p_specs]
-    by_id = {id(p): sh for p, sh in zip(spec.params, p_sh)}
-    shape_by_id = {id(p): tuple(p.value.shape) for p in spec.params}
     repl = NamedSharding(mesh, P())
-
-    def opt_sh(state_dict):
-        out = {}
-        for key, v in state_dict.items():
-            pid = key[0] if isinstance(key, tuple) else None
-            if pid in by_id and tuple(v.shape) == shape_by_id[pid]:
-                out[key] = by_id[pid]
-            else:
-                out[key] = repl
-        return out
-
     # "grads" is filled in by the caller (presence depends on whether the
     # step has run before); grads shard like their params.
     return {
         "params": p_sh,
         "buffers": [repl for _ in spec.buffers],
-        "opt": [opt_sh(o._eager_state) for o in spec.optimizers],
+        "opt": map_opt_state(spec, spec.snapshot()["opt"],
+                             lambda i, v: p_sh[i], lambda v: repl),
     }
+
+
+def map_opt_state(spec, opt_states, moment, scalar):
+    """Map the ``"opt"`` part of a ``jit._StateSpec.snapshot()`` (one
+    ordered dict per optimizer, keyed ``(param index, slot)``) to a
+    pytree of the same structure: an entry with its parameter's shape is
+    a moment of parameter ``i`` and becomes ``moment(i, v)``; anything
+    else (the ``(1,)`` beta_pow scalars) becomes ``scalar(v)``."""
+    shapes = [tuple(p.value.shape) for p in spec.params]
+
+    def one(key, v):
+        i = key[0] if isinstance(key, tuple) else None
+        if isinstance(i, int) and i < len(shapes) \
+                and tuple(v.shape) == shapes[i]:
+            return moment(i, v)
+        return scalar(v)
+
+    return [OrderedDict((k, one(k, v)) for k, v in od.items())
+            for od in opt_states]
 
 
 def _param_names_by_id(layers) -> Dict[int, str]:
@@ -285,19 +293,11 @@ def constrain_snapshot(spec, snapshot, mesh: Mesh, rules: ShardingRules):
     import jax
 
     p_specs = param_partition_specs(spec, mesh, rules)
-    spec_by_id = {id(p): s for p, s in zip(spec.params, p_specs)}
-    shape_by_id = {id(p): tuple(p.value.shape) for p in spec.params}
 
     def c(v, s):
         if v is None:
             return None
         return jax.lax.with_sharding_constraint(v, NamedSharding(mesh, s))
-
-    def opt_entry(key, v):
-        pid = key[0] if isinstance(key, tuple) else None
-        if pid in spec_by_id and tuple(v.shape) == shape_by_id[pid]:
-            return c(v, spec_by_id[pid])
-        return c(v, P())
 
     out = dict(snapshot)
     out["params"] = [c(v, s) for v, s in zip(snapshot["params"], p_specs)]
@@ -305,8 +305,9 @@ def constrain_snapshot(spec, snapshot, mesh: Mesh, rules: ShardingRules):
         out["grads"] = [c(v, s)
                         for v, s in zip(snapshot["grads"], p_specs)]
     out["buffers"] = [c(v, P()) for v in snapshot["buffers"]]
-    out["opt"] = [{k: opt_entry(k, v) for k, v in od.items()}
-                  for od in snapshot["opt"]]
+    out["opt"] = map_opt_state(spec, snapshot["opt"],
+                               lambda i, v: c(v, p_specs[i]),
+                               lambda v: c(v, P()))
     return out
 
 
@@ -371,28 +372,11 @@ def opt_state_shardings(spec, mesh: Mesh, rules: ShardingRules, *,
     the plain param-inherited layouts."""
     if stage <= 0:
         return state_shardings(spec, mesh, rules)["opt"]
-    p_specs = param_partition_specs(spec, mesh, rules)
-    names = _param_names_by_id(spec.layers)
-    zsh_by_id = {}
-    shape_by_id = {}
-    for p, ps in zip(spec.params, p_specs):
-        shape_by_id[id(p)] = tuple(p.value.shape)
-        zsh_by_id[id(p)] = NamedSharding(mesh, zero_partition_spec(
-            tuple(p.value.shape), mesh, axis=axis, base=ps,
-            name=names.get(id(p), p.name)))
+    zsh = [NamedSharding(mesh, s)
+           for s in zero_grad_specs(spec, mesh, rules, axis=axis)]
     repl = NamedSharding(mesh, P())
-
-    def opt_sh(state_dict):
-        out = {}
-        for key, v in state_dict.items():
-            pid = key[0] if isinstance(key, tuple) else None
-            if pid in zsh_by_id and tuple(v.shape) == shape_by_id[pid]:
-                out[key] = zsh_by_id[pid]
-            else:
-                out[key] = repl
-        return out
-
-    return [opt_sh(o._eager_state) for o in spec.optimizers]
+    return map_opt_state(spec, spec.snapshot()["opt"],
+                         lambda i, v: zsh[i], lambda v: repl)
 
 
 def estimate_zero_opt_bytes(named_params, mesh, rules: ShardingRules, *,

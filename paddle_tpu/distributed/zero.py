@@ -34,9 +34,10 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from .. import flags as _flags
 from ..dygraph.tensor import Tensor
-from ..jit import _StateSpec, to_static
-from .sharding import (ShardingRules, _param_names_by_id, opt_state_shardings,
-                       param_partition_specs, state_shardings,
+from ..jit import _StateSpec, _lower_step, batch_axis, to_static
+from ..ops.pallas.utils import kernel_sharding
+from .sharding import (ShardingRules, _param_names_by_id, map_opt_state,
+                       opt_state_shardings, state_shardings,
                        zero_grad_specs)
 
 __all__ = [
@@ -80,38 +81,25 @@ def _constrain_zero(spec, snapshot, mesh, rules: ShardingRules,
     their data-sharded ZeRO spec instead of inheriting the param layout
     — this in-graph pin is what makes GSPMD keep the update sharded
     rather than all-gathering the moments back."""
-    from .sharding import constrain_snapshot, zero_partition_spec
+    from .sharding import constrain_snapshot
 
     out = constrain_snapshot(spec, snapshot, mesh, rules)
     if stage <= 0:
         return out
-    p_specs = param_partition_specs(spec, mesh, rules)
-    names = _param_names_by_id(spec.layers)
-    zspec_by_id = {}
-    shape_by_id = {}
-    for p, ps in zip(spec.params, p_specs):
-        shape_by_id[id(p)] = tuple(p.value.shape)
-        zspec_by_id[id(p)] = zero_partition_spec(
-            tuple(p.value.shape), mesh, axis=axis, base=ps,
-            name=names.get(id(p), p.name))
+    # moments (and stage-2 grads) share one spec per parameter
+    zspecs = zero_grad_specs(spec, mesh, rules, axis=axis)
 
     def c(v, s):
         if v is None:
             return None
         return jax.lax.with_sharding_constraint(v, NamedSharding(mesh, s))
 
-    def opt_entry(key, v):
-        pid = key[0] if isinstance(key, tuple) else None
-        if pid in zspec_by_id and tuple(v.shape) == shape_by_id[pid]:
-            return c(v, zspec_by_id[pid])
-        return c(v, P())
-
-    out["opt"] = [{k: opt_entry(k, v) for k, v in od.items()}
-                  for od in snapshot["opt"]]
+    out["opt"] = map_opt_state(spec, snapshot["opt"],
+                               lambda i, v: c(v, zspecs[i]),
+                               lambda v: c(v, P()))
     if stage >= 2 and "grads" in snapshot:
-        g_specs = zero_grad_specs(spec, mesh, rules, axis=axis)
         out["grads"] = [c(v, s)
-                        for v, s in zip(snapshot["grads"], g_specs)]
+                        for v, s in zip(snapshot["grads"], zspecs)]
     return out
 
 
@@ -169,7 +157,8 @@ def zero_train_step(function=None, *, layers, optimizers, mesh,
                 spec.load(state)
                 targs = jax.tree_util.tree_map(
                     lambda a: Tensor(a, stop_gradient=True), args)
-                out = fn(*targs)
+                with kernel_sharding(mesh, batch=batch_axis(arg_specs)):
+                    out = fn(*targs)
                 out_arrays = jax.tree_util.tree_map(
                     lambda t: t.value if isinstance(t, Tensor) else t, out,
                     is_leaf=lambda t: isinstance(t, Tensor))
@@ -202,10 +191,8 @@ def zero_train_step(function=None, *, layers, optimizers, mesh,
                         "stage": str(stage_v)},
                 donate_argnums=donate, in_shardings=(st_sh, arg_sh))
 
-        @functools.wraps(fn)
-        def wrapper(*args):
-            spec = get_spec()
-            state = spec.snapshot()
+        def prepare(args):
+            state = get_spec().snapshot()
             grads_present = tuple(g is not None for g in state["grads"])
             key = (grads_present, _flags.version())
             if key not in compiled_holder:
@@ -214,8 +201,14 @@ def zero_train_step(function=None, *, layers, optimizers, mesh,
                 lambda a: a.value if isinstance(a, Tensor)
                 else jnp.asarray(a), tuple(args),
                 is_leaf=lambda t: isinstance(t, Tensor))
+            return compiled_holder[key], state, arr_args
+
+        @functools.wraps(fn)
+        def wrapper(*args):
+            spec = get_spec()
+            compiled, state, arr_args = prepare(args)
             try:
-                out_arrays, new_state = compiled_holder[key](state, arr_args)
+                out_arrays, new_state = compiled(state, arr_args)
             except Exception:
                 # tracing assigns tracers into the eager Parameters; on a
                 # mid-trace raise restore concrete state (to_static's
@@ -229,6 +222,7 @@ def zero_train_step(function=None, *, layers, optimizers, mesh,
                 if isinstance(a, jax.Array) else a, out_arrays)
 
         wrapper.__wrapped__ = fn
+        wrapper.lower = functools.partial(_lower_step, prepare, get_spec)
         wrapper.byte_report = lambda: byte_report(layers, optimizers,
                                                   stage=stage_v,
                                                   publish=False)

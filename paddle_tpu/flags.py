@@ -568,10 +568,12 @@ define_flag("serving_devprof_sample", 0.1,
             "identical to devprof off on the step path).")
 define_flag("devprof_peak_flops", 0.0,
             "Roofline peak compute (FLOP/s) the MFU gauge divides by. "
-            "0 (default) picks a per-platform nominal: 275e12 (TPU), "
-            "312e12 (GPU), 1e11 (CPU) — pin it to your part's "
-            "datasheet number for honest MFU.")
+            "0 (default): on a TPU the device_kind's row of "
+            "observability.devprof.TPU_PEAKS (v5e: 197e12; an unlisted "
+            "TPU is an error), else a per-platform nominal: 312e12 "
+            "(GPU), 1e11 (CPU).")
 define_flag("devprof_peak_hbm_gbps", 0.0,
             "Roofline peak memory bandwidth (GB/s) the HBM-"
-            "utilization gauge divides by. 0 (default) picks a "
-            "per-platform nominal: 1200 (TPU), 2000 (GPU), 50 (CPU).")
+            "utilization gauge divides by. 0 (default): on a TPU the "
+            "device_kind's row of observability.devprof.TPU_PEAKS "
+            "(v5e: 819), else a nominal: 2000 (GPU), 50 (CPU).")

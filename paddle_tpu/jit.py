@@ -11,12 +11,13 @@ optimizer accumulators, PRNG) through the traced function as inputs/outputs
 written back to the eager objects after each call.
 
 This is the dygraph performance path on TPU: one XLA computation per step
-instead of per-op dispatch (which is pathologically slow on remote TPU).
+instead of per-op dispatch.
 """
 
 from __future__ import annotations
 
 import functools
+from collections import OrderedDict
 from typing import Callable, Iterable, List, Optional, Sequence
 
 import jax
@@ -25,6 +26,7 @@ import numpy as np
 
 from .dygraph.layers import Layer
 from .dygraph.tensor import Parameter, Tensor
+from .ops.pallas.utils import kernel_sharding
 
 
 class _StateSpec:
@@ -46,12 +48,33 @@ class _StateSpec:
                         seen.add(id(b))
                         self.buffers.append(b)
         self.optimizers = list(optimizers)
+        self._index = {id(p): i for i, p in enumerate(self.params)}
+
+    def opt_key(self, key):
+        """An optimizer-state key as the traced step sees it: the
+        optimizer's ``(id(param), slot)`` becomes ``(index of the param
+        in this spec, slot)``. An id differs from process to process, and
+        a pytree's keys end up in the lowered program (argument order,
+        ``jax.result_info``): with ids in them the same step would never
+        find itself in the persistent compile cache."""
+        if isinstance(key, tuple) and key[0] in self._index:
+            return (self._index[key[0]],) + key[1:]
+        return key
+
+    def _eager_key(self, key):
+        """Inverse of :meth:`opt_key`."""
+        if isinstance(key, tuple) and isinstance(key[0], int) \
+                and key[0] < len(self.params):
+            return (id(self.params[key[0]]),) + key[1:]
+        return key
 
     def snapshot(self):
         """-> pytree of current state arrays."""
-        opt_states = []
-        for opt in self.optimizers:
-            opt_states.append({k: v for k, v in opt._eager_state.items()})
+        # OrderedDict keeps the order the optimizer made its accumulators
+        # in (a plain dict is flattened sorted by key)
+        opt_states = [OrderedDict((self.opt_key(k), v)
+                                  for k, v in opt._eager_state.items())
+                      for opt in self.optimizers]
         return {
             "params": [p.value for p in self.params],
             "grads": [None if p.grad is None else p.grad.value
@@ -68,7 +91,8 @@ class _StateSpec:
         for b, v in zip(self.buffers, state["buffers"]):
             b.value = v
         for opt, os in zip(self.optimizers, state["opt"]):
-            opt._eager_state = dict(os)
+            opt._eager_state = {self._eager_key(k): v
+                                for k, v in os.items()}
 
 
 def to_static(function: Optional[Callable] = None, *, layers=None,
@@ -139,7 +163,8 @@ def to_static(function: Optional[Callable] = None, *, layers=None,
                 spec.load(state)
                 targs = jax.tree_util.tree_map(
                     lambda a: Tensor(a, stop_gradient=True), args)
-                out = fn(*targs)
+                with kernel_sharding(mesh, batch=batch_axis(arg_specs)):
+                    out = fn(*targs)
                 out_arrays = jax.tree_util.tree_map(
                     lambda t: t.value if isinstance(t, Tensor) else t, out,
                     is_leaf=lambda t: isinstance(t, Tensor))
@@ -177,10 +202,10 @@ def to_static(function: Optional[Callable] = None, *, layers=None,
                                    donate_argnums=donate,
                                    in_shardings=(st_sh, arg_sh))
 
-        @functools.wraps(fn)
-        def wrapper(*args):
-            spec = get_spec()
-            state = spec.snapshot()
+        def prepare(args):
+            """-> (jitted step for the current state structure, state,
+            array args)."""
+            state = get_spec().snapshot()
             grads_present = tuple(g is not None for g in state["grads"])
             # flags version: a set_flags() between calls must retrace so
             # flag-gated lowerings (pallas attention/LN) take effect
@@ -192,8 +217,14 @@ def to_static(function: Optional[Callable] = None, *, layers=None,
                 lambda a: a.value if isinstance(a, Tensor) else jnp.asarray(a),
                 tuple(args),
                 is_leaf=lambda t: isinstance(t, Tensor))
+            return compiled_holder[key], state, arr_args
+
+        @functools.wraps(fn)
+        def wrapper(*args):
+            spec = get_spec()
+            compiled, state, arr_args = prepare(args)
             try:
-                out_arrays, new_state = compiled_holder[key](state, arr_args)
+                out_arrays, new_state = compiled(state, arr_args)
             except Exception:
                 # tracing assigns tracers into the eager Parameters; if the
                 # user fn raised mid-trace, restore concrete state so the
@@ -205,11 +236,40 @@ def to_static(function: Optional[Callable] = None, *, layers=None,
                 lambda a: Tensor(a, stop_gradient=True) if isinstance(
                     a, jax.Array) else a, out_arrays)
         wrapper.__wrapped__ = fn
+        wrapper.lower = functools.partial(_lower_step, prepare, get_spec)
         return wrapper
 
     if function is not None:
         return deco(function)
     return deco
+
+
+def batch_axis(arg_specs):
+    """The mesh axis a sharded step splits its batch over: the leading
+    entry the step arguments' PartitionSpecs agree on, else None. Read
+    by the Pallas kernels (``kernel_sharding``), which run per chip on
+    their own rows of the batch."""
+    firsts = {s[0] if len(s) else None for s in (arg_specs or ())}
+    return firsts.pop() if len(firsts) == 1 else None
+
+
+def _lower_step(prepare, get_spec, *args, place=None):
+    """``step.lower(*args)``: the ``jax.stages.Lowered`` of the program
+    the next ``step(*args)`` would run, for reading what the compiler
+    made of it (``.compile().as_text()``, ``.memory_analysis()``).
+    Runs nothing and leaves the eager state as it was. ``place`` maps
+    every state/argument array to what is handed to ``jit.lower`` — a
+    ``jax.ShapeDtypeStruct`` on a described device compiles the step
+    for a chip that is not attached."""
+    compiled, state, arr_args = prepare(args)
+    placed = (state, arr_args)
+    if place is not None:
+        placed = jax.tree_util.tree_map(place, placed)
+    try:
+        return compiled.lower(*placed)
+    finally:
+        # a trace leaves tracers in the eager Parameters
+        get_spec().load(state)
 
 
 def to_static_multi_step(fn, *, layers, optimizers=None,

@@ -117,6 +117,9 @@ def _inject_params(model, raw):
             params = param_leaves(model)
         return raw(params, *args)
     fn.traces = raw.traces
+    # the tracked jit itself, for reading the compiled program:
+    # fn.raw.lower(param_leaves(model), *args).compile().as_text()
+    fn.raw = raw
     return fn
 
 
@@ -130,6 +133,25 @@ def _mesh_param_shardings(model, mesh):
     return [NamedSharding(mesh, SERVING_TP_RULES.spec_for(
                 name, p.value.shape, mesh))
             for name, p in model.named_parameters()]
+
+
+def _kernel_layout(model, mesh):
+    """The ``kernel_sharding`` context a paged step traces its model
+    call under: on a serving mesh the Pallas kernels run per chip on
+    that chip's heads (the pools' ``"model"`` axis, with the same
+    divisibility fallback as :func:`_mesh_step_shardings`); without a
+    mesh it changes nothing."""
+    from ..ops.pallas.utils import kernel_sharding
+    if mesh is None:
+        return kernel_sharding(None)
+    return kernel_sharding(mesh, heads=_heads_axis(model, mesh))
+
+
+def _heads_axis(model, mesh):
+    """``"model"`` when the mesh's model axis divides the head count,
+    else None (heads whole on every chip)."""
+    return ("model" if model.gpt.cfg.num_heads % mesh.shape["model"] == 0
+            else None)
 
 
 def step_entry(model, key, build):
@@ -172,8 +194,7 @@ def _mesh_step_shardings(model, mesh, kv_dtype: str):
     — tokens, positions, block tables, logits, qerr — is replicated
     host-visible state."""
     from jax.sharding import NamedSharding, PartitionSpec as P
-    heads_ok = model.gpt.cfg.num_heads % mesh.shape["model"] == 0
-    ax = "model" if heads_ok else None
+    ax = _heads_axis(model, mesh)
     repl = NamedSharding(mesh, P())
     pool = NamedSharding(mesh, P(None, ax, None, None))
     scale = NamedSharding(mesh, P(None, ax))
@@ -349,7 +370,8 @@ def decode_step_paged(model, mesh=None, kv_dtype: str = "f32",
 
     def _build():
         def _impl(params, tokens, pos, tables, pools, samp, lora):
-            with no_grad(), _borrowed_params(model, params):
+            with no_grad(), _borrowed_params(model, params), \
+                    _kernel_layout(model, mesh):
                 logits, newp = model(_t(tokens[:, None]),
                                      cache=_wrap_pools(pools),
                                      cache_pos=pos, block_tables=tables,
@@ -469,7 +491,8 @@ def decode_megastep_paged(model, n: int, mesh=None, kv_dtype: str = "f32",
 
             def body(carry, _):
                 tok, p, pl, keys, lv, rem, st, qerr = carry
-                with no_grad(), _borrowed_params(model, params):
+                with no_grad(), _borrowed_params(model, params), \
+                        _kernel_layout(model, mesh):
                     logits, newp = model(_t(tok[:, None]),
                                          cache=_wrap_pools(pl),
                                          cache_pos=p, block_tables=tables,
@@ -564,7 +587,8 @@ def verify_step_paged(model, spec_tokens: int, mesh=None,
 
     def _build():
         def _impl(params, tokens, pos, tables, pools, samp, lora):
-            with no_grad(), _borrowed_params(model, params):
+            with no_grad(), _borrowed_params(model, params), \
+                    _kernel_layout(model, mesh):
                 logits, newp = model(_t(tokens), cache=_wrap_pools(pools),
                                      cache_pos=pos, block_tables=tables,
                                      lora=lora)

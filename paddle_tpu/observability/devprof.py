@@ -17,9 +17,7 @@ Three planes, all off by default (``FLAGS_serving_devprof``):
   zero-compile contract ``predict_serving_compiles(devprof=True)``
   validates) and records flops / HBM bytes / output bytes per
   site+signature into :func:`cost_table` and the
-  ``xla_cost{fn,metric}`` gauges. On jax builds whose ``Lowered`` has
-  no ``cost_analysis`` (:func:`cost_analysis_supported` is False) the
-  capture degrades to ``None`` fields instead of failing.
+  ``xla_cost{fn,metric}`` gauges.
 
 - **sampled device timing** — the serving engine owns a
   :class:`DevProfiler`; a deterministic hash of its dispatch counter
@@ -58,10 +56,25 @@ from typing import Any, Dict, List, Optional
 
 from .. import flags as _flags
 
-#: per-platform nominal roofline peaks used when the devprof_peak_*
-#: flags are 0 — pin the flags to your part's datasheet for honest MFU
-_PEAK_FLOPS = {"tpu": 275e12, "gpu": 312e12, "cpu": 1e11}
-_PEAK_HBM_GBPS = {"tpu": 1200.0, "gpu": 2000.0, "cpu": 50.0}
+#: Per-chip peaks of the TPUs this repository may be measured on,
+#: keyed by ``jax.devices()[0].device_kind``: (dense bf16 FLOP/s, HBM
+#: bytes/s). Source: Google Cloud TPU documentation, the "System
+#: architecture" page of each generation (v5e: 197 TFLOP/s bf16,
+#: 819 GB/s; v6e: 918 TFLOP/s, 1640 GB/s; v5p: 459 TFLOP/s, 2765 GB/s;
+#: v4: 275 TFLOP/s, 1200 GB/s). The ONE table: ``bench.py`` reads it
+#: too. A TPU that is not listed is an error (:func:`tpu_peaks`), never
+#: a default — a wrong peak makes every utilisation wrong silently.
+TPU_PEAKS = {
+    "TPU v5 lite": (197e12, 819e9),
+    "TPU v6 lite": (918e12, 1640e9),
+    "TPU v5": (459e12, 2765e9),
+    "TPU v4": (275e12, 1200e9),
+}
+
+#: nominal peaks for the non-TPU platforms (CPU tests, GPU), used when
+#: the devprof_peak_* flags are 0
+_PEAK_FLOPS = {"gpu": 312e12, "cpu": 1e11}
+_PEAK_HBM_GBPS = {"gpu": 2000.0, "cpu": 50.0}
 
 _lock = threading.Lock()
 #: qualified tracked_jit name -> {"signature", "flops", "hbm_bytes",
@@ -71,37 +84,29 @@ _COSTS: Dict[str, Dict[str, Any]] = {}
 #: live DevProfiler instances with >= 1 sample feed the export embeds
 _PROFILERS: List["DevProfiler"] = []
 
-_SUPPORTED: Optional[bool] = None
-
 
 def enabled() -> bool:
     """The master switch: FLAGS_serving_devprof."""
     return bool(_flags.get_flag("serving_devprof"))
 
 
-def cost_analysis_supported() -> bool:
-    """Feature-detect lowered cost analysis (absent on some jax
-    builds). Probes one trivial lowering, cached for the process;
-    capture degrades to None fields when False."""
-    global _SUPPORTED
-    if _SUPPORTED is None:
-        try:
-            import jax
-            lowered = jax.jit(lambda x: x + 1).lower(1.0)
-            _SUPPORTED = callable(getattr(lowered, "cost_analysis",
-                                          None))
-        except Exception:
-            _SUPPORTED = False
-    return _SUPPORTED
+def tpu_peaks(device_kind: str):
+    """``(peak bf16 FLOP/s, peak HBM bytes/s)`` of one TPU chip by its
+    ``device_kind``; raises for a kind outside :data:`TPU_PEAKS`."""
+    try:
+        return TPU_PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no peak FLOP/s / HBM bandwidth known for TPU device_kind "
+            f"{device_kind!r}; add it (with its source) to "
+            f"observability.devprof.TPU_PEAKS — known: "
+            f"{sorted(TPU_PEAKS)}") from None
 
 
 def _normalize_cost(cost) -> Dict[str, Optional[float]]:
-    """Fold jax's cost_analysis() shape variants (a dict on current
-    builds, a list of per-computation dicts on older ones, None when
-    the backend reports nothing) into the three numbers the roofline
-    needs. Unknown keys are ignored; missing keys stay None."""
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else None
+    """Pick the three numbers the roofline needs out of
+    ``Lowered.cost_analysis()``'s dict (None when the backend reports
+    nothing). Unknown keys are ignored; missing keys stay None."""
     if not isinstance(cost, dict):
         return {"flops": None, "hbm_bytes": None, "out_bytes": None}
 
@@ -134,17 +139,16 @@ def note_compile(name: str, labels: Dict[str, str], fn, jit_kwargs,
         return None
     qual = _qualname(name, dict(labels or {}))
     entry = {"flops": None, "hbm_bytes": None, "out_bytes": None,
-             "signature": None, "supported": cost_analysis_supported()}
-    if entry["supported"]:
-        try:
-            import jax
-            lowered = jax.jit(fn, **jit_kwargs).lower(*args, **kwargs)
-            entry.update(_normalize_cost(lowered.cost_analysis()))
-        except Exception:
-            # a site whose lowering needs device context we don't have
-            # (exotic shardings, backend quirks) records None fields —
-            # the observatory must never break the serving path
-            entry["supported"] = False
+             "signature": None, "supported": True}
+    try:
+        import jax
+        lowered = jax.jit(fn, **jit_kwargs).lower(*args, **kwargs)
+        entry.update(_normalize_cost(lowered.cost_analysis()))
+    except Exception:
+        # a site whose lowering needs device context we don't have
+        # (exotic shardings, backend quirks) records None fields —
+        # the observatory must never break the serving path
+        entry["supported"] = False
     from .compile_tracker import abstract_signature
     entry["signature"] = abstract_signature(args, kwargs)
     with _lock:
@@ -195,22 +199,26 @@ def cost_digest() -> Optional[str]:
 
 
 def _peaks() -> Dict[str, float]:
-    """Resolve the roofline peaks: flags when pinned, else the
-    per-platform nominals."""
+    """Resolve the roofline peaks: flags when pinned; else on a TPU the
+    :data:`TPU_PEAKS` row of the device (an unlisted kind raises), on
+    other platforms the nominals."""
     g = _flags.get_flags(["devprof_peak_flops", "devprof_peak_hbm_gbps"])
     flops = float(g["devprof_peak_flops"])
-    hbm = float(g["devprof_peak_hbm_gbps"])
-    if flops <= 0 or hbm <= 0:
-        try:
-            import jax
-            plat = jax.default_backend()
-        except Exception:
-            plat = "cpu"
+    bps = float(g["devprof_peak_hbm_gbps"]) * 1e9
+    if flops <= 0 or bps <= 0:
+        import jax
+        dev = jax.devices()[0]
+        if dev.platform == "tpu":
+            tab_flops, tab_bps = tpu_peaks(dev.device_kind)
+        else:
+            tab_flops = _PEAK_FLOPS.get(dev.platform, _PEAK_FLOPS["cpu"])
+            tab_bps = 1e9 * _PEAK_HBM_GBPS.get(dev.platform,
+                                               _PEAK_HBM_GBPS["cpu"])
         if flops <= 0:
-            flops = _PEAK_FLOPS.get(plat, _PEAK_FLOPS["cpu"])
-        if hbm <= 0:
-            hbm = _PEAK_HBM_GBPS.get(plat, _PEAK_HBM_GBPS["cpu"])
-    return {"peak_flops": flops, "peak_bytes_per_s": hbm * 1e9}
+            flops = tab_flops
+        if bps <= 0:
+            bps = tab_bps
+    return {"peak_flops": flops, "peak_bytes_per_s": bps}
 
 
 class DevProfiler:

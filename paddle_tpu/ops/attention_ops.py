@@ -24,7 +24,10 @@ from .registry import register
 from .. import flags
 
 _logger = logging.getLogger(__name__)
-_warned_fallback = False
+#: (q shape, k shape) of every fused_attention_qkv trace that wanted the
+#: flash kernel and took the XLA-composed form because the kernel could
+#: not tile it — empty on a healthy run; chip_smoke.py fails on any
+flash_fallback_shapes = set()
 
 
 def decode_attention_mask(pos, q_len: int, capacity: int,
@@ -289,20 +292,23 @@ def _fused_attention_qkv(ctx, ins, attrs):
                   and q.shape[-2] == k.shape[-2]
                   and mask is None)
     if use_pallas:
+        from .pallas.flash_attention import flash_attention
         try:
-            from .pallas.flash_attention import flash_attention
             return {"Out": [flash_attention(
                 q, k, v, causal=causal, scale=scale,
                 block_q=flags.get_flag("pallas_flash_block_q"),
                 block_k=flags.get_flag("pallas_flash_block_k"))]}
-        except (ValueError, ImportError) as e:
-            # untileable shapes, or a jax without pallas/Mosaic —
-            # fall back to the XLA-composed form, loudly (once)
-            global _warned_fallback
-            if not _warned_fallback:
-                _warned_fallback = True
+        except ValueError as e:
+            # a shape the kernel cannot tile (flash_attention raises
+            # before building anything): take the XLA-composed form and
+            # say so once per distinct shape. A refusal by the TPU
+            # compiler itself comes later, under jit, and is never
+            # caught here.
+            shape = (tuple(q.shape), tuple(k.shape))
+            if shape not in flash_fallback_shapes:
+                flash_fallback_shapes.add(shape)
                 _logger.warning(
-                    "fused_attention_qkv: pallas flash attention "
-                    "unavailable for shape %s (%s); using XLA-composed "
-                    "attention (O(s^2) memory)", q.shape, e)
+                    "fused_attention_qkv: pallas flash attention cannot "
+                    "tile q%s k%s (%s); using XLA-composed attention "
+                    "(O(s^2) memory)", *shape, e)
     return {"Out": [_composed_attention(q, k, v, mask, causal, scale)]}

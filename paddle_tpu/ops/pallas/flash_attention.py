@@ -9,8 +9,9 @@ forward and a recomputing two-kernel backward (dq-kernel gridded over q
 blocks; dk/dv-kernel gridded over k blocks), so nothing quadratic ever
 touches HBM. Inputs may be bf16; all accumulation is fp32 on the MXU.
 
-Layout: q/k/v are [batch*heads, seq, head_dim]; the public entry accepts
-[b, h, s, d] and collapses the leading axes into the grid's first dim.
+Layout: the kernels take q/k/v as [batch*heads, seq, head_dim]; the
+public entry accepts [b, h, s, d] and collapses the leading axes into the
+grid's first dim (per chip, when the program spans several).
 The only saved residuals are (o, lse) — the backward recomputes the
 probabilities blockwise, the standard flash-attention trade.
 
@@ -30,7 +31,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .utils import interpret_mode as _interpret, pad_lane_dim, pick_block
+from .utils import (interpret_mode as _interpret, pad_lane_dim, pick_block,
+                    shard_parallel)
 
 NEG_INF = float("-inf")
 
@@ -228,37 +230,75 @@ def _flash_bwd(causal, scale, block_q, block_k, res, g):
     return dq, dk, dv
 
 
+# The [b, h, s, d] level is where a program that spans several chips
+# meets the kernels: each (batch, head) pair is independent, so under
+# utils.kernel_sharding these two wrappers run the 3-D kernels above on
+# each chip's own shard of batch and heads (see utils.shard_parallel).
+
+def _fwd_4d(q, k, v, causal, scale, block_q, block_k):
+    b, h, seq_q, d = q.shape
+    o, lse = _flash_fwd(*(a.reshape(b * h, a.shape[2], d)
+                          for a in (q, k, v)),
+                        causal, scale, block_q, block_k)
+    return o.reshape(q.shape), lse.reshape(b, h, 1, seq_q)
+
+
+def _bwd_4d(q, k, v, o, lse, do, causal, scale, block_q, block_k):
+    b, h, seq_q, d = q.shape
+    res = tuple(a.reshape(b * h, a.shape[2], d) for a in (q, k, v, o)) \
+        + (lse.reshape(b * h, 1, seq_q),)
+    dq, dk, dv = _flash_bwd(causal, scale, block_q, block_k, res,
+                            do.reshape(b * h, seq_q, d))
+    return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
+
+
+_fwd_call = shard_parallel(_fwd_4d, ("bh--",) * 3, ("bh--", "bh--"))
+_bwd_call = shard_parallel(_bwd_4d, ("bh--",) * 6, ("bh--",) * 3)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def _flash(q, k, v, causal, scale, block_q, block_k):
-    o, _ = _flash_fwd(q, k, v, causal, scale, block_q, block_k)
-    return o
+    return _fwd_call(q, k, v, causal, scale, block_q, block_k)[0]
 
 
 def _flash_vjp_fwd(q, k, v, causal, scale, block_q, block_k):
-    o, lse = _flash_fwd(q, k, v, causal, scale, block_q, block_k)
+    o, lse = _fwd_call(q, k, v, causal, scale, block_q, block_k)
     return o, (q, k, v, o, lse)
 
 
-_flash.defvjp(_flash_vjp_fwd, _flash_bwd)
+def _flash_vjp_bwd(causal, scale, block_q, block_k, res, g):
+    return _bwd_call(*res, g, causal, scale, block_q, block_k)
+
+
+_flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
 def flash_attention(q, k, v, causal=False, scale=None,
                     block_q=512, block_k=512):
     """Flash attention on [b, h, s, d] (or [bh, s, d]) inputs.
 
-    Returns attention output with the input's shape/dtype. Falls back to
-    raising ValueError for shapes the kernel cannot tile (caller decides
-    the fallback); self-attention (seq_q == seq_k) plus cross shapes whose
+    Returns attention output with the input's shape/dtype. Raises
+    ValueError for shapes the kernel cannot tile (caller decides the
+    fallback); self-attention (seq_q == seq_k) plus cross shapes whose
     sequences are divisible by a power-of-two block are supported.
+
+    Sequence-length limit: every grid step keeps the WHOLE K and V of
+    one head in VMEM (the ``(1, seq_k, d)`` blocks above; q, do, lse and
+    delta likewise in the dk/dv kernel). At d=128 bf16 the v5e compiler
+    accepts forward and backward through s=8192 and refuses s=16384
+    ("RESOURCE_EXHAUSTED: Ran out of memory in memory space vmem") —
+    at compile time under jit, so no ValueError fallback sees it. Longer
+    sequences need K/V streamed by block first (ROADMAP S5 / R7a).
+
+    Inside a program that spans several chips (utils.kernel_sharding)
+    the kernels run on each chip's own (batch, head) shard; sequence and
+    head_dim are whole per chip.
     """
-    squeeze = q.ndim == 4
+    squeeze = q.ndim == 3
     if squeeze:
-        b, h, sq, d = q.shape
-        q = q.reshape(b * h, sq, d)
-        k = k.reshape(b * h, k.shape[2], d)
-        v = v.reshape(b * h, v.shape[2], d)
-    bh, seq_q, d = q.shape
-    seq_k = k.shape[1]
+        q, k, v = q[:, None], k[:, None], v[:, None]
+    b, h, seq_q, d = q.shape
+    seq_k = k.shape[2]
     if scale is None:
         scale = 1.0 / math.sqrt(d)
     bq = pick_block(seq_q, block_q, minimum=16)
@@ -274,11 +314,9 @@ def flash_attention(q, k, v, causal=False, scale=None,
     # than rejected — pick_block's divisor rule never applies to d.
     dp = pad_lane_dim(d)
     if dp != d:
-        pad = [(0, 0), (0, 0), (0, dp - d)]
+        pad = [(0, 0), (0, 0), (0, 0), (0, dp - d)]
         q, k, v = (jnp.pad(a, pad) for a in (q, k, v))
     out = _flash(q, k, v, causal, float(scale), bq, bk)
     if dp != d:
         out = out[..., :d]
-    if squeeze:
-        out = out.reshape(b, h, seq_q, d)
-    return out
+    return out[:, 0] if squeeze else out

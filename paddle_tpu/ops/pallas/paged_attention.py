@@ -44,7 +44,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .utils import LANE, interpret_mode as _interpret, pad_lane_dim
+from .utils import (LANE, interpret_mode as _interpret, pad_lane_dim,
+                    shard_parallel)
 
 NEG_INF = float("-inf")
 
@@ -132,14 +133,12 @@ def paged_attention(q, k_pool, v_pool, tables, pos, *,
     """
     if (k_scale is None) != (v_scale is None):
         raise ValueError("k_scale and v_scale must be given together")
-    quant = k_scale is not None
     b, h, s, d = q.shape
     nb, hp, bs, dpool = k_pool.shape
     if (hp, dpool) != (h, d) or v_pool.shape != k_pool.shape:
         raise ValueError(
             f"pool shape {k_pool.shape}/{v_pool.shape} does not match "
             f"q {q.shape}")
-    T = tables.shape[1]
     if scale is None:
         scale = 1.0 / math.sqrt(d)
     if interpret is None:
@@ -152,8 +151,30 @@ def paged_attention(q, k_pool, v_pool, tables, pos, *,
         k_pool = jnp.pad(k_pool, pad)
         v_pool = jnp.pad(v_pool, pad)
 
-    tables_flat = jnp.asarray(tables, jnp.int32).reshape(-1)
+    tables = jnp.asarray(tables, jnp.int32)
     pos = jnp.asarray(pos, jnp.int32)
+    if k_scale is None:
+        out = _paged_call(q, k_pool, v_pool, tables, pos,
+                          float(scale), bool(interpret))
+    else:
+        out = _paged_quant_call(
+            q, k_pool, v_pool, tables, pos,
+            jnp.asarray(k_scale, jnp.float32),
+            jnp.asarray(v_scale, jnp.float32),
+            float(scale), bool(interpret))
+    return out[..., :d] if dp != d else out
+
+
+def _paged_local(q, k_pool, v_pool, tables, pos, k_scale, v_scale,
+                 scale, interpret):
+    """The kernel call on what one chip holds: q [b, h, s, dp] (dp
+    lane-aligned), pools [nb, h, bs, dp], tables [b, T], pos [b],
+    optional scales [nb, h]."""
+    quant = k_scale is not None
+    b, h, s, dp = q.shape
+    nb, _, bs, _ = k_pool.shape
+    T = tables.shape[1]
+    tables_flat = tables.reshape(-1)
 
     qkv_specs = [
         pl.BlockSpec((1, 1, s, dp), lambda b, h, t, tbl, pos: (b, h, 0, 0)),
@@ -170,8 +191,8 @@ def paged_attention(q, k_pool, v_pool, tables, pos, *,
             pl.BlockSpec((1, 1, 1, 1),
                          lambda b, h, t, tbl, pos: (tbl[b * T + t], h, 0, 0)),
         ]
-        operands += [jnp.asarray(k_scale, jnp.float32).reshape(nb, h, 1, 1),
-                     jnp.asarray(v_scale, jnp.float32).reshape(nb, h, 1, 1)]
+        operands += [k_scale.reshape(nb, h, 1, 1),
+                     v_scale.reshape(nb, h, 1, 1)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -185,11 +206,26 @@ def paged_attention(q, k_pool, v_pool, tables, pos, *,
             pltpu.VMEM((s, dp), jnp.float32),     # output accumulator
         ],
     )
-    out = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(_kernel, block_size=bs, q_len=s,
-                          scale=float(scale), quant=quant),
+                          scale=scale, quant=quant),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, s, dp), q.dtype),
         interpret=interpret,
     )(*operands)
-    return out[..., :d] if dp != d else out
+
+
+def _paged_float(q, k_pool, v_pool, tables, pos, scale, interpret):
+    return _paged_local(q, k_pool, v_pool, tables, pos, None, None,
+                        scale, interpret)
+
+
+# Every (batch row, head) pair is independent, so a tensor-parallel
+# engine (heads on the mesh's model axis) runs the kernel on each chip's
+# own heads of q and of the pools; the pool's block axis, the block rows
+# and head_dim stay whole per chip (see utils.shard_parallel).
+_paged_call = shard_parallel(
+    _paged_float, ("bh--", "-h--", "-h--", "b-", "b"), ("bh--",))
+_paged_quant_call = shard_parallel(
+    _paged_local,
+    ("bh--", "-h--", "-h--", "b-", "b", "-h", "-h"), ("bh--",))

@@ -1391,9 +1391,11 @@ class ServingEngine:
 
             def _prefill(params, ids, last, pos, tables, pools,
                          lora=None):
-                from ..models.generation import (_unwrap_pools,
+                from ..models.generation import (_kernel_layout,
+                                                 _unwrap_pools,
                                                  _wrap_pools)
-                with no_grad(), _borrowed_params(model, params):
+                with no_grad(), _borrowed_params(model, params), \
+                        _kernel_layout(model, mesh):
                     logits, newp = model(
                         Tensor(ids, stop_gradient=True),
                         cache=_wrap_pools(pools),

@@ -1,0 +1,184 @@
+"""The main path's Pallas kernels, compiled by the TPU's own compiler.
+
+Every other test runs the kernels under the Pallas interpreter on the
+CPU, where they are plain HLO: a block the chip's tiling refuses, a
+kernel that wants more VMEM than it may have, or a kernel the
+partitioner cannot split all pass there. libtpu is installed, and it
+compiles for a chip that is *described* and not attached
+(``jax.experimental.topologies``), so these cases hand the real shapes
+of ``gpt2-1p1b`` / ``gpt2-1p3b`` (h16 d128), ``gpt2-medium`` (d64) and
+the serving pool to Mosaic and assert a ``tpu_custom_call`` came out.
+Nothing runs: a compile that passes is not a chip run.
+
+The topology is described inside a module-scoped fixture — never at
+import, so every xdist worker collects the same tests and only the
+worker that runs this file loads libtpu — and the kernels are steered
+to ``interpret=False`` here in the test (``jax.default_backend()`` is
+still the CPU), not through an option of the program. All cases live in
+this one file: a second file could land on another worker.
+"""
+
+import importlib
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+import chip_smoke
+from paddle_tpu.ops.pallas.utils import kernel_sharding
+
+# the package re-exports the functions under the modules' names
+fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
+pa = importlib.import_module("paddle_tpu.ops.pallas.paged_attention")
+
+KERNEL = chip_smoke.KERNEL      # a Mosaic kernel in a compiled program
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    """Sharding on one described chip; the persistent compile cache is
+    off around these compiles (an entry written for a described chip
+    cannot be read back without one)."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def mosaic(monkeypatch):
+    """Compile the kernels via Mosaic although the backend is the CPU
+    (paged_attention also takes ``interpret=False`` itself), and as the
+    chip runs them: without the suite's x64 (conftest turns it on, and
+    then the literal zeros of the BlockSpec index maps lower as i64,
+    which Mosaic refuses)."""
+    monkeypatch.setattr(fa, "_interpret", lambda: False)
+    monkeypatch.setattr(pa, "_interpret", lambda: False)
+    with jax.enable_x64(False):
+        yield
+
+
+def _flash_loss(q, k, v):
+    return fa.flash_attention(q, k, v, causal=True).astype(
+        jnp.float32).sum()
+
+
+# [b, h, s, d] of the train step's attention: gpt2-1p1b b8, gpt2-medium
+# b8, and the longest sequence the whole-sequence K/V block allows
+# (s=16384 is refused for VMEM — see flash_attention's docstring)
+FLASH_SHAPES = {"d128_s1024": (8, 16, 1024, 128),
+                "d64_s1024": (8, 16, 1024, 64),
+                "d128_s8192": (1, 16, 8192, 128)}
+
+
+@pytest.mark.parametrize("direction", ["forward", "backward"])
+@pytest.mark.parametrize("shape", sorted(FLASH_SHAPES))
+def test_flash_attention_compiles_for_v5e(one_chip, mosaic, shape,
+                                          direction):
+    q = jax.ShapeDtypeStruct(FLASH_SHAPES[shape], jnp.bfloat16,
+                             sharding=one_chip)
+    fn = (_flash_loss if direction == "forward"
+          else jax.grad(_flash_loss, argnums=(0, 1, 2)))
+    text = jax.jit(fn).lower(q, q, q).compile().as_text()
+    # forward: one kernel; backward: forward + dq + dk/dv
+    assert text.count(KERNEL) == (1 if direction == "forward" else 3)
+
+
+# the serving pool at gpt2-1p3b width: 32 slots, h16 d128, 16-row
+# blocks, 64 table slots (max_len 1024), 2048 blocks
+B, H, D, BS, T, NB = 32, 16, 128, 16, 64, 2048
+PAGED_CASES = {           # q_len, q dtype, pool dtype, int8 scales
+    "decode_f32": (1, jnp.float32, jnp.float32, False),
+    "decode_bf16_pool": (1, jnp.float32, jnp.bfloat16, False),
+    "decode_int8_pool": (1, jnp.float32, jnp.int8, True),
+    "verify_q5": (5, jnp.float32, jnp.float32, False),
+}
+
+
+def _paged_args(q_len, q_dt, pool_dt, quant, where):
+    def s(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=where(len(shape)))
+    args = [s((B, H, q_len, D), q_dt), s((NB, H, BS, D), pool_dt),
+            s((NB, H, BS, D), pool_dt), s((B, T), jnp.int32),
+            s((B,), jnp.int32)]
+    if quant:
+        args += [s((NB, H), jnp.float32)] * 2
+    return args
+
+
+def _paged(q, k, v, tables, pos, ks=None, vs=None):
+    return pa.paged_attention(q, k, v, tables, pos, k_scale=ks,
+                              v_scale=vs, interpret=False)
+
+
+@pytest.mark.parametrize("case", sorted(PAGED_CASES))
+def test_paged_attention_compiles_for_v5e(one_chip, mosaic, case):
+    args = _paged_args(*PAGED_CASES[case], where=lambda nd: one_chip)
+    text = jax.jit(_paged).lower(*args).compile().as_text()
+    assert text.count(KERNEL) == 1
+
+
+def _kernel_shapes(text):
+    """Operand and result shapes of the program's Mosaic kernels."""
+    return [s for res, ops in chip_smoke.tpu_custom_calls(text)
+            for s in res + ops]
+
+
+def test_flash_attention_runs_on_the_batch_shard_of_each_chip(
+        topo, one_chip, mosaic):
+    """Inside a program over four chips (ZeRO/data parallel: batch on
+    ``dp``) each chip's kernels see batch/4 — and the program compiles
+    at all: a bare Mosaic kernel under a mesh is refused."""
+    mesh = Mesh(np.array(topo.devices), ("dp",))
+    b, h, s, d = FLASH_SHAPES["d64_s1024"]
+    sh = NamedSharding(mesh, P("dp"))
+    q = jax.ShapeDtypeStruct((b, h, s, d), jnp.bfloat16, sharding=sh)
+
+    def grads(q, k, v):
+        with kernel_sharding(mesh, batch="dp"):
+            return jax.grad(_flash_loss, argnums=(0, 1, 2))(q, k, v)
+
+    text = jax.jit(grads, out_shardings=(sh,) * 3).lower(
+        q, q, q).compile().as_text()
+    assert text.count(KERNEL) == 3
+    assert "all-gather" not in text
+    assert {x[0] for x in _kernel_shapes(text) if len(x) == 3} \
+        == {b // 4 * h}
+
+
+def test_paged_attention_runs_on_the_heads_shard_of_each_chip(
+        topo, one_chip, mosaic):
+    """Tensor-parallel serving (heads on ``model``): each chip's kernel
+    sees heads/4 of q and of the pools, with nothing gathered."""
+    mesh = Mesh(np.array(topo.devices).reshape(1, 4), ("data", "model"))
+    heads = NamedSharding(mesh, P(None, "model"))
+    whole = NamedSharding(mesh, P())
+    args = _paged_args(*PAGED_CASES["decode_f32"],
+                       where=lambda nd: heads if nd == 4 else whole)
+
+    def step(*a):
+        with kernel_sharding(mesh, heads="model"):
+            return _paged(*a)
+
+    text = jax.jit(step, out_shardings=heads).lower(
+        *args).compile().as_text()
+    assert text.count(KERNEL) == 1
+    assert "all-gather" not in text
+    assert {x[1] for x in _kernel_shapes(text) if len(x) == 4} == {H // 4}

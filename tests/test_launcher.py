@@ -139,3 +139,28 @@ def test_http_kv_rendezvous():
         assert srv.should_stop()
     finally:
         srv.stop()
+
+
+def test_multiproc_refused_off_the_cpu_platform_plane(monkeypatch):
+    """--nproc_per_node > 1 starts N children that each open every local
+    chip; on an accelerator host that fails or hangs, so the launcher
+    refuses from its arguments alone (no jax call in the parent) unless
+    the platform plane says CPU."""
+    from paddle_tpu.distributed.fleet import launch
+    monkeypatch.delenv("PADDLE_DIST_PLATFORM", raising=False)
+    monkeypatch.setattr(launch.subprocess, "Popen", lambda *a, **k: (
+        pytest.fail("launcher started a child it had to refuse")))
+    with pytest.raises(SystemExit) as exc:
+        launch.main(["--nproc_per_node", "2", "train.py"])
+    assert "--dist_platform cpu" in str(exc.value)
+    # either spelling of the CPU plane gets past the refusal
+    started = []
+    monkeypatch.setattr(launch.subprocess, "Popen",
+                        lambda *a, **k: started.append(k["env"]))
+    monkeypatch.setattr(launch, "_watch_pod", lambda procs: None)
+    launch.main(["--nproc_per_node", "2", "--dist_platform", "cpu",
+                 "train.py"])
+    monkeypatch.setenv("PADDLE_DIST_PLATFORM", "cpu")
+    launch.main(["--nproc_per_node", "2", "train.py"])
+    assert len(started) == 4
+    assert all(e["PADDLE_DIST_PLATFORM"] == "cpu" for e in started)

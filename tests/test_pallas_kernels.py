@@ -135,3 +135,40 @@ def test_attention_op_uses_flash_when_enabled():
                              1.0 / np.sqrt(64))
     np.testing.assert_allclose(np.asarray(out.value), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
+
+
+def test_attention_op_fallback_is_visible_per_shape(caplog):
+    """A shape the kernel cannot tile still takes the XLA-composed form,
+    but never in silence: it is recorded (chip_smoke.py fails on any)
+    and logged once for each distinct shape."""
+    import logging
+
+    from paddle_tpu import flags
+    from paddle_tpu.dygraph.tape import run_op
+    from paddle_tpu.dygraph.tensor import Tensor
+    from paddle_tpu.ops import attention_ops
+
+    rng = np.random.RandomState(5)
+    # 40 = 8 * 5: no power-of-two block >= 16 divides it
+    q = Tensor(jnp.asarray(rng.randn(1, 2, 40, 8), jnp.float32))
+    old = flags.get_flag("pallas_min_seq")
+    attention_ops.flash_fallback_shapes.clear()
+    try:
+        flags.set_flags({"pallas_min_seq": 32})
+        with caplog.at_level(logging.WARNING,
+                             logger=attention_ops.__name__):
+            for _ in range(2):
+                out = run_op("fused_attention_qkv",
+                             {"Q": [q], "K": [q], "V": [q]},
+                             {"causal": True})["Out"][0]
+    finally:
+        flags.set_flags({"pallas_min_seq": old})
+    shape = (1, 2, 40, 8)
+    assert attention_ops.flash_fallback_shapes == {(shape, shape)}
+    assert len([r for r in caplog.records
+                if "cannot tile" in r.getMessage()]) == 1
+    attention_ops.flash_fallback_shapes.clear()
+    ref = composed_attention(q.value, q.value, q.value, True,
+                             1.0 / np.sqrt(8))
+    np.testing.assert_allclose(np.asarray(out.value), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)

@@ -11,6 +11,7 @@ of how many requests flow through.
 
 import http.client
 import json
+import time
 
 import numpy as np
 import pytest
@@ -705,10 +706,16 @@ def test_http_delete_cancels_and_status_combos(model):
     try:
         c = http.client.HTTPConnection("127.0.0.1", srv.port, timeout=60)
         # an in-flight victim: submitted straight to the engine so the
-        # HTTP DELETE races a real scheduler thread
+        # HTTP DELETE races a real scheduler thread. The scheduler is
+        # held to 20 ms a step while it does: on an idle machine it
+        # otherwise finishes all 24 tokens before the handler thread
+        # gets the GIL, and the DELETE finds a finished request (404)
+        fast_step = eng.step
+        eng.step = lambda: (time.sleep(0.02), fast_step())[1]
         victim = eng.submit(prompt, max_new_tokens=24)
         c.request("DELETE", f"/v1/requests/{victim.id}")
         r = c.getresponse()
+        eng.step = fast_step
         assert r.status == 200
         out = json.loads(r.read())
         assert out["id"] == victim.id and out["reason"] == "client"

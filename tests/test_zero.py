@@ -106,7 +106,7 @@ def test_opt_state_shardings_moments_sharded_scalars_replicated():
     assert len(shardings) == 1
     sharded = replicated = 0
     for (pid, key), v in opt._eager_state.items():
-        sh = shardings[0][(pid, key)]
+        sh = shardings[0][spec.opt_key((pid, key))]
         if tuple(v.shape) == (1,):
             assert sh.spec == P(), f"scalar {key} must replicate"
             replicated += 1

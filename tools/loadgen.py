@@ -1198,9 +1198,10 @@ def main(argv=None) -> int:
                     "tier on)")
     args = ap.parse_args(argv)
 
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     import paddle_tpu as pt
     from paddle_tpu.models.gpt import GPT_CONFIGS, GPTForCausalLM
+    from paddle_tpu.utils.chip import device_info, enable_compile_cache
+    enable_compile_cache()
     from paddle_tpu.resilience import fault_scope
     from paddle_tpu.serving import AutoscalePolicy, ReplicaRouter, \
         ServingEngine
@@ -1383,6 +1384,7 @@ def main(argv=None) -> int:
             v["count"] - base_compiles.get(k, 0)
             for k, v in _obs.compiles().items()
             if k.startswith(_SERVING))
+    report["device"] = device_info()
     trace = report.pop("trace", None)
     if args.trace:
         with open(args.trace, "w") as f:

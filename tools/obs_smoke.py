@@ -976,10 +976,9 @@ def main() -> int:
     costsD = devprof.cost_table()
     assert "decode_step_paged" in costsD, sorted(costsD)
     assert devprof.cost_digest(), costsD
-    if devprof.cost_analysis_supported():
-        cD = costsD["decode_step_paged"]
-        assert cD["flops"] and cD["hbm_bytes"], cD
-        assert stD["mfu"] is not None and stD["mfu"] > 0.0, stD
+    cD = costsD["decode_step_paged"]
+    assert cD["flops"] and cD["hbm_bytes"], cD
+    assert stD["mfu"] is not None and stD["mfu"] > 0.0, stD
     for r in reqsD:
         infoD = tracing.get(r.id)
         bl = infoD["blame_ms"]

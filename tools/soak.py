@@ -283,8 +283,9 @@ def main(argv=None) -> int:
                     "guarded-state violations")
     args = ap.parse_args(argv)
 
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     import paddle_tpu as pt
+    from paddle_tpu.utils.chip import device_info, enable_compile_cache
+    enable_compile_cache()
     from paddle_tpu.analysis import predict_serving_compiles
     from paddle_tpu.models.gpt import GPT_CONFIGS, GPTForCausalLM
     from tools.loadgen import LoadGen
@@ -415,6 +416,7 @@ def main(argv=None) -> int:
     out = {
         "bench": "soak_fleet_fault_tolerance",
         "model": args.model,
+        "device": device_info(),
         "simulated_hours": args.hours,
         "seed": args.seed,
         "fault_spec": spec,
