@@ -84,6 +84,7 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k):
                                block_k=block_k, seq_k=seq_k)
     o, lse = pl.pallas_call(
         kernel,
+        name="flash_fwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
@@ -191,6 +192,7 @@ def _flash_bwd(causal, scale, block_q, block_k, res, g):
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
                           block_k=block_k, seq_k=seq_k),
+        name="flash_bwd_dq",
         grid=(bh, seq_q // block_q),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
@@ -208,6 +210,7 @@ def _flash_bwd(causal, scale, block_q, block_k, res, g):
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
                           block_q=block_q, seq_q=seq_q),
+        name="flash_bwd_dkv",
         grid=(bh, seq_k // block_k),
         in_specs=[
             pl.BlockSpec((1, seq_q, d), lambda i, j: (i, 0, 0)),

@@ -69,6 +69,7 @@ def _ln_fwd(x, gamma, beta, eps, block_n):
     grid = (n // block_n,)
     y, mean, rstd = pl.pallas_call(
         functools.partial(_fwd_kernel, eps=eps),
+        name="layer_norm_fwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_n, h), lambda i: (i, 0)),
@@ -95,6 +96,7 @@ def _ln_bwd(eps, block_n, res, dy):
     n, h = x.shape
     dx, dg, db = pl.pallas_call(
         _bwd_kernel,
+        name="layer_norm_bwd",
         grid=(n // block_n,),
         in_specs=[
             pl.BlockSpec((block_n, h), lambda i: (i, 0)),

@@ -209,6 +209,7 @@ def _paged_local(q, k_pool, v_pool, tables, pos, k_scale, v_scale,
     return pl.pallas_call(
         functools.partial(_kernel, block_size=bs, q_len=s,
                           scale=scale, quant=quant),
+        name="paged_decode_attn",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, s, dp), q.dtype),
         interpret=interpret,

@@ -15,35 +15,63 @@ from __future__ import annotations
 
 import contextlib
 import functools
+import itertools
 import json
 import os
 import threading
 import time
 from typing import Dict, List, Optional
 
+try:
+    from jax.profiler import TraceAnnotation as _TraceAnnotation
+except ImportError:          # host-only use without jax: in-process events only
+    _TraceAnnotation = None
+
 _lock = threading.Lock()
 _enabled = False
 _events: List[dict] = []
 _trace_dir: Optional[str] = None
+_ids = itertools.count(1)       # span ids, unique in the process
+_local = threading.local()      # .stack: ids of the spans open on this thread
+
+
+def _open_spans() -> List[int]:
+    try:
+        return _local.stack
+    except AttributeError:
+        _local.stack = []
+        return _local.stack
 
 
 class RecordEvent:
     """Scoped annotation (platform/profiler.h:126 RAII analog); usable
     as a context manager or decorator. No-op unless the profiler is on,
-    except the jax TraceAnnotation which is cheap and always useful."""
+    except the jax TraceAnnotation which is cheap and always useful.
 
-    def __init__(self, name: str):
+    While the profiler is on each event keeps an ``id`` unique in the
+    process, the ``id`` of its ``parent`` (the RecordEvent open on the
+    same thread when it was entered, None at the root) and the optional
+    ``args`` (a small dict of str/int/float, also handed to the
+    TraceAnnotation as keyword arguments: they become stats of the
+    trace event, its name stays ``name``)."""
+
+    _ann = _id = _parent = None
+    _t0 = 0
+
+    def __init__(self, name: str, args: Optional[dict] = None):
         self.name = name
-        self._ann = None
-        self._t0 = 0.0
+        self.args = args
 
     def __enter__(self):
-        try:
-            import jax.profiler
-            self._ann = jax.profiler.TraceAnnotation(self.name)
+        if _TraceAnnotation is not None:
+            self._ann = (_TraceAnnotation(self.name, **self.args)
+                         if self.args else _TraceAnnotation(self.name))
             self._ann.__enter__()
-        except Exception:
-            self._ann = None
+        if _enabled:
+            stack = _open_spans()
+            self._id = next(_ids)
+            self._parent = stack[-1] if stack else None
+            stack.append(self._id)
         self._t0 = time.perf_counter_ns()
         return self
 
@@ -51,24 +79,46 @@ class RecordEvent:
         t1 = time.perf_counter_ns()
         if self._ann is not None:
             self._ann.__exit__(*exc)
-        with _lock:
-            # _enabled is mutated by start/stop_profiler under _lock;
-            # read it there too so a concurrent stop can't interleave
-            if _enabled:
-                _events.append({
-                    "name": self.name,
-                    "ts": self._t0 / 1e3,     # chrome trace uses us
-                    "dur": (t1 - self._t0) / 1e3,
-                    "tid": threading.get_ident() % 100000,
-                })
+        if self._id is None:        # entered with the profiler off
+            return False
+        stack = _open_spans()
+        if self._id in stack:       # not when exited on another thread
+            stack.remove(self._id)
+        _record(self.name, self._t0 / 1e3, (t1 - self._t0) / 1e3,
+                self._id, self._parent, self.args)
+        self._id = None
         return False
 
     def __call__(self, fn):
         @functools.wraps(fn)
         def wrapper(*a, **kw):
-            with RecordEvent(self.name):
+            with RecordEvent(self.name, self.args):
                 return fn(*a, **kw)
         return wrapper
+
+
+def _record(name, ts_us, dur_us, id_, parent, args):
+    event = {"name": name, "ts": ts_us, "dur": dur_us,   # chrome trace: us
+             "tid": threading.get_ident() % 100000,
+             "id": id_, "parent": parent}
+    if args:
+        event["args"] = args
+    with _lock:
+        # _enabled is mutated by start/stop_profiler under _lock;
+        # read it there too so a concurrent stop can't interleave
+        if _enabled:
+            _events.append(event)
+
+
+def record_span(name: str, start_s: float, dur_s: float,
+                args: Optional[dict] = None):
+    """Record an interval measured elsewhere (seconds on the
+    ``time.perf_counter`` clock the RecordEvents use) as a parentless
+    event; nothing happens with the profiler off. For intervals that no
+    ``with`` block spans, e.g. the gap between two of a request's tokens
+    committed in different engine steps."""
+    if _enabled:
+        _record(name, start_s * 1e6, dur_s * 1e6, next(_ids), None, args)
 
 
 def start_profiler(state: str = "All", trace_dir: Optional[str] = None):
@@ -99,8 +149,7 @@ def stop_profiler(sorted_key: Optional[str] = None,
         jax.profiler.stop_trace()
         _trace_dir = None
     trace = {"traceEvents": [
-        {"name": e["name"], "ph": "X", "ts": e["ts"], "dur": e["dur"],
-         "pid": 0, "tid": e["tid"], "cat": "host"} for e in events]}
+        {**e, "ph": "X", "pid": 0, "cat": "host"} for e in events]}
     d = os.path.dirname(profile_path)
     if d:
         os.makedirs(d, exist_ok=True)
@@ -110,10 +159,11 @@ def stop_profiler(sorted_key: Optional[str] = None,
     if summary:
         name_w = max(len(s["name"]) for s in summary)
         print(f"{'Event':{name_w}s}  {'Calls':>6s}  {'Total(ms)':>10s}  "
-              f"{'Avg(ms)':>10s}")
+              f"{'Avg(ms)':>10s}  {'Self(ms)':>10s}")
         for s in summary:
             print(f"{s['name']:{name_w}s}  {s['calls']:6d}  "
-                  f"{s['total_ms']:10.3f}  {s['avg_ms']:10.3f}")
+                  f"{s['total_ms']:10.3f}  {s['avg_ms']:10.3f}  "
+                  f"{s['self_ms']:10.3f}")
     _print_metrics_summary()
     return summary
 
@@ -166,12 +216,21 @@ def _print_metrics_summary():
 
 
 def summarize(events: List[dict], sorted_key: Optional[str] = None):
+    """Per-name calls, total, average and self time. ``self_ms`` is the
+    events' durations minus what their children (the events naming them
+    as ``parent``) cover; children of one parent sit on one thread and
+    do not overlap, so their durations add."""
+    covered: Dict[int, float] = {}
+    for e in events:
+        if e.get("parent") is not None:
+            covered[e["parent"]] = covered.get(e["parent"], 0.0) + e["dur"]
     agg: Dict[str, dict] = {}
     for e in events:
         a = agg.setdefault(e["name"], {"name": e["name"], "calls": 0,
-                                       "total_ms": 0.0})
+                                       "total_ms": 0.0, "self_ms": 0.0})
         a["calls"] += 1
         a["total_ms"] += e["dur"] / 1e3
+        a["self_ms"] += max(0.0, e["dur"] - covered.get(e.get("id"), 0.0)) / 1e3
     out = list(agg.values())
     for a in out:
         a["avg_ms"] = a["total_ms"] / a["calls"]

@@ -261,7 +261,6 @@ class PrefillEngine(ServingEngine):
 
     def step(self) -> bool:
         with self._step_lock:
-            _monitor.stat_add("STAT_serving_steps")
             worked = self._flush_pending() > 0
             if not self._pending and self._handoff.room > 0:
                 worked = bool(self._admit()) or worked
@@ -443,7 +442,6 @@ class DecodeEngine(ServingEngine):
         safe concurrently with another worker allocating on it — while
         fanning the decode halves out in parallel."""
         with self._step_lock:
-            _monitor.stat_add("STAT_serving_steps")
             reaped = self._reap_expired()
             worked = self._adopt_handoffs() > 0
             return bool(worked or reaped)
@@ -456,8 +454,7 @@ class DecodeEngine(ServingEngine):
         metrics), so the threaded router may run decode halves of
         workers with *distinct* pools concurrently."""
         with self._step_lock:
-            produced = (self._spec_decode() if self.spec_tokens
-                        else self._decode_any())
+            produced = self._decode_any()
             if self.kv_tier is not None:
                 self._demote_sweep()
             if self.paged:

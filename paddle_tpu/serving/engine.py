@@ -239,6 +239,11 @@ class Request:
                              else float(now))
         self.deadline: Optional[float] = None
         self.first_token_at: Optional[float] = None
+        # engine-clock stamp of every committed token, one clock read
+        # per commit shared by all the tokens it commits (so the n
+        # tokens of one megastep share a stamp); token_at[0] is
+        # first_token_at, the same float
+        self.token_at: List[float] = []
         self.finished_at: Optional[float] = None
         self._done = threading.Event()
 
@@ -714,6 +719,7 @@ class ServingEngine:
         # dispatch-ahead speculation: megastep k+1's un-synced device
         # result, enqueued while k's commit ran; consumed by the next
         # decode only when the scheduler state it assumed is unchanged
+        self._step_no = 0                 # guarded-by: _step_lock
         self._ahead = None                # guarded-by: _step_lock
         self._ahead_hits = 0              # guarded-by: _step_lock
         self._ahead_misses = 0            # guarded-by: _step_lock
@@ -1531,92 +1537,109 @@ class ServingEngine:
         exhaustion requeues the head-of-line request (and all behind
         it — intra-class FIFO order is part of the equivalence oracle)
         until retirements free blocks. Returns (consumed, admitted)."""
-        candidates, expired = self._pop_candidates(self.cache.num_free)
-        if not candidates:
-            return expired, 0
-        acquired = []   # (req, row, shared)
-        back: List[Request] = []
-        for req in candidates:
-            if back:          # head-of-line blocked: keep FIFO order
-                back.append(req)
-                continue
-            need = (len(req.prompt) + req.max_new_tokens +
-                    self.spec_tokens)
-            try:
-                res = RetryPolicy.from_flags("serving.alloc").call(
-                    self._alloc_attempt, req, need)
-            except _Shed as e:
-                self._shed(req, e)
-                continue
-            except RetryError as e:
-                self._shed(req, e)
-                continue
-            if res is None:
-                back.append(req)   # pool dry: wait for retirements
-                continue
-            if req.tenant and self.lora_pool is not None:
-                # pin the tenant's adapter page for the request's
-                # lifetime (released in _finish/_shed); an adapter
-                # evicted between submit and admit sheds here
-                try:
-                    self.lora_pool.acquire(req.tenant)
-                    req._lora_held = True
-                except ValueError as e:
-                    self.cache.release_row(res[0])
-                    self._shed(req, _Shed(str(e)))
+        with _profiler.RecordEvent("serving.schedule") as sched:
+            candidates, expired = self._pop_candidates(
+                self.cache.num_free)
+            if not candidates:
+                return expired, 0
+            acquired = []   # (req, row, shared)
+            back: List[Request] = []
+            for req in candidates:
+                if back:          # head-of-line blocked: keep FIFO order
+                    back.append(req)
                     continue
-            acquired.append((req, res[0], res[1]))
-        if back:
-            with self._lock:
-                self._queue.extendleft(reversed(back))
-        if not acquired:
-            return expired + len(candidates) - len(back), 0
-        groups: Dict[int, List] = {}
-        for rec in acquired:
-            req, row, shared = rec
-            groups.setdefault(
-                self._bucket_for(len(req.context) - shared),
-                []).append(rec)
+                need = (len(req.prompt) + req.max_new_tokens +
+                        self.spec_tokens)
+                try:
+                    res = RetryPolicy.from_flags("serving.alloc").call(
+                        self._alloc_attempt, req, need)
+                except _Shed as e:
+                    self._shed(req, e)
+                    continue
+                except RetryError as e:
+                    self._shed(req, e)
+                    continue
+                if res is None:
+                    back.append(req)   # pool dry: wait for retirements
+                    continue
+                if req.tenant and self.lora_pool is not None:
+                    # pin the tenant's adapter page for the request's
+                    # lifetime (released in _finish/_shed); an adapter
+                    # evicted between submit and admit sheds here
+                    try:
+                        self.lora_pool.acquire(req.tenant)
+                        req._lora_held = True
+                    except ValueError as e:
+                        self.cache.release_row(res[0])
+                        self._shed(req, _Shed(str(e)))
+                        continue
+                acquired.append((req, res[0], res[1]))
+            if back:
+                with self._lock:
+                    self._queue.extendleft(reversed(back))
+            consumed = expired + len(candidates) - len(back)
+            if not acquired:
+                return consumed, 0
+            groups: Dict[int, List] = {}
+            for rec in acquired:
+                req, row, shared = rec
+                groups.setdefault(
+                    self._bucket_for(len(req.context) - shared),
+                    []).append(rec)
+            # known only now, so kept in the in-process event alone
+            sched.args = {"admitted": len(acquired)}
         admitted = 0
         for bucket in sorted(groups):
-            group = groups[bucket]
-            t_adm = self._clock()
-            for g_req, _row, _shared in group:
-                _tracing.mark(g_req.id, "admit", t_adm,
-                              self.trace_track)
-            timer = self._devprof_timer(
-                f"serving_prefill_paged{{bucket={bucket}}}")
-            t0 = time.perf_counter()
-            try:
-                with _monitor.stat_time("STAT_serving_prefill"), \
-                        _profiler.RecordEvent("serving.prefill"):
-                    live, shed, out = RetryPolicy.from_flags(
-                        "serving.step").call(
-                            self._prefill_group_attempt_paged,
-                            bucket, group)
-            except RetryError as e:
-                for req, row, _ in group:
-                    self.cache.release_row(row)
-                    self._shed(req, e)
-                continue
-            if out is not None:
-                # EMA window closes BEFORE the devprof sync: the
-                # block_until_ready below must not inflate the cost
-                # estimate that drives SLO admission
-                self._note_prefill_ms(
-                    bucket, (time.perf_counter() - t0) * 1e3)
-            if timer is not None and out is not None:
-                timer.device_done(out)
-            for (req, row, _), err in shed:
+            with _profiler.RecordEvent(
+                    "serving.prefill_step",
+                    {"bucket": bucket, "rows": len(groups[bucket])}):
+                admitted += self._prefill_group_paged(bucket,
+                                                      groups[bucket])
+        return consumed, admitted
+
+    def _prefill_group_paged(self, bucket: int,
+                             group) -> int:  # holds: _step_lock
+        """One group of a paged admission round, from building its
+        inputs to its first tokens committed. Returns rows admitted."""
+        t_adm = self._clock()
+        for g_req, _row, _shared in group:
+            _tracing.mark(g_req.id, "admit", t_adm, self.trace_track)
+        timer = self._devprof_timer(
+            f"serving_prefill_paged{{bucket={bucket}}}")
+        t0 = time.perf_counter()
+        try:
+            with _monitor.stat_time("STAT_serving_prefill"), \
+                    _profiler.RecordEvent("serving.prefill"):
+                live, shed, out = RetryPolicy.from_flags(
+                    "serving.step").call(
+                        self._prefill_group_attempt_paged,
+                        bucket, group)
+        except RetryError as e:
+            for req, row, _ in group:
                 self.cache.release_row(row)
-                self._shed(req, err)
-            if not live:
-                continue
-            lg, pools, qerr = out
+                self._shed(req, e)
+            return 0
+        if out is not None:
+            # EMA window closes BEFORE the devprof sync: the
+            # block_until_ready below must not inflate the cost
+            # estimate that drives SLO admission
+            self._note_prefill_ms(
+                bucket, (time.perf_counter() - t0) * 1e3)
+        if timer is not None and out is not None:
+            timer.device_done(out)
+        for (req, row, _), err in shed:
+            self.cache.release_row(row)
+            self._shed(req, err)
+        if not live:
+            return 0
+        lg, pools, qerr = out
+        with _profiler.RecordEvent("serving.prefill.fetch"):
+            first = np.asarray(jnp.argmax(lg, axis=-1))
+        with _profiler.RecordEvent("serving.prefill.commit"):
+            now = self._clock()     # the commit's one stamp
             self.cache.set_arrays(pools)
             self._note_qerr(qerr, sum(len(req.context) - shared
                                       for req, _, shared in live))
-            first = np.asarray(jnp.argmax(lg, axis=-1))
             for i, (req, row, shared) in enumerate(live):
                 ctx = req.context
                 self.cache.commit_prefill(row, len(ctx))
@@ -1624,7 +1647,6 @@ class ServingEngine:
                 req.slot = row
                 req.state = "running"
                 self._active[row] = req
-                admitted += 1
                 if shared:
                     self._prefix_hit_reqs += 1
                     _monitor.stat_add("STAT_serving_prefix_hits")
@@ -1642,11 +1664,11 @@ class ServingEngine:
                     # here instead of re-stamping a first token
                     _tracing.mark(req.id, "resume", self._clock(),
                                   self.trace_track)
-                self._append_token(req,
-                                   self._take_first(req, first, lg, i))
-            if timer is not None and out is not None:
-                timer.finish()
-        return expired + len(candidates) - len(back), admitted
+                self._append_token(
+                    req, self._take_first(req, first, lg, i), now)
+        if timer is not None:
+            timer.finish()
+        return len(live)
 
     def _admit_round(self):  # holds: _step_lock
         """One admission pass: pop up to num_free queued requests,
@@ -1654,54 +1676,66 @@ class ServingEngine:
         group. Returns (popped, admitted)."""
         if self.paged:
             return self._admit_round_paged()
-        candidates, expired = self._pop_candidates(self.cache.num_free)
-        if not candidates:
-            return expired, 0
-        groups: Dict[int, List[Request]] = {}
-        for req in candidates:
-            groups.setdefault(self._bucket_for(len(req.context)),
-                              []).append(req)
+        with _profiler.RecordEvent("serving.schedule") as sched:
+            candidates, expired = self._pop_candidates(
+                self.cache.num_free)
+            if not candidates:
+                return expired, 0
+            groups: Dict[int, List[Request]] = {}
+            for req in candidates:
+                groups.setdefault(self._bucket_for(len(req.context)),
+                                  []).append(req)
+            sched.args = {"admitted": len(candidates)}
         admitted = 0
         for bucket in sorted(groups):
-            group = groups[bucket]
-            t_adm = self._clock()
-            for g_req in group:
-                _tracing.mark(g_req.id, "admit", t_adm,
-                              self.trace_track)
-            timer = self._devprof_timer(
-                f"serving_prefill{{bucket={bucket}}}")
-            t0 = time.perf_counter()
-            try:
-                with _monitor.stat_time("STAT_serving_prefill"), \
-                        _profiler.RecordEvent("serving.prefill"):
-                    live, shed, out = RetryPolicy.from_flags(
-                        "serving.step").call(self._prefill_group_attempt,
-                                             bucket, group)
-            except RetryError as e:
-                for req in group:
-                    self._shed(req, e)
-                continue
-            if out is not None:
-                # EMA window closes before the devprof sync (see the
-                # paged twin above)
-                self._note_prefill_ms(
-                    bucket, (time.perf_counter() - t0) * 1e3)
-            if timer is not None and out is not None:
-                timer.device_done(out)
-            for req, err in shed:
-                self._shed(req, err)
-            if not live:
-                continue
-            lg, rows = out
-            slots = [self.cache.alloc() for _ in live]
-            self.cache.write_prefill_batch(
-                slots, rows, [len(r.context) for r in live])
+            with _profiler.RecordEvent(
+                    "serving.prefill_step",
+                    {"bucket": bucket, "rows": len(groups[bucket])}):
+                admitted += self._prefill_group(bucket, groups[bucket])
+        return expired + len(candidates), admitted
+
+    def _prefill_group(self, bucket: int,
+                       group: List[Request]) -> int:  # holds: _step_lock
+        """The unpaged twin of :meth:`_prefill_group_paged`."""
+        t_adm = self._clock()
+        for g_req in group:
+            _tracing.mark(g_req.id, "admit", t_adm, self.trace_track)
+        timer = self._devprof_timer(
+            f"serving_prefill{{bucket={bucket}}}")
+        t0 = time.perf_counter()
+        try:
+            with _monitor.stat_time("STAT_serving_prefill"), \
+                    _profiler.RecordEvent("serving.prefill"):
+                live, shed, out = RetryPolicy.from_flags(
+                    "serving.step").call(self._prefill_group_attempt,
+                                         bucket, group)
+        except RetryError as e:
+            for req in group:
+                self._shed(req, e)
+            return 0
+        if out is not None:
+            # EMA window closes before the devprof sync (see the
+            # paged twin above)
+            self._note_prefill_ms(
+                bucket, (time.perf_counter() - t0) * 1e3)
+        if timer is not None and out is not None:
+            timer.device_done(out)
+        for req, err in shed:
+            self._shed(req, err)
+        if not live:
+            return 0
+        lg, rows = out
+        slots = [self.cache.alloc() for _ in live]
+        self.cache.write_prefill_batch(
+            slots, rows, [len(r.context) for r in live])
+        with _profiler.RecordEvent("serving.prefill.fetch"):
             first = np.asarray(jnp.argmax(lg, axis=-1))
+        with _profiler.RecordEvent("serving.prefill.commit"):
+            now = self._clock()     # the commit's one stamp
             for i, (req, slot) in enumerate(zip(live, slots)):
                 req.slot = slot
                 req.state = "running"
                 self._active[slot] = req
-                admitted += 1
                 _monitor.stat_add("STAT_serving_prefills")
                 _runlog.log_event("serving_admit", request=req.id,
                                   bucket=bucket, slot=slot,
@@ -1712,11 +1746,11 @@ class ServingEngine:
                 # the first generated token comes from the prefill
                 # logits (same argmax greedy_search takes after ITS
                 # prefill; sampled/masked rows draw from them instead)
-                self._append_token(req,
-                                   self._take_first(req, first, lg, i))
-            if timer is not None and out is not None:
-                timer.finish()
-        return expired + len(candidates), admitted
+                self._append_token(
+                    req, self._take_first(req, first, lg, i), now)
+        if timer is not None:
+            timer.finish()
+        return len(live)
 
     def _take_first(self, req: Request, first: np.ndarray, lg,
                     i: int) -> int:
@@ -1804,22 +1838,23 @@ class ServingEngine:
         kind = fault_point("serving.step")
         if kind == "skip":
             raise _SkipStep("injected skip of one decode iteration")
-        samp = self._build_samp()
         if self.paged:
             fn = decode_step_paged(self.model, self.mesh,
                                    self.kv_dtype,
                                    self._lora_shape)["fn"]
-            args = (jnp.asarray(tokens),
-                    jnp.asarray(self.cache.lengths),
-                    jnp.asarray(self.cache.tables),
-                    self.cache.arrays(), samp)
-            if self._lora_shape is not None:
-                args = args + (self._lora_args(),)
+            with _profiler.RecordEvent("serving.decode.inputs"):
+                args = (jnp.asarray(tokens),
+                        jnp.asarray(self.cache.lengths),
+                        jnp.asarray(self.cache.tables),
+                        self.cache.arrays(), self._build_samp())
+                if self._lora_shape is not None:
+                    args = args + (self._lora_args(),)
             return fn(*args)
         fn = decode_step(self.model)["fn"]
-        return fn(jnp.asarray(tokens),
-                  jnp.asarray(self.cache.lengths),
-                  self.cache.arrays(), samp)
+        with _profiler.RecordEvent("serving.decode.inputs"):
+            args = (jnp.asarray(tokens), jnp.asarray(self.cache.lengths),
+                    self.cache.arrays(), self._build_samp())
+        return fn(*args)
 
     def _note_qerr(self, qerr, rows: int):  # holds: _step_lock
         """Surface an int8 step's max-abs dequantization error: bump
@@ -1888,19 +1923,24 @@ class ServingEngine:
         self._note_tpot_ms((time.perf_counter() - t0) * 1e3)
         if timer is not None:
             timer.device_done(out)   # block_until_ready + stamp
+        qerr = None
         if self.paged:
             nxt, _, arrays, qerr, new_keys = out
-            self._note_qerr(qerr, len(self._active))
         else:
             nxt, _, arrays, new_keys = out
-        self.cache.set_arrays(arrays)
-        self._writeback_keys(new_keys)
-        nxt = np.asarray(nxt)
-        produced = 0
-        for slot, req in list(self._active.items()):
-            self.cache.advance(slot, 1)
-            self._append_token(req, int(nxt[slot]))
-            produced += 1
+        with _profiler.RecordEvent("serving.decode.fetch"):
+            nxt = np.asarray(nxt)     # the host waits for the device
+        with _profiler.RecordEvent("serving.decode.commit",
+                                   {"tokens": len(self._active)}):
+            now = self._clock()       # the commit's one stamp
+            self._note_qerr(qerr, len(self._active))
+            self.cache.set_arrays(arrays)
+            self._writeback_keys(new_keys)
+            produced = 0
+            for slot, req in list(self._active.items()):
+                self.cache.advance(slot, 1)
+                self._append_token(req, int(nxt[slot]), now)
+                produced += 1
         if timer is not None:
             timer.finish()   # host_s = the commit loop above
         return produced
@@ -1962,24 +2002,25 @@ class ServingEngine:
         fn = decode_megastep_paged(self.model, n, self.mesh,
                                    self.kv_dtype,
                                    self._lora_shape)["fn"]
-        samp = self._build_samp()
-        ctx = {
-            "fn": fn,
-            "tables": jnp.asarray(self.cache.tables),
-            "samp_const": (samp[0], samp[1], samp[2], samp[4]),
-            "eos": jnp.asarray(eos),
-            "stop_tables": (jnp.asarray(pat), jnp.asarray(plen),
-                            jnp.asarray(fail)),
-            "lora": (self._lora_args()
-                     if self._lora_shape is not None else None),
-        }
-        spat, splen, sfail = ctx["stop_tables"]
-        args = (jnp.asarray(tokens), jnp.asarray(self.cache.lengths),
-                ctx["tables"], self.cache.arrays(), samp,
-                jnp.asarray(live), jnp.asarray(budget), ctx["eos"],
-                (spat, splen, sfail, jnp.asarray(state)))
-        if self._lora_shape is not None:
-            args = args + (ctx["lora"],)
+        with _profiler.RecordEvent("serving.decode.inputs"):
+            samp = self._build_samp()
+            ctx = {
+                "fn": fn,
+                "tables": jnp.asarray(self.cache.tables),
+                "samp_const": (samp[0], samp[1], samp[2], samp[4]),
+                "eos": jnp.asarray(eos),
+                "stop_tables": (jnp.asarray(pat), jnp.asarray(plen),
+                                jnp.asarray(fail)),
+                "lora": (self._lora_args()
+                         if self._lora_shape is not None else None),
+            }
+            spat, splen, sfail = ctx["stop_tables"]
+            args = (jnp.asarray(tokens), jnp.asarray(self.cache.lengths),
+                    ctx["tables"], self.cache.arrays(), samp,
+                    jnp.asarray(live), jnp.asarray(budget), ctx["eos"],
+                    (spat, splen, sfail, jnp.asarray(state)))
+            if self._lora_shape is not None:
+                args = args + (ctx["lora"],)
         return args, ctx
 
     def _ahead_snapshot(self, n: int, extra_tokens: int = 0):
@@ -2097,27 +2138,31 @@ class ServingEngine:
             # enqueue k+1 behind k on the device BEFORE the host
             # blocks on k's results: commit work below overlaps it
             self._dispatch_ahead(n, out, ctx)
-        toks = np.asarray(toks)          # syncs megastep k
-        finish = np.asarray(finish)
-        keys_arr = np.asarray(keys_f)
-        self.cache.set_arrays(pools_f)
-        self._note_qerr(qerr, n * n_active)
-        produced = 0
-        for slot, req in list(self._active.items()):
-            f = int(finish[slot])
-            ncommit = (f + 1) if f >= 0 else n
-            # iteration i wrote its token's KV at pos0 + i; a slot
-            # finishing at iteration f committed f+1 tokens, a live
-            # slot all n — lengths stay prompt + generated - 1, the
-            # same invariant the single step keeps
-            self.cache.advance(slot, ncommit)
-            for i in range(ncommit):
-                self._append_token(req, int(toks[i, slot]))
-                produced += 1
-                if req.state != "running":
-                    break
-            if req.state == "running":
-                req._key = keys_arr[slot].copy()
+        with _profiler.RecordEvent("serving.decode.fetch"):
+            toks = np.asarray(toks)          # syncs megastep k
+            finish = np.asarray(finish)
+            keys_arr = np.asarray(keys_f)
+        with _profiler.RecordEvent("serving.decode.commit") as commit:
+            now = self._clock()              # the commit's one stamp
+            self.cache.set_arrays(pools_f)
+            self._note_qerr(qerr, n * n_active)
+            produced = 0
+            for slot, req in list(self._active.items()):
+                f = int(finish[slot])
+                ncommit = (f + 1) if f >= 0 else n
+                # iteration i wrote its token's KV at pos0 + i; a slot
+                # finishing at iteration f committed f+1 tokens, a live
+                # slot all n — lengths stay prompt + generated - 1, the
+                # same invariant the single step keeps
+                self.cache.advance(slot, ncommit)
+                for i in range(ncommit):
+                    self._append_token(req, int(toks[i, slot]), now)
+                    produced += 1
+                    if req.state != "running":
+                        break
+                if req.state == "running":
+                    req._key = keys_arr[slot].copy()
+            commit.args = {"tokens": produced}
         if produced:
             # per-token pace: the megastep wall spread over the tokens
             # each slot actually committed (satellite: TPOT samples
@@ -2133,38 +2178,51 @@ class ServingEngine:
         return produced
 
     def _decode_any(self) -> int:  # holds: _step_lock
-        """Route one decode round: the device-resident megastep when
+        """Route one decode round: the draft-verify step when
+        speculation is on, else the device-resident megastep when
         eligible, else the per-token single step (megastep=1, grammar
         rows, oversized stops, tight deadlines). A fallback round
         drops any stored speculation — its snapshot could never match
-        a state the single step advanced."""
-        n = self._choose_megastep()
-        if n > 1:
-            return self._decode_megastep(n)
-        self._ahead = None
-        return self._decode()
+        a state the single step advanced. Whatever runs is one
+        ``serving.decode_step`` span, from building the step's tokens
+        to its last commit; an idle engine records none."""
+        if not self._active:
+            self._ahead = None
+            return 0
+        n = (self.spec_tokens + 1 if self.spec_tokens
+             else self._choose_megastep())
+        with _profiler.RecordEvent(
+                "serving.decode_step",
+                {"active": len(self._active), "n": n}):
+            if self.spec_tokens:
+                return self._spec_decode()
+            if n > 1:
+                return self._decode_megastep(n)
+            self._ahead = None
+            return self._decode()
 
     # ------------------------------------------------- speculative decode
     def _verify_attempt(self, tokens: np.ndarray):
         kind = fault_point("serving.step")
         if kind == "skip":
             raise _SkipStep("injected skip of one verify iteration")
-        samp = self._build_samp()
         if self.paged:
             fn = verify_step_paged(self.model, self.spec_tokens,
                                    self.mesh, self.kv_dtype,
                                    self._lora_shape)["fn"]
-            args = (jnp.asarray(tokens),
-                    jnp.asarray(self.cache.lengths),
-                    jnp.asarray(self.cache.tables),
-                    self.cache.arrays(), samp)
-            if self._lora_shape is not None:
-                args = args + (self._lora_args(),)
+            with _profiler.RecordEvent("serving.decode.inputs"):
+                args = (jnp.asarray(tokens),
+                        jnp.asarray(self.cache.lengths),
+                        jnp.asarray(self.cache.tables),
+                        self.cache.arrays(), self._build_samp())
+                if self._lora_shape is not None:
+                    args = args + (self._lora_args(),)
             return fn(*args)
         fn = verify_step(self.model, self.spec_tokens)["fn"]
-        return fn(jnp.asarray(tokens),
-                  jnp.asarray(self.cache.lengths),
-                  self.cache.arrays(), samp)
+        with _profiler.RecordEvent("serving.decode.inputs"):
+            args = (jnp.asarray(tokens), jnp.asarray(self.cache.lengths),
+                    self.cache.arrays(), self._build_samp())
+        return fn(*args)
 
     def _spec_decode(self) -> int:  # holds: _step_lock
         """One speculative draft–verify step over every occupied slot:
@@ -2201,42 +2259,49 @@ class ServingEngine:
             return 0
         if timer is not None:
             timer.device_done(out)
+        qerr = None
         if self.paged:
             nxt, _, arrays, qerr, accept, new_keys = out
-            self._note_qerr(qerr, (K + 1) * len(self._active))
         else:
             nxt, _, arrays, accept, new_keys = out
-        self.cache.set_arrays(arrays)
-        self._writeback_keys(new_keys)
-        nxt = np.asarray(nxt)
-        accept = np.asarray(accept)
-        produced = 0
-        for slot, req in list(self._active.items()):
-            # the verify wrote K+1 rows at this slot's offset; commit
-            # them optimistically, then trim to what was accepted
-            self.cache.advance(slot, K + 1)
-            committed = accepted = 0
-            for i in range(K + 1):
-                tok = int(nxt[slot, i])
-                self._append_token(req, tok)
-                committed += 1
-                produced += 1
-                if req.state != "running":
-                    break        # finished (EOS / budget) mid-verify
-                if i == K or not bool(accept[slot, i]):
-                    break        # out of drafts / first rejection
-                accepted += 1
-            self._spec_proposed += K
-            self._spec_accepted += accepted
-            _monitor.stat_add("STAT_serving_spec_proposed", K)
-            _monitor.stat_add("STAT_serving_spec_accepted", accepted)
-            if _runlog.enabled():
-                _runlog.log_event("serving_spec", request=req.id,
-                                  proposed=K, accepted=accepted)
-            if req.state == "running":
-                # reject the unaccepted tail: roll the write offset
-                # back so the next step overwrites those rows
-                self.cache.rollback(slot, K + 1 - committed)
+        with _profiler.RecordEvent("serving.decode.fetch"):
+            nxt = np.asarray(nxt)
+            accept = np.asarray(accept)
+        with _profiler.RecordEvent("serving.decode.commit") as commit:
+            now = self._clock()          # the commit's one stamp
+            self._note_qerr(qerr, (K + 1) * len(self._active))
+            self.cache.set_arrays(arrays)
+            self._writeback_keys(new_keys)
+            produced = 0
+            for slot, req in list(self._active.items()):
+                # the verify wrote K+1 rows at this slot's offset;
+                # commit them optimistically, then trim to what was
+                # accepted
+                self.cache.advance(slot, K + 1)
+                committed = accepted = 0
+                for i in range(K + 1):
+                    tok = int(nxt[slot, i])
+                    self._append_token(req, tok, now)
+                    committed += 1
+                    produced += 1
+                    if req.state != "running":
+                        break    # finished (EOS / budget) mid-verify
+                    if i == K or not bool(accept[slot, i]):
+                        break    # out of drafts / first rejection
+                    accepted += 1
+                self._spec_proposed += K
+                self._spec_accepted += accepted
+                _monitor.stat_add("STAT_serving_spec_proposed", K)
+                _monitor.stat_add("STAT_serving_spec_accepted",
+                                  accepted)
+                if _runlog.enabled():
+                    _runlog.log_event("serving_spec", request=req.id,
+                                      proposed=K, accepted=accepted)
+                if req.state == "running":
+                    # reject the unaccepted tail: roll the write offset
+                    # back so the next step overwrites those rows
+                    self.cache.rollback(slot, K + 1 - committed)
+            commit.args = {"tokens": produced}
         if produced:
             # per-output-token pace: step wall time spread over the
             # tokens each slot actually committed this step
@@ -2247,14 +2312,22 @@ class ServingEngine:
         return produced
 
     # -------------------------------------------------------- lifecycle
-    def _append_token(self, req: Request, token: int):
+    def _append_token(self, req: Request, token: int, now: float):
+        """Commit one token; ``now`` is the commit's one clock read."""
         req.tokens.append(token)
         if req.first_token_at is None:
-            req.first_token_at = self._clock()
+            req.first_token_at = now
             # the mark reuses the stamp so the blame prefix up to
             # first_token equals the measured TTFT exactly
-            _tracing.mark(req.id, "first_token", req.first_token_at,
-                          self.trace_track)
+            _tracing.mark(req.id, "first_token", now, self.trace_track)
+        elif req.token_at:
+            # the program's own inter-token time, on the clock of every
+            # other span (nothing is recorded with the profiler off);
+            # tokens that share a commit are 0 apart
+            _profiler.record_span("serving.token_gap", req.token_at[-1],
+                                  now - req.token_at[-1],
+                                  {"request": req.id})
+        req.token_at.append(now)
         _monitor.stat_add("STAT_serving_tokens")
         if req._stop is not None:
             # advance the incremental matcher over the committed token
@@ -2482,20 +2555,23 @@ class ServingEngine:
         speculation on, one draft–verify multi-token step. Returns
         whether any work happened."""
         with self._step_lock:
-            _monitor.stat_add("STAT_serving_steps")
-            # hard-deadline sweep first: a request that expired since
-            # the last step is canceled within one step and its slot
-            # is free for this step's admissions
-            reaped = self._reap_expired()
-            admitted = self._admit()
-            produced = (self._spec_decode() if self.spec_tokens
-                        else self._decode_any())
-            if self.kv_tier is not None:
-                self._demote_sweep()
-            if self.paged:
-                self._blocks_used_g.set(self.cache.blocks_used)
-                self._blocks_free_g.set(self.cache.blocks_free)
-            return bool(admitted or produced or reaped)
+            self._step_no += 1
+            with _profiler.RecordEvent(
+                    "serving.engine_step",
+                    {"step": self._step_no, "active": len(self._active),
+                     "queued": len(self._queue)}):
+                # hard-deadline sweep first: a request that expired
+                # since the last step is canceled within one step and
+                # its slot is free for this step's admissions
+                reaped = self._reap_expired()
+                admitted = self._admit()
+                produced = self._decode_any()
+                if self.kv_tier is not None:
+                    self._demote_sweep()
+                if self.paged:
+                    self._blocks_used_g.set(self.cache.blocks_used)
+                    self._blocks_free_g.set(self.cache.blocks_free)
+                return bool(admitted or produced or reaped)
 
     def _demote_sweep(self):  # holds: _step_lock
         """Between-steps host-tier demotion: prefix entries that have

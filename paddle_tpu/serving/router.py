@@ -729,6 +729,7 @@ class ReplicaRouter:
         timing and terminal state, then release the waiter."""
         req.tokens = list(clone.tokens)
         req.first_token_at = clone.first_token_at
+        req.token_at = list(clone.token_at)
         req.finished_at = clone.finished_at
         req.error = clone.error
         req.shed_reason = clone.shed_reason

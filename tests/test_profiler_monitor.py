@@ -77,9 +77,65 @@ def test_chrome_trace_event_schema(tmp_path):
     profiler.stop_profiler(profile_path=path)
     trace = json.load(open(path))
     (e,) = trace["traceEvents"]
-    assert set(e) == {"name", "ph", "ts", "dur", "pid", "tid", "cat"}
+    # ... plus the span's identity; "args" only when some were given
+    assert set(e) == {"name", "ph", "ts", "dur", "pid", "tid", "cat",
+                      "id", "parent"}
     assert e["ph"] == "X" and e["cat"] == "host" and e["pid"] == 0
     assert e["dur"] >= 1000  # slept 1ms; dur is in microseconds
+    assert isinstance(e["id"], int) and e["parent"] is None
+
+
+def test_span_identity_parent_args_and_self_time(tmp_path):
+    """Each event keeps a process-unique id, the id of the RecordEvent
+    open on its thread when it was entered, and its args; summarize()
+    gives a name's self time: its durations minus what children cover."""
+    path = str(tmp_path / "tree.json")
+    profiler.start_profiler()
+    with profiler.RecordEvent("outer", {"step": 3}):
+        with profiler.RecordEvent("inner"):
+            time.sleep(0.002)
+        with profiler.RecordEvent("inner"):
+            with profiler.RecordEvent("leaf", {"rows": 2, "kind": "x"}):
+                time.sleep(0.001)
+        time.sleep(0.001)
+    # an interval measured elsewhere, on the same clock, parentless
+    t = time.perf_counter()
+    profiler.record_span("gap", t - 0.5, 0.25, {"request": 7})
+    summary = profiler.stop_profiler(profile_path=path)
+    events = json.load(open(path))["traceEvents"]
+    by_name = {}
+    for e in events:
+        by_name.setdefault(e["name"], []).append(e)
+    (outer,), (leaf,), (gap,) = by_name["outer"], by_name["leaf"], by_name["gap"]
+    assert len({e["id"] for e in events}) == len(events) == 5
+    assert outer["parent"] is None and outer["args"] == {"step": 3}
+    assert [e["parent"] for e in by_name["inner"]] == [outer["id"]] * 2
+    assert leaf["parent"] == by_name["inner"][1]["id"]
+    assert leaf["args"] == {"rows": 2, "kind": "x"}
+    assert "args" not in by_name["inner"][0]
+    assert gap["parent"] is None and gap["args"] == {"request": 7}
+    assert gap["dur"] == pytest.approx(0.25e6)
+    assert gap["ts"] == pytest.approx((t - 0.5) * 1e6)
+    rows = {s["name"]: s for s in summary}
+    inner_total = sum(e["dur"] for e in by_name["inner"]) / 1e3
+    assert rows["outer"]["self_ms"] == pytest.approx(
+        outer["dur"] / 1e3 - inner_total)
+    assert rows["outer"]["self_ms"] >= 1.0          # its own 1 ms sleep
+    assert rows["inner"]["self_ms"] == pytest.approx(
+        inner_total - leaf["dur"] / 1e3)
+    assert rows["leaf"]["self_ms"] == pytest.approx(rows["leaf"]["total_ms"])
+    # the stack is kept only while the profiler is on: a span entered
+    # now is nobody's child later
+    with profiler.RecordEvent("off"):
+        profiler.start_profiler()
+        with profiler.RecordEvent("fresh"):
+            pass
+    profiler.stop_profiler(profile_path=path)
+    (fresh,) = json.load(open(path))["traceEvents"]
+    assert fresh["name"] == "fresh" and fresh["parent"] is None
+    profiler.record_span("ghost", 0.0, 1.0)         # off: nothing
+    profiler.start_profiler()
+    assert profiler.stop_profiler(profile_path=path) == []
 
 
 def test_summarize_sort_keys():
