@@ -281,9 +281,12 @@ define_flag("serving_dispatch_ahead", False,
             "(jax.block_until_ready only at commit). The speculative "
             "dispatch is consumed only if the scheduler state it "
             "assumed is unchanged (no finishes, no admissions, no "
-            "weight/flag changes); otherwise it is discarded — pools "
-            "are pure functional values, so a discard has no side "
-            "effects. Requires serving_megastep > 1.")
+            "weight/flag changes); otherwise its tokens are discarded. "
+            "It has consumed step k's pools like any paged step, so the "
+            "cache holds the pools it returned: the rows it wrote lie "
+            "at or beyond every slot's committed length and are "
+            "written again before anything reads them. Requires "
+            "serving_megastep > 1.")
 define_flag("serving_dispatch_threads", 0,
             "Router dispatch concurrency: ReplicaRouter / DisaggRouter "
             "step their replicas from a bounded thread pool of this "
@@ -316,8 +319,9 @@ define_flag("serving_num_blocks", 0,
             "pool runs dry).")
 define_flag("serving_attn_impl", "xla",
             "Paged decode/verify/prefill attention implementation: "
-            "'xla' composes block_gather + masked softmax (the "
-            "reference oracle); 'pallas' runs the fused paged "
+            "'xla' gathers each request's blocks and composes the "
+            "masked softmax over them (ops.attention_ops."
+            "block_attention); 'pallas' runs the fused paged "
             "decode-attention kernel (ops/pallas/paged_attention.py) "
             "that walks each request's block table inside the kernel — "
             "gather + QK^T + online softmax + V-accumulate in one "

@@ -303,6 +303,16 @@ def verify_step(model, spec_tokens: int):
     return step_entry(model, ("verify", k), _build)
 
 
+#: Every paged step entry takes ``pools`` as argument 4 of its jitted
+#: function (after params, two per-row inputs and the tables) and owns
+#: it: the caller's arrays are deleted by the call and the returned
+#: pools take their place. With the in-place row write of
+#: ``ops.attention_ops.block_scatter_write`` that is what keeps a decode
+#: step from copying every pool; a caller that still needs the old
+#: pools (nobody does on the serving path) copies them first.
+POOLS_DONATED = {"donate_argnums": (4,)}
+
+
 def _wrap_pools(pools):
     """Lift raw per-layer pool tuples into Tensors, generically over
     the tuple width: (k, v) float pools or (k, v, k_scale, v_scale)
@@ -347,6 +357,11 @@ def decode_step_paged(model, mesh=None, kv_dtype: str = "f32",
     path's max-abs dequantization error over the rows written this
     step (0.0 for float pools).
 
+    The step **owns** ``pools`` (:data:`POOLS_DONATED`, as do the
+    megastep, verify and the engine's paged prefill entries): the
+    arrays passed in are deleted by the call, the rows are written in
+    place, and ``new_pools`` is what the caller holds from then on.
+
     With ``lora_shape`` = (rank, pages) the step gains one more input:
     ``lora = (page_ids [b] i32, pool_arrays)`` from a
     ``serving.lora.LoRAPool`` — per-row adapter pages gathered inside
@@ -390,14 +405,14 @@ def decode_step_paged(model, mesh=None, kv_dtype: str = "f32",
                 return _impl(params, tokens, pos, tables, pools, samp,
                              lora)
 
-        jit_kwargs = {}
+        jit_kwargs = dict(POOLS_DONATED)
         if mesh is not None:
             repl, pools_sh = _mesh_step_shardings(model, mesh, kv_dtype)
             in_sh = (_mesh_param_shardings(model, mesh),
                      repl, repl, repl, pools_sh, repl)
             if lora_shape is not None:
                 in_sh = in_sh + (repl,)
-            jit_kwargs = dict(
+            jit_kwargs.update(
                 in_shardings=in_sh,
                 out_shardings=(repl, repl, pools_sh, repl, repl))
         fn = _inject_params(
@@ -535,7 +550,7 @@ def decode_megastep_paged(model, n: int, mesh=None, kv_dtype: str = "f32",
                 return _impl(params, tokens, pos, tables, pools, samp,
                              live, budget, eos, stop, lora)
 
-        jit_kwargs = {}
+        jit_kwargs = dict(POOLS_DONATED)
         if mesh is not None:
             repl, pools_sh = _mesh_step_shardings(model, mesh, kv_dtype)
             in_sh = (_mesh_param_shardings(model, mesh),
@@ -543,7 +558,7 @@ def decode_megastep_paged(model, n: int, mesh=None, kv_dtype: str = "f32",
                      repl, repl)
             if lora_shape is not None:
                 in_sh = in_sh + (repl,)
-            jit_kwargs = dict(
+            jit_kwargs.update(
                 in_shardings=in_sh,
                 out_shardings=(repl, repl, repl, repl, pools_sh, repl,
                                repl, repl, repl, repl))
@@ -607,14 +622,14 @@ def verify_step_paged(model, spec_tokens: int, mesh=None,
                              lora)
 
         from ..observability import compile_tracker as _ct
-        jit_kwargs = {}
+        jit_kwargs = dict(POOLS_DONATED)
         if mesh is not None:
             repl, pools_sh = _mesh_step_shardings(model, mesh, kv_dtype)
             in_sh = (_mesh_param_shardings(model, mesh),
                      repl, repl, repl, pools_sh, repl)
             if lora_shape is not None:
                 in_sh = in_sh + (repl,)
-            jit_kwargs = dict(
+            jit_kwargs.update(
                 in_shardings=in_sh,
                 out_shardings=(repl, repl, pools_sh, repl, repl, repl))
         fn = _inject_params(

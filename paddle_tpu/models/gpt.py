@@ -172,7 +172,7 @@ class GPTAttention(Layer):
             # compiled step caches key on the flags version, so
             # flipping the flag retraces instead of going stale.
             from .. import flags as _flags
-            from ..ops.attention_ops import (block_gather,
+            from ..ops.attention_ops import (block_attention,
                                              block_gather_dequant,
                                              block_scatter_write,
                                              block_scatter_write_quant,
@@ -206,13 +206,12 @@ class GPTAttention(Layer):
                 out = Tensor(paged_attention(q.value, kp, vp, tables, pos,
                                              k_scale=ksc, v_scale=vsc),
                              stop_gradient=True)
+            elif not quant:
+                out = Tensor(block_attention(q.value, kp, vp, tables, pos),
+                             stop_gradient=True)
             else:
-                if quant:
-                    kg = block_gather_dequant(kp, ksc, tables)
-                    vg = block_gather_dequant(vp, vsc, tables)
-                else:
-                    kg = block_gather(kp, tables)    # [b, h, T*bs, d]
-                    vg = block_gather(vp, tables)
+                kg = block_gather_dequant(kp, ksc, tables)
+                vg = block_gather_dequant(vp, vsc, tables)
                 mask = decode_attention_mask(pos, s, kg.shape[2],
                                              kg.dtype)
                 out = run_op("fused_attention_qkv",

@@ -83,23 +83,40 @@ def cache_scatter_write(buf, new, pos):
     return jax.vmap(_write)(buf, new, pos)
 
 
+#: most rows (batch x rows per request) a KV write lands as unrolled
+#: in-place row updates; above it the one fused scatter is cheaper. On
+#: the v5e an update of one [h, d] row costs ~1.2 us against ~320 us a
+#: pool for the scatter's two layout copies, and 160 rows a pool in 48
+#: pools take 47 s to compile (PERF.md, PR 25).
+INPLACE_WRITE_MAX_ROWS = 64
+
+
 def block_scatter_write(pool, new, pos, tables, overflow_block=0):
     """Write ``new`` [b, h, s, d] rows into the block-paged KV pool
     ``pool`` [num_blocks, h, block_size, d], routing each batch row's
     logical positions ``pos[b]..pos[b]+s-1`` through its block table
     row ``tables[b]`` [b, T] to physical (block, offset) pairs — the
-    paged generalization of :func:`cache_scatter_write`, still a
-    single fused XLA scatter so the compiled decode/verify/prefill
-    steps keep one fixed signature.
+    paged generalization of :func:`cache_scatter_write`, at one fixed
+    signature for the compiled decode/verify/prefill steps.
+
+    The form follows the static shape. A few rows (decode: one per
+    request; verify: K+1) are written as one in-place
+    ``dynamic_update_slice`` of ``[1, h, 1, d]`` each, which keeps the
+    pool in the layout it arrived in: with the pool donated to the
+    step nothing pool-sized is copied. Many rows (the prefill buckets)
+    go through one fused scatter, for which XLA's TPU layout assignment
+    moves the whole pool to ``[block, row, head, d]`` and back — a fixed
+    cost that only a large write repays.
 
     Positions whose logical block falls outside the table (bucketed
     prefill's suffix padding rows, beyond a short request's
     reservation) are routed to ``overflow_block`` — physical block 0,
     BlockKVCache's permanently-allocated *trash block* — instead of
     letting XLA's index clamping silently redirect them onto a live
-    block's committed rows. Duplicate (trash, offset) targets are fine:
-    scatter picks one row's value, and nothing ever reads the trash
-    block through a position mask.
+    block's committed rows (so every start index is in range and the
+    clamping never engages). Duplicate (trash, offset) targets are
+    fine: one row's value lands, and nothing ever reads the trash block
+    through a position mask.
     """
     pos = jnp.asarray(pos, jnp.int32)
     b, h, s, d = new.shape
@@ -112,6 +129,17 @@ def block_scatter_write(pool, new, pos, tables, overflow_block=0):
         jnp.minimum(logical, T - 1), axis=1)                      # [b, s]
     phys = jnp.where(logical < T, phys, jnp.int32(overflow_block))
     offset = rowpos % bs
+    new = new.astype(pool.dtype)
+    if b * s <= INPLACE_WRITE_MAX_ROWS:
+        # all start indices must share a dtype (x64 mode makes a bare
+        # python 0 an int64)
+        z = jnp.zeros((), jnp.int32)
+        for i in range(b):
+            for j in range(s):
+                pool = jax.lax.dynamic_update_slice(
+                    pool, new[i:i + 1, :, j:j + 1],
+                    (phys[i, j], z, offset[i, j], z))
+        return pool
     # advanced indices (flat rows) are separated from the heads slice,
     # so they broadcast to the FRONT: value rows are [b*s, h, d]
     rows = jnp.swapaxes(new, 1, 2).reshape(b * s, h, d)
@@ -202,6 +230,28 @@ def block_gather(pool, tables):
     g = pool[jnp.asarray(tables, jnp.int32)]        # [b, T, h, bs, d]
     b, T, h, bs, d = g.shape
     return jnp.swapaxes(g, 1, 2).reshape(b, h, T * bs, d)
+
+
+def block_attention(q, k_pool, v_pool, tables, pos):
+    """Masked softmax attention of ``q`` [b, h, s, d] over each
+    request's paged float KV, composed in XLA on the blocks as they are
+    gathered: ``pool[tables]`` is [b, T, h, bs, d], and both
+    contractions take it in that order instead of through
+    :func:`block_gather`'s [b, h, T*bs, d] view. With the pool kept in
+    its own layout (:func:`block_scatter_write`) that view costs a
+    transposing copy of the whole gathered V of every layer on the TPU;
+    this form has none. Same mask, same values as
+    :func:`paged_attention_reference` -> [b, h, s, d].
+    """
+    tables = jnp.asarray(tables, jnp.int32)
+    kg, vg = k_pool[tables], v_pool[tables]         # [b, T, h, bs, d]
+    b, T, h, bs, d = kg.shape
+    s = q.shape[2]
+    logits = jnp.einsum("bhqd,bthkd->bhqtk", q, kg).reshape(
+        b, h, s, T * bs) * (1.0 / math.sqrt(d))
+    logits = logits + decode_attention_mask(pos, s, T * bs, logits.dtype)
+    probs = jax.nn.softmax(logits, axis=-1).reshape(b, h, s, T, bs)
+    return jnp.einsum("bhqtk,bthkd->bhqd", probs, vg)
 
 
 def block_gather_dequant(pool, scales, tables):

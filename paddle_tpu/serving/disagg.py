@@ -350,6 +350,8 @@ class DecodeEngine(ServingEngine):
         kind = fault_point("serving.handoff")
         if kind == "skip":
             raise _Shed("injected shed at serving.handoff")
+        if item.rec["epoch"] != item.rec["pool"].epoch:
+            raise _Shed("the record's KV went with its rebuilt pool")
         same_pool = item.rec["pool"] is self.cache.pool
         row = (self.cache.import_row(item.rec) if same_pool
                else self.cache.adopt_row(item.rec))

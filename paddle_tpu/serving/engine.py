@@ -156,6 +156,11 @@ class _Shed(Exception):
     NOT be retried."""
 
 
+class _PoolsLost(Exception):
+    """A paged entry failed after it had consumed the KV pools: the
+    engine has shed what was running and rebuilt zeroed pools."""
+
+
 class _SkipStep(Exception):
     """Internal: skip one decode iteration (injected `skip` at
     serving.step during decode); requests stay live."""
@@ -723,6 +728,11 @@ class ServingEngine:
         self._ahead = None                # guarded-by: _step_lock
         self._ahead_hits = 0              # guarded-by: _step_lock
         self._ahead_misses = 0            # guarded-by: _step_lock
+        # paged dispatches, and those after which the pools handed in
+        # were deleted (donated: the KV rows were written in place)
+        self._pool_dispatches = 0         # guarded-by: _step_lock
+        self._pool_inplace = 0            # guarded-by: _step_lock
+        self._pool_epoch = self.cache.pool.epoch if self.paged else 0
         self._qerr_max = 0.0              # guarded-by: _step_lock
         self._qerr_gauge = None
         if self.kv_dtype == "int8":
@@ -765,6 +775,8 @@ class ServingEngine:
             "_ahead": "_step_lock",
             "_ahead_hits": "_step_lock",
             "_ahead_misses": "_step_lock",
+            "_pool_dispatches": "_step_lock",
+            "_pool_inplace": "_step_lock",
         })
 
     # -------------------------------------------------------------- mesh
@@ -1412,7 +1424,9 @@ class ServingEngine:
                 pools_out, qerr = _unwrap_pools(newp)
                 return lg, pools_out, qerr
 
-            jit_kwargs = {}
+            # the entry owns ``pools`` (argument 5 here), like the
+            # step entries: generation.POOLS_DONATED
+            jit_kwargs = {"donate_argnums": (5,)}
             if mesh is not None:
                 from ..models.generation import (_mesh_param_shardings,
                                                  _mesh_step_shardings)
@@ -1422,7 +1436,7 @@ class ServingEngine:
                          repl, repl, repl, repl, pools_sh)
                 if lora_shape is not None:
                     in_sh = in_sh + (repl,)
-                jit_kwargs = dict(
+                jit_kwargs.update(
                     in_shardings=in_sh,
                     out_shardings=(repl, pools_sh, repl))
             fn = _inject_params(
@@ -1490,7 +1504,7 @@ class ServingEngine:
                 self.cache.arrays())
         if self.lora_pool is not None:
             args = args + ((jnp.asarray(pages), self.lora_pool.arrays),)
-        return live, shed, fn(*args)
+        return live, shed, self._call_paged(fn, args, args[4])
 
     def _pop_candidates(self, limit: int):
         """Pop up to ``limit`` queued requests in admission order —
@@ -1614,7 +1628,7 @@ class ServingEngine:
                     "serving.step").call(
                         self._prefill_group_attempt_paged,
                         bucket, group)
-        except RetryError as e:
+        except (RetryError, _PoolsLost) as e:
             for req, row, _ in group:
                 self.cache.release_row(row)
                 self._shed(req, e)
@@ -1834,6 +1848,47 @@ class ServingEngine:
                 pages[slot] = self.lora_pool.page_of(req.tenant)
         return (jnp.asarray(pages), self.lora_pool.arrays)
 
+    def _shed_active(self, err: BaseException):  # holds: _step_lock
+        """Shed every running request and free its row."""
+        for slot, req in list(self._active.items()):
+            del self._active[slot]
+            self.cache.release(slot)
+            self._shed(req, err)
+
+    def _call_paged(self, fn, args, pools):  # holds: _step_lock
+        """Dispatch one paged entry. The entry owns ``pools`` (one of
+        ``args``, fresh from ``cache.arrays()`` or a previous entry's
+        result): the call deletes them, and the caller binds the
+        returned pools before anything reads the cache's arrays again.
+        ``STAT_serving_pool_inplace`` counts the dispatches after which
+        they were in fact deleted (all of them, unless the backend
+        ignores donation: then the pools are copied as before).
+
+        An entry that raises once the pools are gone has taken every
+        row's KV with it: what was running is shed, the pool is rebuilt
+        zeroed with its prefix cache flushed, and :class:`_PoolsLost`
+        tells the caller that this dispatch produced nothing. A raise
+        that left the pools alone passes through (the retry policy's
+        business, as before)."""
+        try:
+            out = fn(*args)
+        except Exception as e:
+            if not pools[0][0].is_deleted():
+                raise
+            self._ahead = None
+            self._shed_active(e)
+            self.cache.pool.rebuild()
+            self._pool_epoch = self.cache.pool.epoch
+            _monitor.stat_add("STAT_serving_pool_rebuilds")
+            _runlog.log_event("serving_pool_rebuild", error=str(e))
+            raise _PoolsLost(f"KV pools consumed by a failed step: {e}"
+                             ) from e
+        self._pool_dispatches += 1
+        if pools[0][0].is_deleted():
+            self._pool_inplace += 1
+            _monitor.stat_add("STAT_serving_pool_inplace")
+        return out
+
     def _decode_attempt(self, tokens: np.ndarray):
         kind = fault_point("serving.step")
         if kind == "skip":
@@ -1849,7 +1904,7 @@ class ServingEngine:
                         self.cache.arrays(), self._build_samp())
                 if self._lora_shape is not None:
                     args = args + (self._lora_args(),)
-            return fn(*args)
+            return self._call_paged(fn, args, args[3])
         fn = decode_step(self.model)["fn"]
         with _profiler.RecordEvent("serving.decode.inputs"):
             args = (jnp.asarray(tokens), jnp.asarray(self.cache.lengths),
@@ -1904,15 +1959,12 @@ class ServingEngine:
                     _profiler.RecordEvent("serving.decode"):
                 out = RetryPolicy.from_flags(
                     "serving.step").call(self._decode_attempt, tokens)
-        except _SkipStep:
+        except (_SkipStep, _PoolsLost):
             return 0
         except RetryError as e:
             # the step itself is unrecoverable: shed the affected
             # requests, keep the engine alive for new submissions
-            for slot, req in list(self._active.items()):
-                del self._active[slot]
-                self.cache.release(slot)
-                self._shed(req, e)
+            self._shed_active(e)
             return 0
         # the TPOT EWMA is per *committed token*: one step commits
         # exactly one token per active slot here, so the step wall is
@@ -2040,8 +2092,16 @@ class ServingEngine:
         queue stays fed while the host commits. The dispatch assumes
         k commits with no finishes, no admissions, no reaps and no
         weight/flag/pool changes; :meth:`_take_ahead` validates all of
-        that before consuming, and a discard is free (pools are pure
-        functional values — nothing was mutated)."""
+        that before consuming.
+
+        The speculative step consumes k's pools like any paged entry,
+        so from here on the KV lives in **its** returned pools, which
+        this returns for k's commit to bind. A discarded speculation
+        therefore leaves its rows behind: each live slot's next ``n``
+        rows, all at or beyond the slot's committed length, where
+        nothing reads before the re-dispatched step writes them again
+        (the invariant that already covers bucket padding, rejected
+        drafts and the trash block). Only its tokens are dropped."""
         (_toks, _finish, tok_f, pos_f, pools_f, keys_f, live_f,
          rem_f, st_f, _qerr) = out
         temp, tk, tp, mask = ctx["samp_const"]
@@ -2051,24 +2111,26 @@ class ServingEngine:
                 ctx["eos"], (spat, splen, sfail, st_f))
         if self._lora_shape is not None:
             args = args + (ctx["lora"],)
+        ahead_out = self._call_paged(ctx["fn"], args, pools_f)
         self._ahead = {
             "n": n,
             "snap": self._ahead_snapshot(n, extra_tokens=n),
-            "leaf": pools_f[0][0],
+            "leaf": ahead_out[4][0][0],
             "lora_arrays": (None if self._lora_shape is None
                             else self.lora_pool.arrays),
-            "out": ctx["fn"](*args),
+            "out": ahead_out,
             "ctx": ctx,
         }
+        return ahead_out[4]
 
     def _take_ahead(self, n: int):  # holds: _step_lock
         """Consume the stored speculative megastep iff the live
         scheduler state matches what it assumed — same N, same
         (slot, request, length) composition, same weight/flag
-        versions, and the KV pools are *the same arrays* the
-        speculation read (identity check on a pool leaf: any prefill,
-        demotion, promotion or adoption rebinds them). Single-shot:
-        hit or miss, the slot clears."""
+        versions, and the cache still holds *the arrays* the
+        speculation returned (identity check on a pool leaf: any
+        prefill, demotion, promotion or adoption rebinds them).
+        Single-shot: hit or miss, the slot clears."""
         ah, self._ahead = self._ahead, None
         if ah is None:
             return None
@@ -2098,7 +2160,7 @@ class ServingEngine:
         if taken is not None:
             return taken
         args, ctx = self._megastep_inputs(n)
-        return ctx["fn"](*args), ctx
+        return self._call_paged(ctx["fn"], args, args[3]), ctx
 
     def _decode_megastep(self, n: int) -> int:  # holds: _step_lock
         """One device-resident megastep over every occupied slot: N
@@ -2118,13 +2180,10 @@ class ServingEngine:
                     _profiler.RecordEvent("serving.decode"):
                 out, ctx = RetryPolicy.from_flags(
                     "serving.step").call(self._megastep_attempt, n)
-        except _SkipStep:
+        except (_SkipStep, _PoolsLost):
             return 0
         except RetryError as e:
-            for slot, req in list(self._active.items()):
-                del self._active[slot]
-                self.cache.release(slot)
-                self._shed(req, e)
+            self._shed_active(e)
             return 0
         (toks, finish, _tok_f, _pos_f, pools_f, keys_f, _live_f,
          _rem_f, _st_f, qerr) = out
@@ -2136,8 +2195,12 @@ class ServingEngine:
             timer.device_done(out)
         if self.dispatch_ahead:
             # enqueue k+1 behind k on the device BEFORE the host
-            # blocks on k's results: commit work below overlaps it
-            self._dispatch_ahead(n, out, ctx)
+            # blocks on k's results: commit work below overlaps it.
+            # k+1 consumed k's pools; the cache binds what it returned
+            try:
+                pools_f = self._dispatch_ahead(n, out, ctx)
+            except _PoolsLost:
+                return 0
         with _profiler.RecordEvent("serving.decode.fetch"):
             toks = np.asarray(toks)          # syncs megastep k
             finish = np.asarray(finish)
@@ -2186,6 +2249,12 @@ class ServingEngine:
         a state the single step advanced. Whatever runs is one
         ``serving.decode_step`` span, from building the step's tokens
         to its last commit; an idle engine records none."""
+        if self.paged and self.cache.pool.epoch != self._pool_epoch:
+            # a co-located engine's failed step took the shared pool's
+            # contents (see _call_paged): this engine's rows went too
+            self._pool_epoch = self.cache.pool.epoch
+            self._shed_active(_PoolsLost(
+                "shared KV pool rebuilt by a co-located engine"))
         if not self._active:
             self._ahead = None
             return 0
@@ -2217,7 +2286,7 @@ class ServingEngine:
                         self.cache.arrays(), self._build_samp())
                 if self._lora_shape is not None:
                     args = args + (self._lora_args(),)
-            return fn(*args)
+            return self._call_paged(fn, args, args[3])
         fn = verify_step(self.model, self.spec_tokens)["fn"]
         with _profiler.RecordEvent("serving.decode.inputs"):
             args = (jnp.asarray(tokens), jnp.asarray(self.cache.lengths),
@@ -2249,13 +2318,10 @@ class ServingEngine:
                     _profiler.RecordEvent("serving.verify"):
                 out = RetryPolicy.from_flags(
                     "serving.step").call(self._verify_attempt, tokens)
-        except _SkipStep:
+        except (_SkipStep, _PoolsLost):
             return 0
         except RetryError as e:
-            for slot, req in list(self._active.items()):
-                del self._active[slot]
-                self.cache.release(slot)
-                self._shed(req, e)
+            self._shed_active(e)
             return 0
         if timer is not None:
             timer.device_done(out)
@@ -2628,6 +2694,8 @@ class ServingEngine:
             prefix_miss_reqs = self._prefix_miss_reqs
             ahead_hits = self._ahead_hits
             ahead_misses = self._ahead_misses
+            pool_dispatches = self._pool_dispatches
+            pool_inplace = self._pool_inplace
         with self._lock:
             completed = self._completed
             slo_met = self._slo_met
@@ -2727,6 +2795,15 @@ class ServingEngine:
                 "prefix_miss_tokens": miss_t,
                 "prefix_hit_rate": (round(hit_t / (hit_t + miss_t), 4)
                                     if hit_t + miss_t else None),
+                # paged dispatches, and the share of them that wrote
+                # their KV rows in place (the pools handed in were
+                # deleted by the call): 1.0 unless the backend ignores
+                # donation
+                "pool_dispatches": pool_dispatches,
+                "pool_inplace": pool_inplace,
+                "pool_inplace_share": (
+                    round(pool_inplace / pool_dispatches, 4)
+                    if pool_dispatches else None),
             })
         return out
 

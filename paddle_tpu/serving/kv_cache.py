@@ -254,9 +254,19 @@ class BlockPool:
 
     Several caches may hold one pool (co-located prefill/decode engine
     roles): each cache keeps its own row state (block tables, lengths,
-    free rows) while allocation, prefix sharing and the functional
-    array updates all land here — ``set_arrays`` through any sharing
-    cache replaces the arrays every other cache reads.
+    free rows) while allocation, prefix sharing and the arrays all
+    land here — ``set_arrays`` through any sharing cache replaces the
+    arrays every other cache reads.
+
+    Ownership of ``layers`` is linear. A compiled paged step is handed
+    them (``arrays()``), **consumes** them (they are donated: the rows
+    are written in place and the handed arrays are deleted) and returns
+    their successors, which ``set_arrays`` binds. So nobody keeps a
+    pool array across a step: every reader (demotion, promotion,
+    adoption, a co-located engine) reads ``layers`` at use, and engines
+    that share one pool step one after another. ``epoch`` counts
+    :meth:`rebuild` calls, so a sharing engine sees that the contents
+    its rows pointed at are gone.
     """
 
     def __init__(self, num_layers: int, num_heads: int, head_dim: int,
@@ -297,6 +307,7 @@ class BlockPool:
             self.layers = [
                 (jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
                 for _ in range(num_layers)]
+        self.epoch = 0
         self.allocator = BlockAllocator(self.num_blocks)
         trash = self.allocator.alloc()
         assert trash == BlockKVCache.TRASH
@@ -345,6 +356,19 @@ class BlockPool:
         Live requests keep their own refs; only cache refs drop."""
         for key in list(self._prefix):
             self._drop_entry(self._prefix[key])
+
+    def rebuild(self):
+        """Zeroed arrays in place of ones a failed step consumed (same
+        shapes, dtypes and shardings: a deleted array still knows
+        them). Every row's KV is gone with them, so the prefix cache is
+        flushed and ``epoch`` moves; the callers shed what they had
+        running."""
+        import jax.numpy as jnp
+        self.layers = [
+            tuple(jnp.zeros(a.shape, a.dtype, device=a.sharding)
+                  for a in layer) for layer in self.layers]
+        self.flush_prefix_cache()
+        self.epoch += 1
 
 
 class BlockKVCache:
@@ -438,7 +462,7 @@ class BlockKVCache:
 
     # -- pool delegation ---------------------------------------------
     # the physical state lives in self.pool so sharing caches observe
-    # every functional array replacement and every counter bump; these
+    # every array replacement and every counter bump; these
     # properties keep the long-standing cache-level API intact
 
     @property
@@ -695,6 +719,9 @@ class BlockKVCache:
             "blocks": [int(b) for b in self.tables[row, :n]],
             "length": int(self.lengths[row]),
             "pool": self.pool,
+            # a record from before a BlockPool.rebuild() points at
+            # zeroed blocks: the adopter sheds it
+            "epoch": self.pool.epoch,
         }
         self.tables[row] = self.TRASH
         self._nblocks[row] = 0
@@ -819,7 +846,10 @@ class BlockKVCache:
 
     def arrays(self):
         """The per-layer block pools, as fed to the steps: (k, v)
-        tuples, or (k, v, k_scale, v_scale) for int8 pools."""
+        tuples, or (k, v, k_scale, v_scale) for int8 pools. A paged
+        step consumes what it is fed (:class:`BlockPool`): bind its
+        returned pools with :meth:`set_arrays` before anything reads
+        the pool again."""
         return list(self.layers)
 
     def set_arrays(self, layers):

@@ -182,3 +182,80 @@ def test_paged_attention_runs_on_the_heads_shard_of_each_chip(
     assert text.count(KERNEL) == 1
     assert "all-gather" not in text
     assert {x[1] for x in _kernel_shapes(text) if len(x) == 4} == {H // 4}
+
+
+# the serving cells' decode step (PERF.md section 4): 8 slots, pool
+# [400, 16, 16, 128], 64 table slots, at the 1.3B width. Two layers and
+# a small vocabulary suffice: every layer writes and reads its pools
+# alike, and the logits are not what is asserted.
+STEP_SLOTS, STEP_BLOCKS = 8, 400
+
+
+@pytest.fixture(scope="module")
+def decode_step_1p3b_width():
+    """``(model, lower)``: a 2-layer model of ``gpt2-1p3b``'s width with
+    zero weights (drawing 100M parameters on the host is not the test),
+    and the compiled text of its paged decode step for one described
+    chip at a given pool dtype."""
+    import dataclasses
+    from paddle_tpu.dygraph import layers
+    from paddle_tpu.models import GPT_CONFIGS, GPTForCausalLM
+    from paddle_tpu.models.generation import (decode_step_paged,
+                                              param_leaves)
+    from paddle_tpu.serving.decoding import neutral_samp
+    cfg = dataclasses.replace(GPT_CONFIGS["gpt2-1p3b"], num_layers=2,
+                              vocab_size=1024)
+    real = layers.eager_init
+    layers.eager_init = lambda init, shape, dtype, rng: jnp.zeros(
+        tuple(int(d) for d in shape), dtype)
+    try:
+        model = GPTForCausalLM(cfg)
+    finally:
+        layers.eager_init = real
+    model.eval()
+
+    def lower(one_chip, pool_dtype):
+        def s(x):
+            return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+        pool = jax.ShapeDtypeStruct(
+            (STEP_BLOCKS, cfg.num_heads, BS, cfg.head_dim), pool_dtype,
+            sharding=one_chip)
+        args = (jax.tree_util.tree_map(s, param_leaves(model)),
+                s(jnp.zeros(STEP_SLOTS, jnp.int32)),
+                s(jnp.zeros(STEP_SLOTS, jnp.int32)),
+                s(jnp.zeros((STEP_SLOTS, T), jnp.int32)),
+                [(pool, pool)] * cfg.num_layers,
+                jax.tree_util.tree_map(
+                    s, neutral_samp(STEP_SLOTS, cfg.vocab_size)))
+        fn = decode_step_paged(model)["fn"]
+        with jax.enable_x64(False):
+            return fn.raw.lower(*args).compile().as_text()
+
+    return cfg, lower
+
+
+@pytest.mark.parametrize("pool_dtype", ["float32", "bfloat16"])
+def test_decode_step_writes_its_kv_rows_in_place(
+        one_chip, decode_step_1p3b_width, pool_dtype):
+    """The decode step's KV write copies no pool: no ``copy`` /
+    ``copy-start`` gives a pool-shaped result (XLA's layout assignment
+    used to move every pool to ``{3,1,2,0}`` for the scatter and back:
+    96 copies of 52 MB a step at 24 layers), and every pool leaf's
+    output aliases its donated input."""
+    import re
+    cfg, lower = decode_step_1p3b_width
+    text = lower(one_chip, jnp.dtype(pool_dtype))
+    short = {"float32": "f32", "bfloat16": "bf16"}[pool_dtype]
+    shape = f"{short}[{STEP_BLOCKS},{cfg.num_heads},{BS},{cfg.head_dim}]"
+    copies = [ln.strip()[:160] for ln in text.splitlines()
+              if re.search(r"\) copy-start\(| copy\(", ln)
+              and shape in ln.split(" copy", 1)[0]]
+    assert not copies, copies
+    # outputs: next tokens, logits, then (k, v) per layer, qerr, keys
+    header = text[:text.index("\n\n")] if "\n\n" in text else text
+    m = re.search(r"input_output_alias=\{(.*?)\}, entry_computation",
+                  header, re.S) or re.search(
+                      r"input_output_alias=\{(.*)\}", header)
+    assert m, "the compiled step aliases nothing"
+    aliased = {int(i) for i in re.findall(r"\{(\d+)\}: \(", m.group(1))}
+    assert set(range(2, 2 + 2 * cfg.num_layers)) <= aliased, m.group(1)

@@ -28,6 +28,7 @@ import pytest
 import paddle_tpu as pt
 from paddle_tpu.models.generation import greedy_search
 from paddle_tpu.models.gpt import GPTConfig, GPTForCausalLM
+from paddle_tpu.ops import attention_ops
 from paddle_tpu.ops.attention_ops import (block_scatter_write,
                                           block_scatter_write_quant,
                                           paged_attention_reference)
@@ -64,6 +65,57 @@ def _tables_for(pos, s, bs, T):
             tables[i, j] = nxt
             nxt += 1
     return jnp.asarray(tables), nxt
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("b,s", [(8, 1), (4, 5), (3, 2)])
+def test_inplace_row_write_equals_the_scatter(monkeypatch, b, s, dtype):
+    """The two forms of ``block_scatter_write`` (row-wise in-place
+    updates for a few rows, one scatter for many) leave the same pool,
+    bit for bit, over random tables: rows that straddle blocks, rows
+    past the table routed to the trash block, and several requests
+    whose overflow rows collide there (where either form may keep any
+    one of the colliding rows)."""
+    rng = np.random.RandomState(100 * b + s)
+    bs, T, h, d = 4, 6, 2, 8
+    for trial in range(8):
+        nb = b * T + 1
+        perm = rng.permutation(np.arange(1, nb))
+        # each request reserves a random number of blocks; the rest of
+        # its row stays on the trash block, as the allocator leaves it
+        reserved = rng.randint(1, T + 1, size=b)
+        tables = np.zeros((b, T), np.int32)
+        for i in range(b):
+            tables[i, :reserved[i]] = perm[i * T:i * T + reserved[i]]
+        # positions up to the table's end: with s > 1 the last rows of
+        # a request at the end overflow the table; two requests are
+        # pinned there so that their overflow rows collide
+        pos = rng.randint(0, T * bs, size=b)
+        pos[:2] = T * bs - 1
+        pos[-1] = 0           # and one surely writes a block it owns
+        pool = jnp.asarray(rng.randn(nb, h, bs, d), dtype)
+        new = jnp.asarray(rng.randn(b, h, s, d), jnp.float32)
+        args = (pool, new, jnp.asarray(pos, jnp.int32),
+                jnp.asarray(tables))
+        assert b * s <= attention_ops.INPLACE_WRITE_MAX_ROWS
+        rowwise = np.asarray(block_scatter_write(*args), np.float32)
+        monkeypatch.setattr(attention_ops, "INPLACE_WRITE_MAX_ROWS", 0)
+        scatter = np.asarray(block_scatter_write(*args), np.float32)
+        monkeypatch.undo()
+        np.testing.assert_array_equal(rowwise[1:], scatter[1:])
+        assert not np.array_equal(rowwise[1:],
+                                  np.asarray(pool, np.float32)[1:])
+        # the trash block: every row is the old row or one of the rows
+        # routed there, in both forms
+        cand = np.asarray(jnp.asarray(new, dtype), np.float32)
+        for got in (rowwise[0], scatter[0]):
+            for off in range(bs):
+                row = got[:, off]
+                ok = np.array_equal(
+                    row, np.asarray(pool, np.float32)[0, :, off]) or any(
+                    np.array_equal(row, cand[i, :, j])
+                    for i in range(b) for j in range(s))
+                assert ok, (trial, off)
 
 
 @pytest.mark.parametrize("s,pos", [
@@ -375,3 +427,24 @@ def test_engine_int8_reports_quant_error(model):
         outs, eng = _run(model, _prompts((5,), seed=8), mnt=4)
     st = eng.stats()
     assert 0.0 < st["kv_quant_max_abs_err"] < 0.5
+
+
+@pytest.mark.parametrize("s,pos", [(1, [3, 15, 4]), (3, [3, 13, 0]),
+                                   (8, [0, 5, 8])])
+def test_block_attention_equals_the_gathered_reference(s, pos):
+    """The engine's XLA read path contracts over the blocks as gathered
+    ([b, T, h, bs, d]); it is the reference's attention over the
+    [b, h, T*bs, d] view, trash-block padding masked alike."""
+    rng = np.random.RandomState(11)
+    bs, T, h, d = 4, 5, 2, 32
+    tables, nb = _tables_for(pos, s, bs, T)
+    k_pool = jnp.asarray(rng.randn(nb, h, bs, d), jnp.float32)
+    v_pool = jnp.asarray(rng.randn(nb, h, bs, d), jnp.float32)
+    k_pool = k_pool.at[0].set(100.0)
+    v_pool = v_pool.at[0].set(100.0)
+    q = jnp.asarray(rng.randn(len(pos), h, s, d), jnp.float32)
+    posv = jnp.asarray(pos, jnp.int32)
+    out = attention_ops.block_attention(q, k_pool, v_pool, tables, posv)
+    ref = paged_attention_reference(q, k_pool, v_pool, tables, posv)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=1e-5, atol=1e-5)

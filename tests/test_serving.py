@@ -772,3 +772,98 @@ def test_http_broken_pipe_cancels_inflight_request(model):
     h2._json = h._json
     _ServingHandler._json_or_cancel(h2, 200, {}, req.id)
     assert eng.stats()["canceled"] == {"disconnect": 1}
+
+
+# ------------------------------------------------- the pools' ownership
+@pytest.mark.parametrize("mode", [
+    {}, {"spec_tokens": 2}, {"megastep": 4, "dispatch_ahead": True},
+    {"kv_dtype": "int8"}])
+def test_every_paged_dispatch_consumes_its_pools(model, mode):
+    """The paged entries own the pools they are handed: after a step
+    the arrays the cache held before it are deleted (their rows were
+    written in place), the cache holds their successors, and
+    ``STAT_serving_pool_inplace`` counts every dispatch — prefill,
+    decode, verify, megastep and the dispatch-ahead alike."""
+    monitor.reset()
+    eng = ServingEngine(model, max_slots=2, max_len=32, buckets=[8, 16],
+                        max_queue=16, block_size=4, **mode)
+    reqs = [eng.submit(p, max_new_tokens=9)
+            for p in _prompts((3, 6, 11), seed=21)]
+    steps = 0
+    while not eng.idle:
+        # the K and V pools (an int8 pool's scales may be replaced by
+        # the allocator before the step's first dispatch sees them)
+        before = [a for layer in eng.cache.arrays() for a in layer[:2]]
+        assert not any(a.is_deleted() for a in before)
+        eng.step()
+        steps += 1
+        assert all(a.is_deleted() for a in before), steps
+    assert all(r.state == "done" for r in reqs)
+    st = eng.stats()
+    assert st["pool_dispatches"] >= steps
+    assert st["pool_inplace"] == st["pool_dispatches"]
+    assert st["pool_inplace_share"] == 1.0
+    assert monitor.stat_get("STAT_serving_pool_inplace") == \
+        st["pool_dispatches"]
+    if not mode.get("dispatch_ahead"):
+        # one dispatch for each timed call (a speculative megastep is
+        # dispatched inside the decode call that it follows)
+        assert st["pool_dispatches"] == sum(
+            monitor.stat_get(f"STAT_serving_{k}_calls")
+            for k in ("prefill", "decode", "verify"))
+    for p, r in zip(_prompts((3, 6, 11), seed=21), reqs):
+        if mode.get("kv_dtype") == "int8":
+            continue        # int8 KV is not token-identical to greedy
+        ref = greedy_search(model, np.asarray([p]), max_new_tokens=9,
+                            cache_len=eng.max_len)[0].tolist()
+        assert r.output_ids == ref
+
+
+@pytest.mark.parametrize("where,err", [("decode", RuntimeError),
+                                       ("decode", OSError),
+                                       ("prefill", RuntimeError)])
+def test_a_step_that_fails_after_consuming_the_pools_keeps_serving(
+        model, monkeypatch, where, err):
+    """An entry that raises once its pools are gone has taken every
+    row's KV with it. The engine sheds what was running, rebuilds
+    zeroed pools with the prefix cache flushed, and serves the next
+    request token-identically; no later step meets a deleted array and
+    no block leaks."""
+    monitor.reset()
+    eng = ServingEngine(model, max_slots=2, max_len=32, buckets=[8],
+                        max_queue=16, block_size=4)
+    prompts = _prompts((5, 7, 6), seed=22)
+    first = eng.submit(prompts[0], max_new_tokens=8)
+    eng.step()                      # prefill + one decode, both sound
+    assert first.state == "running"
+
+    ent = (decode_step_paged(model) if where == "decode"
+           else eng._prefill_entry_paged(8))
+    real = ent["fn"]
+
+    def consume_then_raise(*args):
+        real(*args)
+        raise err("device fault after the pools were donated")
+
+    monkeypatch.setitem(ent, "fn", consume_then_raise)
+    second = eng.submit(prompts[1], max_new_tokens=8)
+    eng.step()
+    monkeypatch.undo()
+
+    assert first.state == "shed"
+    assert monitor.stat_get("STAT_serving_pool_rebuilds") == 1
+    leaves = [a for layer in eng.cache.arrays() for a in layer]
+    assert not any(a.is_deleted() for a in leaves)
+    assert eng.cache.prefix_entries == 0
+    assert eng.cache.num_used == 0
+
+    third = eng.submit(prompts[2], max_new_tokens=8)
+    eng.run_until_idle()
+    assert third.state == "done"
+    ref = greedy_search(model, np.asarray([prompts[2]]), max_new_tokens=8,
+                        cache_len=eng.max_len)[0].tolist()
+    assert third.output_ids == ref
+    # admitted before the failing decode, the second went with the rest
+    assert second.state == "shed"
+    eng.cache.flush_prefix_cache()
+    assert eng.cache.allocator.leaked() == 1     # trash block only

@@ -428,3 +428,45 @@ def test_handoff_expired_deadline_shed_not_adopted(model):
     assert req.state == "shed" and req.shed_reason == "deadline"
     assert rt.stats()["shed"].get("deadline") == 1
     assert all(lk == 1 for lk in _leaked_per_pool(rt))
+
+
+def test_colocated_roles_survive_a_step_that_consumed_their_shared_pool(
+        model, monkeypatch):
+    """Co-located prefill and decode workers hold one BlockPool. When a
+    prefill fails after its pools were consumed, the pool is rebuilt
+    zeroed: the decode worker sheds the rows whose KV went with it (it
+    sees the pool's epoch move), staged and queued handoff records from
+    before the rebuild are shed rather than adopted, and the fleet
+    serves what comes next token-identically, with no leaked block."""
+    monitor.reset()
+    rt = _fleet(model, p=1, d=1, colocate=True)
+    pre, dec = rt.prefills[0], rt.decodes[0]
+    assert pre.cache.pool is dec.cache.pool
+    prompts = _prompts((5, 7, 6, 4), seed=31)
+    early = [rt.submit(p, max_new_tokens=12) for p in prompts[:2]]
+    for _ in range(3):
+        rt.step()
+    assert any(r.state == "running" for r in early)
+
+    ent = pre._prefill_entry_paged(8)
+    real = ent["fn"]
+
+    def consume_then_raise(*args):
+        real(*args)
+        raise RuntimeError("device fault after the pools were donated")
+
+    monkeypatch.setitem(ent, "fn", consume_then_raise)
+    victim = rt.submit(prompts[2], max_new_tokens=4)
+    for _ in range(3):
+        rt.step()
+    monkeypatch.undo()
+    assert victim.state == "shed"
+    assert all(r.state == "shed" for r in early), \
+        [r.state for r in early]
+    assert monitor.stat_get("STAT_serving_pool_rebuilds") == 1
+
+    late = rt.submit(prompts[3], max_new_tokens=6)
+    rt.run_until_idle()
+    assert late.state == "done"
+    assert late.output_ids == _ref(model, prompts[3], 6)
+    assert all(lk == 1 for lk in _leaked_per_pool(rt))

@@ -346,6 +346,39 @@ def test_dispatch_ahead_hits_and_identity(model):
     _assert_no_leaks(eng)
 
 
+@pytest.mark.parametrize("kv_dtype", ["f32", "int8"])
+@pytest.mark.parametrize("every", [1, 2])
+def test_forced_dispatch_ahead_misses_stay_token_identical(model, every,
+                                                           kv_dtype):
+    """A discarded speculation has already consumed step k's pools, so
+    the cache holds the pools *it* returned: its rows sit at or beyond
+    every slot's committed length and are written again before anything
+    reads them. With the speculation spoiled after every step (or every
+    other one), on top of the misses that finishes and admissions cause
+    by themselves, the tokens are those of the single-step engine."""
+    prompts = _prompts((4, 6, 9, 5, 7), seed=16)
+    eng = _engine(model, megastep=4, dispatch_ahead=True,
+                  kv_dtype=kv_dtype)
+    reqs = [eng.submit(p, max_new_tokens=22) for p in prompts]
+    steps = 0
+    while not eng.idle:
+        eng.step()
+        steps += 1
+        assert not eng.cache.arrays()[0][0].is_deleted()
+        if eng._ahead is not None and steps % every == 0:
+            eng._ahead["n"] = -1          # _take_ahead will refuse it
+    assert all(r.state == "done" for r in reqs)
+    st = eng.stats()
+    assert st["ahead_misses"] >= 3, st
+    if every == 2:
+        assert st["ahead_hits"] >= 1, st
+    assert st["pool_inplace_share"] == 1.0, st
+    ref = _run(_engine(model, megastep=1, kv_dtype=kv_dtype), prompts,
+               mnt=22)
+    assert [r.output_ids for r in reqs] == [r.output_ids for r in ref]
+    _assert_no_leaks(eng)
+
+
 def test_threaded_replica_router_megastep_identity(model):
     """2 replicas stepped from a bounded worker pool, each running
     megastep=4 decodes == the greedy oracle per request; no kills, no

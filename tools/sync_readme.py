@@ -365,6 +365,24 @@ def render_serving_block():
         "plus token-granular `prefix_hit_rate` from",
         "`STAT_serving_prefix_hits` / `_misses`.",
         "",
+        "The KV pools have one owner at a time. Every paged entry",
+        "(prefill, decode, megastep, verify) is handed the pools",
+        "donated: it writes its KV rows in place — a decode or verify",
+        "step as one `dynamic_update_slice` per row, in the layout the",
+        "pool arrived in, a prefill bucket as one scatter — the arrays",
+        "handed in are deleted by the call, and the engine binds the",
+        "returned pools (`cache.set_arrays`) before anything reads the",
+        "cache again; nobody keeps a pool array across a step.",
+        "`STAT_serving_pool_inplace` counts the dispatches whose input",
+        "pools were in fact deleted, and `engine.stats()` gives",
+        "`pool_dispatches` / `pool_inplace` / `pool_inplace_share`",
+        "(1.0 unless the backend ignores donation, in which case the",
+        "pools are copied as before). A step that raises after its",
+        "pools were consumed sheds what was running, rebuilds zeroed",
+        "pools with the prefix cache flushed",
+        "(`STAT_serving_pool_rebuilds`) and keeps serving; engines that",
+        "share the pool shed their rows when they see its epoch move.",
+        "",
         "The paged decode/verify hot path has two lowerings, selected",
         "by `FLAGS_serving_attn_impl`: `xla` composes gather ->",
         "masked-softmax attention from the block pool, while `pallas`",
