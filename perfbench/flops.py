@@ -1,5 +1,6 @@
 """Operation counts from shapes, and the table of peaks: the arithmetic
-behind ``mfu_pct`` lives here, where a PR that claims a gain cannot change it.
+behind ``mfu_pct`` and every ``<kernel>_roofline_pct`` lives here and in the
+families, where a PR that claims a gain cannot change it.
 
 ``train_flops_per_token`` is ``bench.model_flops_per_token`` with one term
 corrected: causal attention does half of the ``s x s`` score and value
@@ -38,3 +39,23 @@ def peaks(device_kind: str) -> dict:
             f"its source, to perfbench/peaks.json "
             f"(known: {sorted(k for k in table if not k.startswith('_'))})")
     return table[device_kind]
+
+
+def kernel_floors(trace: dict, counts, device_kind: str) -> dict:
+    """``kernel_floor_s.<name>`` for every named kernel of a reduced trace
+    (:func:`xplane.reduce`'s ``kernel_calls.<name>``) that ``counts(name)``
+    knows: the least seconds the chip could have taken for those calls,
+    calls x max(flops / peak FLOP/s, bytes / peak bytes/s). Over
+    ``kernel_s.<name>`` it is the kernel's share of its roofline."""
+    peak = peaks(device_kind)
+    out = {}
+    for key, calls in trace.items():
+        if not key.startswith("kernel_calls."):
+            continue
+        name = key[len("kernel_calls."):]
+        known = counts(name)
+        if known is not None:
+            out["kernel_floor_s." + name] = calls * max(
+                known[0] / peak["bf16_flops"],
+                known[1] / peak["hbm_bytes_per_s"])
+    return out

@@ -1,22 +1,31 @@
 """Per-layer metrics as data: ``metrics/<name>.json`` says where a number
 comes from and how it is reduced, and :func:`read` does it.
 
-A run gathers *observations*, a dict of four groups:
+A run gathers *observations*, a dict of five groups:
 
 - ``spans``: name -> list of durations in seconds (the program's
   ``serving.prefill`` / ``serving.decode`` ``RecordEvent`` spans and the
   harness's own ``bench.*`` spans, over the measured window);
+- ``span_args``: ``<span>.<arg>`` -> the values that argument took over the
+  window, for every recorded span that carries ``args``
+  (``serving.prefill_step.rows``, ``serving.engine_step.active``);
 - ``samples``: name -> list of numbers the harness took (one per step, per
   request or per release);
 - ``counters``: name -> one number (counts and readings: compiles, peak
-  bytes, tokens);
-- ``trace``: name -> one number from the device trace (:mod:`xplane`).
+  bytes, tokens; and the program's own, ``engine.<key>`` of
+  ``engine.stats()`` and ``STAT_serving_*``: the window's difference under
+  the name, the closing value under ``<name>.close``);
+- ``trace``: name -> one number from the device trace (:mod:`xplane`;
+  ``kernel_s.<kernel>``, ``kernel_calls.<kernel>`` and, where the family has
+  counts for the kernel, ``kernel_floor_s.<kernel>`` among them).
 
 A metric file's ``reader`` names a group (``from``), a ``name`` in it, a
 reduction, and optionally a ``scale`` to multiply by and an ``over`` — a
-second name whose value divides the first (``"over": "window_s"`` or
-``"trace.busy_s"``). A reader that finds nothing to read returns ``None``
-and the harness leaves the metric out of the line.
+second name whose value divides the first (``"over": "window_s"``, a
+counter, or ``"<group>.<name>"``: ``"trace.busy_s"``; the name may itself
+hold dots, ``"counters.engine.pool_dispatches"``). A reader that finds
+nothing to read returns ``None`` and the harness leaves the metric out of
+the line.
 
 The reductions are a fixed set: ``sum mean max min count value p<q>``.
 A new metric over an existing span or counter is a new file, no code.
@@ -31,7 +40,7 @@ import statistics
 from typing import Optional
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
-GROUPS = ("spans", "samples", "counters", "trace")
+GROUPS = ("spans", "span_args", "samples", "counters", "trace")
 
 
 def spec(name: str) -> dict:
@@ -84,8 +93,10 @@ def read(name: str, obs: dict) -> Optional[float]:
     if value is None:
         return None
     if "over" in r:
-        group, _, key = r["over"].rpartition(".")
-        denom = _lookup(obs, group or "counters", key)
+        group, _, key = r["over"].partition(".")
+        if group not in GROUPS:
+            group, key = "counters", r["over"]
+        denom = _lookup(obs, group, key)
         if not denom:
             return None
         value /= float(denom)

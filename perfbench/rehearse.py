@@ -1,12 +1,13 @@
 #!/usr/bin/env python3
-"""Rehearse the harness on the CPU at ``gpt2-tiny`` size: the control flow,
-the lookup of files by name, the shape of the last line. Not a measurement.
+"""Rehearse the harness on the CPU at toy size: the control flow, the
+lookup of files by name, the shape of the last line. Not a measurement.
 
     JAX_PLATFORMS=cpu python3 perfbench/rehearse.py --workload chat_steady
 
 Each cell of BENCHMARK.json runs as its twin: the same cell name (so the
-same lists of metrics), the configuration ``rehearsal/gpt2-tiny.json`` and
-the traffic file ``rehearsal/<traffic>.json``. A four-chip cell gets four
+same lists of metrics), the toy configuration of the cell's family
+(``rehearsal/<family>-tiny.json``) and the traffic file
+``rehearsal/<traffic>.json``. A four-chip cell gets four
 virtual CPU devices. The script prints the device it ran on, and on any
 device that is not a TPU every value of the last line is ``null`` — a
 number from a CPU run is never written under the name of a device metric.
@@ -36,16 +37,18 @@ def main(argv=None) -> int:
     ap.add_argument("--trace", type=int, default=0, choices=(0, 1))
     args = ap.parse_args(argv)
     sys.path.insert(0, ROOT)
-    from perfbench import run as harness
+    from perfbench import families, run as harness
     bench = harness.load_json(ROOT, "BENCHMARK.json")
     cell = harness.find_cell(bench, args.workload)
     if int(cell["chips"]) > 1 and os.environ.get("JAX_PLATFORMS") == "cpu":
         os.environ["XLA_FLAGS"] = (
             os.environ.get("XLA_FLAGS", "")
             + f" --xla_force_host_platform_device_count={cell['chips']}")
-    # the twin: same cell name, toy configuration, toy traffic
+    # the twin: same cell name, the family's toy configuration, toy traffic
+    entry = next(c for c in bench["configs"] if c["name"] == cell["config"])
+    family = families.name_of(harness.load_json(ROOT, entry["file"]))
     bench["configs"] = [{"name": cell["config"],
-                         "file": "perfbench/rehearsal/gpt2-tiny.json"}]
+                         "file": f"perfbench/rehearsal/{family}-tiny.json"}]
     line = harness.run_cell(bench, args, rehearsal=True,
                             traffic_dir="rehearsal")
     dev = line["device"]

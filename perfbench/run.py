@@ -5,9 +5,11 @@
 
 The cell's configuration, its traffic mix and every per-layer metric are
 files found by the names in ``BENCHMARK.json`` (``configs/<config>.json``,
-``traffic/<traffic>.json``, ``metrics/<metric>.json``): a later PR adds a
-cell, a configuration or a metric over an existing span by adding files and
-an entry, and edits nothing here.
+``traffic/<traffic>.json``, ``metrics/<metric>.json``), and the
+configuration names its architecture's family (``families/<family>``): a
+later PR adds a cell, a configuration, an architecture or a metric over an
+existing span, counter or kernel by adding files and an entry, and edits
+nothing here.
 
 The LAST line of stdout is one JSON object (``correct``, ``attempted``,
 ``failed``, ``metrics``, ``device``, and with ``--trace 1`` ``breakdown``);
@@ -83,7 +85,7 @@ def run_cell(bench: dict, args, rehearsal: bool = False,
     cfg = load_json(ROOT, cfg_entry["file"])
     traffic = load_json(HERE, traffic_dir, cell["traffic"] + ".json")
     os.makedirs(OUT, exist_ok=True)
-    from perfbench import readers, serve, train
+    from perfbench import families, flops, readers, serve, train
     runner = {"serve": serve, "train": train}[RUNNERS[traffic["kind"]]]
     e2e, obs, counts = runner.run(cell, cfg, traffic, args.seed,
                                   float(args.seconds), bool(args.trace),
@@ -93,6 +95,11 @@ def run_cell(bench: dict, args, rehearsal: bool = False,
         obs["counters"]["peak_hbm_bytes"] = device["memory_peak_bytes"]
     metrics = {}
     tr = obs.get("trace") or {}
+    if tr:
+        family = families.load(cfg)
+        tr.update(flops.kernel_floors(
+            tr, lambda name: family.kernel_counts(name, cfg, traffic),
+            device["kind"]))
     if args.trace:
         for m in metrics_of(bench, "per_layer", cell["name"]):
             value = readers.read(m["name"], obs)

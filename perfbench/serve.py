@@ -102,33 +102,12 @@ class Follower:
 
 # ------------------------------------------------------------------- build
 
-def model_config(cfg: dict):
-    """The program's GPTConfig for a configuration file, checked against
-    the file's own numbers (the file is the truth, the preset the means)."""
-    import dataclasses
-    from paddle_tpu.models import GPT_CONFIGS
-    prog = cfg["program"]
-    gc_ = GPT_CONFIGS[prog["preset"]]
-    if "num_layers" in prog:
-        gc_ = dataclasses.replace(gc_, num_layers=int(prog["num_layers"]))
-    want = {"hidden_size": cfg["n_embd"], "num_layers": cfg["n_layer"],
-            "num_heads": cfg["n_head"], "ffn_hidden_size": cfg["n_inner"],
-            "max_position_embeddings": cfg["n_positions"],
-            "vocab_size": prog["vocab_rows"]}
-    got = {k: getattr(gc_, k) for k in want}
-    if got != want:
-        raise SystemExit(f"configuration {cfg['name']}: the program's preset "
-                         f"{prog['preset']} has {got}, the file says {want}")
-    return gc_
-
-
 def build_engine(cfg: dict, seed: int):
-    from paddle_tpu.models import GPTForCausalLM
     from paddle_tpu.serving import ServingEngine
-    from . import weights
-    gcfg = model_config(cfg)
+    from . import families, weights
+    family = families.load(cfg)
     with weights.recording() as specs:
-        model = GPTForCausalLM(gcfg)
+        model = family.serving_model(cfg)
     weights.fill(model, specs, seed)
     model.eval()
     e = cfg["engine"]
@@ -162,18 +141,25 @@ def warm(engine, traffic: dict, token_limit: int):
 # ------------------------------------------------------------------ tracing
 
 class Tracing:
-    """The traced run's extras: the program's host spans over the whole
-    window, the device trace over its last ``last_s`` seconds."""
+    """What is read at the window's edges: the program's counters at its
+    open and close (``counters``, a callable, in every run), and the traced
+    run's extras: the program's host spans over the whole window, the
+    device trace over its last ``last_s`` seconds."""
 
     def __init__(self, on: bool, out_dir: str, seconds: float,
-                 last_s: float = TRACE_S):
+                 last_s: float = TRACE_S, counters=None):
         self.on, self.dir = on, os.path.join(out_dir, "trace")
         self.start_at = max(0.0, seconds - last_s)
         self.device_on = False
         self._ann = None
         self.spans_path = os.path.join(out_dir, "host_spans.json")
+        self._counters, self._at_open = counters, {}
+        self.window_counters: Dict[str, float] = {}
+        self.span_args: Dict[str, List[float]] = {}
 
     def window_open(self):
+        if self._counters is not None:
+            self._at_open = self._counters()
         if self.on:
             from paddle_tpu import profiler
             profiler.start_profiler()
@@ -198,7 +184,15 @@ class Tracing:
             jax.profiler.stop_trace()
 
     def window_close(self) -> Dict[str, List[float]]:
-        """Stops both; returns the program's spans as name -> seconds."""
+        """Stops both; returns the program's spans as name -> seconds
+        (their numeric ``args`` go to ``span_args`` as ``<span>.<arg>`` ->
+        values). A counter of the program's is kept twice: under its name
+        the window's difference (what a cumulative count wants), under
+        ``<name>.close`` its closing value (what a gauge wants)."""
+        if self._counters is not None:
+            for name, value in self._counters().items():
+                self.window_counters[name] = value - self._at_open.get(name, 0)
+                self.window_counters[name + ".close"] = value
         if not self.on:
             return {}
         from paddle_tpu import profiler
@@ -206,10 +200,7 @@ class Tracing:
         with contextlib.redirect_stdout(io.StringIO()):
             profiler.stop_profiler(profile_path=self.spans_path)
         with open(self.spans_path) as f:
-            events = json.load(f)["traceEvents"]
-        spans: Dict[str, List[float]] = {}
-        for ev in events:
-            spans.setdefault(ev["name"], []).append(ev["dur"] / 1e6)
+            spans, self.span_args = spans_of(json.load(f)["traceEvents"])
         return spans
 
     def reduce(self) -> Optional[dict]:
@@ -227,6 +218,34 @@ class Tracing:
         red = xplane.reduce(xplane.load(paths[0]))
         red["idle_s"] = red["window_s"] - red["busy_s"]
         return red
+
+
+def _numeric(value) -> bool:
+    return isinstance(value, (int, float)) and not isinstance(value, bool)
+
+
+def spans_of(events: List[dict]):
+    """The profiler's events -> (name -> durations in seconds,
+    ``<span>.<arg>`` -> the values of each numeric argument)."""
+    spans: Dict[str, List[float]] = {}
+    span_args: Dict[str, List[float]] = {}
+    for ev in events:
+        spans.setdefault(ev["name"], []).append(ev["dur"] / 1e6)
+        for arg, value in (ev.get("args") or {}).items():
+            if _numeric(value):
+                span_args.setdefault(f"{ev['name']}.{arg}", []).append(value)
+    return spans, span_args
+
+
+def program_counters(engine) -> Dict[str, float]:
+    """Every numeric entry of ``engine.stats()`` (as ``engine.<key>``) and
+    of the monitor's ``STAT_serving_*`` counters (under their own names),
+    as they stand now."""
+    from paddle_tpu import monitor
+    out = {f"engine.{k}": v for k, v in engine.stats().items()
+           if _numeric(v)}
+    out.update(monitor.stats_with_prefix("STAT_serving"))
+    return out
 
 
 def compile_count() -> int:
@@ -417,7 +436,8 @@ def check(model, engine, cfg, fol: Follower, seed: int, lo: float,
     request the schedule fixed in length has that length; no block leaks."""
     import jax
     import jax.numpy as jnp
-    from . import reference
+    from . import families
+    family = families.load(cfg)
     done = [s for s in fol.done if s.req.state == "done"
             and s.finished is not None and lo <= s.finished <= hi]
     notes = {}
@@ -429,12 +449,10 @@ def check(model, engine, cfg, fol: Follower, seed: int, lo: float,
         notes["wrong_length_requests"] = short[:5]
     pad = int(cfg["engine"]["max_len"])
     params = {n: p.value for n, p in model.named_parameters()}
-    kw = dict(num_layers=cfg["n_layer"], num_heads=cfg["n_head"],
-              vocab_size=cfg["program"]["vocab_rows"])
 
     @jax.jit
     def deficits(params, ids, nxt):
-        logits = reference.forward(params, ids, **kw)[0]
+        logits = family.forward(params, ids, cfg)[0]
         got = jnp.take_along_axis(logits, nxt[:, None], axis=-1)[:, 0]
         return jnp.max(logits, axis=-1) - got
 
@@ -475,7 +493,8 @@ def run(cell: dict, cfg: dict, traffic: dict, seed: int, seconds: float,
     model, engine = build_engine(cfg, seed)
     token_limit = int(cfg["vocab_size"])
     warm(engine, traffic, token_limit)
-    tracing = Tracing(trace, out_dir, seconds)
+    tracing = Tracing(trace, out_dir, seconds,
+                      counters=lambda: program_counters(engine))
     gc.collect()
     gc.freeze()
 
@@ -497,9 +516,11 @@ def run(cell: dict, cfg: dict, traffic: dict, seed: int, seconds: float,
     if compiles:
         counts["correct"] = False
         counts["compiled_in_window"] = compiles
-    counters = {"compiles_in_window": compiles, "window_s": hi - lo}
+    counters = {**tracing.window_counters,
+                "compiles_in_window": compiles, "window_s": hi - lo}
     if "itl_worst5pct_mean_ms" in counts:
         counters["itl_worst5pct_mean_ms"] = counts["itl_worst5pct_mean_ms"]
-    obs = {"spans": spans, "samples": samples, "counters": counters,
+    obs = {"spans": spans, "span_args": tracing.span_args,
+           "samples": samples, "counters": counters,
            "trace": tracing.reduce() or {}}
     return e2e, obs, counts

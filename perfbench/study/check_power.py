@@ -59,10 +59,10 @@ def main(argv=None) -> int:
     import jax.numpy as jnp
     import numpy as np
     from paddle_tpu.models import GPTForCausalLM
-    from perfbench import reference, run as harness, serve, train, weights
+    from perfbench import families, reference, run as harness, serve, train, weights
     cfg = harness.load_json(ROOT, "perfbench", "configs",
                             args.config + ".json")
-    gcfg = serve.model_config(cfg)
+    gcfg = families.load(cfg).model_config(cfg)
     kw = dict(num_heads=cfg["n_head"],
               vocab_size=cfg["program"]["vocab_rows"])
     layers, vocab = int(cfg["n_layer"]), int(cfg["vocab_size"])

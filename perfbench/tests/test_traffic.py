@@ -74,7 +74,8 @@ def test_open_loop_due_times_inside_their_pacing_intervals(seed):
     assert arrivals[-1].due_s < 45
 
 
-@pytest.mark.parametrize("name", ["chat_steady", "docs_offline"])
+@pytest.mark.parametrize("name", ["chat_steady", "docs_offline",
+                                  "decode_heavy"])
 def test_multiset_is_the_files_own(name):
     tr = load(name)
     ms = T.multiset(tr)
@@ -107,9 +108,10 @@ def test_chat_steady_median_lengths_and_buckets():
     assert T.buckets_used(load("docs_offline"), BUCKETS) == [768, 1024]
 
 
+@pytest.mark.parametrize("name", ["docs_offline", "decode_heavy"])
 @pytest.mark.parametrize("seed", SEEDS)
-def test_closed_loop_each_repetition_is_the_whole_multiset(seed):
-    tr = load("docs_offline")
+def test_closed_loop_each_repetition_is_the_whole_multiset(seed, name):
+    tr = load(name)
     ms = sorted(T.multiset(tr))
     stream = T.closed_loop_stream(tr, seed, VOCAB)
     first = [next(stream) for _ in range(len(ms))]
@@ -119,3 +121,47 @@ def test_closed_loop_each_repetition_is_the_whole_multiset(seed):
     again = T.closed_loop_stream(tr, seed, VOCAB)
     assert T.schedule_bytes(first) == T.schedule_bytes(
         [next(again) for _ in range(len(ms))])
+
+
+# ------------------------------------------------------------- decode_heavy
+
+def test_decode_heavy_any_eight_pairs_fit_the_pool():
+    """``BlockKVCache.acquire`` reserves blocks for prompt + the whole
+    answer at admission; 399 of cgpt-1p3b's 400 blocks of 16 are usable. If
+    the eight largest pairs fit, any eight do, and no slot ever stands empty
+    waiting for blocks (the cell would measure the allocator)."""
+    cfg_path = os.path.join(os.path.dirname(HERE), "configs", "cgpt-1p3b.json")
+    engine = T.load(cfg_path)["engine"]
+    assert (engine["max_slots"], engine["num_blocks"],
+            engine["block_size"]) == (8, 400, 16)
+    ms = T.multiset(load("decode_heavy"))
+    blocks = sorted(-(-(p + a) // engine["block_size"]) for p, a in ms)
+    assert sum(blocks[-engine["max_slots"]:]) == 393 <= engine["num_blocks"] - 1
+    assert all(p + a <= engine["max_len"] for p, a in ms)
+
+
+def test_decode_heavy_lengths_and_its_one_bucket():
+    tr = load("decode_heavy")
+    ms = T.multiset(tr)
+    assert len(ms) == 16 and tr["queue_depth_slots"] == 1
+    assert all(16 <= p <= 64 and 512 <= a <= 840 for p, a in ms)
+    assert T.buckets_used(tr, BUCKETS) == [64]        # prefill attention bypassed
+    assert "ASSUMED" in tr["lengths_why"]
+    # decode is the work: 19 output tokens for every prompt token
+    assert sum(a for _, a in ms) > 15 * sum(p for p, _ in ms)
+
+
+@pytest.mark.parametrize("n", [8, 16, 27, 40])
+def test_decode_heavy_tokens_per_bucket_do_not_depend_on_the_seed(n):
+    tr = load("decode_heavy")
+    firsts = []
+    for seed in SEEDS:
+        stream = T.closed_loop_stream(tr, seed, VOCAB)
+        firsts.append([next(stream) for _ in range(n)])
+    whole = n - n % 16       # whole repetitions offer the same work
+    assert len({tuple(sorted(per_bucket(f[:whole]).items()))
+                for f in firsts}) == 1
+    assert len({tuple(lengths(f[:whole])) for f in firsts}) == 1
+    if n >= 16:
+        assert [len(a.prompt) for a in firsts[0]] != \
+            [len(a.prompt) for a in firsts[1]]

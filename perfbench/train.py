@@ -1,7 +1,8 @@
-"""One training cell, once: the program's own train job (``bench.build_train``:
-``jit.to_static`` or ``zero_train_step``, AdamW, AMP O2), a new seeded
-batch every step made on the device before the step that uses it, steps
-ended by a fetch of the loss.
+"""One training cell, once: the family's train job for the configuration
+(model, optimizer, train function) compiled as the job file says
+(``jit.to_static`` or ``zero_train_step``), a new seeded batch every step
+made on the device before the step that uses it, steps ended by a fetch of
+the loss.
 
 The window opens after ``warm_steps`` (two of them compile: the optimizer's
 state appears after step 1) and closes at the end of the first step that
@@ -11,7 +12,6 @@ the window's tokens over all the window's time.
 
 from __future__ import annotations
 
-import os
 import time
 
 import numpy as np
@@ -33,16 +33,8 @@ def build(cfg: dict, traffic: dict, seed: int):
     the train function as the job says (``to_static`` / ``zero``)."""
     import paddle_tpu as pt
     from paddle_tpu import jit
-    import bench
-    from . import serve, weights
-    gcfg = serve.model_config(cfg)
-    tr = cfg["trainer"]
-    # bench.build_train reads its levers from the environment
-    os.environ["BENCH_RECOMPUTE"] = "1" if tr["recompute"] else "0"
-    os.environ["BENCH_NO_RETAIN_GRADS"] = "0" if tr["retain_grads"] else "1"
-    os.environ["BENCH_BF16_MOMENTS"] = \
-        "1" if tr["moment_dtype"] == "bfloat16" else "0"
-    os.environ.pop("BENCH_GPT_LAYERS", None)
+    from . import families, weights
+    family = families.load(cfg)
     seq = int(traffic["seq"])
     mesh = replicated = None
     if traffic["step"] == "zero":
@@ -52,11 +44,7 @@ def build(cfg: dict, traffic: dict, seed: int):
         mesh = build_mesh((axis,), (int(n),))
         replicated = NamedSharding(mesh, P())
     with weights.recording(replicated) as specs:
-        _, model, opt, fn, retain = bench.build_train(
-            cfg["program"]["preset"], seq)
-    if model.cfg.num_layers != gcfg.num_layers:
-        raise SystemExit("bench.build_train built another depth than the "
-                         "configuration file states")
+        model, opt, fn, retain = family.train_job(cfg, traffic)
     weights.fill(model, specs, seed, sharding=replicated)
     # the flash kernel takes over from seq 1024 up (FLAGS_pallas_min_seq);
     # a rehearsal at a shorter seq still has to drive it
@@ -110,7 +98,8 @@ def fetch(loss) -> float:
 def run(cell: dict, cfg: dict, traffic: dict, seed: int, seconds: float,
         trace: bool, out_dir: str, t_start: float):
     import jax
-    from . import flops, reference, serve
+    from . import families, flops, serve
+    family = families.load(cfg)
     chips = int(cell["chips"])
     if len(jax.devices()) < chips:
         raise SystemExit(f"{cell['name']} needs {chips} chips")
@@ -156,9 +145,7 @@ def run(cell: dict, cfg: dict, traffic: dict, seed: int, seconds: float,
     tokens = len(step_s) * batch * seq
     rate = tokens / window_s / chips
     kind = jax.devices()[0].device_kind
-    per_token = flops.train_flops_per_token(
-        hidden=cfg["n_embd"], ffn=cfg["n_inner"], layers=cfg["n_layer"],
-        vocab_rows=cfg["program"]["vocab_rows"], seq=seq)
+    per_token = family.train_flops_per_token(cfg, seq)
 
     # correctness, outside the window: one more step on a batch whose rows
     # are all the same row, so the program's mean loss is that row's loss,
@@ -169,9 +156,7 @@ def run(cell: dict, cfg: dict, traffic: dict, seed: int, seconds: float,
     for name, p in model.named_parameters():
         v = p.value
         params[name] = v.addressable_shards[0].data if mesh is not None else v
-    ref = jax.jit(lambda p, i, l: reference.loss(
-        p, i, l, num_layers=cfg["n_layer"], num_heads=cfg["n_head"],
-        vocab_size=cfg["program"]["vocab_rows"]))
+    ref = jax.jit(lambda p, i, l: family.loss(p, i, l, cfg))
     want = float(np.asarray(ref(params, *row)))
     del params
     got = fetch(step(ids, labels))

@@ -1,6 +1,7 @@
 """From the profiler's trace to numbers: device busy and idle, time per
-operation, idle gaps named by what the host was doing, Mosaic kernel time,
-collective time that no compute hides.
+operation, idle gaps named by what the host was doing, Mosaic kernel time
+(all of it, and each named kernel's seconds and calls), collective time that
+no compute hides.
 
 Two steps, so that the arithmetic can be checked without a chip:
 
@@ -147,6 +148,15 @@ def short_name(instr: str) -> str:
     return label
 
 
+def kernel_stem(instr: str) -> str:
+    """The name the program gave a kernel (``pallas_call(name=...)``), read
+    off the instruction XLA made of it: ``%flash_fwd.40 = (bf16[128,1024,
+    128]{..}, ..) custom-call(..)`` -> ``flash_fwd``. XLA numbers its
+    instructions after a dot, so a kernel's name has none."""
+    m = _INSTR.match(instr)
+    return m.group("name").split(".")[0] if m else ""
+
+
 def _module_of(modules: List[Tuple[float, float, str]], starts: List[float],
                t: float) -> str:
     """The program execution (``XLA Modules`` event) that holds time ``t``;
@@ -203,9 +213,11 @@ def _blame_all(spans, times: Sequence[float]) -> List[str]:
 def reduce(trace: dict, top: int = 10) -> dict:
     """Everything the per-layer metrics and ``breakdown`` read from a trace.
 
-    Seconds are averaged over the device planes (the chips used). Raises if
-    the trace has no window span or no device plane: a traced run in which
-    no operation ran on the device is not a result."""
+    Seconds are averaged over the device planes (the chips used). Every
+    Mosaic kernel in the window, not the ``top`` largest, is there by the
+    name the program gave it: ``kernel_s.<name>``, ``kernel_calls.<name>``.
+    Raises if the trace has no window span or no device plane: a traced run
+    in which no operation ran on the device is not a result."""
     window, spans = _host_spans(trace)
     if window is None:
         raise ValueError(f"no {WINDOW_SPAN!r} span in the trace")
@@ -217,6 +229,7 @@ def reduce(trace: dict, top: int = 10) -> dict:
         raise ValueError("no /device:TPU:<n> plane with an 'XLA Ops' line")
     busy = mosaic = coll_total = coll_exposed = 0.0
     per_op: Dict[str, float] = {}
+    kernels: Dict[str, List[float]] = {}      # stem -> [ns, calls]
     gaps: Dict[str, float] = {}
     for plane in devices:
         lines = _lines(plane)
@@ -239,6 +252,10 @@ def reduce(trace: dict, top: int = 10) -> dict:
             dur = min(b, hi) - max(a, lo)
             if is_mosaic(n):
                 mosaic += dur
+                # a call the window cuts counts as the part of it inside
+                k = kernels.setdefault(kernel_stem(n), [0.0, 0.0])
+                k[0] += dur
+                k[1] += dur / (b - a) if b > a else 1.0
             key = f"{_module_of(modules, starts, a)}:{short_name(n)}"
             per_op[key] = per_op.get(key, 0.0) + dur
         idle = subtract([(lo, hi)], ops_u)
@@ -256,7 +273,12 @@ def reduce(trace: dict, top: int = 10) -> dict:
         return [[k, v / n / 1e9] for k, v in
                 sorted(d.items(), key=lambda kv: -kv[1])[:top]]
 
-    return {"devices": n,
+    named = {}
+    for stem, (ns, calls) in kernels.items():
+        named["kernel_s." + stem] = ns / n / 1e9
+        named["kernel_calls." + stem] = calls / n
+    return {**named,
+            "devices": n,
             "window_s": (hi - lo) / 1e9,
             "busy_s": busy / n / 1e9,
             "mosaic_s": mosaic / n / 1e9,
