@@ -20,6 +20,9 @@ BLACK_LIST = {
     "layer_norm", "batch_norm", "group_norm", "instance_norm",
     "reduce_sum", "reduce_mean", "reduce_prod",
     "squared_l2_norm", "p_norm", "norm", "logsumexp",
+    # rotary tables and router scores stay float32 (a bf16 angle or score
+    # moves positions and expert choices); rms_norm upcasts inside
+    "rotary_embedding", "moe_router",
 }
 
 # Everything else runs in whatever dtype its inputs already have.

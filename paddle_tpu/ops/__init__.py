@@ -11,6 +11,7 @@ from . import reduce_ops  # noqa: F401
 from . import random_ops  # noqa: F401
 from . import optimizer_ops  # noqa: F401
 from . import attention_ops  # noqa: F401
+from . import decoder_ops  # noqa: F401
 from . import fused_ops  # noqa: F401
 from . import detection_ops  # noqa: F401
 from . import proposal_ops  # noqa: F401

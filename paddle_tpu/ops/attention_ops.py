@@ -294,11 +294,19 @@ def paged_attention_reference(q, k_pool, v_pool, tables, pos, *,
                                scale=float(scale))
 
 
-def _composed_attention(q, k, v, mask, causal, scale):
+def _composed_attention(q, k, v, mask, causal, scale, window=0):
+    if k.shape[1] != q.shape[1]:
+        # grouped KV heads: query head j reads KV head j // group
+        group = q.shape[1] // k.shape[1]
+        k, v = (jnp.repeat(a, group, axis=1) for a in (k, v))
     logits = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
     if causal:
         s_q, s_k = logits.shape[-2], logits.shape[-1]
         causal_mask = jnp.tril(jnp.ones((s_q, s_k), bool), s_k - s_q)
+        if window:
+            # query i sees keys i-window+1 .. i
+            causal_mask &= ~jnp.tril(jnp.ones((s_q, s_k), bool),
+                                     s_k - s_q - window)
         logits = jnp.where(causal_mask, logits, jnp.finfo(logits.dtype).min)
     if mask is not None:
         logits = logits + mask.astype(logits.dtype)
@@ -309,10 +317,17 @@ def _composed_attention(q, k, v, mask, causal, scale):
 @register("fused_attention_qkv", no_grad_slots=("Mask",))
 def _fused_attention_qkv(ctx, ins, attrs):
     """q/k/v: [batch, heads, seq, head_dim]. Mask broadcastable to
-    [batch, heads, q_seq, k_seq] (additive, -inf for masked)."""
+    [batch, heads, q_seq, k_seq] (additive, -inf for masked). K and V may
+    have fewer heads than Q (grouped KV: a divisor of Q's count; query head
+    j reads KV head ``j // group``). Attr ``window`` > 0 with ``causal``
+    limits query i to keys ``i-window+1 .. i``; attr ``kernel_tag`` names
+    the flash kernels of this call (``flash_fwd_<tag>`` ...)."""
     q, k, v = ins["Q"][0], ins["K"][0], ins["V"][0]
     mask = ins["Mask"][0] if ins.get("Mask") else None
     causal = bool(attrs.get("causal", False))
+    window = int(attrs.get("window") or 0)
+    if window and not causal:
+        raise ValueError("fused_attention_qkv: window needs causal=True")
     scale = attrs.get("scale") or (1.0 / math.sqrt(q.shape[-1]))
 
     # sequence/context parallelism: with attr seq_axis set and the axis
@@ -347,7 +362,8 @@ def _fused_attention_qkv(ctx, ins, attrs):
             return {"Out": [flash_attention(
                 q, k, v, causal=causal, scale=scale,
                 block_q=flags.get_flag("pallas_flash_block_q"),
-                block_k=flags.get_flag("pallas_flash_block_k"))]}
+                block_k=flags.get_flag("pallas_flash_block_k"),
+                window=window, tag=attrs.get("kernel_tag") or "")]}
         except ValueError as e:
             # a shape the kernel cannot tile (flash_attention raises
             # before building anything): take the XLA-composed form and
@@ -361,4 +377,5 @@ def _fused_attention_qkv(ctx, ins, attrs):
                     "fused_attention_qkv: pallas flash attention cannot "
                     "tile q%s k%s (%s); using XLA-composed attention "
                     "(O(s^2) memory)", *shape, e)
-    return {"Out": [_composed_attention(q, k, v, mask, causal, scale)]}
+    return {"Out": [_composed_attention(q, k, v, mask, causal, scale,
+                                        window)]}

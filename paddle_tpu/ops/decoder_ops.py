@@ -1,0 +1,408 @@
+"""Operations of rotary / RMSNorm / sparse-expert decoders
+(``models/laguna.py``): ``rms_norm``, ``rotary_embedding``, ``moe_router``
+and ``moe_experts``.
+
+The expert layer is *dropless* at static shapes. The router scores every
+token over ALL experts and chooses ``top_k``; ``moe_experts`` is told which
+contiguous range of experts it holds (its stacked weights' leading axis,
+starting at ``expert_lo``) and computes every (token, held expert) pair,
+whatever the imbalance; choices that fall on absent experts are left out
+of the sum. A counting sort lays the pairs out by expert in a row buffer
+(``pallas.grouped_matmul.tile_layout``: every expert starts on a tile of
+rows) and the three expert products run as grouped matrix products whose
+work follows the group sizes. On the TPU a gather or scatter costs by the
+element, so the layout is made of dense pieces (a table of running counts
+by a triangular product, lookups in tables of a few entries as masked sums)
+and ONE scatter of choice numbers; what remains are the gathers of whole
+rows: one into the buffer, one a slot out of it.
+
+The buffer has ``CAPACITY_FACTOR`` times the expected number of pairs; a
+step whose pairs exceed it takes, by a ``lax.cond`` inside the same compiled
+program, a second path that walks the tokens in chunks, each with room for
+all its pairs. So routing never recompiles and never drops, and the common
+case does not pay for the worst one. Backward is written out (no residual
+of the untaken branch is ever materialised): the fast path keeps its sorted
+input and hidden rows, the chunked path recomputes them.
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from .pallas import grouped_matmul as gm
+from .registry import register
+
+# ------------------------------------------------------------------ norm
+
+
+@register("rms_norm")
+def _rms_norm(ctx, ins, attrs):
+    """``x * rsqrt(mean(x^2) + eps) * w`` over the last axis, computed in
+    float32 whatever the input's dtype, returned in the input's."""
+    x, w = ins["X"][0], ins["Scale"][0]
+    eps = float(attrs.get("epsilon", 1e-6))
+    xf = x.astype(jnp.float32)
+    y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    return {"Out": [(y * w.astype(jnp.float32)).astype(x.dtype)]}
+
+
+# ---------------------------------------------------------------- rotary
+
+def rotary_inv_freq(rot_dim: int, theta: float, yarn: dict = None):
+    """-> (``inv_freq`` float64 [rot_dim / 2], ``attention_factor``).
+    Plain: ``theta^(-2i / rot_dim)``. YaRN (as ``transformers`` computes it
+    from ``factor``, ``original_max_position_embeddings``, ``beta_fast``,
+    ``beta_slow``): a linear ramp between the two correction dimensions
+    blends ``inv_freq`` (fast dimensions, extrapolated) with
+    ``inv_freq / factor`` (slow ones, interpolated); cos and sin are then
+    multiplied by ``attention_factor`` (``0.1 ln(factor) + 1`` when the
+    configuration gives none)."""
+    pos_freqs = theta ** (np.arange(0, rot_dim, 2, dtype=np.float64)
+                          / rot_dim)
+    inv = 1.0 / pos_freqs
+    if not yarn:
+        return inv, 1.0
+    factor = float(yarn["factor"])
+    orig = float(yarn["original_max_position_embeddings"])
+
+    def correction_dim(rotations):
+        return (rot_dim * math.log(orig / (rotations * 2 * math.pi))) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(correction_dim(float(yarn["beta_fast"]))), 0)
+    high = min(math.ceil(correction_dim(float(yarn["beta_slow"]))),
+               rot_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(rot_dim // 2, dtype=np.float64) - low)
+                   / (high - low), 0.0, 1.0)
+    extrapolation = 1.0 - ramp
+    inv = inv / factor * (1.0 - extrapolation) + inv * extrapolation
+    att = yarn.get("attention_factor")
+    return inv, float(att if att is not None
+                      else 0.1 * math.log(factor) + 1.0)
+
+
+def rotary_tables(seq: int, rot_dim: int, theta: float, yarn: dict = None):
+    """cos and sin of ``position * inv_freq``, float32 [seq, rot_dim / 2],
+    times the attention factor."""
+    inv, att = rotary_inv_freq(rot_dim, theta, yarn)
+    ang = np.arange(seq, dtype=np.float64)[:, None] * inv[None]
+    return ((np.cos(ang) * att).astype(np.float32),
+            (np.sin(ang) * att).astype(np.float32))
+
+
+@register("rotary_embedding", no_grad_slots=("Cos", "Sin"))
+def _rotary_embedding(ctx, ins, attrs):
+    """Rotate the first ``r = 2 * Cos.shape[-1]`` dims of every head of X
+    [b, h, s, d], pairs ``(i, i + r/2)`` (rotate-half); the other dims pass.
+    Cos/Sin: [s, r/2]."""
+    x, cos, sin = ins["X"][0], ins["Cos"][0], ins["Sin"][0]
+    half = cos.shape[-1]
+    cos, sin = cos.astype(jnp.float32), sin.astype(jnp.float32)
+    x1 = x[..., :half].astype(jnp.float32)
+    x2 = x[..., half:2 * half].astype(jnp.float32)
+    parts = [x1 * cos - x2 * sin, x2 * cos + x1 * sin]
+    out = jnp.concatenate(
+        [p.astype(x.dtype) for p in parts] + [x[..., 2 * half:]], axis=-1)
+    return {"Out": [out]}
+
+
+# ---------------------------------------------------------------- router
+
+@register("moe_router", no_grad_slots=(), nondiff_outputs=("TopkIdx",))
+def _moe_router(ctx, ins, attrs):
+    """X [.., h] x W [h, experts] -> the ``top_k`` experts of every token
+    (TopkIdx int32 [.., k]) and their weights (TopkWeight float32 [.., k]):
+    ``scale * s_e / sum of the chosen s``, ``s = sigmoid(x W)`` in float32
+    at full precision (under AMP the op is on the float32 list)."""
+    x, w = ins["X"][0], ins["W"][0]
+    k = int(attrs["top_k"])
+    scores = jax.nn.sigmoid(jnp.matmul(
+        x.astype(jnp.float32), w.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    top, idx = jax.lax.top_k(scores, k)
+    weight = float(attrs.get("scale", 1.0)) * top \
+        / jnp.sum(top, axis=-1, keepdims=True)
+    return {"TopkIdx": [idx.astype(jnp.int32)], "TopkWeight": [weight]}
+
+
+# --------------------------------------------------------------- experts
+
+def _silu_mul(h, f):
+    g, u = h[:, :f].astype(jnp.float32), h[:, f:].astype(jnp.float32)
+    return (jax.nn.silu(g) * u).astype(h.dtype)
+
+
+def _gather_sum(rows, pos, valid, weight=None):
+    """``sum_k where(valid[:, k], weight[:, k] * rows[pos[:, k]], 0)`` in
+    float32: one gather of ``[t, h]`` for each of the ``k`` slots, whatever
+    the routing (a slot gathered only when some token needs it would be a
+    ``cond`` a slot, which costs a copy of the sum and makes the step's
+    time follow the draw)."""
+    acc = jnp.zeros((pos.shape[0], rows.shape[1]), jnp.float32)
+    for k in range(pos.shape[1]):
+        r = jnp.take(rows, pos[:, k], axis=0).astype(jnp.float32)
+        if weight is not None:
+            r = r * weight[:, k, None].astype(jnp.float32)
+        acc = acc + jnp.where(valid[:, k, None], r, 0.0)
+    return acc
+
+
+_COUNT_BLOCK = 256
+
+
+def _running_counts(hot):
+    """``hot`` bool [pairs, groups] (choice ``p`` chose group ``g``) ->
+    ``c[p, g]`` = how many of the choices ``0 .. p`` chose ``g`` (int32): a
+    counting sort's table, made without a sort. Blocks of 256 choices are
+    summed by a product with a triangular matrix (0/1 values in bfloat16,
+    float32 sums: exact), the blocks' totals by a short cumulative sum."""
+    pairs, groups = hot.shape
+    if pairs % _COUNT_BLOCK:
+        return jnp.cumsum(hot.astype(jnp.int32), axis=0)
+    tri = jnp.tril(jnp.ones((_COUNT_BLOCK, _COUNT_BLOCK), jnp.bfloat16))
+    inner = jnp.einsum(
+        "ij,bjg->big", tri,
+        hot.astype(jnp.bfloat16).reshape(-1, _COUNT_BLOCK, groups),
+        preferred_element_type=jnp.float32)
+    totals = inner[:, -1, :]
+    before = jnp.cumsum(totals, axis=0) - totals
+    return (inner + before[:, None, :]).reshape(pairs, groups) \
+        .astype(jnp.int32)
+
+
+def _lookup(table, index):
+    """``table[index]`` for a table of a few entries, as a masked sum (on
+    the TPU a gather costs by the element whatever the table's size)."""
+    hot = index[..., None] == jnp.arange(table.shape[0], dtype=jnp.int32)
+    return jnp.sum(jnp.where(hot, table, 0), axis=-1)
+
+
+def _route(local, valid, groups, tm, num_tiles):
+    """The sorted layout of one set of tokens: ``local`` [t, k] the chosen
+    experts as indices into the held range, ``valid`` which of them are
+    held. -> dict of ``tile_group`` / ``n_active`` (the kernels' tables),
+    ``tok`` [rows] the token of every buffer row (``t`` = none: a zero
+    row), ``pair`` [rows] its flat choice, ``live`` [rows], ``pos`` [t, k]
+    the row of every held choice, ``counts`` [groups], ``dropped``. A counting sort by expert that keeps the choices' order
+    within an expert: the table gives every choice its row, one scatter of
+    choice numbers gives every row its choice."""
+    t, k = local.shape
+    pairs = t * k
+    key = jnp.where(valid, local, groups).reshape(pairs).astype(jnp.int32)
+    hot = key[:, None] == jnp.arange(groups, dtype=jnp.int32)[None]
+    running = _running_counts(hot)
+    counts = running[-1]
+    tile_group, n_active, row_start = gm.tile_layout(counts, tm, num_tiles)
+    rank = jnp.sum(jnp.where(hot, running, 0), axis=1) - 1
+    rows = num_tiles * tm
+    pos = jnp.where(valid.reshape(pairs), _lookup(row_start, key) + rank,
+                    rows)
+    dropped = jnp.sum(jnp.logical_and(valid.reshape(pairs), pos >= rows))
+    pair = jnp.full((rows,), pairs, jnp.int32).at[pos].set(
+        jnp.arange(pairs, dtype=jnp.int32), mode="drop",
+        unique_indices=True)
+    live = pair < pairs
+    pair = jnp.minimum(pair, pairs - 1)
+    pos = jnp.where(valid, jnp.minimum(pos, rows - 1).reshape(t, k), 0)
+    return dict(tile_group=tile_group, n_active=n_active, live=live,
+                tok=jnp.where(live, pair // k, t), pair=pair, pos=pos,
+                counts=counts, dropped=dropped)
+
+
+def _set_fwd(x, weight, route, valid, w13, w2, tm):
+    """Forward of one set of tokens -> (out float32 [t, h], (xs, hid, y))."""
+    f = w2.shape[1]
+    tg, na = route["tile_group"], route["n_active"]
+    xs = jnp.take(x, route["tok"], axis=0, mode="fill", fill_value=0)
+    hid = gm.gmm(xs, w13, tg, na, name="moe_up", tm=tm)
+    y = gm.gmm(_silu_mul(hid, f), w2, tg, na, name="moe_down", tm=tm)
+    return _gather_sum(y, route["pos"], valid, weight), (xs, hid, y)
+
+
+def _set_bwd(x, weight, route, valid, w13, w2, tm, dout, saved):
+    """Backward of one set of tokens -> (dx, dweight, dw13, dw2); ``saved``
+    is the forward's (xs, hid, y) or None to recompute them."""
+    f = w2.shape[1]
+    tg, na, pos = route["tile_group"], route["n_active"], route["pos"]
+    groups = w13.shape[0]
+    if saved is None:
+        _, saved = _set_fwd(x, weight, route, valid, w13, w2, tm)
+    xs, hid, y = saved
+    dt = xs.dtype
+    # the combine's two gradients in the sorted domain: a row's incoming
+    # gradient is its token's, so the weight's is a dot a row (mapped back
+    # to choices by a gather of scalars) and y's is that row scaled
+    dout_row = jnp.take(dout, route["tok"], axis=0, mode="fill",
+                        fill_value=0).astype(jnp.float32)
+    dw_row = jnp.sum(dout_row * y.astype(jnp.float32), axis=-1)
+    dweight = jnp.where(valid, dw_row[pos], 0.0).astype(weight.dtype)
+    w_row = jnp.where(route["live"],
+                      weight.reshape(-1)[route["pair"]].astype(jnp.float32),
+                      0.0)
+    dy = (dout_row * w_row[:, None]).astype(dt)
+    act = _silu_mul(hid, f)
+    da = gm.gmm(dy, w2, tg, na, name="moe_down_dx", tm=tm,
+                transpose_rhs=True).astype(jnp.float32)
+    dw2 = gm.tgmm(act, dy, tg, na, groups, name="moe_down_dw", tm=tm,
+                  tk=f, tn=min(w2.shape[2], (1 << 20) // f))
+    g, u = hid[:, :f].astype(jnp.float32), hid[:, f:].astype(jnp.float32)
+    sig = jax.nn.sigmoid(g)
+    dhid = jnp.concatenate(
+        [da * u * sig * (1.0 + g * (1.0 - sig)), da * g * sig],
+        axis=1).astype(dt)
+    dxs = gm.gmm(dhid, w13, tg, na, name="moe_up_dx", tm=tm,
+                 transpose_rhs=True)
+    dw13 = gm.tgmm(xs, dhid, tg, na, groups, name="moe_up_dw", tm=tm)
+    dx = _gather_sum(dxs, pos, valid).astype(x.dtype)
+    return dx, dweight, dw13, dw2
+
+
+#: rows of the fast buffer over the expected number of (token, held expert)
+#: pairs. Under near-uniform routing the pairs stay within a few percent of
+#: the expectation; twice it leaves the chunked path to real imbalance.
+CAPACITY_FACTOR = 2.0
+
+
+def _plan(tokens, top_k, groups, num_experts, tm):
+    """-> (tiles of the fast buffer, chunks of the second path or 0 when
+    the fast buffer already holds every pair)."""
+    pairs = tokens * top_k
+    expected = pairs * groups / float(num_experts)
+    rows = min(pairs, int(math.ceil(CAPACITY_FACTOR * expected)))
+    rows = -(-rows // tm) * tm
+    if rows >= pairs:
+        return -(-pairs // tm) + groups, 0
+    chunks = next(c for c in range(-(-pairs // rows), tokens + 1)
+                  if tokens % c == 0)
+    return rows // tm + groups, chunks
+
+
+def _chunk_tiles(tokens, chunks, top_k, groups, tm):
+    """Tiles of one chunk's buffer: room for all its pairs."""
+    return -(-(tokens // chunks) * top_k // tm) + groups
+
+
+def _chunked(a, chunks):
+    return a.reshape((chunks, a.shape[0] // chunks) + a.shape[1:])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def moe_experts(x, weight, idx, w13, w2, expert_lo, num_experts, tm):
+    """x [t, h], weight/idx [t, k] (the router's), w13 [g, h, 2f] (gate and
+    up side by side), w2 [g, f, h] -> (out [t, h], stats float32 [4]:
+    pairs on held experts, largest expert load over the mean load, dropped
+    pairs, 1 if the fast buffer held them all)."""
+    return _experts_fwd(x, weight, idx, w13, w2, expert_lo, num_experts,
+                        tm)[0]
+
+
+def _experts_fwd(x, weight, idx, w13, w2, expert_lo, num_experts, tm):
+    t, k = idx.shape
+    groups = w13.shape[0]
+    tiles, chunks = _plan(t, k, groups, num_experts, tm)
+    local = idx - expert_lo
+    valid = jnp.logical_and(local >= 0, local < groups)
+    route = _route(local, valid, groups, tm, tiles)
+    counts = route["counts"].astype(jnp.float32)
+    total = jnp.sum(counts)
+
+    def fast(_):
+        out, saved = _set_fwd(x, weight, route, valid, w13, w2, tm)
+        return out, saved, route["dropped"]
+
+    if not chunks:
+        out, saved, dropped = fast(None)
+        fits = jnp.ones((), bool)
+    else:
+        chunk_tiles = _chunk_tiles(t, chunks, k, groups, tm)
+
+        def slow(_):
+            def one(c):
+                xc, wc, lc, vc = c
+                rc = _route(lc, vc, groups, tm, chunk_tiles)
+                return _set_fwd(xc, wc, rc, vc, w13, w2, tm)[0], \
+                    rc["dropped"]
+            out, dropped = jax.lax.map(
+                one, tuple(_chunked(a, chunks)
+                           for a in (x, weight, local, valid)))
+            rows = tiles * tm
+            f2 = w13.shape[2]
+            empty = (jnp.zeros((rows, x.shape[1]), x.dtype),
+                     jnp.zeros((rows, f2), x.dtype),
+                     jnp.zeros((rows, x.shape[1]), x.dtype))
+            return out.reshape(t, -1), empty, jnp.sum(dropped)
+
+        fits = total <= (tiles - groups) * tm
+        out, saved, dropped = jax.lax.cond(fits, fast, slow, None)
+    stats = jnp.stack([total, jnp.max(counts) / jnp.maximum(
+        total / groups, 1.0), dropped.astype(jnp.float32),
+        fits.astype(jnp.float32)])
+    res = (x, weight, local, valid, w13, w2, route, saved, fits)
+    return (out.astype(x.dtype), stats), res
+
+
+def _experts_bwd(expert_lo, num_experts, tm, res, cts):
+    x, weight, local, valid, w13, w2, route, saved, fits = res
+    dout = cts[0]
+    t, k = local.shape
+    groups = w13.shape[0]
+    _, chunks = _plan(t, k, groups, num_experts, tm)
+
+    def fast(_):
+        return _set_bwd(x, weight, route, valid, w13, w2, tm, dout, saved)
+
+    if not chunks:
+        grads = fast(None)
+    else:
+        chunk_tiles = _chunk_tiles(t, chunks, k, groups, tm)
+
+        def slow(_):
+            def one(carry, c):
+                xc, wc, lc, vc, dc = c
+                rc = _route(lc, vc, groups, tm, chunk_tiles)
+                dx, dw, d13, d2 = _set_bwd(xc, wc, rc, vc, w13, w2, tm, dc,
+                                           None)
+                return (carry[0] + d13.astype(jnp.float32),
+                        carry[1] + d2.astype(jnp.float32)), (dx, dw)
+            zero = (jnp.zeros(w13.shape, jnp.float32),
+                    jnp.zeros(w2.shape, jnp.float32))
+            (d13, d2), (dx, dw) = jax.lax.scan(
+                one, zero, tuple(_chunked(a, chunks)
+                                 for a in (x, weight, local, valid, dout)))
+            return (dx.reshape(x.shape), dw.reshape(weight.shape),
+                    d13.astype(w13.dtype), d2.astype(w2.dtype))
+
+        grads = jax.lax.cond(fits, fast, slow, None)
+    dx, dweight, dw13, dw2 = grads
+    return dx, dweight, np.zeros(local.shape, jax.dtypes.float0), dw13, dw2
+
+
+moe_experts.defvjp(_experts_fwd, _experts_bwd)
+
+
+@register("moe_experts", no_grad_slots=("TopkIdx",),
+          nondiff_outputs=("Stats",))
+def _moe_experts(ctx, ins, attrs):
+    """X [.., h], TopkIdx / TopkWeight [.., k] (``moe_router``'s), WGateUp
+    [held, h, 2f], WDown [held, f, h] -> Out [.., h]: the held experts'
+    part of ``sum_e w_e FFN_e(x)`` (SwiGLU), and Stats (see
+    :func:`moe_experts`). Attrs: ``expert_lo`` (the first held expert's
+    id), ``num_experts`` (all the router chooses from), ``tile_m`` (rows
+    of a tile; the kernels' 128 unless a toy size asks for less)."""
+    x = ins["X"][0]
+    idx, weight = ins["TopkIdx"][0], ins["TopkWeight"][0]
+    w13, w2 = ins["WGateUp"][0], ins["WDown"][0]
+    h, k = x.shape[-1], idx.shape[-1]
+    out, stats = moe_experts(
+        x.reshape(-1, h), weight.reshape(-1, k).astype(x.dtype),
+        idx.reshape(-1, k), w13.astype(x.dtype), w2.astype(x.dtype),
+        int(attrs.get("expert_lo", 0)), int(attrs["num_experts"]),
+        int(attrs.get("tile_m", gm.TILE_M)))
+    return {"Out": [out.reshape(x.shape)], "Stats": [stats]}

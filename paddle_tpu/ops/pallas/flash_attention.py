@@ -18,6 +18,17 @@ probabilities blockwise, the standard flash-attention trade.
 Causal masking is block-skipped: a q block only loops over k blocks at or
 below its diagonal, halving causal FLOPs rather than masking dead work.
 
+A sliding window (``window``: query i sees keys ``i-window+1 .. i``) is
+block-skipped the same way from below: the key loop of a q block starts at
+the first block the window reaches, the query loop of a k block ends at
+the last one. Grouped KV heads (fewer k/v heads than q heads) are read
+through the index map (query head ``j`` reads KV head ``j // group``); the
+dk/dv kernel writes one partial per query head and the group is summed
+outside it. Both are static arguments: with ``window`` off and one KV head
+a query head the traced kernels are what they were without them. ``tag``
+names the three kernels (``flash_fwd_<tag>`` ...), so that a program whose
+layers differ in head count or key range has one shape under each name.
+
 On a CPU backend (tests, virtual meshes) the kernels run in Pallas
 interpreter mode, so the same code path is exercised everywhere.
 """
@@ -39,8 +50,27 @@ NEG_INF = float("-inf")
 
 # ---------------------------------------------------------------- forward
 
+def _window_lo(jq, block_q, block_k, window):
+    """First k block a q block's window reaches (0 with no window)."""
+    if not window:
+        return 0
+    return jnp.maximum(jq * block_q - (window - 1), 0) // block_k
+
+
+def _mask(s, row0, col0, window):
+    """Causal mask of one ``[block_q, block_k]`` tile of logits whose
+    first row and column are ``row0`` / ``col0``; with ``window`` also the
+    keys more than ``window - 1`` behind the query."""
+    row = row0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    col = col0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    keep = row >= col
+    if window:
+        keep = jnp.logical_and(keep, col > row - window)
+    return jnp.where(keep, s, NEG_INF)
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
-                scale, causal, block_k, seq_k):
+                scale, causal, block_k, seq_k, window=0):
     block_q, d = q_ref.shape[1], q_ref.shape[2]
     jq = pl.program_id(1)
     q = q_ref[0].astype(jnp.float32) * scale
@@ -55,15 +85,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         if causal:
-            row = jq * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            col = kb * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(row >= col, s, NEG_INF)
+            s = _mask(s, jq * block_q, kb * block_k, window)
         m_cur = jnp.max(s, axis=1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
+        # the first block a window reaches can lie wholly behind the
+        # window of the q block's last rows: their maximum is still -inf
+        m_ref = jnp.where(m_new == NEG_INF, 0.0, m_new) if window else m_new
+        alpha = jnp.exp(m_prev - m_ref)
+        p = jnp.exp(s - m_ref)
         l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
         acc = acc * alpha + jnp.dot(p, v, preferred_element_type=jnp.float32)
         return m_new, l_new, acc
@@ -71,25 +100,38 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
     m0 = jnp.full((block_q, 1), NEG_INF, jnp.float32)
     l0 = jnp.zeros((block_q, 1), jnp.float32)
     acc0 = jnp.zeros((block_q, d), jnp.float32)
-    m, l, acc = jax.lax.fori_loop(0, hi, body, (m0, l0, acc0))
+    m, l, acc = jax.lax.fori_loop(
+        _window_lo(jq, block_q, block_k, window), hi, body, (m0, l0, acc0))
     o_ref[0] = (acc / l).astype(o_ref.dtype)
     lse_ref[0, 0] = (m + jnp.log(l))[:, 0]
 
 
-def _flash_fwd(q, k, v, causal, scale, block_q, block_k):
+def _kv_map(group):
+    """Index map of a k/v operand whose head serves ``group`` query heads."""
+    if group == 1:
+        return lambda i, j: (i, 0, 0)
+    return lambda i, j: (i // group, 0, 0)
+
+
+def _name(stem, tag):
+    return f"{stem}_{tag}" if tag else stem
+
+
+def _flash_fwd(q, k, v, causal, scale, block_q, block_k, window=0, tag=""):
     bh, seq_q, d = q.shape
     seq_k = k.shape[1]
+    kv = _kv_map(bh // k.shape[0])
     grid = (bh, seq_q // block_q)
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                               block_k=block_k, seq_k=seq_k)
+                               block_k=block_k, seq_k=seq_k, window=window)
     o, lse = pl.pallas_call(
         kernel,
-        name="flash_fwd",
+        name=_name("flash_fwd", tag),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, seq_k, d), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((1, seq_k, d), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((1, seq_k, d), kv),
+            pl.BlockSpec((1, seq_k, d), kv),
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
@@ -110,7 +152,7 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k):
 # --------------------------------------------------------------- backward
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                   *, scale, causal, block_k, seq_k):
+                   *, scale, causal, block_k, seq_k, window=0):
     block_q, d = q_ref.shape[1], q_ref.shape[2]
     jq = pl.program_id(1)
     q = q_ref[0].astype(jnp.float32) * scale
@@ -126,28 +168,31 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         if causal:
-            row = jq * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            col = kb * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(row >= col, s, NEG_INF)
+            s = _mask(s, jq * block_q, kb * block_k, window)
         p = jnp.exp(s - lse)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         ds = p * (dp - delta)
         return dq + jnp.dot(ds, k, preferred_element_type=jnp.float32)
 
-    dq = jax.lax.fori_loop(0, hi, body, jnp.zeros((block_q, d), jnp.float32))
+    dq = jax.lax.fori_loop(_window_lo(jq, block_q, block_k, window), hi,
+                           body, jnp.zeros((block_q, d), jnp.float32))
     dq_ref[0] = (dq * scale).astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, *, scale, causal, block_q, seq_q):
+                    dk_ref, dv_ref, *, scale, causal, block_q, seq_q,
+                    window=0):
     block_k, d = k_ref.shape[1], k_ref.shape[2]
     jk = pl.program_id(1)
     k = k_ref[0].astype(jnp.float32)
     v = v_ref[0].astype(jnp.float32)
     lo = (jk * block_k) // block_q if causal else 0
+    hi = seq_q // block_q
+    if window:
+        # the last query that sees this block's last key
+        hi = jnp.minimum(
+            ((jk + 1) * block_k + window - 2) // block_q + 1, hi)
 
     def body(qb, carry):
         dk, dv = carry
@@ -159,11 +204,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         if causal:
-            row = qb * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            col = jk * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(row >= col, s, NEG_INF)
+            s = _mask(s, qb * block_q, jk * block_k, window)
         p = jnp.exp(s - lse)
         dv = dv + jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())),
                                       preferred_element_type=jnp.float32)
@@ -175,29 +216,31 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         return dk, dv
 
     z = jnp.zeros((block_k, d), jnp.float32)
-    dk, dv = jax.lax.fori_loop(lo, seq_q // block_q, body, (z, z))
+    dk, dv = jax.lax.fori_loop(lo, hi, body, (z, z))
     # q was pre-scaled, so dk already carries the scale factor
     dk_ref[0] = dk.astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
 
-def _flash_bwd(causal, scale, block_q, block_k, res, g):
+def _flash_bwd(causal, scale, block_q, block_k, res, g, window=0, tag=""):
     q, k, v, o, lse = res
     bh, seq_q, d = q.shape
-    seq_k = k.shape[1]
+    bh_kv, seq_k = k.shape[0], k.shape[1]
+    group = bh // bh_kv
+    kv = _kv_map(group)
     do = g
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                     axis=-1)[:, None, :]
 
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
-                          block_k=block_k, seq_k=seq_k),
-        name="flash_bwd_dq",
+                          block_k=block_k, seq_k=seq_k, window=window),
+        name=_name("flash_bwd_dq", tag),
         grid=(bh, seq_q // block_q),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, seq_k, d), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((1, seq_k, d), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((1, seq_k, d), kv),
+            pl.BlockSpec((1, seq_k, d), kv),
             pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
             pl.BlockSpec((1, 1, block_q), lambda i, j: (i, 0, j)),
             pl.BlockSpec((1, 1, block_q), lambda i, j: (i, 0, j)),
@@ -207,15 +250,18 @@ def _flash_bwd(causal, scale, block_q, block_k, res, g):
         interpret=_interpret(),
     )(q, k, v, do, lse, delta)
 
+    # one (dk, dv) partial per QUERY head; a KV head's group is summed below
+    kv_block = (lambda i, j: (i, j, 0)) if group == 1 \
+        else (lambda i, j: (i // group, j, 0))
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
-                          block_q=block_q, seq_q=seq_q),
-        name="flash_bwd_dkv",
+                          block_q=block_q, seq_q=seq_q, window=window),
+        name=_name("flash_bwd_dkv", tag),
         grid=(bh, seq_k // block_k),
         in_specs=[
             pl.BlockSpec((1, seq_q, d), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((1, block_k, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((1, block_k, d), kv_block),
+            pl.BlockSpec((1, block_k, d), kv_block),
             pl.BlockSpec((1, seq_q, d), lambda i, j: (i, 0, 0)),
             pl.BlockSpec((1, 1, seq_q), lambda i, j: (i, 0, 0)),
             pl.BlockSpec((1, 1, seq_q), lambda i, j: (i, 0, 0)),
@@ -225,11 +271,14 @@ def _flash_bwd(causal, scale, block_q, block_k, res, g):
             pl.BlockSpec((1, block_k, d), lambda i, j: (i, j, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct(k.shape, k.dtype),
-            jax.ShapeDtypeStruct(v.shape, v.dtype),
+            jax.ShapeDtypeStruct((bh, seq_k, d), k.dtype),
+            jax.ShapeDtypeStruct((bh, seq_k, d), v.dtype),
         ],
         interpret=_interpret(),
     )(q, k, v, do, lse, delta)
+    if group > 1:
+        dk, dv = (a.reshape(bh_kv, group, seq_k, d).astype(jnp.float32)
+                  .sum(axis=1).astype(a.dtype) for a in (dk, dv))
     return dq, dk, dv
 
 
@@ -238,20 +287,21 @@ def _flash_bwd(causal, scale, block_q, block_k, res, g):
 # utils.kernel_sharding these two wrappers run the 3-D kernels above on
 # each chip's own shard of batch and heads (see utils.shard_parallel).
 
-def _fwd_4d(q, k, v, causal, scale, block_q, block_k):
+def _fwd_4d(q, k, v, causal, scale, block_q, block_k, window, tag):
     b, h, seq_q, d = q.shape
-    o, lse = _flash_fwd(*(a.reshape(b * h, a.shape[2], d)
+    o, lse = _flash_fwd(*(a.reshape(-1, a.shape[2], d)
                           for a in (q, k, v)),
-                        causal, scale, block_q, block_k)
+                        causal, scale, block_q, block_k, window, tag)
     return o.reshape(q.shape), lse.reshape(b, h, 1, seq_q)
 
 
-def _bwd_4d(q, k, v, o, lse, do, causal, scale, block_q, block_k):
+def _bwd_4d(q, k, v, o, lse, do, causal, scale, block_q, block_k, window,
+            tag):
     b, h, seq_q, d = q.shape
-    res = tuple(a.reshape(b * h, a.shape[2], d) for a in (q, k, v, o)) \
+    res = tuple(a.reshape(-1, a.shape[2], d) for a in (q, k, v, o)) \
         + (lse.reshape(b * h, 1, seq_q),)
     dq, dk, dv = _flash_bwd(causal, scale, block_q, block_k, res,
-                            do.reshape(b * h, seq_q, d))
+                            do.reshape(b * h, seq_q, d), window, tag)
     return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
 
 
@@ -259,25 +309,26 @@ _fwd_call = shard_parallel(_fwd_4d, ("bh--",) * 3, ("bh--", "bh--"))
 _bwd_call = shard_parallel(_bwd_4d, ("bh--",) * 6, ("bh--",) * 3)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash(q, k, v, causal, scale, block_q, block_k):
-    return _fwd_call(q, k, v, causal, scale, block_q, block_k)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash(q, k, v, causal, scale, block_q, block_k, window, tag):
+    return _fwd_call(q, k, v, causal, scale, block_q, block_k, window,
+                     tag)[0]
 
 
-def _flash_vjp_fwd(q, k, v, causal, scale, block_q, block_k):
-    o, lse = _fwd_call(q, k, v, causal, scale, block_q, block_k)
+def _flash_vjp_fwd(q, k, v, causal, scale, block_q, block_k, window, tag):
+    o, lse = _fwd_call(q, k, v, causal, scale, block_q, block_k, window, tag)
     return o, (q, k, v, o, lse)
 
 
-def _flash_vjp_bwd(causal, scale, block_q, block_k, res, g):
-    return _bwd_call(*res, g, causal, scale, block_q, block_k)
+def _flash_vjp_bwd(causal, scale, block_q, block_k, window, tag, res, g):
+    return _bwd_call(*res, g, causal, scale, block_q, block_k, window, tag)
 
 
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
 def flash_attention(q, k, v, causal=False, scale=None,
-                    block_q=512, block_k=512):
+                    block_q=512, block_k=512, window=0, tag=""):
     """Flash attention on [b, h, s, d] (or [bh, s, d]) inputs.
 
     Returns attention output with the input's shape/dtype. Raises
@@ -285,13 +336,18 @@ def flash_attention(q, k, v, causal=False, scale=None,
     fallback); self-attention (seq_q == seq_k) plus cross shapes whose
     sequences are divisible by a power-of-two block are supported.
 
+    ``k`` / ``v`` may have fewer heads than ``q`` (a divisor of its count):
+    query head ``j`` reads KV head ``j // (h_q // h_kv)``. ``window`` > 0
+    (causal only) limits query ``i`` to keys ``i-window+1 .. i``. ``tag``
+    is appended to the three kernels' names.
+
     Sequence-length limit: every grid step keeps the WHOLE K and V of
     one head in VMEM (the ``(1, seq_k, d)`` blocks above; q, do, lse and
     delta likewise in the dk/dv kernel). At d=128 bf16 the v5e compiler
     accepts forward and backward through s=8192 and refuses s=16384
     ("RESOURCE_EXHAUSTED: Ran out of memory in memory space vmem") —
     at compile time under jit, so no ValueError fallback sees it. Longer
-    sequences need K/V streamed by block first (ROADMAP S5 / R7a).
+    sequences need K/V streamed by block first (ROADMAP S5 / R8a).
 
     Inside a program that spans several chips (utils.kernel_sharding)
     the kernels run on each chip's own (batch, head) shard; sequence and
@@ -311,6 +367,11 @@ def flash_attention(q, k, v, causal=False, scale=None,
             f"flash_attention: cannot tile seq_q={seq_q}, seq_k={seq_k}")
     if causal and seq_q != seq_k:
         raise ValueError("causal flash_attention requires seq_q == seq_k")
+    if window and not causal:
+        raise ValueError("flash_attention: a window needs causal=True")
+    if h % k.shape[1] or k.shape[1] != v.shape[1]:
+        raise ValueError(
+            f"flash_attention: {h} query heads over {k.shape[1]} KV heads")
     # head_dim rides the lane axis whole; an unaligned width is padded
     # with zero columns (k's zero columns contribute nothing to the
     # logits, v's produce zero output columns sliced off below) rather
@@ -319,7 +380,8 @@ def flash_attention(q, k, v, causal=False, scale=None,
     if dp != d:
         pad = [(0, 0), (0, 0), (0, 0), (0, dp - d)]
         q, k, v = (jnp.pad(a, pad) for a in (q, k, v))
-    out = _flash(q, k, v, causal, float(scale), bq, bk)
+    out = _flash(q, k, v, causal, float(scale), bq, bk, int(window or 0),
+                 str(tag))
     if dp != d:
         out = out[..., :d]
     return out[:, 0] if squeeze else out

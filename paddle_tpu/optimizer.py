@@ -402,17 +402,8 @@ class Optimizer:
             lr_arr = jnp.asarray([lr * getattr(p, "lr_scale", 1.0)],
                                  jnp.float32)
             ins = {"Param": [p.value], "Grad": [g], "LearningRate": [lr_arr]}
-            moment_dtype = getattr(self, "_moment_dtype", None)
             for slot, key, init, shape in spec["accums"]:
-                skey = (id(p), key)
-                if skey not in self._eager_state:
-                    dt = p.value.dtype
-                    if moment_dtype is not None and shape is None \
-                            and key in ("m1", "m2", "moment", "mom"):
-                        dt = jnp.dtype(moment_dtype)
-                    self._eager_state[skey] = jnp.full(
-                        shape or p.value.shape, init, dt)
-                ins[slot] = [self._eager_state[skey]]
+                ins[slot] = [self._accumulator(p, key, init, shape)]
             outs = _reg.execute(ctx, op_type, ins, self._eager_attrs())
             for oslot, target in spec["outs"].items():
                 val = outs[oslot][0]
@@ -423,6 +414,36 @@ class Optimizer:
                     if prev is not None and val.dtype != prev.dtype:
                         val = val.astype(prev.dtype)  # keep bf16 storage
                     self._eager_state[(id(p), target)] = val
+
+    def _accumulator(self, p, key, init, shape):
+        """The eager accumulator ``key`` of parameter ``p``, made at its
+        initial value the first time it is asked for."""
+        import jax.numpy as jnp
+        skey = (id(p), key)
+        if skey not in self._eager_state:
+            dt = p.value.dtype
+            moment_dtype = getattr(self, "_moment_dtype", None)
+            if moment_dtype is not None and shape is None \
+                    and key in ("m1", "m2", "moment", "mom"):
+                dt = jnp.dtype(moment_dtype)
+            self._eager_state[skey] = jnp.full(
+                shape or p.value.shape, init, dt)
+        return self._eager_state[skey]
+
+    def init_state(self):
+        """Make every trainable parameter's accumulators now, at their
+        initial values, instead of in the first ``step()``. A step compiled
+        by ``jit.to_static`` then has the same state before and after its
+        first call, so it compiles once and not twice."""
+        if self._eager_op is None:
+            raise NotImplementedError(
+                f"{type(self).__name__} has no eager step path")
+        for p in self._parameters_or_raise:
+            if getattr(p, "trainable", True):
+                for _, key, init, shape in \
+                        _EAGER_SPECS[self._eager_op]["accums"]:
+                    self._accumulator(p, key, init, shape)
+
     def clear_grad(self):
         for p in self._parameters_or_raise:
             p.clear_grad()

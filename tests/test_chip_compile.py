@@ -34,6 +34,7 @@ from paddle_tpu.ops.pallas.utils import kernel_sharding
 # the package re-exports the functions under the modules' names
 fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
 pa = importlib.import_module("paddle_tpu.ops.pallas.paged_attention")
+gm = importlib.import_module("paddle_tpu.ops.pallas.grouped_matmul")
 
 KERNEL = chip_smoke.KERNEL      # a Mosaic kernel in a compiled program
 
@@ -71,6 +72,7 @@ def mosaic(monkeypatch):
     which Mosaic refuses)."""
     monkeypatch.setattr(fa, "_interpret", lambda: False)
     monkeypatch.setattr(pa, "_interpret", lambda: False)
+    monkeypatch.setattr(gm, "_interpret", lambda: False)
     with jax.enable_x64(False):
         yield
 
@@ -99,6 +101,63 @@ def test_flash_attention_compiles_for_v5e(one_chip, mosaic, shape,
     text = jax.jit(fn).lower(q, q, q).compile().as_text()
     # forward: one kernel; backward: forward + dq + dk/dv
     assert text.count(KERNEL) == (1 if direction == "forward" else 3)
+
+
+# laguna_pretrain_8k's attention on one chip's share: batch 2, one KV head
+# of 128 at s 8192 under 8 query heads with window 512 (the window layers)
+# or 6 query heads, causal (the full layers)
+GROUPED_FLASH = {"win": (8, 512), "full": (6, 0)}
+
+
+@pytest.mark.parametrize("kind", sorted(GROUPED_FLASH))
+def test_windowed_grouped_flash_attention_compiles_for_v5e(one_chip, mosaic,
+                                                           kind):
+    heads, window = GROUPED_FLASH[kind]
+
+    def s(h):
+        return jax.ShapeDtypeStruct((2, h, 8192, 128), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    def loss(q, k, v):
+        return fa.flash_attention(q, k, v, causal=True, window=window,
+                                  tag=kind).astype(jnp.float32).sum()
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        s(heads), s(1), s(1)).compile().as_text()
+    assert text.count(KERNEL) == 3
+    for stem in ("flash_fwd_", "flash_bwd_dq_", "flash_bwd_dkv_"):
+        assert stem + kind in text
+
+
+# the expert layer's grouped products at laguna_pretrain_8k's shapes: 32
+# held experts of width 512 under hidden 2048, the fast buffer's 32768 rows
+# and a tile an expert; (lhs, rhs or second lhs, transposed, tk, tn)
+ROWS, HELD = 32768 + 32 * 128, 32
+GROUPED = {
+    "moe_up": ((ROWS, 2048), (HELD, 2048, 1024), False),
+    "moe_down": ((ROWS, 512), (HELD, 512, 2048), False),
+    "moe_up_dx": ((ROWS, 1024), (HELD, 2048, 1024), True),
+    "moe_down_dx": ((ROWS, 2048), (HELD, 512, 2048), True),
+    "moe_up_dw": ((ROWS, 2048), (ROWS, 1024), (1024, 1024)),
+    "moe_down_dw": ((ROWS, 512), (ROWS, 2048), (512, 2048)),
+}
+
+
+@pytest.mark.parametrize("name", sorted(GROUPED))
+def test_grouped_products_compile_for_v5e(one_chip, mosaic, name):
+    a, b, how = GROUPED[name]
+
+    def s(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    tables = (s((ROWS // gm.TILE_M,), jnp.int32), s((1,), jnp.int32))
+    if isinstance(how, bool):
+        def fn(x, w, tg, na):
+            return gm.gmm(x, w, tg, na, name=name, transpose_rhs=how)
+    else:
+        def fn(x, y, tg, na):
+            return gm.tgmm(x, y, tg, na, HELD, name=name, tk=how[0],
+                           tn=how[1])
+    text = jax.jit(fn).lower(s(a), s(b), *tables).compile().as_text()
+    assert text.count(KERNEL) == 1 and name in text
 
 
 # the serving pool at gpt2-1p3b width: 32 slots, h16 d128, 16-row
