@@ -12,6 +12,14 @@ while every request brings its own decoding recipe:
     rows bit-for-bit as before, so the PR 3..12 token-identity oracles
     survive unchanged while sampled/constrained/LoRA rows share the
     same executable in the same batch.
+  - The logit-processor chain and the draws (a sort, two argsorts, a
+    softmax, a cumsum and Gumbel noise over ``[rows, vocab]``) sit in
+    the true branch of ONE ``lax.cond`` on "does any row sample"
+    (``_where_any_sampled``), a device value read off the ``samp``
+    input: a step whose rows are all greedy runs the argmax and the
+    key split and nothing else, a batch with one sampled row computes
+    what it always did for every row.  No flag, no second executable:
+    the choice is made on the device, in the one compiled step.
   - Per-slot ``jax.random`` key state advances functionally inside the
     step (a fixed number of ``split``s per row per step, data
     independent), so a request's random stream depends only on its own
@@ -143,10 +151,14 @@ def request_key(seed: int) -> np.ndarray:
 def neutral_samp(rows: int, vocab: int):
     """Per-slot sampling inputs that reproduce pure greedy decoding.
 
-    temperature 0 routes every row through the argmax branch of
+    temperature 0 routes every row through the argmax of
     ``sample_tokens`` on bit-identical logits (the additive mask is
-    exactly zero), so offline greedy/beam callers and empty engine
-    slots pay nothing for the sampling machinery."""
+    exactly zero).  A batch of such rows alone takes the false branch
+    of the step's ``lax.cond`` on "does any row sample", so offline
+    greedy/beam callers and all-greedy engine batches pay for the
+    argmax and the key split only, not for the processor chain or the
+    draws; an empty engine slot beside a sampled row rides that row's
+    branch, as every row of a mixed batch does."""
     return (np.zeros((rows,), np.float32),
             np.zeros((rows,), np.int32),
             np.zeros((rows,), np.float32),
@@ -203,23 +215,45 @@ def split_keys(keys):
     return pairs[:, 0], pairs[:, 1]
 
 
+def _where_any_sampled(temp, sampled, greedy):
+    """``sampled()`` in steps where some row has temp > 0, else
+    ``greedy`` (a pytree of the same shapes), under one ``lax.cond``.
+
+    The predicate is a device value computed from the step's own
+    ``samp`` input, so one executable serves both kinds of step and an
+    all-greedy batch runs nothing that only ``sampled`` computes.  A
+    mixed batch takes the true branch for every row; ``sampled`` keeps
+    ``greedy`` for its temp == 0 rows."""
+    import jax
+    import jax.numpy as jnp
+    return jax.lax.cond(jnp.any(temp > 0), sampled, lambda: greedy)
+
+
 def sample_tokens(logits, samp):
     """One next token per row from ``[rows, vocab]`` logits.
 
     ``samp = (temperature, top_k, top_p, keys, mask)``.  Returns
     ``(tokens [rows] i32, carry_keys [rows, 2] uint32)``.  Greedy rows
     (temp == 0) take ``argmax(logits + mask)`` — with a zero mask this
-    is bit-identical to the pre-sampling decode step.
+    is bit-identical to the pre-sampling decode step.  The processor
+    chain and the categorical draw run only in steps where some row
+    samples (``_where_any_sampled``); the key split runs in every
+    step, so the carry is the same in both branches and a request's
+    stream depends on its seed alone.
     """
     import jax
     import jax.numpy as jnp
     temp, top_k, top_p, keys, mask = samp
     lgm = logits + mask
     greedy = jnp.argmax(lgm, axis=-1).astype(jnp.int32)
-    proc = process_logits(lgm, temp, top_k, top_p)
     carry, sub = split_keys(keys)
-    drawn = jax.vmap(jax.random.categorical)(sub, proc).astype(jnp.int32)
-    return jnp.where(temp > 0, drawn, greedy), carry
+
+    def sampled():
+        proc = process_logits(lgm, temp, top_k, top_p)
+        drawn = jax.vmap(jax.random.categorical)(sub, proc)
+        return jnp.where(temp > 0, drawn.astype(jnp.int32), greedy)
+
+    return _where_any_sampled(temp, sampled, greedy), carry
 
 
 def verify_tokens(logits, drafts, samp):
@@ -235,7 +269,11 @@ def verify_tokens(logits, drafts, samp):
     rejection draw "``p_i`` with the draft masked out"; the bonus
     position is a plain sample from ``p_K``.  Greedy rows reduce to
     ``chosen = argmax`` and ``accept = (argmax == draft)`` — the PR 7
-    prefix match, token-identical.  Entries past a row's first
+    prefix match, token-identical — and a step whose rows are all
+    greedy computes that and the key split alone: the chain over
+    ``rows x (K+1)`` positions, the softmax, the uniforms and the
+    residual draws run only where some row samples
+    (``_where_any_sampled``).  Entries past a row's first
     rejection are garbage by construction; the engine's host loop
     commits the accepted prefix and rolls the KV write offset back.
     """
@@ -244,40 +282,44 @@ def verify_tokens(logits, drafts, samp):
     temp, top_k, top_p, keys, mask = samp
     rows, kp1, vocab = logits.shape
     k = kp1 - 1
-    neg = jnp.asarray(NEG_MASK, logits.dtype)
     lgm = logits + mask[:, None, :]
     greedy = jnp.argmax(lgm, axis=-1).astype(jnp.int32)
-
-    rep = lambda x: jnp.repeat(x, kp1)
-    proc = process_logits(lgm.reshape(rows * kp1, vocab), rep(temp),
-                          rep(top_k), rep(top_p)).reshape(rows, kp1, vocab)
+    greedy_accept = greedy[:, :k] == drafts
     carry, sub = split_keys(keys)
-    # Fixed fan-out per row per step: K+1 accept draws + K+1 token
-    # draws, consumed whether or not any draft survives.
-    subs = jax.vmap(lambda kk: jax.random.split(kk, 2 * kp1))(sub)
-    ukeys, ckeys = subs[:, :kp1], subs[:, kp1:]
-    probs = jax.nn.softmax(proc, axis=-1)
-    bonus = jax.vmap(jax.random.categorical)(
-        ckeys[:, k], proc[:, k]).astype(jnp.int32)
 
-    if k == 0:
-        chosen = jnp.where(temp[:, None] > 0, bonus[:, None], greedy)
-        return chosen, jnp.zeros((rows, 0), bool), carry
+    def sampled():
+        neg = jnp.asarray(NEG_MASK, logits.dtype)
+        rep = lambda x: jnp.repeat(x, kp1)
+        proc = process_logits(
+            lgm.reshape(rows * kp1, vocab), rep(temp), rep(top_k),
+            rep(top_p)).reshape(rows, kp1, vocab)
+        # Fixed fan-out per row per step: K+1 accept draws + K+1 token
+        # draws, consumed whether or not any draft survives.
+        subs = jax.vmap(lambda kk: jax.random.split(kk, 2 * kp1))(sub)
+        ukeys, ckeys = subs[:, :kp1], subs[:, kp1:]
+        bonus = jax.vmap(jax.random.categorical)(
+            ckeys[:, k], proc[:, k]).astype(jnp.int32)
+        samples = (temp > 0)[:, None]
+        if k == 0:
+            return (jnp.where(samples, bonus[:, None], greedy),
+                    greedy_accept)
+        probs = jax.nn.softmax(proc, axis=-1)
+        draft_p = jnp.take_along_axis(
+            probs[:, :k], drafts[..., None].astype(jnp.int32),
+            axis=-1)[..., 0]
+        u = jax.vmap(jax.vmap(jax.random.uniform))(ukeys[:, :k])
+        accept_s = u < draft_p
+        resid = jnp.where(jax.nn.one_hot(drafts, vocab, dtype=bool),
+                          neg, proc[:, :k])
+        resample = jax.vmap(jax.vmap(jax.random.categorical))(
+            ckeys[:, :k], resid).astype(jnp.int32)
+        chosen_s = jnp.where(accept_s, drafts.astype(jnp.int32), resample)
+        chosen_s = jnp.concatenate([chosen_s, bonus[:, None]], axis=1)
+        return (jnp.where(samples, chosen_s, greedy),
+                jnp.where(samples, accept_s, greedy_accept))
 
-    draft_p = jnp.take_along_axis(
-        probs[:, :k], drafts[..., None].astype(jnp.int32), axis=-1)[..., 0]
-    u = jax.vmap(jax.vmap(jax.random.uniform))(ukeys[:, :k])
-    accept_s = u < draft_p
-    resid = jnp.where(jax.nn.one_hot(drafts, vocab, dtype=bool),
-                      neg, proc[:, :k])
-    resample = jax.vmap(jax.vmap(jax.random.categorical))(
-        ckeys[:, :k], resid).astype(jnp.int32)
-    chosen_s = jnp.where(accept_s, drafts.astype(jnp.int32), resample)
-    chosen_s = jnp.concatenate([chosen_s, bonus[:, None]], axis=1)
-
-    sampled = (temp > 0)[:, None]
-    chosen = jnp.where(sampled, chosen_s, greedy)
-    accept = jnp.where(sampled, accept_s, greedy[:, :k] == drafts)
+    chosen, accept = _where_any_sampled(temp, sampled,
+                                        (greedy, greedy_accept))
     return chosen, accept, carry
 
 

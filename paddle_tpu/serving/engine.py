@@ -733,6 +733,11 @@ class ServingEngine:
         self._pool_dispatches = 0         # guarded-by: _step_lock
         self._pool_inplace = 0            # guarded-by: _step_lock
         self._pool_epoch = self.cache.pool.epoch if self.paged else 0
+        # decode/verify dispatches, and those whose batch was all
+        # greedy: the step's lax.cond took the branch without the
+        # sampler (decoding._where_any_sampled)
+        self._sampler_dispatches = 0      # guarded-by: _step_lock
+        self._sampler_skipped = 0         # guarded-by: _step_lock
         self._qerr_max = 0.0              # guarded-by: _step_lock
         self._qerr_gauge = None
         if self.kv_dtype == "int8":
@@ -777,6 +782,8 @@ class ServingEngine:
             "_ahead_misses": "_step_lock",
             "_pool_dispatches": "_step_lock",
             "_pool_inplace": "_step_lock",
+            "_sampler_dispatches": "_step_lock",
+            "_sampler_skipped": "_step_lock",
         })
 
     # -------------------------------------------------------------- mesh
@@ -1889,6 +1896,18 @@ class ServingEngine:
             _monitor.stat_add("STAT_serving_pool_inplace")
         return out
 
+    def _note_sampler(self):  # holds: _step_lock
+        """Count one decode/verify dispatch, and whether every live
+        row was greedy: what the step's ``lax.cond`` on "does any row
+        sample" reads on the device from the ``samp`` this batch was
+        given. ``sampler_skipped / sampler_dispatches`` in
+        :meth:`stats` is the share of dispatches that ran the argmax
+        alone, without the processor chain and the draws."""
+        self._sampler_dispatches += 1
+        if all(req.decode.is_greedy for req in self._active.values()):
+            self._sampler_skipped += 1
+            _monitor.stat_add("STAT_serving_sampler_skipped")
+
     def _decode_attempt(self, tokens: np.ndarray):
         kind = fault_point("serving.step")
         if kind == "skip":
@@ -1904,12 +1923,16 @@ class ServingEngine:
                         self.cache.arrays(), self._build_samp())
                 if self._lora_shape is not None:
                     args = args + (self._lora_args(),)
-            return self._call_paged(fn, args, args[3])
-        fn = decode_step(self.model)["fn"]
-        with _profiler.RecordEvent("serving.decode.inputs"):
-            args = (jnp.asarray(tokens), jnp.asarray(self.cache.lengths),
-                    self.cache.arrays(), self._build_samp())
-        return fn(*args)
+            out = self._call_paged(fn, args, args[3])
+        else:
+            fn = decode_step(self.model)["fn"]
+            with _profiler.RecordEvent("serving.decode.inputs"):
+                args = (jnp.asarray(tokens),
+                        jnp.asarray(self.cache.lengths),
+                        self.cache.arrays(), self._build_samp())
+            out = fn(*args)
+        self._note_sampler()
+        return out
 
     def _note_qerr(self, qerr, rows: int):  # holds: _step_lock
         """Surface an int8 step's max-abs dequantization error: bump
@@ -2112,6 +2135,7 @@ class ServingEngine:
         if self._lora_shape is not None:
             args = args + (ctx["lora"],)
         ahead_out = self._call_paged(ctx["fn"], args, pools_f)
+        self._note_sampler()
         self._ahead = {
             "n": n,
             "snap": self._ahead_snapshot(n, extra_tokens=n),
@@ -2160,7 +2184,9 @@ class ServingEngine:
         if taken is not None:
             return taken
         args, ctx = self._megastep_inputs(n)
-        return self._call_paged(ctx["fn"], args, args[3]), ctx
+        out = self._call_paged(ctx["fn"], args, args[3])
+        self._note_sampler()
+        return out, ctx
 
     def _decode_megastep(self, n: int) -> int:  # holds: _step_lock
         """One device-resident megastep over every occupied slot: N
@@ -2286,12 +2312,16 @@ class ServingEngine:
                         self.cache.arrays(), self._build_samp())
                 if self._lora_shape is not None:
                     args = args + (self._lora_args(),)
-            return self._call_paged(fn, args, args[3])
-        fn = verify_step(self.model, self.spec_tokens)["fn"]
-        with _profiler.RecordEvent("serving.decode.inputs"):
-            args = (jnp.asarray(tokens), jnp.asarray(self.cache.lengths),
-                    self.cache.arrays(), self._build_samp())
-        return fn(*args)
+            out = self._call_paged(fn, args, args[3])
+        else:
+            fn = verify_step(self.model, self.spec_tokens)["fn"]
+            with _profiler.RecordEvent("serving.decode.inputs"):
+                args = (jnp.asarray(tokens),
+                        jnp.asarray(self.cache.lengths),
+                        self.cache.arrays(), self._build_samp())
+            out = fn(*args)
+        self._note_sampler()
+        return out
 
     def _spec_decode(self) -> int:  # holds: _step_lock
         """One speculative draft–verify step over every occupied slot:
@@ -2696,6 +2726,8 @@ class ServingEngine:
             ahead_misses = self._ahead_misses
             pool_dispatches = self._pool_dispatches
             pool_inplace = self._pool_inplace
+            sampler_dispatches = self._sampler_dispatches
+            sampler_skipped = self._sampler_skipped
         with self._lock:
             completed = self._completed
             slo_met = self._slo_met
@@ -2742,6 +2774,10 @@ class ServingEngine:
             if self.dispatch_ahead:
                 out["ahead_hits"] = ahead_hits
                 out["ahead_misses"] = ahead_misses
+        # decode/verify dispatches, and those whose batch was all
+        # greedy (the step skipped the sampler on the device)
+        out["sampler_dispatches"] = sampler_dispatches
+        out["sampler_skipped"] = sampler_skipped
         out["paged"] = self.paged
         out["attn_impl"] = self.attn_impl
         out["kv_dtype"] = self.kv_dtype

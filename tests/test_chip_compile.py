@@ -318,3 +318,19 @@ def test_decode_step_writes_its_kv_rows_in_place(
     assert m, "the compiled step aliases nothing"
     aliased = {int(i) for i in re.findall(r"\{(\d+)\}: \(", m.group(1))}
     assert set(range(2, 2 + 2 * cfg.num_layers)) <= aliased, m.group(1)
+
+
+def test_decode_step_keeps_the_sampler_under_a_conditional(
+        one_chip, decode_step_1p3b_width):
+    """The chip's compiler keeps ``decoding._where_any_sampled``'s
+    ``cond`` a conditional (it does not run both branches and select):
+    the step's entry computation has one ``conditional`` and no sort;
+    the top-k / top-p sorts live in the branch it calls."""
+    import re
+    _, lower = decode_step_1p3b_width
+    text = lower(one_chip, jnp.dtype("float32"))
+    entry = text[text.index("\nENTRY"):]
+    entry = entry[:entry.index("\n}")]
+    assert len(re.findall(r" conditional\(", entry)) == 1
+    assert not re.findall(r" sort\(", entry)
+    assert re.findall(r" sort\(", text)

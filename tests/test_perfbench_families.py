@@ -216,3 +216,64 @@ def test_the_new_cells_toy_twin_rehearses_to_its_end():
     assert set(line["metrics"]) == {"train_tok_s_chip", "setup_s"}
     assert all(m["value"] is None for m in line["metrics"].values())
     assert line["notes"]["check_loss_diff"] <= 0.05
+
+
+# ---- PR 28: the sampler's counters through the counter channel
+
+SAMPLER_SHARES = {"sampler_skipped_share_pct.chat": "chat_steady",
+                  "sampler_skipped_share_pct.docs": "docs_offline",
+                  "sampler_skipped_share_pct.decode": "decode_heavy"}
+
+
+@pytest.mark.parametrize("name", sorted(SAMPLER_SHARES))
+def test_sampler_skipped_share_reads_both_engine_counters(name):
+    from perfbench import readers
+    bench = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
+    entry = next(m for m in bench["per_layer"] if m["name"] == name)
+    spec = load("metrics", name + ".json")
+    twin = load("metrics", name.replace("sampler_skipped", "pool_inplace")
+                + ".json")
+    assert entry["workloads"] == [SAMPLER_SHARES[name]]
+    assert all(spec[k] == entry[k] == twin[k]
+               for k in ("layer", "unit", "better", "source", "moves"))
+    obs = {"counters": {"engine.sampler_skipped": 1500.0,
+                        "engine.sampler_dispatches": 1600.0}}
+    assert readers.read(name, obs) == pytest.approx(93.75)
+    # the parent's program has neither counter: nothing to read, no metric
+    assert readers.read(name, {"counters": {
+        "engine.pool_dispatches": 1600.0}}) is None
+    # no decode dispatch in the window: no share
+    assert readers.read(name, {"counters": {
+        "engine.sampler_skipped": 0, "engine.sampler_dispatches": 0}}) is None
+
+
+REHEARSE_WITH_VALUES = """
+import argparse, json, sys
+sys.path.insert(0, {root!r})
+from perfbench import run as harness
+bench = harness.load_json({root!r}, "BENCHMARK.json")
+bench["configs"] = [{{"name": "cgpt-1p3b",
+                     "file": "perfbench/rehearsal/gpt2-tiny.json"}}]
+args = argparse.Namespace(workload="decode_heavy", seed=2147483659,
+                          seconds=1.5, trace=1)
+print(json.dumps(harness.run_cell(bench, args, rehearsal=True,
+                                  traffic_dir="rehearsal")))
+"""
+
+
+def test_a_rehearsal_reports_every_decode_step_as_sampler_skipped():
+    """``decode_heavy``'s toy twin, traced, on a real engine (its own
+    process, as ``rehearse.py`` runs it, but with the values kept): the
+    harness submits no decode parameters, so every decode dispatch of the
+    window is all-greedy and the reader finds both counters."""
+    out = subprocess.run(
+        [sys.executable, "-c", REHEARSE_WITH_VALUES.format(root=ROOT)],
+        env=dict(os.environ, JAX_PLATFORMS="cpu"), cwd=ROOT,
+        capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, out.stderr[-2000:]
+    line = json.loads(out.stdout.strip().splitlines()[-1])
+    assert line["correct"] is True and line["failed"] == 0
+    m = line["metrics"]
+    assert m["sampler_skipped_share_pct.decode"]["value"] == 100.0
+    assert m["pool_inplace_share_pct.decode"]["value"] == 100.0
+    assert m["compiles_in_window.decode"]["value"] == 0

@@ -16,7 +16,13 @@ The decoding subsystem's contract, locked at tier 1:
   sharing one engine and one KV pool, with zero leaked adapter pages
   or KV blocks even under injected chaos;
 - none of it adds a decode compile: sampling params, stop sequences,
-  grammar masks and adapter pages are all step *data*.
+  grammar masks and adapter pages are all step *data*;
+- a step whose rows are all greedy does not run the sampler: the
+  processor chain and the draws sit under one ``lax.cond`` on "does
+  any row sample", greedy rows are ``argmax(logits + mask)`` bit for
+  bit, sampled and mixed batches are token-, accept- and key-identical
+  to the chain inlined without the ``cond`` (kept here as the oracle),
+  and the engine counts the dispatches that took the cheap branch.
 """
 
 import json
@@ -31,8 +37,10 @@ from paddle_tpu.models.gpt import GPTConfig, GPTForCausalLM
 from paddle_tpu.serving import (DecodeParams, DisaggRouter, JsonGrammar,
                                 ReplicaRouter, ServingEngine,
                                 json_token_strings, make_adapter)
-from paddle_tpu.serving.decoding import (process_logits, request_key,
-                                         sample_tokens, verify_tokens)
+from paddle_tpu.serving import decoding
+from paddle_tpu.serving.decoding import (NEG_MASK, process_logits,
+                                         request_key, sample_tokens,
+                                         split_keys, verify_tokens)
 
 VOCAB = 97
 
@@ -314,3 +322,296 @@ def test_request_key_ignores_everything_but_seed():
     assert a.dtype == np.uint32 and a.shape == (2,)
     assert np.array_equal(a, b)
     assert not np.array_equal(request_key(42), request_key(43))
+
+
+# ------------------------------- greedy steps skip the sampler (PR 28)
+
+def _oracle_sample_tokens(logits, samp):
+    """``sample_tokens`` as it was before the ``cond``: the chain and
+    the draw computed for every row of every step."""
+    import jax
+    import jax.numpy as jnp
+    temp, top_k, top_p, keys, mask = samp
+    lgm = logits + mask
+    greedy = jnp.argmax(lgm, axis=-1).astype(jnp.int32)
+    proc = process_logits(lgm, temp, top_k, top_p)
+    carry, sub = split_keys(keys)
+    drawn = jax.vmap(jax.random.categorical)(sub, proc).astype(jnp.int32)
+    return jnp.where(temp > 0, drawn, greedy), carry
+
+
+def _oracle_verify_tokens(logits, drafts, samp):
+    """``verify_tokens`` as it was before the ``cond``."""
+    import jax
+    import jax.numpy as jnp
+    temp, top_k, top_p, keys, mask = samp
+    rows, kp1, vocab = logits.shape
+    k = kp1 - 1
+    neg = jnp.asarray(NEG_MASK, logits.dtype)
+    lgm = logits + mask[:, None, :]
+    greedy = jnp.argmax(lgm, axis=-1).astype(jnp.int32)
+    rep = lambda x: jnp.repeat(x, kp1)
+    proc = process_logits(lgm.reshape(rows * kp1, vocab), rep(temp),
+                          rep(top_k), rep(top_p)).reshape(rows, kp1, vocab)
+    carry, sub = split_keys(keys)
+    subs = jax.vmap(lambda kk: jax.random.split(kk, 2 * kp1))(sub)
+    ukeys, ckeys = subs[:, :kp1], subs[:, kp1:]
+    probs = jax.nn.softmax(proc, axis=-1)
+    bonus = jax.vmap(jax.random.categorical)(
+        ckeys[:, k], proc[:, k]).astype(jnp.int32)
+    if k == 0:
+        chosen = jnp.where(temp[:, None] > 0, bonus[:, None], greedy)
+        return chosen, jnp.zeros((rows, 0), bool), carry
+    draft_p = jnp.take_along_axis(
+        probs[:, :k], drafts[..., None].astype(jnp.int32), axis=-1)[..., 0]
+    u = jax.vmap(jax.vmap(jax.random.uniform))(ukeys[:, :k])
+    accept_s = u < draft_p
+    resid = jnp.where(jax.nn.one_hot(drafts, vocab, dtype=bool),
+                      neg, proc[:, :k])
+    resample = jax.vmap(jax.vmap(jax.random.categorical))(
+        ckeys[:, :k], resid).astype(jnp.int32)
+    chosen_s = jnp.where(accept_s, drafts.astype(jnp.int32), resample)
+    chosen_s = jnp.concatenate([chosen_s, bonus[:, None]], axis=1)
+    sampled = (temp > 0)[:, None]
+    chosen = jnp.where(sampled, chosen_s, greedy)
+    accept = jnp.where(sampled, accept_s, greedy[:, :k] == drafts)
+    return chosen, accept, carry
+
+
+# one recipe a row: (temperature, top_k, top_p); None = a greedy row
+_RECIPES = {
+    "all_greedy": [None] * 6,
+    "mixed": [None, (0.7, 0, 0.0), None, None, (1.3, 8, 0.95), None],
+    "one_sampled": [None, None, None, (1.0, 5, 0.0), None, None],
+    "all_sampled": [(0.7, 0, 0.0), (1.0, 5, 0.0), (0.9, 0, 0.8),
+                    (1.3, 8, 0.95), (0.2, 1, 0.0), (2.0, 0, 0.5)],
+}
+_V = 61
+
+
+def _batch(kind, k, grammar_mask=False, seed=0):
+    """``(logits [rows, k+1, V], drafts [rows, k], samp)`` for one
+    recipe list; the drafts are the greedy token on even positions (so
+    some accept) and a random one on odd."""
+    import jax.numpy as jnp
+    recipes = _RECIPES[kind]
+    rows = len(recipes)
+    rng = np.random.RandomState(seed)
+    logits = (rng.randn(rows, k + 1, _V) * 2.0).astype(np.float32)
+    mask = np.zeros((rows, _V), np.float32)
+    if grammar_mask:
+        # rows 0 and 3 may emit only a few tokens, as a JSON cursor
+        # allows; the argmax of the bare logits is banned on both
+        for r in (0, 3):
+            mask[r] = NEG_MASK
+            mask[r, rng.choice(_V, size=5, replace=False)] = 0.0
+            mask[r, logits[r].argmax(-1)] = NEG_MASK
+    best = (logits + mask[:, None, :]).argmax(-1)
+    drafts = rng.randint(0, _V, size=(rows, k)).astype(np.int32)
+    drafts[:, ::2] = best[:, :k][:, ::2]
+    temp = np.array([r[0] if r else 0.0 for r in recipes], np.float32)
+    tk = np.array([r[1] if r else 0 for r in recipes], np.int32)
+    tp = np.array([r[2] if r else 0.0 for r in recipes], np.float32)
+    keys = np.stack([request_key(100 + seed + i) for i in range(rows)])
+    samp = tuple(jnp.asarray(x) for x in (temp, tk, tp, keys, mask))
+    return jnp.asarray(logits), jnp.asarray(drafts), samp
+
+
+def _primitives(jaxpr, into_cond):
+    """Names of every primitive of ``jaxpr`` and of the programs its
+    equations carry, those under a ``cond`` only if ``into_cond``."""
+    names = []
+    for eqn in jaxpr.eqns:
+        names.append(eqn.primitive.name)
+        if eqn.primitive.name == "cond" and not into_cond:
+            continue
+        for value in eqn.params.values():
+            for sub in (value if isinstance(value, (tuple, list))
+                        else (value,)):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    names += _primitives(inner, into_cond)
+    return names
+
+
+_SAMPLER_ONLY = {"sort", "cumsum", "random_bits"}
+
+
+@pytest.mark.parametrize("fn", ["sample", "verify_k0", "verify_k3"])
+def test_the_sampler_is_under_one_cond(fn):
+    """(a) Structure: one ``cond`` at the top level, and no sort,
+    cumsum or random draw anywhere outside it; the argmax and the key
+    split are outside, the sampler inside."""
+    import jax
+    k = {"sample": 0, "verify_k0": 0, "verify_k3": 3}[fn]
+    logits, drafts, samp = _batch("mixed", k)
+    if fn == "sample":
+        closed = jax.make_jaxpr(sample_tokens)(logits[:, 0], samp)
+    else:
+        closed = jax.make_jaxpr(verify_tokens)(logits, drafts, samp)
+    top = [e.primitive.name for e in closed.jaxpr.eqns]
+    assert top.count("cond") == 1, top
+    outside = set(_primitives(closed.jaxpr, into_cond=False))
+    assert not outside & _SAMPLER_ONLY, outside & _SAMPLER_ONLY
+    assert {"argmax", "random_split"} <= outside
+    inside = set(_primitives(closed.jaxpr, into_cond=True)) - outside
+    assert _SAMPLER_ONLY <= inside, inside
+
+
+@pytest.mark.parametrize("k", [0, 3])
+@pytest.mark.parametrize("grammar_mask", [False, True],
+                         ids=["zero_mask", "grammar_mask"])
+def test_all_greedy_batches_are_the_argmax_bit_for_bit(grammar_mask, k):
+    """(b) No row samples: tokens are ``argmax(logits + mask)``,
+    accepts are ``argmax == draft``, and the carried keys are the one
+    unconditional split — under jit and eagerly."""
+    import jax
+    logits, drafts, samp = _batch("all_greedy", k, grammar_mask)
+    mask = np.asarray(samp[4])
+    want = (np.asarray(logits) + mask[:, None, :]).argmax(-1)
+    want_keys = np.asarray(split_keys(samp[3])[0])
+    if grammar_mask:
+        assert (want[0] != np.asarray(logits)[0].argmax(-1)).all()
+    for wrap in (jax.jit, lambda f: f):
+        toks, carry = wrap(sample_tokens)(logits[:, 0], samp)
+        assert toks.dtype == np.int32
+        assert np.array_equal(np.asarray(toks), want[:, 0])
+        assert np.array_equal(np.asarray(carry), want_keys)
+        chosen, accept, carry = wrap(verify_tokens)(logits, drafts, samp)
+        assert chosen.dtype == np.int32 and accept.dtype == bool
+        assert np.array_equal(np.asarray(chosen), want)
+        assert accept.shape == (len(want), k)
+        assert np.array_equal(np.asarray(accept),
+                              want[:, :k] == np.asarray(drafts))
+        assert np.array_equal(np.asarray(carry), want_keys)
+
+
+@pytest.mark.parametrize("mode", ["jit", "eager"])
+@pytest.mark.parametrize("kind", ["mixed", "one_sampled", "all_sampled"])
+def test_sampled_batches_draw_what_the_inlined_chain_draws(kind, mode):
+    """(c) A batch with any sampled row computes what it computed
+    before the ``cond``: tokens and carry keys of every row, over
+    temperature, top-k, top-p and a grammar mask."""
+    import jax
+    wrap = jax.jit if mode == "jit" else (lambda f: f)
+    for seed, grammar_mask in ((0, False), (1, True)):
+        logits, _, samp = _batch(kind, 0, grammar_mask, seed)
+        toks, carry = wrap(sample_tokens)(logits[:, 0], samp)
+        want, want_carry = wrap(_oracle_sample_tokens)(logits[:, 0], samp)
+        assert np.array_equal(np.asarray(toks), np.asarray(want))
+        assert np.array_equal(np.asarray(carry), np.asarray(want_carry))
+        greedy = np.asarray(samp[0]) == 0
+        best = (np.asarray(logits[:, 0]) + np.asarray(samp[4])).argmax(-1)
+        assert np.array_equal(np.asarray(toks)[greedy], best[greedy])
+
+
+@pytest.mark.parametrize("mode", ["jit", "eager"])
+@pytest.mark.parametrize("k", [0, 3])
+@pytest.mark.parametrize("kind", ["mixed", "one_sampled", "all_sampled"])
+def test_sampled_verify_accepts_what_the_inlined_chain_accepts(kind, k,
+                                                               mode):
+    """(c) The same for the speculative verify: chosen tokens, accepts
+    and carry keys of every row equal the chain's without the ``cond``."""
+    import jax
+    wrap = jax.jit if mode == "jit" else (lambda f: f)
+    for seed, grammar_mask in ((0, False), (1, True)):
+        logits, drafts, samp = _batch(kind, k, grammar_mask, seed)
+        got = wrap(verify_tokens)(logits, drafts, samp)
+        want = wrap(_oracle_verify_tokens)(logits, drafts, samp)
+        for g, w in zip(got, want):
+            assert g.shape == w.shape and g.dtype == w.dtype
+            assert np.array_equal(np.asarray(g), np.asarray(w))
+    if k and kind == "all_sampled":
+        accept = np.asarray(got[1])
+        assert accept.any() and not accept.all()
+
+
+@pytest.mark.parametrize("kind", ["all_greedy", "mixed"])
+def test_megastep_tokens_are_the_inlined_chains(kind, monkeypatch):
+    """(d) Through ``decode_megastep_paged`` on ``gpt2-tiny`` (the
+    ``cond`` sits in the scan's body): a megastep engine commits what
+    an engine whose step was built around the inlined chain commits,
+    for an all-greedy and for a mixed batch."""
+    from paddle_tpu.models.gpt import gpt2_tiny
+
+    def serve(oracle):
+        pt.seed(11)
+        m = gpt2_tiny()
+        m.eval()
+        if oracle:      # the step entry imports the name when it is built
+            monkeypatch.setattr(decoding, "sample_tokens",
+                                _oracle_sample_tokens)
+        eng = ServingEngine(m, max_slots=4, max_len=32, buckets=[8],
+                            max_queue=8, block_size=4, megastep=4)
+        rng = np.random.RandomState(3)
+        reqs = []
+        for i in range(4):
+            # the sampled rows are the short ones: the last megasteps
+            # of a mixed run are all greedy again
+            sampled = kind == "mixed" and i in (0, 1)
+            kw = (dict(temperature=0.9, top_k=6 * i, top_p=0.9,
+                       seed=40 + i) if sampled else {})
+            reqs.append(eng.submit(rng.randint(1, 1024, size=5).tolist(),
+                                   max_new_tokens=4 + 3 * i, **kw))
+        eng.run_until_idle()
+        monkeypatch.undo()
+        assert all(r.state == "done" for r in reqs)
+        st = eng.stats()
+        assert st["sampler_dispatches"] > 0
+        return [r.output_ids for r in reqs], st
+
+    got, st = serve(oracle=False)
+    want, _ = serve(oracle=True)
+    assert got == want
+    if kind == "all_greedy":
+        assert st["sampler_skipped"] == st["sampler_dispatches"]
+    else:
+        assert 0 < st["sampler_skipped"] < st["sampler_dispatches"]
+
+
+@pytest.mark.parametrize("spec_tokens", [0, 2])
+def test_engine_counts_the_dispatches_that_skipped_the_sampler(
+        spec_tokens):
+    """(e) ``sampler_skipped / sampler_dispatches``: 1 for an
+    all-greedy run; one sampled request among greedy ones takes it down
+    by exactly the dispatches that request was live in; and one decode
+    (verify) executable served both runs."""
+    from paddle_tpu import monitor
+    from paddle_tpu.models.generation import (decode_step_paged,
+                                              verify_step_paged)
+    pt.seed(7)
+    m = GPTForCausalLM(GPTConfig(
+        vocab_size=VOCAB, max_position_embeddings=64, hidden_size=32,
+        num_layers=2, num_heads=4, ffn_hidden_size=64))
+    m.eval()
+    eng = _engine(m, max_slots=4, spec_tokens=spec_tokens)
+    stat0 = monitor.stat_get("STAT_serving_sampler_skipped")
+    _run(eng, _prompts((4, 6, 5)))
+    st = eng.stats()
+    assert st["sampler_dispatches"] > 0
+    assert st["sampler_skipped"] == st["sampler_dispatches"]
+    assert monitor.stat_get("STAT_serving_sampler_skipped") - stat0 == \
+        st["sampler_skipped"]
+
+    greedy = [eng.submit(p, max_new_tokens=24)
+              for p in _prompts((4, 6, 5), seed=1)]
+    sampled = eng.submit(_prompts((5,), seed=2)[0], max_new_tokens=4,
+                         seed=9, **SAMPLED)
+    eng.run_until_idle()
+    assert all(r.state == "done" for r in greedy + [sampled])
+    st2 = eng.stats()
+    ran_sampler = ((st2["sampler_dispatches"] - st["sampler_dispatches"])
+                   - (st2["sampler_skipped"] - st["sampler_skipped"]))
+    # the first token comes from the prefill, the rest one (or, with
+    # drafts accepted, up to K+1) a dispatch
+    steps = len(sampled.tokens) - 1
+    if spec_tokens:
+        assert -(-steps // (spec_tokens + 1)) <= ran_sampler <= steps
+    else:
+        assert ran_sampler == steps == 3
+    assert st2["sampler_skipped"] > st["sampler_skipped"]
+    assert monitor.stat_get("STAT_serving_sampler_skipped") - stat0 == \
+        st2["sampler_skipped"]
+    entry = (verify_step_paged(m, spec_tokens) if spec_tokens
+             else decode_step_paged(m))
+    assert entry["traces"]["count"] == 1

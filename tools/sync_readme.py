@@ -528,6 +528,16 @@ def render_serving_block():
         "the request — engine restarts, replica routing and the",
         "disaggregated fleet replay the same bytes, and `temperature",
         "0` rows stay bit-identical to the pre-sampling engine.",
+        "A step whose live rows are all greedy does not pay for the",
+        "sampler: the top-k / top-p chain and the draws sit under one",
+        "`lax.cond` on \"does any row sample\" inside the one compiled",
+        "step (a device value read off the step's own `samp` input: no",
+        "flag, no second executable), so such a step runs the argmax",
+        "and the key split alone, and one sampled row in the batch",
+        "brings the chain back for every row, as before.",
+        "`STAT_serving_sampler_skipped` counts the decode/verify",
+        "dispatches whose batch was all greedy, and `engine.stats()`",
+        "gives `sampler_dispatches` / `sampler_skipped`.",
         "Speculative decoding verifies sampled rows by rejection",
         "sampling: the committed-token law matches non-speculative",
         "sampling exactly (greedy rows keep the prefix-match rule,",
