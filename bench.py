@@ -1,6 +1,5 @@
 #!/usr/bin/env python
-"""Driver benchmark: one train step (default ``gpt2-1p1b``) or serving
-run on the chip.
+"""Driver benchmark: one train step (default ``gpt2-1p1b``) on the chip.
 
 One process for each chip: this parent imports only numpy and the
 standard library — never jax, never paddle_tpu — and runs every attempt
@@ -360,835 +359,6 @@ def child_main_resnet(batch: int, img: int, steps: int) -> int:
     return 0
 
 
-def child_main_serving(batch: int, seq: int, steps: int) -> int:
-    """BENCH_MODEL=serving: continuous-batching decode throughput.
-
-    ``batch`` = engine slots, ``seq`` = per-slot KV capacity, ``steps``
-    = requests per slot (steps*batch mixed-length requests total).
-    Reports generated tokens/s plus p50/p99 submit-to-finish latency
-    and TTFT/TPOT percentiles; ``vs_baseline`` is the speedup over
-    serving the same requests one at a time through ``greedy_search``
-    (the pre-engine path), unless BENCH_SERVING_COMPARE=0 skips that
-    run. With BENCH_SERVING_SPEC=K (default 4; 0 disables) it also
-    serves a repetitive-suffix workload — where the n-gram self-drafter
-    earns its keep — once without and once with speculative decoding
-    and reports the spec_* block (tokens/s, acceptance rate, speedup).
-    Unless BENCH_SERVING_PAGED=0, it also serves a shared-system-prompt
-    workload through a dense engine and a paged engine holding the SAME
-    total KV pool bytes and reports the paged block: KV bytes/request,
-    prefix-cache hit rate, and max concurrent requests (the paged
-    engine packs more in-flight requests into the fixed pool because
-    shared prefix blocks are stored once and each request pays only
-    its actual need, not a full max_len row).
-
-    Unless BENCH_SERVING_ATTN=0, two more paged comparisons run:
-
-    - FLAGS_serving_attn_impl pallas vs xla on the same workload (the
-      fused paged-decode kernel vs the gather-compose reference). The
-      token streams must match exactly; the >=1.5x tokens/s target is
-      asserted on TPU only — on CPU the kernel runs under the Pallas
-      interpreter, so only parity is meaningful there.
-    - FLAGS_serving_kv_dtype int8 vs f32 at EQUAL KV pool bytes: the
-      int8 pool holds ~4x the blocks, so the engine packs >=1.8x the
-      concurrent requests into the same memory (asserted; concurrency
-      is a scheduling fact, valid on any backend).
-
-    Unless BENCH_SERVING_MEGASTEP is 0/1 (default 8), the megastep
-    block serves a decode-heavy workload (short uniform prompts, long
-    decodes) through a 2-replica fleet twice — the serial per-token
-    loop vs device-resident decode megasteps
-    (FLAGS_serving_megastep=N, router stepping from a 2-thread pool) —
-    asserts exact token parity and a >=1.2x goodput win on every
-    backend: the win is the removed per-token host loop, not device
-    speed. Dispatch-ahead stays off in the timed arm (it only pays
-    under async dispatch, i.e. on TPU).
-    BENCH_SERVING_MEGASTEP_ASSERT=0 reports without the gate.
-
-    Unless BENCH_SERVING_TP=0, the tp block compares the same workload
-    through a mesh-sharded tensor-parallel engine (1xM model split when
-    >=2 devices exist, the degenerate 1x1 mesh otherwise) and a
-    2-replica ReplicaRouter. Token parity with the single-device engine
-    is asserted on every backend; the >=1.5x TP scaling target only on
-    real multi-chip TPU (virtual CPU devices share the same cores).
-    """
-    import jax
-
-    import paddle_tpu as pt
-    from paddle_tpu.models import GPT_CONFIGS, GPTForCausalLM
-    from paddle_tpu.models.generation import greedy_search
-    from paddle_tpu.serving import ServingEngine
-
-    dev = jax.devices()[0]
-    gpt = os.environ.get("BENCH_SERVING_GPT", "gpt2-medium")
-    new_tokens = int(os.environ.get("BENCH_SERVING_NEW_TOKENS", "32"))
-    spec_k = int(os.environ.get("BENCH_SERVING_SPEC", "4"))
-    nreq = steps * batch
-    try:
-        pt.seed(0)
-        cfg = GPT_CONFIGS[gpt]
-        model = GPTForCausalLM(cfg)
-        model.eval()
-        rng = np.random.RandomState(0)
-        max_prompt = max(4, min(64, seq - new_tokens - spec_k))
-
-        def prompts(n, r):
-            return [r.randint(1, cfg.vocab_size,
-                              size=r.randint(4, max_prompt + 1)).tolist()
-                    for _ in range(n)]
-
-        def rep_prompts(n, r):
-            # repetitive-suffix workload: periodic token patterns the
-            # n-gram drafter predicts near-perfectly (code/templated
-            # text analog)
-            out = []
-            for _ in range(n):
-                period = r.randint(2, 5)
-                pat = r.randint(1, cfg.vocab_size, size=period).tolist()
-                ln = r.randint(8, max_prompt + 1)
-                out.append((pat * (ln // period + 1))[:ln])
-            return out
-
-        def serve(ps, k=0):
-            eng = ServingEngine(model, max_slots=batch, max_len=seq,
-                                max_queue=len(ps) + batch,
-                                spec_tokens=k)
-            reqs = [eng.submit(p, max_new_tokens=new_tokens) for p in ps]
-            eng.run_until_idle()
-            return reqs, eng
-
-        # warmup fleet: every prefill bucket + the decode step compile
-        # outside the timed window
-        serve(prompts(2 * batch, np.random.RandomState(1)))
-        ps = prompts(nreq, rng)
-        t0 = time.perf_counter()
-        reqs, eng = serve(ps)
-        dt = time.perf_counter() - t0
-        assert all(r.state == "done" for r in reqs)
-        toks = sum(len(r.tokens) for r in reqs)
-        lat = sorted(r.latency for r in reqs)
-        eng_stats = eng.stats()
-        seq_dt = None
-        if os.environ.get("BENCH_SERVING_COMPARE", "1") != "0":
-            sub = ps[:batch]   # sequential sample; compiled b=1 warmup
-            greedy_search(model, np.asarray([sub[0]]),
-                          max_new_tokens=new_tokens, cache_len=seq)
-            t0 = time.perf_counter()
-            for p in sub:
-                greedy_search(model, np.asarray([p]),
-                              max_new_tokens=new_tokens, cache_len=seq)
-            seq_dt = (time.perf_counter() - t0) / len(sub)
-        spec = None
-        if spec_k > 0:
-            rep = rep_prompts(nreq, np.random.RandomState(2))
-            # warm the verify compile outside the timed window
-            serve(rep_prompts(batch, np.random.RandomState(3)), k=spec_k)
-            t0 = time.perf_counter()
-            base_reqs, _ = serve(rep)
-            base_dt = time.perf_counter() - t0
-            t0 = time.perf_counter()
-            spec_reqs, spec_eng = serve(rep, k=spec_k)
-            spec_dt = time.perf_counter() - t0
-            for a, b in zip(base_reqs, spec_reqs):
-                assert a.output_ids == b.output_ids, \
-                    "speculative decode diverged from plain greedy"
-            base_toks = sum(len(r.tokens) for r in base_reqs)
-            spec_toks = sum(len(r.tokens) for r in spec_reqs)
-            st = spec_eng.stats()
-            spec = {
-                "spec_tokens": spec_k,
-                "tokens_per_sec": round(spec_toks / spec_dt, 1),
-                "nonspec_tokens_per_sec": round(base_toks / base_dt, 1),
-                "speedup": round((spec_toks / spec_dt) /
-                                 (base_toks / base_dt), 2),
-                "acceptance_rate": st.get("spec_acceptance_rate"),
-            }
-        paged_cmp = None
-        if os.environ.get("BENCH_SERVING_PAGED", "1") != "0":
-            # shared-system-prompt workload: one long shared prefix +
-            # short unique user suffixes, served through a dense engine
-            # and a paged engine holding the SAME total KV pool bytes
-            # (batch full max_len rows == batch*blocks_per_row blocks)
-            bs = int(os.environ.get("BENCH_SERVING_BLOCK", "8"))
-            blocks_per_row = -(-seq // bs)
-            pool_blocks = batch * blocks_per_row + 1   # +1: trash block
-            sys_len = min(max_prompt - 2, 4 * bs)
-            user_len = 2
-            mnt = min(new_tokens, seq - sys_len - user_len)
-            r = np.random.RandomState(4)
-            sysp = r.randint(1, cfg.vocab_size, size=sys_len).tolist()
-            nshared = max(nreq, 4 * batch)
-            shared_ps = [sysp + r.randint(1, cfg.vocab_size,
-                                          size=user_len).tolist()
-                         for _ in range(nshared)]
-
-            def serve_peak(paged, **kw):
-                eng = ServingEngine(model, max_len=seq,
-                                    max_queue=nshared + batch,
-                                    paged=paged, **kw)
-                rs = [eng.submit(p, max_new_tokens=mnt)
-                      for p in shared_ps]
-                peak = 0
-                while eng._queue or eng._active:
-                    eng.step()
-                    peak = max(peak, len(eng._active))
-                assert all(rq.state == "done" for rq in rs)
-                return rs, eng, peak
-
-            d_reqs, d_eng, d_peak = serve_peak(False, max_slots=batch)
-            p_reqs, p_eng, p_peak = serve_peak(
-                True, max_slots=4 * batch, block_size=bs,
-                num_blocks=pool_blocks, prefix_cache=True)
-            for a, b in zip(d_reqs, p_reqs):
-                assert a.output_ids == b.output_ids, \
-                    "paged shared-prefix serve diverged from dense"
-            pos_bytes = (cfg.num_layers * 2 * cfg.num_heads *
-                         (cfg.hidden_size // cfg.num_heads) * 4)
-            dense_bpr = seq * pos_bytes        # one full row per request
-            paged_bpr = (p_eng.cache.blocks_allocated_total * bs *
-                         pos_bytes) / nshared
-            st = p_eng.stats()
-            paged_cmp = {
-                "workload": f"{sys_len}-token shared system prompt + "
-                            f"{user_len}-token user suffix x{nshared}",
-                "pool_kv_positions": (pool_blocks - 1) * bs,
-                "block_size": bs,
-                "dense_kv_bytes_per_request": dense_bpr,
-                "paged_kv_bytes_per_request": round(paged_bpr),
-                "kv_bytes_saved": round(1 - paged_bpr / dense_bpr, 3),
-                "dense_max_concurrent": d_peak,
-                "paged_max_concurrent": p_peak,
-                "concurrency_gain": round(p_peak / max(d_peak, 1), 2),
-                "prefix_hit_rate": st.get("prefix_hit_rate"),
-                "prefix_hit_requests": st.get("prefix_hit_requests"),
-            }
-        attn_cmp = None
-        kv_quant_cmp = None
-        if os.environ.get("BENCH_SERVING_ATTN", "1") != "0":
-            bs = int(os.environ.get("BENCH_SERVING_BLOCK", "8"))
-            on_tpu = getattr(dev, "platform", "cpu") == "tpu"
-
-            def serve_paged(ps, impl, kv_dtype="f32", slots=None,
-                            num_blocks=None, mnt=new_tokens):
-                pt.set_flags({"serving_attn_impl": impl,
-                              "serving_kv_dtype": kv_dtype})
-                eng = ServingEngine(
-                    model, max_slots=slots or batch, max_len=seq,
-                    max_queue=len(ps) + (slots or batch), paged=True,
-                    block_size=bs, num_blocks=num_blocks,
-                    prefix_cache=False)
-                rs = [eng.submit(p, max_new_tokens=mnt) for p in ps]
-                peak = 0
-                while eng._queue or eng._active:
-                    eng.step()
-                    peak = max(peak, len(eng._active))
-                assert all(rq.state == "done" for rq in rs)
-                return rs, eng, peak
-
-            try:
-                # -- pallas fused kernel vs XLA gather-compose --------
-                r = np.random.RandomState(6)
-                attn_ps = prompts(nreq, r)
-                warm = prompts(batch, np.random.RandomState(7))
-                serve_paged(warm, "xla")       # compile outside window
-                t0 = time.perf_counter()
-                x_reqs, _, _ = serve_paged(attn_ps, "xla")
-                x_dt = time.perf_counter() - t0
-                serve_paged(warm, "pallas")
-                t0 = time.perf_counter()
-                f_reqs, _, _ = serve_paged(attn_ps, "pallas")
-                f_dt = time.perf_counter() - t0
-                for a, b2 in zip(x_reqs, f_reqs):
-                    assert a.output_ids == b2.output_ids, \
-                        "pallas paged decode diverged from the XLA " \
-                        "reference"
-                x_toks = sum(len(rq.tokens) for rq in x_reqs)
-                f_toks = sum(len(rq.tokens) for rq in f_reqs)
-                attn_speedup = (f_toks / f_dt) / (x_toks / x_dt)
-                if on_tpu and os.environ.get(
-                        "BENCH_SERVING_ATTN_ASSERT", "1") != "0":
-                    assert attn_speedup >= 1.5, (
-                        f"fused paged kernel speedup {attn_speedup:.2f}x "
-                        "< 1.5x target")
-                attn_cmp = {
-                    "xla_tokens_per_sec": round(x_toks / x_dt, 1),
-                    "pallas_tokens_per_sec": round(f_toks / f_dt, 1),
-                    "speedup": round(attn_speedup, 2),
-                    "token_parity": True,
-                    "interpret_mode": not on_tpu,
-                }
-
-                # -- int8 vs f32 concurrency at EQUAL pool bytes ------
-                hd = cfg.hidden_size // cfg.num_heads
-                f32_block_bytes = cfg.num_heads * bs * hd * 4
-                int8_block_bytes = cfg.num_heads * (bs * hd + 4)
-                L = min(max_prompt, 2 * bs)       # uniform prompt length
-                mnt8 = min(new_tokens, seq - L)
-                blocks_per_req = -(-(L + mnt8) // bs)
-                f32_blocks = batch * blocks_per_req + 1
-                int8_blocks = (f32_blocks - 1) * f32_block_bytes \
-                    // int8_block_bytes + 1
-                r = np.random.RandomState(8)
-                nq8 = max(nreq, 6 * batch)
-                q_ps = [r.randint(1, cfg.vocab_size, size=L).tolist()
-                        for _ in range(nq8)]
-                slots8 = nq8                      # pool is the binding cap
-                f_out, _, f_peak = serve_paged(
-                    q_ps, "xla", "f32", slots=slots8,
-                    num_blocks=f32_blocks, mnt=mnt8)
-                q_out, q_eng, q_peak = serve_paged(
-                    q_ps, "xla", "int8", slots=slots8,
-                    num_blocks=int8_blocks, mnt=mnt8)
-                gain = q_peak / max(f_peak, 1)
-                assert gain >= 1.8, (
-                    f"int8 concurrency gain {gain:.2f}x < 1.8x at equal "
-                    f"pool bytes ({f_peak} -> {q_peak} concurrent)")
-                parity = sum(a.output_ids == b2.output_ids
-                             for a, b2 in zip(f_out, q_out))
-                kv_quant_cmp = {
-                    "pool_bytes": f32_blocks * f32_block_bytes,
-                    "f32_blocks": f32_blocks,
-                    "int8_blocks": int8_blocks,
-                    "f32_max_concurrent": f_peak,
-                    "int8_max_concurrent": q_peak,
-                    "concurrency_gain": round(gain, 2),
-                    "token_parity_requests": f"{parity}/{nq8}",
-                    "kv_quant_max_abs_err":
-                        q_eng.stats().get("kv_quant_max_abs_err"),
-                }
-            finally:
-                pt.set_flags({"serving_attn_impl": "xla",
-                              "serving_kv_dtype": "f32"})
-        mega_cmp = None
-        ms_n = int(os.environ.get("BENCH_SERVING_MEGASTEP", "8"))
-        if ms_n > 1:
-            # -- decode megasteps + threaded dispatch vs serial N=1 --
-            # the same workload through a 2-replica fleet twice: the
-            # serial per-token loop (megastep=1) and device-resident
-            # megasteps (N decode iterations per compiled dispatch,
-            # one host commit per megastep) with the router stepping
-            # replicas from a thread pool. Token streams must match
-            # exactly; the >=1.2x goodput gate holds on CPU too — the
-            # win is removed Python/host-commit overhead, not device
-            # speed (BENCH_SERVING_MEGASTEP_ASSERT=0 reports without
-            # asserting; BENCH_SERVING_MEGASTEP=0/1 skips the block).
-            from paddle_tpu.serving import ReplicaRouter
-            # decode-heavy geometry: short uniform prompts, long
-            # decodes — the regime the megastep exists for (the host
-            # loop runs once per token; prefill-heavy mixes measure
-            # prefill, which megasteps don't touch). Sized
-            # independently of --seq so the gate is stable across
-            # bench geometries.
-            ms_slots = min(batch, 4)
-            ms_mnt = max(new_tokens, 48)
-            ms_len = max(seq, 8 + ms_mnt + 8)
-            r9 = np.random.RandomState(9)
-            ms_ps = [r9.randint(1, cfg.vocab_size, size=8).tolist()
-                     for _ in range(4 * ms_slots)]
-
-            def serve_fleet():
-                rt = ReplicaRouter(model, n_replicas=2,
-                                   max_slots=ms_slots, max_len=ms_len,
-                                   max_queue=len(ms_ps) + ms_slots)
-                rs = [rt.submit(p, max_new_tokens=ms_mnt)
-                      for p in ms_ps]
-                rt.run_until_idle()
-                assert all(rq.state == "done" for rq in rs)
-                return rs, rt
-
-            def timed_arm(flags):
-                # set_flags bumps the flag-plane version (invalidating
-                # every step_entry), so it runs ONCE per arm; the warm
-                # pass right after it pays every compile, leaving the
-                # timed pass compile-free
-                pt.set_flags(flags)
-                serve_fleet()[1].stop()
-                t0 = time.perf_counter()
-                rs, rt = serve_fleet()
-                dt_arm = time.perf_counter() - t0
-                rt.stop()
-                return rs, dt_arm
-
-            try:
-                s_reqs, s_dt = timed_arm(
-                    {"serving_megastep": 1,
-                     "serving_dispatch_ahead": False,
-                     "serving_dispatch_threads": 0})
-                # dispatch-ahead stays OFF in the timed arm: it
-                # overlaps commit with megastep k+1 only on async
-                # backends (TPU); under synchronous CPU dispatch the
-                # speculative call blocks before the commit, a wash
-                m_reqs, m_dt = timed_arm(
-                    {"serving_megastep": ms_n,
-                     "serving_dispatch_ahead": False,
-                     "serving_dispatch_threads": 2})
-            finally:
-                pt.set_flags({"serving_megastep": 1,
-                              "serving_dispatch_ahead": False,
-                              "serving_dispatch_threads": 0})
-            for a, b2 in zip(s_reqs, m_reqs):
-                assert a.output_ids == b2.output_ids, \
-                    "megastep decode diverged from the serial " \
-                    "per-token loop"
-            s_toks = sum(len(rq.tokens) for rq in s_reqs)
-            m_toks = sum(len(rq.tokens) for rq in m_reqs)
-            ms_speedup = (m_toks / m_dt) / (s_toks / s_dt)
-            if os.environ.get(
-                    "BENCH_SERVING_MEGASTEP_ASSERT", "1") != "0":
-                assert ms_speedup >= 1.2, (
-                    f"megastep={ms_n}+threaded goodput speedup "
-                    f"{ms_speedup:.2f}x < 1.2x over the serial "
-                    "per-token fleet")
-            mega_cmp = {
-                "megastep": ms_n,
-                "dispatch_threads": 2,
-                "dispatch_ahead": False,
-                "slots": ms_slots,
-                "new_tokens": ms_mnt,
-                "serial_tokens_per_sec": round(s_toks / s_dt, 1),
-                "megastep_tokens_per_sec": round(m_toks / m_dt, 1),
-                "speedup": round(ms_speedup, 2),
-                "token_parity": True,
-            }
-        tp_cmp = None
-        if os.environ.get("BENCH_SERVING_TP", "1") != "0":
-            # mesh-sharded serving: the same workload through a
-            # tensor-parallel engine (params + paged KV pool
-            # head-sharded, steps under pjit) and a 2-replica
-            # ReplicaRouter. Token parity vs the single-device engine
-            # is asserted everywhere; the >=1.5x scaling target only on
-            # real multi-chip TPU — virtual CPU "devices" share the
-            # same cores, so GSPMD there is pure overhead by design.
-            from paddle_tpu.distributed.sharding import serving_mesh
-            from paddle_tpu.serving import ReplicaRouter
-            n_dev = len(jax.devices())
-            mp = 2 if (n_dev >= 2 and cfg.num_heads % 2 == 0) else 1
-            mesh = serving_mesh(1, mp)
-
-            def serve_tp(ps, m):
-                eng = ServingEngine(model, max_slots=batch, max_len=seq,
-                                    max_queue=len(ps) + batch, mesh=m)
-                reqs = [eng.submit(p, max_new_tokens=new_tokens)
-                        for p in ps]
-                eng.run_until_idle()
-                return reqs, eng
-
-            tp_ps = prompts(nreq, np.random.RandomState(4))
-            # the attn/kv_quant phases above churned flags (bumping the
-            # step caches' flags version): warm both paths on the exact
-            # timed workload so every bucket's compile lands outside
-            # the timed windows (engines are fresh per serve, so the
-            # warm run can't leak prefix state into the timed one)
-            serve(tp_ps)
-            t0 = time.perf_counter()
-            base_tp, _ = serve(tp_ps)
-            base_tp_dt = time.perf_counter() - t0
-            serve_tp(tp_ps, mesh)
-            t0 = time.perf_counter()
-            mesh_tp, _ = serve_tp(tp_ps, mesh)
-            mesh_tp_dt = time.perf_counter() - t0
-            for a, b2 in zip(base_tp, mesh_tp):
-                assert a.output_ids == b2.output_ids, \
-                    "mesh-sharded engine diverged from single-device"
-            tp_toks = sum(len(r.tokens) for r in mesh_tp)
-            scaling = ((tp_toks / mesh_tp_dt) /
-                       (sum(len(r.tokens) for r in base_tp) / base_tp_dt))
-            on_tpu = getattr(dev, "platform", "") == "tpu"
-            if on_tpu and mp > 1:
-                assert scaling >= 1.5, (
-                    f"TP scaling {scaling:.2f}x < 1.5x on a real "
-                    f"{mp}-chip model split")
-            rt = ReplicaRouter(model, n_replicas=2, max_slots=batch,
-                               max_len=seq, max_queue=nreq + batch)
-            t0 = time.perf_counter()
-            rt_reqs = [rt.submit(p, max_new_tokens=new_tokens)
-                       for p in tp_ps]
-            rt.run_until_idle()
-            rt_dt = time.perf_counter() - t0
-            assert all(r.state == "done" for r in rt_reqs)
-            tp_cmp = {
-                "mesh_shape": [1, mp],
-                "devices": n_dev,
-                "tokens_per_sec": round(tp_toks / mesh_tp_dt, 1),
-                "single_device_tokens_per_sec":
-                    round(sum(len(r.tokens) for r in base_tp)
-                          / base_tp_dt, 1),
-                "scaling": round(scaling, 2),
-                "token_parity": True,
-                "scaling_asserted": bool(on_tpu and mp > 1),
-                "router": {
-                    "replicas": 2,
-                    "tokens_per_sec": round(
-                        sum(len(r.tokens) for r in rt_reqs) / rt_dt, 1),
-                    "routed_per_replica": [len(e._all)
-                                           for e in rt.engines],
-                },
-            }
-    except Exception as e:
-        if is_oom(e):
-            sys.stderr.write("OOM: " + str(e)[:300] + "\n")
-            return OOM_RC
-        raise
-
-    tokens_per_sec = toks / dt
-    req_dt = dt / nreq   # engine wall time amortized per request
-    speedup = round(seq_dt / req_dt, 2) if seq_dt else 1.0
-    out = {
-        "metric": "serving_tokens_per_sec",
-        "value": round(tokens_per_sec, 1),
-        "unit": "tokens/s",
-        "vs_baseline": speedup,   # vs one-request-at-a-time greedy
-        "p50_latency_ms": round(lat[len(lat) // 2] * 1000, 1),
-        "p99_latency_ms": round(
-            lat[min(int(len(lat) * 0.99), len(lat) - 1)] * 1000, 1),
-        "ttft_p50_ms": eng_stats["ttft_p50_ms"],
-        "ttft_p99_ms": eng_stats["ttft_p99_ms"],
-        "tpot_p50_ms": eng_stats["tpot_p50_ms"],
-        "tpot_p99_ms": eng_stats["tpot_p99_ms"],
-        "requests": nreq, "slots": batch, "max_len": seq,
-        "new_tokens": new_tokens, "model": gpt,
-        "device": getattr(dev, "device_kind", str(dev)),
-    }
-    if spec is not None:
-        out["spec"] = spec
-    if paged_cmp is not None:
-        out["paged"] = paged_cmp
-    if attn_cmp is not None:
-        out["attn"] = attn_cmp
-    if kv_quant_cmp is not None:
-        out["kv_quant"] = kv_quant_cmp
-    if mega_cmp is not None:
-        out["megastep"] = mega_cmp
-    if tp_cmp is not None:
-        out["tp"] = tp_cmp
-    # full observability snapshot (counters + histogram percentiles +
-    # compile records, never raw samples) rides along in BENCH_*.json
-    from paddle_tpu import observability
-    out["observability"] = observability.snapshot()
-    print(json.dumps(out))
-    return 0
-
-
-def child_main_loadgen(batch: int, seq: int, steps: int) -> int:
-    """BENCH_MODEL=loadgen: goodput under SLO on open-loop traffic.
-
-    ``batch`` = engine slots, ``seq`` = per-slot KV capacity, ``steps``
-    scales the arrival window (seconds). Three phases over the SAME
-    seeded bursty arrival trace, all on gpt2-tiny (override with
-    BENCH_SERVING_GPT):
-
-    - calibrate: measure engine capacity (saturated batch drain) and
-      calm TTFT; the SLO is 3x calm p50 TTFT, the offered rate is
-      BENCH_LOADGEN_OVERLOAD x capacity (default 3 — real overload);
-    - phase A (baseline): depth-only admission with a deep queue,
-      goodput scored post-hoc against the SLO — the PR 9 behaviour;
-    - phase B (SLO-aware): predictive admission with costs pinned to
-      the calibrated values, same trace. Gate: goodput_B >= 1.2x
-      goodput_A (shedding doomed work early must buy real goodput),
-      and ZERO new serving compiles vs phase A — admission is
-      host-side. BENCH_LOADGEN_GATE=0 reports without asserting;
-    - phase C (chaos crossover): the same SLO engine under
-      FLAGS_fault_spec submit/alloc faults — goodput degrades but
-      stays > 0, zero leaked KV blocks, zero unhandled exceptions,
-      every lost request accounted as a shed;
-    - phase D (disagg vs symmetric): the same trace through a
-      3-replica symmetric ReplicaRouter and through a 1 prefill x
-      2 decode DisaggRouter — equal worker count, identical
-      geometry. Everywhere: zero leaks, zero exceptions, and ZERO
-      new compiles (both topologies share the model's step cache).
-      On real TPU hardware the role split must also win TTFT p95
-      (prefill batches no longer stall running decodes); on CPU
-      the timings are reported without a win gate.
-
-    ``vs_baseline`` is goodput_B / goodput_A.
-    """
-    import jax
-
-    import paddle_tpu as pt
-    from paddle_tpu import observability
-    from paddle_tpu.models import GPT_CONFIGS, GPTForCausalLM
-    from paddle_tpu.resilience import fault_scope
-    from paddle_tpu.serving import ServingEngine
-    from tools.loadgen import LoadGen, warmup
-
-    dev = jax.devices()[0]
-    gpt = os.environ.get("BENCH_SERVING_GPT", "gpt2-tiny")
-    seed = int(os.environ.get("BENCH_LOADGEN_SEED", "0"))
-    overload = float(os.environ.get("BENCH_LOADGEN_OVERLOAD", "3"))
-    duration = float(os.environ.get("BENCH_LOADGEN_DURATION",
-                                    str(max(1, steps))))
-    gate = os.environ.get("BENCH_LOADGEN_GATE", "1") == "1"
-    fault_spec = os.environ.get(
-        "BENCH_LOADGEN_FAULT_SPEC",
-        "serving.submit:skip@0.1;serving.alloc:skip@0.05")
-    buckets = [max(4, seq // 4), max(8, seq // 2)]
-    pt.seed(0)
-    cfg = GPT_CONFIGS[gpt]
-    model = GPTForCausalLM(cfg)
-    model.eval()
-    eng_kw = dict(max_slots=batch, max_len=seq, buckets=buckets,
-                  max_queue=64)
-    lo = 4
-    hi = max(lo, buckets[0] - 1)   # fresh prompts stay in bucket 0
-    lg_kw = dict(mode="bursty", rate=1.0, duration=duration, seed=seed,
-                 vocab_size=cfg.vocab_size, prompt_tokens=(lo, hi),
-                 new_tokens=(2, 8), priority_mix={0: 0.2, 1: 0.6,
-                                                  2: 0.2})
-
-    def serving_compiles():
-        return {site: c["count"]
-                for site, c in observability.compiles().items()
-                if site.startswith(("serving_", "decode_", "verify_"))}
-
-    try:
-        # -- calibrate: capacity + calm latency + step costs ----------
-        cal = ServingEngine(model, **eng_kw)
-        warmup(cal)
-        rng = np.random.RandomState(seed)
-        calm = []
-        for _ in range(4):        # calm TTFT: one request at a time
-            r = cal.submit(rng.randint(1, cfg.vocab_size,
-                                       size=6).tolist(),
-                           max_new_tokens=4)
-            cal.run_until_idle()
-            calm.append(r.ttft * 1e3)
-        sat = [cal.submit(rng.randint(1, cfg.vocab_size,
-                                      size=rng.randint(lo, hi + 1)
-                                      ).tolist(),
-                          max_new_tokens=4) for _ in range(8 * batch)]
-        t0 = time.perf_counter()
-        cal.run_until_idle()
-        capacity = len(sat) / (time.perf_counter() - t0)
-        slo_ms = max(25.0, 3.0 * float(np.median(calm)))
-        prefill_pin = cal._prefill_cost_ms(buckets[0]) or 1.0
-        tpot_pin = cal._tpot_cost_ms() or 0.5
-        lg_kw["rate"] = max(2.0, overload * capacity)
-
-        # -- phase A: depth-only, scored post-hoc against the SLO -----
-        eng_a = ServingEngine(model, **eng_kw)
-        warmup(eng_a)
-        rep_a = LoadGen(**lg_kw).run(eng_a, slo_ttft_ms=slo_ms)
-        compiles_a = serving_compiles()
-
-        # -- phase B: SLO-aware admission, same trace -----------------
-        eng_b = ServingEngine(model, slo_ttft_ms=slo_ms,
-                              slo_prefill_ms=prefill_pin,
-                              slo_tpot_ms=tpot_pin, **eng_kw)
-        warmup(eng_b)
-        rep_b = LoadGen(**lg_kw).run(eng_b)
-        compiles_b = serving_compiles()
-        assert compiles_b == compiles_a, (
-            f"SLO-aware admission must add ZERO compiles:\n"
-            f"  phase A {compiles_a}\n  phase B {compiles_b}")
-        goodput_a = rep_a["goodput_per_s"] or 0.0
-        goodput_b = rep_b["goodput_per_s"] or 0.0
-        ratio = round(goodput_b / goodput_a, 2) if goodput_a else None
-        if gate:
-            assert goodput_a > 0, rep_a
-            assert goodput_b >= 1.2 * goodput_a, (
-                f"SLO-aware goodput {goodput_b:.2f}/s < 1.2x depth-only "
-                f"{goodput_a:.2f}/s at offered {lg_kw['rate']:.1f}/s")
-
-        # -- phase C: chaos crossover ---------------------------------
-        with fault_scope(fault_spec, seed=seed):
-            eng_c = ServingEngine(model, slo_ttft_ms=slo_ms,
-                                  slo_prefill_ms=prefill_pin,
-                                  slo_tpot_ms=tpot_pin, **eng_kw)
-            warmup(eng_c)
-            rep_c = LoadGen(**lg_kw).run(eng_c)
-        goodput_c = rep_c["goodput_per_s"] or 0.0
-        if gate:
-            assert rep_c["exceptions"] == 0, rep_c
-            assert rep_c["leaked_kv_blocks"] == 0, rep_c
-            assert rep_c["shed"].get("fault", 0) >= 1, rep_c
-            assert goodput_c > 0, rep_c
-            accounted = (rep_c["completed"] + rep_c["shed_total"] +
-                         sum(1 for d in rep_c["decisions"]
-                             if d[0] == "invalid"))
-            assert accounted == rep_c["offered"], rep_c
-
-        # -- phase D: disaggregated P/D fleet vs symmetric router -----
-        from paddle_tpu.serving import DisaggRouter, ReplicaRouter
-        sym = ReplicaRouter(model, n_replicas=3, **eng_kw)
-        warmup(sym)
-        rep_sym = LoadGen(**lg_kw).run(sym, slo_ttft_ms=slo_ms)
-        compiles_sym = serving_compiles()
-        fleet = DisaggRouter(model, n_prefill=1, n_decode=2, **eng_kw)
-        warmup(fleet)
-        rep_d = LoadGen(**lg_kw).run(fleet, slo_ttft_ms=slo_ms)
-        compiles_d = serving_compiles()
-        assert compiles_d == compiles_sym, (
-            f"disaggregated roles must add ZERO compiles:\n"
-            f"  symmetric {compiles_sym}\n  disagg    {compiles_d}")
-        fleet_st = fleet.stats()
-        if gate:
-            for rep in (rep_sym, rep_d):
-                assert rep["exceptions"] == 0, rep
-                assert rep["leaked_kv_blocks"] == 0, rep
-                assert rep["completed"] > 0, rep
-            assert fleet_st["handoffs_adopted"] >= 1, fleet_st
-            if dev.platform == "tpu":
-                assert (rep_d["ttft_ms_p95"] or 0) <= \
-                       (rep_sym["ttft_ms_p95"] or 0), (
-                    f"disagg TTFT p95 {rep_d['ttft_ms_p95']}ms worse "
-                    f"than symmetric {rep_sym['ttft_ms_p95']}ms")
-    except Exception as e:
-        if is_oom(e):
-            sys.stderr.write("OOM: " + str(e)[:300] + "\n")
-            return OOM_RC
-        raise
-
-    def phase(rep):
-        return {k: rep[k] for k in
-                ("offered", "offered_rate", "completed", "shed",
-                 "shed_total", "exceptions", "slo_attainment",
-                 "goodput_per_s", "throughput_per_s", "ttft_ms_p50",
-                 "ttft_ms_p95", "leaked_kv_blocks", "makespan_s")}
-
-    out = {
-        "metric": "loadgen_goodput_per_sec",
-        "value": round(goodput_b, 2),
-        "unit": "SLO-met requests/s",
-        "vs_baseline": ratio,     # SLO-aware / depth-only goodput
-        "mode": lg_kw["mode"], "seed": seed,
-        "offered_rate": round(lg_kw["rate"], 2),
-        "capacity_per_s": round(capacity, 2),
-        "slo_ttft_ms": round(slo_ms, 2),
-        "slo_prefill_ms": round(prefill_pin, 3),
-        "slo_tpot_ms": round(tpot_pin, 3),
-        "slots": batch, "max_len": seq, "model": gpt,
-        "gate_asserted": gate,
-        "depth_only": phase(rep_a),
-        "slo_aware": phase(rep_b),
-        "chaos": dict(phase(rep_c), fault_spec=fault_spec,
-                      goodput_ratio_vs_clean=(
-                          round(goodput_c / goodput_b, 2)
-                          if goodput_b else None)),
-        "symmetric_router": dict(phase(rep_sym), workers=3),
-        "disagg": dict(
-            phase(rep_d), workers=3, topology="1x2",
-            handoffs_adopted=fleet_st["handoffs_adopted"],
-            affinity_hits=fleet_st["affinity_hits"],
-            fleet_prefix_hit_rate=fleet_st["fleet_prefix_hit_rate"],
-            ttft_p95_ratio_vs_symmetric=(
-                round(rep_d["ttft_ms_p95"] / rep_sym["ttft_ms_p95"], 3)
-                if rep_d["ttft_ms_p95"] and rep_sym["ttft_ms_p95"]
-                else None)),
-        "serving_compiles": compiles_b,
-        "device": getattr(dev, "device_kind", str(dev)),
-    }
-    out["observability"] = observability.snapshot()
-    # BENCH_LEDGER=PATH: feed the SLO-aware arm (the headline goodput
-    # number) into the perf-regression ledger alongside loadgen/soak
-    ledger = os.environ.get("BENCH_LEDGER")
-    if ledger:
-        from tools import perf_ledger
-        out["ledger_row"] = perf_ledger.append_report(
-            ledger, rep_b, run="bench", label="loadgen")
-    print(json.dumps(out))
-    return 0
-
-
-def child_main_zero(batch: int, seq: int, steps: int) -> int:
-    """BENCH_MODEL=zero: ZeRO optimizer-plane memory + step-time bench.
-
-    Runs the same gpt2-tiny train step twice over identical batches on
-    a (dp, 1) mesh spanning every visible device (main() carves out
-    BENCH_ZERO_DP=2 virtual CPU devices via XLA_FLAGS when the host
-    has only one): once replicated (stage 0 — plain to_static) and
-    once under BENCH_ZERO_STAGE (default 2: moments sharded + grads
-    reduce-scattered). Reports per-device parameter/optimizer bytes
-    from live ``addressable_shards`` (not estimates) and per-step wall
-    time for both, asserting loss parity and the ZeRO headline:
-    per-device optimizer bytes ~ 1/dp.
-    """
-    import jax
-    from jax.sharding import Mesh, PartitionSpec as P
-
-    import paddle_tpu as pt
-    from paddle_tpu import jit, observability
-    from paddle_tpu.distributed import zero
-    from paddle_tpu.models import GPT_CONFIGS, GPTForCausalLM
-    from paddle_tpu.optimizer import AdamW
-
-    dev = jax.devices()[0]
-    gpt = os.environ.get("BENCH_ZERO_GPT", "gpt2-tiny")
-    stage = int(os.environ.get("BENCH_ZERO_STAGE", "2"))
-    dp = jax.device_count()
-    cfg = GPT_CONFIGS[gpt]
-    mesh = Mesh(np.asarray(jax.devices()).reshape(dp, 1), ("dp", "mp"))
-
-    def build():
-        pt.seed(0)
-        model = GPTForCausalLM(cfg)
-        opt = AdamW(learning_rate=1e-3,
-                    parameters=model.parameters())
-
-        def train_step(ids, labels):
-            loss = model(ids, labels=labels)
-            model.clear_gradients()
-            loss.backward()
-            opt.step()
-            return loss
-        return model, opt, train_step
-
-    rng = np.random.RandomState(0)
-    ids = rng.randint(0, cfg.vocab_size,
-                      (steps, batch, seq)).astype(np.int32)
-    labels = np.roll(ids, -1, axis=2).astype(np.int32)
-
-    def run(step_fn, report_fn):
-        # warmup pays the (grads-absent + grads-present) compiles
-        np.asarray(step_fn(ids[0], labels[0]).value)
-        np.asarray(step_fn(ids[0], labels[0]).value)
-        t0 = time.perf_counter()
-        losses = [float(np.asarray(step_fn(ids[i], labels[i]).value))
-                  for i in range(steps)]
-        dt = (time.perf_counter() - t0) / steps
-        return losses, dt * 1000, report_fn()
-
-    model0, opt0, fn0 = build()
-    rep_step = jit.to_static(fn0, layers=[model0], optimizers=[opt0])
-    rep_losses, rep_ms, rep_bytes = run(
-        rep_step, lambda: zero.byte_report([model0], [opt0],
-                                           publish=False))
-
-    model1, opt1, fn1 = build()
-    z_step = zero.zero_train_step(
-        fn1, layers=[model1], optimizers=[opt1], mesh=mesh,
-        stage=stage, arg_specs=(P("dp"), P("dp")))
-    z_losses, z_ms, z_bytes = run(z_step, z_step.byte_report)
-
-    parity = all(abs(a - b) <= 2e-3 * abs(a)
-                 for a, b in zip(rep_losses, z_losses))
-    assert parity, (rep_losses, z_losses)
-    ratio = z_bytes["opt_bytes_per_device"] / z_bytes["opt_bytes"]
-    assert ratio <= 1.0 / dp + 0.1, (
-        f"ZeRO-{stage} per-device opt ratio {ratio:.3f} on dp={dp}")
-
-    print(json.dumps({
-        "metric": f"zero{stage}_opt_bytes_per_device_ratio",
-        "value": round(ratio, 4),
-        "unit": "x total (replicated = 1.0)",
-        # the memory win vs the replicated baseline's per-device cost
-        "vs_baseline": round(rep_bytes["opt_bytes_per_device"] /
-                             z_bytes["opt_bytes_per_device"], 4),
-        "dp": dp, "stage": stage, "model": gpt,
-        "batch": batch, "seq": seq, "steps": steps,
-        "loss_parity": parity,
-        "opt_bytes_total": z_bytes["opt_bytes"],
-        "opt_bytes_per_device": z_bytes["opt_bytes_per_device"],
-        "param_bytes_per_device": z_bytes["param_bytes_per_device"],
-        "replicated_opt_bytes_per_device":
-            rep_bytes["opt_bytes_per_device"],
-        "step_time_ms": round(z_ms, 2),
-        "replicated_step_time_ms": round(rep_ms, 2),
-        "device": getattr(dev, "device_kind", str(dev)),
-        "observability": {
-            "compiles": observability.snapshot()["compiles"]},
-    }))
-    return 0
-
-
 def child_main(model_name: str, batch: int, seq: int, steps: int) -> int:
     """Measure one (model, batch, seq, steps) config; print the JSON line.
 
@@ -1272,27 +442,6 @@ def main() -> int:
         seq = int(os.environ.get("BENCH_IMG", "224"))
     if model_name == "ernie":
         seq = int(os.environ.get("BENCH_SEQ", "512"))
-    if model_name == "serving":
-        # seq = slot KV capacity; steps = requests per slot
-        seq = int(os.environ.get("BENCH_SEQ", "256"))
-        steps = int(os.environ.get("BENCH_STEPS", "4"))
-    if model_name == "loadgen":
-        # seq = slot KV capacity; steps = arrival window seconds
-        batch = int(os.environ.get("BENCH_BATCH", "4"))
-        seq = int(os.environ.get("BENCH_SEQ", "64"))
-        steps = int(os.environ.get("BENCH_STEPS", "2"))
-    if model_name == "zero":
-        batch = int(os.environ.get("BENCH_BATCH", "8"))
-        seq = int(os.environ.get("BENCH_SEQ", "64"))
-        steps = int(os.environ.get("BENCH_STEPS", "5"))
-        # the ZeRO bench needs a data axis: carve BENCH_ZERO_DP virtual
-        # CPU devices in the child (a no-op when real devices exist)
-        if os.environ.get("JAX_PLATFORMS", "") == "cpu" or \
-                not os.environ.get("XLA_FLAGS", "").count("device_count"):
-            dp = int(os.environ.get("BENCH_ZERO_DP", "2"))
-            os.environ["XLA_FLAGS"] = (
-                os.environ.get("XLA_FLAGS", "") +
-                f" --xla_force_host_platform_device_count={dp}").strip()
 
     here = os.path.abspath(__file__)
     last_err = ""
@@ -1342,18 +491,6 @@ if __name__ == "__main__":
             sys.exit(child_main_ernie(int(sys.argv[i + 2]),
                                       int(sys.argv[i + 3]),
                                       int(sys.argv[i + 4])))
-        if name == "serving":
-            sys.exit(child_main_serving(int(sys.argv[i + 2]),
-                                        int(sys.argv[i + 3]),
-                                        int(sys.argv[i + 4])))
-        if name == "loadgen":
-            sys.exit(child_main_loadgen(int(sys.argv[i + 2]),
-                                        int(sys.argv[i + 3]),
-                                        int(sys.argv[i + 4])))
-        if name == "zero":
-            sys.exit(child_main_zero(int(sys.argv[i + 2]),
-                                     int(sys.argv[i + 3]),
-                                     int(sys.argv[i + 4])))
         sys.exit(child_main(name, int(sys.argv[i + 2]),
                             int(sys.argv[i + 3]), int(sys.argv[i + 4])))
     sys.exit(main())

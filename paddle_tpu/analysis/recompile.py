@@ -121,7 +121,7 @@ def _bucket_for(buckets: Sequence[int], length: int) -> int:
 
 def predict_serving_compiles(
         request_rounds: Iterable[Sequence[Tuple[Sequence[int], int]]], *,
-        buckets: Sequence[int], max_len: int, paged: bool = True,
+        buckets: Sequence[int], max_len: int,
         block_size: int = 16, prefix_cache: bool = True,
         spec_tokens: int = 0, attn_impl: str = "xla",
         kv_dtype: str = "f32",
@@ -157,16 +157,16 @@ def predict_serving_compiles(
 
     Model (mirrors ``serving/engine.py`` + ``serving/kv_cache.py``):
 
-    - prefill compiles once per length bucket hit; the paged path
+    - prefill compiles once per length bucket hit; the engine
       buckets the *unshared suffix* ``len(prompt) - shared`` where
       ``shared = min(matched_blocks * block_size, len(prompt) - 1)``
       (the last prompt token is always recomputed to emit the first
       output token);
-    - decode (``decode_step[_paged]``) compiles once iff any request
+    - decode (``decode_step_paged``) compiles once iff any request
       needs tokens beyond the one its prefill emits
       (``max_new_tokens > 1``) — with ``spec_tokens`` K > 0 the engine
       takes the verify path exclusively, so the compile lands on
-      ``verify_step[_paged]{k=K}`` instead.
+      ``verify_step_paged{k=K}`` instead.
 
     ``attn_impl`` (``FLAGS_serving_attn_impl``) and ``kv_dtype``
     (``FLAGS_serving_kv_dtype``) are part of the compiled steps' cache
@@ -271,8 +271,7 @@ def predict_serving_compiles(
     within a phase it's a validated no-op: per-row adapter pages are
     gathered *inside* the step from one more fixed-shape input, so
     adapter loads, evictions and any per-tenant traffic mix trace
-    nothing. Requires ``paged=True`` (the pool reuses the block
-    allocator's discipline).
+    nothing.
 
     ``tracing`` (``FLAGS_serving_trace``: the per-request distributed-
     tracing sampling fraction in [0, 1], or True for fully sampled) is
@@ -332,8 +331,8 @@ def predict_serving_compiles(
     beyond the device-table caps, a hard deadline with room for fewer
     than N tokens). Both compile once; ``_choose_megastep`` never
     picks an intermediate N, so no third surface exists. Requires
-    ``paged=True`` and ``spec_tokens == 0`` (the engine rejects both
-    combinations). ``dispatch_ahead`` and threaded routers reuse the
+    ``spec_tokens == 0`` (the engine rejects the combination).
+    ``dispatch_ahead`` and threaded routers reuse the
     same two entries — enqueueing megastep k+1 early replays the
     cached trace by construction.
     """
@@ -343,20 +342,12 @@ def predict_serving_compiles(
                            "kv_dtype")):
         if val not in ok:
             raise ValueError(f"{flag} must be one of {ok}, got {val!r}")
-    if kv_dtype != "f32" and not paged:
-        raise ValueError(
-            f"kv_dtype={kv_dtype!r} requires paged=True (the engine "
-            "rejects non-f32 dense caches)")
     if mesh_shape is not None:
         dims = tuple(int(d) for d in mesh_shape)
         if len(dims) != 2 or any(d < 1 for d in dims):
             raise ValueError(
                 f"mesh_shape must be a (data, model) pair of positive "
                 f"ints, got {mesh_shape!r}")
-        if not paged:
-            raise ValueError(
-                "mesh_shape requires paged=True (mesh-sharded serving "
-                "runs on the paged KV cache)")
     if int(n_replicas) < 1:
         raise ValueError(f"n_replicas must be >= 1, got {n_replicas}")
     if float(slo_ttft_ms) < 0:
@@ -388,10 +379,6 @@ def predict_serving_compiles(
             raise ValueError(
                 f"disagg must be (n_prefill >= 1, n_decode >= 1), got "
                 f"{disagg!r}")
-        if not paged:
-            raise ValueError(
-                "disagg requires paged=True (the prefill->decode KV "
-                "handoff is a block-table splice)")
     if sampling is not None:
         from ..serving.decoding import DecodeParams
         for rec in sampling:
@@ -404,10 +391,6 @@ def predict_serving_compiles(
             raise ValueError(
                 f"lora must be (rank >= 1, max_adapters >= 1), got "
                 f"{lora!r}")
-        if not paged:
-            raise ValueError(
-                "lora requires paged=True (the adapter pool is paged "
-                "like the KV cache)")
     if tracing is not None:
         frac = 1.0 if tracing is True else float(tracing)
         if not (0.0 <= frac <= 1.0):
@@ -435,10 +418,6 @@ def predict_serving_compiles(
     megastep = int(megastep)
     if megastep < 1:
         raise ValueError(f"megastep must be >= 1, got {megastep}")
-    if megastep > 1 and not paged:
-        raise ValueError(
-            "megastep > 1 requires paged=True (the device-resident "
-            "decode loop carries the paged KV pool through lax.scan)")
     if megastep > 1 and spec_tokens > 0:
         raise ValueError(
             "megastep > 1 is mutually exclusive with spec_tokens > 0 "
@@ -447,12 +426,7 @@ def predict_serving_compiles(
         raise ValueError(
             "sessions requires host_tier=True (submit(session=...) "
             "needs the host KV tier to park a conversation)")
-    if host_tier and not paged:
-        raise ValueError(
-            "host_tier requires paged=True (the tier migrates paged "
-            "KV blocks)")
     bks = _parse_buckets(buckets, max_len)
-    suffix = "_paged" if paged else ""
     counts: Dict[str, int] = {}
     seen_buckets: Set[int] = set()
     published: Set[Tuple] = set()   # rolling chains of full-block chunks
@@ -463,7 +437,7 @@ def predict_serving_compiles(
         for prompt, max_new_tokens in round_reqs:
             prompt = tuple(int(t) for t in prompt)
             shared = 0
-            if paged and prefix_cache:
+            if prefix_cache:
                 matched, chain = 0, ()
                 for i in range(len(prompt) // block_size):
                     chain = (chain,
@@ -473,13 +447,10 @@ def predict_serving_compiles(
                     matched += 1
                 shared = min(matched * block_size, len(prompt) - 1)
                 round_published.append(prompt)
-            length = len(prompt) - shared if paged else len(prompt)
-            b = _bucket_for(bks, length)
+            b = _bucket_for(bks, len(prompt) - shared)
             if b not in seen_buckets:
                 seen_buckets.add(b)
-                counts[f"serving_prefill{suffix}{{bucket={b}}}"] = \
-                    counts.get(f"serving_prefill{suffix}{{bucket={b}}}",
-                               0) + 1
+                counts[f"serving_prefill_paged{{bucket={b}}}"] = 1
             if max_new_tokens > 1:
                 needs_decode = True
         # prefix publication happens post-prefill, i.e. between rounds
@@ -491,9 +462,9 @@ def predict_serving_compiles(
 
     if needs_decode:
         if spec_tokens > 0:
-            counts[f"verify_step{suffix}{{k={spec_tokens}}}"] = 1
+            counts[f"verify_step_paged{{k={spec_tokens}}}"] = 1
         else:
-            counts[f"decode_step{suffix}"] = 1
+            counts["decode_step_paged"] = 1
             if megastep > 1:
                 counts[f"decode_megastep_paged{{n={megastep}}}"] = 1
     return counts

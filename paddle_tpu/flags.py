@@ -266,8 +266,7 @@ define_flag("serving_megastep", 1,
             "EOS / budget / stop-sequence early-exit carried as "
             "per-slot data (finished slots freeze behind a live-mask) "
             "and one host commit per megastep instead of per token. "
-            "Output is byte-identical to megastep=1; requires "
-            "serving_paged and is incompatible with "
+            "Output is byte-identical to megastep=1; incompatible with "
             "serving_spec_tokens > 0. Requests the device stop tables "
             "cannot hold (decoding.STOP_MAX_SEQS/STOP_MAX_LEN) or "
             "that decode under a JSON grammar fall back to single "
@@ -295,14 +294,6 @@ define_flag("serving_dispatch_threads", 0,
             "deadline reaping stay at step boundaries with identical "
             "semantics). 0 (default) = serial stepping, byte-identical "
             "scheduling order.")
-define_flag("serving_paged", True,
-            "ServingEngine KV memory manager: True = block-paged "
-            "BlockKVCache (per-request block tables over a fixed pool "
-            "of serving_block_size-row KV blocks, ref-counted with "
-            "shared-prefix reuse — each request pays only the blocks "
-            "it needs); False = the dense SlotKVCache (every request "
-            "pays a full max_len row). Output is token-identical "
-            "either way.")
 define_flag("serving_block_size", 16,
             "Paged serving: KV rows per block. Smaller blocks waste "
             "less memory on partial blocks and share shorter "
@@ -427,8 +418,8 @@ define_flag("serving_lora_rank", 0,
             "block-table trick applied to weights), so base and "
             "per-tenant rows mix in one batch of one executable and "
             "loading/evicting adapters never recompiles. 0 disables "
-            "(no pool, no lora step input). Requires the paged KV "
-            "cache. Constructor state read once, like the SLO knobs.")
+            "(no pool, no lora step input). Constructor state read "
+            "once, like the SLO knobs.")
 define_flag("serving_lora_max_adapters", 4,
             "Multi-tenant paged LoRA: adapter pages in the pool "
             "(tenants resident at once; +1 all-zero base page is "

@@ -9,7 +9,7 @@ from .laguna import LAGUNA_CONFIGS, LagunaConfig, LagunaForCausalLM
 from . import generation
 from .generation import (beam_search, decode_step, decode_step_paged,
                          draft_ngram, greedy_search, sample,
-                         verify_step, verify_step_paged)
+                         verify_step_paged)
 from .ernie import (ERNIE_CONFIGS, ErnieForPretraining,
                     ErnieForSequenceClassification, ErnieModel,
                     ernie_tiny)

@@ -13,10 +13,12 @@ jitted step function compiles exactly once and serves every step of
 every request at that shape. The old concat-cache loop grew the key
 axis each step, forcing an XLA recompile per generated token.
 ``decode_step(model)`` exposes the per-model compiled step (and its
-trace counter, asserted ==1 in tests); ``paddle_tpu.serving`` drives
-the same step function with slots on the batch axis.
+trace counter, asserted ==1 in tests); it is the oracle
+``paddle_tpu.serving`` is compared against. The engine's own steps
+(``decode_step_paged`` and friends) run the same forward through
+per-row block tables into a shared block pool.
 
-``verify_step(model, k)`` is the speculative-decoding sibling: one
+``verify_step_paged(model, k)`` is the speculative-decoding step: one
 fixed-shape forward scores K+1 positions (the last committed token
 plus K drafts from ``draft_ngram``), so a serving step can commit up
 to K+1 tokens while staying on a single compiled executable.
@@ -247,60 +249,6 @@ def decode_step(model):
         return {"fn": fn, "traces": fn.traces}
 
     return step_entry(model, ("decode",), _build)
-
-
-def verify_step(model, spec_tokens: int):
-    """The compiled draft–verify step for speculative decoding.
-
-    Returns ``{"fn": jitted, "traces": {"count": n}}`` where ``fn``
-    maps ``(tokens [b, K+1] i32, pos [b] i32, caches)`` to
-    ``(next_tokens [b, K+1] i32, logits [b, K+1, V], new_caches)``.
-    Row layout: ``tokens[:, 0]`` is each row's last *committed* token
-    (the one a plain decode step would feed), ``tokens[:, 1:]`` the K
-    draft tokens proposed for the positions after it. One forward
-    scatter-writes all K+1 rows at ``pos..pos+K`` and scores them
-    under the causal position mask; ``decoding.verify_tokens`` then
-    turns the K+1 per-position logits into ``(chosen, accept)``:
-    greedy rows keep the old prefix match (``chosen = argmax``,
-    ``accept = argmax == draft``, token-identical), sampled rows run
-    rejection sampling so every emitted token is an exact draw from
-    the non-speculative sampled distribution. Entries past a row's
-    first rejection are garbage by construction; the caller commits
-    the accepted prefix on the host, rolls the slot's write offset
-    back, and the position mask hides the stale cache rows. Returns
-    ``(chosen [b, K+1] i32, logits [b, K+1, V], new_caches,
-    accept [b, K] bool, new_keys [b, 2] u32)``.
-
-    Compiled once per (model, K) — the fixed K+1 query width is what
-    keeps speculative serving on a single XLA executable. Cached in the
-    unified :func:`step_entry` cache, like ``decode_step``.
-    """
-    k = int(spec_tokens)
-    if k < 1:
-        raise ValueError(f"verify_step needs spec_tokens >= 1, got {k}")
-
-    def _build():
-        from ..serving.decoding import verify_tokens
-
-        def _step(params, tokens, pos, caches, samp):
-            with no_grad(), _borrowed_params(model, params):
-                tcaches = [(Tensor(kk, stop_gradient=True),
-                            Tensor(vv, stop_gradient=True))
-                           for kk, vv in caches]
-                logits, newc = model(_t(tokens), cache=tcaches,
-                                     cache_pos=pos)
-            lg = logits.value                            # [b, K+1, V]
-            nxt, accept, new_keys = verify_tokens(lg, tokens[:, 1:], samp)
-            return (nxt, lg, [(c[0].value, c[1].value) for c in newc],
-                    accept, new_keys)
-
-        from ..observability import compile_tracker as _ct
-        fn = _inject_params(
-            model, _ct.tracked_jit("verify_step", _step,
-                                   labels={"k": str(k)}))
-        return {"fn": fn, "traces": fn.traces}
-
-    return step_entry(model, ("verify", k), _build)
 
 
 #: Every paged step entry takes ``pools`` as argument 4 of its jitted
@@ -576,13 +524,19 @@ def decode_megastep_paged(model, n: int, mesh=None, kv_dtype: str = "f32",
 
 def verify_step_paged(model, spec_tokens: int, mesh=None,
                       kv_dtype: str = "f32", lora_shape=None):
-    """The block-paged sibling of :func:`verify_step`: one fixed-shape
-    forward scores the last committed token plus K drafts
-    (``tokens [b, K+1]``) through per-row block tables, then
-    ``decoding.verify_tokens`` picks ``(chosen, accept)`` per row —
-    greedy prefix match on temp==0 rows (token-identical to the old
-    argmax verify), rejection sampling on sampled rows. Same row
-    layout and rollback contract as the dense verify step — rejected
+    """The compiled draft–verify step of speculative decoding: one
+    fixed-shape forward scores the last committed token plus K drafts
+    (``tokens [b, K+1]``: ``tokens[:, 0]`` is what a plain decode step
+    would feed, ``tokens[:, 1:]`` the drafts for the positions after
+    it) through per-row block tables, writing all K+1 rows at
+    ``pos..pos+K``; then ``decoding.verify_tokens`` picks
+    ``(chosen, accept)`` per row — greedy prefix match on temp==0 rows
+    (``chosen = argmax``, ``accept = argmax == draft``: token-identical
+    to plain greedy), rejection sampling on sampled rows, so every
+    emitted token is an exact draw from the non-speculative
+    distribution. Entries past a row's first rejection are garbage by
+    construction: the caller commits the accepted prefix on the host
+    and rolls the row's write offset back, and the rejected
     rows are stale pool contents past the row's valid length, hidden
     by the position mask (blocks stay reserved, so rollback across a
     block boundary is pure host-side length arithmetic). Compiled

@@ -130,6 +130,13 @@ class GPTAttention(Layer):
                 lora=None):
         cfg = self.cfg
         b, s, _ = x.shape
+        if cache is not None and cache_pos is None:
+            raise ValueError(
+                "a KV cache needs cache_pos: with block_tables it is a "
+                "paged block pool (gen_block_pool, the serving "
+                "engine's), without them a fixed-capacity "
+                "[b, h, max_len, d] pair (gen_fixed_cache, "
+                "generation.py's)")
         qkv = self.qkv_proj(x)
         if lora is not None:
             # lora = (page_ids [b] i32, Aq, Bq, Ao, Bo) — this layer's
@@ -223,14 +230,13 @@ class GPTAttention(Layer):
             out = out.transpose([0, 2, 1, 3]).reshape(
                 [b, s, cfg.hidden_size])
             return _out(out), cache
-        if cache is not None and cache_pos is not None:
+        if cache is not None:
             # fixed-capacity (slotted) KV cache: `cache` is a
             # preallocated [b, h, max_len, d] pair and the new keys are
             # written in place at each row's own offset, so every
             # decode step has ONE shape and XLA compiles it once. The
-            # same path serves s > 1 blocks — bucketed prefill and the
-            # speculative verify step (last token + K drafts) both
-            # scatter-write s rows at once; the per-row position mask
+            # same path serves s > 1 blocks — the prompt pass
+            # scatter-writes s rows at once; the per-row position mask
             # keeps each query row causal within the written block.
             # Inference-only by construction (writes bypass the tape).
             from ..ops.attention_ops import (cache_scatter_write,
@@ -251,16 +257,11 @@ class GPTAttention(Layer):
             out = out.transpose([0, 2, 1, 3]).reshape(
                 [b, s, cfg.hidden_size])
             return _out(out), cache
-        if cache is not None:
-            k = run_op("concat", {"X": [cache[0], k]}, {"axis": 2})["Out"][0]
-            v = run_op("concat", {"X": [cache[1], v]}, {"axis": 2})["Out"][0]
-            cache = (k, v)
         out = run_op("fused_attention_qkv",
                      {"Q": [q], "K": [k], "V": [v]},
                      {"causal": True})["Out"][0]
         out = out.transpose([0, 2, 1, 3]).reshape([b, s, cfg.hidden_size])
-        out = _out(out)
-        return out if cache is None else (out, cache)
+        return _out(out)
 
 
 class GPTBlock(Layer):
@@ -389,12 +390,6 @@ class GPTModel(Layer):
                 new_caches.append(c)
         x = self.ln_f(x)
         return x if cache is None else (x, new_caches)
-
-    def gen_cache(self, batch_size):
-        z = Tensor(jnp.zeros((batch_size, self.cfg.num_heads, 0,
-                              self.cfg.head_dim), jnp.float32),
-                   stop_gradient=True)
-        return [(z, z) for _ in range(self.cfg.num_layers)]
 
     def gen_fixed_cache(self, batch_size, max_len):
         """Preallocated fixed-capacity KV cache: one [b, h, max_len, d]

@@ -35,7 +35,7 @@ INSTRUMENT_DOCS = {
     "xla_compiles{fn=...}":
         "counter — XLA compiles per tracked_jit site (executor_step, "
         "parallel_executor_step, decode_step[_paged], "
-        "verify_step[_paged], serving_prefill[_paged]{bucket=...}, "
+        "verify_step_paged{k=...}, serving_prefill_paged{bucket=...}, "
         "to_static, to_static_multi_step, zero_train_step{stage=...})",
     "xla_compile_ms":
         "histogram — wall ms of calls that triggered an XLA compile",
@@ -152,9 +152,9 @@ INSTRUMENT_DOCS = {
         "sample rate",
     "serving_device_step_ms{fn=...}":
         "histogram — sampled block_until_ready device ms per step "
-        "dispatch, per compiled entry (decode_step[_paged], "
-        "decode_megastep_paged{n=...}, verify_step[_paged]{k=...}, "
-        "serving_prefill[_paged]{bucket=...})",
+        "dispatch, per compiled entry (decode_step_paged, "
+        "decode_megastep_paged{n=...}, verify_step_paged{k=...}, "
+        "serving_prefill_paged{bucket=...})",
     "sanitizer_lock_acquires":
         "counter — lock acquisitions instrumented by the concurrency "
         "sanitizer (FLAGS_sanitize_locks): every outermost acquire of "
@@ -221,8 +221,8 @@ EVENT_DOCS = {
     "guardian_skip": "TrainGuardian skipped a non-finite step",
     "guardian_rollback": "TrainGuardian restored a checkpoint",
     "serving_admit": "request admitted into a KV slot (bucket, "
-                     "prompt_tokens; + shared_tokens reused from the "
-                     "prefix cache when paged)",
+                     "prompt_tokens, shared_tokens reused from the "
+                     "prefix cache)",
     "serving_finish": "request retired (tokens, ttft_ms, tpot_ms; + "
                       "deadline_met under a TTFT SLO)",
     "serving_shed": "request shed (reason: queue_full | slo | deadline "

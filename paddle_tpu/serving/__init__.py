@@ -3,13 +3,12 @@
 Continuous-batching engine over a fixed-shape KV cache: requests share
 one preallocated decode batch, prefill is shape-bucketed AND batched
 (every same-bucket admission rides one dispatch), and the decode step
-compiles exactly once per engine geometry. KV memory is block-paged by
-default (``FLAGS_serving_paged``): a fixed pool of KV blocks with
-per-request block tables, a ref-counted allocator, and a rolling-hash
-prefix cache so a shared system prompt prefills once and is referenced
-by later requests (copy-on-write at the boundary block) — each request
-pays blocks for its actual need instead of a full ``max_len`` row. The
-dense ``SlotKVCache`` remains as the ``paged=False`` baseline. With
+compiles exactly once per engine geometry. KV memory is block-paged:
+a fixed pool of KV blocks with per-request block tables, a ref-counted
+allocator, and a rolling-hash prefix cache so a shared system prompt
+prefills once and is referenced by later requests (copy-on-write at
+the boundary block) — each request pays blocks for its actual need
+instead of a full ``max_len`` row. With
 ``FLAGS_serving_spec_tokens`` = K > 0 the engine runs draft–verify
 speculative decoding: an n-gram self-drafter proposes K tokens per
 slot and one fixed-shape verify forward commits up to K+1 tokens per
@@ -78,13 +77,13 @@ from .disagg import (DecodeEngine, DisaggRouter, HandoffQueue,
                      PrefillEngine)
 from .http import ServingHTTPServer
 from .kv_cache import (BlockAllocator, BlockKVCache, BlockPool,
-                       SlotKVCache, prefix_chain_keys)
+                       prefix_chain_keys)
 from .kv_tier import HostBlockStore, SessionStore, TierManager
 from .lora import LoRAPool, make_adapter
 from .router import AutoscalePolicy, ReplicaRouter
 
 __all__ = ["ServingEngine", "Request", "QueueFullError",
-           "SlotKVCache", "BlockKVCache", "BlockAllocator",
+           "BlockKVCache", "BlockAllocator",
            "BlockPool", "prefix_chain_keys",
            "HostBlockStore", "TierManager", "SessionStore",
            "ServingHTTPServer", "ReplicaRouter", "AutoscalePolicy",

@@ -216,11 +216,6 @@ class PrefillEngine(ServingEngine):
     trace_role = "prefill"
 
     def __init__(self, model, handoff: HandoffQueue, **kwargs):
-        if kwargs.get("paged") is False:
-            raise ValueError(
-                "disaggregated serving requires the paged KV cache "
-                "(the handoff is a block-table splice)")
-        kwargs["paged"] = True
         super().__init__(model, **kwargs)
         self._handoff = handoff
         self._pending: deque = deque()  # guarded-by: _step_lock
@@ -268,9 +263,8 @@ class PrefillEngine(ServingEngine):
                 worked = self._flush_pending() > 0 or worked
             if self.kv_tier is not None:
                 self._demote_sweep()
-            if self.paged:
-                self._blocks_used_g.set(self.cache.blocks_used)
-                self._blocks_free_g.set(self.cache.blocks_free)
+            self._blocks_used_g.set(self.cache.blocks_used)
+            self._blocks_free_g.set(self.cache.blocks_free)
             return worked
 
     @property
@@ -326,11 +320,6 @@ class DecodeEngine(ServingEngine):
     trace_role = "decode"
 
     def __init__(self, model, handoff: HandoffQueue, **kwargs):
-        if kwargs.get("paged") is False:
-            raise ValueError(
-                "disaggregated serving requires the paged KV cache "
-                "(the handoff is a block-table splice)")
-        kwargs["paged"] = True
         super().__init__(model, **kwargs)
         self._handoff = handoff
         self.adopted = 0          # guarded-by: _step_lock
@@ -459,9 +448,8 @@ class DecodeEngine(ServingEngine):
             produced = self._decode_any()
             if self.kv_tier is not None:
                 self._demote_sweep()
-            if self.paged:
-                self._blocks_used_g.set(self.cache.blocks_used)
-                self._blocks_free_g.set(self.cache.blocks_free)
+            self._blocks_used_g.set(self.cache.blocks_used)
+            self._blocks_free_g.set(self.cache.blocks_free)
             return bool(produced)
 
     def step(self) -> bool:
@@ -650,15 +638,12 @@ class DisaggRouter:
         with eng._lock:
             return len(eng._queue) + len(eng._active)
 
-    def _blocks_free(self, eng: ServingEngine) -> int:
-        return eng.cache.blocks_free
-
     def _least_loaded(self) -> List[int]:
         return sorted(
             (i for i, e in enumerate(self.prefills)
              if not e.draining),
             key=lambda i: (self._depth(self.prefills[i]),
-                           -self._blocks_free(self.prefills[i]), i))
+                           -self.prefills[i].cache.blocks_free, i))
 
     def _affinity_pick(self, prompt: Sequence[int],
                        keys: Sequence[int]) -> Optional[int]:
@@ -747,7 +732,7 @@ class DisaggRouter:
             _monitor.stat_add("STAT_serving_routed")
             _runlog.log_event("serving_route", request=req.id,
                               replica=i, depth=self._depth(eng),
-                              kv_blocks_free=self._blocks_free(eng),
+                              kv_blocks_free=eng.cache.blocks_free,
                               role="prefill")
             if self.prefix_affinity and keys:
                 self._publish_affinity(keys, eng)
@@ -1066,7 +1051,7 @@ class DisaggRouter:
             # normal retirement path
             for row, req in list(eng._active.items()):
                 del eng._active[row]
-                eng.cache.release(row)
+                eng.cache.release_row(row)
                 eng._shed(req, _Shed("prefill worker killed"))
                 shed += 1
         # still-queued requests re-home onto survivors
@@ -1148,7 +1133,7 @@ class DisaggRouter:
                     self.decodes,
                     key=lambda p: (
                         0 if rec["pool"] is p.cache.pool else 1,
-                        self._depth(p), -self._blocks_free(p)))
+                        self._depth(p), -p.cache.blocks_free))
                 for peer in order:
                     same_pool = rec["pool"] is peer.cache.pool
                     row2 = (peer.cache.import_row(rec) if same_pool
@@ -1334,7 +1319,7 @@ class DisaggRouter:
             "canceled_total": sum(canceled.values()),
             "dispatch_threads": self._dispatch_threads,
             "queue_depths": [self._depth(e) for e in self.prefills],
-            "kv_blocks_free": [self._blocks_free(e)
+            "kv_blocks_free": [e.cache.blocks_free
                                for e in self.prefills],
             "per_prefill": [e.stats() for e in self.prefills],
             "per_decode": [e.stats() for e in self.decodes],

@@ -1,5 +1,4 @@
-"""ServingEngine — continuous-batching inference on a paged (or
-slotted) KV cache.
+"""ServingEngine — continuous-batching inference on a paged KV cache.
 
 Iteration-level scheduling (the Orca design point): the unit of work is
 one *step*, not one request. Each step first admits queued requests
@@ -11,11 +10,11 @@ to let stragglers finish.
 
 Compile surfaces, all fixed-shape:
 
-- decode: ``models.generation.decode_step(model)`` at batch =
+- decode: ``models.generation.decode_step_paged(model)`` at batch =
   ``max_slots`` — every step of every request, one XLA executable;
 - verify (``FLAGS_serving_spec_tokens`` = K > 0): speculative
   decoding replaces the one-token decode with
-  ``models.generation.verify_step(model, K)`` — an on-host n-gram
+  ``models.generation.verify_step_paged(model, K)`` — an on-host n-gram
   self-drafter proposes K tokens per slot from the request's own
   generated suffix, one fixed-shape forward scores all K+1 positions,
   and the accepted prefix commits to the cache while the rejected
@@ -35,23 +34,19 @@ Compile surfaces, all fixed-shape:
   CompiledProgram's keyed ``_cache`` (compiler.py), keyed here by
   shape bucket instead of program.
 
-KV memory comes from one of two managers (``FLAGS_serving_paged``):
-
-- **paged** (default): :class:`~paddle_tpu.serving.kv_cache.BlockKVCache`
-  — a fixed pool of block_size-row KV blocks per layer, per-request
-  host-side block tables shipped into the compiled steps as fixed-shape
-  inputs (``decode_step_paged`` / ``verify_step_paged`` /
-  ``serving_prefill_paged``, each still compiling exactly once), a
-  ref-counted allocator, and a rolling-hash prefix cache so a shared
-  system prompt prefills once and later admissions reference its
-  blocks (copy-on-write at a partially shared boundary block; only the
-  unshared prompt *suffix* runs through the bucketed prefill). A
-  request pays blocks for prompt + max_new_tokens + K, not a full
-  ``max_len`` row; when the pool runs dry admission blocks
-  head-of-line (FIFO preserved) and queue backpressure sheds via
-  QueueFullError/429 as before.
-- **dense**: the original :class:`SlotKVCache` (one max_len row per
-  request) — the bench baseline and fallback.
+KV memory comes from one manager,
+:class:`~paddle_tpu.serving.kv_cache.BlockKVCache`: a fixed pool of
+block_size-row KV blocks per layer, per-request host-side block tables
+shipped into the compiled steps as fixed-shape inputs
+(``decode_step_paged`` / ``verify_step_paged`` /
+``serving_prefill_paged``, each compiling exactly once), a ref-counted
+allocator, and a rolling-hash prefix cache so a shared system prompt
+prefills once and later admissions reference its blocks (copy-on-write
+at a partially shared boundary block; only the unshared prompt *suffix*
+runs through the bucketed prefill). A request pays blocks for prompt +
+max_new_tokens + K, not a full ``max_len`` row; when the pool runs dry
+admission blocks head-of-line (FIFO preserved) and queue backpressure
+sheds via QueueFullError/429.
 
 Mesh sharding (``FLAGS_serving_mesh`` / the ``mesh=`` argument): the
 engine runs tensor-parallel within one replica on a ``("data",
@@ -70,8 +65,8 @@ attempt and per decode attempt — drop/error retry through RetryPolicy
 (exhaustion sheds the affected requests, never the whole engine),
 ``skip`` sheds the request being prefilled / skips one decode
 iteration; ``serving.alloc`` faults fire per block-table acquisition
-attempt (paged), shedding that request with every taken block
-unwound. Counters land in monitor.stats() as ``STAT_serving_*``.
+attempt, shedding that request with every taken block unwound.
+Counters land in monitor.stats() as ``STAT_serving_*``.
 
 Admission (``FLAGS_serving_slo_ttft_ms`` > 0): instead of the blunt
 queue-depth gate alone, ``submit()`` predicts the newcomer's TTFT
@@ -118,16 +113,15 @@ from ..dygraph.tensor import Tensor
 from ..distributed.sharding import (SERVING_TP_RULES, kv_pool_shardings,
                                     mesh_cache_key, parse_serving_mesh,
                                     serving_mesh)
-from ..models.generation import (decode_megastep_paged, decode_step,
+from ..models.generation import (decode_megastep_paged,
                                  decode_step_paged, draft_ngram,
-                                 step_entry, verify_step,
-                                 verify_step_paged)
+                                 step_entry, verify_step_paged)
 from ..resilience.injector import fault_point
 from ..resilience.retry import RetryError, RetryPolicy
 from .decoding import (STOP_MAX_LEN, STOP_MAX_SEQS, DecodeParams,
                        StopMatcher, request_key, sample_first,
                        stop_table_rows, stops_fit)
-from .kv_cache import BlockKVCache, SlotKVCache
+from .kv_cache import BlockKVCache
 from .kv_tier import HostBlockStore, TierManager
 from .lora import LoRAPool
 
@@ -355,7 +349,6 @@ class ServingEngine:
                  max_queue: Optional[int] = None,
                  eos_token_id: Optional[int] = None,
                  spec_tokens: Optional[int] = None,
-                 paged: Optional[bool] = None,
                  block_size: Optional[int] = None,
                  num_blocks: Optional[int] = None,
                  prefix_cache: Optional[bool] = None,
@@ -382,7 +375,7 @@ class ServingEngine:
                               "serving_spec_ngram",
                               "serving_megastep",
                               "serving_dispatch_ahead",
-                              "serving_paged", "serving_block_size",
+                              "serving_block_size",
                               "serving_num_blocks",
                               "serving_prefix_cache",
                               "serving_kv_dtype",
@@ -479,13 +472,6 @@ class ServingEngine:
                         if buckets is None else
                         _parse_buckets(",".join(map(str, buckets)),
                                        self.max_len))
-        self.paged = bool(paged if paged is not None
-                          else g["serving_paged"])
-        if self.megastep > 1 and not self.paged:
-            raise ValueError(
-                "megastep > 1 requires the paged KV cache "
-                "(FLAGS_serving_paged); the dense decode step has no "
-                "device-resident scan sibling")
         self.kv_dtype = str(kv_dtype if kv_dtype is not None
                             else g["serving_kv_dtype"])
         # which attention lowering the compiled paged steps traced with;
@@ -496,16 +482,11 @@ class ServingEngine:
             dims = parse_serving_mesh(g["serving_mesh"])
             if dims is not None:
                 mesh = serving_mesh(*dims)
-        if mesh is not None:
-            if tuple(mesh.axis_names) != ("data", "model"):
-                raise ValueError(
-                    f"serving mesh axes must be ('data', 'model'), got "
-                    f"{tuple(mesh.axis_names)}")
-            if not self.paged:
-                raise ValueError(
-                    "mesh-sharded serving requires the paged KV cache "
-                    "(FLAGS_serving_paged); the dense SlotKVCache has "
-                    "no head-sharded placement")
+        if mesh is not None and \
+                tuple(mesh.axis_names) != ("data", "model"):
+            raise ValueError(
+                f"serving mesh axes must be ('data', 'model'), got "
+                f"{tuple(mesh.axis_names)}")
         self.mesh = mesh
         self.mesh_shape = (None if mesh is None else
                            tuple(int(s) for s in mesh.devices.shape))
@@ -513,10 +494,6 @@ class ServingEngine:
             # co-located disaggregated roles share one physical pool:
             # geometry comes from the pool (not the flags) so the
             # sharing cache cannot drift from what the blocks are
-            if not self.paged:
-                raise ValueError(
-                    "kv_pool sharing requires the paged KV cache "
-                    "(FLAGS_serving_paged)")
             if self.mesh is not None:
                 raise ValueError(
                     "kv_pool sharing and mesh placement are mutually "
@@ -531,7 +508,7 @@ class ServingEngine:
                 prefix_cache=bool(prefix_cache if prefix_cache is not None
                                   else g["serving_prefix_cache"]),
                 kv_dtype=self.kv_dtype, pool=kv_pool)
-        elif self.paged:
+        else:
             self.cache = BlockKVCache(
                 cfg.num_layers, cfg.num_heads, cfg.head_dim,
                 self.max_slots, self.max_len,
@@ -542,15 +519,6 @@ class ServingEngine:
                 prefix_cache=bool(prefix_cache if prefix_cache is not None
                                   else g["serving_prefix_cache"]),
                 kv_dtype=self.kv_dtype)
-        else:
-            if self.kv_dtype != "f32":
-                raise ValueError(
-                    f"serving_kv_dtype={self.kv_dtype!r} requires the "
-                    "paged KV cache (FLAGS_serving_paged); the dense "
-                    "SlotKVCache is f32-only")
-            self.cache = SlotKVCache(cfg.num_layers, cfg.num_heads,
-                                     cfg.head_dim, self.max_slots,
-                                     self.max_len)
         # Multi-tenant paged LoRA: a pool of per-tenant adapter pages
         # fed to the compiled steps as one more fixed-shape input (the
         # lora geometry joins the step-cache key like kv_dtype, but
@@ -569,11 +537,6 @@ class ServingEngine:
                     else g["serving_lora_max_adapters"]))
         else:
             self.lora_pool = None
-        if self.lora_pool is not None and not self.paged:
-            raise ValueError(
-                "multi-tenant LoRA requires the paged KV cache "
-                "(FLAGS_serving_paged); the dense steps carry no "
-                "adapter-page input")
         self._lora_shape = (None if self.lora_pool is None
                             else self.lora_pool.shape_key)
         # Host-RAM KV tier (serving/kv_tier.py): an explicit kv_tier=
@@ -585,11 +548,6 @@ class ServingEngine:
         if kv_tier is not None:
             self.kv_tier = kv_tier
         elif g["serving_host_tier"]:
-            if not self.paged:
-                raise ValueError(
-                    "the host KV tier requires the paged KV cache "
-                    "(FLAGS_serving_paged); dense slots have no "
-                    "block-granular migration")
             self.kv_tier = TierManager(HostBlockStore(
                 cfg.num_layers, cfg.num_heads, cfg.head_dim,
                 block_size=self.cache.block_size,
@@ -597,11 +555,6 @@ class ServingEngine:
         else:
             self.kv_tier = None
         if self.kv_tier is not None:
-            if not self.paged:
-                raise ValueError(
-                    "the host KV tier requires the paged KV cache "
-                    "(FLAGS_serving_paged); dense slots have no "
-                    "block-granular migration")
             self.kv_tier.attach(self.cache)
         # first-seen-cold timestamps feeding the between-steps demotion
         # sweep (FLAGS_serving_demote_idle_ms); step-lock-owned like
@@ -678,18 +631,17 @@ class ServingEngine:
         self._spec_accepted = 0     # guarded-by: _step_lock
         self._prefix_hit_reqs = 0   # guarded-by: _step_lock
         self._prefix_miss_reqs = 0  # guarded-by: _step_lock
-        if self.paged:
-            self._blocks_used_g = _obs.gauge(
-                "serving_kv_blocks_used",
-                "physical KV blocks currently referenced (paged "
-                "serving; includes the trash block and prefix-cache "
-                "holds)").labels(engine=eid, tier="device")
-            self._blocks_free_g = _obs.gauge(
-                "serving_kv_blocks_free",
-                "physical KV blocks on the free list (paged serving)"
-                ).labels(engine=eid, tier="device")
-            self._blocks_used_g.set(self.cache.blocks_used)
-            self._blocks_free_g.set(self.cache.blocks_free)
+        self._blocks_used_g = _obs.gauge(
+            "serving_kv_blocks_used",
+            "physical KV blocks currently referenced (paged "
+            "serving; includes the trash block and prefix-cache "
+            "holds)").labels(engine=eid, tier="device")
+        self._blocks_free_g = _obs.gauge(
+            "serving_kv_blocks_free",
+            "physical KV blocks on the free list (paged serving)"
+            ).labels(engine=eid, tier="device")
+        self._blocks_used_g.set(self.cache.blocks_used)
+        self._blocks_free_g.set(self.cache.blocks_free)
         # which paged-attention lowering this engine runs (1 on the
         # active impl/dtype series — the Prometheus idiom for enums)
         _obs.gauge(
@@ -732,7 +684,7 @@ class ServingEngine:
         # were deleted (donated: the KV rows were written in place)
         self._pool_dispatches = 0         # guarded-by: _step_lock
         self._pool_inplace = 0            # guarded-by: _step_lock
-        self._pool_epoch = self.cache.pool.epoch if self.paged else 0
+        self._pool_epoch = self.cache.pool.epoch
         # decode/verify dispatches, and those whose batch was all
         # greedy: the step's lax.cond took the branch without the
         # sampler (decoding._where_any_sampled)
@@ -1162,14 +1114,13 @@ class ServingEngine:
             raise ValueError(
                 f"prompt ({len(prompt)}) + max_new_tokens ({mnt})"
                 f"{spec} exceeds slot capacity max_len={self.max_len}")
-        if self.paged:
-            need = self.cache.blocks_needed(
-                len(prompt) + mnt + self.spec_tokens)
-            if need > self.cache.num_blocks - 1:  # minus trash block
-                raise ValueError(
-                    f"request needs {need} KV blocks but the pool only "
-                    f"has {self.cache.num_blocks - 1} usable; raise "
-                    "FLAGS_serving_num_blocks or shorten the request")
+        need = self.cache.blocks_needed(
+            len(prompt) + mnt + self.spec_tokens)
+        if need > self.cache.num_blocks - 1:  # minus trash block
+            raise ValueError(
+                f"request needs {need} KV blocks but the pool only "
+                f"has {self.cache.num_blocks - 1} usable; raise "
+                "FLAGS_serving_num_blocks or shorten the request")
         pr = int(priority if priority is not None else 1)
         now = self._clock()
         if _log_request and _runlog.enabled():
@@ -1320,85 +1271,23 @@ class ServingEngine:
         return self.max_len  # unreachable: submit() validated length
 
     def _prefill_entry(self, bucket: int) -> dict:
-        """The jitted prompt pass for one length bucket (compiled on
-        first use, reused for every admission that pads to it). Fixed
-        batch = ``max_slots`` so every same-bucket admission in a step
-        shares ONE dispatch: maps ``(ids [max_slots, bucket] i32,
-        last [max_slots] i32)`` to each row's logits at its true last
-        prompt position plus full-capacity cache rows; rows past the
-        admitted count are padding the caller discards.
-
-        Cached in the model's unified ``step_entry`` cache keyed by
-        (bucket, max_slots, max_len) — like ``decode_step``/
-        ``verify_step`` — so engine restarts with the same geometry
-        (benchmark reruns, rolling deploys) reuse the executable
-        instead of paying the prefill compile again."""
-        model, max_len, slots = self.model, self.max_len, self.max_slots
-
-        def _build():
-            from ..models.generation import (_borrowed_params,
-                                             _inject_params)
-
-            def _prefill(params, ids, last):
-                with no_grad(), _borrowed_params(model, params):
-                    cache = model.gpt.gen_fixed_cache(slots, max_len)
-                    logits, newc = model(
-                        Tensor(ids, stop_gradient=True), cache=cache,
-                        cache_pos=0)
-                lg = jnp.take_along_axis(logits.value,
-                                         last[:, None, None],
-                                         axis=1)[:, 0]
-                return lg, [(c[0].value, c[1].value) for c in newc]
-
-            fn = _inject_params(
-                model, _ct.tracked_jit("serving_prefill", _prefill,
-                                       labels={"bucket": str(bucket)}))
-            return {"fn": fn, "traces": fn.traces}
-
-        ent = step_entry(model, ("prefill", bucket, slots, max_len),
-                         _build)
-        self._prefill_fns[bucket] = ent
-        return ent
-
-    def _prefill_group_attempt(self, bucket: int, group: List[Request]):
-        """One batched prefill attempt for every same-bucket admission.
-        The fault site fires once per request per attempt (preserving
-        the per-request `skip`-sheds-one semantics); surviving requests
-        share one dispatch of the bucket's compiled function. Returns
-        ``(live, shed, (logits, rows) | None)``."""
-        live, shed = [], []
-        for req in group:
-            kind = fault_point("serving.step")
-            if kind == "skip":
-                shed.append((req, _Shed("injected skip during prefill "
-                                        f"of request {req.id}")))
-            else:
-                live.append(req)
-        if not live:
-            return live, shed, None
-        ids = np.zeros((self.max_slots, bucket), np.int32)
-        last = np.zeros(self.max_slots, np.int32)
-        for i, req in enumerate(live):
-            ctx = req.context
-            ids[i, :len(ctx)] = ctx
-            last[i] = len(ctx) - 1
-        fn = self._prefill_entry(bucket)["fn"]
-        return live, shed, fn(jnp.asarray(ids), jnp.asarray(last))
-
-    # ----------------------------------------------------- paged prefill
-    def _prefill_entry_paged(self, bucket: int) -> dict:
-        """The paged sibling of :meth:`_prefill_entry`: one jitted
-        prompt-suffix pass per length bucket at a fixed ``max_slots``
-        batch, writing KV through per-row block tables into the shared
-        pools. Maps ``(ids [max_slots, bucket] i32, last [max_slots]
-        i32, pos [max_slots] i32, tables [max_slots, T] i32, pools)``
+        """The jitted prompt-suffix pass for one length bucket
+        (compiled on first use, reused for every admission that pads
+        to it) at a fixed ``max_slots`` batch, so every same-bucket
+        admission in a step shares ONE dispatch; it writes KV through
+        per-row block tables into the shared pools. Maps
+        ``(ids [max_slots, bucket] i32, last [max_slots] i32,
+        pos [max_slots] i32, tables [max_slots, T] i32, pools)``
         to each row's logits at its true last token plus the updated
         pools; ``pos`` is each row's write offset (its shared-prefix
         length — 0 without a prefix hit), so a prefix-cached prompt
-        only computes its unshared suffix. Cached in the model's
+        only computes its unshared suffix; rows past the admitted
+        count are padding the caller discards. Cached in the model's
         unified ``step_entry`` cache keyed by the full pool geometry,
-        attn impl, KV dtype, and mesh — one compile per key. Under a
-        mesh the pass runs with explicit in/out shardings: pools keep
+        attn impl, KV dtype, and mesh — one compile per key, so
+        engine restarts with the same geometry (benchmark reruns,
+        rolling deploys) reuse the executable. Under a mesh the
+        pass runs with explicit in/out shardings: pools keep
         their heads axis on ``"model"``; ids/last/pos/tables stay
         replicated plain inputs so block remapping never retraces."""
         key = ("prefill_paged", bucket, self.max_slots, self.max_len,
@@ -1475,11 +1364,13 @@ class ServingEngine:
             self.kv_tier.promote(self.cache, req.context)
         return self.cache.acquire(req.context, need)
 
-    def _prefill_group_attempt_paged(self, bucket: int, group):
-        """One batched paged-prefill attempt for every same-bucket
-        admission; ``group`` rows are ``(req, row, shared)``. Same
-        per-request fault semantics as the dense path. Returns
-        ``(live, shed, (logits, new_pools) | None)``."""
+    def _prefill_group_attempt(self, bucket: int, group):
+        """One batched prefill attempt for every same-bucket
+        admission; ``group`` rows are ``(req, row, shared)``. The
+        fault site fires once per request per attempt (preserving the
+        per-request `skip`-sheds-one semantics); surviving requests
+        share one dispatch of the bucket's compiled function. Returns
+        ``(live, shed, (logits, new_pools, qerr) | None)``."""
         live, shed = [], []
         for rec in group:
             kind = fault_point("serving.step")
@@ -1505,7 +1396,7 @@ class ServingEngine:
             tables[i] = self.cache.tables[row]
             if self.lora_pool is not None and req.tenant:
                 pages[i] = self.lora_pool.page_of(req.tenant)
-        fn = self._prefill_entry_paged(bucket)["fn"]
+        fn = self._prefill_entry(bucket)["fn"]
         args = (jnp.asarray(ids), jnp.asarray(last),
                 jnp.asarray(pos), jnp.asarray(tables),
                 self.cache.arrays())
@@ -1550,8 +1441,8 @@ class ServingEngine:
             self._finalize_cancel(req, "queued", "deadline")
         return out, len(expired) + len(hard_expired)
 
-    def _admit_round_paged(self):  # holds: _step_lock
-        """One paged admission pass: pop queued requests in admission
+    def _admit_round(self):  # holds: _step_lock
+        """One admission pass: pop queued requests in admission
         order (FIFO within a priority class), acquire a block table
         for each (prefix-cache reuse first), group by the unshared
         *suffix*'s bucket, one batched prefill per group. Pool
@@ -1614,14 +1505,13 @@ class ServingEngine:
             with _profiler.RecordEvent(
                     "serving.prefill_step",
                     {"bucket": bucket, "rows": len(groups[bucket])}):
-                admitted += self._prefill_group_paged(bucket,
-                                                      groups[bucket])
+                admitted += self._prefill_group(bucket, groups[bucket])
         return consumed, admitted
 
-    def _prefill_group_paged(self, bucket: int,
-                             group) -> int:  # holds: _step_lock
-        """One group of a paged admission round, from building its
-        inputs to its first tokens committed. Returns rows admitted."""
+    def _prefill_group(self, bucket: int,
+                       group) -> int:  # holds: _step_lock
+        """One group of an admission round, from building its inputs
+        to its first tokens committed. Returns rows admitted."""
         t_adm = self._clock()
         for g_req, _row, _shared in group:
             _tracing.mark(g_req.id, "admit", t_adm, self.trace_track)
@@ -1633,8 +1523,7 @@ class ServingEngine:
                     _profiler.RecordEvent("serving.prefill"):
                 live, shed, out = RetryPolicy.from_flags(
                     "serving.step").call(
-                        self._prefill_group_attempt_paged,
-                        bucket, group)
+                        self._prefill_group_attempt, bucket, group)
         except (RetryError, _PoolsLost) as e:
             for req, row, _ in group:
                 self.cache.release_row(row)
@@ -1683,85 +1572,6 @@ class ServingEngine:
                     # a re-homed request re-prefilled its committed
                     # context: the original trace resumes decoding
                     # here instead of re-stamping a first token
-                    _tracing.mark(req.id, "resume", self._clock(),
-                                  self.trace_track)
-                self._append_token(
-                    req, self._take_first(req, first, lg, i), now)
-        if timer is not None:
-            timer.finish()
-        return len(live)
-
-    def _admit_round(self):  # holds: _step_lock
-        """One admission pass: pop up to num_free queued requests,
-        group them by prefill bucket, and run ONE batched prefill per
-        group. Returns (popped, admitted)."""
-        if self.paged:
-            return self._admit_round_paged()
-        with _profiler.RecordEvent("serving.schedule") as sched:
-            candidates, expired = self._pop_candidates(
-                self.cache.num_free)
-            if not candidates:
-                return expired, 0
-            groups: Dict[int, List[Request]] = {}
-            for req in candidates:
-                groups.setdefault(self._bucket_for(len(req.context)),
-                                  []).append(req)
-            sched.args = {"admitted": len(candidates)}
-        admitted = 0
-        for bucket in sorted(groups):
-            with _profiler.RecordEvent(
-                    "serving.prefill_step",
-                    {"bucket": bucket, "rows": len(groups[bucket])}):
-                admitted += self._prefill_group(bucket, groups[bucket])
-        return expired + len(candidates), admitted
-
-    def _prefill_group(self, bucket: int,
-                       group: List[Request]) -> int:  # holds: _step_lock
-        """The unpaged twin of :meth:`_prefill_group_paged`."""
-        t_adm = self._clock()
-        for g_req in group:
-            _tracing.mark(g_req.id, "admit", t_adm, self.trace_track)
-        timer = self._devprof_timer(
-            f"serving_prefill{{bucket={bucket}}}")
-        t0 = time.perf_counter()
-        try:
-            with _monitor.stat_time("STAT_serving_prefill"), \
-                    _profiler.RecordEvent("serving.prefill"):
-                live, shed, out = RetryPolicy.from_flags(
-                    "serving.step").call(self._prefill_group_attempt,
-                                         bucket, group)
-        except RetryError as e:
-            for req in group:
-                self._shed(req, e)
-            return 0
-        if out is not None:
-            # EMA window closes before the devprof sync (see the
-            # paged twin above)
-            self._note_prefill_ms(
-                bucket, (time.perf_counter() - t0) * 1e3)
-        if timer is not None and out is not None:
-            timer.device_done(out)
-        for req, err in shed:
-            self._shed(req, err)
-        if not live:
-            return 0
-        lg, rows = out
-        slots = [self.cache.alloc() for _ in live]
-        self.cache.write_prefill_batch(
-            slots, rows, [len(r.context) for r in live])
-        with _profiler.RecordEvent("serving.prefill.fetch"):
-            first = np.asarray(jnp.argmax(lg, axis=-1))
-        with _profiler.RecordEvent("serving.prefill.commit"):
-            now = self._clock()     # the commit's one stamp
-            for i, (req, slot) in enumerate(zip(live, slots)):
-                req.slot = slot
-                req.state = "running"
-                self._active[slot] = req
-                _monitor.stat_add("STAT_serving_prefills")
-                _runlog.log_event("serving_admit", request=req.id,
-                                  bucket=bucket, slot=slot,
-                                  prompt_tokens=len(req.prompt))
-                if req.first_token_at is not None:
                     _tracing.mark(req.id, "resume", self._clock(),
                                   self.trace_track)
                 # the first generated token comes from the prefill
@@ -1859,7 +1669,7 @@ class ServingEngine:
         """Shed every running request and free its row."""
         for slot, req in list(self._active.items()):
             del self._active[slot]
-            self.cache.release(slot)
+            self.cache.release_row(slot)
             self._shed(req, err)
 
     def _call_paged(self, fn, args, pools):  # holds: _step_lock
@@ -1908,29 +1718,26 @@ class ServingEngine:
             self._sampler_skipped += 1
             _monitor.stat_add("STAT_serving_sampler_skipped")
 
+    def _step_args(self, tokens: np.ndarray):  # holds: _step_lock
+        """The inputs of one decode or verify dispatch after the
+        params: ``(tokens, lengths, tables, pools, samp[, lora])``."""
+        with _profiler.RecordEvent("serving.decode.inputs"):
+            args = (jnp.asarray(tokens),
+                    jnp.asarray(self.cache.lengths),
+                    jnp.asarray(self.cache.tables),
+                    self.cache.arrays(), self._build_samp())
+            if self._lora_shape is not None:
+                args = args + (self._lora_args(),)
+        return args
+
     def _decode_attempt(self, tokens: np.ndarray):
         kind = fault_point("serving.step")
         if kind == "skip":
             raise _SkipStep("injected skip of one decode iteration")
-        if self.paged:
-            fn = decode_step_paged(self.model, self.mesh,
-                                   self.kv_dtype,
-                                   self._lora_shape)["fn"]
-            with _profiler.RecordEvent("serving.decode.inputs"):
-                args = (jnp.asarray(tokens),
-                        jnp.asarray(self.cache.lengths),
-                        jnp.asarray(self.cache.tables),
-                        self.cache.arrays(), self._build_samp())
-                if self._lora_shape is not None:
-                    args = args + (self._lora_args(),)
-            out = self._call_paged(fn, args, args[3])
-        else:
-            fn = decode_step(self.model)["fn"]
-            with _profiler.RecordEvent("serving.decode.inputs"):
-                args = (jnp.asarray(tokens),
-                        jnp.asarray(self.cache.lengths),
-                        self.cache.arrays(), self._build_samp())
-            out = fn(*args)
+        fn = decode_step_paged(self.model, self.mesh, self.kv_dtype,
+                               self._lora_shape)["fn"]
+        args = self._step_args(tokens)
+        out = self._call_paged(fn, args, args[3])
         self._note_sampler()
         return out
 
@@ -1974,8 +1781,7 @@ class ServingEngine:
         tokens = np.zeros(self.max_slots, np.int32)
         for slot, req in self._active.items():
             tokens[slot] = req.tokens[-1]
-        timer = self._devprof_timer(
-            "decode_step_paged" if self.paged else "decode_step")
+        timer = self._devprof_timer("decode_step_paged")
         t0 = time.perf_counter()
         try:
             with _monitor.stat_time("STAT_serving_decode"), \
@@ -1998,11 +1804,7 @@ class ServingEngine:
         self._note_tpot_ms((time.perf_counter() - t0) * 1e3)
         if timer is not None:
             timer.device_done(out)   # block_until_ready + stamp
-        qerr = None
-        if self.paged:
-            nxt, _, arrays, qerr, new_keys = out
-        else:
-            nxt, _, arrays, new_keys = out
+        nxt, _, arrays, qerr, new_keys = out
         with _profiler.RecordEvent("serving.decode.fetch"):
             nxt = np.asarray(nxt)     # the host waits for the device
         with _profiler.RecordEvent("serving.decode.commit",
@@ -2275,7 +2077,7 @@ class ServingEngine:
         a state the single step advanced. Whatever runs is one
         ``serving.decode_step`` span, from building the step's tokens
         to its last commit; an idle engine records none."""
-        if self.paged and self.cache.pool.epoch != self._pool_epoch:
+        if self.cache.pool.epoch != self._pool_epoch:
             # a co-located engine's failed step took the shared pool's
             # contents (see _call_paged): this engine's rows went too
             self._pool_epoch = self.cache.pool.epoch
@@ -2301,25 +2103,10 @@ class ServingEngine:
         kind = fault_point("serving.step")
         if kind == "skip":
             raise _SkipStep("injected skip of one verify iteration")
-        if self.paged:
-            fn = verify_step_paged(self.model, self.spec_tokens,
-                                   self.mesh, self.kv_dtype,
-                                   self._lora_shape)["fn"]
-            with _profiler.RecordEvent("serving.decode.inputs"):
-                args = (jnp.asarray(tokens),
-                        jnp.asarray(self.cache.lengths),
-                        jnp.asarray(self.cache.tables),
-                        self.cache.arrays(), self._build_samp())
-                if self._lora_shape is not None:
-                    args = args + (self._lora_args(),)
-            out = self._call_paged(fn, args, args[3])
-        else:
-            fn = verify_step(self.model, self.spec_tokens)["fn"]
-            with _profiler.RecordEvent("serving.decode.inputs"):
-                args = (jnp.asarray(tokens),
-                        jnp.asarray(self.cache.lengths),
-                        self.cache.arrays(), self._build_samp())
-            out = fn(*args)
+        fn = verify_step_paged(self.model, self.spec_tokens, self.mesh,
+                               self.kv_dtype, self._lora_shape)["fn"]
+        args = self._step_args(tokens)
+        out = self._call_paged(fn, args, args[3])
         self._note_sampler()
         return out
 
@@ -2339,9 +2126,7 @@ class ServingEngine:
             tokens[slot, 0] = req.tokens[-1]
             tokens[slot, 1:] = d
         n_active = len(self._active)
-        timer = self._devprof_timer(
-            f"verify_step_paged{{k={K}}}" if self.paged
-            else f"verify_step{{k={K}}}")
+        timer = self._devprof_timer(f"verify_step_paged{{k={K}}}")
         t0 = time.perf_counter()
         try:
             with _monitor.stat_time("STAT_serving_verify"), \
@@ -2355,11 +2140,7 @@ class ServingEngine:
             return 0
         if timer is not None:
             timer.device_done(out)
-        qerr = None
-        if self.paged:
-            nxt, _, arrays, qerr, accept, new_keys = out
-        else:
-            nxt, _, arrays, accept, new_keys = out
+        nxt, _, arrays, qerr, accept, new_keys = out
         with _profiler.RecordEvent("serving.decode.fetch"):
             nxt = np.asarray(nxt)
             accept = np.asarray(accept)
@@ -2464,7 +2245,7 @@ class ServingEngine:
                 # between-steps sweep demotes the now-cold chain to
                 # host RAM, and the next turn resumes off it
                 self.cache.insert_prefix(req.slot, req.context)
-            self.cache.release(req.slot)
+            self.cache.release_row(req.slot)
             req.slot = None
         if req._lora_held:
             self.lora_pool.release(req.tenant)
@@ -2580,7 +2361,7 @@ class ServingEngine:
                 slot = req.slot
                 if slot is not None and self._active.get(slot) is req:
                     del self._active[slot]
-                    self.cache.release(slot)
+                    self.cache.release_row(slot)
                     req.slot = None
                     stage = ("decode" if req.first_token_at is not None
                              else "prefill")
@@ -2636,7 +2417,7 @@ class ServingEngine:
             hd = req.hard_deadline
             if hd is not None and now > hd:
                 del self._active[slot]
-                self.cache.release(slot)
+                self.cache.release_row(slot)
                 req.slot = None
                 stage = ("decode" if req.first_token_at is not None
                          else "prefill")
@@ -2664,9 +2445,8 @@ class ServingEngine:
                 produced = self._decode_any()
                 if self.kv_tier is not None:
                     self._demote_sweep()
-                if self.paged:
-                    self._blocks_used_g.set(self.cache.blocks_used)
-                    self._blocks_free_g.set(self.cache.blocks_free)
+                self._blocks_used_g.set(self.cache.blocks_used)
+                self._blocks_free_g.set(self.cache.blocks_free)
                 return bool(admitted or produced or reaped)
 
     def _demote_sweep(self):  # holds: _step_lock
@@ -2778,7 +2558,6 @@ class ServingEngine:
         # greedy (the step skipped the sampler on the device)
         out["sampler_dispatches"] = sampler_dispatches
         out["sampler_skipped"] = sampler_skipped
-        out["paged"] = self.paged
         out["attn_impl"] = self.attn_impl
         out["kv_dtype"] = self.kv_dtype
         out["mesh_shape"] = (None if self.mesh_shape is None
@@ -2812,35 +2591,34 @@ class ServingEngine:
             # MFU/HBM utilization and verdicts) — flows into
             # GET /v1/stats with the rest of this dict
             out["devprof"] = self._devprof.stats()
-        if self.paged:
-            c = self.cache
-            hit_t, miss_t = c.prefix_hits, c.prefix_misses
-            out.update({
-                "block_size": c.block_size,
-                "num_blocks": c.num_blocks,
-                "kv_blocks_used": c.blocks_used,
-                "kv_blocks_free": c.blocks_free,
-                "prefix_cache": c.prefix_cache_enabled,
-                "prefix_entries": c.prefix_entries,
-                # request-granular (an admission that reused >=1 block
-                # is a hit) and token-granular (prompt tokens whose KV
-                # came from the cache vs were prefilled)
-                "prefix_hit_requests": prefix_hit_reqs,
-                "prefix_miss_requests": prefix_miss_reqs,
-                "prefix_hit_tokens": hit_t,
-                "prefix_miss_tokens": miss_t,
-                "prefix_hit_rate": (round(hit_t / (hit_t + miss_t), 4)
-                                    if hit_t + miss_t else None),
-                # paged dispatches, and the share of them that wrote
-                # their KV rows in place (the pools handed in were
-                # deleted by the call): 1.0 unless the backend ignores
-                # donation
-                "pool_dispatches": pool_dispatches,
-                "pool_inplace": pool_inplace,
-                "pool_inplace_share": (
-                    round(pool_inplace / pool_dispatches, 4)
-                    if pool_dispatches else None),
-            })
+        c = self.cache
+        hit_t, miss_t = c.prefix_hits, c.prefix_misses
+        out.update({
+            "block_size": c.block_size,
+            "num_blocks": c.num_blocks,
+            "kv_blocks_used": c.blocks_used,
+            "kv_blocks_free": c.blocks_free,
+            "prefix_cache": c.prefix_cache_enabled,
+            "prefix_entries": c.prefix_entries,
+            # request-granular (an admission that reused >=1 block
+            # is a hit) and token-granular (prompt tokens whose KV
+            # came from the cache vs were prefilled)
+            "prefix_hit_requests": prefix_hit_reqs,
+            "prefix_miss_requests": prefix_miss_reqs,
+            "prefix_hit_tokens": hit_t,
+            "prefix_miss_tokens": miss_t,
+            "prefix_hit_rate": (round(hit_t / (hit_t + miss_t), 4)
+                                if hit_t + miss_t else None),
+            # paged dispatches, and the share of them that wrote
+            # their KV rows in place (the pools handed in were
+            # deleted by the call): 1.0 unless the backend ignores
+            # donation
+            "pool_dispatches": pool_dispatches,
+            "pool_inplace": pool_inplace,
+            "pool_inplace_share": (
+                round(pool_inplace / pool_dispatches, 4)
+                if pool_dispatches else None),
+        })
         return out
 
     @property

@@ -44,8 +44,8 @@ minimal JSON generation protocol:
                              Prometheus text exposition format
                              (serving counters/latency histograms,
                              fault counters, XLA compile tracking)
-  GET  /health        -> 200 {"ok": true, "slots_free": n, "queued": n}
-                             (+ kv_blocks_free/used with paged KV)
+  GET  /health        -> 200 {"ok": true, "slots_free": n, "queued": n,
+                              "kv_blocks_free": n, "kv_blocks_used": n}
   GET  /v1/requests/<id>
                       -> 200 the request's span timeline + blame
                              breakdown from the tracing store (marks
@@ -124,10 +124,9 @@ class _ServingHandler(BaseHTTPRequestHandler):
         if self.path == "/health":
             payload = {"ok": True,
                        "slots_free": engine.cache.num_free,
-                       "queued": len(engine._queue)}
-            if engine.paged:
-                payload["kv_blocks_free"] = engine.cache.blocks_free
-                payload["kv_blocks_used"] = engine.cache.blocks_used
+                       "queued": len(engine._queue),
+                       "kv_blocks_free": engine.cache.blocks_free,
+                       "kv_blocks_used": engine.cache.blocks_used}
             self._json(200, payload)
         elif self.path == "/v1/stats":
             payload = _monitor.stats_with_prefix("STAT_serving")

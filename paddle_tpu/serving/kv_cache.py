@@ -1,22 +1,15 @@
-"""KV cache memory managers for the serving plane.
+"""The KV cache memory manager of the serving plane.
 
-Two designs live here:
-
-- :class:`BlockKVCache` — the production design: a fixed pool of
-  ``[num_blocks, heads, block_size, head_dim]`` KV *blocks* per layer,
-  a per-request host-side block table mapping logical positions to
-  physical blocks (vLLM/PagedAttention-style), a ref-counted
-  :class:`BlockAllocator`, and a prefix cache keyed on a rolling hash
-  of the token prefix so a shared system prompt prefills once and its
-  blocks are *referenced* (copy-on-write at the boundary block) by
-  every subsequent request. A request pays ``ceil(need/block_size)``
-  blocks instead of a full ``max_len`` row, minus whatever prefix it
-  shares — the memory unlock for high-concurrency serving.
-
-- :class:`SlotKVCache` — the original dense design (one
-  ``[max_slots, heads, max_len, head_dim]`` pair per layer, one *slot*
-  row per request), kept as the ``paged=False`` fallback and the
-  benchmark baseline the paged cache is measured against.
+:class:`BlockKVCache`: a fixed pool of
+``[num_blocks, heads, block_size, head_dim]`` KV *blocks* per layer,
+a per-request host-side block table mapping logical positions to
+physical blocks (vLLM/PagedAttention-style), a ref-counted
+:class:`BlockAllocator`, and a prefix cache keyed on a rolling hash
+of the token prefix so a shared system prompt prefills once and its
+blocks are *referenced* (copy-on-write at the boundary block) by
+every subsequent request. A request pays ``ceil(need/block_size)``
+blocks instead of a full ``max_len`` row, minus whatever prefix it
+shares — the memory unlock for high-concurrency serving.
 
 The physical half of :class:`BlockKVCache` — the block arrays, the
 ref-counted :class:`BlockAllocator` and the prefix cache — lives in a
@@ -28,11 +21,11 @@ transfer, zero ref changes), while engines on distinct pools copy the
 committed blocks through the destination allocator (``adopt_row``).
 Either way ``BlockAllocator.leaked()`` stays exact across the handoff.
 
-Both keep every buffer at a fixed shape so the batched decode step has
-a single signature and compiles exactly once; admitting or retiring a
+Every buffer keeps a fixed shape so the batched decode step has a
+single signature and compiles exactly once; admitting or retiring a
 request is bookkeeping, never a recompile.
 
-Slot/row lifecycle (shared by both): allocate at admission -> the
+Row lifecycle: allocate at admission -> the
 bucketed prompt pass populates KV rows and sets the valid length ->
 per-step in-place writes inside the compiled decode (``advance``: +1
 per plain decode token, +K+1 per speculative verify) -> ``rollback``
@@ -52,107 +45,6 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 import jax
-
-
-class SlotKVCache:
-    """Fixed-geometry KV storage + slot free list.
-
-    The jnp arrays are functionally updated (the compiled decode step
-    returns replacement buffers via :meth:`set_arrays`); the host-side
-    ``lengths`` vector tracks each slot's valid prefix and doubles as
-    the decode step's position input.
-    """
-
-    def __init__(self, num_layers: int, num_heads: int, head_dim: int,
-                 max_slots: int, max_len: int, dtype=None):
-        import jax.numpy as jnp
-        dtype = dtype or jnp.float32
-        shape = (max_slots, num_heads, max_len, head_dim)
-        self.max_slots = int(max_slots)
-        self.max_len = int(max_len)
-        self.layers: List[Tuple[jax.Array, jax.Array]] = [
-            (jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
-            for _ in range(num_layers)]
-        self.lengths = np.zeros(max_slots, np.int32)
-        # kept sorted so admission order -> slot order is deterministic
-        # (the equivalence tests replay exact schedules)
-        self._free = list(range(max_slots))
-
-    @property
-    def num_free(self) -> int:
-        return len(self._free)
-
-    @property
-    def num_used(self) -> int:
-        return self.max_slots - len(self._free)
-
-    def alloc(self) -> Optional[int]:
-        """Claim the lowest free slot, or None when full."""
-        return self._free.pop(0) if self._free else None
-
-    def release(self, slot: int):
-        self.lengths[slot] = 0
-        insort(self._free, slot)
-
-    def write_prefill(self, slot: int, rows, length: int):
-        """Install a prefilled row: ``rows`` is one (k, v) pair per
-        layer shaped [1, heads, max_len, d] (full capacity, as produced
-        by the bucketed prefill function); ``length`` is the true
-        prompt length — entries past it are padding the position mask
-        hides until decode overwrites them."""
-        self.layers = [
-            (k.at[slot].set(rk[0]), v.at[slot].set(rv[0]))
-            for (k, v), (rk, rv) in zip(self.layers, rows)]
-        self.lengths[slot] = int(length)
-
-    def write_prefill_batch(self, slots, rows, lengths):
-        """Install several prefilled rows in one functional update per
-        layer: ``rows`` is one (k, v) pair per layer shaped
-        [batch, heads, max_len, d] (a batched prefill's output; only
-        the first ``len(slots)`` batch rows are meaningful — the rest
-        are padding), row i landing in ``slots[i]`` with true prompt
-        length ``lengths[i]``."""
-        import jax.numpy as jnp
-        n = len(slots)
-        if n != len(lengths):
-            raise ValueError(f"{n} slots but {len(lengths)} lengths")
-        sl = jnp.asarray(np.asarray(slots, np.int32))
-        self.layers = [
-            (k.at[sl].set(rk[:n]), v.at[sl].set(rv[:n]))
-            for (k, v), (rk, rv) in zip(self.layers, rows)]
-        for s, ln in zip(slots, lengths):
-            self.lengths[s] = int(ln)
-
-    def advance(self, slot: int, n: int = 1):
-        """Advance a slot's valid length by ``n`` freshly written rows
-        (1 for a plain decode token, K+1 after a speculative verify —
-        committed optimistically, then trimmed via :meth:`rollback`)."""
-        ln = int(self.lengths[slot]) + int(n)
-        if ln > self.max_len:
-            raise ValueError(
-                f"slot {slot}: advancing by {n} overflows capacity "
-                f"max_len={self.max_len} (at {self.lengths[slot]})")
-        self.lengths[slot] = ln
-
-    def rollback(self, slot: int, n: int):
-        """Roll a slot's write offset back over ``n`` rejected rows
-        (the speculative verify's unaccepted draft tail). The rows'
-        contents stay in the buffer but sit past the valid length, so
-        the position mask hides them and the next write at this offset
-        overwrites them."""
-        if n < 0 or n > int(self.lengths[slot]):
-            raise ValueError(
-                f"slot {slot}: cannot roll back {n} rows from length "
-                f"{self.lengths[slot]}")
-        self.lengths[slot] = int(self.lengths[slot]) - int(n)
-
-    def arrays(self):
-        """The per-layer (k, v) buffers, as fed to the decode step."""
-        return list(self.layers)
-
-    def set_arrays(self, layers):
-        """Adopt the decode step's returned buffers."""
-        self.layers = [(k, v) for k, v in layers]
 
 
 class BlockAllocator:
@@ -398,11 +290,10 @@ class BlockKVCache:
     write into a block other requests read. Entries idle at
     refcount 1 (cache-only) are evicted LRU when the pool runs dry.
 
-    The row-level API mirrors :class:`SlotKVCache` (``lengths``,
-    ``advance``/``rollback``, ``arrays``/``set_arrays``,
-    ``num_free``/``num_used`` count *rows*) so the engine and the
-    chaos suite treat both interchangeably; block-level accounting is
-    exposed via ``blocks_free``/``blocks_used``.
+    The row-level API (``lengths``, ``advance``/``rollback``,
+    ``arrays``/``set_arrays``) counts *rows* in ``num_free``/
+    ``num_used``; block-level accounting is exposed via
+    ``blocks_free``/``blocks_used``.
     """
 
     TRASH = 0  # physical block 0: permanent ref, padding + overflow sink
@@ -530,7 +421,7 @@ class BlockKVCache:
     def blocks_used(self) -> int:
         return self.allocator.num_used
 
-    # row-level view, API-compatible with SlotKVCache
+    # row-level view
     @property
     def num_free(self) -> int:
         return len(self._free_rows)
@@ -654,10 +545,6 @@ class BlockKVCache:
         self._nblocks[row] = 0
         self.lengths[row] = 0
         insort(self._free_rows, row)
-
-    # SlotKVCache-compatible aliases (engine + chaos suite call these)
-    def release(self, row: int):
-        self.release_row(row)
 
     def insert_prefix(self, row: int, prompt: Sequence[int]):
         """Publish a just-prefilled prompt's full blocks into the
@@ -813,7 +700,7 @@ class BlockKVCache:
         self.lengths[row] = length
         return row
 
-    # -- per-step bookkeeping (same contract as SlotKVCache) ---------
+    # -- per-step bookkeeping ---------------------------------------
 
     def commit_prefill(self, row: int, length: int):
         """The prompt pass populated this row's blocks up to
@@ -825,6 +712,9 @@ class BlockKVCache:
         self.lengths[row] = int(length)
 
     def advance(self, row: int, n: int = 1):
+        """Advance a row's valid length by ``n`` freshly written rows
+        (1 for a plain decode token, K+1 after a speculative verify —
+        committed optimistically, then trimmed via :meth:`rollback`)."""
         ln = int(self.lengths[row]) + int(n)
         if ln > int(self._nblocks[row]) * self.block_size:
             raise ValueError(

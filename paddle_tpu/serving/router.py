@@ -122,9 +122,7 @@ class AutoscalePolicy:
         depths = [router._depth(e) for e in router.engines]
         mean_depth = sum(depths) / n
         free_frac = min(
-            (router._blocks_free(e) / max(1, e.cache.num_blocks)
-             if e.paged else
-             router._blocks_free(e) / max(1, e.max_slots))
+            e.cache.blocks_free / max(1, e.cache.num_blocks)
             for e in router.engines)
         att = router._slo_attainment()
         pressured = (mean_depth > self.queue_high or
@@ -478,10 +476,6 @@ class ReplicaRouter:
         with eng._lock:
             return len(eng._queue) + len(eng._active)
 
-    def _blocks_free(self, eng: ServingEngine) -> int:
-        return (eng.cache.blocks_free if eng.paged
-                else eng.cache.num_free)
-
     def _shed_total(self, eng: ServingEngine) -> int:
         with eng._lock:
             return sum(eng._shed_by_reason.values())
@@ -529,7 +523,7 @@ class ReplicaRouter:
             range(len(self.engines)),
             key=lambda i: (_HEALTH_RANK[self.engines[i]._health],
                            self._depth(self.engines[i]),
-                           -self._blocks_free(self.engines[i]), i))
+                           -self.engines[i].cache.blocks_free, i))
         last_err: Optional[QueueFullError] = None
         for i in order:
             eng = self.engines[i]
@@ -564,7 +558,7 @@ class ReplicaRouter:
             _monitor.stat_add("STAT_serving_routed")
             _runlog.log_event("serving_route", request=req.id,
                               replica=i, depth=self._depth(eng),
-                              kv_blocks_free=self._blocks_free(eng))
+                              kv_blocks_free=eng.cache.blocks_free)
             self._depth_gauges[i].set(self._depth(eng))
             return req
         _monitor.stat_add("STAT_serving_route_shed")
@@ -688,7 +682,7 @@ class ReplicaRouter:
         peers = sorted(self._routable(but=h["primary"]),
                        key=lambda p: (_HEALTH_RANK[p._health],
                                       self._depth(p),
-                                      -self._blocks_free(p)))
+                                      -p.cache.blocks_free))
         clone = None
         for peer in peers:
             try:
@@ -1029,7 +1023,7 @@ class ReplicaRouter:
                     (p for p in peers
                      if not getattr(p, "draining", False)),
                     key=lambda p: (self._depth(p),
-                                   -self._blocks_free(p))):
+                                   -p.cache.blocks_free)):
                 if peer.adopt_request(req):
                     placed = True
                     moved += 1
@@ -1103,7 +1097,7 @@ class ReplicaRouter:
             for row, req in sorted(eng._active.items(),
                                    key=lambda kv: kv[1].id):
                 del eng._active[row]
-                eng.cache.release(row)
+                eng.cache.release_row(row)
                 if req._lora_held:
                     if eng.lora_pool is not None:
                         eng.lora_pool.release(req.tenant)
@@ -1131,7 +1125,7 @@ class ReplicaRouter:
                      if not getattr(p, "draining", False)
                      and p._health != "dead"),
                     key=lambda p: (self._depth(p),
-                                   -self._blocks_free(p))):
+                                   -p.cache.blocks_free)):
                 if peer.adopt_request(req):
                     placed = True
                     break
@@ -1149,8 +1143,8 @@ class ReplicaRouter:
         # the dead replica's prefix cache holds block refs on its pool;
         # drop them unless a live engine shares that pool (prebuilt
         # engines on one kv_pool)
-        if eng.paged and not any(p.cache.pool is eng.cache.pool
-                                 for p in self.engines):
+        if not any(p.cache.pool is eng.cache.pool
+                   for p in self.engines):
             eng.cache.flush_prefix_cache()
         with self._lock:
             self._kills += 1
@@ -1296,7 +1290,7 @@ class ReplicaRouter:
             "mesh_shape": (None if live[0].mesh_shape is None
                            else list(live[0].mesh_shape)),
             "queue_depths": depths,
-            "kv_blocks_free": [self._blocks_free(e) for e in live],
+            "kv_blocks_free": [e.cache.blocks_free for e in live],
             "health": [e._health for e in live],
             "kills": kills,
             "restarts": restarts,

@@ -377,12 +377,27 @@ def test_serving_predictor_buckets_and_decode():
     # (max_new_tokens=1) alone must not predict a decode compile
     p = predict_serving_compiles(
         [[(list(range(1, 6)), 1), (list(range(1, 13)), 1)]],
-        buckets=[8, 16], max_len=32, paged=False)
-    assert p == {"serving_prefill{bucket=8}": 1,
-                 "serving_prefill{bucket=16}": 1}
+        buckets=[8, 16], max_len=32)
+    assert p == {"serving_prefill_paged{bucket=8}": 1,
+                 "serving_prefill_paged{bucket=16}": 1}
     p2 = predict_serving_compiles(
-        [[(list(range(1, 6)), 4)]], buckets=[8], max_len=32, paged=False)
-    assert p2 == {"serving_prefill{bucket=8}": 1, "decode_step": 1}
+        [[(list(range(1, 6)), 4)]], buckets=[8], max_len=32)
+    assert p2 == {"serving_prefill_paged{bucket=8}": 1,
+                  "decode_step_paged": 1}
+
+
+def test_serving_predictor_has_one_kv_path():
+    # the engine has one KV manager, so the predictor has no switch:
+    # the site names are the paged ones whatever else is asked for
+    with pytest.raises(TypeError):
+        predict_serving_compiles(
+            [[(list(range(1, 6)), 4)]], buckets=[8], max_len=32,
+            **{"paged": False})
+    p = predict_serving_compiles(
+        [[(list(range(1, 6)), 4)]], buckets=[8], max_len=32,
+        prefix_cache=False, spec_tokens=2)
+    assert p == {"serving_prefill_paged{bucket=8}": 1,
+                 "verify_step_paged{k=2}": 1}
 
 
 def test_serving_predictor_prefix_rounds():

@@ -447,7 +447,6 @@ def test_serving_alloc_skip_sheds_request_not_engine(_serving_model):
     from paddle_tpu.models.generation import greedy_search
     with fault_scope("serving.alloc:skip@0"):
         eng = _serving_engine(_serving_model)
-        assert eng.paged
         reqs = [eng.submit([1, 2, 3], max_new_tokens=3),
                 eng.submit([4, 5], max_new_tokens=3)]
         eng.run_until_idle()
@@ -512,7 +511,7 @@ def test_serving_alloc_shed_no_block_leak_int8(_serving_model):
     try:
         with fault_scope("serving.alloc:skip@1"):
             eng = _serving_engine(_serving_model)
-            assert eng.paged and eng.cache.kv_dtype == "int8"
+            assert eng.cache.kv_dtype == "int8"
             assert len(eng.cache.layers[0]) == 4
             reqs = [eng.submit([1, 2, 3], max_new_tokens=3),
                     eng.submit([4, 5], max_new_tokens=3),

@@ -345,6 +345,3 @@ def test_predict_serving_compiles_host_tier_is_validated_noop():
     with pytest.raises(ValueError, match="host_tier"):
         predict_serving_compiles(rounds, buckets=[8], max_len=64,
                                  sessions=5)
-    with pytest.raises(ValueError, match="paged"):
-        predict_serving_compiles(rounds, buckets=[8], max_len=64,
-                                 paged=False, host_tier=True)

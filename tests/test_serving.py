@@ -18,13 +18,14 @@ import pytest
 
 import paddle_tpu as pt
 from paddle_tpu import monitor
-from paddle_tpu.models.generation import (decode_step, decode_step_paged,
-                                          draft_ngram, greedy_search,
-                                          verify_step, verify_step_paged)
+from paddle_tpu.models.generation import (beam_search, decode_step,
+                                          decode_step_paged, draft_ngram,
+                                          greedy_search, sample,
+                                          verify_step_paged)
 from paddle_tpu.models.gpt import GPTConfig, GPTForCausalLM
 from paddle_tpu.serving import (BlockAllocator, BlockKVCache,
                                 QueueFullError, ServingEngine,
-                                ServingHTTPServer, SlotKVCache)
+                                ServingHTTPServer)
 
 
 @pytest.fixture(scope="module")
@@ -63,12 +64,10 @@ def test_engine_matches_sequential_greedy(model):
 def test_decode_compiles_once_prefill_once_per_bucket(model):
     """The compile-reuse contract: across many requests of many lengths,
     decode traces exactly once and each prefill bucket exactly once
-    (the engine runs the paged steps by default — block remapping,
-    prefix sharing and COW must never retrace)."""
+    (block remapping, prefix sharing and COW must never retrace)."""
     before = decode_step_paged(model)["traces"]["count"]
     eng = ServingEngine(model, max_slots=3, max_len=32,
                         buckets=[4, 8, 16], max_queue=32)
-    assert eng.paged
     for p in _prompts((2, 3, 4, 6, 7, 9, 13, 15), seed=1):
         eng.submit(p, max_new_tokens=4)
     eng.run_until_idle()
@@ -170,28 +169,36 @@ def test_http_endpoint(model):
         srv.stop()
 
 
-def test_greedy_search_single_compile(model):
-    """The generation.py refactor's point: a greedy decode of many
-    steps traces the step function exactly once (the old concat-cache
-    loop recompiled every step)."""
+# the three callers that keep ``decode_step`` (the oracle's compiled
+# step) alive, each at a decode batch no other test of this file traces
+@pytest.mark.parametrize("search,batch", [
+    (greedy_search, 4),
+    (lambda m, ids, **kw: sample(m, ids, temperature=0.8, top_k=5,
+                                 seed=3, **kw), 5),
+    (lambda m, ids, **kw: beam_search(m, ids, beam_size=3, **kw), 2),
+], ids=["greedy_search", "sample", "beam_search"])
+def test_greedy_search_single_compile(model, search, batch):
+    """A decode of many steps on the fixed-capacity cache traces the
+    step function exactly once per batch shape (a cache that grew with
+    each token would recompile every step)."""
     before = decode_step(model)["traces"]["count"]
-    # batch size 4: a decode shape no other test has traced yet
-    ids = np.asarray(_prompts((5, 5, 5, 5), seed=5))
-    greedy_search(model, ids, max_new_tokens=8)
+    ids = np.asarray(_prompts((5,) * batch, seed=5))
+    search(model, ids, max_new_tokens=8)
     # same batch shape again: zero new traces
-    greedy_search(model, ids + 1, max_new_tokens=8)
+    search(model, ids + 1, max_new_tokens=8)
     assert decode_step(model)["traces"]["count"] - before == 1
 
 
-def test_slot_kv_cache_bookkeeping():
-    c = SlotKVCache(num_layers=1, num_heads=2, head_dim=4, max_slots=2,
-                    max_len=8)
-    a, b = c.alloc(), c.alloc()
-    assert (a, b) == (0, 1) and c.alloc() is None
-    c.lengths[a] = 5
-    c.release(a)
-    assert c.lengths[a] == 0 and c.num_free == 1
-    assert c.alloc() == 0  # lowest slot is reused first, deterministic
+def test_cache_without_cache_pos_names_the_two_modes(model):
+    """A KV cache is a paged block pool (the engine's) or a
+    fixed-capacity pair (the oracle's), both written at ``cache_pos``;
+    a cache handed in without it is refused with both named."""
+    from paddle_tpu.dygraph.tensor import Tensor
+    ids = Tensor(np.asarray([[1, 2, 3]], np.int32), stop_gradient=True)
+    with pytest.raises(ValueError,
+                       match="gen_block_pool.*gen_fixed_cache"):
+        model(ids, cache=model.gpt.gen_fixed_cache(1, 8))
+    assert not hasattr(model.gpt, "gen_cache")
 
 
 # -- speculative decoding ------------------------------------------------
@@ -232,7 +239,6 @@ def test_spec_verify_compiles_once(model):
     before_d = decode_step_paged(model)["traces"]["count"]
     eng = ServingEngine(model, max_slots=3, max_len=32,
                         buckets=[4, 8, 16], max_queue=32, spec_tokens=k)
-    assert eng.paged
     for p in _prompts((2, 3, 4, 6, 7, 9, 13, 15), seed=7):
         eng.submit(p, max_new_tokens=4)
     eng.run_until_idle()
@@ -309,24 +315,6 @@ def test_draft_ngram():
     assert draft_ngram([2, 9, 8, 2, 5, 2], 1) == [5]
 
 
-# -- SlotKVCache rollback / batched writes -------------------------------
-
-def test_slot_kv_advance_rollback_guards():
-    c = SlotKVCache(num_layers=1, num_heads=2, head_dim=4, max_slots=2,
-                    max_len=8)
-    s = c.alloc()
-    c.lengths[s] = 3
-    c.advance(s, 4)                    # optimistic verify commit
-    assert c.lengths[s] == 7
-    c.rollback(s, 2)                   # rejected draft tail
-    assert c.lengths[s] == 5
-    with pytest.raises(ValueError):
-        c.advance(s, 4)                # 5 + 4 > max_len
-    with pytest.raises(ValueError):
-        c.rollback(s, 6)               # below zero
-    assert c.lengths[s] == 5           # failed calls left state alone
-
-
 def test_slot_reuse_after_rollback_interleaved_retirement(model):
     """The bug class speculative rollback introduces: release -> alloc
     -> write must land at the NEW request's offsets, never a stale
@@ -348,27 +336,6 @@ def test_slot_reuse_after_rollback_interleaved_retirement(model):
                             max_new_tokens=r.max_new_tokens,
                             cache_len=eng.max_len)[0].tolist()
         assert r.output_ids == ref
-
-
-def test_write_prefill_batch_matches_single_writes(model):
-    """One batched functional update per layer == N single-slot
-    writes, bit for bit."""
-    import jax.numpy as jnp
-    kw = dict(num_layers=2, num_heads=2, head_dim=4, max_slots=3,
-              max_len=8)
-    a, b = SlotKVCache(**kw), SlotKVCache(**kw)
-    rng = np.random.RandomState(0)
-    # a batched prefill output: rows for 2 admissions + 1 padding row
-    rows = [(jnp.asarray(rng.randn(3, 2, 8, 4).astype(np.float32)),
-             jnp.asarray(rng.randn(3, 2, 8, 4).astype(np.float32)))
-            for _ in range(2)]
-    a.write_prefill_batch([2, 0], rows, [5, 3])
-    for i, slot in enumerate([2, 0]):
-        b.write_prefill(slot, [(rk[i:i + 1], rv[i:i + 1])
-                               for rk, rv in rows], [5, 3][i])
-    assert a.lengths.tolist() == b.lengths.tolist()
-    for (ak, av), (bk, bv) in zip(a.arrays(), b.arrays()):
-        assert jnp.array_equal(ak, bk) and jnp.array_equal(av, bv)
 
 
 # -- batched prefill admission -------------------------------------------
@@ -577,28 +544,13 @@ def test_paged_engine_matches_greedy_without_prefix_cache(model):
     prompt prefills from scratch through the block tables)."""
     prompts = _prompts((3, 7, 5, 11, 4), seed=11)
     eng = ServingEngine(model, max_slots=2, max_len=32,
-                        buckets=[4, 8, 16], paged=True, block_size=4,
+                        buckets=[4, 8, 16], block_size=4,
                         prefix_cache=False)
     reqs = [eng.submit(p, max_new_tokens=6) for p in prompts]
     eng.run_until_idle()
     assert eng.cache.prefix_hits == 0
     for p, r in zip(prompts, reqs):
         ref = greedy_search(model, np.asarray([p]), max_new_tokens=6,
-                            cache_len=eng.max_len)[0].tolist()
-        assert r.output_ids == ref
-
-
-def test_dense_engine_still_matches_greedy(model):
-    """paged=False keeps the original SlotKVCache path working (the
-    bench baseline)."""
-    prompts = _prompts((3, 7, 5), seed=12)
-    eng = ServingEngine(model, max_slots=2, max_len=32, buckets=[8, 16],
-                        paged=False)
-    assert isinstance(eng.cache, SlotKVCache)
-    reqs = [eng.submit(p, max_new_tokens=5) for p in prompts]
-    eng.run_until_idle()
-    for p, r in zip(prompts, reqs):
-        ref = greedy_search(model, np.asarray([p]), max_new_tokens=5,
                             cache_len=eng.max_len)[0].tolist()
         assert r.output_ids == ref
 
@@ -611,7 +563,7 @@ def test_paged_prefix_reuse_is_exact_and_counted(model):
     system = _prompts((12,), seed=13)[0]       # 3 full blocks at bs=4
     tails = _prompts((3, 5, 2), seed=14)
     eng = ServingEngine(model, max_slots=2, max_len=32, buckets=[8, 16],
-                        paged=True, block_size=4)
+                        block_size=4)
     r0 = eng.submit(system, max_new_tokens=4)
     eng.run_until_idle()                       # publishes the prefix
     reqs = [eng.submit(system + t, max_new_tokens=4) for t in tails]
@@ -635,7 +587,7 @@ def test_paged_pool_exhaustion_blocks_head_of_line_then_completes(model):
     # each request needs ceil((6+4)/4)=3 blocks; pool of 7 usable
     # blocks fits two in flight, so admission must wait for releases
     eng = ServingEngine(model, max_slots=4, max_len=32, buckets=[8],
-                        paged=True, block_size=4, num_blocks=8,
+                        block_size=4, num_blocks=8,
                         prefix_cache=False)
     reqs = [eng.submit(p, max_new_tokens=4) for p in prompts]
     eng.run_until_idle()
@@ -658,7 +610,7 @@ def test_paged_spec_rollback_across_block_boundary_matches_greedy(model):
     # boundary every verify; mixed with a random prompt for rejections
     prompts = [[5, 9] * 4, _prompts((7,), seed=16)[0], [3, 3, 3, 3]]
     eng = ServingEngine(model, max_slots=2, max_len=32, buckets=[8, 16],
-                        paged=True, block_size=2, spec_tokens=3)
+                        block_size=2, spec_tokens=3)
     reqs = [eng.submit(p, max_new_tokens=9) for p in prompts]
     eng.run_until_idle()
     for p, r in zip(prompts, reqs):
@@ -672,7 +624,7 @@ def test_paged_health_and_stats_surface(model):
     """GET /health exposes block headroom; stats() carries the paged
     block/prefix keys."""
     eng = ServingEngine(model, max_slots=2, max_len=32, buckets=[8],
-                        paged=True, block_size=4)
+                        block_size=4)
     srv = ServingHTTPServer(eng, port=0)
     srv.start()
     try:
@@ -689,6 +641,29 @@ def test_paged_health_and_stats_surface(model):
         c.close()
     finally:
         srv.stop()
+
+
+def test_engine_has_one_kv_manager(model):
+    """There is no switch between KV managers: the constructor takes no
+    ``paged``, the flag plane knows no flag of that name, and an engine
+    built with no option set runs on the block pool and reports it."""
+    import inspect
+    from paddle_tpu import flags
+    gone = "paged"
+    assert gone not in inspect.signature(
+        ServingEngine.__init__).parameters
+    with pytest.raises(TypeError):
+        ServingEngine(model, **{gone: False})
+    assert f"serving_{gone}" not in flags.list_flags()
+    with pytest.raises(ValueError, match="unknown flag"):
+        flags.set_flags({f"serving_{gone}": False})
+    eng = ServingEngine(model, max_slots=2, max_len=32, buckets=[8])
+    assert isinstance(eng.cache, BlockKVCache)
+    assert not hasattr(eng, gone)
+    st = eng.stats()
+    assert gone not in st
+    assert st["kv_blocks_free"] + st["kv_blocks_used"] == st["num_blocks"]
+    assert st["pool_dispatches"] == 0 and st["prefix_hit_rate"] is None
 
 
 # -- cancellation over HTTP ----------------------------------------------
@@ -838,7 +813,7 @@ def test_a_step_that_fails_after_consuming_the_pools_keeps_serving(
     assert first.state == "running"
 
     ent = (decode_step_paged(model) if where == "decode"
-           else eng._prefill_entry_paged(8))
+           else eng._prefill_entry(8))
     real = ent["fn"]
 
     def consume_then_raise(*args):

@@ -144,9 +144,6 @@ def test_predict_serving_compiles_disagg_is_noop():
     assert predict_serving_compiles(rounds, disagg=(4, 4), **kw) == plain
     with pytest.raises(ValueError, match="disagg"):
         predict_serving_compiles(rounds, disagg=(0, 2), **kw)
-    with pytest.raises(ValueError, match="paged"):
-        predict_serving_compiles(rounds, disagg=(1, 2), paged=False,
-                                 buckets=[8, 16], max_len=32)
 
 
 # --------------------------------------------------- prefix affinity
@@ -448,7 +445,7 @@ def test_colocated_roles_survive_a_step_that_consumed_their_shared_pool(
         rt.step()
     assert any(r.state == "running" for r in early)
 
-    ent = pre._prefill_entry_paged(8)
+    ent = pre._prefill_entry(8)
     real = ent["fn"]
 
     def consume_then_raise(*args):

@@ -316,16 +316,11 @@ def test_megastep_validation_errors(model):
         _engine(model, megastep=4, spec_tokens=2)
     with pytest.raises(ValueError, match="dispatch_ahead"):
         _engine(model, megastep=1, dispatch_ahead=True)
-    with pytest.raises(ValueError, match="paged"):
-        _engine(model, megastep=4, paged=False)
     # the predictor rejects exactly what the engine rejects
     wl = [[(list(range(1, 6)), 4)]]
     with pytest.raises(ValueError, match="megastep"):
         predict_serving_compiles(wl, buckets=[8], max_len=32,
                                  megastep=0)
-    with pytest.raises(ValueError, match="paged"):
-        predict_serving_compiles(wl, buckets=[8], max_len=32,
-                                 paged=False, megastep=4)
     with pytest.raises(ValueError, match="spec_tokens"):
         predict_serving_compiles(wl, buckets=[8], max_len=32,
                                  spec_tokens=2, megastep=4)

@@ -231,12 +231,6 @@ def test_mesh_engine_from_flag_and_stats(model):
     assert plain.stats()["mesh_shape"] is None
 
 
-def test_mesh_requires_paged_cache(model):
-    with pytest.raises(ValueError, match="paged"):
-        ServingEngine(model, max_slots=1, max_len=32, buckets=[8],
-                      paged=False, mesh=serving_mesh(1, 1))
-
-
 def test_serving_mesh_too_many_devices():
     n = len(jax.devices())
     with pytest.raises(ValueError, match="devices"):

@@ -14,8 +14,8 @@ The contracts under test, on ``gpt2-tiny`` on the CPU:
   the first one ``first_token_at`` itself, and each gap between two of
   them is one ``serving.token_gap`` event — with the profiler off the
   stamps are still there and no event is;
-- the paged and the unpaged path, the megastep and the speculative
-  step all speak the same names;
+- the single step (f32, int8 pools, with a LoRA pool), the megastep
+  and the speculative step all speak the same names;
 - nothing of this reaches ``observability/tracing.py``: a seeded
   virtual-clock run exports the same bytes with the profiler on and off
   and marks nothing new.
@@ -38,7 +38,8 @@ from tools.loadgen import LoadGen, VirtualClock
 GEOM = dict(max_slots=4, max_len=64, buckets=[16, 32])
 PATHS = {
     "paged": dict(block_size=8, num_blocks=40),
-    "unpaged": dict(paged=False),
+    "int8": dict(block_size=8, num_blocks=40, kv_dtype="int8"),
+    "lora": dict(block_size=8, num_blocks=40, lora_rank=2),
     "megastep2": dict(block_size=8, num_blocks=40, megastep=2),
     "spec2": dict(block_size=8, num_blocks=40, spec_tokens=2),
 }
@@ -179,7 +180,7 @@ def test_token_stamps_and_gap_events(paged_run):
         assert mine[0]["ts"] == pytest.approx(r.token_at[0] * 1e6)
 
 
-@pytest.mark.parametrize("path", ["unpaged", "megastep2", "spec2"])
+@pytest.mark.parametrize("path", ["int8", "lora", "megastep2", "spec2"])
 def test_every_path_speaks_the_same_names(model, tmp_path, path):
     reqs, events, summary = _run(model, path, tmp_path)
     names = {e["name"] for e in events} - {"serving.token_gap"}
@@ -192,7 +193,7 @@ def test_every_path_speaks_the_same_names(model, tmp_path, path):
         if e["name"] == "serving.decode.inputs":
             assert by_id[e["parent"]]["name"] in ("serving.decode",
                                                   "serving.verify")
-    n = {"unpaged": 1, "megastep2": 2, "spec2": 3}[path]
+    n = {"megastep2": 2, "spec2": 3}.get(path, 1)
     assert {e["args"]["n"] for e in events
             if e["name"] == "serving.decode_step"} == {n}
     gaps = sum(e["name"] == "serving.token_gap" for e in events)

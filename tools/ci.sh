@@ -35,9 +35,7 @@
 #      compiles, with the chaos kill-prefill-worker path leaking
 #      nothing
 #   8. speculative-decoding gate (FLAGS_serving_spec_tokens>0 engine
-#      token-identical to sequential greedy, compile counts pinned;
-#      full mode also runs the BENCH_MODEL=serving spec variant on a
-#      tiny model: tokens/s + acceptance rate vs the plain engine)
+#      token-identical to sequential greedy, compile counts pinned)
 #   9. observability gate (train + serving smoke under the run log;
 #      /metrics parses as Prometheus text, compile tracker pins the
 #      decode/prefill compile budget, run-log events feed
@@ -149,7 +147,7 @@ if [[ "${1:-}" != "quick" ]]; then
   echo "== 7/16 serving plane (incl. paged-KV equivalence)"
   # the full file carries the paged oracle: engine output token-identical
   # to sequential greedy with the prefix cache on AND off, plus the
-  # dense paged=False baseline and the paged compile-count pins
+  # paged compile-count pins
   JAX_PLATFORMS=cpu python -m pytest tests/test_serving.py -q
   echo "   fused paged kernel + int8 KV oracle (Pallas interpret mode)"
   JAX_PLATFORMS=cpu python -m pytest tests/test_paged_attention.py -q
@@ -172,8 +170,8 @@ if [[ "${1:-}" != "quick" ]]; then
 else
   echo "== 7/16 serving plane: reduced subset (quick mode)"
   JAX_PLATFORMS=cpu python -m pytest tests/test_serving.py -q \
-    -k "matches_sequential or queue_full or slot_kv or block_allocator \
-or paged_engine_matches or dense_engine_still or prefix_reuse"
+    -k "matches_sequential or queue_full or block_allocator \
+or paged_engine_matches or prefix_reuse"
   JAX_PLATFORMS=cpu python -m pytest tests/test_paged_attention.py -q \
     -k "engine_pallas_matches or kernel_matches_reference_int8"
   echo "   mesh-sharded serving gate: reduced subset (quick mode)"
@@ -194,12 +192,6 @@ fi
 
 echo "== 8/16 speculative decoding gate"
 JAX_PLATFORMS=cpu python -m pytest tests/test_serving.py -q -k "spec"
-if [[ "${1:-}" != "quick" ]]; then
-  echo "   bench: spec vs non-spec on the repetitive-suffix workload"
-  BENCH_MODEL=serving BENCH_SERVING_GPT=gpt2-tiny BENCH_BATCH=4 \
-    BENCH_SEQ=64 BENCH_STEPS=1 BENCH_SERVING_NEW_TOKENS=16 \
-    BENCH_SERVING_COMPARE=0 JAX_PLATFORMS=cpu python bench.py
-fi
 
 echo "== 9/16 observability gate"
 # tiny train + serving smoke under the run log: /metrics parses as

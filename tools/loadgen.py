@@ -4,7 +4,7 @@
 Drives a :class:`ServingEngine` or :class:`ReplicaRouter` directly —
 no HTTP hop — with a replayable synthetic arrival process, and reports
 goodput under the TTFT SLO: the regression-locked "real traffic"
-scenario (ROADMAP item 2, ``BENCH_MODEL=loadgen``).
+scenario.
 
 Arrival processes (all derived from one ``np.random.RandomState(seed)``
 by thinning against the peak rate, so the same seed reproduces the
@@ -799,13 +799,12 @@ class LoadGen:
         leaked = 0
         seen_allocs = set()   # co-located disagg roles share one pool
         for eng in self._engines(target):
-            if getattr(eng, "paged", False):
-                alloc = eng.cache.allocator
-                if id(alloc) in seen_allocs:
-                    continue
-                seen_allocs.add(id(alloc))
-                eng.cache.flush_prefix_cache()
-                leaked += max(0, alloc.leaked() - 1)
+            alloc = eng.cache.allocator
+            if id(alloc) in seen_allocs:
+                continue
+            seen_allocs.add(id(alloc))
+            eng.cache.flush_prefix_cache()
+            leaked += max(0, alloc.leaked() - 1)
 
         def pct(vals, q):
             return (round(float(np.percentile(vals, q)), 3)
@@ -883,11 +882,9 @@ class LoadGen:
                 "session_turns": sum(1 for r in records
                                      if r["session"]),
             }
-            dev_blocks = next(
+            sess["device_blocks"] = next(
                 (e.cache.allocator.num_blocks
-                 for e in self._engines(target)
-                 if getattr(e, "paged", False)), 0)
-            sess["device_blocks"] = dev_blocks
+                 for e in self._engines(target)), 0)
             if tier is not None:
                 ts = tier.stats()
                 sess.update(
@@ -958,8 +955,7 @@ def warmup(target, max_new_tokens: int = 2):
     tiers = set()
     for e in engines:
         e.reset_cost_estimates()
-        if e.paged:
-            e.cache.flush_prefix_cache()
+        e.cache.flush_prefix_cache()
         # warmup chains demoted by the between-steps sweep would sit
         # in the (fleet-shared) host store; flush it once so measured
         # traffic starts from an empty tier

@@ -48,7 +48,7 @@ degradation contract**:
 ``--sweep`` reruns the identical workload + kill schedule across
 :class:`AutoscalePolicy` bounds and emits the cost-vs-goodput
 frontier (replica-seconds provisioned vs SLO-met completions/s) —
-written to ``--out`` (e.g. ``BENCH_r12.json``).
+written to ``--out``.
 
 Every request carries a distributed trace (``observability.tracing``,
 virtual-clock timestamps), so the per-window report also includes SLO
@@ -246,7 +246,7 @@ def main(argv=None) -> int:
     ap.add_argument("--json", action="store_true")
     ap.add_argument("--out", default="",
                     help="write the soak record (windows + frontier) "
-                    "here, e.g. BENCH_r12.json")
+                    "here")
     ap.add_argument("--ledger", default="", metavar="PATH",
                     help="append the primary arm's headline metrics "
                     "as one tools/perf_ledger.py JSONL row")

@@ -326,7 +326,7 @@ def render_serving_block():
         "compile per bucket — and all same-bucket admissions in a step",
         "share ONE dispatch of that compile) and runs one batched",
         "decode over every occupied slot (one compile, total). KV",
-        "memory is block-paged by default (`FLAGS_serving_paged`): a",
+        "memory is block-paged: a",
         "fixed pool of `[num_blocks, heads, block_size, head_dim]` KV",
         "blocks per layer, host-side per-request block tables fed to",
         "the jitted steps as plain inputs (block remapping never",
@@ -337,8 +337,7 @@ def render_serving_block():
         "Physical block 0 is a permanently-allocated trash block that",
         "backs table padding and absorbs overflow writes. Pool",
         "exhaustion holds the head-of-line request (FIFO order is part",
-        "of the equivalence oracle) until retirements free blocks;",
-        "`paged=False` falls back to the dense per-slot rows. With",
+        "of the equivalence oracle) until retirements free blocks. With",
         "`FLAGS_serving_spec_tokens` = K > 0 the decode becomes",
         "draft–verify speculative decoding: an n-gram self-drafter",
         "proposes K tokens per slot from the request's own generated",
@@ -359,8 +358,8 @@ def render_serving_block():
         "`engine.stats()` (merged into `GET /v1/stats`) adds",
         "time-to-first-token and time-per-output-token percentiles",
         "(`ttft_p50_ms` / `ttft_p99_ms` / `tpot_p50_ms` /",
-        "`tpot_p99_ms`), the speculative `spec_acceptance_rate`, and —",
-        "paged — the block-pool accounting (`kv_blocks_used` /",
+        "`tpot_p99_ms`), the speculative `spec_acceptance_rate`, and",
+        "the block-pool accounting (`kv_blocks_used` /",
         "`kv_blocks_free`, also exported as gauges on `GET /metrics`)",
         "plus token-granular `prefix_hit_rate` from",
         "`STAT_serving_prefix_hits` / `_misses`.",
@@ -401,8 +400,6 @@ def render_serving_block():
         "The engine reports the high-water dequantization error as",
         "`kv_quant_max_abs_err` in `stats()` and as the",
         "`serving_kv_dequant_max_abs_err` gauge on `GET /metrics`.",
-        "`BENCH_MODEL=serving` measures pallas-vs-xla tokens/s and the",
-        "int8-vs-f32 max-concurrency gain at equal pool bytes.",
         "",
         "Scaling is two orthogonal axes. `FLAGS_serving_mesh=DxM` (or",
         "`ServingEngine(mesh=...)`) runs ONE engine tensor-parallel on a",
@@ -469,10 +466,7 @@ def render_serving_block():
         "drives an engine or router directly (no HTTP in the loop) and",
         "reports goodput (SLO-met completions/s), attainment, per-",
         "reason sheds, TTFT/TPOT percentiles, and leaked KV blocks",
-        "(must be zero). CI runs a seeded clean + chaos-crossover gate;",
-        "`BENCH_MODEL=loadgen` measures SLO-aware vs depth-only goodput",
-        "at equal offered load and the graceful-degradation contract",
-        "under injected faults.",
+        "(must be zero). CI runs a seeded clean + chaos-crossover gate.",
         "",
         "Prefill and decode can also split into dedicated roles.",
         "`FLAGS_serving_disagg=PxD` (or `serving.DisaggRouter`) runs a",
@@ -509,9 +503,7 @@ def render_serving_block():
         "fault site sheds or retries cleanly, and",
         "`kill_prefill_worker()` re-homes queued work, purges the dead",
         "worker's affinity entries and sheds in-flight handoffs with",
-        "zero leaked blocks. `BENCH_MODEL=loadgen` compares the fleet",
-        "against a symmetric router at equal worker count (TTFT p95 +",
-        "goodput; the win is gated on real TPU hardware).",
+        "zero leaked blocks.",
         "",
         "Decoding is per-request *data* on the same compiled engine.",
         "Every `submit()` (and `POST /v1/generate`) accepts",
@@ -671,9 +663,7 @@ def render_serving_block():
         "sustains 0.5+ and profits from K of 4-8; low-entropy-free chat",
         "traffic near 0.2 wants K of 2-3 or 0. Each request reserves K",
         "rows of slot headroom, so `prompt + max_new_tokens + K` must",
-        "fit in `FLAGS_serving_max_len`. `BENCH_MODEL=serving` reports",
-        "spec vs non-spec tokens/s and the measured acceptance rate on",
-        "a repetitive-suffix workload.",
+        "fit in `FLAGS_serving_max_len`.",
         "",
         "Fault sites (see Fault tolerance for the spec grammar):",
         "",
@@ -774,9 +764,7 @@ def render_trainserve_block():
         "asserts per-device optimizer bytes ~1/2 of total with",
         "loss-for-loss parity against the unsharded baseline, then",
         "publishes and hot-swaps into a live engine asserting",
-        "token-correct output and 0 compiles. `BENCH_MODEL=zero`",
-        "benchmarks the per-device byte ratio and step time against",
-        "replicated Adam.",
+        "token-correct output and 0 compiles.",
         "",
         "Flags:",
         "",
@@ -937,7 +925,7 @@ def render_observability_block():
         "# TYPE STAT_serving_tokens counter",
         "STAT_serving_tokens 128",
         "# TYPE xla_compiles counter",
-        'xla_compiles{bucket="16",fn="serving_prefill"} 1',
+        'xla_compiles{bucket="16",fn="serving_prefill_paged"} 1',
         "```",
         "",
         "Flags:",
