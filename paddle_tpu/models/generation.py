@@ -109,15 +109,28 @@ def _inject_params(model, raw):
     the wrapper prepends the model's *current* parameter arrays on every
     call (post-swap weights ride in as data, not as constants).
 
+    The ``Parameter`` objects are listed once, when the entry is built
+    (the walk of ``named_parameters()`` is what a call used to pay for:
+    290 leaves of a 24-layer model); a call reads each one's ``value``
+    as it stands, so a ``swap_weights``, a ``set_value`` or a
+    ``set_state_dict`` is seen by the next call with nothing to
+    invalidate. What the list assumes, the entry assumed already: its
+    trace borrowed these objects and its mesh ``in_shardings`` were
+    fitted to them, so a model that gains or replaces a ``Parameter``
+    object needs a new entry either way.
+
     The snapshot happens under :func:`model_trace_lock` so it can never
     observe a sibling thread's mid-trace borrowed tracers; the compiled
     call itself runs outside the lock (a first-call trace re-enters it
     through ``_borrowed_params``), keeping threaded replica dispatch
     concurrent."""
+    params = [p for _, p in model.named_parameters()]
+    lock = model_trace_lock(model)
+
     def fn(*args):
-        with model_trace_lock(model):
-            params = param_leaves(model)
-        return raw(params, *args)
+        with lock:
+            values = [p.value for p in params]
+        return raw(values, *args)
     fn.traces = raw.traces
     # the tracked jit itself, for reading the compiled program:
     # fn.raw.lower(param_leaves(model), *args).compile().as_text()

@@ -690,6 +690,22 @@ class ServingEngine:
         # sampler (decoding._where_any_sampled)
         self._sampler_dispatches = 0      # guarded-by: _step_lock
         self._sampler_skipped = 0         # guarded-by: _step_lock
+        # the step entries' operands as the device holds them, each
+        # beside the host state it was made from (_resident), and the
+        # last commit's (progress, tokens, keys) carried outputs
+        # (_carried): a dispatch re-sends only what changed since.
+        # Mutated in place / rebound under the step lock like _active
+        self._res: Dict[str, tuple] = {}
+        self._carry = None                # guarded-by: _step_lock
+        self._resent = False              # did this dispatch re-send?
+        self._repl = None
+        if self.mesh is not None:
+            from jax.sharding import NamedSharding, PartitionSpec
+            self._repl = NamedSharding(self.mesh, PartitionSpec())
+        # of the dispatches _sampler_dispatches counts, those that
+        # copied nothing to the device but the step's own tokens /
+        # lengths
+        self._inputs_resident = 0         # guarded-by: _step_lock
         self._qerr_max = 0.0              # guarded-by: _step_lock
         self._qerr_gauge = None
         if self.kv_dtype == "int8":
@@ -736,6 +752,8 @@ class ServingEngine:
             "_pool_inplace": "_step_lock",
             "_sampler_dispatches": "_step_lock",
             "_sampler_skipped": "_step_lock",
+            "_carry": "_step_lock",
+            "_inputs_resident": "_step_lock",
         })
 
     # -------------------------------------------------------------- mesh
@@ -1614,56 +1632,164 @@ class ServingEngine:
                 return admitted
 
     # ------------------------------------------------------------ decode
-    def _build_samp(self):
-        """The per-slot sampling-as-data tuple for one compiled step,
-        rebuilt from the active requests every iteration: fixed-shape
-        plain inputs ``(temperature [b] f32, top_k [b] i32, top_p [b]
-        f32, keys [b, 2] u32, mask [b, vocab] f32)``. Empty slots stay
-        at the all-zero neutral row (greedy, no mask) so padding rows
-        reproduce the pre-sampling argmax bit-for-bit; grammar-cursored
-        rows get their additive JSON mask for the *next* position,
-        budget-aware so the emitted document always closes in time."""
-        b, V = self.max_slots, self._vocab
-        temp = np.zeros(b, np.float32)
-        tk = np.zeros(b, np.int32)
-        tp = np.zeros(b, np.float32)
-        keys = np.zeros((b, 2), np.uint32)
-        mask = np.zeros((b, V), np.float32)
+    def _send(self, host):  # holds: _step_lock
+        """The one host->device copy of a decode / verify / megastep
+        operand (an array, or a tuple of arrays sent together), placed
+        as the entries' ``in_shardings`` name it: replicated over the
+        serving mesh, so that no call re-shards it, else on the default
+        device. Everything else a dispatch is given is an array the
+        device already holds."""
+        if self._repl is None:
+            return jax.device_put(host)
+        return jax.device_put(host, self._repl)
+
+    def _resident(self, name: str, state, build):  # holds: _step_lock
+        """Operand ``name`` as the device holds it. ``state`` is what
+        the engine observes of the host state the operand mirrors (the
+        batch's membership, a version); the operand is built
+        (``build()``, on the host) and sent again only when that is no
+        longer what the resident copy was made from."""
+        held = self._res.get(name)
+        if held is None or held[0] != state:
+            held = self._res[name] = (state, self._send(build()))
+            self._resent = True
+        return held[1]
+
+    def _forget_inputs(self):  # holds: _step_lock
+        """Drop every resident operand, so that the next dispatch
+        rebuilds and sends all of its inputs: what every step did
+        before they were kept, and what the tests compare the resident
+        path with. Nothing on the serving path calls it: a step whose
+        batch, tables or keys changed re-sends what changed."""
+        self._res.clear()
+        self._carry = None
+
+    def _batch(self):  # holds: _step_lock
+        """Which request sits in which slot: what the per-request
+        operands (sampling parameters, stop tables, LoRA pages) are
+        valid against."""
+        return tuple((slot, req.id) for slot, req in self._active.items())
+
+    def _progress(self):  # holds: _step_lock
+        """:meth:`_batch` with each request's count of tokens: what the
+        last dispatch's carried outputs (next tokens, advanced keys)
+        are valid against. A request that left and came back, or that
+        anything but the last commit advanced, does not match."""
+        return tuple((slot, req.id, len(req.tokens))
+                     for slot, req in self._active.items())
+
+    def _carried(self):  # holds: _step_lock
+        """``(tokens, keys)``: the last dispatch's next tokens (None
+        after a verify) and advanced keys, as the device holds them,
+        while the batch still stands where its commit left it; else
+        ``(None, None)``."""
+        carry = self._carry
+        if carry is not None and carry[0] == self._progress():
+            return carry[1], carry[2]
+        return None, None
+
+    def _tokens_arg(self, carried):  # holds: _step_lock
+        """Each row's last token: the last step's own output
+        (``carried``) while the batch stands as it left it, else built
+        from the requests and sent. An empty row's entry is never read
+        (its writes land in the trash block, as ever)."""
+        if carried is not None:
+            return carried
+        tokens = np.zeros(self.max_slots, np.int32)
         for slot, req in self._active.items():
-            p = req.decode
-            temp[slot] = p.temperature
-            tk[slot] = p.top_k
-            tp[slot] = p.top_p
-            keys[slot] = req._key
-            if req._cursor is not None:
+            tokens[slot] = req.tokens[-1]
+        return self._send(tokens)
+
+    def _tables_arg(self):  # holds: _step_lock
+        """The block tables, sent again only after the cache wrote one
+        (bind, release, handoff: ``tables_version``); a copy, because
+        the cache writes its array in place."""
+        return self._resident("tables", self.cache.tables_version,
+                              self.cache.tables.copy)
+
+    def _build_samp(self, keys):  # holds: _step_lock
+        """The per-slot sampling-as-data tuple for one compiled step:
+        fixed-shape plain inputs ``(temperature [b] f32, top_k [b] i32,
+        top_p [b] f32, keys [b, 2] u32, mask [b, vocab] f32)``, each as
+        the device already holds it unless what it mirrors changed.
+        Temperature, top-k and top-p are rebuilt from the active
+        requests when the batch's membership changed (empty slots stay
+        at the all-zero neutral row, greedy, so padding rows reproduce
+        the pre-sampling argmax bit-for-bit). The keys are the last
+        step's ``new_keys`` while the batch stands as that step's
+        commit left it (``keys``, :meth:`_carried`), else (None)
+        gathered from the requests, whose host-side key stays the
+        authoritative one. The mask is
+        one resident zero array; only a step with a grammar-cursored
+        row builds and sends a real one: that row's additive JSON mask
+        for the *next* position, budget-aware so the emitted document
+        always closes in time."""
+        b, V = self.max_slots, self._vocab
+
+        def consts():
+            temp = np.zeros(b, np.float32)
+            tk = np.zeros(b, np.int32)
+            tp = np.zeros(b, np.float32)
+            for slot, req in self._active.items():
+                p = req.decode
+                temp[slot] = p.temperature
+                tk[slot] = p.top_k
+                tp[slot] = p.top_p
+            return temp, tk, tp
+
+        temp, tk, tp = self._resident("samp", self._batch(), consts)
+        if keys is None:
+            keys = np.zeros((b, 2), np.uint32)
+            for slot, req in self._active.items():
+                keys[slot] = req._key
+            keys = self._send(keys)
+            self._resent = True
+        cursored = [(slot, req) for slot, req in self._active.items()
+                    if req._cursor is not None]
+        if cursored:
+            mask = np.zeros((b, V), np.float32)
+            for slot, req in cursored:
                 remaining = req.max_new_tokens - len(req.tokens)
                 req._cursor.mask_row(remaining, out=mask[slot])
-        return (jnp.asarray(temp), jnp.asarray(tk), jnp.asarray(tp),
-                jnp.asarray(keys), jnp.asarray(mask))
+            mask = self._send(mask)
+            self._resent = True
+        else:
+            mask = self._resident("mask", (b, V),
+                                  lambda: np.zeros((b, V), np.float32))
+        return temp, tk, tp, keys, mask
 
     def _writeback_keys(self, new_keys):
         """Persist each active row's advanced RNG key back onto its
         request — the authoritative key lives host-side on the Request
-        (it travels with disagg handoffs and engine restarts), the
-        device copy is rebuilt per step. Advancement is request-local
-        (a fixed per-row split fan-out), so replaying the same request
-        through any batch composition draws the same stream."""
+        (it travels with disagg handoffs and engine restarts); the
+        device array it was read from is what the next step takes while
+        the batch is unchanged (:meth:`_carried`). Advancement is
+        request-local (a fixed per-row split fan-out), so replaying the
+        same request through any batch composition draws the same
+        stream."""
         if not self._active:
             return
         arr = np.asarray(new_keys)
         for slot, req in self._active.items():
             req._key = arr[slot].copy()
 
-    def _lora_args(self):
+    def _lora_args(self):  # holds: _step_lock
         """The per-step LoRA input ``(page_ids [b] i32, pool arrays)``:
         each active row's tenant resolved by NAME to its current pool
         page (safe against eviction — in-flight requests pin their
-        page), empty/base rows on the all-zero base page 0."""
-        pages = np.zeros(self.max_slots, np.int32)
-        for slot, req in self._active.items():
-            if req.tenant:
-                pages[slot] = self.lora_pool.page_of(req.tenant)
-        return (jnp.asarray(pages), self.lora_pool.arrays)
+        page), empty/base rows on the all-zero base page 0. The page
+        ids are sent again when the batch's membership changed or the
+        pool loaded or evicted an adapter."""
+        def pages():
+            ids = np.zeros(self.max_slots, np.int32)
+            for slot, req in self._active.items():
+                if req.tenant:
+                    ids[slot] = self.lora_pool.page_of(req.tenant)
+            return ids
+
+        return (self._resident("lora", (self._batch(),
+                                        self.lora_pool.version), pages),
+                self.lora_pool.arrays)
 
     def _shed_active(self, err: BaseException):  # holds: _step_lock
         """Shed every running request and free its row."""
@@ -1706,39 +1832,55 @@ class ServingEngine:
             _monitor.stat_add("STAT_serving_pool_inplace")
         return out
 
-    def _note_sampler(self):  # holds: _step_lock
-        """Count one decode/verify dispatch, and whether every live
-        row was greedy: what the step's ``lax.cond`` on "does any row
+    def _note_dispatch(self):  # holds: _step_lock
+        """Count one decode / verify / megastep dispatch, whether every
+        live row was greedy, and whether its inputs were resident.
+        The first is what the step's ``lax.cond`` on "does any row
         sample" reads on the device from the ``samp`` this batch was
-        given. ``sampler_skipped / sampler_dispatches`` in
+        given: ``sampler_skipped / sampler_dispatches`` in
         :meth:`stats` is the share of dispatches that ran the argmax
-        alone, without the processor chain and the draws."""
+        alone, without the processor chain and the draws. The second:
+        ``inputs_resident / inputs_dispatches`` is the share that
+        copied nothing to the device but the step's own small arrays
+        (tokens, lengths, the megastep's live / budget / stop state),
+        every other operand being the array the device held."""
         self._sampler_dispatches += 1
         if all(req.decode.is_greedy for req in self._active.values()):
             self._sampler_skipped += 1
             _monitor.stat_add("STAT_serving_sampler_skipped")
+        if not self._resent:
+            self._inputs_resident += 1
+            _monitor.stat_add("STAT_serving_inputs_resident")
 
-    def _step_args(self, tokens: np.ndarray):  # holds: _step_lock
+    def _step_args(self, tokens=None):  # holds: _step_lock
         """The inputs of one decode or verify dispatch after the
-        params: ``(tokens, lengths, tables, pools, samp[, lora])``."""
+        params: ``(tokens, lengths, tables, pools, samp[, lora])``.
+        A decode step's ``tokens`` are the last step's own output while
+        the batch is unchanged; a verify's ``[b, K+1]`` tree comes from
+        the host. ``lengths`` is one small copy a step (a copy, because
+        the cache advances its array in place); everything else is
+        resident (:meth:`_resident`) and re-sent only when changed."""
         with _profiler.RecordEvent("serving.decode.inputs"):
-            args = (jnp.asarray(tokens),
-                    jnp.asarray(self.cache.lengths),
-                    jnp.asarray(self.cache.tables),
-                    self.cache.arrays(), self._build_samp())
+            self._resent = False
+            last, keys = self._carried()
+            args = (self._tokens_arg(last) if tokens is None
+                    else self._send(tokens),
+                    self._send(self.cache.lengths.copy()),
+                    self._tables_arg(),
+                    self.cache.arrays(), self._build_samp(keys))
             if self._lora_shape is not None:
                 args = args + (self._lora_args(),)
         return args
 
-    def _decode_attempt(self, tokens: np.ndarray):
+    def _decode_attempt(self):
         kind = fault_point("serving.step")
         if kind == "skip":
             raise _SkipStep("injected skip of one decode iteration")
         fn = decode_step_paged(self.model, self.mesh, self.kv_dtype,
                                self._lora_shape)["fn"]
-        args = self._step_args(tokens)
+        args = self._step_args()
         out = self._call_paged(fn, args, args[3])
-        self._note_sampler()
+        self._note_dispatch()
         return out
 
     def _note_qerr(self, qerr, rows: int):  # holds: _step_lock
@@ -1778,16 +1920,13 @@ class ServingEngine:
         many tokens were produced (0 when idle/skipped)."""
         if not self._active:
             return 0
-        tokens = np.zeros(self.max_slots, np.int32)
-        for slot, req in self._active.items():
-            tokens[slot] = req.tokens[-1]
         timer = self._devprof_timer("decode_step_paged")
         t0 = time.perf_counter()
         try:
             with _monitor.stat_time("STAT_serving_decode"), \
                     _profiler.RecordEvent("serving.decode"):
                 out = RetryPolicy.from_flags(
-                    "serving.step").call(self._decode_attempt, tokens)
+                    "serving.step").call(self._decode_attempt)
         except (_SkipStep, _PoolsLost):
             return 0
         except RetryError as e:
@@ -1804,9 +1943,9 @@ class ServingEngine:
         self._note_tpot_ms((time.perf_counter() - t0) * 1e3)
         if timer is not None:
             timer.device_done(out)   # block_until_ready + stamp
-        nxt, _, arrays, qerr, new_keys = out
+        nxt_dev, _, arrays, qerr, new_keys = out
         with _profiler.RecordEvent("serving.decode.fetch"):
-            nxt = np.asarray(nxt)     # the host waits for the device
+            nxt = np.asarray(nxt_dev)  # the host waits for the device
         with _profiler.RecordEvent("serving.decode.commit",
                                    {"tokens": len(self._active)}):
             now = self._clock()       # the commit's one stamp
@@ -1818,6 +1957,8 @@ class ServingEngine:
                 self.cache.advance(slot, 1)
                 self._append_token(req, int(nxt[slot]), now)
                 produced += 1
+            # the next step's tokens and keys, while the batch stands
+            self._carry = (self._progress(), nxt_dev, new_keys)
         if timer is not None:
             timer.finish()   # host_s = the commit loop above
         return produced
@@ -1850,55 +1991,68 @@ class ServingEngine:
                     return 1
         return n
 
+    def _stop_consts(self):  # holds: _step_lock
+        """The megastep's per-request constants ``(eos [b], pat
+        [b, J, L], plen [b, J], fail [b, J, L+1])``, resident while
+        the batch's membership stands (a request's EOS id and stop
+        patterns never change; its matcher's *state* does, and goes
+        with every dispatch)."""
+        b, J, L = self.max_slots, STOP_MAX_SEQS, STOP_MAX_LEN
+
+        def build():
+            eos = np.full(b, -1, np.int32)
+            pat = np.full((b, J, L), -1, np.int32)
+            plen = np.zeros((b, J), np.int32)
+            fail = np.zeros((b, J, L + 1), np.int32)
+            for slot, req in self._active.items():
+                if req.eos_token_id is not None:
+                    eos[slot] = int(req.eos_token_id)
+                if req._stop is not None:
+                    pat[slot], plen[slot], fail[slot], _ = \
+                        stop_table_rows(req._stop)
+            return eos, pat, plen, fail
+
+        return self._resident("stops", self._batch(), build)
+
+    def _megastep_fn(self, n: int):
+        """The compiled megastep entry this engine dispatches."""
+        return decode_megastep_paged(self.model, n, self.mesh,
+                                     self.kv_dtype,
+                                     self._lora_shape)["fn"]
+
     def _megastep_inputs(self, n: int):  # holds: _step_lock
-        """Build one megastep dispatch's ``(args, ctx)``: the
-        fixed-shape device inputs plus the reusable constants (tables,
-        sampling params, stop tables, the compiled fn) a dispatch-ahead
-        re-dispatch feeds unchanged. Empty slots are frozen from
-        iteration 0 (``live=False``) and write their strays into the
-        trash block exactly as the single step does."""
+        """One megastep dispatch's fixed-shape inputs. The tokens and
+        keys are the last dispatch's carried outputs while the batch
+        stands as its commit left it; tables, sampling parameters,
+        stop tables and LoRA pages are the step entries' resident
+        operands (one state, :meth:`_resident`: a dispatch-ahead
+        re-dispatch feeds the same arrays); lengths, ``live``, the
+        budgets and the stop matchers' states are this dispatch's own
+        and are sent with it. Empty slots are frozen from iteration 0
+        (``live=False``) and write their strays into the trash block
+        exactly as the single step does."""
         b = self.max_slots
-        tokens = np.zeros(b, np.int32)
-        live = np.zeros(b, bool)
-        budget = np.ones(b, np.int32)
-        eos = np.full(b, -1, np.int32)
-        J, L = STOP_MAX_SEQS, STOP_MAX_LEN
-        pat = np.full((b, J, L), -1, np.int32)
-        plen = np.zeros((b, J), np.int32)
-        fail = np.zeros((b, J, L + 1), np.int32)
-        state = np.zeros((b, J), np.int32)
-        for slot, req in self._active.items():
-            tokens[slot] = req.tokens[-1]
-            live[slot] = True
-            budget[slot] = req.max_new_tokens - len(req.tokens)
-            if req.eos_token_id is not None:
-                eos[slot] = int(req.eos_token_id)
-            if req._stop is not None:
-                (pat[slot], plen[slot], fail[slot],
-                 state[slot]) = stop_table_rows(req._stop)
-        fn = decode_megastep_paged(self.model, n, self.mesh,
-                                   self.kv_dtype,
-                                   self._lora_shape)["fn"]
         with _profiler.RecordEvent("serving.decode.inputs"):
-            samp = self._build_samp()
-            ctx = {
-                "fn": fn,
-                "tables": jnp.asarray(self.cache.tables),
-                "samp_const": (samp[0], samp[1], samp[2], samp[4]),
-                "eos": jnp.asarray(eos),
-                "stop_tables": (jnp.asarray(pat), jnp.asarray(plen),
-                                jnp.asarray(fail)),
-                "lora": (self._lora_args()
-                         if self._lora_shape is not None else None),
-            }
-            spat, splen, sfail = ctx["stop_tables"]
-            args = (jnp.asarray(tokens), jnp.asarray(self.cache.lengths),
-                    ctx["tables"], self.cache.arrays(), samp,
-                    jnp.asarray(live), jnp.asarray(budget), ctx["eos"],
-                    (spat, splen, sfail, jnp.asarray(state)))
+            self._resent = False
+            last, keys = self._carried()
+            live = np.zeros(b, bool)
+            budget = np.ones(b, np.int32)
+            state = np.zeros((b, STOP_MAX_SEQS), np.int32)
+            for slot, req in self._active.items():
+                live[slot] = True
+                budget[slot] = req.max_new_tokens - len(req.tokens)
+                if req._stop is not None:
+                    state[slot] = stop_table_rows(req._stop)[3]
+            eos, pat, plen, fail = self._stop_consts()
+            args = (self._tokens_arg(last),
+                    self._send(self.cache.lengths.copy()),
+                    self._tables_arg(), self.cache.arrays(),
+                    self._build_samp(keys), self._send(live),
+                    self._send(budget), eos,
+                    (pat, plen, fail, self._send(state)))
             if self._lora_shape is not None:
-                args = args + (ctx["lora"],)
-        return args, ctx
+                args = args + (self._lora_args(),)
+        return args
 
     def _ahead_snapshot(self, n: int, extra_tokens: int = 0):
         """The scheduler state a speculative dispatch assumes: the
@@ -1911,7 +2065,7 @@ class ServingEngine:
                     (slot, req.id, len(req.tokens) + extra_tokens)
                     for slot, req in self._active.items())))
 
-    def _dispatch_ahead(self, n: int, out, ctx):  # holds: _step_lock
+    def _dispatch_ahead(self, n: int, out):  # holds: _step_lock
         """Enqueue megastep k+1 from k's still-un-synced device carry
         outputs, before the host blocks on k's results — the device
         queue stays fed while the host commits. The dispatch assumes
@@ -1929,15 +2083,18 @@ class ServingEngine:
         drafts and the trash block). Only its tokens are dropped."""
         (_toks, _finish, tok_f, pos_f, pools_f, keys_f, live_f,
          rem_f, st_f, _qerr) = out
-        temp, tk, tp, mask = ctx["samp_const"]
-        spat, splen, sfail = ctx["stop_tables"]
-        args = (tok_f, pos_f, ctx["tables"], pools_f,
-                (temp, tk, tp, keys_f, mask), live_f, rem_f,
-                ctx["eos"], (spat, splen, sfail, st_f))
+        self._resent = False
+        # the operands k was dispatched with, as the device holds
+        # them: k's carry for what a step advances, the resident
+        # constants of k's batch for the rest (no copy is made)
+        eos, spat, splen, sfail = self._stop_consts()
+        args = (tok_f, pos_f, self._tables_arg(), pools_f,
+                self._build_samp(keys_f), live_f, rem_f,
+                eos, (spat, splen, sfail, st_f))
         if self._lora_shape is not None:
-            args = args + (ctx["lora"],)
-        ahead_out = self._call_paged(ctx["fn"], args, pools_f)
-        self._note_sampler()
+            args = args + (self._lora_args(),)
+        ahead_out = self._call_paged(self._megastep_fn(n), args, pools_f)
+        self._note_dispatch()
         self._ahead = {
             "n": n,
             "snap": self._ahead_snapshot(n, extra_tokens=n),
@@ -1945,7 +2102,6 @@ class ServingEngine:
             "lora_arrays": (None if self._lora_shape is None
                             else self.lora_pool.arrays),
             "out": ahead_out,
-            "ctx": ctx,
         }
         return ahead_out[4]
 
@@ -1971,24 +2127,24 @@ class ServingEngine:
             return None
         self._ahead_hits += 1
         _monitor.stat_add("STAT_serving_ahead_hits")
-        return ah["out"], ah["ctx"]
+        return ah["out"]
 
     def _megastep_attempt(self, n: int):
         """One megastep dispatch attempt (the serving.step fault
         site). The fault check fires BEFORE the speculation is
         consumed, so an injected skip leaves the stored dispatch valid
         for the next attempt — the state it assumed is untouched.
-        Returns ``(out, ctx)``."""
+        Returns the entry's outputs."""
         kind = fault_point("serving.step")
         if kind == "skip":
             raise _SkipStep("injected skip of one decode megastep")
         taken = self._take_ahead(n)
         if taken is not None:
             return taken
-        args, ctx = self._megastep_inputs(n)
-        out = self._call_paged(ctx["fn"], args, args[3])
-        self._note_sampler()
-        return out, ctx
+        args = self._megastep_inputs(n)
+        out = self._call_paged(self._megastep_fn(n), args, args[3])
+        self._note_dispatch()
+        return out
 
     def _decode_megastep(self, n: int) -> int:  # holds: _step_lock
         """One device-resident megastep over every occupied slot: N
@@ -2006,14 +2162,14 @@ class ServingEngine:
         try:
             with _monitor.stat_time("STAT_serving_decode"), \
                     _profiler.RecordEvent("serving.decode"):
-                out, ctx = RetryPolicy.from_flags(
+                out = RetryPolicy.from_flags(
                     "serving.step").call(self._megastep_attempt, n)
         except (_SkipStep, _PoolsLost):
             return 0
         except RetryError as e:
             self._shed_active(e)
             return 0
-        (toks, finish, _tok_f, _pos_f, pools_f, keys_f, _live_f,
+        (toks, finish, tok_f, _pos_f, pools_f, keys_f, _live_f,
          _rem_f, _st_f, qerr) = out
         if timer is not None:
             # the one documented sampling cost: block on megastep k
@@ -2026,7 +2182,7 @@ class ServingEngine:
             # blocks on k's results: commit work below overlaps it.
             # k+1 consumed k's pools; the cache binds what it returned
             try:
-                pools_f = self._dispatch_ahead(n, out, ctx)
+                pools_f = self._dispatch_ahead(n, out)
             except _PoolsLost:
                 return 0
         with _profiler.RecordEvent("serving.decode.fetch"):
@@ -2054,6 +2210,9 @@ class ServingEngine:
                 if req.state == "running":
                     req._key = keys_arr[slot].copy()
             commit.args = {"tokens": produced}
+            # what a miss of the speculation, or the single step a
+            # fallback takes, feeds next (a frozen row's are never read)
+            self._carry = (self._progress(), tok_f, keys_f)
         if produced:
             # per-token pace: the megastep wall spread over the tokens
             # each slot actually committed (satellite: TPOT samples
@@ -2107,7 +2266,7 @@ class ServingEngine:
                                self.kv_dtype, self._lora_shape)["fn"]
         args = self._step_args(tokens)
         out = self._call_paged(fn, args, args[3])
-        self._note_sampler()
+        self._note_dispatch()
         return out
 
     def _spec_decode(self) -> int:  # holds: _step_lock
@@ -2179,6 +2338,8 @@ class ServingEngine:
                     # back so the next step overwrites those rows
                     self.cache.rollback(slot, K + 1 - committed)
             commit.args = {"tokens": produced}
+            # the keys carry over; a tree of K+1 tokens is drafted anew
+            self._carry = (self._progress(), None, new_keys)
         if produced:
             # per-output-token pace: step wall time spread over the
             # tokens each slot actually committed this step
@@ -2508,6 +2669,7 @@ class ServingEngine:
             pool_inplace = self._pool_inplace
             sampler_dispatches = self._sampler_dispatches
             sampler_skipped = self._sampler_skipped
+            inputs_resident = self._inputs_resident
         with self._lock:
             completed = self._completed
             slo_met = self._slo_met
@@ -2558,6 +2720,11 @@ class ServingEngine:
         # greedy (the step skipped the sampler on the device)
         out["sampler_dispatches"] = sampler_dispatches
         out["sampler_skipped"] = sampler_skipped
+        # the same dispatches, and those whose operands were all
+        # resident: nothing copied to the device but the step's own
+        # tokens / lengths
+        out["inputs_dispatches"] = sampler_dispatches
+        out["inputs_resident"] = inputs_resident
         out["attn_impl"] = self.attn_impl
         out["kv_dtype"] = self.kv_dtype
         out["mesh_shape"] = (None if self.mesh_shape is None

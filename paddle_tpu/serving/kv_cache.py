@@ -347,6 +347,9 @@ class BlockKVCache:
         self.tables = np.full((self.max_slots, self.blocks_per_row),
                               self.TRASH, np.int32)
         self.lengths = np.zeros(self.max_slots, np.int32)
+        # bumped by every write to ``tables`` (_bind_row): a holder of
+        # a device copy re-sends it when this moved, and only then
+        self.tables_version = 0
         self._nblocks = np.zeros(self.max_slots, np.int32)  # owned per row
         self._free_rows = list(range(self.max_slots))
         self.prefix_cache_enabled = bool(prefix_cache)
@@ -457,6 +460,15 @@ class BlockKVCache:
             matched.append(ent)
         return matched
 
+    def _bind_row(self, row: int, blocks: Sequence[int]):
+        """Point ``row``'s table at ``blocks`` (none: every entry on the
+        trash block). The one place ``tables`` is written, so
+        ``tables_version`` moves exactly when a table did."""
+        self.tables[row] = self.TRASH
+        self.tables[row, :len(blocks)] = blocks
+        self._nblocks[row] = len(blocks)
+        self.tables_version += 1
+
     def acquire(self, prompt: Sequence[int],
                 need: int) -> Optional[Tuple[int, int]]:
         """Admit a request: reserve a row plus blocks for ``need``
@@ -525,9 +537,7 @@ class BlockKVCache:
         # counted here, not in _alloc_block: a failed acquire unwinds
         # its allocs, and those must not inflate the bytes/request bench
         self.blocks_allocated_total += len(taken)
-        self.tables[row] = self.TRASH
-        self.tables[row, :nblocks] = blocks
-        self._nblocks[row] = nblocks
+        self._bind_row(row, blocks)
         self.lengths[row] = 0
         if shared:
             self.prefix_hits += shared
@@ -541,8 +551,7 @@ class BlockKVCache:
         n = int(self._nblocks[row])
         for blk in self.tables[row, :n]:
             self.allocator.deref(int(blk))
-        self.tables[row] = self.TRASH
-        self._nblocks[row] = 0
+        self._bind_row(row, ())
         self.lengths[row] = 0
         insort(self._free_rows, row)
 
@@ -610,8 +619,7 @@ class BlockKVCache:
             # zeroed blocks: the adopter sheds it
             "epoch": self.pool.epoch,
         }
-        self.tables[row] = self.TRASH
-        self._nblocks[row] = 0
+        self._bind_row(row, ())
         self.lengths[row] = 0
         insort(self._free_rows, row)
         return rec
@@ -633,9 +641,7 @@ class BlockKVCache:
         if not self._free_rows:
             return None
         row = self._free_rows.pop(0)
-        self.tables[row] = self.TRASH
-        self.tables[row, :len(blocks)] = blocks
-        self._nblocks[row] = len(blocks)
+        self._bind_row(row, blocks)
         self.lengths[row] = int(rec["length"])
         return row
 
@@ -694,9 +700,7 @@ class BlockKVCache:
                 for layer, src_layer in zip(self.layers, src_pool.layers)]
         row = self._free_rows.pop(0)
         self.blocks_allocated_total += len(taken)
-        self.tables[row] = self.TRASH
-        self.tables[row, :len(taken)] = taken
-        self._nblocks[row] = len(taken)
+        self._bind_row(row, taken)
         self.lengths[row] = length
         return row
 

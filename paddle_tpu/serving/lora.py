@@ -78,6 +78,10 @@ class LoRAPool:
             arrs.append(jnp.zeros(
                 (self.num_layers, self.pages, rank, dout), jnp.float32))
         self.arrays = tuple(arrs)
+        # bumped by every load and evict: a name's page can only have
+        # changed when this moved (what an engine's resident page ids
+        # are valid against)
+        self.version = 0                    # guarded-by: _lock
         self._by_name: Dict[str, int] = {}  # guarded-by: _lock
         self._alloc = BlockAllocator(self.pages)
         base = self._alloc.alloc()
@@ -90,7 +94,8 @@ class LoRAPool:
         # their own _step_lock; the pool never calls back into an
         # engine, so the order edge is acyclic.
         self._lock = _ccz.make_lock("lora_pool._lock", reentrant=True)
-        _ccz.declare_guarded(self, {"arrays": "_lock"})
+        _ccz.declare_guarded(self, {"arrays": "_lock",
+                                    "version": "_lock"})
 
     @property
     def shape_key(self) -> Tuple[int, int]:
@@ -184,6 +189,7 @@ class LoRAPool:
                 arrs[2 * i] = arrs[2 * i].at[:, page].set(a)
                 arrs[2 * i + 1] = arrs[2 * i + 1].at[:, page].set(b)
             self.arrays = tuple(arrs)
+            self.version += 1
         return page
 
     def evict(self, name: str) -> int:
@@ -207,6 +213,7 @@ class LoRAPool:
                 arrs[i] = arrs[i].at[:, page].set(
                     jnp.zeros_like(arrs[i][:, page]))
             self.arrays = tuple(arrs)
+            self.version += 1
         return page
 
     def leaked(self) -> int:

@@ -218,33 +218,37 @@ def test_the_new_cells_toy_twin_rehearses_to_its_end():
     assert line["notes"]["check_loss_diff"] <= 0.05
 
 
-# ---- PR 28: the sampler's counters through the counter channel
+# ---- PR 28, PR 30: the engine's counters through the counter channel
 
-SAMPLER_SHARES = {"sampler_skipped_share_pct.chat": "chat_steady",
-                  "sampler_skipped_share_pct.docs": "docs_offline",
-                  "sampler_skipped_share_pct.decode": "decode_heavy"}
+COUNTER_SHARES = {
+    "sampler_skipped_share_pct": ("engine.sampler_skipped",
+                                  "engine.sampler_dispatches"),
+    "inputs_resident_share_pct": ("engine.inputs_resident",
+                                  "engine.inputs_dispatches")}
+SHARE_CELLS = {"chat": "chat_steady", "docs": "docs_offline",
+               "decode": "decode_heavy"}
 
 
-@pytest.mark.parametrize("name", sorted(SAMPLER_SHARES))
-def test_sampler_skipped_share_reads_both_engine_counters(name):
+@pytest.mark.parametrize("suffix", sorted(SHARE_CELLS))
+@pytest.mark.parametrize("metric", sorted(COUNTER_SHARES))
+def test_a_counter_share_reads_both_engine_counters(metric, suffix):
     from perfbench import readers
+    name = f"{metric}.{suffix}"
+    part, whole = COUNTER_SHARES[metric]
     bench = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
     entry = next(m for m in bench["per_layer"] if m["name"] == name)
     spec = load("metrics", name + ".json")
-    twin = load("metrics", name.replace("sampler_skipped", "pool_inplace")
-                + ".json")
-    assert entry["workloads"] == [SAMPLER_SHARES[name]]
+    twin = load("metrics", f"pool_inplace_share_pct.{suffix}.json")
+    assert entry["workloads"] == [SHARE_CELLS[suffix]]
     assert all(spec[k] == entry[k] == twin[k]
                for k in ("layer", "unit", "better", "source", "moves"))
-    obs = {"counters": {"engine.sampler_skipped": 1500.0,
-                        "engine.sampler_dispatches": 1600.0}}
+    obs = {"counters": {part: 1500.0, whole: 1600.0}}
     assert readers.read(name, obs) == pytest.approx(93.75)
     # the parent's program has neither counter: nothing to read, no metric
     assert readers.read(name, {"counters": {
         "engine.pool_dispatches": 1600.0}}) is None
     # no decode dispatch in the window: no share
-    assert readers.read(name, {"counters": {
-        "engine.sampler_skipped": 0, "engine.sampler_dispatches": 0}}) is None
+    assert readers.read(name, {"counters": {part: 0, whole: 0}}) is None
 
 
 REHEARSE_WITH_VALUES = """
@@ -275,5 +279,7 @@ def test_a_rehearsal_reports_every_decode_step_as_sampler_skipped():
     assert line["correct"] is True and line["failed"] == 0
     m = line["metrics"]
     assert m["sampler_skipped_share_pct.decode"]["value"] == 100.0
+    # eight rows turn over about once in thirty steps of the toy twin
+    assert 80.0 <= m["inputs_resident_share_pct.decode"]["value"] < 100.0
     assert m["pool_inplace_share_pct.decode"]["value"] == 100.0
     assert m["compiles_in_window.decode"]["value"] == 0

@@ -530,6 +530,17 @@ def render_serving_block():
         "`STAT_serving_sampler_skipped` counts the decode/verify",
         "dispatches whose batch was all greedy, and `engine.stats()`",
         "gives `sampler_dispatches` / `sampler_skipped`.",
+        "The step's inputs stay on the device: sampling parameters,",
+        "the zero mask, block tables, LoRA pages and the megastep's",
+        "stop tables are sent again only when the batch's membership,",
+        "a table (`cache.tables_version`) or a grammar cursor changed,",
+        "and the next step's tokens and keys are the last step's own",
+        "outputs while the batch stands (the request's host-side key",
+        "stays authoritative). Nothing selects this: a step re-sends",
+        "what the engine observes to have changed. `engine.stats()`",
+        "gives `inputs_dispatches` / `inputs_resident` (dispatches",
+        "that copied nothing to the device but their own tokens /",
+        "lengths; `STAT_serving_inputs_resident`).",
         "Speculative decoding verifies sampled rows by rejection",
         "sampling: the committed-token law matches non-speculative",
         "sampling exactly (greedy rows keep the prefix-match rule,",
