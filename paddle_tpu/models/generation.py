@@ -165,8 +165,9 @@ def _kernel_layout(model, mesh):
 def _heads_axis(model, mesh):
     """``"model"`` when the mesh's model axis divides the head count,
     else None (heads whole on every chip)."""
-    return ("model" if model.gpt.cfg.num_heads % mesh.shape["model"] == 0
-            else None)
+    from ..serving.seam import served
+    heads = served(model).cache_kinds[0].kv_heads
+    return "model" if heads % mesh.shape["model"] == 0 else None
 
 
 def step_entry(model, key, build):
@@ -215,7 +216,8 @@ def _mesh_step_shardings(model, mesh, kv_dtype: str):
     scale = NamedSharding(mesh, P(None, ax))
     layer = ((pool, pool, scale, scale) if kv_dtype == "int8"
              else (pool, pool))
-    n_layers = model.gpt.cfg.num_layers
+    from ..serving.seam import served
+    n_layers = served(model).num_layers
     return repl, [layer for _ in range(n_layers)]
 
 
@@ -300,7 +302,7 @@ def _unwrap_pools(newp):
 
 
 def decode_step_paged(model, mesh=None, kv_dtype: str = "f32",
-                      lora_shape=None):
+                      lora_shape=None, counters: bool = False):
     """The block-paged sibling of :func:`decode_step`.
 
     Returns ``{"fn": jitted, "traces": {"count": n}}`` where ``fn``
@@ -338,26 +340,41 @@ def decode_step_paged(model, mesh=None, kv_dtype: str = "f32",
     mesh (it picks the pool tuple width for the sharding pytree); the
     mesh geometry is part of the cache key so each mesh compiles
     exactly once.
+
+    With ``counters`` (a model whose seam names device counters) the step
+    takes one more input after ``samp`` and returns one more result: the
+    float32 vector of the model's counters, which the model's call
+    (``counters=``) adds this step's counts to. The engine carries it from
+    step to step and reads it in ``stats()`` only.
     """
     from ..distributed.sharding import mesh_cache_key
     from ..observability import compile_tracker as _ct
     from ..serving.decoding import sample_tokens
     mkey = mesh_cache_key(mesh)
+    if counters and (mesh is not None or lora_shape is not None):
+        raise ValueError("a decode step with device counters runs on one "
+                         "chip and without LoRA pages")
 
     def _build():
-        def _impl(params, tokens, pos, tables, pools, samp, lora):
+        def _impl(params, tokens, pos, tables, pools, samp, lora,
+                  counted=None):
+            extra = {} if counted is None else {"counters": counted}
             with no_grad(), _borrowed_params(model, params), \
                     _kernel_layout(model, mesh):
-                logits, newp = model(_t(tokens[:, None]),
-                                     cache=_wrap_pools(pools),
-                                     cache_pos=pos, block_tables=tables,
-                                     lora=lora)
+                logits, newp, *counted = model(
+                    _t(tokens[:, None]), cache=_wrap_pools(pools),
+                    cache_pos=pos, block_tables=tables, lora=lora,
+                    **extra)
             lg = logits.value[:, -1]
             nxt, new_keys = sample_tokens(lg, samp)
             pools_out, qerr = _unwrap_pools(newp)
-            return nxt, lg, pools_out, qerr, new_keys
+            return (nxt, lg, pools_out, qerr, new_keys, *counted)
 
-        if lora_shape is None:
+        if counters:
+            def _step(params, tokens, pos, tables, pools, samp, counted):
+                return _impl(params, tokens, pos, tables, pools, samp,
+                             None, counted)
+        elif lora_shape is None:
             def _step(params, tokens, pos, tables, pools, samp):
                 return _impl(params, tokens, pos, tables, pools, samp,
                              None)
@@ -385,6 +402,8 @@ def decode_step_paged(model, mesh=None, kv_dtype: str = "f32",
            else ("decode_paged", mkey, kv_dtype))
     if lora_shape is not None:
         key = key + ("lora", tuple(lora_shape))
+    if counters:
+        key = key + ("counters",)
     return step_entry(model, key, _build)
 
 
@@ -640,7 +659,9 @@ def draft_ngram(context, k: int, max_ngram: int = 3):
 def _prefill(model, ids: np.ndarray, capacity: int):
     """Eager prompt pass into a fresh fixed cache. Returns
     (last_logits [b, V] jnp, caches [(k, v) jnp arrays])."""
-    cfg = model.gpt.cfg
+    from ..serving.seam import require_gpt
+    cfg = require_gpt(model, "decoding on the fixed-capacity cache "
+                      "(greedy_search / sample / beam_search)")
     if capacity > cfg.max_position_embeddings:
         raise ValueError(
             f"cache capacity {capacity} exceeds max_position_embeddings="

@@ -457,6 +457,20 @@ class GPTForCausalLM(Layer):
             return loss
         return logits if cache is None else (logits, cache)
 
+    def serving_spec(self):
+        """What the serving plane needs of this model
+        (``serving/seam.py``): one kind of layer that keeps every row,
+        every optional feature, the seam's generic step builders."""
+        from ..serving.seam import CacheKind, ServedModel
+        cfg = self.cfg
+        return ServedModel(
+            model=self, family="gpt",
+            max_positions=cfg.max_position_embeddings,
+            vocab=cfg.vocab_size,
+            cache_kinds=(CacheKind("full", tuple(range(cfg.num_layers)),
+                                   cfg.num_heads, cfg.head_dim),),
+            lora_config=cfg)
+
 
 def gpt2_tiny() -> GPTForCausalLM:
     return GPTForCausalLM(GPT_CONFIGS["gpt2-tiny"])

@@ -35,6 +35,27 @@ experts, which cost this chip's loss nothing. A share of the experts
 therefore computes the router's gradient and withholds its update (the
 weight's learning-rate factor is 0): the update belongs after the
 all-reduce, which this process does not run.
+
+**One decoder for both sparse-expert families.** What differs between
+Laguna and its relatives is configuration: ``attention_gate`` off, no
+shared expert (``shared_expert_intermediate_size`` 0), ``router_score``
+``"softmax"`` (over all experts, renormalised over the chosen), one head
+count for every layer (``models/mellum.py`` is such a configuration).
+
+**Serving.** With ``cache`` / ``cache_pos`` / ``block_tables`` the forward
+is the serving engine's: K and V go through per-request block tables into
+paged pools, one pool and one table a *layer kind* (``cache_kinds``): a
+full layer keeps every row of a request, a sliding-window layer the blocks
+its window still covers. A call of more than one row a request is a prompt
+(positions from 0, attention by the training path's kernels over the rows
+of the call itself, the rows the window still covers written to the pool);
+a call of one row is a decode step (K rotated at its position and written,
+Q rotated and read against the pool through the table, on a window layer
+through the table entries the window covers only; the expert layer in its
+few-rows form, ``ops.decoder_ops.moe_experts_decode``). With ``dtype``
+``"bfloat16"`` the parameters, the pools and the matmuls' inputs are
+bfloat16; the residual stream, the norms, the router, the softmax and the
+logits stay float32.
 """
 
 from __future__ import annotations
@@ -44,6 +65,7 @@ import math
 from dataclasses import dataclass, field
 from typing import Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 
 from ..dygraph.layers import Layer, LayerList
@@ -52,7 +74,10 @@ from ..dygraph.tensor import Tensor
 from ..initializer import ConstantInitializer, NormalInitializer
 from ..nn import functional as F
 from ..nn.layers_common import Embedding, Linear
-from ..ops.decoder_ops import rotary_tables
+from ..ops.attention_ops import block_attention_gqa, block_scatter_write
+from ..ops.decoder_ops import (DECODE_TILE_M, _moe_router, moe_experts,
+                               moe_experts_decode, rotary_inv_freq,
+                               rotary_tables)
 from ..param_attr import ParamAttr
 from ..profiler import RecordEvent
 
@@ -102,6 +127,23 @@ class LagunaConfig:
     moe_tile_m: int = 128
     # rematerialize each block's activations in backward
     recompute: bool = False
+    # what differs between the families of this decoder (the defaults are
+    # Laguna's): the per-head sigmoid output gate; the router's scores
+    # ("sigmoid", or "softmax" over all experts)
+    attention_gate: bool = True
+    router_score: str = "sigmoid"
+    # the parameters' dtype (and the served pools')
+    dtype: str = "float32"
+    # the std of the embedding's rows and of the routers' weights where a
+    # random-weight model needs another than init_std (None: init_std):
+    # they set the share of the residual stream the layers carry and how
+    # unevenly a token's chosen experts weigh
+    embed_init_std: Optional[float] = None
+    router_init_std: Optional[float] = None
+    # served prompts: rows of one pass of the expert layer (0: the whole
+    # call), so that its grouped products have one shape whatever the
+    # prompt's bucket and its buffers stay bounded
+    moe_chunk_rows: int = 0
 
     def __post_init__(self):
         n = self.num_hidden_layers
@@ -150,7 +192,9 @@ class LagunaConfig:
         n = 2 * (self.vocab[1] - self.vocab[0]) * h + h
         for i in range(self.num_hidden_layers):
             q = self.query_heads(i)
-            n += h * (q + 2 * kv) * d + h * q + q * d * h + 2 * h
+            n += h * (q + 2 * kv) * d + q * d * h + 2 * h
+            if self.attention_gate:
+                n += h * q
             if self.mlp_layer_types[i] == "dense":
                 n += 3 * h * self.intermediate_size
             else:
@@ -159,18 +203,37 @@ class LagunaConfig:
                     + 3 * h * self.shared_expert_intermediate_size
         return n
 
+    def cache_kinds(self):
+        """The layer kinds a paged cache keeps apart, full layers first:
+        ``(name, layers, window)`` with ``window`` 0 for a kind that keeps
+        every row of a request."""
+        out = []
+        for name, window in (("full_attention", 0),
+                             ("sliding_attention", self.sliding_window)):
+            layers = tuple(i for i, t in enumerate(self.layer_types)
+                           if t == name)
+            if layers:
+                out.append((name, layers, window))
+        return out
+
 
 def _w(std):
     return ParamAttr(initializer=NormalInitializer(0.0, std))
 
 
-def _linear(n_in, n_out, std):
-    return Linear(n_in, n_out, weight_attr=_w(std), bias_attr=False)
+def _linear(n_in, n_out, std, dtype="float32"):
+    return Linear(n_in, n_out, weight_attr=_w(std), bias_attr=False,
+                  dtype=dtype)
+
+
+def _matmul_in(x, dtype: str):
+    """``x`` as a matmul's input: in the parameters' dtype."""
+    return x if str(x.dtype) == dtype else x.astype(dtype)
 
 
 class RMSNorm(Layer):
-    def __init__(self, size: int, eps: float):
-        super().__init__()
+    def __init__(self, size: int, eps: float, dtype="float32"):
+        super().__init__(dtype=dtype)
         self.eps = eps
         self.weight = self.create_parameter(
             [size], attr=ParamAttr(initializer=ConstantInitializer(1.0)))
@@ -184,11 +247,12 @@ class SwiGLU(Layer):
     """``(silu(x W1) * (x W3)) W2``; W1 and W3 side by side in one
     ``gate_up`` matrix (gate first)."""
 
-    def __init__(self, hidden: int, width: int, std: float, out_std: float):
+    def __init__(self, hidden: int, width: int, std: float, out_std: float,
+                 dtype="float32"):
         super().__init__()
         self.width = width
-        self.gate_up = _linear(hidden, 2 * width, std)
-        self.down = _linear(width, hidden, out_std)
+        self.gate_up = _linear(hidden, 2 * width, std, dtype)
+        self.down = _linear(width, hidden, out_std, dtype)
 
     def forward(self, x):
         gu = self.gate_up(x)
@@ -206,12 +270,16 @@ class LagunaAttention(Layer):
         h, d = cfg.hidden_size, cfg.head_dim
         out_std = cfg.init_std / math.sqrt(2.0 * cfg.num_hidden_layers)
         # columns: the held query heads, then the held K heads, then V
-        self.qkv_proj = _linear(h, (self.q + 2 * self.kv) * d, cfg.init_std)
-        self.g_proj = _linear(h, self.q, cfg.init_std)
-        self.o_proj = _linear(self.q * d, h, out_std)
+        self.qkv_proj = _linear(h, (self.q + 2 * self.kv) * d, cfg.init_std,
+                                cfg.dtype)
+        if cfg.attention_gate:
+            self.g_proj = _linear(h, self.q, cfg.init_std, cfg.dtype)
+        self.o_proj = _linear(self.q * d, h, out_std, cfg.dtype)
         rope = cfg.rope_parameters[self.kind]
         self.rot_dim = int(d * float(rope.get("partial_rotary_factor", 1.0)))
         self.rope = rope
+        self.window = cfg.sliding_window \
+            if self.kind == "sliding_attention" else 0
         self._tables = {}
 
     def _rotary(self, seq: int):
@@ -222,15 +290,11 @@ class LagunaAttention(Layer):
         return [Tensor(jnp.asarray(t), stop_gradient=True)
                 for t in self._tables[seq]]
 
-    def forward(self, h):
-        cfg, d = self.cfg, self.cfg.head_dim
-        b, s, _ = h.shape
-        if s > cfg.max_position_embeddings:
-            raise ValueError(f"sequence length {s} exceeds "
-                             f"max_position_embeddings="
-                             f"{cfg.max_position_embeddings}")
-        qkv = self.qkv_proj(h)
-        cos, sin = self._rotary(s)
+    def _heads(self, qkv, cos, sin):
+        """The projection's columns as q [b, heads, s, d] and k, v
+        [b, kv, s, d]; q and k rotated."""
+        d = self.cfg.head_dim
+        b, s, _ = qkv.shape
 
         def heads(lo, n, rotate):
             x = qkv[:, :, lo * d:(lo + n) * d].reshape([b, s, n, d])
@@ -240,15 +304,32 @@ class LagunaAttention(Layer):
                            {"X": [x], "Cos": [cos], "Sin": [sin]},
                            {})["Out"][0]
             return x
-        q = heads(0, self.q, True)
-        k = heads(self.q, self.kv, True)
-        v = heads(self.q + self.kv, self.kv, False)
-        window = self.kind == "sliding_attention"
+        return (heads(0, self.q, True), heads(self.q, self.kv, True),
+                heads(self.q + self.kv, self.kv, False))
+
+    def _attend(self, q, k, v):
+        """Causal (and windowed) attention of a call's rows over
+        themselves -> [b, s, heads, d]."""
         o = run_op("fused_attention_qkv", {"Q": [q], "K": [k], "V": [v]},
-                   {"causal": True,
-                    "window": cfg.sliding_window if window else 0,
-                    "kernel_tag": "win" if window else "full"})["Out"][0]
-        o = self._gate(h, o.transpose([0, 2, 1, 3]))
+                   {"causal": True, "window": self.window,
+                    "kernel_tag": "win" if self.window else "full"}
+                   )["Out"][0]
+        return o.transpose([0, 2, 1, 3])
+
+    def forward(self, h, cache=None, cache_pos=None, block_tables=None,
+                ctx_len=None):
+        cfg, d = self.cfg, self.cfg.head_dim
+        b, s, _ = h.shape
+        if cache is not None:
+            return self._served(h, cache, cache_pos, block_tables, ctx_len)
+        if s > cfg.max_position_embeddings:
+            raise ValueError(f"sequence length {s} exceeds "
+                             f"max_position_embeddings="
+                             f"{cfg.max_position_embeddings}")
+        cos, sin = self._rotary(s)
+        o = self._attend(*self._heads(self.qkv_proj(h), cos, sin))
+        if cfg.attention_gate:
+            o = self._gate(h, o)
         return self.o_proj(o.reshape([b, s, self.q * d]))
 
     def _gate(self, h, o):
@@ -257,10 +338,64 @@ class LagunaAttention(Layer):
         b, s, _ = h.shape
         return o * F.sigmoid(self.g_proj(h)).reshape([b, s, self.q, 1])
 
+    def _served(self, h, cache, cache_pos, tables, ctx_len):
+        """The serving engine's call: ``cache`` this layer's (k, v) pool
+        pair ``[blocks, kv, block_size, d]``, ``tables`` [b, T] its kind's
+        block tables, ``cache_pos`` [b] each request's first row of this
+        call, ``ctx_len`` [b] its rows once the call is done. -> (output,
+        the pools with the call's K and V written)."""
+        cfg, d = self.cfg, self.cfg.head_dim
+        b, s, _ = h.shape
+        kp, vp = cache[0].value, cache[1].value
+        bs = kp.shape[2]
+        pos = jnp.broadcast_to(jnp.asarray(cache_pos, jnp.int32), (b,))
+        rows = jnp.clip(pos[:, None] + jnp.arange(s, dtype=jnp.int32)[None],
+                        0, cfg.max_position_embeddings - 1)
+        # cos and sin of the rows' own positions, computed in the program:
+        # a table of max_position_embeddings rows gathered here is a
+        # constant of 4 MB a layer and a table, and made every served
+        # executable 46 MB (PERF.md section 6)
+        inv, att = rotary_inv_freq(
+            self.rot_dim, float(self.rope["rope_theta"]),
+            self.rope if self.rope.get("rope_type") == "yarn" else None)
+        ang = rows.astype(jnp.float32)[:, None, :, None] \
+            * jnp.asarray(inv, jnp.float32)
+        cos, sin = (Tensor(f(ang) * att, stop_gradient=True)
+                    for f in (jnp.cos, jnp.sin))
+        q, k, v = self._heads(self.qkv_proj(h), cos, sin)
+        kw, vw, wpos = k.value, v.value, pos
+        keep = (-(-self.window // bs) + 1) * bs
+        if self.window and s > keep:
+            # a prompt longer than the window keeps the rows its last
+            # blocks hold (BlockKVCache's window kind has no block for the
+            # ones behind): one slice of `keep` rows from the first block
+            # the window of the next position reaches
+            first = jnp.maximum(ctx_len - self.window + 1, 0) // bs * bs
+            start = jnp.clip(first - pos, 0, s - keep)
+
+            def tail(a):
+                return jax.vmap(lambda x, st: jax.lax.dynamic_slice_in_dim(
+                    x, st, keep, axis=1))(a, start)
+            kw, vw, wpos = tail(kw), tail(vw), pos + start
+        kp = block_scatter_write(kp, kw, wpos, tables)
+        vp = block_scatter_write(vp, vw, wpos, tables)
+        if s > 1:
+            o = self._attend(q, k, v)
+        else:
+            o = Tensor(block_attention_gqa(q.value, kp, vp, tables, pos,
+                                           self.window).astype(q.dtype),
+                       stop_gradient=True).transpose([0, 2, 1, 3])
+        if cfg.attention_gate:
+            o = self._gate(h, o)
+        return (self.o_proj(o.reshape([b, s, self.q * d])),
+                (Tensor(kp, stop_gradient=True),
+                 Tensor(vp, stop_gradient=True)))
+
 
 class LagunaMoE(Layer):
     """Router over all experts, the held experts' grouped products, the
-    shared expert. ``forward`` -> (output, stats of ``moe_experts``)."""
+    shared expert (where the configuration has one). ``forward`` ->
+    (output, stats of ``moe_experts``)."""
 
     def __init__(self, cfg: LagunaConfig):
         super().__init__()
@@ -272,22 +407,31 @@ class LagunaMoE(Layer):
         # computed, and its update withheld (see the module's docstring)
         whole = (lo, hi) == (0, cfg.num_experts)
         self.router = Linear(
-            h, cfg.num_experts, bias_attr=False, weight_attr=ParamAttr(
-                initializer=NormalInitializer(0.0, cfg.init_std),
+            h, cfg.num_experts, bias_attr=False, dtype=cfg.dtype,
+            weight_attr=ParamAttr(
+                initializer=NormalInitializer(
+                    0.0, cfg.init_std if cfg.router_init_std is None
+                    else cfg.router_init_std),
                 learning_rate=1.0 if whole else 0.0))
         # stacked over the held experts; gate and up side by side
         self.experts_gate_up = self.create_parameter(
-            [hi - lo, h, 2 * f], attr=_w(cfg.init_std))
+            [hi - lo, h, 2 * f], attr=_w(cfg.init_std), dtype=cfg.dtype)
         self.experts_down = self.create_parameter(
-            [hi - lo, f, h], attr=_w(out_std))
-        self.shared = SwiGLU(h, cfg.shared_expert_intermediate_size,
-                             cfg.init_std, out_std)
+            [hi - lo, f, h], attr=_w(out_std), dtype=cfg.dtype)
+        if cfg.shared_expert_intermediate_size:
+            self.shared = SwiGLU(h, cfg.shared_expert_intermediate_size,
+                                 cfg.init_std, out_std, cfg.dtype)
+
+    def _router_attrs(self):
+        cfg = self.cfg
+        return {"top_k": cfg.num_experts_per_tok,
+                "scale": cfg.moe_routed_scaling_factor,
+                "score": cfg.router_score}
 
     def forward(self, u):
         cfg = self.cfg
         r = run_op("moe_router", {"X": [u], "W": [self.router.weight]},
-                   {"top_k": cfg.num_experts_per_tok,
-                    "scale": cfg.moe_routed_scaling_factor})
+                   self._router_attrs())
         e = run_op("moe_experts",
                    {"X": [u], "TopkIdx": r["TopkIdx"],
                     "TopkWeight": r["TopkWeight"],
@@ -296,7 +440,53 @@ class LagunaMoE(Layer):
                    {"expert_lo": cfg.experts[0],
                     "num_experts": cfg.num_experts,
                     "tile_m": cfg.moe_tile_m})
-        return e["Out"][0] + self.shared(u), e["Stats"][0]
+        out = e["Out"][0]
+        if cfg.shared_expert_intermediate_size:
+            out = out + self.shared(u)
+        return out, e["Stats"][0]
+
+    def served(self, u, live=None):
+        """The serving engine's call, on arrays: ``u`` float32 [b, s, h]
+        (the norm's output; the router reads it as it is, the experts in
+        the parameters' dtype) -> (output float32 [b, s, h], experts
+        touched, int32: counted where ``s`` is 1). One row a request
+        (``live`` [b]: which are requests at all) takes the few-rows form;
+        a prompt takes the training path's, ``moe_chunk_rows`` rows a
+        pass."""
+        cfg = self.cfg
+        b, s, h = u.shape
+        dt = jnp.dtype(cfg.dtype)
+        w13, w2 = self.experts_gate_up.value, self.experts_down.value
+        router = self.router.weight.value
+
+        def route(x):
+            r = _moe_router(None, {"X": [x], "W": [router]},
+                            self._router_attrs())
+            return r["TopkIdx"][0], r["TopkWeight"][0].astype(dt)
+
+        flat = u.reshape(b * s, h)
+        if s == 1:
+            idx, weight = route(flat)
+            out, touched = moe_experts_decode(
+                flat.astype(dt), weight, idx, w13, w2, live,
+                min(cfg.moe_tile_m, DECODE_TILE_M))
+        else:
+            def one(x):
+                idx, weight = route(x)
+                return moe_experts(x.astype(dt), weight, idx, w13, w2,
+                                   cfg.experts[0], cfg.num_experts,
+                                   cfg.moe_tile_m)[0].astype(jnp.float32)
+            rows, chunk = b * s, cfg.moe_chunk_rows
+            if chunk and rows > chunk and rows % chunk == 0:
+                out = jax.lax.map(one, flat.reshape(-1, chunk, h))
+            else:
+                out = one(flat)
+            touched = jnp.zeros((), jnp.int32)
+        out = out.reshape(b, s, h).astype(jnp.float32)
+        if cfg.shared_expert_intermediate_size:
+            sh = self.shared(Tensor(u.astype(dt), stop_gradient=True))
+            out = out + sh.value.astype(jnp.float32)
+        return out, touched
 
 
 class LagunaBlock(Layer):
@@ -304,16 +494,20 @@ class LagunaBlock(Layer):
 
     def __init__(self, cfg: LagunaConfig, layer: int):
         super().__init__()
+        self.cfg = cfg
         self.sparse = cfg.mlp_layer_types[layer] == "sparse"
-        self.attn_norm = RMSNorm(cfg.hidden_size, cfg.rms_norm_eps)
+        self.attn_norm = RMSNorm(cfg.hidden_size, cfg.rms_norm_eps,
+                                 cfg.dtype)
         self.attn = LagunaAttention(cfg, layer)
-        self.mlp_norm = RMSNorm(cfg.hidden_size, cfg.rms_norm_eps)
+        self.mlp_norm = RMSNorm(cfg.hidden_size, cfg.rms_norm_eps,
+                                cfg.dtype)
         if self.sparse:
             self.moe = LagunaMoE(cfg)
         else:
             self.mlp = SwiGLU(
                 cfg.hidden_size, cfg.intermediate_size, cfg.init_std,
-                cfg.init_std / math.sqrt(2.0 * cfg.num_hidden_layers))
+                cfg.init_std / math.sqrt(2.0 * cfg.num_hidden_layers),
+                cfg.dtype)
 
     def forward(self, x):
         x = x + self.attn(self.attn_norm(x))
@@ -323,17 +517,33 @@ class LagunaBlock(Layer):
         y, stats = self.moe(u)
         return x + y, stats
 
+    def served(self, x, cache, cache_pos, tables, ctx_len, live):
+        """The serving engine's call: ``x`` the float32 residual stream
+        -> (x, this layer's pools, experts touched)."""
+        dt = self.cfg.dtype
+        a, cache = self.attn(_matmul_in(self.attn_norm(x), dt), cache,
+                             cache_pos, tables, ctx_len)
+        x = x + a.astype("float32")
+        u = self.mlp_norm(x)
+        if not self.sparse:
+            y = self.mlp(_matmul_in(u, dt)).astype("float32")
+            return x + y, cache, jnp.zeros((), jnp.int32)
+        y, touched = self.moe.served(u.value, live)
+        return x + Tensor(y, stop_gradient=True), cache, touched
+
 
 class LagunaModel(Layer):
     def __init__(self, cfg: LagunaConfig):
         super().__init__()
         self.cfg = cfg
         lo, hi = cfg.vocab
-        self.embed = Embedding(hi - lo, cfg.hidden_size,
-                               weight_attr=_w(cfg.init_std))
+        self.embed = Embedding(
+            hi - lo, cfg.hidden_size, dtype=cfg.dtype,
+            weight_attr=_w(cfg.init_std if cfg.embed_init_std is None
+                           else cfg.embed_init_std))
         self.layers = LayerList([LagunaBlock(cfg, i)
                                  for i in range(cfg.num_hidden_layers)])
-        self.norm = RMSNorm(cfg.hidden_size, cfg.rms_norm_eps)
+        self.norm = RMSNorm(cfg.hidden_size, cfg.rms_norm_eps, cfg.dtype)
         self.sparse_layers = [i for i, t in enumerate(cfg.mlp_layer_types)
                               if t == "sparse"]
         # the expert layers' counters of the last forward, one row a sparse
@@ -342,19 +552,22 @@ class LagunaModel(Layer):
             jnp.zeros((max(len(self.sparse_layers), 1), 4), jnp.float32),
             stop_gradient=True), persistable=False)
 
-    def forward(self, input_ids, collect=None):
+    def _embed(self, input_ids):
         cfg = self.cfg
         lo, hi = cfg.vocab
         ids = input_ids.value if isinstance(input_ids, Tensor) \
             else jnp.asarray(input_ids)
         if (lo, hi) == (0, cfg.vocab_size):
-            x = self.embed(Tensor(ids, stop_gradient=True))
-        else:
-            held = jnp.logical_and(ids >= lo, ids < hi)
-            x = self.embed(Tensor(jnp.where(held, ids - lo, 0),
-                                  stop_gradient=True))
-            x = x * Tensor(held[..., None].astype(x.value.dtype),
-                           stop_gradient=True)
+            return self.embed(Tensor(ids, stop_gradient=True))
+        held = jnp.logical_and(ids >= lo, ids < hi)
+        x = self.embed(Tensor(jnp.where(held, ids - lo, 0),
+                              stop_gradient=True))
+        return x * Tensor(held[..., None].astype(x.value.dtype),
+                          stop_gradient=True)
+
+    def forward(self, input_ids, collect=None):
+        cfg = self.cfg
+        x = self._embed(input_ids)
         stats = []
         for blk in self.layers:
             if cfg.recompute:
@@ -373,28 +586,80 @@ class LagunaModel(Layer):
             self.moe_stats_last.value = jnp.stack(stats)
         return self.norm(x)
 
+    def served(self, input_ids, cache, cache_pos, block_tables,
+               last=None, collect=None):
+        """The serving engine's call -> (the final norm's output float32
+        [b, s, h], the caches, the experts some live row chose summed over
+        the layers: an int32 scalar). ``cache``: one (k, v) pool pair a
+        layer. ``block_tables``: one
+        table a layer kind in ``cfg.cache_kinds()``'s order (the table
+        itself where there is one kind). ``last`` [b]: each prompt's last
+        row in this call (None: every row is one)."""
+        cfg = self.cfg
+        kinds = cfg.cache_kinds()
+        tables = block_tables if isinstance(block_tables, (tuple, list)) \
+            else (block_tables,)
+        if len(tables) != len(kinds):
+            raise ValueError(f"{len(tables)} block tables for the layer "
+                             f"kinds {[k[0] for k in kinds]}")
+        table_of = {name: jnp.asarray(t, jnp.int32)
+                    for (name, _, _), t in zip(kinds, tables)}
+        x = self._embed(input_ids).astype("float32")
+        b, s = x.shape[0], x.shape[1]
+        pos = jnp.broadcast_to(jnp.asarray(cache_pos, jnp.int32), (b,))
+        ctx_len = pos + (s if last is None
+                         else jnp.asarray(last, jnp.int32) + 1)
+        # a slot with no request has no row yet: it routes nowhere
+        live = pos > 0 if s == 1 else None
+        caches, touched = [], jnp.zeros((), jnp.int32)
+        for i, blk in enumerate(self.layers):
+            x, c, t = blk.served(x, cache[i], pos,
+                                 table_of[cfg.layer_types[i]], ctx_len, live)
+            caches.append(c)
+            touched = touched + t
+            if collect is not None:
+                collect.append(x)
+        return self.norm(x), caches, touched
+
 
 class LagunaForCausalLM(Layer):
     """The model with its untied head. ``forward(ids)`` -> logits over the
     held vocabulary rows; with ``labels`` the mean next-token
-    cross-entropy over them (labels outside the held rows are ignored)."""
+    cross-entropy over them (labels outside the held rows are ignored);
+    with ``cache`` the serving engine's call -> (float32 logits, caches)."""
+
+    #: the name the build's and the first trace's spans carry
+    span_prefix = "laguna"
 
     def __init__(self, cfg: LagunaConfig):
         super().__init__()
-        with RecordEvent("laguna.build",
+        with RecordEvent(f"{self.span_prefix}.build",
                          {"layers": cfg.num_hidden_layers,
                           "params": cfg.num_params()}):
             self.cfg = cfg
             self.model = LagunaModel(cfg)
             lo, hi = cfg.vocab
-            self.lm_head = _linear(cfg.hidden_size, hi - lo, cfg.init_std)
+            self.lm_head = _linear(cfg.hidden_size, hi - lo, cfg.init_std,
+                                   cfg.dtype)
         self._traced = False
 
-    def forward(self, input_ids, labels=None, collect=None):
+    def forward(self, input_ids, labels=None, collect=None, cache=None,
+                cache_pos=None, block_tables=None, lora=None, last=None,
+                counters=None):
         # the first forward is the one a compiled step traces
         span = contextlib.nullcontext() if self._traced \
-            else RecordEvent("laguna.first_trace")
+            else RecordEvent(f"{self.span_prefix}.first_trace")
         self._traced = True
+        if cache is not None:
+            if lora is not None:
+                raise ValueError(f"{type(self).__name__} has no LoRA path")
+            with span:
+                logits, caches, touched = self._served(
+                    input_ids, cache, cache_pos, block_tables, last, collect)
+            if counters is None:
+                return logits, caches
+            # the decode step's device counters (``serving_spec``'s names)
+            return logits, caches, counters + touched.astype(counters.dtype)
         with span:
             logits = self.lm_head(self.model(input_ids, collect))
         if labels is None:
@@ -409,6 +674,22 @@ class LagunaForCausalLM(Layer):
             logits.reshape([-1, hi - lo]),
             Tensor(lab.reshape(-1, 1), stop_gradient=True),
             ignore_index=-100)
+
+    def _served(self, input_ids, cache, cache_pos, block_tables, last,
+                collect):
+        """-> (logits float32 [b, s, vocab], or [b, 1, vocab] of each
+        prompt's ``last`` row: the head never multiplies a bucket's
+        padding; the caches; the experts touched)."""
+        h, caches, touched = self.model.served(
+            input_ids, cache, cache_pos, block_tables, last, collect)
+        h = h.value
+        if last is not None:
+            h = jnp.take_along_axis(
+                h, jnp.asarray(last, jnp.int32)[:, None, None], axis=1)
+        w = self.lm_head.weight.value
+        logits = jnp.einsum("bsh,hv->bsv", h.astype(w.dtype), w,
+                            preferred_element_type=jnp.float32)
+        return Tensor(logits, stop_gradient=True), caches, touched
 
     def moe_stats(self) -> dict:
         """The expert layers' counters of the last step, read from the

@@ -19,8 +19,9 @@ from . import functional as F
 
 class Linear(Layer):
     def __init__(self, in_features: int, out_features: int,
-                 weight_attr=None, bias_attr=None, name=None):
-        super().__init__()
+                 weight_attr=None, bias_attr=None, name=None,
+                 dtype="float32"):
+        super().__init__(dtype=dtype)
         self.weight = self.create_parameter(
             [in_features, out_features], attr=weight_attr,
             default_initializer=XavierInitializer())
@@ -207,8 +208,8 @@ class GroupNorm(Layer):
 
 class Embedding(Layer):
     def __init__(self, num_embeddings, embedding_dim, padding_idx=None,
-                 sparse=False, weight_attr=None):
-        super().__init__()
+                 sparse=False, weight_attr=None, dtype="float32"):
+        super().__init__(dtype=dtype)
         self._padding_idx = padding_idx
         from ..initializer import NormalInitializer
         self.weight = self.create_parameter(

@@ -254,6 +254,53 @@ def block_attention(q, k_pool, v_pool, tables, pos):
     return jnp.einsum("bhqtk,bthkd->bhqd", probs, vg)
 
 
+def block_attention_gqa(q, k_pool, v_pool, tables, pos, window=0):
+    """One decode row a request over its paged KV with grouped KV heads:
+    ``q`` [b, hq, 1, d] (already rotated), pools [blocks, hkv, bs, d]
+    (``hq`` a multiple of ``hkv``: query head ``j`` reads KV head
+    ``j // (hq / hkv)``), ``tables`` [b, T], ``pos`` [b] the row's position
+    (its own K and V are in the pool already) -> [b, hq, 1, d] float32.
+
+    ``window`` > 0: key ``t`` is visible iff ``pos - window < t <= pos``, and
+    only the ``ceil(window / bs) + 1`` table entries from the first block
+    the window reaches are gathered, so a window layer reads its window
+    whatever the context's length (the entries behind it are the trash
+    block's anyway: ``BlockKVCache`` frees them). Products take the pools'
+    dtype in and accumulate in float32; the softmax is float32."""
+    tables = jnp.asarray(tables, jnp.int32)
+    pos = jnp.asarray(pos, jnp.int32)
+    b, hq, s, d = q.shape
+    if s != 1:
+        raise ValueError(f"block_attention_gqa reads one row a request, "
+                         f"got {s}")
+    hkv, bs = k_pool.shape[1], k_pool.shape[2]
+    T = tables.shape[1]
+    if window:
+        slots = min(T, -(-window // bs) + 1)
+        first = jnp.maximum(pos - window + 1, 0) // bs
+    else:
+        slots, first = T, jnp.zeros_like(pos)
+    entry = first[:, None] + jnp.arange(slots, dtype=jnp.int32)[None]
+    phys = jnp.take_along_axis(tables, jnp.minimum(entry, T - 1), axis=1)
+    phys = jnp.where(entry < T, phys, 0)
+    kg, vg = k_pool[phys], v_pool[phys]           # [b, slots, hkv, bs, d]
+    qg = q.reshape(b, hkv, hq // hkv, d).astype(kg.dtype)
+    logits = jnp.einsum("bhgd,bthkd->bhgtk", qg, kg,
+                        preferred_element_type=jnp.float32) \
+        * (1.0 / math.sqrt(d))
+    key = entry[:, :, None] * bs + jnp.arange(bs, dtype=jnp.int32)
+    seen = key <= pos[:, None, None]
+    if window:
+        seen = jnp.logical_and(seen, key > (pos - window)[:, None, None])
+    logits = jnp.where(seen[:, None, None], logits,
+                       jnp.finfo(jnp.float32).min)
+    probs = jax.nn.softmax(logits.reshape(b, hkv, hq // hkv, slots * bs),
+                           axis=-1).reshape(logits.shape)
+    out = jnp.einsum("bhgtk,bthkd->bhgd", probs.astype(vg.dtype), vg,
+                     preferred_element_type=jnp.float32)
+    return out.reshape(b, hq, 1, d)
+
+
 def block_gather_dequant(pool, scales, tables):
     """:func:`block_gather` for the int8 pool: gather code blocks and
     their per-block-per-head scales through ``tables`` and dequantize to

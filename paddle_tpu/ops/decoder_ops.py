@@ -119,11 +119,15 @@ def _rotary_embedding(ctx, ins, attrs):
 def _moe_router(ctx, ins, attrs):
     """X [.., h] x W [h, experts] -> the ``top_k`` experts of every token
     (TopkIdx int32 [.., k]) and their weights (TopkWeight float32 [.., k]):
-    ``scale * s_e / sum of the chosen s``, ``s = sigmoid(x W)`` in float32
-    at full precision (under AMP the op is on the float32 list)."""
+    ``scale * s_e / sum of the chosen s``, ``s = sigmoid(x W)`` (attr
+    ``score`` ``"softmax"``: the softmax over all experts) in float32 at
+    full precision (under AMP the op is on the float32 list)."""
     x, w = ins["X"][0], ins["W"][0]
     k = int(attrs["top_k"])
-    scores = jax.nn.sigmoid(jnp.matmul(
+    score = {"sigmoid": jax.nn.sigmoid,
+             "softmax": lambda z: jax.nn.softmax(z, axis=-1)}[
+                 attrs.get("score") or "sigmoid"]
+    scores = score(jnp.matmul(
         x.astype(jnp.float32), w.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST))
     top, idx = jax.lax.top_k(scores, k)
@@ -184,7 +188,7 @@ def _lookup(table, index):
     return jnp.sum(jnp.where(hot, table, 0), axis=-1)
 
 
-def _route(local, valid, groups, tm, num_tiles):
+def _route(local, valid, groups, tm, num_tiles, min_tiles=1):
     """The sorted layout of one set of tokens: ``local`` [t, k] the chosen
     experts as indices into the held range, ``valid`` which of them are
     held. -> dict of ``tile_group`` / ``n_active`` (the kernels' tables),
@@ -199,7 +203,8 @@ def _route(local, valid, groups, tm, num_tiles):
     hot = key[:, None] == jnp.arange(groups, dtype=jnp.int32)[None]
     running = _running_counts(hot)
     counts = running[-1]
-    tile_group, n_active, row_start = gm.tile_layout(counts, tm, num_tiles)
+    tile_group, n_active, row_start = gm.tile_layout(counts, tm, num_tiles,
+                                                     min_tiles)
     rank = jnp.sum(jnp.where(hot, running, 0), axis=1) - 1
     rows = num_tiles * tm
     pos = jnp.where(valid.reshape(pairs), _lookup(row_start, key) + rank,
@@ -216,13 +221,14 @@ def _route(local, valid, groups, tm, num_tiles):
                 counts=counts, dropped=dropped)
 
 
-def _set_fwd(x, weight, route, valid, w13, w2, tm):
+def _set_fwd(x, weight, route, valid, w13, w2, tm, names=("moe_up",
+                                                         "moe_down")):
     """Forward of one set of tokens -> (out float32 [t, h], (xs, hid, y))."""
     f = w2.shape[1]
     tg, na = route["tile_group"], route["n_active"]
     xs = jnp.take(x, route["tok"], axis=0, mode="fill", fill_value=0)
-    hid = gm.gmm(xs, w13, tg, na, name="moe_up", tm=tm)
-    y = gm.gmm(_silu_mul(hid, f), w2, tg, na, name="moe_down", tm=tm)
+    hid = gm.gmm(xs, w13, tg, na, name=names[0], tm=tm)
+    y = gm.gmm(_silu_mul(hid, f), w2, tg, na, name=names[1], tm=tm)
     return _gather_sum(y, route["pos"], valid, weight), (xs, hid, y)
 
 
@@ -385,6 +391,39 @@ def _experts_bwd(expert_lo, num_experts, tm, res, cts):
 
 
 moe_experts.defvjp(_experts_fwd, _experts_bwd)
+
+
+#: rows of a tile of the decode regime's buffer: bfloat16's sublane pack,
+#: the least a grouped product's row tile can be
+DECODE_TILE_M = 16
+
+
+def moe_experts_decode(x, weight, idx, w13, w2, live=None,
+                       tm: int = DECODE_TILE_M):
+    """The expert layer at a few rows (a decode step: one row a request),
+    forward only, every expert held. x [t, h], weight / idx [t, k], w13
+    [e, h, 2f], w2 [e, f, h], ``live`` bool [t] (rows of empty slots route
+    nowhere) -> (out float32 [t, h], experts touched int32 []).
+
+    Where training has thousands of rows a call, tiles of 128 rows and is
+    bound by compute, a step of 16 rows x 8 choices touches most of the
+    experts with two or three rows each and is bound by the read of their
+    weights. So the same sorted buffer is laid out in tiles of ``tm`` = 16
+    rows, an expert no row chose owns no tile (its weights are not read:
+    ``tile_layout(min_tiles=0)``), and the buffer has room for every pair
+    whatever the routing (``t x k`` pairs can open at most ``min(t x k,
+    e)`` tiles beyond ``t x k / tm``), so there is no second path. The two
+    products are ``moe_up_dec`` / ``moe_down_dec`` in a device trace."""
+    t, k = idx.shape
+    groups = w13.shape[0]
+    pairs = t * k
+    tiles = -(-pairs // tm) + min(pairs, groups)
+    valid = jnp.ones((t, k), bool) if live is None \
+        else jnp.broadcast_to(live[:, None], (t, k))
+    route = _route(idx, valid, groups, tm, tiles, min_tiles=0)
+    out, _ = _set_fwd(x, weight, route, valid, w13, w2, tm,
+                      names=("moe_up_dec", "moe_down_dec"))
+    return out, jnp.sum(route["counts"] > 0).astype(jnp.int32)
 
 
 @register("moe_experts", no_grad_slots=("TopkIdx",),

@@ -38,19 +38,28 @@ TILE_M = 128
 _VMEM_LIMIT = 64 * 1024 * 1024
 
 
-def tile_layout(group_sizes, tm: int, num_tiles: int):
+def tile_layout(group_sizes, tm: int, num_tiles: int, min_tiles: int = 1):
     """The padded layout of groups of ``group_sizes`` [g] rows in a buffer
     of ``num_tiles`` row tiles -> (``tile_group`` [num_tiles] int32: the
     group of each tile, the last active tile's group past the end;
     ``n_active`` [1] int32; ``row_start`` [g] int32: the first row of each
-    group). Every group owns at least one tile, so that its block of a
-    ``tgmm`` result is written (zeros for an empty group)."""
+    group). Every group owns at least ``min_tiles`` tiles: one, so that its
+    block of a ``tgmm`` result is written (zeros for an empty group); none
+    where only :func:`gmm` runs (a forward pass of a few rows: an expert no
+    row chose then costs no tile and its weights are not read). At least
+    one tile is active whatever the sizes."""
     sizes = jnp.asarray(group_sizes, jnp.int32)
-    tiles = jnp.maximum((sizes + tm - 1) // tm, 1)
+    tiles = jnp.maximum((sizes + tm - 1) // tm, min_tiles)
     ends = jnp.cumsum(tiles)
     n_active = ends[-1]
+    if not min_tiles:
+        n_active = jnp.maximum(n_active, 1)
     m = jnp.minimum(jnp.arange(num_tiles, dtype=jnp.int32), n_active - 1)
     tile_group = jnp.searchsorted(ends, m, side="right").astype(jnp.int32)
+    if not min_tiles:
+        # with no row at all the one active tile would name a group past
+        # the end
+        tile_group = jnp.minimum(tile_group, sizes.shape[0] - 1)
     return (tile_group, n_active[None].astype(jnp.int32),
             ((ends - tiles) * tm).astype(jnp.int32))
 
@@ -78,6 +87,16 @@ def _gmm_kernel(tg_ref, na_ref, lhs_ref, rhs_ref, out_ref, *, transpose_rhs):
             preferred_element_type=jnp.float32).astype(out_ref.dtype)
 
 
+def _column_tile(n: int, tn: int) -> int:
+    """The widest tile of at most ``tn`` columns that divides ``n`` in
+    whole lanes (``tn`` itself where it divides: 1024 of 1024 or 2048; 896
+    of 1792, 768 of 2304), or ``n`` when it is narrower or has no such
+    divisor."""
+    if n <= tn or n % tn == 0:
+        return min(tn, n)
+    return next((t for t in range(tn - tn % 128, 0, -128) if n % t == 0), n)
+
+
 def gmm(lhs, rhs, tile_group, n_active, *, name: str, tm: int = TILE_M,
         tn: int = 1024, transpose_rhs: bool = False):
     """``lhs`` [m, k] x ``rhs`` [g, k, n] (``[g, n, k]`` with
@@ -85,7 +104,7 @@ def gmm(lhs, rhs, tile_group, n_active, *, name: str, tm: int = TILE_M,
     multiplied by the block of group ``tile_group[i]``."""
     m, k = lhs.shape
     n = rhs.shape[1] if transpose_rhs else rhs.shape[2]
-    tn = min(tn, n)
+    tn = _column_tile(n, tn)
     if m % tm or n % tn:
         raise ValueError(f"gmm: cannot tile m={m} by {tm}, n={n} by {tn}")
 

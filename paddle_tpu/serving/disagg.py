@@ -76,6 +76,7 @@ from ..observability import tracing as _tracing
 from ..resilience.injector import fault_point
 from ..resilience.retry import RetryError, RetryPolicy
 from .engine import QueueFullError, Request, ServingEngine, _Shed
+from .seam import served_with
 from .kv_cache import prefix_chain_keys
 
 
@@ -94,6 +95,7 @@ def parse_disagg(text: str) -> Optional[Tuple[int, int]]:
             f"serving_disagg needs at least 1 worker per role, "
             f"got {text!r}")
     return p, d
+
 
 
 class _HostTierAffinity:
@@ -489,6 +491,8 @@ class DisaggRouter:
                  dispatch_threads: Optional[int] = None,
                  **engine_kwargs):
         from .. import flags as _flags
+        # the roles hand rows of one kind of cache to each other
+        served_with(model, "disaggregation", "DisaggRouter")
         g = _flags.get_flags(["serving_disagg",
                               "serving_prefix_affinity",
                               "serving_handoff_queue",
@@ -528,7 +532,8 @@ class DisaggRouter:
                 mx = engine_kwargs.get("lora_max_adapters")
                 engine_kwargs = dict(engine_kwargs)
                 engine_kwargs["lora_pool"] = LoRAPool(
-                    model.gpt.cfg, rank,
+                    served_with(model, "lora", "lora_rank > 0").lora_config,
+                    rank,
                     int(mx if mx is not None
                         else gl["serving_lora_max_adapters"]))
         if "kv_tier" not in engine_kwargs:
@@ -541,14 +546,15 @@ class DisaggRouter:
                                    "serving_block_size"])
             if gt["serving_host_tier"]:
                 from .kv_tier import HostBlockStore, TierManager
-                cfg = model.gpt.cfg
+                cfg = served_with(model, "host_tier",
+                               "FLAGS_serving_host_tier").cache_kinds[0]
                 bs = engine_kwargs.get("block_size")
                 bs = int(bs if bs is not None
                          else gt["serving_block_size"])
                 engine_kwargs = dict(engine_kwargs)
                 engine_kwargs["kv_tier"] = TierManager(
                     HostBlockStore(
-                        cfg.num_layers, cfg.num_heads, cfg.head_dim,
+                        len(cfg.layers), cfg.kv_heads, cfg.head_dim,
                         block_size=bs,
                         num_blocks=int(gt["serving_host_blocks"])))
         self.kv_tier = engine_kwargs.get("kv_tier")

@@ -113,8 +113,7 @@ from ..dygraph.tensor import Tensor
 from ..distributed.sharding import (SERVING_TP_RULES, kv_pool_shardings,
                                     mesh_cache_key, parse_serving_mesh,
                                     serving_mesh)
-from ..models.generation import (decode_megastep_paged,
-                                 decode_step_paged, draft_ngram,
+from ..models.generation import (decode_megastep_paged, draft_ngram,
                                  step_entry, verify_step_paged)
 from ..resilience.injector import fault_point
 from ..resilience.retry import RetryError, RetryPolicy
@@ -122,6 +121,7 @@ from .decoding import (STOP_MAX_LEN, STOP_MAX_SEQS, DecodeParams,
                        StopMatcher, request_key, sample_first,
                        stop_table_rows, stops_fit)
 from .kv_cache import BlockKVCache
+from .seam import served
 from .kv_tier import HostBlockStore, TierManager
 from .lora import LoRAPool
 
@@ -392,15 +392,16 @@ class ServingEngine:
                               "serving_devprof",
                               "serving_devprof_sample"])
         self.model = model
-        cfg = model.gpt.cfg
+        # everything the engine knows of the model: serving/seam.py
+        spec = self.spec = served(model)
         self.max_slots = int(max_slots if max_slots is not None
                              else g["serving_max_slots"])
         self.max_len = int(max_len if max_len is not None
                            else g["serving_max_len"])
-        if self.max_len > cfg.max_position_embeddings:
+        if self.max_len > spec.max_positions:
             raise ValueError(
                 f"serving max_len {self.max_len} exceeds the model's "
-                f"max_position_embeddings={cfg.max_position_embeddings}")
+                f"max_position_embeddings={spec.max_positions}")
         self.max_queue = int(max_queue if max_queue is not None
                              else g["serving_max_queue"])
         self.default_max_new_tokens = int(g["serving_max_new_tokens"])
@@ -442,6 +443,8 @@ class ServingEngine:
         if self.spec_tokens < 0:
             raise ValueError(
                 f"spec_tokens must be >= 0, got {self.spec_tokens}")
+        if self.spec_tokens:
+            spec.require("speculative", f"spec_tokens={self.spec_tokens}")
         if self.spec_tokens >= self.max_len:
             raise ValueError(
                 f"spec_tokens {self.spec_tokens} leaves no room in "
@@ -454,6 +457,8 @@ class ServingEngine:
         if self.megastep < 1:
             raise ValueError(
                 f"megastep must be >= 1, got {self.megastep}")
+        if self.megastep > 1:
+            spec.require("megastep", f"megastep={self.megastep}")
         if self.megastep > 1 and self.spec_tokens > 0:
             raise ValueError(
                 "megastep > 1 cannot combine with speculative decoding "
@@ -474,6 +479,18 @@ class ServingEngine:
                                        self.max_len))
         self.kv_dtype = str(kv_dtype if kv_dtype is not None
                             else g["serving_kv_dtype"])
+        if spec.kv_dtype is not None:
+            # the model states its pools' dtype; only an explicit other
+            # one is refused (the flag's default is not a request)
+            if kv_dtype is not None and str(kv_dtype) != spec.kv_dtype:
+                if str(kv_dtype) == "int8":
+                    spec.require("int8_pool", "kv_dtype='int8'")
+                raise ValueError(
+                    f"{spec.family} keeps its KV pools in "
+                    f"{spec.kv_dtype!r}; kv_dtype={kv_dtype!r} was asked")
+            self.kv_dtype = spec.kv_dtype
+        elif self.kv_dtype == "int8":
+            spec.require("int8_pool", "kv_dtype='int8'")
         # which attention lowering the compiled paged steps traced with;
         # gpt.py re-reads the flag at trace time, so this attribute is
         # observability (the gauge label + stats()), not the switch
@@ -482,6 +499,8 @@ class ServingEngine:
             dims = parse_serving_mesh(g["serving_mesh"])
             if dims is not None:
                 mesh = serving_mesh(*dims)
+        if mesh is not None:
+            spec.require("mesh", "mesh= / FLAGS_serving_mesh")
         if mesh is not None and \
                 tuple(mesh.axis_names) != ("data", "model"):
             raise ValueError(
@@ -490,7 +509,13 @@ class ServingEngine:
         self.mesh = mesh
         self.mesh_shape = (None if mesh is None else
                            tuple(int(s) for s in mesh.devices.shape))
+        want_prefix = bool(prefix_cache if prefix_cache is not None
+                           else g["serving_prefix_cache"])
+        if want_prefix:
+            spec.require("prefix_cache", "prefix_cache=True / "
+                         "FLAGS_serving_prefix_cache")
         if kv_pool is not None:
+            spec.require("disaggregation", "kv_pool=")
             # co-located disaggregated roles share one physical pool:
             # geometry comes from the pool (not the flags) so the
             # sharing cache cannot drift from what the blocks are
@@ -501,23 +526,19 @@ class ServingEngine:
                     "that built it")
             if kv_dtype is None:
                 self.kv_dtype = kv_pool.kv_dtype
-            self.cache = BlockKVCache(
-                cfg.num_layers, cfg.num_heads, cfg.head_dim,
-                self.max_slots, self.max_len,
-                block_size=kv_pool.block_size,
-                prefix_cache=bool(prefix_cache if prefix_cache is not None
-                                  else g["serving_prefix_cache"]),
+            self.cache = BlockKVCache.for_model(
+                spec, self.max_slots, self.max_len,
+                block_size=kv_pool.block_size, num_blocks=0,
+                prefix_cache=want_prefix,
                 kv_dtype=self.kv_dtype, pool=kv_pool)
         else:
-            self.cache = BlockKVCache(
-                cfg.num_layers, cfg.num_heads, cfg.head_dim,
-                self.max_slots, self.max_len,
+            self.cache = BlockKVCache.for_model(
+                spec, self.max_slots, self.max_len,
                 block_size=int(block_size if block_size is not None
                                else g["serving_block_size"]),
                 num_blocks=int(num_blocks if num_blocks is not None
                                else g["serving_num_blocks"]),
-                prefix_cache=bool(prefix_cache if prefix_cache is not None
-                                  else g["serving_prefix_cache"]),
+                prefix_cache=want_prefix,
                 kv_dtype=self.kv_dtype)
         # Multi-tenant paged LoRA: a pool of per-tenant adapter pages
         # fed to the compiled steps as one more fixed-shape input (the
@@ -528,11 +549,13 @@ class ServingEngine:
         # page ids never travel between engines.
         rank = int(lora_rank if lora_rank is not None
                    else g["serving_lora_rank"])
+        if lora_pool is not None or rank > 0:
+            spec.require("lora", "lora_rank > 0 / lora_pool=")
         if lora_pool is not None:
             self.lora_pool = lora_pool
         elif rank > 0:
             self.lora_pool = LoRAPool(
-                cfg, rank,
+                spec.lora_config, rank,
                 int(lora_max_adapters if lora_max_adapters is not None
                     else g["serving_lora_max_adapters"]))
         else:
@@ -545,11 +568,15 @@ class ServingEngine:
         # builds a per-engine one. Migration is host-side block surgery
         # plus eager pool writes — zero compiled surfaces join the step
         # cache (predict_serving_compiles(host_tier=True) is a no-op).
+        if kv_tier is not None or g["serving_host_tier"]:
+            spec.require("host_tier",
+                         "kv_tier= / FLAGS_serving_host_tier")
         if kv_tier is not None:
             self.kv_tier = kv_tier
         elif g["serving_host_tier"]:
+            full = spec.cache_kinds[0]
             self.kv_tier = TierManager(HostBlockStore(
-                cfg.num_layers, cfg.num_heads, cfg.head_dim,
+                len(full.layers), full.kv_heads, full.head_dim,
                 block_size=self.cache.block_size,
                 num_blocks=int(g["serving_host_blocks"])))
         else:
@@ -566,11 +593,11 @@ class ServingEngine:
         # are rejected with guidance.
         self.grammar = grammar
         if self.grammar is not None and \
-                self.grammar.vocab_size != cfg.vocab_size:
+                self.grammar.vocab_size != spec.vocab:
             raise ValueError(
                 f"grammar vocab {self.grammar.vocab_size} != model "
-                f"vocab {cfg.vocab_size}")
-        self._vocab = int(cfg.vocab_size)
+                f"vocab {spec.vocab}")
+        self._vocab = int(spec.vocab)
         if self.mesh is not None:
             self._place_on_mesh()
         self._queue: deque = deque()          # guarded-by: _lock
@@ -698,6 +725,10 @@ class ServingEngine:
         self._res: Dict[str, tuple] = {}
         self._carry = None                # guarded-by: _step_lock
         self._resent = False              # did this dispatch re-send?
+        # the model's device counters (seam: ``counters``): the decode
+        # step's last argument and last result, read by stats() only
+        self._counted = jnp.zeros((len(self.spec.counters),), jnp.float32) \
+            if self.spec.counters else None   # guarded-by: _step_lock
         self._repl = None
         if self.mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec
@@ -753,6 +784,7 @@ class ServingEngine:
             "_sampler_dispatches": "_step_lock",
             "_sampler_skipped": "_step_lock",
             "_carry": "_step_lock",
+            "_counted": "_step_lock",
             "_inputs_resident": "_step_lock",
         })
 
@@ -1316,6 +1348,7 @@ class ServingEngine:
         if lora_shape is not None:
             key = key + ("lora", tuple(lora_shape))
         model, mesh, kv_dtype = self.model, self.mesh, self.kv_dtype
+        spec = self.spec
 
         def _build():
             from ..models.generation import (_borrowed_params,
@@ -1324,17 +1357,11 @@ class ServingEngine:
             def _prefill(params, ids, last, pos, tables, pools,
                          lora=None):
                 from ..models.generation import (_kernel_layout,
-                                                 _unwrap_pools,
-                                                 _wrap_pools)
+                                                 _unwrap_pools)
                 with no_grad(), _borrowed_params(model, params), \
                         _kernel_layout(model, mesh):
-                    logits, newp = model(
-                        Tensor(ids, stop_gradient=True),
-                        cache=_wrap_pools(pools),
-                        cache_pos=pos, block_tables=tables, lora=lora)
-                lg = jnp.take_along_axis(logits.value,
-                                         last[:, None, None],
-                                         axis=1)[:, 0]
+                    lg, newp = spec.prefill_logits(ids, last, pos, tables,
+                                                   pools, lora)
                 pools_out, qerr = _unwrap_pools(newp)
                 return lg, pools_out, qerr
 
@@ -1399,24 +1426,23 @@ class ServingEngine:
                 live.append(rec)
         if not live:
             return live, shed, None
-        T = self.cache.blocks_per_row
-        ids = np.zeros((self.max_slots, bucket), np.int32)
-        last = np.zeros(self.max_slots, np.int32)
-        pos = np.zeros(self.max_slots, np.int32)
-        tables = np.full((self.max_slots, T), BlockKVCache.TRASH,
-                         np.int32)
-        pages = np.zeros(self.max_slots, np.int32)
+        n = self.spec.prefill_rows(bucket, self.max_slots)
+        ids = np.zeros((n, bucket), np.int32)
+        last = np.zeros(n, np.int32)
+        pos = np.zeros(n, np.int32)
+        tables = self.cache.table_rows([row for _, row, _ in live], n)
+        pages = np.zeros(n, np.int32)
         for i, (req, row, shared) in enumerate(live):
             suffix = req.context[shared:]
             ids[i, :len(suffix)] = suffix
             last[i] = len(suffix) - 1
             pos[i] = shared
-            tables[i] = self.cache.tables[row]
             if self.lora_pool is not None and req.tenant:
                 pages[i] = self.lora_pool.page_of(req.tenant)
         fn = self._prefill_entry(bucket)["fn"]
         args = (jnp.asarray(ids), jnp.asarray(last),
-                jnp.asarray(pos), jnp.asarray(tables),
+                jnp.asarray(pos), jax.tree_util.tree_map(jnp.asarray,
+                                                         tables),
                 self.cache.arrays())
         if self.lora_pool is not None:
             args = args + ((jnp.asarray(pages), self.lora_pool.arrays),)
@@ -1520,10 +1546,15 @@ class ServingEngine:
             sched.args = {"admitted": len(acquired)}
         admitted = 0
         for bucket in sorted(groups):
-            with _profiler.RecordEvent(
-                    "serving.prefill_step",
-                    {"bucket": bucket, "rows": len(groups[bucket])}):
-                admitted += self._prefill_group(bucket, groups[bucket])
+            # a dispatch carries as many prompts as the model's prefill
+            # entry has rows (max_slots, unless the model says fewer)
+            n = self.spec.prefill_rows(bucket, self.max_slots)
+            for k in range(0, len(groups[bucket]), n):
+                part = groups[bucket][k:k + n]
+                with _profiler.RecordEvent(
+                        "serving.prefill_step",
+                        {"bucket": bucket, "rows": len(part)}):
+                    admitted += self._prefill_group(bucket, part)
         return consumed, admitted
 
     def _prefill_group(self, bucket: int,
@@ -1705,7 +1736,7 @@ class ServingEngine:
         (bind, release, handoff: ``tables_version``); a copy, because
         the cache writes its array in place."""
         return self._resident("tables", self.cache.tables_version,
-                              self.cache.tables.copy)
+                              self.cache.tables_arg)
 
     def _build_samp(self, keys):  # holds: _step_lock
         """The per-slot sampling-as-data tuple for one compiled step:
@@ -1820,7 +1851,7 @@ class ServingEngine:
                 raise
             self._ahead = None
             self._shed_active(e)
-            self.cache.pool.rebuild()
+            self.cache.rebuild_pools()
             self._pool_epoch = self.cache.pool.epoch
             _monitor.stat_add("STAT_serving_pool_rebuilds")
             _runlog.log_event("serving_pool_rebuild", error=str(e))
@@ -1876,9 +1907,11 @@ class ServingEngine:
         kind = fault_point("serving.step")
         if kind == "skip":
             raise _SkipStep("injected skip of one decode iteration")
-        fn = decode_step_paged(self.model, self.mesh, self.kv_dtype,
-                               self._lora_shape)["fn"]
+        fn = self.spec.decode_entry(self.mesh, self.kv_dtype,
+                                    self._lora_shape)["fn"]
         args = self._step_args()
+        if self._counted is not None:
+            args = args + (self._counted,)
         out = self._call_paged(fn, args, args[3])
         self._note_dispatch()
         return out
@@ -1943,7 +1976,9 @@ class ServingEngine:
         self._note_tpot_ms((time.perf_counter() - t0) * 1e3)
         if timer is not None:
             timer.device_done(out)   # block_until_ready + stamp
-        nxt_dev, _, arrays, qerr, new_keys = out
+        nxt_dev, _, arrays, qerr, new_keys, *counted = out
+        if counted:
+            self._counted, = counted
         with _profiler.RecordEvent("serving.decode.fetch"):
             nxt = np.asarray(nxt_dev)  # the host waits for the device
         with _profiler.RecordEvent("serving.decode.commit",
@@ -2759,6 +2794,16 @@ class ServingEngine:
             # GET /v1/stats with the rest of this dict
             out["devprof"] = self._devprof.stats()
         c = self.cache
+        # blocks held now by layer kind, and the blocks window layers
+        # returned behind their windows while their request lived
+        out.update(c.kind_stats())
+        if self.spec.counters:
+            # the model's counters, kept on the device by its steps and
+            # fetched here only (the steps' own fetch is their tokens)
+            with self._step_lock:
+                values = np.asarray(self._counted)
+            out.update({name: float(v) for name, v
+                        in zip(self.spec.counters, values)})
         hit_t, miss_t = c.prefix_hits, c.prefix_misses
         out.update({
             "block_size": c.block_size,

@@ -21,6 +21,17 @@ transfer, zero ref changes), while engines on distinct pools copy the
 committed blocks through the destination allocator (``adopt_row``).
 Either way ``BlockAllocator.leaked()`` stays exact across the handoff.
 
+Layer kinds. A model whose layers do not all keep the same rows declares
+its kinds (``serving/seam.py``): the first keeps every row of a request and
+is the cache described above; each further kind has a ``window`` and needs
+only the last ``window`` rows (:class:`_WindowKind`: its own pool, bounded
+by ``max_slots x (window + a block)``, its own tables, blocks taken as a
+request's rows reach them and returned to the free list once they lie
+wholly behind the window, while the request lives). ``arrays()`` then
+hands the steps one pool pair a *model* layer, in the model's order, the
+tables go as one array a kind, and ``allocator`` / ``blocks_free`` /
+``blocks_used`` answer for all kinds together.
+
 Every buffer keeps a fixed shape so the batched decode step has a
 single signature and compiles exactly once; admitting or retiring a
 request is bookkeeping, never a recompile.
@@ -102,6 +113,33 @@ class BlockAllocator:
         """Blocks still referenced — for the chaos suite's leak check
         (after every request releases, only permanent refs remain)."""
         return int((self.refcount > 0).sum())
+
+
+class AllocatorView:
+    """The allocators of a cache with several layer kinds, read as one:
+    blocks free, used and still referenced over all kinds. A pool's trash
+    block is a permanent reference; the view counts ONE in all (the first
+    kind's), as a single-kind cache has one, so ``leaked() - 1`` is what a
+    leak check reads for either."""
+
+    def __init__(self, allocators):
+        self.allocators = list(allocators)
+
+    @property
+    def num_blocks(self) -> int:
+        return sum(a.num_blocks for a in self.allocators)
+
+    @property
+    def num_free(self) -> int:
+        return sum(a.num_free for a in self.allocators)
+
+    @property
+    def num_used(self) -> int:
+        return sum(a.num_used for a in self.allocators)
+
+    def leaked(self) -> int:
+        return sum(a.leaked() for a in self.allocators) \
+            - (len(self.allocators) - 1)
 
 
 class _PrefixEntry:
@@ -263,6 +301,91 @@ class BlockPool:
         self.epoch += 1
 
 
+class _WindowKind:
+    """The layers of one kind that need only a request's last ``window``
+    rows: a pool of their own, ``max_slots x budget + 1`` blocks
+    (``budget`` = the blocks ``window`` rows can touch + the one being
+    written), a table of their own (logical entries as the unbounded
+    kind's: entry ``j`` is the block of rows ``[j x bs, (j + 1) x bs)``;
+    entries behind the window or not reached yet are the trash block), and
+    per row the range ``[lo, hi)`` of entries it holds.
+
+    :meth:`hold` is the one mover: told the position a row writes next, it
+    returns the blocks wholly behind that position's window to the free
+    list and takes blocks ahead, up to ``budget`` and to the row's
+    reservation. A row never holds more than ``budget`` blocks, so the
+    pool cannot run dry while a row is free: admission reserves
+    ``min(budget, blocks of the whole request)`` and never fails on this
+    kind."""
+
+    def __init__(self, kind, max_slots: int, blocks_per_row: int,
+                 block_size: int, kv_dtype: str):
+        self.kind = kind
+        self.window = int(kind.window)
+        self.budget = -(-self.window // block_size) + 1
+        self.pool = BlockPool(len(kind.layers), kind.kv_heads,
+                              kind.head_dim, block_size=block_size,
+                              num_blocks=max_slots * self.budget + 1,
+                              kv_dtype=kv_dtype)
+        self.tables = np.full((max_slots, blocks_per_row),
+                              BlockKVCache.TRASH, np.int32)
+        self.lo = np.zeros(max_slots, np.int64)
+        self.hi = np.zeros(max_slots, np.int64)
+        self.cap = np.zeros(max_slots, np.int64)  # blocks of the request
+        self.reserved = 0           # blocks promised to admitted rows
+        self.freed_behind = 0       # blocks returned while their row lived
+
+    @property
+    def usable(self) -> int:
+        return self.pool.num_blocks - 1
+
+    def admit(self, row: int, need: int, length: int) -> bool:
+        """Reserve for a request of ``need`` rows and hold what a prompt
+        of ``length`` rows leaves for the next position. False (nothing
+        taken) if the reservation would over-commit the pool."""
+        cap = -(-int(need) // self.pool.block_size)
+        if self.reserved + min(cap, self.budget) > self.usable:
+            return False
+        self.reserved += min(cap, self.budget)
+        self.cap[row] = cap         # lo = hi = 0: release() left them so
+        self.hold(row, length, count=False)
+        return True
+
+    def hold(self, row: int, length: int, count: bool = True) -> bool:
+        """``row`` writes position ``length`` next. -> whether its table
+        changed."""
+        bs = self.pool.block_size
+        lo = max(int(length) - self.window + 1, 0) // bs
+        hi = min(int(self.cap[row]), lo + self.budget)
+        old_lo, old_hi = int(self.lo[row]), int(self.hi[row])
+        if (lo, hi) == (old_lo, old_hi):
+            return False
+        for j in range(old_lo, min(lo, old_hi)):
+            self.pool.allocator.deref(int(self.tables[row, j]))
+            self.tables[row, j] = BlockKVCache.TRASH
+            self.freed_behind += bool(count)
+        for j in range(max(old_hi, lo), hi):
+            blk = self.pool.allocator.alloc()
+            if blk is None:
+                raise RuntimeError(
+                    f"window kind {self.kind.name!r}: no block for row "
+                    f"{row} (reserved {self.reserved} of {self.usable})")
+            self.tables[row, j] = blk
+        self.lo[row], self.hi[row] = lo, max(hi, lo)
+        return True
+
+    def release(self, row: int):
+        for j in range(int(self.lo[row]), int(self.hi[row])):
+            self.pool.allocator.deref(int(self.tables[row, j]))
+            self.tables[row, j] = BlockKVCache.TRASH
+        self.reserved -= min(int(self.cap[row]), self.budget)
+        self.lo[row] = self.hi[row] = self.cap[row] = 0
+
+    @property
+    def live_blocks(self) -> int:
+        return self.pool.allocator.num_used - 1
+
+
 class BlockKVCache:
     """Block-paged KV storage + ref-counted allocator + prefix cache.
 
@@ -302,7 +425,8 @@ class BlockKVCache:
                  max_slots: int, max_len: int, block_size: int = 16,
                  num_blocks: int = 0, prefix_cache: bool = True,
                  dtype=None, kv_dtype: str = "f32",
-                 pool: Optional[BlockPool] = None):
+                 pool: Optional[BlockPool] = None,
+                 window_kinds: Sequence = (), layer_order=None):
         self.max_slots = int(max_slots)
         self.max_len = int(max_len)
         if pool is not None:
@@ -353,6 +477,38 @@ class BlockKVCache:
         self._nblocks = np.zeros(self.max_slots, np.int32)  # owned per row
         self._free_rows = list(range(self.max_slots))
         self.prefix_cache_enabled = bool(prefix_cache)
+        # the bounded kinds beside the kind above; ``_order[l]`` = (kind,
+        # index in the kind) of model layer ``l``, kind 0 the unbounded one
+        self._windows = [
+            _WindowKind(k, self.max_slots, self.blocks_per_row,
+                        self.pool.block_size, self.pool.kv_dtype)
+            for k in window_kinds]
+        self._order = list(layer_order) if self._windows else None
+        if self._windows and (prefix_cache or pool is not None):
+            raise ValueError(
+                "a cache with window kinds has no prefix cache and no "
+                "shared pool: a layer that forgets rows cannot lend them")
+
+    @classmethod
+    def for_model(cls, spec, max_slots: int, max_len: int, *,
+                  block_size: int, num_blocks: int, prefix_cache: bool,
+                  kv_dtype: str, pool: Optional[BlockPool] = None):
+        """The cache of a served model (``serving/seam.py``): the first
+        kind sized by ``num_blocks``, each window kind by ``max_slots``,
+        its window and ``block_size``."""
+        first, rest = spec.cache_kinds[0], spec.cache_kinds[1:]
+        if first.window or any(not k.window for k in rest):
+            raise ValueError("the first cache kind keeps every row, the "
+                             "others have a window")
+        order = {}
+        for ki, kind in enumerate(spec.cache_kinds):
+            for i, layer in enumerate(kind.layers):
+                order[layer] = (ki, i)
+        return cls(len(first.layers), first.kv_heads, first.head_dim,
+                   max_slots, max_len, block_size=block_size,
+                   num_blocks=num_blocks, prefix_cache=prefix_cache,
+                   kv_dtype=kv_dtype, pool=pool, window_kinds=rest,
+                   layer_order=[order[l] for l in sorted(order)])
 
     # -- pool delegation ---------------------------------------------
     # the physical state lives in self.pool so sharing caches observe
@@ -380,8 +536,13 @@ class BlockKVCache:
         self.pool.layers = value
 
     @property
-    def allocator(self) -> BlockAllocator:
-        return self.pool.allocator
+    def allocator(self):
+        """The allocator; of a cache with window kinds, all of them read
+        as one (:class:`AllocatorView`)."""
+        if not self._windows:
+            return self.pool.allocator
+        return AllocatorView([self.pool.allocator]
+                             + [w.pool.allocator for w in self._windows])
 
     @property
     def _prefix(self) -> "OrderedDict[int, _PrefixEntry]":
@@ -497,7 +658,7 @@ class BlockKVCache:
         reffed: List[int] = []  # prefix refs to unwind on failure
         blocks: List[int] = []
         for ent in matched[:nshared]:
-            self.allocator.ref(ent.block)
+            self.pool.allocator.ref(ent.block)
             self._prefix.move_to_end(ent.key)
             reffed.append(ent.block)
             blocks.append(ent.block)
@@ -506,9 +667,9 @@ class BlockKVCache:
             blk = self._alloc_block()
             if blk is None:
                 for b in taken:
-                    self.allocator.deref(b)
+                    self.pool.allocator.deref(b)
                 for b in reffed:
-                    self.allocator.deref(b)
+                    self.pool.allocator.deref(b)
                 return None
             taken.append(blk)
             blocks.append(blk)
@@ -533,7 +694,17 @@ class BlockKVCache:
             self.layers = [
                 tuple(a.at[dst].set(a[src]) for a in layer)
                 for layer in self.layers]
-        row = self._free_rows.pop(0)
+        row = self._free_rows[0]
+        admitted = []
+        for w in self._windows:
+            if not w.admit(row, need, len(prompt)):
+                for a in admitted:
+                    a.release(row)
+                for b in taken:
+                    self.pool.allocator.deref(b)
+                return None
+            admitted.append(w)
+        self._free_rows.pop(0)
         # counted here, not in _alloc_block: a failed acquire unwinds
         # its allocs, and those must not inflate the bytes/request bench
         self.blocks_allocated_total += len(taken)
@@ -550,7 +721,9 @@ class BlockKVCache:
         """Retire a request: deref every block its table row owns."""
         n = int(self._nblocks[row])
         for blk in self.tables[row, :n]:
-            self.allocator.deref(int(blk))
+            self.pool.allocator.deref(int(blk))
+        for w in self._windows:
+            w.release(row)
         self._bind_row(row, ())
         self.lengths[row] = 0
         insort(self._free_rows, row)
@@ -576,12 +749,12 @@ class BlockKVCache:
             blk = int(self.tables[row, i])
             if blk == self.TRASH:
                 break
-            self.allocator.ref(blk)
+            self.pool.allocator.ref(blk)
             pin = None
             if parent is not None and parent in self._prefix:
                 # children pin their parent so chains evict leaf-first
                 pin = self._prefix[parent].block
-                self.allocator.ref(pin)
+                self.pool.allocator.ref(pin)
             self._prefix[key] = _PrefixEntry(key, pin, blk, chunk)
 
     def flush_prefix_cache(self):
@@ -610,6 +783,10 @@ class BlockKVCache:
         destination cache, or its refs dropped via
         ``record["pool"].release_blocks(record["blocks"])`` — else
         ``leaked()`` rightly reports the blocks as lost."""
+        if self._windows:
+            raise ValueError("a row of a cache with window kinds is not "
+                             "handed off: disaggregation is refused for "
+                             "such a model at the engine's construction")
         n = int(self._nblocks[row])
         rec = {
             "blocks": [int(b) for b in self.tables[row, :n]],
@@ -677,7 +854,7 @@ class BlockKVCache:
             blk = self._alloc_block()
             if blk is None:
                 for b in taken:
-                    self.allocator.deref(b)
+                    self.pool.allocator.deref(b)
                 return None
             taken.append(blk)
         if taken and self.kv_dtype == "int8":
@@ -714,6 +891,7 @@ class BlockKVCache:
                 f"row {row}: prefill length {length} exceeds reserved "
                 f"blocks ({self._nblocks[row]} x {self.block_size})")
         self.lengths[row] = int(length)
+        self._slide(row)
 
     def advance(self, row: int, n: int = 1):
         """Advance a row's valid length by ``n`` freshly written rows
@@ -726,6 +904,15 @@ class BlockKVCache:
                 f"({self._nblocks[row]} x {self.block_size} rows, at "
                 f"{self.lengths[row]})")
         self.lengths[row] = ln
+        self._slide(row)
+
+    def _slide(self, row: int):
+        """The window kinds follow ``row``'s new length: blocks wholly
+        behind the window of the position it writes next go back to their
+        free list, blocks ahead are taken."""
+        for w in self._windows:
+            if w.hold(row, int(self.lengths[row])):
+                self.tables_version += 1
 
     def rollback(self, row: int, n: int):
         """Rewind over ``n`` rejected speculative rows. Blocks stay
@@ -743,10 +930,64 @@ class BlockKVCache:
         tuples, or (k, v, k_scale, v_scale) for int8 pools. A paged
         step consumes what it is fed (:class:`BlockPool`): bind its
         returned pools with :meth:`set_arrays` before anything reads
-        the pool again."""
-        return list(self.layers)
+        the pool again. With window kinds: one pool pair a model layer, in
+        the model's order, whichever kind's pool holds it."""
+        if not self._windows:
+            return list(self.layers)
+        pools = [self.layers] + [w.pool.layers for w in self._windows]
+        return [pools[k][i] for k, i in self._order]
 
     def set_arrays(self, layers):
         """Adopt a compiled step's returned pools (generic over the
-        2- or 4-wide layer tuples)."""
-        self.layers = [tuple(layer) for layer in layers]
+        2- or 4-wide layer tuples), each to the kind that lent it."""
+        layers = [tuple(layer) for layer in layers]
+        if not self._windows:
+            self.layers = layers
+            return
+        split = [[] for _ in range(1 + len(self._windows))]
+        for (k, _), layer in zip(self._order, layers):
+            split[k].append(layer)
+        self.layers = split[0]
+        for w, got in zip(self._windows, split[1:]):
+            w.pool.layers = got
+
+    def rebuild_pools(self):
+        """Zeroed pools (every kind's) in place of ones a failed step
+        consumed: :meth:`BlockPool.rebuild`."""
+        self.pool.rebuild()
+        for w in self._windows:
+            w.pool.rebuild()
+
+    # -- the tables as the steps take them ---------------------------
+
+    def tables_arg(self):
+        """A copy of the block tables (the cache writes its own in
+        place): the array, or with window kinds one array a kind."""
+        if not self._windows:
+            return self.tables.copy()
+        return (self.tables.copy(),) + tuple(w.tables.copy()
+                                             for w in self._windows)
+
+    def table_rows(self, rows: Sequence[int], n: int):
+        """The tables of ``rows`` as the first of ``n`` rows of a prefill
+        dispatch (the others on the trash block), shaped like
+        :meth:`tables_arg`."""
+        def pick(tables):
+            out = np.full((n, tables.shape[1]), self.TRASH, np.int32)
+            out[:len(rows)] = tables[list(rows)]
+            return out
+        if not self._windows:
+            return pick(self.tables)
+        return (pick(self.tables),) + tuple(pick(w.tables)
+                                            for w in self._windows)
+
+    def kind_stats(self) -> Dict[str, int]:
+        """Blocks a request holds now, by kind, and the blocks the window
+        kinds returned behind their windows while their request lived."""
+        out = {"kv_blocks_live_full": self.pool.allocator.num_used - 1}
+        if self._windows:
+            out["kv_blocks_live_window"] = sum(w.live_blocks
+                                               for w in self._windows)
+            out["window_blocks_freed"] = sum(w.freed_behind
+                                             for w in self._windows)
+        return out

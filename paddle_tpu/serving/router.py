@@ -54,6 +54,7 @@ from ..observability import tracing as _tracing
 from ..resilience.injector import InjectedFault, fault_point
 from ..resilience.retry import RetryError, RetryPolicy
 from .engine import QueueFullError, Request, ServingEngine
+from .seam import served_with
 
 #: per-replica health states (the serving_replica_state gauge family)
 HEALTH_STATES = ("healthy", "suspect", "dead", "recovering")
@@ -77,6 +78,7 @@ def _parse_autoscale(text: str):
         raise ValueError(
             f"serving_autoscale bounds need 1 <= MIN <= MAX, got {text!r}")
     return lo, hi
+
 
 
 class AutoscalePolicy:
@@ -222,7 +224,8 @@ class ReplicaRouter:
                 from .lora import LoRAPool
                 mx = self._engine_kwargs.get("lora_max_adapters")
                 self._engine_kwargs["lora_pool"] = LoRAPool(
-                    model.gpt.cfg, rank,
+                    served_with(model, "lora", "lora_rank > 0").lora_config,
+                    rank,
                     int(mx if mx is not None
                         else gl["serving_lora_max_adapters"]))
         if model is not None and \
@@ -240,13 +243,14 @@ class ReplicaRouter:
                                    "serving_block_size"])
             if gt["serving_host_tier"]:
                 from .kv_tier import HostBlockStore, TierManager
-                cfg = model.gpt.cfg
+                cfg = served_with(model, "host_tier",
+                               "FLAGS_serving_host_tier").cache_kinds[0]
                 bs = self._engine_kwargs.get("block_size")
                 bs = int(bs if bs is not None
                          else gt["serving_block_size"])
                 self._engine_kwargs["kv_tier"] = TierManager(
                     HostBlockStore(
-                        cfg.num_layers, cfg.num_heads, cfg.head_dim,
+                        len(cfg.layers), cfg.kv_heads, cfg.head_dim,
                         block_size=bs,
                         num_blocks=int(gt["serving_host_blocks"])))
         engine_kwargs = self._engine_kwargs

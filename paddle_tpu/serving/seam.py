@@ -1,0 +1,161 @@
+"""The model seam of the serving plane: what a model declares so that
+:class:`~paddle_tpu.serving.ServingEngine` can run it.
+
+The engine schedules, admits, allocates blocks, samples and keeps its step
+inputs on the device; everything it needs to know of the *model* it reads
+from one :class:`ServedModel`, which the model builds
+(``model.serving_spec()``):
+
+- its limits: positions, rows of the logits;
+- its paged cache, one :class:`CacheKind` a layer kind: which layers, KV
+  heads and head size, and ``window`` > 0 for a kind that needs only the
+  last ``window`` rows of a request (``BlockKVCache`` then bounds that
+  kind's pool and frees its blocks behind the window);
+- the pools' dtype where the model fixes it (``kv_dtype``; None: the
+  engine's ``FLAGS_serving_kv_dtype``);
+- which of the engine's optional features its steps can run
+  (:data:`FEATURES`); the engine refuses the others at construction, by
+  name;
+- its step builders: how a prompt's rows become logits of the last one
+  (:meth:`ServedModel.prefill_logits`, traced inside the engine's
+  ``serving_prefill_paged`` entry), how many prompts a prefill dispatch of
+  a bucket carries (:meth:`ServedModel.prefill_rows`; ``prompts_a_dispatch``
+  where the model fixes it), and the compiled decode step
+  (:meth:`ServedModel.decode_entry`);
+- the counters its decode step keeps on the device (``counters``: names of
+  the entries of one float32 vector that the step takes as its last
+  argument and returns as its last result; the engine carries it from
+  step to step beside the step's tokens and keys, so the step's fetch
+  stays what it is and only ``engine.stats()`` reads them).
+
+The defaults are the generic paged forward every model of this repo with a
+``model(ids, cache=, cache_pos=, block_tables=, lora=)`` call shares, so
+:class:`~paddle_tpu.models.gpt.GPTForCausalLM` declares numbers only.
+"""
+
+from __future__ import annotations
+
+from dataclasses import dataclass
+from typing import Optional, Tuple
+
+import jax.numpy as jnp
+
+#: what an engine can be asked for beyond the plain paged loop
+FEATURES = frozenset({
+    "prefix_cache",     # prefix reuse (prefix_cache=True)
+    "megastep",         # megastep > 1 / dispatch_ahead
+    "speculative",      # spec_tokens > 0
+    "lora",             # lora_rank > 0 / lora_pool=
+    "mesh",             # mesh= / FLAGS_serving_mesh
+    "host_tier",        # kv_tier= / FLAGS_serving_host_tier
+    "int8_pool",        # kv_dtype="int8"
+    "disaggregation",   # kv_pool= (a pool shared between engine roles)
+})
+
+
+@dataclass(frozen=True)
+class CacheKind:
+    """One kind of layer of a paged cache."""
+    name: str
+    layers: Tuple[int, ...]     # the model's layer indices, ascending
+    kv_heads: int
+    head_dim: int
+    window: int = 0             # 0: every row of a request is kept
+
+
+@dataclass
+class ServedModel:
+    """A model as the serving engine sees it (see the module)."""
+    model: object
+    family: str                         # for error messages
+    max_positions: int
+    vocab: int
+    cache_kinds: Tuple[CacheKind, ...]  # the unbounded kind first
+    kv_dtype: Optional[str] = None
+    features: frozenset = FEATURES
+    counters: Tuple[str, ...] = ()
+    lora_config: object = None          # what LoRAPool sizes its pages by
+    #: prompts a prefill dispatch carries (None: ``max_slots``)
+    prompts_a_dispatch: Optional[int] = None
+    #: the model's call takes ``last=`` and multiplies the head on each
+    #: prompt's last row only (else the seam gathers that row's logits)
+    head_on_last_row: bool = False
+
+    @property
+    def num_layers(self) -> int:
+        return sum(len(k.layers) for k in self.cache_kinds)
+
+    def require(self, feature: str, asked_by: str):
+        """Refuse, by name, a feature this model's steps cannot run."""
+        if feature not in FEATURES:
+            raise KeyError(feature)
+        if feature not in self.features:
+            raise ValueError(
+                f"{self.family} is not served with {feature} "
+                f"({asked_by}): the serving seam of "
+                f"{type(self.model).__name__} does not declare it "
+                f"(it declares {sorted(self.features) or 'none'})")
+
+    # ------------------------------------------------------ step builders
+    def prefill_rows(self, bucket: int, max_slots: int) -> int:
+        """Prompts one prefill dispatch of ``bucket`` carries."""
+        return int(self.prompts_a_dispatch or max_slots)
+
+    def prefill_logits(self, ids, last, pos, tables, pools, lora):
+        """Inside the engine's prefill entry: the prompts' rows ``ids``
+        [rows, bucket] written through ``tables`` into ``pools`` from
+        ``pos`` on -> (float32 logits [rows, vocab] of each prompt's
+        ``last`` row, the model's returned caches)."""
+        from ..dygraph.tensor import Tensor
+        from ..models.generation import _wrap_pools
+        ids, cache = Tensor(ids, stop_gradient=True), _wrap_pools(pools)
+        if self.head_on_last_row:
+            logits, newp = self.model(ids, cache=cache, cache_pos=pos,
+                                      block_tables=tables, lora=lora,
+                                      last=last)
+            return logits.value[:, 0], newp
+        logits, newp = self.model(ids, cache=cache, cache_pos=pos,
+                                  block_tables=tables, lora=lora)
+        return jnp.take_along_axis(logits.value, last[:, None, None],
+                                   axis=1)[:, 0], newp
+
+    def decode_entry(self, mesh, kv_dtype: str, lora_shape):
+        """The compiled decode step (a ``step_entry``)."""
+        from ..models.generation import decode_step_paged
+        return decode_step_paged(self.model, mesh, kv_dtype, lora_shape,
+                                 counters=bool(self.counters))
+
+
+def served(model) -> ServedModel:
+    """``model``'s declaration to the serving plane, or a TypeError that
+    says what a model needs to be served."""
+    spec = getattr(model, "serving_spec", None)
+    if spec is None:
+        raise TypeError(
+            f"{type(model).__name__} is not a served model: the serving "
+            f"plane reads a model through model.serving_spec() -> "
+            f"paddle_tpu.serving.seam.ServedModel (GPTForCausalLM and "
+            f"MellumForCausalLM have one)")
+    return spec()
+
+
+def served_with(model, feature: str, asked_by: str) -> ServedModel:
+    """:func:`served`, refusing by name a feature the model's steps cannot
+    run: what the router and the disaggregated roles share between engines
+    (a LoRA pool, a host tier, a block pool) is sized from the seam, never
+    from a model's own attributes."""
+    spec = served(model)
+    spec.require(feature, asked_by)
+    return spec
+
+
+def require_gpt(model, what: str):
+    """For the paths that are GPT's alone (the fixed-capacity cache of
+    ``models.generation``, the router's and the disaggregated roles'
+    block shipping): refuse another model by name instead of failing on a
+    missing attribute."""
+    if getattr(model, "gpt", None) is None:
+        raise TypeError(
+            f"{what} runs GPTForCausalLM only; {type(model).__name__} is "
+            f"served by ServingEngine through its serving_spec()")
+    return model.gpt.cfg

@@ -128,6 +128,49 @@ def test_windowed_grouped_flash_attention_compiles_for_v5e(one_chip, mosaic,
         assert stem + kind in text
 
 
+# mellum_code_16k's served prompts: one prompt of the longest bucket, 32
+# query heads over 4 KV heads of 128, window 1024 or causal, forward only
+# (the whole-sequence K/V block holds at 12288 rows; 16384 is refused)
+@pytest.mark.parametrize("kind,window", [("win", 1024), ("full", 0)])
+def test_served_prompt_flash_attention_compiles_for_v5e(one_chip, mosaic,
+                                                        kind, window):
+    def s(h):
+        return jax.ShapeDtypeStruct((1, h, 12288, 128), jnp.bfloat16,
+                                    sharding=one_chip)
+    # at the precision the serving process runs at: the suite's `highest`
+    # (conftest) makes the kernel's float32 products six passes wide and
+    # 12288 rows then pass the kernel's 16 MB of VMEM by 5%
+    with jax.default_matmul_precision("default"):
+        text = jax.jit(lambda q, k, v: fa.flash_attention(
+            q, k, v, causal=True, window=window, tag=kind)).lower(
+                s(32), s(4), s(4)).compile().as_text()
+    assert text.count(KERNEL) == 1 and "flash_fwd_" + kind in text
+
+
+# and its expert layer at published widths (64 experts of 896 under hidden
+# 2304): a decode step's 16 rows x 8 choices in tiles of 16 rows
+# (moe_experts_decode: 128 / 16 + 64 tiles), and a prompt's pass of 3072
+# rows in tiles of 128; column tiles of 896 (of 1792) and 768 (of 2304)
+@pytest.mark.parametrize("name,rows,tm", [("moe_up_dec", 72 * 16, 16),
+                                          ("moe_down_dec", 72 * 16, 16),
+                                          ("moe_up", 24576 + 64 * 128, 128),
+                                          ("moe_down", 24576 + 64 * 128,
+                                           128)])
+def test_served_grouped_products_compile_for_v5e(one_chip, mosaic, name,
+                                                 rows, tm):
+    k, n = (2304, 1792) if "up" in name else (896, 2304)
+    assert gm._column_tile(n, 1024) == (896 if "up" in name else 768)
+    assert gm._column_tile(1024, 1024) == gm._column_tile(2048, 1024) == 1024
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    text = jax.jit(lambda x, w, tg, na: gm.gmm(
+        x, w, tg, na, name=name, tm=tm)).lower(
+            s((rows, k)), s((64, k, n)), s((rows // tm,), jnp.int32),
+            s((1,), jnp.int32)).compile().as_text()
+    assert text.count(KERNEL) == 1 and name in text
+
+
 # the expert layer's grouped products at laguna_pretrain_8k's shapes: 32
 # held experts of width 512 under hidden 2048, the fast buffer's 32768 rows
 # and a tile an expert; (lhs, rhs or second lhs, transposed, tk, tn)

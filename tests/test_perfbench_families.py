@@ -17,8 +17,11 @@ sys.path.insert(0, ROOT)
 from perfbench import families, flops          # noqa: E402
 
 CONFIGS = {"cgpt-1p3b": "gpt2", "cgpt-1p3b-d20": "gpt2",
-           "laguna-xs2-share8": "laguna"}
-JOBS = {"gpt2": "pretrain_1chip", "laguna": "laguna_pretrain_8k"}
+           "laguna-xs2-share8": "laguna", "mellum2-12b-d8": "mellum"}
+JOBS = {"gpt2": "pretrain_1chip", "laguna": "laguna_pretrain_8k",
+        "mellum": "mellum_code_16k"}
+FAMILY_CONFIG = {"gpt2": "cgpt-1p3b-d20", "laguna": "laguna-xs2-share8",
+                 "mellum": "mellum2-12b-d8"}
 
 
 def load(*parts):
@@ -34,7 +37,7 @@ def test_an_unknown_family_is_an_error_that_lists_the_known_ones():
     with pytest.raises(SystemExit) as e:
         families.load({"name": "some-model", "family": "no_such_family"})
     assert "no_such_family" in str(e.value)
-    assert families.known() == ["gpt2", "laguna"]
+    assert families.known() == ["gpt2", "laguna", "mellum"]
     assert all(name in str(e.value) for name in families.known())
 
 
@@ -57,7 +60,8 @@ def test_a_family_that_lacks_an_export_is_refused(monkeypatch):
 
 
 @pytest.mark.parametrize("name,key", [("cgpt-1p3b-d20", "n_head"),
-                                      ("laguna-xs2-share8", "vocab_size")])
+                                      ("laguna-xs2-share8", "vocab_size"),
+                                      ("mellum2-12b-d8", "hidden_size")])
 def test_the_file_is_the_truth_the_program_is_checked_against(name, key):
     cfg = config(name)
     family = families.load(cfg)
@@ -83,6 +87,69 @@ def test_the_laguna_file_holds_the_published_widths_uncut():
     assert mc.num_params() == cfg["params_held"] == 975_874_048
     assert (mc.experts, mc.kv_heads, mc.vocab) == \
         ((0, 32), (0, 1), (0, 12544))
+
+
+def test_the_mellum_file_holds_the_published_widths_uncut():
+    cfg = config("mellum2-12b-d8")
+    catalog = "/opt/skills/guides/model-configs/architectures.jsonl"
+    if os.path.exists(catalog):     # the catalog's row, where there is one
+        with open(catalog) as f:
+            row = next(r for r in map(json.loads, f)
+                       if r["name"] == "Mellum2-12B-A2.5B-Instruct")
+        changed = {k for k, v in row["config"].items() if cfg.get(k) != v}
+        assert changed == {"num_hidden_layers", "max_position_embeddings"}
+        assert cfg["source"].startswith(row["source_url"])
+    assert set(cfg["reduced"]) == set(cfg["reduced_why"]) == \
+        {"num_hidden_layers", "max_position_embeddings"}
+    assert (cfg["hidden_size"], cfg["num_attention_heads"],
+            cfg["num_key_value_heads"], cfg["head_dim"], cfg["num_experts"],
+            cfg["moe_intermediate_size"], cfg["num_experts_per_tok"],
+            cfg["sliding_window"], cfg["vocab_size"]) == \
+        (2304, 32, 4, 128, 64, 896, 8, 1024, 98304)
+    assert cfg["num_hidden_layers"] >= 8 and cfg["num_hidden_layers"] % 4 == 0
+    assert all(cfg.get(k) for k in ("assumed", "published", "deployment",
+                                    "engine_why"))
+    mc = families.load(cfg).model_config(cfg)
+    # 21.2M a layer outside the experts, 6.19M an expert, 453M in embedding
+    # and head (and the final norm's 2304)
+    layer = 2304 * (32 + 8) * 128 + 32 * 128 * 2304 + 2 * 2304 \
+        + 2304 * 64 + 64 * 3 * 2304 * 896
+    assert layer == 417_747_456
+    assert mc.num_params() == cfg["params_held"] \
+        == 8 * layer + 2 * 98304 * 2304 + 2304 == 3_794_966_784
+    assert (mc.attention_gate, mc.router_score, mc.dtype) == \
+        (False, "softmax", "bfloat16")
+    assert mc.shared_expert_intermediate_size == 0
+    assert [(n, len(l), w) for n, l, w in mc.cache_kinds()] == \
+        [("full_attention", 2, 0), ("sliding_attention", 6, 1024)]
+
+
+def test_the_mellum_family_refuses_a_training_job_by_name():
+    cfg = config("mellum2-12b-d8")
+    with pytest.raises(SystemExit) as e:
+        families.load(cfg).train_job(cfg, {"kind": "train"})
+    assert "no training job" in str(e.value)
+
+
+def test_any_16_requests_of_mellum_code_16k_fit_the_pool():
+    """The file is held to what its ``lengths_why`` says: the largest pair
+    sixteen times over fits the full layers' pool, every prompt reaches a
+    bucket, and the window layers' pools hold 16 x (window + a block)."""
+    from perfbench import traffic as T
+    cfg, tr = config("mellum2-12b-d8"), load("traffic",
+                                             "mellum_code_16k.json")
+    e = cfg["engine"]
+    pairs = T.multiset(tr)
+    assert len(pairs) == 16 == e["max_slots"]
+    assert tr["queue_depth_slots"] == 1 and tr["preroll_completions"] == 16
+    assert all(2048 <= p <= 12288 and 256 <= a <= 768 for p, a in pairs)
+    assert all(p + a <= tr["multiset"]["max_total"] == e["max_len"]
+               for p, a in pairs)
+    need = max(-(-(p + a) // e["block_size"]) for p, a in pairs)
+    assert need == 49 and 16 * need <= e["num_blocks"] - 1
+    assert T.buckets_used(tr, e["buckets"]) == [3072, 6144, 9216, 12288]
+    assert all(b % 3072 == 0 and b % 512 == 0 for b in e["buckets"])
+    assert e["prefix_cache"] is False
 
 
 # per layer 8*2048^2 + 4*2048*8192 + 2*1024*2048 = 104,857,600; head
@@ -150,12 +217,49 @@ KERNELS = {
 }
 
 
+# Mellum's served kernels, one call. A prompt's expert pass: 3072 rows x
+# 8 choices = 24576 pairs against the whole stack of 64: up 2*24576*2304*
+# 1792, down 2*24576*896*2304, bytes the pairs on both sides and the stack
+# at 2 B. A decode step's: 16 x 8 = 128 pairs and 64 * (1 - (7/8)^16) =
+# 56.444 experts' weights. Flash, 32 query heads over 4 KV heads, d 128:
+# the MEAN call of the 16 prompts' buckets: a window layer's query sees
+# (1024*1025/2 + (s - 1024)*1024) / s keys, a full layer's (s + 1) / 2.
+TOUCHED = 64 * (1 - (7 / 8) ** 16)
+MELLUM_BUCKETS = ((3072, 4), (6144, 6), (9216, 3), (12288, 3))
+KERNELS.update({
+    ("mellum", "moe_up"): (2.0 * 24576 * 2304 * 1792,
+                           2.0 * (24576 * (2304 + 1792)
+                                  + 64 * 2304 * 1792)),
+    ("mellum", "moe_down"): (2.0 * 24576 * 896 * 2304,
+                             2.0 * (24576 * (896 + 2304)
+                                    + 64 * 896 * 2304)),
+    ("mellum", "moe_up_dec"): (2.0 * 128 * 2304 * 1792,
+                               2.0 * (128 * (2304 + 1792)
+                                      + TOUCHED * 2304 * 1792)),
+    ("mellum", "moe_down_dec"): (2.0 * 128 * 896 * 2304,
+                                 2.0 * (128 * (896 + 2304)
+                                        + TOUCHED * 896 * 2304)),
+    # the mean call of the 16 prompts' buckets (3072 x 4, 6144 x 6,
+    # 9216 x 3, 12288 x 3; mean 7104 rows): operations and bytes apart
+    ("mellum", "flash_fwd_win"): (
+        4.0 * 32 * 128 * sum(
+            n * (1024 * 1025 / 2.0 + (s - 1024) * 1024)
+            for s, n in MELLUM_BUCKETS) / 16,
+        (2 * 32 + 2 * 4) * 7104.0 * 128 * 2.0 + 32 * 7104.0 * 4.0),
+    ("mellum", "flash_fwd_full"): (
+        4.0 * 32 * 128 * sum(n * s * (s + 1) / 2.0
+                             for s, n in MELLUM_BUCKETS) / 16,
+        (2 * 32 + 2 * 4) * 7104.0 * 128 * 2.0 + 32 * 7104.0 * 4.0),
+})
+
+
 @pytest.mark.parametrize("family,kernel", sorted(KERNELS))
 def test_kernel_counts_are_the_hand_count(family, kernel):
-    name = {"gpt2": "cgpt-1p3b-d20", "laguna": "laguna-xs2-share8"}[family]
+    name = FAMILY_CONFIG[family]
     cfg, job = config(name), load("traffic", JOBS[family] + ".json")
     got = families.load(cfg).kernel_counts(kernel, cfg, job)
-    assert got == KERNELS[family, kernel]
+    assert got == pytest.approx(KERNELS[family, kernel], rel=1e-12)
+    assert TOUCHED == pytest.approx(56.444, abs=1e-3)
     assert 2 * 16 * 8192 * 496.03125 * 128 * 2 == 33_288_093_696
     # and the floor the roofline share divides
     floors = flops.kernel_floors(
@@ -168,16 +272,20 @@ def test_kernel_counts_are_the_hand_count(family, kernel):
                   got[1] / peak["hbm_bytes_per_s"]))
 
 
-@pytest.mark.parametrize("family", ["gpt2", "laguna"])
+@pytest.mark.parametrize("family", ["gpt2", "laguna", "mellum"])
 def test_a_kernel_the_family_has_no_count_for_is_none(family):
-    name = {"gpt2": "cgpt-1p3b-d20", "laguna": "laguna-xs2-share8"}[family]
+    name = FAMILY_CONFIG[family]
     cfg, job = config(name), load("traffic", JOBS[family] + ".json")
     counts = families.load(cfg).kernel_counts
     assert counts("paged_decode_attn", cfg, job) is None
-    other = {"gpt2": "flash_fwd_win", "laguna": "flash_fwd"}[family]
-    assert counts(other, cfg, job) is None        # the other family's name
+    other = {"gpt2": "flash_fwd_win", "laguna": "flash_fwd",
+             "mellum": "moe_up_dx"}[family]
+    assert counts(other, cfg, job) is None        # another family's name
+    # and none for a job of the other kind (serving for the families that
+    # train, training for the one that serves)
     assert counts(sorted(k for f, k in KERNELS if f == family)[0], cfg,
-                  {"kind": "open_loop"}) is None
+                  {"kind": "train" if family == "mellum"
+                   else "open_loop"}) is None
 
 
 def test_every_metric_of_the_new_cell_has_its_file_and_its_kernel():
@@ -199,6 +307,68 @@ def test_every_metric_of_the_new_cell_has_its_file_and_its_kernel():
         if stem.endswith("_busy_pct"):
             assert stem[:-len("_busy_pct")] in kernels
             assert spec["reader"]["over"] == "trace.busy_s"
+
+
+def test_every_metric_of_the_mellum_cell_has_its_file_and_its_kernel():
+    bench = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
+    cell = next(c for c in bench["workloads"]
+                if c["name"] == "mellum_code_16k")
+    assert (cell["config"], cell["traffic"], cell["chips"]) == \
+        ("mellum2-12b-d8", "mellum_code_16k", 1)
+    assert "mellum_code_16k" in next(
+        m for m in bench["end_to_end"]
+        if m["name"] == "serve_tok_s")["workloads"]
+    mine = [m for m in bench["per_layer"]
+            if m.get("workloads") == ["mellum_code_16k"]]
+    assert len(mine) == 13 + 4 + 12     # the four twins after the review
+    kernels = {k for f, k in KERNELS if f == "mellum"}
+    for m in mine:
+        spec = load("metrics", m["name"] + ".json")
+        assert m["name"].endswith(".mellum") and spec["unit"] == m["unit"]
+        assert spec["moves"] == m["moves"] == "serve_tok_s"
+        assert spec["layer"] == m["layer"]
+        stem = m["name"][:-len(".mellum")]
+        if stem.endswith("_roofline_pct"):
+            kernel = stem[:-len("_roofline_pct")]
+            assert kernel in kernels
+            assert spec["reader"]["name"] == "kernel_floor_s." + kernel
+            assert spec["reader"]["over"] == "trace.kernel_s." + kernel
+        if stem.endswith("_busy_pct"):
+            assert stem[:-len("_busy_pct")] in kernels
+            assert spec["reader"]["over"] == "trace.busy_s"
+    over = {m["name"]: load("metrics", m["name"] + ".json")["reader"]
+            for m in mine}
+    assert over["experts_touched_per_step.mellum"]["over"] == \
+        "counters.engine.sampler_dispatches"
+    assert over["window_blocks_freed_per_request.mellum"]["over"] == \
+        "counters.engine.completed"
+
+
+def test_the_mellum_cells_toy_twin_rehearses_to_its_end():
+    """``rehearse.py --workload mellum_code_16k --trace 1`` exits 0: the
+    harness found every file by name, built the family's served model
+    behind the engine's seam, ran the closed loop with contexts that cross
+    the toy window, and checked 8 requests against the family's reference:
+    correct, nothing leaked, and the counters' metrics were read."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    out = subprocess.run(
+        [sys.executable, os.path.join(ROOT, "perfbench", "rehearse.py"),
+         "--workload", "mellum_code_16k", "--seconds", "2", "--trace", "1",
+         "--seed", str(2**31 + 77)],
+        env=env, capture_output=True, text=True, timeout=900)
+    assert out.returncode == 0, out.stderr[-2000:]
+    line = json.loads(out.stdout.strip().splitlines()[-1])
+    assert line["correct"] is True and line["failed"] == 0
+    assert line["notes"]["leaked_kv_blocks"] == 0
+    assert line["notes"]["checked_requests"] == 8
+    assert line["notes"]["max_logit_deficit"] <= 0.05
+    for name in ("experts_touched_per_step.mellum",
+                 "window_blocks_freed_per_request.mellum",
+                 "sched_occupancy_pct.mellum",
+                 "inputs_resident_share_pct.mellum",
+                 "decode_step_inside_p50_ms.mellum"):
+        assert name in line["metrics"], sorted(line["metrics"])
+    assert all(m["value"] is None for m in line["metrics"].values())
 
 
 def test_the_new_cells_toy_twin_rehearses_to_its_end():
