@@ -422,6 +422,15 @@ class GPTModel(Layer):
         return [(z, z) for _ in range(self.cfg.num_layers)]
 
 
+#: tokens one serving prefill dispatch computes (``ServedModel.
+#: tokens_a_dispatch``): about where a dense decoder's products stop being
+#: bound by the read of its weights, so a bucket of this length or more
+#: computes one prompt a dispatch and eight 64-token prompts share one.
+#: Chosen from a sweep of 1 / 2 / 4 / 8 rows at buckets 64-1024 on a v5e
+#: at 1.3B float32 (``perfbench/study/runs_pr32.jsonl``, PERF.md PR 32).
+PREFILL_TOKENS_A_DISPATCH = 512
+
+
 class GPTForCausalLM(Layer):
     """LM head tied to the token embedding (weight sharing, like GPT-2)."""
 
@@ -460,7 +469,9 @@ class GPTForCausalLM(Layer):
     def serving_spec(self):
         """What the serving plane needs of this model
         (``serving/seam.py``): one kind of layer that keeps every row,
-        every optional feature, the seam's generic step builders."""
+        every optional feature, the seam's generic step builders, and
+        the tokens a prefill dispatch computes
+        (``PREFILL_TOKENS_A_DISPATCH``)."""
         from ..serving.seam import CacheKind, ServedModel
         cfg = self.cfg
         return ServedModel(
@@ -469,7 +480,8 @@ class GPTForCausalLM(Layer):
             vocab=cfg.vocab_size,
             cache_kinds=(CacheKind("full", tuple(range(cfg.num_layers)),
                                    cfg.num_heads, cfg.head_dim),),
-            lora_config=cfg)
+            lora_config=cfg,
+            tokens_a_dispatch=PREFILL_TOKENS_A_DISPATCH)
 
 
 def gpt2_tiny() -> GPTForCausalLM:

@@ -110,10 +110,11 @@ class MellumForCausalLM(LagunaForCausalLM):
             vocab=cfg.vocab_size, cache_kinds=kinds,
             kv_dtype={"bfloat16": "bf16", "float32": "f32"}[cfg.dtype],
             features=frozenset(), counters=("experts_touched",),
-            # one prompt a dispatch: a 12288-row prompt's activations and
-            # expert buffers are ~1.5 GB, and a closed or paced loop admits
-            # one request at a time anyway
-            prompts_a_dispatch=1, head_on_last_row=True)
+            # a budget under every bucket, so one prompt a dispatch: a
+            # 12288-row prompt's activations and expert buffers are
+            # ~1.5 GB, and a closed or paced loop admits one request at a
+            # time anyway
+            tokens_a_dispatch=1, head_on_last_row=True)
 
 
 MELLUM_CONFIGS = {

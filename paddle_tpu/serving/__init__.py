@@ -2,7 +2,8 @@
 
 Continuous-batching engine over a fixed-shape KV cache: requests share
 one preallocated decode batch, prefill is shape-bucketed AND batched
-(every same-bucket admission rides one dispatch), and the decode step
+(same-bucket admissions ride one dispatch, as many as the bucket's one
+program has rows: fewer the longer the bucket), and the decode step
 compiles exactly once per engine geometry. KV memory is block-paged:
 a fixed pool of KV blocks with per-request block tables, a ref-counted
 allocator, and a rolling-hash prefix cache so a shared system prompt

@@ -23,12 +23,17 @@ Compile surfaces, all fixed-shape:
   drafter's acceptance rate (``STAT_serving_spec_*``). One XLA
   executable, compiled once per engine geometry like decode;
 - prefill: one jitted function per prompt-length *bucket*
-  (``FLAGS_serving_prefill_buckets``) at a fixed ``max_slots`` batch;
-  prompts are right-padded to the smallest bucket that fits and every
-  queued same-bucket admission rides ONE dispatch of that function
-  per step (batch rows past the admitted count are padding), so a
-  fleet of arbitrary-length prompts compiles ``len(buckets)`` times
-  and dispatches once per (bucket, step), total. Padding is sound
+  (``FLAGS_serving_prefill_buckets``), whose rows follow the bucket's
+  length: the model's token budget a dispatch over the bucket
+  (``seam.ServedModel.prefill_rows``), from one row for a long bucket
+  to ``max_slots`` for a short one. Prompts are right-padded to the
+  smallest bucket that fits and the queued same-bucket admissions of
+  a step ride that function ``rows`` at a time, in admission order
+  (batch rows past the admitted count are padding), so a fleet of
+  arbitrary-length prompts compiles ``len(buckets)`` times and a long
+  prompt does not pay for ``max_slots - 1`` rows of padding
+  (``stats()``: ``prefill_rows_live`` / ``prefill_rows_computed``).
+  Padding is sound
   because the position mask hides rows past the true length and
   decode overwrites them in place — same reuse idea as
   CompiledProgram's keyed ``_cache`` (compiler.py), keyed here by
@@ -737,6 +742,10 @@ class ServingEngine:
         # copied nothing to the device but the step's own tokens /
         # lengths
         self._inputs_resident = 0         # guarded-by: _step_lock
+        # rows the prefill dispatches carried live, and rows they
+        # computed (the rest was padding)
+        self._prefill_rows_live = 0       # guarded-by: _step_lock
+        self._prefill_rows_computed = 0   # guarded-by: _step_lock
         self._qerr_max = 0.0              # guarded-by: _step_lock
         self._qerr_gauge = None
         if self.kv_dtype == "int8":
@@ -786,6 +795,8 @@ class ServingEngine:
             "_carry": "_step_lock",
             "_counted": "_step_lock",
             "_inputs_resident": "_step_lock",
+            "_prefill_rows_live": "_step_lock",
+            "_prefill_rows_computed": "_step_lock",
         })
 
     # -------------------------------------------------------------- mesh
@@ -970,9 +981,11 @@ class ServingEngine:
         batch's per-token pace. Monotone non-decreasing in queue depth
         — the property the SLO gate and Retry-After rely on.
 
-        Model: requests ahead prefill in waves of ``max_slots``
-        (``ceil(q / max_slots)`` dispatches before ours), and the
-        newcomer waits ``ceil(max(0, q + 1 - free) / max_slots)``
+        Model: requests ahead prefill in dispatches of the rows the
+        newcomer's bucket has (``ceil(q / rows)`` dispatches before
+        ours: a request that is the k-th dispatch of its group waits k
+        of them), and the newcomer waits
+        ``ceil(max(0, q + 1 - free) / max_slots)``
         generation rounds for a slot, each lasting one mean new-token
         budget at the current TPOT. Costs come from pins
         (``slo_prefill_ms`` / ``slo_tpot_ms``) or measured EWMAs; with
@@ -993,7 +1006,8 @@ class ServingEngine:
         mean_budget = (sum(budgets) / len(budgets) if budgets
                        else self.default_max_new_tokens)
         free = max(0, self.max_slots - len(live))
-        waves_ahead = -(-q // self.max_slots)
+        waves_ahead = -(-q // self.spec.prefill_rows(bucket,
+                                                     self.max_slots))
         rounds = -(-max(0, q + 1 - free) // self.max_slots)
         return (waves_ahead + 1) * prefill + rounds * mean_budget * tpot
 
@@ -1323,24 +1337,31 @@ class ServingEngine:
     def _prefill_entry(self, bucket: int) -> dict:
         """The jitted prompt-suffix pass for one length bucket
         (compiled on first use, reused for every admission that pads
-        to it) at a fixed ``max_slots`` batch, so every same-bucket
-        admission in a step shares ONE dispatch; it writes KV through
-        per-row block tables into the shared pools. Maps
-        ``(ids [max_slots, bucket] i32, last [max_slots] i32,
-        pos [max_slots] i32, tables [max_slots, T] i32, pools)``
+        to it), ONE program a bucket, of ``rows = spec.prefill_rows(
+        bucket, max_slots)`` rows: the model's token budget over the
+        bucket's length, so a long bucket computes the one prompt it
+        admitted and a short one shares a dispatch (and a read of the
+        weights) between up to ``max_slots``; a group of more
+        same-bucket admissions than ``rows`` goes out as several
+        dispatches in admission order. It writes KV through per-row
+        block tables into the shared pools. Maps
+        ``(ids [rows, bucket] i32, last [rows] i32,
+        pos [rows] i32, tables [rows, T] i32, pools)``
         to each row's logits at its true last token plus the updated
         pools; ``pos`` is each row's write offset (its shared-prefix
         length — 0 without a prefix hit), so a prefix-cached prompt
         only computes its unshared suffix; rows past the admitted
         count are padding the caller discards. Cached in the model's
-        unified ``step_entry`` cache keyed by the full pool geometry,
-        attn impl, KV dtype, and mesh — one compile per key, so
-        engine restarts with the same geometry (benchmark reruns,
+        unified ``step_entry`` cache keyed by the rows, the full pool
+        geometry, attn impl, KV dtype, and mesh — one compile per key,
+        so engine restarts with the same geometry (benchmark reruns,
         rolling deploys) reuse the executable. Under a mesh the
         pass runs with explicit in/out shardings: pools keep
         their heads axis on ``"model"``; ids/last/pos/tables stay
         replicated plain inputs so block remapping never retraces."""
-        key = ("prefill_paged", bucket, self.max_slots, self.max_len,
+        key = ("prefill_paged", bucket,
+               self.spec.prefill_rows(bucket, self.max_slots),
+               self.max_slots, self.max_len,
                self.cache.block_size, self.cache.num_blocks,
                self.kv_dtype, self.attn_impl,
                mesh_cache_key(self.mesh))
@@ -1409,9 +1430,11 @@ class ServingEngine:
             self.kv_tier.promote(self.cache, req.context)
         return self.cache.acquire(req.context, need)
 
-    def _prefill_group_attempt(self, bucket: int, group):
-        """One batched prefill attempt for every same-bucket
-        admission; ``group`` rows are ``(req, row, shared)``. The
+    def _prefill_group_attempt(self, bucket: int,
+                               group):  # holds: _step_lock
+        """One batched prefill attempt for the same-bucket admissions
+        of one dispatch (at most the rows of the bucket's entry);
+        ``group`` rows are ``(req, row, shared)``. The
         fault site fires once per request per attempt (preserving the
         per-request `skip`-sheds-one semantics); surviving requests
         share one dispatch of the bucket's compiled function. Returns
@@ -1427,6 +1450,10 @@ class ServingEngine:
         if not live:
             return live, shed, None
         n = self.spec.prefill_rows(bucket, self.max_slots)
+        self._prefill_rows_live += len(live)
+        self._prefill_rows_computed += n
+        _monitor.stat_add("STAT_serving_prefill_rows_live", len(live))
+        _monitor.stat_add("STAT_serving_prefill_rows_computed", n)
         ids = np.zeros((n, bucket), np.int32)
         last = np.zeros(n, np.int32)
         pos = np.zeros(n, np.int32)
@@ -1545,12 +1572,28 @@ class ServingEngine:
             # known only now, so kept in the in-process event alone
             sched.args = {"admitted": len(acquired)}
         admitted = 0
+        epoch = self.cache.pool.epoch
         for bucket in sorted(groups):
-            # a dispatch carries as many prompts as the model's prefill
-            # entry has rows (max_slots, unless the model says fewer)
+            # a dispatch carries as many prompts as the bucket's entry
+            # has rows (the model's token budget over the bucket: fewer
+            # the longer it is), the rest follow in admission order
             n = self.spec.prefill_rows(bucket, self.max_slots)
             for k in range(0, len(groups[bucket]), n):
                 part = groups[bucket][k:k + n]
+                if self.cache.pool.epoch != epoch:
+                    # an earlier dispatch of this round lost the pools,
+                    # and with them the prefix blocks a row acquired
+                    # before it shares
+                    stale = [rec for rec in part if rec[2]]
+                    for req, row, _ in stale:
+                        self.cache.release_row(row)
+                        self._shed(req, _PoolsLost(
+                            f"the shared prefix of request {req.id} "
+                            f"went with the pools an earlier dispatch "
+                            f"of its round lost"))
+                    part = [rec for rec in part if rec not in stale]
+                    if not part:
+                        continue
                 with _profiler.RecordEvent(
                         "serving.prefill_step",
                         {"bucket": bucket, "rows": len(part)}):
@@ -2705,6 +2748,8 @@ class ServingEngine:
             sampler_dispatches = self._sampler_dispatches
             sampler_skipped = self._sampler_skipped
             inputs_resident = self._inputs_resident
+            prefill_rows_live = self._prefill_rows_live
+            prefill_rows_computed = self._prefill_rows_computed
         with self._lock:
             completed = self._completed
             slo_met = self._slo_met
@@ -2760,6 +2805,10 @@ class ServingEngine:
         # tokens / lengths
         out["inputs_dispatches"] = sampler_dispatches
         out["inputs_resident"] = inputs_resident
+        # rows the prefill dispatches computed, and those of them that
+        # were a prompt's (the rest was padding up to the bucket's rows)
+        out["prefill_rows_computed"] = prefill_rows_computed
+        out["prefill_rows_live"] = prefill_rows_live
         out["attn_impl"] = self.attn_impl
         out["kv_dtype"] = self.kv_dtype
         out["mesh_shape"] = (None if self.mesh_shape is None

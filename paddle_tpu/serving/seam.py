@@ -18,9 +18,11 @@ from one :class:`ServedModel`, which the model builds
   name;
 - its step builders: how a prompt's rows become logits of the last one
   (:meth:`ServedModel.prefill_logits`, traced inside the engine's
-  ``serving_prefill_paged`` entry), how many prompts a prefill dispatch of
-  a bucket carries (:meth:`ServedModel.prefill_rows`; ``prompts_a_dispatch``
-  where the model fixes it), and the compiled decode step
+  ``serving_prefill_paged`` entry), how many rows a prefill dispatch of
+  a bucket computes (:meth:`ServedModel.prefill_rows`: the model's
+  ``tokens_a_dispatch`` over the bucket's length, from 1 to ``max_slots``,
+  so a long bucket computes the prompt it admitted and a short one shares
+  a read of the weights between several), and the compiled decode step
   (:meth:`ServedModel.decode_entry`);
 - the counters its decode step keeps on the device (``counters``: names of
   the entries of one float32 vector that the step takes as its last
@@ -75,8 +77,12 @@ class ServedModel:
     features: frozenset = FEATURES
     counters: Tuple[str, ...] = ()
     lora_config: object = None          # what LoRAPool sizes its pages by
-    #: prompts a prefill dispatch carries (None: ``max_slots``)
-    prompts_a_dispatch: Optional[int] = None
+    #: tokens a prefill dispatch computes, about where the model's
+    #: products stop being bound by the read of its weights: a bucket's
+    #: entry has ``tokens_a_dispatch // bucket`` rows, from 1 (any bucket
+    #: over the budget: one prompt a dispatch) to ``max_slots`` (None:
+    #: ``max_slots`` whatever the bucket)
+    tokens_a_dispatch: Optional[int] = None
     #: the model's call takes ``last=`` and multiplies the head on each
     #: prompt's last row only (else the seam gathers that row's logits)
     head_on_last_row: bool = False
@@ -98,8 +104,12 @@ class ServedModel:
 
     # ------------------------------------------------------ step builders
     def prefill_rows(self, bucket: int, max_slots: int) -> int:
-        """Prompts one prefill dispatch of ``bucket`` carries."""
-        return int(self.prompts_a_dispatch or max_slots)
+        """Rows the prefill entry of ``bucket`` computes, which is the
+        most prompts one dispatch of it carries."""
+        if self.tokens_a_dispatch is None:
+            return int(max_slots)
+        return max(1, min(int(max_slots),
+                          int(self.tokens_a_dispatch) // int(bucket)))
 
     def prefill_logits(self, ids, last, pos, tables, pools, lora):
         """Inside the engine's prefill entry: the prompts' rows ``ids``

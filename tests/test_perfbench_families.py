@@ -388,13 +388,15 @@ def test_the_new_cells_toy_twin_rehearses_to_its_end():
     assert line["notes"]["check_loss_diff"] <= 0.05
 
 
-# ---- PR 28, PR 30: the engine's counters through the counter channel
+# ---- PR 28, PR 30, PR 32: the engine's counters through the counter channel
 
 COUNTER_SHARES = {
     "sampler_skipped_share_pct": ("engine.sampler_skipped",
                                   "engine.sampler_dispatches"),
     "inputs_resident_share_pct": ("engine.inputs_resident",
-                                  "engine.inputs_dispatches")}
+                                  "engine.inputs_dispatches"),
+    "prefill_live_rows_share_pct": ("engine.prefill_rows_live",
+                                    "engine.prefill_rows_computed")}
 SHARE_CELLS = {"chat": "chat_steady", "docs": "docs_offline",
                "decode": "decode_heavy"}
 
@@ -435,11 +437,10 @@ print(json.dumps(harness.run_cell(bench, args, rehearsal=True,
 """
 
 
-def test_a_rehearsal_reports_every_decode_step_as_sampler_skipped():
+@pytest.fixture(scope="module")
+def decode_heavy_rehearsed():
     """``decode_heavy``'s toy twin, traced, on a real engine (its own
-    process, as ``rehearse.py`` runs it, but with the values kept): the
-    harness submits no decode parameters, so every decode dispatch of the
-    window is all-greedy and the reader finds both counters."""
+    process, as ``rehearse.py`` runs it, but with the values kept)."""
     out = subprocess.run(
         [sys.executable, "-c", REHEARSE_WITH_VALUES.format(root=ROOT)],
         env=dict(os.environ, JAX_PLATFORMS="cpu"), cwd=ROOT,
@@ -447,9 +448,26 @@ def test_a_rehearsal_reports_every_decode_step_as_sampler_skipped():
     assert out.returncode == 0, out.stderr[-2000:]
     line = json.loads(out.stdout.strip().splitlines()[-1])
     assert line["correct"] is True and line["failed"] == 0
-    m = line["metrics"]
+    return line
+
+
+def test_a_rehearsal_reports_every_decode_step_as_sampler_skipped(
+        decode_heavy_rehearsed):
+    """The harness submits no decode parameters, so every decode dispatch
+    of the window is all-greedy and the reader finds both counters."""
+    m = decode_heavy_rehearsed["metrics"]
     assert m["sampler_skipped_share_pct.decode"]["value"] == 100.0
     # eight rows turn over about once in thirty steps of the toy twin
     assert 80.0 <= m["inputs_resident_share_pct.decode"]["value"] < 100.0
     assert m["pool_inplace_share_pct.decode"]["value"] == 100.0
     assert m["compiles_in_window.decode"]["value"] == 0
+
+
+def test_a_rehearsal_reads_the_live_share_of_its_prefill_rows(
+        decode_heavy_rehearsed):
+    """The toy twin's prompts (4-16 tokens) fall in its bucket of 16, whose
+    one program has ``max_slots`` = 4 rows under GPT's 512 tokens a
+    dispatch; a completion frees one slot, so a dispatch carries one live
+    row of four, two where two requests ended in one step."""
+    m = decode_heavy_rehearsed["metrics"]
+    assert 25.0 <= m["prefill_live_rows_share_pct.decode"]["value"] <= 50.0

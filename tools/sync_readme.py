@@ -323,9 +323,22 @@ def render_serving_block():
         "`paddle_tpu.serving.ServingEngine` batches requests at",
         "iteration granularity: each step admits queued prompts into",
         "free KV-cache slots (prefill padded to a length bucket, one",
-        "compile per bucket — and all same-bucket admissions in a step",
-        "share ONE dispatch of that compile) and runs one batched",
-        "decode over every occupied slot (one compile, total). KV",
+        "compile per bucket, whose rows follow the bucket's length: the",
+        "model's token budget a dispatch over the bucket, GPT's 512",
+        "tokens, from one row for a bucket of 512 or more to",
+        "`max_slots` for a short one — so a long prompt's dispatch",
+        "computes the prompt it admitted, a burst of short ones shares",
+        "a dispatch and its read of the weights, and a group of more",
+        "same-bucket admissions than the rows goes out as several",
+        "dispatches in admission order) and runs one batched",
+        "decode over every occupied slot (one compile, total).",
+        "`engine.stats()` gives `prefill_rows_live` /",
+        "`prefill_rows_computed` (`STAT_serving_prefill_rows_live` /",
+        "`_computed`): of the rows the prefill dispatches computed,",
+        "those that were an admitted prompt's; an operator reads a low",
+        "share as short prompts arriving one at a time (padding up to",
+        "the bucket's rows), and a share near 100 with a high prefill",
+        "time as prompts long enough to be compute-bound. KV",
         "memory is block-paged: a",
         "fixed pool of `[num_blocks, heads, block_size, head_dim]` KV",
         "blocks per layer, host-side per-request block tables fed to",
@@ -432,7 +445,8 @@ def render_serving_block():
         "Admission is SLO-aware. With `FLAGS_serving_slo_ttft_ms` > 0",
         "(or `ServingEngine(slo_ttft_ms=...)`) every `submit()` first",
         "predicts the request's time-to-first-token from live state —",
-        "queue depth in prefill waves, the per-bucket prefill cost, and",
+        "queue depth in prefill dispatches of the bucket's rows, the",
+        "per-bucket prefill cost, and",
         "a decode time-per-output-token EWMA (pin both via",
         "`slo_prefill_ms` / `slo_tpot_ms` for deterministic tests) —",
         "and rejects requests that cannot meet the deadline instead of",
