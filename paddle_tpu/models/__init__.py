@@ -7,6 +7,7 @@ from .gpt import (GPT_CONFIGS, GPTForCausalLM, GPTModel, gpt2_medium,
                   gpt2_small, gpt2_tiny)
 from .laguna import LAGUNA_CONFIGS, LagunaConfig, LagunaForCausalLM
 from .mellum import MELLUM_CONFIGS, MellumConfig, MellumForCausalLM
+from .jamba import JAMBA_CONFIGS, JambaConfig, JambaForCausalLM
 from . import generation
 from .generation import (beam_search, decode_step, decode_step_paged,
                          draft_ngram, greedy_search, sample,

@@ -746,6 +746,11 @@ class ServingEngine:
         # computed (the rest was padding)
         self._prefill_rows_live = 0       # guarded-by: _step_lock
         self._prefill_rows_computed = 0   # guarded-by: _step_lock
+        # prompt tokens those rows held, and the positions (rows x
+        # bucket) the dispatches computed: a recurrence pays for every
+        # padded position, which attention's mask does not
+        self._prefill_tokens_live = 0     # guarded-by: _step_lock
+        self._prefill_tokens_computed = 0  # guarded-by: _step_lock
         self._qerr_max = 0.0              # guarded-by: _step_lock
         self._qerr_gauge = None
         if self.kv_dtype == "int8":
@@ -797,6 +802,8 @@ class ServingEngine:
             "_inputs_resident": "_step_lock",
             "_prefill_rows_live": "_step_lock",
             "_prefill_rows_computed": "_step_lock",
+            "_prefill_tokens_live": "_step_lock",
+            "_prefill_tokens_computed": "_step_lock",
         })
 
     # -------------------------------------------------------------- mesh
@@ -1454,6 +1461,12 @@ class ServingEngine:
         self._prefill_rows_computed += n
         _monitor.stat_add("STAT_serving_prefill_rows_live", len(live))
         _monitor.stat_add("STAT_serving_prefill_rows_computed", n)
+        tokens = sum(len(req.context) - shared for req, _, shared in live)
+        self._prefill_tokens_live += tokens
+        self._prefill_tokens_computed += n * bucket
+        _monitor.stat_add("STAT_serving_prefill_tokens_live", tokens)
+        _monitor.stat_add("STAT_serving_prefill_tokens_computed",
+                          n * bucket)
         ids = np.zeros((n, bucket), np.int32)
         last = np.zeros(n, np.int32)
         pos = np.zeros(n, np.int32)
@@ -2750,6 +2763,8 @@ class ServingEngine:
             inputs_resident = self._inputs_resident
             prefill_rows_live = self._prefill_rows_live
             prefill_rows_computed = self._prefill_rows_computed
+            prefill_tokens_live = self._prefill_tokens_live
+            prefill_tokens_computed = self._prefill_tokens_computed
         with self._lock:
             completed = self._completed
             slo_met = self._slo_met
@@ -2809,6 +2824,10 @@ class ServingEngine:
         # were a prompt's (the rest was padding up to the bucket's rows)
         out["prefill_rows_computed"] = prefill_rows_computed
         out["prefill_rows_live"] = prefill_rows_live
+        # the positions those dispatches computed (rows x bucket), and
+        # those of them that were a prompt's tokens
+        out["prefill_tokens_computed"] = prefill_tokens_computed
+        out["prefill_tokens_live"] = prefill_tokens_live
         out["attn_impl"] = self.attn_impl
         out["kv_dtype"] = self.kv_dtype
         out["mesh_shape"] = (None if self.mesh_shape is None
@@ -2846,6 +2865,11 @@ class ServingEngine:
         # blocks held now by layer kind, and the blocks window layers
         # returned behind their windows while their request lived
         out.update(c.kind_stats())
+        # recurrent state beside the blocks: the bytes the cache holds
+        # for it (0 for a model with none) and the rows that are a
+        # request's now
+        out["state_bytes"] = c.state_bytes
+        out["state_rows_live"] = c.state_rows_live
         if self.spec.counters:
             # the model's counters, kept on the device by its steps and
             # fetched here only (the steps' own fetch is their tokens)

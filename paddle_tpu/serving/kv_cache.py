@@ -32,6 +32,14 @@ hands the steps one pool pair a *model* layer, in the model's order, the
 tables go as one array a kind, and ``allocator`` / ``blocks_free`` /
 ``blocks_used`` answer for all kinds together.
 
+Recurrent state. A kind of layer that keeps a fixed-size record a request
+whatever its context (``seam.StateKind``) has no blocks at all
+(:class:`_StateKind`): ``[max_slots, ...]`` arrays a layer, indexed by the
+request's row, handed to the steps, consumed and rebound with the pools
+(``arrays()`` / ``set_arrays()`` / ``rebuild_pools()``). Its entry of the
+tables is the row index of each row of a dispatch. Admission is by row: a
+request that got a row has its state's room. Nothing of it can leak.
+
 Every buffer keeps a fixed shape so the batched decode step has a
 single signature and compiles exactly once; admitting or retiring a
 request is bookkeeping, never a recompile.
@@ -386,6 +394,45 @@ class _WindowKind:
         return self.pool.allocator.num_used - 1
 
 
+class _StateKind:
+    """The layers of one kind that keep a fixed-size record a request
+    (``seam.StateKind``): per layer a tuple of ``[max_slots, *shape]``
+    arrays, row ``r`` the record of the request in cache row ``r``. A
+    prefill dispatch writes the rows it admitted whole (so a row needs no
+    zeroing at admission), the decode step rewrites every row, and a row
+    with no request holds garbage that nothing reads. ``layers`` is owned
+    linearly, as a :class:`BlockPool`'s is."""
+
+    def __init__(self, kind, max_slots: int):
+        self.kind = kind
+        self.max_slots = int(max_slots)
+        self.rebuild()
+        #: the kind's entry of the decode step's tables: row r is row r
+        self.rows = np.arange(self.max_slots, dtype=np.int32)
+
+    def rebuild(self):
+        import jax.numpy as jnp
+        self.layers = [
+            tuple(jnp.zeros((self.max_slots,) + tuple(shape), dtype)
+                  for shape, dtype in self.kind.arrays)
+            for _ in self.kind.layers]
+
+    @property
+    def nbytes(self) -> int:
+        import jax.numpy as jnp
+        return sum(int(np.prod(shape)) * jnp.dtype(dtype).itemsize
+                   for shape, dtype in self.kind.arrays) \
+            * len(self.kind.layers) * self.max_slots
+
+    def pick(self, rows: Sequence[int], n: int):
+        """The rows of a prefill dispatch of ``n`` rows: the cache rows of
+        the admitted, ``max_slots`` (out of range: the write is dropped)
+        for the padding."""
+        out = np.full(n, self.max_slots, np.int32)
+        out[:len(rows)] = list(rows)
+        return out
+
+
 class BlockKVCache:
     """Block-paged KV storage + ref-counted allocator + prefix cache.
 
@@ -426,7 +473,8 @@ class BlockKVCache:
                  num_blocks: int = 0, prefix_cache: bool = True,
                  dtype=None, kv_dtype: str = "f32",
                  pool: Optional[BlockPool] = None,
-                 window_kinds: Sequence = (), layer_order=None):
+                 window_kinds: Sequence = (), layer_order=None,
+                 state_kinds: Sequence = ()):
         self.max_slots = int(max_slots)
         self.max_len = int(max_len)
         if pool is not None:
@@ -483,11 +531,19 @@ class BlockKVCache:
             _WindowKind(k, self.max_slots, self.blocks_per_row,
                         self.pool.block_size, self.pool.kv_dtype)
             for k in window_kinds]
-        self._order = list(layer_order) if self._windows else None
+        # the kinds with no blocks: a fixed-size record a row
+        self._states = [_StateKind(k, self.max_slots) for k in state_kinds]
+        self._order = list(layer_order) \
+            if self._windows or self._states else None
         if self._windows and (prefix_cache or pool is not None):
             raise ValueError(
                 "a cache with window kinds has no prefix cache and no "
                 "shared pool: a layer that forgets rows cannot lend them")
+        if self._states and (prefix_cache or pool is not None):
+            raise ValueError(
+                "a cache with recurrent state has no prefix cache and no "
+                "shared pool: a shared prefix would need the state at its "
+                "last row, which no block holds")
 
     @classmethod
     def for_model(cls, spec, max_slots: int, max_len: int, *,
@@ -501,14 +557,15 @@ class BlockKVCache:
             raise ValueError("the first cache kind keeps every row, the "
                              "others have a window")
         order = {}
-        for ki, kind in enumerate(spec.cache_kinds):
+        for ki, kind in enumerate(spec.cache_kinds + spec.state_kinds):
             for i, layer in enumerate(kind.layers):
                 order[layer] = (ki, i)
         return cls(len(first.layers), first.kv_heads, first.head_dim,
                    max_slots, max_len, block_size=block_size,
                    num_blocks=num_blocks, prefix_cache=prefix_cache,
                    kv_dtype=kv_dtype, pool=pool, window_kinds=rest,
-                   layer_order=[order[l] for l in sorted(order)])
+                   layer_order=[order[l] for l in sorted(order)],
+                   state_kinds=spec.state_kinds)
 
     # -- pool delegation ---------------------------------------------
     # the physical state lives in self.pool so sharing caches observe
@@ -783,10 +840,11 @@ class BlockKVCache:
         destination cache, or its refs dropped via
         ``record["pool"].release_blocks(record["blocks"])`` — else
         ``leaked()`` rightly reports the blocks as lost."""
-        if self._windows:
-            raise ValueError("a row of a cache with window kinds is not "
-                             "handed off: disaggregation is refused for "
-                             "such a model at the engine's construction")
+        if self._windows or self._states:
+            raise ValueError("a row of a cache with window kinds or "
+                             "recurrent state is not handed off: "
+                             "disaggregation is refused for such a model "
+                             "at the engine's construction")
         n = int(self._nblocks[row])
         rec = {
             "blocks": [int(b) for b in self.tables[row, :n]],
@@ -932,24 +990,28 @@ class BlockKVCache:
         returned pools with :meth:`set_arrays` before anything reads
         the pool again. With window kinds: one pool pair a model layer, in
         the model's order, whichever kind's pool holds it."""
-        if not self._windows:
+        if self._order is None:
             return list(self.layers)
-        pools = [self.layers] + [w.pool.layers for w in self._windows]
+        pools = [self.layers] + [w.pool.layers for w in self._windows] \
+            + [st.layers for st in self._states]
         return [pools[k][i] for k, i in self._order]
 
     def set_arrays(self, layers):
         """Adopt a compiled step's returned pools (generic over the
         2- or 4-wide layer tuples), each to the kind that lent it."""
         layers = [tuple(layer) for layer in layers]
-        if not self._windows:
+        if self._order is None:
             self.layers = layers
             return
-        split = [[] for _ in range(1 + len(self._windows))]
+        split = [[] for _ in range(1 + len(self._windows)
+                                   + len(self._states))]
         for (k, _), layer in zip(self._order, layers):
             split[k].append(layer)
         self.layers = split[0]
         for w, got in zip(self._windows, split[1:]):
             w.pool.layers = got
+        for st, got in zip(self._states, split[1 + len(self._windows):]):
+            st.layers = got
 
     def rebuild_pools(self):
         """Zeroed pools (every kind's) in place of ones a failed step
@@ -957,16 +1019,19 @@ class BlockKVCache:
         self.pool.rebuild()
         for w in self._windows:
             w.pool.rebuild()
+        for st in self._states:
+            st.rebuild()
 
     # -- the tables as the steps take them ---------------------------
 
     def tables_arg(self):
         """A copy of the block tables (the cache writes its own in
         place): the array, or with window kinds one array a kind."""
-        if not self._windows:
+        if self._order is None:
             return self.tables.copy()
-        return (self.tables.copy(),) + tuple(w.tables.copy()
-                                             for w in self._windows)
+        return (self.tables.copy(),) \
+            + tuple(w.tables.copy() for w in self._windows) \
+            + tuple(st.rows for st in self._states)
 
     def table_rows(self, rows: Sequence[int], n: int):
         """The tables of ``rows`` as the first of ``n`` rows of a prefill
@@ -976,10 +1041,11 @@ class BlockKVCache:
             out = np.full((n, tables.shape[1]), self.TRASH, np.int32)
             out[:len(rows)] = tables[list(rows)]
             return out
-        if not self._windows:
+        if self._order is None:
             return pick(self.tables)
-        return (pick(self.tables),) + tuple(pick(w.tables)
-                                            for w in self._windows)
+        return (pick(self.tables),) \
+            + tuple(pick(w.tables) for w in self._windows) \
+            + tuple(st.pick(rows, n) for st in self._states)
 
     def kind_stats(self) -> Dict[str, int]:
         """Blocks a request holds now, by kind, and the blocks the window
@@ -991,3 +1057,14 @@ class BlockKVCache:
             out["window_blocks_freed"] = sum(w.freed_behind
                                              for w in self._windows)
         return out
+
+    @property
+    def state_bytes(self) -> int:
+        """Bytes of recurrent state the cache holds (all rows, live or
+        not: the arrays are allocated whole)."""
+        return sum(st.nbytes for st in self._states)
+
+    @property
+    def state_rows_live(self) -> int:
+        """Rows whose recurrent state belongs to a request now."""
+        return self.num_used if self._states else 0

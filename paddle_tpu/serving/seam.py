@@ -11,6 +11,14 @@ from one :class:`ServedModel`, which the model builds
   heads and head size, and ``window`` > 0 for a kind that needs only the
   last ``window`` rows of a request (``BlockKVCache`` then bounds that
   kind's pool and frees its blocks behind the window);
+- its recurrent state, one :class:`StateKind` a kind of layer that carries
+  a fixed-size record from token to token whatever the context (a
+  state-space layer's scan state and its convolution's tail): which
+  layers, and the shape and dtype of each array a request keeps. The one
+  cache manager holds them beside the blocks, ``[max_slots, ...]`` a layer
+  and indexed by the request's row; a kind's entry of the block tables is
+  the row index of each row of the dispatch (``max_slots`` for a row that
+  is none: its write is dropped);
 - the pools' dtype where the model fixes it (``kv_dtype``; None: the
   engine's ``FLAGS_serving_kv_dtype``);
 - which of the engine's optional features its steps can run
@@ -65,6 +73,15 @@ class CacheKind:
     window: int = 0             # 0: every row of a request is kept
 
 
+@dataclass(frozen=True)
+class StateKind:
+    """One kind of layer that keeps a fixed-size record a request."""
+    name: str
+    layers: Tuple[int, ...]     # the model's layer indices, ascending
+    #: (shape, dtype name) of each array one request keeps in one layer
+    arrays: Tuple[Tuple[Tuple[int, ...], str], ...]
+
+
 @dataclass
 class ServedModel:
     """A model as the serving engine sees it (see the module)."""
@@ -73,6 +90,7 @@ class ServedModel:
     max_positions: int
     vocab: int
     cache_kinds: Tuple[CacheKind, ...]  # the unbounded kind first
+    state_kinds: Tuple[StateKind, ...] = ()
     kv_dtype: Optional[str] = None
     features: frozenset = FEATURES
     counters: Tuple[str, ...] = ()
@@ -89,7 +107,8 @@ class ServedModel:
 
     @property
     def num_layers(self) -> int:
-        return sum(len(k.layers) for k in self.cache_kinds)
+        return sum(len(k.layers)
+                   for k in self.cache_kinds + self.state_kinds)
 
     def require(self, feature: str, asked_by: str):
         """Refuse, by name, a feature this model's steps cannot run."""
@@ -144,8 +163,8 @@ def served(model) -> ServedModel:
         raise TypeError(
             f"{type(model).__name__} is not a served model: the serving "
             f"plane reads a model through model.serving_spec() -> "
-            f"paddle_tpu.serving.seam.ServedModel (GPTForCausalLM and "
-            f"MellumForCausalLM have one)")
+            f"paddle_tpu.serving.seam.ServedModel (GPTForCausalLM, "
+            f"MellumForCausalLM and JambaForCausalLM have one)")
     return spec()
 
 

@@ -35,6 +35,7 @@ from paddle_tpu.ops.pallas.utils import kernel_sharding
 fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
 pa = importlib.import_module("paddle_tpu.ops.pallas.paged_attention")
 gm = importlib.import_module("paddle_tpu.ops.pallas.grouped_matmul")
+ss = importlib.import_module("paddle_tpu.ops.pallas.selective_scan")
 
 KERNEL = chip_smoke.KERNEL      # a Mosaic kernel in a compiled program
 
@@ -73,6 +74,7 @@ def mosaic(monkeypatch):
     monkeypatch.setattr(fa, "_interpret", lambda: False)
     monkeypatch.setattr(pa, "_interpret", lambda: False)
     monkeypatch.setattr(gm, "_interpret", lambda: False)
+    monkeypatch.setattr(ss, "_interpret", lambda: False)
     with jax.enable_x64(False):
         yield
 
@@ -131,6 +133,24 @@ def test_windowed_grouped_flash_attention_compiles_for_v5e(one_chip, mosaic,
 # mellum_code_16k's served prompts: one prompt of the longest bucket, 32
 # query heads over 4 KV heads of 128, window 1024 or causal, forward only
 # (the whole-sequence K/V block holds at 12288 rows; 16384 is refused)
+# jamba_reasoning_6k's prompt scan: 5120 channels by 16 states, a dispatch
+# of two prompts at the 512-row bucket or of one at the 4096-row bucket
+@pytest.mark.parametrize("rows,bucket", [(2, 512), (1, 4096)])
+def test_the_selective_scan_compiles_for_v5e(one_chip, mosaic, rows, bucket):
+    def s(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    wide = s(rows, bucket, 5120)
+    compiled = jax.jit(ss.selective_scan).lower(
+        wide, wide, s(16, 5120), s(rows, bucket, 16), s(rows, bucket, 16),
+        s(5120), wide, s(rows, dtype=jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count(KERNEL) == 1 and "selective_scan" in text
+    # [T, 5120, 16] never reaches HBM: the program's temporaries are the
+    # relayouts of its four [T, 5120] operands, not 16 times that
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < 6 * rows * bucket * 5120 * 4
+
+
 @pytest.mark.parametrize("kind,window", [("win", 1024), ("full", 0)])
 def test_served_prompt_flash_attention_compiles_for_v5e(one_chip, mosaic,
                                                         kind, window):

@@ -23,6 +23,7 @@ from jax import export
 fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
 ln = importlib.import_module("paddle_tpu.ops.pallas.layer_norm")
 pa = importlib.import_module("paddle_tpu.ops.pallas.paged_attention")
+ss = importlib.import_module("paddle_tpu.ops.pallas.selective_scan")
 
 
 def _flash_loss(q, k, v):
@@ -42,10 +43,17 @@ def _s(shape, dtype=jnp.float32):
     return jax.ShapeDtypeStruct(shape, dtype)
 
 
+def _scan(x, dt, a, b, c, d, z, last):
+    return ss.selective_scan(x, dt, a, b, c, d, z, last, interpret=False)
+
+
 _QKV = (_s((2, 4, 256, 128), jnp.bfloat16),) * 3
 _LN = (_s((256, 512)), _s((512,)), _s((512,)))
 _PAGED = (_s((4, 4, 1, 128)), _s((32, 4, 16, 128)), _s((32, 4, 16, 128)),
           _s((4, 8), jnp.int32), _s((4,), jnp.int32))
+_SCAN = (_s((2, 256, 1024)), _s((2, 256, 1024)), _s((16, 1024)),
+         _s((2, 256, 16)), _s((2, 256, 16)), _s((1024,)),
+         _s((2, 256, 1024)), _s((2,), jnp.int32))
 #: kernel name -> (function, argument shapes); a backward program holds
 #: its forward kernel too
 CASES = {
@@ -55,6 +63,7 @@ CASES = {
     "layer_norm_fwd": (_ln_loss, _LN),
     "layer_norm_bwd": (jax.grad(_ln_loss, argnums=(0, 1, 2)), _LN),
     "paged_decode_attn": (_paged, _PAGED),
+    "selective_scan": (_scan, _SCAN),
 }
 
 

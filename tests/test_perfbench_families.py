@@ -17,11 +17,12 @@ sys.path.insert(0, ROOT)
 from perfbench import families, flops          # noqa: E402
 
 CONFIGS = {"cgpt-1p3b": "gpt2", "cgpt-1p3b-d20": "gpt2",
-           "laguna-xs2-share8": "laguna", "mellum2-12b-d8": "mellum"}
+           "laguna-xs2-share8": "laguna", "mellum2-12b-d8": "mellum",
+           "jamba2-3b": "jamba"}
 JOBS = {"gpt2": "pretrain_1chip", "laguna": "laguna_pretrain_8k",
-        "mellum": "mellum_code_16k"}
+        "mellum": "mellum_code_16k", "jamba": "jamba_reasoning_6k"}
 FAMILY_CONFIG = {"gpt2": "cgpt-1p3b-d20", "laguna": "laguna-xs2-share8",
-                 "mellum": "mellum2-12b-d8"}
+                 "mellum": "mellum2-12b-d8", "jamba": "jamba2-3b"}
 
 
 def load(*parts):
@@ -37,7 +38,7 @@ def test_an_unknown_family_is_an_error_that_lists_the_known_ones():
     with pytest.raises(SystemExit) as e:
         families.load({"name": "some-model", "family": "no_such_family"})
     assert "no_such_family" in str(e.value)
-    assert families.known() == ["gpt2", "laguna", "mellum"]
+    assert families.known() == ["gpt2", "jamba", "laguna", "mellum"]
     assert all(name in str(e.value) for name in families.known())
 
 
@@ -152,6 +153,81 @@ def test_any_16_requests_of_mellum_code_16k_fit_the_pool():
     assert e["prefix_cache"] is False
 
 
+def test_the_jamba_file_holds_the_published_model_uncut():
+    cfg = config("jamba2-3b")
+    catalog = "/opt/skills/guides/model-configs/architectures.jsonl"
+    if os.path.exists(catalog):     # the catalog's row, where there is one
+        with open(catalog) as f:
+            row = next(r for r in map(json.loads, f)
+                       if r["name"] == "AI21-Jamba2-3B")
+        changed = {k for k, v in row["config"].items() if cfg.get(k, 0) != v}
+        assert changed == {"max_position_embeddings"}
+        assert cfg["source"] == row["source_url"]
+    assert cfg["reduced"] == list(cfg["reduced_why"]) == \
+        ["max_position_embeddings"]
+    assert cfg["published"]["max_position_embeddings"] == 262144
+    assert (cfg["num_hidden_layers"], cfg["hidden_size"],
+            cfg["intermediate_size"], cfg["num_attention_heads"],
+            cfg["num_key_value_heads"], cfg["mamba_d_state"],
+            cfg["mamba_d_conv"], cfg["mamba_expand"], cfg["mamba_dt_rank"],
+            cfg["attn_layer_period"], cfg["attn_layer_offset"],
+            cfg["vocab_size"], cfg["tie_word_embeddings"]) == \
+        (28, 2560, 8192, 20, 1, 16, 4, 2, 160, 14, 7, 65536, True)
+    assert all(cfg.get(k) for k in ("assumed", "published", "deployment",
+                                    "engine_why"))
+    mc = families.load(cfg).model_config(cfg)
+    mamba = 2560 * 10240 + 5120 * 2560 + 5120 * 192 + 160 * 5120 + 5120 \
+        + 5120 * 4 + 5120 + 5120 * 16 + 5120 + 160 + 16 + 16
+    attn = 2560 * 22 * 128 + 2560 * 2560
+    mlp = 3 * 2560 * 8192
+    assert (mamba, attn, mlp) == (41_241_792, 13_762_560, 62_914_560)
+    assert mc.num_params() == cfg["params_held"] == \
+        26 * (mamba + mlp + 5120) + 2 * (attn + mlp + 5120) \
+        + 65536 * 2560 + 2560 == 3_029_337_472
+    assert (mc.head_dim, mc.dtype, mc.d_inner) == (128, "bfloat16", 5120)
+    assert mc.layers_of("attention") == (7, 21)
+    # the deployment's bytes: a request's recurrent state, the pool
+    state = 26 * (16 * 5120 * 4 + 3 * 5120 * 2)
+    assert state == 9_318_400 and 64 * state == 596_377_600
+    e = cfg["engine"]
+    assert e["num_blocks"] * e["block_size"] * 2 * 2 * 128 * 2 == 402_915_328
+
+
+def test_the_jamba_family_refuses_a_training_job_by_name():
+    cfg = config("jamba2-3b")
+    with pytest.raises(SystemExit) as e:
+        families.load(cfg).train_job(cfg, {"kind": "train"})
+    assert "no training job" in str(e.value)
+
+
+def test_any_64_requests_of_jamba_reasoning_6k_fit_the_pool():
+    """The file is held to what its ``lengths_why`` says: the largest pair
+    sixty-four times over fits the attention layers' pool, and every
+    prompt reaches a bucket whose dispatch the model's budget sizes."""
+    from perfbench import traffic as T
+    cfg, tr = config("jamba2-3b"), load("traffic", "jamba_reasoning_6k.json")
+    e = cfg["engine"]
+    pairs = T.multiset(tr)
+    assert len(pairs) == 32 and e["max_slots"] == 64
+    assert tr["queue_depth_slots"] == 1 and tr["preroll_completions"] == 64
+    assert all(256 <= p <= 4096 and 512 <= a <= 2048 for p, a in pairs)
+    assert (min(p for p, _ in pairs), max(p for p, _ in pairs),
+            min(a for _, a in pairs), max(a for _, a in pairs)) == \
+        (267, 3922, 523, 2004)
+    assert all(p + a <= tr["multiset"]["max_total"] <= e["max_len"]
+               for p, a in pairs)
+    # the largest prompt with the largest answer, whatever the pairing
+    need = -(-(3922 + 2004) // e["block_size"])
+    assert need == 24 and 64 * need == e["num_blocks"] - 1
+    assert T.buckets_used(tr, e["buckets"]) == [512, 1024, 2048, 4096]
+    assert all(b % 128 == 0 for b in e["buckets"])      # the scan's chunk
+    mc = families.load(cfg).model_config(cfg)
+    spec_rows = [max(1, min(64, mc.tokens_a_dispatch // b))
+                 for b in e["buckets"]]
+    assert spec_rows == [2, 1, 1, 1]
+    assert e["prefix_cache"] is False
+
+
 # per layer 8*2048^2 + 4*2048*8192 + 2*1024*2048 = 104,857,600; head
 # 2*2048*50304 = 206,045,184; x3 for the backward.
 # Laguna share, forward a token at s 8192: a window layer's projections
@@ -253,6 +329,27 @@ KERNELS.update({
 })
 
 
+# Jamba's served kernels, one call, at the MEAN call of the 32 prompts'
+# dispatches (8 in each bucket; rows x bucket = 2 x 512, 1024, 2048, 4096:
+# mean 2048 positions). The scan: 5120 x (7 x 16 + 6) = 604,160 operations a
+# position; bytes x, Delta, z, y float32 [positions, 5120], B and C float32
+# [positions, 16], A [16, 5120], D [5120], the state [rows, 16, 5120] (rows
+# 2, 1, 1, 1: mean 1.25). Flash: 20 query heads over 1 KV head, d 128,
+# (s + 1) / 2 keys a query, over the dispatches that reach the kernel (the
+# 512-row bucket is under FLAGS_pallas_min_seq): 1024, 2048, 4096.
+JAMBA_FLASH = (1024, 2048, 4096)
+KERNELS.update({
+    ("jamba", "selective_scan"): (
+        2048 * 604_160.0,
+        4.0 * (4 * 2048 * 5120 + 2 * 2048 * 16 + 16 * 5120 + 5120
+               + 1.25 * 16 * 5120)),
+    ("jamba", "flash_fwd_full"): (
+        sum(4.0 * 20 * 128 * s * (s + 1) / 2.0 for s in JAMBA_FLASH) / 3,
+        sum((2 * 20 + 2) * s * 128 * 2.0 + 20 * s * 4.0
+            for s in JAMBA_FLASH) / 3),
+})
+
+
 @pytest.mark.parametrize("family,kernel", sorted(KERNELS))
 def test_kernel_counts_are_the_hand_count(family, kernel):
     name = FAMILY_CONFIG[family]
@@ -272,19 +369,19 @@ def test_kernel_counts_are_the_hand_count(family, kernel):
                   got[1] / peak["hbm_bytes_per_s"]))
 
 
-@pytest.mark.parametrize("family", ["gpt2", "laguna", "mellum"])
+@pytest.mark.parametrize("family", ["gpt2", "laguna", "mellum", "jamba"])
 def test_a_kernel_the_family_has_no_count_for_is_none(family):
     name = FAMILY_CONFIG[family]
     cfg, job = config(name), load("traffic", JOBS[family] + ".json")
     counts = families.load(cfg).kernel_counts
     assert counts("paged_decode_attn", cfg, job) is None
     other = {"gpt2": "flash_fwd_win", "laguna": "flash_fwd",
-             "mellum": "moe_up_dx"}[family]
+             "mellum": "moe_up_dx", "jamba": "flash_fwd_win"}[family]
     assert counts(other, cfg, job) is None        # another family's name
     # and none for a job of the other kind (serving for the families that
     # train, training for the one that serves)
     assert counts(sorted(k for f, k in KERNELS if f == family)[0], cfg,
-                  {"kind": "train" if family == "mellum"
+                  {"kind": "train" if family in ("mellum", "jamba")
                    else "open_loop"}) is None
 
 
@@ -344,31 +441,121 @@ def test_every_metric_of_the_mellum_cell_has_its_file_and_its_kernel():
         "counters.engine.completed"
 
 
-def test_the_mellum_cells_toy_twin_rehearses_to_its_end():
-    """``rehearse.py --workload mellum_code_16k --trace 1`` exits 0: the
-    harness found every file by name, built the family's served model
-    behind the engine's seam, ran the closed loop with contexts that cross
-    the toy window, and checked 8 requests against the family's reference:
-    correct, nothing leaked, and the counters' metrics were read."""
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
+REHEARSE_ON_A_STEPPED_CLOCK = """
+import sys
+sys.path.insert(0, {root!r})
+from perfbench import rehearse, serve
+
+
+class Stepped:
+    # the harness's clock, stepped: every read is 10 ms later than the
+    # last, so a window of S seconds is S / 0.03 steps of the engine (the
+    # closed loop reads it three times a step) whatever the CPU's speed
+    now = 0.0
+
+    def perf_counter(self):
+        Stepped.now += 0.01
+        return Stepped.now
+
+    def sleep(self, seconds):
+        pass
+
+
+serve.time = Stepped()
+sys.exit(rehearse.main({argv!r}))
+"""
+
+
+def rehearse_on_a_stepped_clock(workload, seconds, seed):
+    """``rehearse.py --workload <a serving cell> --trace 1`` in its own
+    process, the harness's window counted in steps and not in wall
+    seconds: on the wall clock a 2 s window had to hold 8 completions of
+    the toy twin, which a CPU shared by six workers did not always give
+    (the take-up run of PR 33)."""
+    argv = ["--workload", workload, "--seconds", str(seconds), "--trace",
+            "1", "--seed", str(seed)]
     out = subprocess.run(
-        [sys.executable, os.path.join(ROOT, "perfbench", "rehearse.py"),
-         "--workload", "mellum_code_16k", "--seconds", "2", "--trace", "1",
-         "--seed", str(2**31 + 77)],
-        env=env, capture_output=True, text=True, timeout=900)
+        [sys.executable, "-c",
+         REHEARSE_ON_A_STEPPED_CLOCK.format(root=ROOT, argv=argv)],
+        env=dict(os.environ, JAX_PLATFORMS="cpu"), cwd=ROOT,
+        capture_output=True, text=True, timeout=900)
     assert out.returncode == 0, out.stderr[-2000:]
     line = json.loads(out.stdout.strip().splitlines()[-1])
     assert line["correct"] is True and line["failed"] == 0
     assert line["notes"]["leaked_kv_blocks"] == 0
     assert line["notes"]["checked_requests"] == 8
     assert line["notes"]["max_logit_deficit"] <= 0.05
+    assert all(m["value"] is None for m in line["metrics"].values())
+    return line
+
+
+def test_the_mellum_cells_toy_twin_rehearses_to_its_end():
+    """``rehearse.py --workload mellum_code_16k --trace 1`` exits 0: the
+    harness found every file by name, built the family's served model
+    behind the engine's seam, ran the closed loop with contexts that cross
+    the toy window, and checked 8 requests against the family's reference:
+    correct, nothing leaked, and the counters' metrics were read."""
+    line = rehearse_on_a_stepped_clock("mellum_code_16k", 9, 2**31 + 77)
+    assert line["notes"]["completed_in_window"] >= 8
     for name in ("experts_touched_per_step.mellum",
                  "window_blocks_freed_per_request.mellum",
                  "sched_occupancy_pct.mellum",
                  "inputs_resident_share_pct.mellum",
                  "decode_step_inside_p50_ms.mellum"):
         assert name in line["metrics"], sorted(line["metrics"])
-    assert all(m["value"] is None for m in line["metrics"].values())
+
+
+def test_every_metric_of_the_jamba_cell_has_its_file_and_its_kernel():
+    bench = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
+    cell = next(c for c in bench["workloads"]
+                if c["name"] == "jamba_reasoning_6k")
+    assert (cell["config"], cell["traffic"], cell["chips"]) == \
+        ("jamba2-3b", "jamba_reasoning_6k", 1)
+    assert "jamba_reasoning_6k" in next(
+        m for m in bench["end_to_end"]
+        if m["name"] == "serve_tok_s")["workloads"]
+    mine = [m for m in bench["per_layer"]
+            if m.get("workloads") == ["jamba_reasoning_6k"]]
+    # BENCHMARK.json holds at most 128 per-layer metrics and held 123: five
+    # of ISSUE 33's twenty-two are listed, the files of all are there
+    assert len(bench["per_layer"]) == 128 and len(mine) == 5
+    kernels = {k for f, k in KERNELS if f == "jamba"}
+    files = sorted(f[:-len(".json")] for f in os.listdir(
+        os.path.join(ROOT, "perfbench", "metrics"))
+        if f.endswith(".jamba.json"))
+    assert len(files) == 22 and {m["name"] for m in mine} <= set(files)
+    for name in files:
+        spec = load("metrics", name + ".json")
+        assert spec["moves"] == "serve_tok_s"
+        stem = name[:-len(".jamba")]
+        if stem.endswith("_roofline_pct"):
+            kernel = stem[:-len("_roofline_pct")]
+            assert kernel in kernels
+            assert spec["reader"]["name"] == "kernel_floor_s." + kernel
+            assert spec["reader"]["over"] == "trace.kernel_s." + kernel
+        if stem.endswith("_busy_pct"):
+            assert stem[:-len("_busy_pct")] in kernels
+            assert spec["reader"]["over"] == "trace.busy_s"
+    for m in mine:
+        spec = load("metrics", m["name"] + ".json")
+        assert all(spec[k] == m[k] for k in ("unit", "better", "source",
+                                             "layer", "moves"))
+    over = {n: load("metrics", n + ".json")["reader"] for n in files}
+    assert over["prefill_live_tokens_share_pct.jamba"]["over"] == \
+        "counters.engine.prefill_tokens_computed"
+    assert over["state_gib.jamba"]["name"] == "engine.state_bytes.close"
+
+
+def test_the_jamba_cells_toy_twin_rehearses_to_its_end():
+    """``rehearse.py --workload jamba_reasoning_6k --trace 1`` exits 0: the
+    harness found every file by name, built the family's served model
+    behind the engine's seam (blocks for one layer, rows of state for
+    three), ran the closed loop, and checked 8 requests against the
+    family's reference: correct, nothing leaked, the new counter read."""
+    line = rehearse_on_a_stepped_clock("jamba_reasoning_6k", 6, 2**31 + 33)
+    assert line["notes"]["completed_in_window"] >= 8
+    assert set(line["metrics"]) == {"decode_step_inside_p50_ms.jamba",
+                                    "prefill_live_tokens_share_pct.jamba"}
 
 
 def test_the_new_cells_toy_twin_rehearses_to_its_end():

@@ -15,6 +15,7 @@ sys.path.insert(0, ROOT)
 
 from paddle_tpu.dygraph import layers                          # noqa: E402
 from paddle_tpu.models import (GPT_CONFIGS, GPTForCausalLM,    # noqa: E402
+                               JAMBA_CONFIGS, JambaForCausalLM,
                                LAGUNA_CONFIGS, LagunaForCausalLM,
                                MELLUM_CONFIGS, MellumForCausalLM,
                                generation)
@@ -33,6 +34,14 @@ BUDGET = WINDOW // BS + 1           # blocks a row of the window kind holds
 def mellum():
     layers.seed(3)
     model = MellumForCausalLM(MELLUM_CONFIGS["mellum-tiny"])
+    model.eval()
+    return model
+
+
+@pytest.fixture(scope="module")
+def jamba():
+    layers.seed(3)
+    model = JambaForCausalLM(JAMBA_CONFIGS["jamba-tiny"])
     model.eval()
     return model
 
@@ -257,9 +266,14 @@ REFUSED = {
 }
 
 
+@pytest.mark.parametrize("family", ["mellum", "jamba"])
 @pytest.mark.parametrize("feature", sorted(REFUSED))
-def test_what_the_second_model_does_not_get_is_refused_by_name(mellum,
-                                                               feature):
+def test_what_the_second_model_does_not_get_is_refused_by_name(
+        request, family, feature):
+    """Mellum (a window layer forgets what a later request would borrow)
+    and Jamba (a recurrence would need its state snapshotted, rolled back
+    or carried) declare none of the optional features yet."""
+    model = request.getfixturevalue(family)
     kw = REFUSED[feature]
     if kw == "mesh":
         from paddle_tpu.distributed.sharding import serving_mesh
@@ -274,8 +288,8 @@ def test_what_the_second_model_does_not_get_is_refused_by_name(mellum,
     base = dict(max_slots=2, max_len=64, buckets=[32], block_size=BS,
                 num_blocks=0, prefix_cache=False)
     with pytest.raises(ValueError) as e:
-        ServingEngine(mellum, **dict(base, **kw))
-    assert "mellum is not served with " + feature in str(e.value)
+        ServingEngine(model, **dict(base, **kw))
+    assert f"{family} is not served with {feature}" in str(e.value)
     assert feature in FEATURES
     # GPT keeps it: the same request builds (or fails on its own terms,
     # never on the seam)

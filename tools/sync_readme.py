@@ -338,7 +338,17 @@ def render_serving_block():
         "those that were an admitted prompt's; an operator reads a low",
         "share as short prompts arriving one at a time (padding up to",
         "the bucket's rows), and a share near 100 with a high prefill",
-        "time as prompts long enough to be compute-bound. KV",
+        "time as prompts long enough to be compute-bound;",
+        "`prefill_tokens_live` / `prefill_tokens_computed`",
+        "(`STAT_serving_prefill_tokens_live` / `_computed`) count the",
+        "same in positions: prompt tokens against the `rows x bucket`",
+        "positions the dispatches computed, which is what a model with",
+        "recurrent layers pays for (its scan runs over the padding",
+        "too). `state_bytes` / `state_rows_live` are the recurrent",
+        "state the cache holds beside its blocks (`[max_slots, ...]`",
+        "arrays a layer for a model whose seam declares a",
+        "`StateKind`; 0 for GPT and Mellum) and the rows of it that",
+        "are a request's now. KV",
         "memory is block-paged: a",
         "fixed pool of `[num_blocks, heads, block_size, head_dim]` KV",
         "blocks per layer, host-side per-request block tables fed to",
