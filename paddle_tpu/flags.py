@@ -274,17 +274,21 @@ define_flag("serving_megastep", 1,
             "not absorb a whole megastep. 1 (default) keeps the "
             "per-token host loop.")
 define_flag("serving_dispatch_ahead", False,
-            "Megastep pipelining: after committing megastep k, "
-            "dispatch k+1 from k's device-carry outputs before "
-            "syncing, so host commit work overlaps device execution "
-            "(jax.block_until_ready only at commit). The speculative "
-            "dispatch is consumed only if the scheduler state it "
-            "assumed is unchanged (no finishes, no admissions, no "
-            "weight/flag changes); otherwise its tokens are discarded. "
-            "It has consumed step k's pools like any paged step, so the "
-            "cache holds the pools it returned: the rows it wrote lie "
-            "at or beyond every slot's committed length and are "
-            "written again before anything reads them. Requires "
+            "Megastep pipelining only (serving_megastep > 1): after "
+            "committing megastep k, dispatch k+1 from k's device-carry "
+            "outputs before syncing, so host commit work overlaps "
+            "device execution. The speculative dispatch is consumed "
+            "only if the scheduler state it assumed is unchanged (no "
+            "finishes, no admissions, no weight/flag changes); "
+            "otherwise its tokens are discarded and the megastep runs "
+            "again, which is why a model with recurrent state is "
+            "refused it. It has consumed step k's pools like any paged "
+            "step, so the cache holds the pools it returned: the rows "
+            "it wrote lie at or beyond every slot's committed length "
+            "and are written again before anything reads them. The "
+            "single decode step (serving_megastep = 1) needs no flag: "
+            "it is always dispatched one ahead of its fetch, row by "
+            "row valid (ServingEngine._decode). Requires "
             "serving_megastep > 1.")
 define_flag("serving_dispatch_threads", 0,
             "Router dispatch concurrency: ReplicaRouter / DisaggRouter "

@@ -11,7 +11,18 @@ to let stragglers finish.
 Compile surfaces, all fixed-shape:
 
 - decode: ``models.generation.decode_step_paged(model)`` at batch =
-  ``max_slots`` — every step of every request, one XLA executable;
+  ``max_slots`` — every step of every request, one XLA executable. It
+  is dispatched **one ahead of its fetch**: step k+1 goes to the device
+  from step k's device outputs (tokens, keys, pools) and host
+  arithmetic (every live row one position further) before the host
+  waits for k, so fetch, commit and launch run under the device's
+  time. A step's rows are committed or dropped one by one (a request
+  that finished, was cancelled or shed meanwhile has its row dropped)
+  and no step is dispatched twice; a step after an admission, with a
+  grammar row, or sampled by devprof is built from the host once the
+  step before it landed (``ServingEngine._decode``; ``stats()``:
+  ``ahead_dispatches`` / ``ahead_rows_committed`` /
+  ``ahead_rows_dropped``). Tokens are counted when the host has them;
 - verify (``FLAGS_serving_spec_tokens`` = K > 0): speculative
   decoding replaces the one-token decode with
   ``models.generation.verify_step_paged(model, K)`` — an on-host n-gram
@@ -97,7 +108,7 @@ import itertools
 import threading
 import time
 from collections import deque
-from typing import Dict, List, Optional, Sequence
+from typing import Dict, List, NamedTuple, Optional, Sequence
 
 import numpy as np
 
@@ -163,6 +174,20 @@ class _PoolsLost(Exception):
 class _SkipStep(Exception):
     """Internal: skip one decode iteration (injected `skip` at
     serving.step during decode); requests stay live."""
+
+
+class _Flight(NamedTuple):
+    """One decode step the device was given and whose tokens the host
+    has not fetched. The pools (and counters) it returns are bound when
+    it is dispatched; these are the outputs its commit reads."""
+    #: (slot, request, the request's count of tokens when the step's
+    #: own token is the next one): whom it decodes for. A row is
+    #: committed iff the slot still holds that request at that count.
+    rows: tuple
+    nxt: object         # [b] i32 next tokens, on the device
+    keys: object        # [b, 2] u32 advanced keys, on the device
+    qerr: object
+    ahead: bool         # dispatched before the step before it was fetched
 
 
 class Request:
@@ -712,6 +737,17 @@ class ServingEngine:
         self._ahead = None                # guarded-by: _step_lock
         self._ahead_hits = 0              # guarded-by: _step_lock
         self._ahead_misses = 0            # guarded-by: _step_lock
+        # the single decode step is dispatched one ahead of its fetch:
+        # the step in flight between two _decode calls (_Flight), the
+        # steps dispatched before the step before them was fetched, and
+        # their rows that were committed / computed for nobody
+        self._flight = None               # guarded-by: _step_lock
+        self._ahead_dispatches = 0        # guarded-by: _step_lock
+        self._ahead_rows_committed = 0    # guarded-by: _step_lock
+        self._ahead_rows_dropped = 0      # guarded-by: _step_lock
+        # devprof sampled the step after the one in flight: it was not
+        # dispatched ahead, and the next dispatch carries the timer
+        self._sample_next = False
         # paged dispatches, and those after which the pools handed in
         # were deleted (donated: the KV rows were written in place)
         self._pool_dispatches = 0         # guarded-by: _step_lock
@@ -793,6 +829,10 @@ class ServingEngine:
             "_ahead": "_step_lock",
             "_ahead_hits": "_step_lock",
             "_ahead_misses": "_step_lock",
+            "_flight": "_step_lock",
+            "_ahead_dispatches": "_step_lock",
+            "_ahead_rows_committed": "_step_lock",
+            "_ahead_rows_dropped": "_step_lock",
             "_pool_dispatches": "_step_lock",
             "_pool_inplace": "_step_lock",
             "_sampler_dispatches": "_step_lock",
@@ -887,6 +927,7 @@ class ServingEngine:
             # the trace lock keeps the cut clean fleet-wide: a sibling
             # replica mid-trace holds borrowed tracers in these same
             # Parameters, and its restore would silently undo the swap
+            self._drain()   # the step in flight is the old weights' last
             for p, v in staged:
                 p.value = v
             self._weight_version += 1
@@ -1905,12 +1946,7 @@ class ServingEngine:
         except Exception as e:
             if not pools[0][0].is_deleted():
                 raise
-            self._ahead = None
-            self._shed_active(e)
-            self.cache.rebuild_pools()
-            self._pool_epoch = self.cache.pool.epoch
-            _monitor.stat_add("STAT_serving_pool_rebuilds")
-            _runlog.log_event("serving_pool_rebuild", error=str(e))
+            self._pools_lost(e)
             raise _PoolsLost(f"KV pools consumed by a failed step: {e}"
                              ) from e
         self._pool_dispatches += 1
@@ -1918,6 +1954,18 @@ class ServingEngine:
             self._pool_inplace += 1
             _monitor.stat_add("STAT_serving_pool_inplace")
         return out
+
+    def _pools_lost(self, err: BaseException):  # holds: _step_lock
+        """The pools' contents went with a failed step: shed what was
+        running (and the step in flight, whose tokens are nobody's
+        now), and start from zeroed pools with the prefix cache
+        flushed."""
+        self._ahead = self._flight = None
+        self._shed_active(err)
+        self.cache.rebuild_pools()
+        self._pool_epoch = self.cache.pool.epoch
+        _monitor.stat_add("STAT_serving_pool_rebuilds")
+        _runlog.log_event("serving_pool_rebuild", error=str(err))
 
     def _note_dispatch(self):  # holds: _step_lock
         """Count one decode / verify / megastep dispatch, whether every
@@ -1939,38 +1987,110 @@ class ServingEngine:
             self._inputs_resident += 1
             _monitor.stat_add("STAT_serving_inputs_resident")
 
-    def _step_args(self, tokens=None):  # holds: _step_lock
+    def _step_args(self, tokens=None, after=None):  # holds: _step_lock
         """The inputs of one decode or verify dispatch after the
         params: ``(tokens, lengths, tables, pools, samp[, lora])``.
         A decode step's ``tokens`` are the last step's own output while
         the batch is unchanged; a verify's ``[b, K+1]`` tree comes from
         the host. ``lengths`` is one small copy a step (a copy, because
         the cache advances its array in place); everything else is
-        resident (:meth:`_resident`) and re-sent only when changed."""
+        resident (:meth:`_resident`) and re-sent only when changed.
+
+        ``after``: the step in flight that this one is dispatched
+        behind, before the host has fetched it (:meth:`_rows_ahead`
+        said it can be). Its tokens and keys are that step's outputs as
+        the device holds them, and every live row stands one further
+        than the cache has committed (``ahead_lengths``, which moves
+        the window kinds first: a table that moved is re-sent here)."""
         with _profiler.RecordEvent("serving.decode.inputs"):
             self._resent = False
-            last, keys = self._carried()
+            if after is None:
+                last, keys = self._carried()
+                lengths = self.cache.lengths.copy()
+            else:
+                last, keys = after.nxt, after.keys
+                lengths = self.cache.ahead_lengths(list(self._active))
             args = (self._tokens_arg(last) if tokens is None
                     else self._send(tokens),
-                    self._send(self.cache.lengths.copy()),
-                    self._tables_arg(),
+                    self._send(lengths), self._tables_arg(),
                     self.cache.arrays(), self._build_samp(keys))
             if self._lora_shape is not None:
                 args = args + (self._lora_args(),)
         return args
 
-    def _decode_attempt(self):
-        kind = fault_point("serving.step")
-        if kind == "skip":
-            raise _SkipStep("injected skip of one decode iteration")
+    def _launch(self, after=None, rows=None):  # holds: _step_lock
+        """Dispatch one decode step, for every running request or, behind
+        the step in flight ``after``, for ``rows`` of it. The step owns
+        the pools (and the model's counters) from here: what it returns
+        of them is bound at once, so whatever is dispatched next, a
+        prefill or the step after, queues behind it on the device.
+        -> the :class:`_Flight` whose tokens are still to be fetched."""
         fn = self.spec.decode_entry(self.mesh, self.kv_dtype,
                                     self._lora_shape)["fn"]
-        args = self._step_args()
+        if after is None:
+            rows = tuple((slot, req, len(req.tokens))
+                         for slot, req in self._active.items())
+        args = self._step_args(after=after)
         if self._counted is not None:
             args = args + (self._counted,)
         out = self._call_paged(fn, args, args[3])
         self._note_dispatch()
-        return out
+        nxt, _, arrays, qerr, new_keys, *counted = out
+        self.cache.set_arrays(arrays)
+        if counted:
+            self._counted, = counted
+        if after is not None:
+            # the rows it computes for nobody: they finish by budget at
+            # ``after``'s commit, which the host knows already
+            dropped = len(self._active) - len(rows)
+            self._ahead_dispatches += 1
+            self._ahead_rows_dropped += dropped
+            _monitor.stat_add("STAT_serving_ahead_dispatches")
+            _monitor.stat_add("STAT_serving_ahead_misses", dropped)
+        return _Flight(rows, nxt, new_keys, qerr, after is not None)
+
+    def _rows_ahead(self, fl: _Flight):  # holds: _step_lock
+        """The rows of the step after ``fl``, if that step can be
+        dispatched before ``fl`` is fetched; else None. It can when
+        everything it needs is on the device or known to the host: every
+        running request is one of ``fl``'s rows (one that a prefill
+        admitted since has its token on the host, and joins the step
+        after), none decodes under a grammar (its mask for the next
+        position is built from the token ``fl`` has not delivered),
+        some request goes on past ``fl`` (one that reaches its budget
+        there does not: its row is computed and dropped), and devprof
+        has not sampled the step (it blocks on purpose). Nothing here is
+        configured: it is read off the batch."""
+        held = {slot: (req, n) for slot, req, n in fl.rows}
+        rows = []
+        for slot, req in self._active.items():
+            was, n = held.get(slot, (None, 0))
+            if was is not req or n != len(req.tokens) \
+                    or req._cursor is not None:
+                return None
+            if len(req.tokens) + 1 < req.max_new_tokens:
+                rows.append((slot, req, len(req.tokens) + 1))
+        if not rows:
+            return None
+        if self._devprof is not None and self._devprof.tick():
+            self._sample_next = True
+            return None
+        return tuple(rows)
+
+    def _decode_attempt(self, sampled: bool = False):  # holds: _step_lock
+        """Dispatch what this round can: the step to commit, unless it
+        is in flight already, and the step after it, ahead of the fetch.
+        A retry after a raise finds the first in ``_flight`` and does
+        not dispatch it again. -> the step dispatched ahead, or None."""
+        kind = fault_point("serving.step")
+        if kind == "skip":
+            raise _SkipStep("injected skip of one decode iteration")
+        if self._flight is None:
+            self._flight = self._launch()
+        rows = None if sampled else self._rows_ahead(self._flight)
+        if rows is None:
+            return None
+        return self._launch(self._flight, rows)
 
     def _note_qerr(self, qerr, rows: int):  # holds: _step_lock
         """Surface an int8 step's max-abs dequantization error: bump
@@ -2000,28 +2120,47 @@ class ServingEngine:
         measure deterministic (zero-wall) splits and stay
         byte-identical."""
         dp = self._devprof
-        if dp is None or not dp.tick():
+        if dp is None:
+            return None
+        # a step that was held back from dispatch-ahead because it
+        # sampled in has consumed its tick already (_rows_ahead)
+        sampled, self._sample_next = self._sample_next or dp.tick(), False
+        if not sampled:
             return None
         return _devprof.StepTimer(dp, entry, self._clock)
 
     def _decode(self) -> int:  # holds: _step_lock
-        """One batched decode over every occupied slot. Returns how
-        many tokens were produced (0 when idle/skipped)."""
+        """One batched decode over every occupied slot, dispatched one
+        ahead of its fetch: the step this round commits is in flight
+        since the last round (or is dispatched now, from the host's
+        state, when none is: the first step, the step after an
+        admission, a batch with a grammar row), the step after it is
+        dispatched from its device outputs **before** the host waits
+        for it (:meth:`_rows_ahead`), and fetch and commit then run
+        under the device's time. Each step is dispatched once and each
+        of its rows committed or dropped on its own (:meth:`_land`), so
+        a request's state, KV rows or recurrent record, advances once a
+        committed token. An injected skip dispatches and commits
+        nothing: what is in flight stays there. Returns how many tokens
+        were produced (0 when idle/skipped)."""
         if not self._active:
             return 0
-        timer = self._devprof_timer("decode_step_paged")
+        timer = None
+        if self._flight is None:
+            timer = self._devprof_timer("decode_step_paged")
         t0 = time.perf_counter()
         try:
             with _monitor.stat_time("STAT_serving_decode"), \
                     _profiler.RecordEvent("serving.decode"):
-                out = RetryPolicy.from_flags(
-                    "serving.step").call(self._decode_attempt)
+                ahead = RetryPolicy.from_flags("serving.step").call(
+                    self._decode_attempt, timer is not None)
         except (_SkipStep, _PoolsLost):
             return 0
         except RetryError as e:
             # the step itself is unrecoverable: shed the affected
             # requests, keep the engine alive for new submissions
             self._shed_active(e)
+            self._flight = None
             return 0
         # the TPOT EWMA is per *committed token*: one step commits
         # exactly one token per active slot here, so the step wall is
@@ -2030,29 +2169,73 @@ class ServingEngine:
         # devprof sync so block_until_ready never inflates the cost
         # estimate that drives SLO admission.
         self._note_tpot_ms((time.perf_counter() - t0) * 1e3)
+        fl, self._flight = self._flight, ahead
         if timer is not None:
-            timer.device_done(out)   # block_until_ready + stamp
-        nxt_dev, _, arrays, qerr, new_keys, *counted = out
-        if counted:
-            self._counted, = counted
-        with _profiler.RecordEvent("serving.decode.fetch"):
-            nxt = np.asarray(nxt_dev)  # the host waits for the device
-        with _profiler.RecordEvent("serving.decode.commit",
-                                   {"tokens": len(self._active)}):
-            now = self._clock()       # the commit's one stamp
-            self._note_qerr(qerr, len(self._active))
-            self.cache.set_arrays(arrays)
-            self._writeback_keys(new_keys)
-            produced = 0
-            for slot, req in list(self._active.items()):
-                self.cache.advance(slot, 1)
-                self._append_token(req, int(nxt[slot]), now)
-                produced += 1
-            # the next step's tokens and keys, while the batch stands
-            self._carry = (self._progress(), nxt_dev, new_keys)
+            timer.device_done((fl.nxt, fl.keys))  # block + stamp
+        produced = self._land(fl)
         if timer is not None:
             timer.finish()   # host_s = the commit loop above
+        if not self._active:
+            self._drain()    # nobody is left for the step in flight
         return produced
+
+    def _land(self, fl: _Flight) -> int:  # holds: _step_lock
+        """Fetch one dispatched step's tokens and commit them, row by
+        row: a row stands iff its slot still holds the request it was
+        computed for, at the count of tokens it was computed at. A row
+        whose request finished at the step before (by EOS or a stop
+        sequence, which only that step's commit could tell), or was
+        cancelled, reaped or shed since, is dropped: its KV row or state
+        update lies at or beyond the committed length of a slot that has
+        been released, where nothing reads before a prefill rewrites it
+        (the invariant that covers bucket padding, rejected drafts and
+        the trash block). The other rows' tokens stand. Returns the
+        tokens committed."""
+        live = [(slot, req) for slot, req, n in fl.rows
+                if self._active.get(slot) is req and len(req.tokens) == n]
+        if fl.ahead:
+            dropped = len(fl.rows) - len(live)
+            self._ahead_rows_committed += len(live)
+            self._ahead_rows_dropped += dropped
+            _monitor.stat_add("STAT_serving_ahead_hits", len(live))
+            _monitor.stat_add("STAT_serving_ahead_misses", dropped)
+        if not live:
+            return 0
+        with _profiler.RecordEvent("serving.decode.fetch"):
+            try:
+                nxt = np.asarray(fl.nxt)  # the host waits for the device
+            except Exception as e:
+                # the step failed on the device, after its dispatch had
+                # bound the pools it returns: they went with it, and
+                # with them whatever was dispatched behind it
+                self._pools_lost(e)
+                return 0
+        with _profiler.RecordEvent("serving.decode.commit",
+                                   {"tokens": len(live)}):
+            now = self._clock()       # the commit's one stamp
+            self._note_qerr(fl.qerr, len(live))
+            # the authoritative key is the request's (_writeback_keys)
+            keys = np.asarray(fl.keys)
+            for slot, req in live:
+                req._key = keys[slot].copy()
+                self.cache.advance(slot, 1)
+                self._append_token(req, int(nxt[slot]), now)
+            # the next step's tokens and keys, while the batch stands:
+            # not if it holds a request this step did not decode for
+            mine = dict(live)
+            stands = all(mine.get(slot) is req
+                         for slot, req in self._active.items())
+            self._carry = (self._progress(), fl.nxt, fl.keys) \
+                if stands else None
+        return len(live)
+
+    def _drain(self) -> int:  # holds: _step_lock
+        """Fetch and commit the step in flight, if there is one, and
+        dispatch nothing: before anything that rebinds or reads the
+        pools outside the steps' order, or when the next step is not
+        this path's to dispatch. Returns the tokens committed."""
+        fl, self._flight = self._flight, None
+        return 0 if fl is None else self._land(fl)
 
     # ------------------------------------------------ decode megasteps
     def _choose_megastep(self) -> int:  # holds: _step_lock
@@ -2214,10 +2397,11 @@ class ServingEngine:
                ah["lora_arrays"] is self.lora_pool.arrays))
         if not ok:
             self._ahead_misses += 1
-            _monitor.stat_add("STAT_serving_ahead_misses")
+            _monitor.stat_add("STAT_serving_ahead_misses",
+                              len(ah["snap"][3]))   # in rows, as _land
             return None
         self._ahead_hits += 1
-        _monitor.stat_add("STAT_serving_ahead_hits")
+        _monitor.stat_add("STAT_serving_ahead_hits", len(self._active))
         return ah["out"]
 
     def _megastep_attempt(self, n: int):
@@ -2324,7 +2508,9 @@ class ServingEngine:
         eligible, else the per-token single step (megastep=1, grammar
         rows, oversized stops, tight deadlines). A fallback round
         drops any stored speculation — its snapshot could never match
-        a state the single step advanced. Whatever runs is one
+        a state the single step advanced — and a megastep round first
+        commits the single step in flight, which is dispatched one
+        ahead of its fetch (:meth:`_decode`). Whatever runs is one
         ``serving.decode_step`` span, from building the step's tokens
         to its last commit; an idle engine records none."""
         if self.cache.pool.epoch != self._pool_epoch:
@@ -2335,6 +2521,7 @@ class ServingEngine:
                 "shared KV pool rebuilt by a co-located engine"))
         if not self._active:
             self._ahead = None
+            self._drain()
             return 0
         n = (self.spec_tokens + 1 if self.spec_tokens
              else self._choose_megastep())
@@ -2344,7 +2531,7 @@ class ServingEngine:
             if self.spec_tokens:
                 return self._spec_decode()
             if n > 1:
-                return self._decode_megastep(n)
+                return self._drain() + self._decode_megastep(n)
             self._ahead = None
             return self._decode()
 
@@ -2724,6 +2911,11 @@ class ServingEngine:
                         if (now - t0) * 1e3 >= idle_ms}
             if not eligible:
                 return
+        elif not any(pool.allocator.refcount[e.block] == 1
+                     for e in pool._prefix.values()):
+            return      # nothing is cold: nothing to copy out
+        # the copies read the pools outside the steps' order
+        self._drain()
         entries, _blocks = self.kv_tier.demote(self.cache,
                                                keys=eligible)
         if entries and eligible is not None:
@@ -2756,6 +2948,9 @@ class ServingEngine:
             prefix_miss_reqs = self._prefix_miss_reqs
             ahead_hits = self._ahead_hits
             ahead_misses = self._ahead_misses
+            ahead_dispatches = self._ahead_dispatches
+            ahead_rows_committed = self._ahead_rows_committed
+            ahead_rows_dropped = self._ahead_rows_dropped
             pool_dispatches = self._pool_dispatches
             pool_inplace = self._pool_inplace
             sampler_dispatches = self._sampler_dispatches
@@ -2811,6 +3006,13 @@ class ServingEngine:
             if self.dispatch_ahead:
                 out["ahead_hits"] = ahead_hits
                 out["ahead_misses"] = ahead_misses
+        # single decode steps dispatched before the step before them was
+        # fetched (of ``sampler_dispatches`` when nothing else decodes),
+        # and their rows: committed, or computed for a request that had
+        # finished or left by then
+        out["ahead_dispatches"] = ahead_dispatches
+        out["ahead_rows_committed"] = ahead_rows_committed
+        out["ahead_rows_dropped"] = ahead_rows_dropped
         # decode/verify dispatches, and those whose batch was all
         # greedy (the step skipped the sampler on the device)
         out["sampler_dispatches"] = sampler_dispatches
@@ -2962,3 +3164,5 @@ class ServingEngine:
         if self._thread is not None:
             self._thread.join(timeout=10)
             self._thread = None
+        with self._step_lock:
+            self._drain()   # what the device was given is committed

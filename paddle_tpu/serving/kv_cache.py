@@ -964,6 +964,23 @@ class BlockKVCache:
         self.lengths[row] = ln
         self._slide(row)
 
+    def ahead_lengths(self, rows: Sequence[int]) -> np.ndarray:
+        """The lengths a step takes that is dispatched while the step
+        before it is not committed yet: ``rows``, the rows that step
+        writes, stand one further than :attr:`lengths` says, and the
+        window kinds are moved to that position first, as
+        :meth:`advance` would have left them (it then finds them there).
+        A block they return here may still be read by the uncommitted
+        step: it was dispatched with the table that held it, and whoever
+        takes the block next writes it behind that step on the device."""
+        lengths = self.lengths.copy()
+        for row in rows:
+            lengths[row] += 1
+            for w in self._windows:
+                if w.hold(row, int(lengths[row])):
+                    self.tables_version += 1
+        return lengths
+
     def _slide(self, row: int):
         """The window kinds follow ``row``'s new length: blocks wholly
         behind the window of the position it writes next go back to their

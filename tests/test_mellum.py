@@ -120,7 +120,13 @@ class Tap:
 
             def fn(*args):
                 out = ent["fn"](*args)
+                lengths = np.asarray(args[1])
                 for slot, req in engine._active.items():
+                    made = int(lengths[slot]) - len(req.prompt) + 1
+                    if made >= req.max_new_tokens:
+                        # dispatched ahead of the commit that ends this
+                        # request by budget: the row is for nobody
+                        continue
                     self.rows.setdefault(req.id, []).append(
                         np.asarray(out[1][slot]))
                 return out

@@ -764,18 +764,26 @@ def test_every_paged_dispatch_consumes_its_pools(model, mode):
                         max_queue=16, block_size=4, **mode)
     reqs = [eng.submit(p, max_new_tokens=9)
             for p in _prompts((3, 6, 11), seed=21)]
-    steps = 0
+    steps = quiet = 0
     while not eng.idle:
         # the K and V pools (an int8 pool's scales may be replaced by
         # the allocator before the step's first dispatch sees them)
         before = [a for layer in eng.cache.arrays() for a in layer[:2]]
         assert not any(a.is_deleted() for a in before)
+        dispatched = eng.stats()["pool_dispatches"]
         eng.step()
         steps += 1
+        if eng.stats()["pool_dispatches"] == dispatched:
+            # the round only committed the step that was in flight (the
+            # single step is dispatched one ahead of its fetch)
+            assert not any(a.is_deleted() for a in before), steps
+            quiet += 1
+            continue
         assert all(a.is_deleted() for a in before), steps
     assert all(r.state == "done" for r in reqs)
     st = eng.stats()
-    assert st["pool_dispatches"] >= steps
+    assert st["pool_dispatches"] >= steps - quiet
+    assert quiet <= len(reqs)    # at most the last round of a request
     assert st["pool_inplace"] == st["pool_dispatches"]
     assert st["pool_inplace_share"] == 1.0
     assert monitor.stat_get("STAT_serving_pool_inplace") == \
@@ -823,6 +831,11 @@ def test_a_step_that_fails_after_consuming_the_pools_keeps_serving(
     monkeypatch.setitem(ent, "fn", consume_then_raise)
     second = eng.submit(prompts[1], max_new_tokens=8)
     eng.step()
+    if where == "decode":
+        # the round that admitted the second committed the step that was
+        # in flight and dispatched none; the next one dispatches
+        assert first.state == second.state == "running"
+        eng.step()
     monkeypatch.undo()
 
     assert first.state == "shed"
