@@ -3,16 +3,18 @@
 ``STAT_ADD("STAT_total_feasign_num_in_mem", n)`` style counters used by
 the dataset/PS tiers for observability; thread-safe, exported as a dict.
 ``stat_time(name)`` adds a minimal latency facility on the same
-registry: phase timings (serving prefill/decode, checkpoint IO) land in
+registry: phase timings (any ``with`` block; serving prefill/decode
+through ``stat_observe``, from readings the engine holds already) land in
 ``stats()`` as ``<name>_calls`` / ``<name>_ms`` without a separate
 metrics stack.
 
 Since the observability plane landed, this module is a *shim*: every
-``stat_add`` counter is a Counter and every ``stat_time`` site a
-Histogram in ``paddle_tpu.observability.metrics.DEFAULT``, so the same
-values surface in ``GET /metrics`` / ``observability.snapshot()``. The
-dict-shaped API (exact key names, int/float types, dotted fault-site
-names) is unchanged — the whole chaos suite pins it.
+``stat_add`` counter is a Counter and every ``stat_time`` /
+``stat_observe`` site a Histogram in
+``paddle_tpu.observability.metrics.DEFAULT``, so the same values surface
+in ``GET /metrics`` / ``observability.snapshot()``. The dict-shaped API
+(exact key names, int/float types, dotted fault-site names) is unchanged
+— the whole chaos suite pins it.
 """
 
 from __future__ import annotations
@@ -67,19 +69,27 @@ def stat_get(name: str) -> int:
 
 @contextlib.contextmanager
 def stat_time(name: str):
-    """``with stat_time("STAT_serving_prefill"): ...`` — records one
+    """``with stat_time("STAT_phase"): ...`` — records one
     call and its wall-clock milliseconds as ``<name>_calls`` (int) and
     ``<name>_ms`` (float total) alongside the ordinary counters, so
-    ``stats()["STAT_serving_prefill_ms"] /
-    stats()["STAT_serving_prefill_calls"]`` is the mean latency."""
-    with _lock:
-        _timer_names.add(name)
-    hist = _registry().histogram(name)
+    ``stats()["STAT_phase_ms"] / stats()["STAT_phase_calls"]``
+    is the mean latency."""
     t0 = time.perf_counter()
     try:
         yield
     finally:
-        hist.observe((time.perf_counter() - t0) * 1e3)
+        stat_observe(name, (time.perf_counter() - t0) * 1e3)
+
+
+def stat_observe(name: str, ms: float):
+    """One call of ``ms`` milliseconds under ``name``, as :func:`stat_time`
+    records it, for a phase whose ends no ``with`` block spans and whose
+    clock readings the caller holds already (the serving engine's
+    ``STAT_serving_prefill`` / ``_decode`` / ``_verify``: one observation a
+    dispatch, from its dispatch to its tokens fetched)."""
+    with _lock:
+        _timer_names.add(name)
+    _registry().histogram(name).observe(ms)
 
 
 def stats() -> Dict[str, float]:

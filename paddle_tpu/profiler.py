@@ -53,10 +53,16 @@ class RecordEvent:
     same thread when it was entered, None at the root) and the optional
     ``args`` (a small dict of str/int/float, also handed to the
     TraceAnnotation as keyword arguments: they become stats of the
-    trace event, its name stays ``name``)."""
+    trace event, its name stays ``name``).
+
+    Profiler on or off, the two clock readings an event takes anyway stay
+    on it: ``t0`` at entry and ``t1`` at exit, ``time.perf_counter_ns``
+    (0 until taken). Code that accounts for the interval beside the span
+    (the serving engine's step account) reads them and not the clock, so
+    the account and the span are the same readings."""
 
     _ann = _id = _parent = None
-    _t0 = 0
+    t0 = t1 = 0
 
     def __init__(self, name: str, args: Optional[dict] = None):
         self.name = name
@@ -72,11 +78,11 @@ class RecordEvent:
             self._id = next(_ids)
             self._parent = stack[-1] if stack else None
             stack.append(self._id)
-        self._t0 = time.perf_counter_ns()
+        self.t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
-        t1 = time.perf_counter_ns()
+        t1 = self.t1 = time.perf_counter_ns()
         if self._ann is not None:
             self._ann.__exit__(*exc)
         if self._id is None:        # entered with the profiler off
@@ -84,7 +90,7 @@ class RecordEvent:
         stack = _open_spans()
         if self._id in stack:       # not when exited on another thread
             stack.remove(self._id)
-        _record(self.name, self._t0 / 1e3, (t1 - self._t0) / 1e3,
+        _record(self.name, self.t0 / 1e3, (t1 - self.t0) / 1e3,
                 self._id, self._parent, self.args)
         self._id = None
         return False

@@ -22,7 +22,11 @@ Compile surfaces, all fixed-shape:
   grammar row, or sampled by devprof is built from the host once the
   step before it landed (``ServingEngine._decode``; ``stats()``:
   ``ahead_dispatches`` / ``ahead_rows_committed`` /
-  ``ahead_rows_dropped``). Tokens are counted when the host has them;
+  ``ahead_rows_dropped``). Tokens are counted when the host has them.
+  A dispatch of any kind is stamped once at each of its edges
+  (:class:`_Stamps`: the readings its spans take anyway), and the
+  stamps feed its spans (``flight=<id>``), the step account in
+  ``stats()`` and the SLO cost estimates (:meth:`_landed`);
 - verify (``FLAGS_serving_spec_tokens`` = K > 0): speculative
   decoding replaces the one-token decode with
   ``models.generation.verify_step_paged(model, K)`` — an on-host n-gram
@@ -89,7 +93,8 @@ queue-depth gate alone, ``submit()`` predicts the newcomer's TTFT
 from live host state — queue depth ahead of it, free decode slots,
 the per-bucket prefill dispatch cost, and the decode batch's
 per-token pace (costs pinned via ``FLAGS_serving_slo_prefill_ms`` /
-``_tpot_ms`` or learned as EWMAs over measured dispatches) — and
+``_tpot_ms`` or learned as EWMAs over measured dispatches, each timed
+to its tokens on the host, not to its launch) — and
 sheds the submission when the prediction exceeds the SLO, with the
 prediction echoed back as the 429 Retry-After hint. Requests carry an
 integer priority class (lower = more urgent, FIFO within a class);
@@ -176,6 +181,40 @@ class _SkipStep(Exception):
     serving.step during decode); requests stay live."""
 
 
+#: the step account of ``ServingEngine.stats()``: counts, and sums in ms
+_ACCOUNT_KEYS = (
+    "decode_flights", "decode_host_ms", "decode_wait_ms",
+    "decode_device_ms", "prefill_flights", "prefill_host_ms",
+    "prefill_wait_ms", "rounds", "round_ms", "rounds_over_100ms",
+    "rounds_over_1s", "stalled_ms", "first_tokens", "ttft_ms",
+    "queue_wait_ms")
+
+
+class _Stamps:
+    """One dispatch the device was given, a decode step (single, megastep
+    or verify) or a prefill group, from its dispatch to its commit.
+
+    ``id`` is its place in the engine's one sequence of dispatches, which
+    is the order the device runs them in; the spans of one dispatch carry
+    it as ``flight=id``. The five ``t_*`` are the readings of its edges,
+    ``time.perf_counter_ns`` as the ``RecordEvent``s that bound those
+    edges took them (no clock is read for a stamp): ``t_dispatch``
+    entering the span that builds its inputs, ``t_launched`` the first
+    reading after its entry returned (the close of ``serving.decode`` /
+    ``.verify`` / ``.prefill``, or the next dispatch's ``t_dispatch``
+    where one span holds two; until that event closes it stands at
+    ``t_dispatch``), ``t_fetch`` / ``t_fetched`` around the host's wait
+    for its tokens, ``t_committed`` at the end of its commit."""
+
+    __slots__ = ("id", "t_dispatch", "t_launched", "t_fetch", "t_fetched",
+                 "t_committed")
+
+    def __init__(self, id_: int):
+        self.id = id_
+        self.t_dispatch = self.t_launched = 0
+        self.t_fetch = self.t_fetched = self.t_committed = 0
+
+
 class _Flight(NamedTuple):
     """One decode step the device was given and whose tokens the host
     has not fetched. The pools (and counters) it returns are bound when
@@ -188,6 +227,7 @@ class _Flight(NamedTuple):
     keys: object        # [b, 2] u32 advanced keys, on the device
     qerr: object
     ahead: bool         # dispatched before the step before it was fetched
+    stamps: _Stamps     # its id and the readings of its edges
 
 
 class Request:
@@ -267,6 +307,9 @@ class Request:
         self.submitted_at = (time.perf_counter() if now is None
                              else float(now))
         self.deadline: Optional[float] = None
+        # engine-clock stamp of the admission whose prefill gave the
+        # first token (the trace's "admit" mark, the same float)
+        self.admitted_at: Optional[float] = None
         self.first_token_at: Optional[float] = None
         # engine-clock stamp of every committed token, one clock read
         # per commit shared by all the tokens it commits (so the n
@@ -742,6 +785,15 @@ class ServingEngine:
         # steps dispatched before the step before them was fetched, and
         # their rows that were committed / computed for nobody
         self._flight = None               # guarded-by: _step_lock
+        # the engine's one sequence of dispatches (a _Stamps' id), and
+        # when the last of them to be fetched was: the device was busy
+        # with it until then, whatever was dispatched behind it
+        self._dispatch_seq = 0            # guarded-by: _step_lock
+        self._fetched_last = (0, 0)       # (id, t_fetched ns)
+        # the step account of stats(): monotone sums (ms) and counts,
+        # fed by the flights' stamps, profiler on or off
+        self._account = dict.fromkeys(    # guarded-by: _step_lock
+            _ACCOUNT_KEYS, 0)
         self._ahead_dispatches = 0        # guarded-by: _step_lock
         self._ahead_rows_committed = 0    # guarded-by: _step_lock
         self._ahead_rows_dropped = 0      # guarded-by: _step_lock
@@ -1000,15 +1052,19 @@ class ServingEngine:
         self._tpot_ewma = self._ewma(self._tpot_ewma, ms)
 
     def _prefill_cost_ms(self, bucket: int) -> float:
-        """Estimated cost of one prefill dispatch for this bucket:
-        the pinned value when set, else the measured EWMA (global
-        fallback before this bucket's first dispatch; 0 before any)."""
+        """Estimated cost of one prefill dispatch for this bucket, from
+        its dispatch to its first tokens on the host: the pinned value
+        when set, else the measured EWMA (global fallback before this
+        bucket's first dispatch; 0 before any)."""
         if self._prefill_ms_pin:
             return self._prefill_ms_pin
         v = self._prefill_ewma.get(bucket, self._prefill_ewma_all)
         return 0.0 if v is None else v
 
     def _tpot_cost_ms(self) -> float:
+        """Estimated time of one output token of a running request: the
+        pinned value when set, else the EWMA of the device's time for a
+        decode step over the tokens it committed a row (0 before any)."""
         if self._tpot_ms_pin:
             return self._tpot_ms_pin
         return self._tpot_ewma if self._tpot_ewma is not None else 0.0
@@ -1016,7 +1072,8 @@ class ServingEngine:
     def reset_cost_estimates(self):
         """Drop the learned EWMA costs (pins stay). Call after a
         warmup pass that paid XLA compiles, so admission predictions
-        reflect steady-state dispatch costs instead of trace time."""
+        reflect what a steady-state dispatch takes on the device
+        (:meth:`_landed` says what a sample is) instead of trace time."""
         self._prefill_ewma.clear()
         self._prefill_ewma_all = None
         self._tpot_ewma = None
@@ -1657,16 +1714,21 @@ class ServingEngine:
     def _prefill_group(self, bucket: int,
                        group) -> int:  # holds: _step_lock
         """One group of an admission round, from building its inputs
-        to its first tokens committed. Returns rows admitted."""
+        to its first tokens committed: one dispatch (:class:`_Stamps`), whose
+        dispatch-to-fetched time is the bucket's sample for the SLO
+        estimate (:meth:`_landed`; a devprof sample blocks on the device
+        inside that interval and moves nothing). Returns rows admitted."""
         t_adm = self._clock()
         for g_req, _row, _shared in group:
             _tracing.mark(g_req.id, "admit", t_adm, self.trace_track)
+            g_req.admitted_at = t_adm
         timer = self._devprof_timer(
             f"serving_prefill_paged{{bucket={bucket}}}")
-        t0 = time.perf_counter()
+        # queued on the device behind the decode step in flight, if any
+        st, behind = self._stamps(), self._flight is not None
         try:
-            with _monitor.stat_time("STAT_serving_prefill"), \
-                    _profiler.RecordEvent("serving.prefill"):
+            with _profiler.RecordEvent("serving.prefill",
+                                       {"flight": st.id}) as ev:
                 live, shed, out = RetryPolicy.from_flags(
                     "serving.step").call(
                         self._prefill_group_attempt, bucket, group)
@@ -1675,12 +1737,7 @@ class ServingEngine:
                 self.cache.release_row(row)
                 self._shed(req, e)
             return 0
-        if out is not None:
-            # EMA window closes BEFORE the devprof sync: the
-            # block_until_ready below must not inflate the cost
-            # estimate that drives SLO admission
-            self._note_prefill_ms(
-                bucket, (time.perf_counter() - t0) * 1e3)
+        st.t_dispatch, st.t_launched = ev.t0, ev.t1
         if timer is not None and out is not None:
             timer.device_done(out)
         for (req, row, _), err in shed:
@@ -1689,9 +1746,12 @@ class ServingEngine:
         if not live:
             return 0
         lg, pools, qerr = out
-        with _profiler.RecordEvent("serving.prefill.fetch"):
+        with _profiler.RecordEvent("serving.prefill.fetch",
+                                   {"flight": st.id}) as ev:
             first = np.asarray(jnp.argmax(lg, axis=-1))
-        with _profiler.RecordEvent("serving.prefill.commit"):
+        st.t_fetch, st.t_fetched = ev.t0, ev.t1
+        with _profiler.RecordEvent("serving.prefill.commit",
+                                   {"flight": st.id}) as ev:
             now = self._clock()     # the commit's one stamp
             self.cache.set_arrays(pools)
             self._note_qerr(qerr, sum(len(req.context) - shared
@@ -1725,6 +1785,8 @@ class ServingEngine:
                 # prefill; sampled/masked rows draw from them instead)
                 self._append_token(
                     req, self._take_first(req, first, lg, i), now)
+        st.t_committed = ev.t1
+        self._landed(st, len(live), ahead=behind, bucket=bucket)
         if timer is not None:
             timer.finish()
         return len(live)
@@ -1987,9 +2049,68 @@ class ServingEngine:
             self._inputs_resident += 1
             _monitor.stat_add("STAT_serving_inputs_resident")
 
-    def _step_args(self, tokens=None, after=None):  # holds: _step_lock
+    def _stamps(self) -> _Stamps:  # holds: _step_lock
+        """The next dispatch's id, and room for its stamps."""
+        self._dispatch_seq += 1
+        return _Stamps(self._dispatch_seq)
+
+    def _landed(self, st: _Stamps, rows: int, tokens_a_row: float = 1.0,
+                ahead: bool = False,
+                bucket: Optional[int] = None):  # holds: _step_lock
+        """One dispatch is committed: its stamps, the readings its spans
+        took, feed the step account of :meth:`stats`, the SLO estimates,
+        the phase's ``STAT_*`` timer and the ``serving.flight`` span.
+        ``ahead``: it was dispatched behind a decode step in flight;
+        ``bucket`` says it was a prefill group.
+
+        The device runs dispatches in the order of their ids and was
+        busy with the last one fetched until that fetch returned. So a
+        decode step took the device from ``max(t_launched, the last
+        fetch's return)`` to ``t_fetched``: exactly, if the host waited
+        for it; at most, if its tokens were there already (the account
+        takes the bound as it is). A step overtaken, because a prefill
+        dispatched behind it was fetched before it, ended unobserved and
+        feeds no estimate. The TPOT sample is that time over the tokens
+        the step committed a row; the prefill sample is dispatch to
+        fetched, the wait behind a step in flight included, which is what
+        the next arrival pays too."""
+        a = self._account
+        host = (st.t_launched - st.t_dispatch
+                + st.t_committed - st.t_fetched) / 1e6
+        wait = (st.t_fetched - st.t_fetch) / 1e6
+        turnaround = (st.t_fetched - st.t_dispatch) / 1e6
+        last_id, last_fetched = self._fetched_last
+        self._fetched_last = (st.id, st.t_fetched)
+        args = {"flight": st.id, "ahead": int(ahead), "rows": rows,
+                "prefill": int(bucket is not None)}
+        if bucket is not None:
+            a["prefill_flights"] += 1
+            a["prefill_host_ms"] += host
+            a["prefill_wait_ms"] += wait
+            self._note_prefill_ms(bucket, turnaround)
+            stat = "STAT_serving_prefill"
+            args["bucket"] = bucket
+        else:
+            # with speculation on, every decode step is a verify
+            stat = ("STAT_serving_verify" if self.spec_tokens
+                    else "STAT_serving_decode")
+            device = (st.t_fetched - max(st.t_launched, last_fetched)) / 1e6
+            a["decode_flights"] += 1
+            a["decode_host_ms"] += host
+            a["decode_wait_ms"] += wait
+            a["decode_device_ms"] += device
+            if last_id < st.id and tokens_a_row:
+                self._note_tpot_ms(device / tokens_a_row)
+        _monitor.stat_observe(stat, turnaround)
+        _profiler.record_span("serving.flight", st.t_dispatch / 1e9,
+                              (st.t_committed - st.t_dispatch) / 1e9, args)
+
+    def _step_args(self, st: _Stamps, tokens=None,
+                   after=None):  # holds: _step_lock
         """The inputs of one decode or verify dispatch after the
-        params: ``(tokens, lengths, tables, pools, samp[, lora])``.
+        params: ``(tokens, lengths, tables, pools, samp[, lora])``,
+        built under the ``serving.decode.inputs`` span whose entry is
+        ``st``'s ``t_dispatch``.
         A decode step's ``tokens`` are the last step's own output while
         the batch is unchanged; a verify's ``[b, K+1]`` tree comes from
         the host. ``lengths`` is one small copy a step (a copy, because
@@ -2002,7 +2123,9 @@ class ServingEngine:
         the device holds them, and every live row stands one further
         than the cache has committed (``ahead_lengths``, which moves
         the window kinds first: a table that moved is re-sent here)."""
-        with _profiler.RecordEvent("serving.decode.inputs"):
+        with _profiler.RecordEvent(
+                "serving.decode.inputs",
+                {"flight": st.id, "ahead": int(after is not None)}) as ev:
             self._resent = False
             if after is None:
                 last, keys = self._carried()
@@ -2016,6 +2139,7 @@ class ServingEngine:
                     self.cache.arrays(), self._build_samp(keys))
             if self._lora_shape is not None:
                 args = args + (self._lora_args(),)
+        st.t_dispatch = st.t_launched = ev.t0
         return args
 
     def _launch(self, after=None, rows=None):  # holds: _step_lock
@@ -2030,7 +2154,8 @@ class ServingEngine:
         if after is None:
             rows = tuple((slot, req, len(req.tokens))
                          for slot, req in self._active.items())
-        args = self._step_args(after=after)
+        st = self._stamps()
+        args = self._step_args(st, after=after)
         if self._counted is not None:
             args = args + (self._counted,)
         out = self._call_paged(fn, args, args[3])
@@ -2047,7 +2172,7 @@ class ServingEngine:
             self._ahead_rows_dropped += dropped
             _monitor.stat_add("STAT_serving_ahead_dispatches")
             _monitor.stat_add("STAT_serving_ahead_misses", dropped)
-        return _Flight(rows, nxt, new_keys, qerr, after is not None)
+        return _Flight(rows, nxt, new_keys, qerr, after is not None, st)
 
     def _rows_ahead(self, fl: _Flight):  # holds: _step_lock
         """The rows of the step after ``fl``, if that step can be
@@ -2085,12 +2210,18 @@ class ServingEngine:
         kind = fault_point("serving.step")
         if kind == "skip":
             raise _SkipStep("injected skip of one decode iteration")
-        if self._flight is None:
+        first = self._flight is None
+        if first:
             self._flight = self._launch()
         rows = None if sampled else self._rows_ahead(self._flight)
         if rows is None:
             return None
-        return self._launch(self._flight, rows)
+        ahead = self._launch(self._flight, rows)
+        if first:
+            # two launches under one serving.decode: the first had
+            # returned by the time the second's inputs were begun
+            self._flight.stamps.t_launched = ahead.stamps.t_dispatch
+        return ahead
 
     def _note_qerr(self, qerr, rows: int):  # holds: _step_lock
         """Surface an int8 step's max-abs dequantization error: bump
@@ -2148,12 +2279,13 @@ class ServingEngine:
         timer = None
         if self._flight is None:
             timer = self._devprof_timer("decode_step_paged")
-        t0 = time.perf_counter()
+        seq = self._dispatch_seq
         try:
-            with _monitor.stat_time("STAT_serving_decode"), \
-                    _profiler.RecordEvent("serving.decode"):
+            with _profiler.RecordEvent("serving.decode") as ev:
                 ahead = RetryPolicy.from_flags("serving.step").call(
                     self._decode_attempt, timer is not None)
+                # known only now, so kept in the in-process event alone
+                ev.args = {"launches": self._dispatch_seq - seq}
         except (_SkipStep, _PoolsLost):
             return 0
         except RetryError as e:
@@ -2162,13 +2294,11 @@ class ServingEngine:
             self._shed_active(e)
             self._flight = None
             return 0
-        # the TPOT EWMA is per *committed token*: one step commits
-        # exactly one token per active slot here, so the step wall is
-        # already a per-token sample (the megastep and spec paths
-        # divide by tokens committed explicitly). Closed BEFORE the
-        # devprof sync so block_until_ready never inflates the cost
-        # estimate that drives SLO admission.
-        self._note_tpot_ms((time.perf_counter() - t0) * 1e3)
+        if self._dispatch_seq > seq:
+            # the span's close is the first reading after the round's
+            # last launch returned
+            last = self._flight if ahead is None else ahead
+            last.stamps.t_launched = ev.t1
         fl, self._flight = self._flight, ahead
         if timer is not None:
             timer.device_done((fl.nxt, fl.keys))  # block + stamp
@@ -2193,6 +2323,7 @@ class ServingEngine:
         tokens committed."""
         live = [(slot, req) for slot, req, n in fl.rows
                 if self._active.get(slot) is req and len(req.tokens) == n]
+        st = fl.stamps
         if fl.ahead:
             dropped = len(fl.rows) - len(live)
             self._ahead_rows_committed += len(live)
@@ -2201,7 +2332,8 @@ class ServingEngine:
             _monitor.stat_add("STAT_serving_ahead_misses", dropped)
         if not live:
             return 0
-        with _profiler.RecordEvent("serving.decode.fetch"):
+        with _profiler.RecordEvent("serving.decode.fetch",
+                                   {"flight": st.id}) as ev:
             try:
                 nxt = np.asarray(fl.nxt)  # the host waits for the device
             except Exception as e:
@@ -2210,8 +2342,10 @@ class ServingEngine:
                 # with them whatever was dispatched behind it
                 self._pools_lost(e)
                 return 0
-        with _profiler.RecordEvent("serving.decode.commit",
-                                   {"tokens": len(live)}):
+        st.t_fetch, st.t_fetched = ev.t0, ev.t1
+        with _profiler.RecordEvent(
+                "serving.decode.commit",
+                {"tokens": len(live), "flight": st.id}) as ev:
             now = self._clock()       # the commit's one stamp
             self._note_qerr(fl.qerr, len(live))
             # the authoritative key is the request's (_writeback_keys)
@@ -2227,6 +2361,9 @@ class ServingEngine:
                          for slot, req in self._active.items())
             self._carry = (self._progress(), fl.nxt, fl.keys) \
                 if stands else None
+        st.t_committed = ev.t1
+        # one step commits one token a row: its time is a TPOT sample
+        self._landed(st, len(live), ahead=fl.ahead)
         return len(live)
 
     def _drain(self) -> int:  # holds: _step_lock
@@ -2294,7 +2431,8 @@ class ServingEngine:
                                      self.kv_dtype,
                                      self._lora_shape)["fn"]
 
-    def _megastep_inputs(self, n: int):  # holds: _step_lock
+    def _megastep_inputs(self, n: int,
+                         st: _Stamps):  # holds: _step_lock
         """One megastep dispatch's fixed-shape inputs. The tokens and
         keys are the last dispatch's carried outputs while the batch
         stands as its commit left it; tables, sampling parameters,
@@ -2306,7 +2444,8 @@ class ServingEngine:
         (``live=False``) and write their strays into the trash block
         exactly as the single step does."""
         b = self.max_slots
-        with _profiler.RecordEvent("serving.decode.inputs"):
+        with _profiler.RecordEvent("serving.decode.inputs",
+                                   {"flight": st.id, "ahead": 0}) as ev:
             self._resent = False
             last, keys = self._carried()
             live = np.zeros(b, bool)
@@ -2326,6 +2465,7 @@ class ServingEngine:
                     (pat, plen, fail, self._send(state)))
             if self._lora_shape is not None:
                 args = args + (self._lora_args(),)
+        st.t_dispatch = st.t_launched = ev.t0
         return args
 
     def _ahead_snapshot(self, n: int, extra_tokens: int = 0):
@@ -2339,7 +2479,8 @@ class ServingEngine:
                     (slot, req.id, len(req.tokens) + extra_tokens)
                     for slot, req in self._active.items())))
 
-    def _dispatch_ahead(self, n: int, out):  # holds: _step_lock
+    def _dispatch_ahead(self, n: int, out,
+                        t_dispatch: int):  # holds: _step_lock
         """Enqueue megastep k+1 from k's still-un-synced device carry
         outputs, before the host blocks on k's results — the device
         queue stays fed while the host commits. The dispatch assumes
@@ -2354,9 +2495,15 @@ class ServingEngine:
         rows, all at or beyond the slot's committed length, where
         nothing reads before the re-dispatched step writes them again
         (the invariant that already covers bucket padding, rejected
-        drafts and the trash block). Only its tokens are dropped."""
+        drafts and the trash block). Only its tokens are dropped.
+
+        No span bounds this dispatch alone: its flight is dispatched at
+        ``t_dispatch``, the close of k's ``serving.decode``, and launched
+        by the entry of k's ``serving.decode.fetch``."""
         (_toks, _finish, tok_f, pos_f, pools_f, keys_f, live_f,
          rem_f, st_f, _qerr) = out
+        st = self._stamps()
+        st.t_dispatch = st.t_launched = t_dispatch
         self._resent = False
         # the operands k was dispatched with, as the device holds
         # them: k's carry for what a step advances, the resident
@@ -2371,6 +2518,7 @@ class ServingEngine:
         self._note_dispatch()
         self._ahead = {
             "n": n,
+            "stamps": st,
             "snap": self._ahead_snapshot(n, extra_tokens=n),
             "leaf": ahead_out[4][0][0],
             "lora_arrays": (None if self._lora_shape is None
@@ -2402,24 +2550,25 @@ class ServingEngine:
             return None
         self._ahead_hits += 1
         _monitor.stat_add("STAT_serving_ahead_hits", len(self._active))
-        return ah["out"]
+        return ah["stamps"], ah["out"]
 
     def _megastep_attempt(self, n: int):
         """One megastep dispatch attempt (the serving.step fault
         site). The fault check fires BEFORE the speculation is
         consumed, so an injected skip leaves the stored dispatch valid
         for the next attempt — the state it assumed is untouched.
-        Returns the entry's outputs."""
+        Returns the dispatch's flight and the entry's outputs."""
         kind = fault_point("serving.step")
         if kind == "skip":
             raise _SkipStep("injected skip of one decode megastep")
         taken = self._take_ahead(n)
         if taken is not None:
             return taken
-        args = self._megastep_inputs(n)
+        st = self._stamps()
+        args = self._megastep_inputs(n, st)
         out = self._call_paged(self._megastep_fn(n), args, args[3])
         self._note_dispatch()
-        return out
+        return st, out
 
     def _decode_megastep(self, n: int) -> int:  # holds: _step_lock
         """One device-resident megastep over every occupied slot: N
@@ -2433,17 +2582,21 @@ class ServingEngine:
             return 0
         n_active = len(self._active)
         timer = self._devprof_timer(f"decode_megastep_paged{{n={n}}}")
-        t0 = time.perf_counter()
+        seq = self._dispatch_seq
         try:
-            with _monitor.stat_time("STAT_serving_decode"), \
-                    _profiler.RecordEvent("serving.decode"):
-                out = RetryPolicy.from_flags(
+            with _profiler.RecordEvent("serving.decode") as ev:
+                st, out = RetryPolicy.from_flags(
                     "serving.step").call(self._megastep_attempt, n)
+                # 0: the step was dispatched ahead, a round ago
+                ev.args = {"launches": self._dispatch_seq - seq}
         except (_SkipStep, _PoolsLost):
             return 0
         except RetryError as e:
             self._shed_active(e)
             return 0
+        taken = self._dispatch_seq == seq
+        if not taken:
+            st.t_launched = ev.t1
         (toks, finish, tok_f, _pos_f, pools_f, keys_f, _live_f,
          _rem_f, _st_f, qerr) = out
         if timer is not None:
@@ -2457,14 +2610,19 @@ class ServingEngine:
             # blocks on k's results: commit work below overlaps it.
             # k+1 consumed k's pools; the cache binds what it returned
             try:
-                pools_f = self._dispatch_ahead(n, out)
+                pools_f = self._dispatch_ahead(n, out, ev.t1)
             except _PoolsLost:
                 return 0
-        with _profiler.RecordEvent("serving.decode.fetch"):
+        with _profiler.RecordEvent("serving.decode.fetch",
+                                   {"flight": st.id}) as fetch:
             toks = np.asarray(toks)          # syncs megastep k
             finish = np.asarray(finish)
             keys_arr = np.asarray(keys_f)
-        with _profiler.RecordEvent("serving.decode.commit") as commit:
+        st.t_fetch, st.t_fetched = fetch.t0, fetch.t1
+        if self._ahead is not None:
+            self._ahead["stamps"].t_launched = fetch.t0
+        with _profiler.RecordEvent("serving.decode.commit",
+                                   {"flight": st.id}) as commit:
             now = self._clock()              # the commit's one stamp
             self.cache.set_arrays(pools_f)
             self._note_qerr(qerr, n * n_active)
@@ -2484,17 +2642,16 @@ class ServingEngine:
                         break
                 if req.state == "running":
                     req._key = keys_arr[slot].copy()
-            commit.args = {"tokens": produced}
+            commit.args = {"tokens": produced, "flight": st.id}
             # what a miss of the speculation, or the single step a
             # fallback takes, feeds next (a frozen row's are never read)
             self._carry = (self._progress(), tok_f, keys_f)
-        if produced:
-            # per-token pace: the megastep wall spread over the tokens
-            # each slot actually committed (satellite: TPOT samples
-            # divide by tokens, not steps, so SLO admission stays
-            # calibrated at megastep > 1)
-            self._note_tpot_ms((time.perf_counter() - t0) * 1e3 *
-                               n_active / produced)
+        st.t_committed = commit.t1
+        # per-token pace: the megastep's time on the device spread over
+        # the tokens each slot actually committed (TPOT samples divide
+        # by tokens, not steps, so SLO admission stays calibrated at
+        # megastep > 1)
+        self._landed(st, n_active, produced / n_active, ahead=taken)
         if timer is not None:
             timer.finish()
         if _runlog.enabled():
@@ -2542,10 +2699,11 @@ class ServingEngine:
             raise _SkipStep("injected skip of one verify iteration")
         fn = verify_step_paged(self.model, self.spec_tokens, self.mesh,
                                self.kv_dtype, self._lora_shape)["fn"]
-        args = self._step_args(tokens)
+        st = self._stamps()
+        args = self._step_args(st, tokens)
         out = self._call_paged(fn, args, args[3])
         self._note_dispatch()
-        return out
+        return st, out
 
     def _spec_decode(self) -> int:  # holds: _step_lock
         """One speculative draft–verify step over every occupied slot:
@@ -2564,24 +2722,27 @@ class ServingEngine:
             tokens[slot, 1:] = d
         n_active = len(self._active)
         timer = self._devprof_timer(f"verify_step_paged{{k={K}}}")
-        t0 = time.perf_counter()
         try:
-            with _monitor.stat_time("STAT_serving_verify"), \
-                    _profiler.RecordEvent("serving.verify"):
-                out = RetryPolicy.from_flags(
+            with _profiler.RecordEvent("serving.verify") as ev:
+                st, out = RetryPolicy.from_flags(
                     "serving.step").call(self._verify_attempt, tokens)
+                ev.args = {"launches": 1}
         except (_SkipStep, _PoolsLost):
             return 0
         except RetryError as e:
             self._shed_active(e)
             return 0
+        st.t_launched = ev.t1
         if timer is not None:
             timer.device_done(out)
         nxt, _, arrays, qerr, accept, new_keys = out
-        with _profiler.RecordEvent("serving.decode.fetch"):
+        with _profiler.RecordEvent("serving.decode.fetch",
+                                   {"flight": st.id}) as fetch:
             nxt = np.asarray(nxt)
             accept = np.asarray(accept)
-        with _profiler.RecordEvent("serving.decode.commit") as commit:
+        st.t_fetch, st.t_fetched = fetch.t0, fetch.t1
+        with _profiler.RecordEvent("serving.decode.commit",
+                                   {"flight": st.id}) as commit:
             now = self._clock()          # the commit's one stamp
             self._note_qerr(qerr, (K + 1) * len(self._active))
             self.cache.set_arrays(arrays)
@@ -2615,14 +2776,13 @@ class ServingEngine:
                     # reject the unaccepted tail: roll the write offset
                     # back so the next step overwrites those rows
                     self.cache.rollback(slot, K + 1 - committed)
-            commit.args = {"tokens": produced}
+            commit.args = {"tokens": produced, "flight": st.id}
             # the keys carry over; a tree of K+1 tokens is drafted anew
             self._carry = (self._progress(), None, new_keys)
-        if produced:
-            # per-output-token pace: step wall time spread over the
-            # tokens each slot actually committed this step
-            self._note_tpot_ms((time.perf_counter() - t0) * 1e3 *
-                               n_active / produced)
+        st.t_committed = commit.t1
+        # per-output-token pace: the step's time on the device spread
+        # over the tokens each slot actually committed this step
+        self._landed(st, n_active, produced / n_active)
         if timer is not None:
             timer.finish()
         return produced
@@ -2636,6 +2796,18 @@ class ServingEngine:
             # the mark reuses the stamp so the blame prefix up to
             # first_token equals the measured TTFT exactly
             _tracing.mark(req.id, "first_token", now, self.trace_track)
+            # TTFT from inside, split at the admission: the prefill part
+            # holds the wait behind a decode step in flight
+            ttft = (now - req.submitted_at) * 1e3
+            queue = (req.admitted_at - req.submitted_at) * 1e3
+            a = self._account
+            a["first_tokens"] += 1
+            a["ttft_ms"] += ttft
+            a["queue_wait_ms"] += queue
+            _profiler.record_span(
+                "serving.ttft", req.submitted_at, now - req.submitted_at,
+                {"request": req.id, "queue_ms": queue,
+                 "prefill_ms": ttft - queue})
         elif req.token_at:
             # the program's own inter-token time, on the clock of every
             # other span (nothing is recorded with the profiler off);
@@ -2875,7 +3047,7 @@ class ServingEngine:
             with _profiler.RecordEvent(
                     "serving.engine_step",
                     {"step": self._step_no, "active": len(self._active),
-                     "queued": len(self._queue)}):
+                     "queued": len(self._queue)}) as ev:
                 # hard-deadline sweep first: a request that expired
                 # since the last step is canceled within one step and
                 # its slot is free for this step's admissions
@@ -2886,7 +3058,19 @@ class ServingEngine:
                     self._demote_sweep()
                 self._blocks_used_g.set(self.cache.blocks_used)
                 self._blocks_free_g.set(self.cache.blocks_free)
-                return bool(admitted or produced or reaped)
+                worked = bool(admitted or produced or reaped)
+            if worked:
+                # a round's time by the span's own readings; a stall of
+                # seconds is a count and a sum in the interval it fell in
+                a, ms = self._account, (ev.t1 - ev.t0) / 1e6
+                a["rounds"] += 1
+                a["round_ms"] += ms
+                if ms > 100.0:
+                    a["rounds_over_100ms"] += 1
+                    a["stalled_ms"] += ms
+                if ms > 1000.0:
+                    a["rounds_over_1s"] += 1
+            return worked
 
     def _demote_sweep(self):  # holds: _step_lock
         """Between-steps host-tier demotion: prefix entries that have
@@ -2929,7 +3113,41 @@ class ServingEngine:
         engine's fixed-bucket Histogram series in the observability
         plane (constant memory — no raw-sample window); None until
         observations exist. The HTTP front end merges this into
-        ``GET /v1/stats``."""
+        ``GET /v1/stats``.
+
+        **The step account** (``_ACCOUNT_KEYS``) is kept whether the
+        profiler is on or off, from the readings the step's spans take
+        anyway (:class:`_Stamps`, :meth:`_landed`): counts, and sums in
+        ms that only grow, so the difference between two calls is what
+        happened between them. A flight is one dispatch that was
+        committed (a decode step: single, megastep or verify; a prefill
+        group).
+
+        - ``decode_flights``; ``decode_host_ms``: the host's own work on
+          them, dispatch to launched plus fetched to committed;
+          ``decode_wait_ms``: the host blocked in their fetch;
+          ``decode_device_ms``: the device's time for them as the host
+          can bound it, each step from the later of its launch and the
+          fetch before it (of either kind) to its own fetch's return.
+          That is exact while the host waits for every step
+          (``decode_wait_ms`` a step well above 0); a step whose tokens
+          were there when the host came, after a prefill dispatched
+          behind it was fetched first or on a host slower than the
+          device, adds its bound, nearly 0, so the mean a step is then a
+          floor.
+        - ``prefill_flights``, ``prefill_host_ms``, ``prefill_wait_ms``:
+          the same for prefill groups (the wait holds the decode step
+          that was in flight ahead of the group).
+        - ``rounds``: calls of :meth:`step` that did work; ``round_ms``
+          their time; ``rounds_over_100ms`` / ``rounds_over_1s`` those
+          that took longer, and ``stalled_ms`` the time of the rounds
+          over 100 ms (where a prefill group takes that long, its rounds
+          are among them).
+        - ``first_tokens``: requests that got their first token;
+          ``ttft_ms`` their submission-to-first-token time and
+          ``queue_wait_ms`` the part of it before admission, on the
+          engine's clock (``ttft_p50_ms`` above counts a request when it
+          completes, these when the token lands)."""
         def pct(hist, q):
             v = hist.quantile(q)
             return None if v is None else round(v * 1e3, 3)
@@ -2960,6 +3178,7 @@ class ServingEngine:
             prefill_rows_computed = self._prefill_rows_computed
             prefill_tokens_live = self._prefill_tokens_live
             prefill_tokens_computed = self._prefill_tokens_computed
+            account = dict(self._account)
         with self._lock:
             completed = self._completed
             slo_met = self._slo_met
@@ -3030,6 +3249,8 @@ class ServingEngine:
         # those of them that were a prompt's tokens
         out["prefill_tokens_computed"] = prefill_tokens_computed
         out["prefill_tokens_live"] = prefill_tokens_live
+        # the step account (_ACCOUNT_KEYS; the docstring says what each is)
+        out.update(account)
         out["attn_impl"] = self.attn_impl
         out["kv_dtype"] = self.kv_dtype
         out["mesh_shape"] = (None if self.mesh_shape is None

@@ -454,20 +454,23 @@ def test_lora_tenant_megastep_identity_and_zero_page_leaks(model):
 
 # -------------------------------------------------- telemetry honesty
 def test_tpot_is_per_token_not_per_dispatch(model, monkeypatch):
-    """TPOT EWMA divides megastep wall time by tokens committed, so
-    the per-token pace at N=4 lands at a quarter of the N=1 pace (a
-    per-dispatch division would land at the same pace — that's the
-    regression bound). The EWMA samples ``time.perf_counter`` around a
-    dispatch; here that clock is stepped (every read advances 1 ms), so a
-    dispatch's "wall" is the count of its reads, the same at N=1 and N=4,
-    and the comparison holds whatever the CPU's speed and load (on the
-    real clock it was a race: under six workers a slow N=4 sample made
-    the take-up run of PR 33 fail)."""
-    from paddle_tpu.serving import engine as engine_mod
+    """TPOT EWMA divides a megastep's time by tokens committed, so
+    the per-token pace at N=4 lands well under the N=1 pace (a
+    per-dispatch division would land at or above it — that's the
+    regression bound). Since PR 35 the EWMA samples the readings the
+    step's spans take (``profiler.RecordEvent``: from a step's launch, or
+    the fetch before it, to its tokens fetched); here that clock is
+    stepped (every read advances 1 ms), so a step's time is the count of
+    the reads in it, and the comparison holds whatever the CPU's speed
+    and load (on the real clock it was a race: under six workers a slow
+    N=4 sample made the take-up run of PR 33 fail)."""
+    from paddle_tpu import profiler as profiler_mod
     stepped = TickClock()
 
     class SteppedTime:
-        perf_counter = staticmethod(stepped)
+        @staticmethod
+        def perf_counter_ns():
+            return int(round(stepped() * 1e9))
 
         def __getattr__(self, name):        # sleep, monotonic, ...
             return getattr(time, name)
@@ -477,13 +480,14 @@ def test_tpot_is_per_token_not_per_dispatch(model, monkeypatch):
         eng = _engine(model, megastep=n)
         _run(eng, prompts, mnt=16)          # warm: compiles land here
         eng._tpot_ewma = None
-        monkeypatch.setattr(engine_mod, "time", SteppedTime())
+        monkeypatch.setattr(profiler_mod, "time", SteppedTime())
         _run(eng, prompts, mnt=16)
         monkeypatch.undo()
         assert eng._tpot_ewma is not None and eng._tpot_ewma > 0
         ewma[n] = eng._tpot_ewma
-    # one dispatch reads the clock as often at N=4 as at N=1 and commits
-    # four tokens a row (three in a megastep cut short by the budget)
+    # a megastep's launch-to-fetched holds no more reads than a single
+    # step's and commits four tokens a row (three in a megastep cut short
+    # by the budget)
     assert ewma[4] < ewma[1] * 0.5, ewma
 
     # per-request TPOT on the engine's own (injected) clock IS strict:

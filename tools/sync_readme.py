@@ -376,7 +376,11 @@ def render_serving_block():
         "engine's predicted-TTFT model and a `reason` in the body).",
         "Per-phase latency lands in `monitor.stats()` as",
         "`STAT_serving_prefill_ms` / `STAT_serving_decode_ms` /",
-        "`STAT_serving_verify_ms`; acceptance as",
+        "`STAT_serving_verify_ms` over `_calls`: one observation a",
+        "dispatch that was committed, from its dispatch to its tokens on",
+        "the host (the device's time included; the SLO gate's cost",
+        "estimates are fed by the same readings, see \"Profiler spans\");",
+        "acceptance as",
         "`STAT_serving_spec_proposed` / `STAT_serving_spec_accepted`;",
         "`engine.stats()` (merged into `GET /v1/stats`) adds",
         "time-to-first-token and time-per-output-token percentiles",
