@@ -549,7 +549,7 @@ class LagunaModel(Layer):
         # the expert layers' counters of the last forward, one row a sparse
         # layer (a buffer, so a compiled step carries them out as state)
         self.register_buffer("moe_stats_last", Tensor(
-            jnp.zeros((max(len(self.sparse_layers), 1), 4), jnp.float32),
+            jnp.zeros((max(len(self.sparse_layers), 1), 5), jnp.float32),
             stop_gradient=True), persistable=False)
 
     def _embed(self, input_ids):
@@ -696,7 +696,9 @@ class LagunaForCausalLM(Layer):
         device now (the step itself never waits for them) and published as
         ``STAT_moe_*``: per sparse layer the (token, held expert) pairs,
         the largest expert load over the mean in thousandths, the dropped
-        pairs (always 0) and whether the fast buffer held the step."""
+        pairs (always 0), whether the fast buffer held the step and the
+        rows its combine gathered over ``tokens x top_k`` (1.0 for the
+        k-slot form; less where the prefix form ran)."""
         import numpy as np
         from .. import monitor
         rows = np.asarray(self.model.moe_stats_last.value)
@@ -705,12 +707,15 @@ class LagunaForCausalLM(Layer):
             out[layer] = {"assignments": int(row[0]),
                           "max_load_over_mean": float(row[1]),
                           "dropped_pairs": int(row[2]),
-                          "fast_path": bool(row[3])}
+                          "fast_path": bool(row[3]),
+                          "combine_rows_share": float(row[4])}
             monitor.stat_set(f"STAT_moe_assignments_l{layer}", int(row[0]))
             monitor.stat_set(f"STAT_moe_max_load_permille_l{layer}",
                              int(round(1000 * float(row[1]))))
             monitor.stat_set(f"STAT_moe_dropped_pairs_l{layer}",
                              int(row[2]))
+            monitor.stat_set(f"STAT_moe_combine_rows_permille_l{layer}",
+                             int(round(1000 * float(row[4]))))
         monitor.stat_set("STAT_moe_dropped_pairs",
                          int(rows[:len(out), 2].sum()))
         return out

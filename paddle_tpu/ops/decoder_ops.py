@@ -14,15 +14,22 @@ work follows the group sizes. On the TPU a gather or scatter costs by the
 element, so the layout is made of dense pieces (a table of running counts
 by a triangular product, lookups in tables of a few entries as masked sums)
 and ONE scatter of choice numbers; what remains are the gathers of whole
-rows: one into the buffer, one a slot out of it.
+rows: one into the buffer, and out of it one a slot (``_gather_sum``) or,
+where the chip holds a small share of the experts and most slots of most
+tokens are absent, the rows the held choices own (``_held_sum``).
 
 The buffer has ``CAPACITY_FACTOR`` times the expected number of pairs; a
-step whose pairs exceed it takes, by a ``lax.cond`` inside the same compiled
-program, a second path that walks the tokens in chunks, each with room for
-all its pairs. So routing never recompiles and never drops, and the common
-case does not pay for the worst one. Backward is written out (no residual
-of the untaken branch is ever materialised): the fast path keeps its sorted
-input and hidden rows, the chunked path recomputes them.
+step whose pairs exceed it takes, by the ONE branch point inside the same
+compiled program (a ``lax.switch`` on the step's own counts), a second path
+that walks the tokens in chunks, each with room for all its pairs. So
+routing never recompiles and never drops, and the common case does not pay
+for the worst one. Where ``_plan`` chose the combine's prefix form for the
+shapes the switch has a third arm: the fast buffer with that form, taken
+when no prefix overflows; a step whose prefixes overflow inside a buffer
+that fits keeps the fast buffer with the k-slot combine (the chunks cost
+2.2x that layer on the v5e: PERF.md section 6, PR 36). Backward is written
+out (no residual of the untaken branch is ever materialised): the fast path
+keeps its sorted input and hidden rows, the chunked path recomputes them.
 """
 
 from __future__ import annotations
@@ -148,7 +155,9 @@ def _gather_sum(rows, pos, valid, weight=None):
     float32: one gather of ``[t, h]`` for each of the ``k`` slots, whatever
     the routing (a slot gathered only when some token needs it would be a
     ``cond`` a slot, which costs a copy of the sum and makes the step's
-    time follow the draw)."""
+    time follow the draw). The combine where every slot is live (all
+    experts held, the decode regime) and of the chunked path; where few
+    are, ``_plan`` chooses ``_held_sum``."""
     acc = jnp.zeros((pos.shape[0], rows.shape[1]), jnp.float32)
     for k in range(pos.shape[1]):
         r = jnp.take(rows, pos[:, k], axis=0).astype(jnp.float32)
@@ -156,6 +165,43 @@ def _gather_sum(rows, pos, valid, weight=None):
             r = r * weight[:, k, None].astype(jnp.float32)
         acc = acc + jnp.where(valid[:, k, None], r, 0.0)
     return acc
+
+
+def _held_sum(rows, order, sizes, weight, dtype):
+    """The same sum over the rows the held choices own. ``order``
+    (``_held_order``) has the tokens sorted by their number of held choices,
+    most first, and every token's held choices moved to the front in their
+    own order: the tokens that have a ``j``-th held choice are then a prefix
+    of that order, and rank ``j`` gathers ``sizes[j]`` rows (static, from
+    ``_plan``; the caller takes another path when a prefix overflows)
+    instead of ``t``. One more gather of ``t`` rows puts the sums back in
+    token order, after the cast to ``dtype`` the caller would make next.
+    Bit-identical to ``_gather_sum(...).astype(dtype)``: the held choices
+    are added in the same order in float32, and what is skipped is what
+    that form adds as ``+ 0.0``."""
+    t, k = order["inv"].shape[0], len(sizes)
+    if weight is not None:      # compacted like the rows, then ordered
+        w = jnp.select([order["sel"][:, :, s] for s in range(k)],
+                       [weight[:, s, None] for s in range(k)], 0)
+        w = jnp.take(w, order["perm"], axis=0).astype(jnp.float32)
+    # the tokens between two prefixes' ends have the same ranks to add
+    bounds = sorted(set(sizes) | {0, t})
+    parts = []
+    for lo, hi in zip(bounds, bounds[1:]):
+        held = order["held"][lo:hi, None]
+        acc = jnp.zeros((hi - lo, rows.shape[1]), jnp.float32)
+        for j, n in enumerate(sizes):
+            if n < hi:
+                break
+            r = jnp.take(rows, order["pos"][lo:hi, j], axis=0) \
+                .astype(jnp.float32)
+            if weight is not None:
+                r = r * w[lo:hi, j, None]
+            acc = acc + jnp.where(j < held, r, 0.0)
+        # an absent slot's + 0.0 also turns a sum of -0.0 into 0.0
+        acc = acc + jnp.where(held < k, 0.0, -0.0)
+        parts.append(acc.astype(dtype))
+    return jnp.take(jnp.concatenate(parts), order["inv"], axis=0)
 
 
 _COUNT_BLOCK = 256
@@ -221,20 +267,63 @@ def _route(local, valid, groups, tm, num_tiles, min_tiles=1):
                 counts=counts, dropped=dropped)
 
 
+def _held_order(valid, pos, sizes):
+    """What ``_held_sum`` reads, from ``valid`` / ``pos`` [t, k] (``_route``'s)
+    and the prefixes' static ``sizes``: ``perm`` [t] the tokens by their
+    number of held choices, most first, ties in token order (a counting sort
+    over its ``k + 1`` values, made like ``_route``'s), ``inv`` its inverse,
+    ``held`` [t] that number in the sorted order, ``sel`` [t, k, k] (slot
+    ``s`` is the token's ``j``-th held choice, in token order), ``pos``
+    [t, k] the row of the ``j``-th held choice of the sorted order's tokens
+    (compacted in token order, then ONE gather of ``[t, k]``: on the v5e a
+    gather of a few thousand scalars a rank costs as much as this one, so
+    eight of them cost more), and ``fits``: no rank has more tokens than
+    its prefix."""
+    t, k = valid.shape
+    key = k - jnp.sum(valid, axis=1, dtype=jnp.int32)
+    hot = key[:, None] == jnp.arange(k + 1, dtype=jnp.int32)[None]
+    running = _running_counts(hot)
+    upto = jnp.cumsum(running[-1])          # tokens with k - i or more held
+    inv = _lookup(upto - running[-1], key) \
+        + jnp.sum(jnp.where(hot, running, 0), axis=1) - 1
+    perm = jnp.zeros((t,), jnp.int32).at[inv].set(
+        jnp.arange(t, dtype=jnp.int32), unique_indices=True)
+    rank = jnp.cumsum(valid, axis=1, dtype=jnp.int32) - 1
+    sel = jnp.logical_and(
+        valid[:, None, :],
+        rank[:, None, :] == jnp.arange(k, dtype=jnp.int32)[None, :, None])
+    front = jnp.sum(jnp.where(sel, pos[:, None, :], 0), axis=2)
+    return dict(
+        perm=perm, inv=inv, sel=sel,
+        pos=jnp.take(front, perm, axis=0),
+        # the sorted order's i-th token is past the tokens of so many keys
+        held=k - jnp.sum(jnp.arange(t, dtype=jnp.int32)[:, None]
+                         >= upto[None, :k], axis=1, dtype=jnp.int32),
+        fits=jnp.all(upto[:k][::-1] <= jnp.asarray(sizes, jnp.int32)))
+
+
 def _set_fwd(x, weight, route, valid, w13, w2, tm, names=("moe_up",
-                                                         "moe_down")):
-    """Forward of one set of tokens -> (out float32 [t, h], (xs, hid, y))."""
+                                                         "moe_down"),
+             sizes=()):
+    """Forward of one set of tokens -> (out [t, h], (xs, hid, y)); ``out``
+    is float32, or with ``sizes`` (the prefix form of the combine, which
+    reads ``route["order"]``) already in ``x``'s dtype."""
     f = w2.shape[1]
     tg, na = route["tile_group"], route["n_active"]
     xs = jnp.take(x, route["tok"], axis=0, mode="fill", fill_value=0)
     hid = gm.gmm(xs, w13, tg, na, name=names[0], tm=tm)
     y = gm.gmm(_silu_mul(hid, f), w2, tg, na, name=names[1], tm=tm)
-    return _gather_sum(y, route["pos"], valid, weight), (xs, hid, y)
+    if sizes:
+        out = _held_sum(y, route["order"], sizes, weight, x.dtype)
+    else:
+        out = _gather_sum(y, route["pos"], valid, weight)
+    return out, (xs, hid, y)
 
 
-def _set_bwd(x, weight, route, valid, w13, w2, tm, dout, saved):
+def _set_bwd(x, weight, route, valid, w13, w2, tm, dout, saved, sizes=()):
     """Backward of one set of tokens -> (dx, dweight, dw13, dw2); ``saved``
-    is the forward's (xs, hid, y) or None to recompute them."""
+    is the forward's (xs, hid, y) or None to recompute them; ``sizes`` as
+    in ``_set_fwd``, for the input gradient's combine."""
     f = w2.shape[1]
     tg, na, pos = route["tile_group"], route["n_active"], route["pos"]
     groups = w13.shape[0]
@@ -266,7 +355,10 @@ def _set_bwd(x, weight, route, valid, w13, w2, tm, dout, saved):
     dxs = gm.gmm(dhid, w13, tg, na, name="moe_up_dx", tm=tm,
                  transpose_rhs=True)
     dw13 = gm.tgmm(xs, dhid, tg, na, groups, name="moe_up_dw", tm=tm)
-    dx = _gather_sum(dxs, pos, valid).astype(x.dtype)
+    if sizes:
+        dx = _held_sum(dxs, route["order"], sizes, None, x.dtype)
+    else:
+        dx = _gather_sum(dxs, pos, valid).astype(x.dtype)
     return dx, dweight, dw13, dw2
 
 
@@ -275,19 +367,51 @@ def _set_bwd(x, weight, route, valid, w13, w2, tm, dout, saved):
 #: the expectation; twice it leaves the chunked path to real imbalance.
 CAPACITY_FACTOR = 2.0
 
+#: a prefix of the combine's prefix form has room for the expected number
+#: of its tokens and this many standard deviations (or CAPACITY_FACTOR
+#: times the expectation, if that is more)
+PREFIX_SIGMAS = 6.0
+
+
+def _prefix_sizes(tokens, top_k, groups, num_experts, tm):
+    """Rows of ``_held_sum``'s ``top_k`` prefixes: for rank ``j`` the
+    expected number of tokens with more than ``j`` of their ``top_k``
+    distinct choices among the ``groups`` held of ``num_experts``
+    (hypergeometric), with the margin above, in whole tiles, at most
+    ``tokens``; 0 where no token can have so many."""
+    pmf = [math.comb(groups, c) * math.comb(num_experts - groups, top_k - c)
+           / math.comb(num_experts, top_k) for c in range(top_k + 1)]
+    sizes = []
+    for j in range(top_k):
+        p = sum(pmf[j + 1:])
+        mean = tokens * p
+        rows = max(CAPACITY_FACTOR * mean,
+                   mean + PREFIX_SIGMAS * math.sqrt(mean * (1.0 - p)))
+        sizes.append(min(tokens, -(-int(math.ceil(rows)) // tm) * tm))
+    return tuple(sizes)
+
 
 def _plan(tokens, top_k, groups, num_experts, tm):
     """-> (tiles of the fast buffer, chunks of the second path or 0 when
-    the fast buffer already holds every pair)."""
+    the fast buffer already holds every pair, the combine's prefix sizes or
+    () for the k-slot form). The form is chosen from the shapes alone: the
+    prefix form where a second path exists to take its overflow and it
+    reads, its un-permute included, under three quarters of the k-slot
+    form's ``top_k x tokens`` rows. Where every expert is held every slot
+    is live, ``top_k`` rows a token is the floor of a gathered combine and
+    the k-slot form reads no more."""
     pairs = tokens * top_k
     expected = pairs * groups / float(num_experts)
     rows = min(pairs, int(math.ceil(CAPACITY_FACTOR * expected)))
     rows = -(-rows // tm) * tm
     if rows >= pairs:
-        return -(-pairs // tm) + groups, 0
+        return -(-pairs // tm) + groups, 0, ()
     chunks = next(c for c in range(-(-pairs // rows), tokens + 1)
                   if tokens % c == 0)
-    return rows // tm + groups, chunks
+    sizes = _prefix_sizes(tokens, top_k, groups, num_experts, tm)
+    if 4 * (sum(sizes) + tokens) > 3 * pairs:
+        sizes = ()
+    return rows // tm + groups, chunks, sizes
 
 
 def _chunk_tiles(tokens, chunks, top_k, groups, tm):
@@ -302,30 +426,43 @@ def _chunked(a, chunks):
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
 def moe_experts(x, weight, idx, w13, w2, expert_lo, num_experts, tm):
     """x [t, h], weight/idx [t, k] (the router's), w13 [g, h, 2f] (gate and
-    up side by side), w2 [g, f, h] -> (out [t, h], stats float32 [4]:
+    up side by side), w2 [g, f, h] -> (out [t, h], stats float32 [5]:
     pairs on held experts, largest expert load over the mean load, dropped
-    pairs, 1 if the fast buffer held them all)."""
+    pairs, 1 if the fast buffer held them all, the rows the combine
+    gathered over ``t x k``).
+
+    One branch point chooses the step's path from its counts: the chunked
+    path when the pairs overflow the fast buffer; else the fast buffer, its
+    combine in the prefix form (``_held_sum``) when ``_plan`` chose it for
+    these shapes and no prefix overflows, and in the k-slot form
+    (``_gather_sum``) otherwise. The three give the same bits."""
     return _experts_fwd(x, weight, idx, w13, w2, expert_lo, num_experts,
                         tm)[0]
 
 
+# the forward and the backward are jitted so that a program traces and
+# lowers their arms once, not once a layer and pass: tracing the kernels
+# was 30 s of laguna_pretrain_8k's 68 s of set-up on the chip's host, 44
+# with a third arm, and is 21 so (PERF.md section 6, PR 36)
+@functools.partial(jax.jit, static_argnums=(5, 6, 7))
 def _experts_fwd(x, weight, idx, w13, w2, expert_lo, num_experts, tm):
     t, k = idx.shape
     groups = w13.shape[0]
-    tiles, chunks = _plan(t, k, groups, num_experts, tm)
+    tiles, chunks, sizes = _plan(t, k, groups, num_experts, tm)
     local = idx - expert_lo
     valid = jnp.logical_and(local >= 0, local < groups)
     route = _route(local, valid, groups, tm, tiles)
     counts = route["counts"].astype(jnp.float32)
     total = jnp.sum(counts)
 
-    def fast(_):
-        out, saved = _set_fwd(x, weight, route, valid, w13, w2, tm)
-        return out, saved, route["dropped"]
+    def fast(prefixes):
+        out, saved = _set_fwd(x, weight, route, valid, w13, w2, tm,
+                              sizes=prefixes)
+        return out.astype(x.dtype), saved, route["dropped"]
 
     if not chunks:
-        out, saved, dropped = fast(None)
-        fits = jnp.ones((), bool)
+        out, saved, dropped = fast(())
+        path = jnp.ones((), jnp.int32)
     else:
         chunk_tiles = _chunk_tiles(t, chunks, k, groups, tm)
 
@@ -343,51 +480,65 @@ def _experts_fwd(x, weight, idx, w13, w2, expert_lo, num_experts, tm):
             empty = (jnp.zeros((rows, x.shape[1]), x.dtype),
                      jnp.zeros((rows, f2), x.dtype),
                      jnp.zeros((rows, x.shape[1]), x.dtype))
-            return out.reshape(t, -1), empty, jnp.sum(dropped)
+            return out.reshape(t, -1).astype(x.dtype), empty, \
+                jnp.sum(dropped)
 
-        fits = total <= (tiles - groups) * tm
-        out, saved, dropped = jax.lax.cond(fits, fast, slow, None)
+        # 0 the chunked path, 1 the fast buffer, 2 with the prefix form
+        path = (total <= (tiles - groups) * tm).astype(jnp.int32)
+        arms = [slow, lambda _: fast(())]
+        if sizes:
+            route["order"] = _held_order(valid, route["pos"], sizes)
+            path = path * (1 + route["order"]["fits"].astype(jnp.int32))
+            arms.append(lambda _: fast(sizes))
+        out, saved, dropped = jax.lax.switch(path, arms, None)
+    share = (sum(sizes) + t) / float(t * k) if sizes else 1.0
     stats = jnp.stack([total, jnp.max(counts) / jnp.maximum(
         total / groups, 1.0), dropped.astype(jnp.float32),
-        fits.astype(jnp.float32)])
-    res = (x, weight, local, valid, w13, w2, route, saved, fits)
-    return (out.astype(x.dtype), stats), res
+        (path > 0).astype(jnp.float32), jnp.where(path == 2, share, 1.0)])
+    res = (x, weight, local, valid, w13, w2, route, saved, path)
+    return (out, stats), res
 
 
 def _experts_bwd(expert_lo, num_experts, tm, res, cts):
-    x, weight, local, valid, w13, w2, route, saved, fits = res
-    dout = cts[0]
+    dx, dweight, dw13, dw2 = _experts_grads(expert_lo, num_experts, tm, res,
+                                            cts[0])
+    return dx, dweight, np.zeros(res[2].shape, jax.dtypes.float0), dw13, dw2
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1, 2))
+def _experts_grads(expert_lo, num_experts, tm, res, dout):
+    x, weight, local, valid, w13, w2, route, saved, path = res
     t, k = local.shape
     groups = w13.shape[0]
-    _, chunks = _plan(t, k, groups, num_experts, tm)
+    _, chunks, sizes = _plan(t, k, groups, num_experts, tm)
 
-    def fast(_):
-        return _set_bwd(x, weight, route, valid, w13, w2, tm, dout, saved)
+    def fast(prefixes):
+        return _set_bwd(x, weight, route, valid, w13, w2, tm, dout, saved,
+                        prefixes)
 
     if not chunks:
-        grads = fast(None)
-    else:
-        chunk_tiles = _chunk_tiles(t, chunks, k, groups, tm)
+        return fast(())
+    chunk_tiles = _chunk_tiles(t, chunks, k, groups, tm)
 
-        def slow(_):
-            def one(carry, c):
-                xc, wc, lc, vc, dc = c
-                rc = _route(lc, vc, groups, tm, chunk_tiles)
-                dx, dw, d13, d2 = _set_bwd(xc, wc, rc, vc, w13, w2, tm, dc,
-                                           None)
-                return (carry[0] + d13.astype(jnp.float32),
-                        carry[1] + d2.astype(jnp.float32)), (dx, dw)
-            zero = (jnp.zeros(w13.shape, jnp.float32),
-                    jnp.zeros(w2.shape, jnp.float32))
-            (d13, d2), (dx, dw) = jax.lax.scan(
-                one, zero, tuple(_chunked(a, chunks)
-                                 for a in (x, weight, local, valid, dout)))
-            return (dx.reshape(x.shape), dw.reshape(weight.shape),
-                    d13.astype(w13.dtype), d2.astype(w2.dtype))
+    def slow(_):
+        def one(carry, c):
+            xc, wc, lc, vc, dc = c
+            rc = _route(lc, vc, groups, tm, chunk_tiles)
+            dx, dw, d13, d2 = _set_bwd(xc, wc, rc, vc, w13, w2, tm, dc, None)
+            return (carry[0] + d13.astype(jnp.float32),
+                    carry[1] + d2.astype(jnp.float32)), (dx, dw)
+        zero = (jnp.zeros(w13.shape, jnp.float32),
+                jnp.zeros(w2.shape, jnp.float32))
+        (d13, d2), (dx, dw) = jax.lax.scan(
+            one, zero, tuple(_chunked(a, chunks)
+                             for a in (x, weight, local, valid, dout)))
+        return (dx.reshape(x.shape), dw.reshape(weight.shape),
+                d13.astype(w13.dtype), d2.astype(w2.dtype))
 
-        grads = jax.lax.cond(fits, fast, slow, None)
-    dx, dweight, dw13, dw2 = grads
-    return dx, dweight, np.zeros(local.shape, jax.dtypes.float0), dw13, dw2
+    arms = [slow, lambda _: fast(())]
+    if sizes:
+        arms.append(lambda _: fast(sizes))
+    return jax.lax.switch(path, arms, None)
 
 
 moe_experts.defvjp(_experts_fwd, _experts_bwd)

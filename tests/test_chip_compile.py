@@ -19,6 +19,7 @@ this one file: a second file could land on another worker.
 """
 
 import importlib
+import re
 
 import numpy as np
 import pytest
@@ -221,6 +222,37 @@ def test_grouped_products_compile_for_v5e(one_chip, mosaic, name):
                            tn=how[1])
     text = jax.jit(fn).lower(s(a), s(b), *tables).compile().as_text()
     assert text.count(KERNEL) == 1 and name in text
+
+
+def test_the_expert_layer_compiles_for_v5e_with_its_three_arms(one_chip,
+                                                             mosaic):
+    """``laguna_pretrain_8k``'s expert layer, forward and backward at the
+    cell's shapes (16384 tokens, 8 choices of 256 experts, 32 held): the one
+    branch point is a conditional of three arms in each direction (the
+    chunks, the fast buffer with the k-slot combine, with the prefix form)
+    around the six grouped products."""
+    from paddle_tpu.ops import decoder_ops as dops
+    t, h, f, k, experts = 16384, 2048, 512, 8, 256
+    assert dops._plan(t, k, HELD, experts, gm.TILE_M) == (
+        ROWS // gm.TILE_M, 4, (16384, 8704, 2176, 384, 128, 128, 128, 128))
+
+    def s(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def layer(x, weight, idx, w13, w2, ct):
+        def loss(x, weight, w13, w2):
+            out, _ = dops.moe_experts(x, weight, idx, w13, w2, 0, experts,
+                                      gm.TILE_M)
+            return jnp.sum(out.astype(jnp.float32) * ct.astype(jnp.float32))
+        return jax.grad(loss, argnums=(0, 1, 2, 3))(x, weight, w13, w2)
+    text = jax.jit(layer).lower(
+        s((t, h)), s((t, k)), s((t, k), jnp.int32), s((HELD, h, 2 * f)),
+        s((HELD, f, h)), s((t, h))).compile().as_text()
+    arms = [len(b.split(",")) for b in
+            re.findall(r"branch_computations=\{([^}]*)\}", text)]
+    assert arms == [3, 3]
+    for name in GROUPED:
+        assert name in text, name
 
 
 # the serving pool at gpt2-1p3b width: 32 slots, h16 d128, 16-row

@@ -131,6 +131,19 @@ def test_loss_matches_the_reference_and_nothing_is_dropped(
     assert all(s["dropped_pairs"] == 0 for s in r["stats"].values())
 
 
+def test_the_model_reports_the_combines_rows(against_reference):
+    """Two choices a token leave the prefix form nothing to save at the toy
+    size: every layer reads the k-slot form's 1.0, in ``moe_stats()`` and
+    as ``STAT_moe_combine_rows_permille_l<i>``."""
+    from paddle_tpu import monitor
+    stats = against_reference["stats"]
+    assert all(s["fast_path"] and s["combine_rows_share"] == 1.0
+               for s in stats.values())
+    published = monitor.stats_with_prefix("STAT_moe_combine_rows_permille")
+    assert published == {f"STAT_moe_combine_rows_permille_l{i}": 1000
+                         for i in stats}
+
+
 @pytest.mark.parametrize("name", GRAD_NAMES)
 def test_gradient_matches_the_reference(against_reference, name):
     got, want = (against_reference[k][name] for k in ("grads", "want_grads"))
@@ -288,29 +301,46 @@ def dense_experts(x, weight, idx, w13, w2, lo):
     return out
 
 
-def test_no_drop_and_no_compile_whatever_the_routing():
+@pytest.mark.parametrize("t,k,lo,held,experts,paths", [
+    (64, 2, 2, 2, 8, {"uniform": (True, 1.0), "one held expert": (True, 1.0),
+                      "every choice held": (False, 1.0)}),
+    (128, 4, 3, 4, 32, {"uniform": (True, (112 + 32 + 8 + 8 + 128) / 512),
+                        "one held expert": (True, 1.0),
+                        "every choice held": (False, 1.0)})],
+    ids=["k-slot", "prefix"])
+def test_no_drop_and_no_compile_whatever_the_routing(t, k, lo, held, experts,
+                                                     paths):
     """All tokens on one held expert, all choices on held experts (more
     pairs than the fast buffer holds: the chunked path) and a uniform
-    routing give the dense result, 0 dropped, through one compilation."""
-    t, h, f, k, lo, held, experts = 64, 32, 16, 2, 2, 2, 8
+    routing give the dense result, 0 dropped, through one compilation. At
+    the second shape ``_plan`` chooses the combine's prefix form: the
+    uniform routing runs it, every token on one held expert overflows the
+    first prefix (112 rows for 128 tokens) inside a buffer that still holds
+    the pairs and takes the fast buffer with the k-slot combine, every
+    choice held overflows both and takes the chunks."""
+    h, f = 32, 16
     r = np.random.RandomState(0)
     x = jnp.asarray(r.randn(t, h), jnp.float32)
     w13 = jnp.asarray(r.randn(held, h, 2 * f) * 0.2, jnp.float32)
     w2 = jnp.asarray(r.randn(held, f, h) * 0.2, jnp.float32)
     weight = jnp.asarray(r.rand(t, k) + 0.5, jnp.float32)
+    absent = [e for e in range(experts) if not lo <= e < lo + held]
     routings = {
         "uniform": np.stack([r.permutation(experts)[:k] for _ in range(t)]),
-        "one held expert": np.stack([np.full(t, lo + 1),
-                                     r.randint(4, 8, t)], axis=1),
-        "every choice held": np.tile([lo, lo + 1], (t, 1)),
+        "one held expert": np.stack(
+            [np.concatenate([[lo + 1], r.permutation(absent)[:k - 1]])
+             for _ in range(t)]),
+        "every choice held": np.tile(
+            np.arange(lo, lo + k) if held >= k else [lo, lo + 1], (t, 1)),
     }
+    assert bool(dops._plan(t, k, held, experts, 8)[2]) == (k == 4)
     fn = jax.jit(lambda x, weight, idx, w13, w2: dops.moe_experts(
         x, weight, idx, w13, w2, lo, experts, 8))
     grad = jax.jit(jax.grad(
         lambda x, weight, idx, w13, w2: jnp.sum(dops.moe_experts(
             x, weight, idx, w13, w2, lo, experts, 8)[0] ** 2),
         argnums=(0, 1, 3, 4)))
-    fast = {}
+    got_paths = {}
     for name, idx in routings.items():
         idx = jnp.asarray(idx, jnp.int32)
         with jax.default_matmul_precision("highest"):
@@ -322,15 +352,147 @@ def test_no_drop_and_no_compile_whatever_the_routing():
                     x, weight, idx, w13, w2, lo) ** 2),
                 argnums=(0, 1, 2, 3))(x, weight, w13, w2)
         np.testing.assert_allclose(out, want, atol=1e-5, err_msg=name)
-        for a, b in zip(got, ref_grad):
-            np.testing.assert_allclose(a, b, atol=1e-4, err_msg=name)
+        for a, b in zip(got, ref_grad):     # 128 rows on one expert: ~2e2
+            np.testing.assert_allclose(a, b, atol=1e-4, rtol=2e-6,
+                                       err_msg=name)
         held_pairs = int(np.sum((np.asarray(idx) >= lo)
                                 & (np.asarray(idx) < lo + held)))
         assert int(stats[0]) == held_pairs and int(stats[2]) == 0, name
-        fast[name] = bool(stats[3])
-    assert fast == {"uniform": True, "one held expert": True,
-                    "every choice held": False}
+        got_paths[name] = (bool(stats[3]), pytest.approx(float(stats[4])))
+    assert got_paths == paths
     assert fn._cache_size() == 1 and grad._cache_size() == 1
+
+
+@pytest.fixture(scope="module", params=[0, 1, 2])
+def both_combines(request):
+    """The expert layer and its four gradients on one random routing at a
+    share of 4 of 32 experts, 4 choices a token, through the combine's
+    prefix form (what ``_plan`` chooses there) and through the k-slot form
+    (``_plan``'s choice overridden)."""
+    t, h, f, k, lo, held, experts, tm = 256, 32, 16, 4, 8, 4, 32, 8
+    r = np.random.RandomState(request.param)
+    x = jnp.asarray(r.randn(t, h), jnp.float32)
+    w13 = jnp.asarray(r.randn(held, h, 2 * f) * 0.2, jnp.float32)
+    w2 = jnp.asarray(r.randn(held, f, h) * 0.2, jnp.float32)
+    weight = jnp.asarray(r.rand(t, k) + 0.5, jnp.float32)
+    # some rows of zeros under negative weights: the terms are -0.0, and
+    # the sign of a zero is held to the k-slot form's too
+    x = x.at[-64:].set(0.0)
+    weight = weight.at[-64:].multiply(-1.0)
+    ct = jnp.asarray(r.randn(t, h), jnp.float32)
+    idx = jnp.asarray(np.stack([r.permutation(experts)[:k]
+                                for _ in range(t)]), jnp.int32)
+    plan = dops._plan
+    assert plan(t, k, held, experts, tm) == (36, 4, (224, 48, 8, 8))
+
+    def run():                  # jit keys its cache by the function: two
+        def layer(x, weight, w13, w2):
+            return dops.moe_experts(x, weight, idx, w13, w2, lo, experts, tm)
+        (out, stats), vjp = jax.vjp(layer, x, weight, w13, w2)
+        return (out, *vjp((ct, jnp.zeros_like(stats)))), stats
+    forms = {"prefix": jax.jit(lambda: run())()}
+    dops._plan = lambda *a: plan(*a)[:2] + ((),)
+    try:                        # the layer's own jits know the shapes only
+        jax.clear_caches()
+        forms["k-slot"] = jax.jit(lambda: run())()
+    finally:
+        dops._plan = plan
+        jax.clear_caches()
+    return forms
+
+
+@pytest.mark.parametrize("which", range(5),
+                         ids=["out", "dx", "dweight", "dw13", "dw2"])
+def test_the_prefix_form_gives_the_k_slot_forms_bits(both_combines, which):
+    """Skipping an absent slot leaves out a ``+ 0.0`` and the held choices
+    keep their order: not close, equal."""
+    (got, stats), (want, base) = (both_combines[f] for f in ("prefix",
+                                                            "k-slot"))
+    assert np.asarray(want[which]).any()
+    assert np.asarray(got[which]).tobytes() == \
+        np.asarray(want[which]).tobytes()
+    assert stats[3] == 1 and base[3] == 1
+    assert float(stats[4]) == (224 + 48 + 8 + 8 + 256) / 1024
+    assert float(base[4]) == 1.0
+
+
+def test_the_held_order_is_a_stable_sort_and_its_prefixes_hold_the_ranks():
+    """``_held_order`` against numpy: the tokens by their number of held
+    choices, most first, ties in token order; ``inv`` undoes ``perm``; the
+    compacted rows are each token's held rows in slot order; ``fits`` says
+    whether every rank's tokens are within its prefix."""
+    t, k = 512, 4
+    r = np.random.RandomState(4)
+    valid = r.rand(t, k) < 0.3
+    pos = r.randint(0, 1000, (t, k)).astype(np.int32)
+    held = valid.sum(1)
+    more = [int((held > j).sum()) for j in range(k)]
+    order = dops._held_order(jnp.asarray(valid), jnp.asarray(pos), more)
+    perm = np.asarray(order["perm"])
+    np.testing.assert_array_equal(perm, np.argsort(-held, kind="stable"))
+    np.testing.assert_array_equal(np.asarray(order["inv"])[perm],
+                                  np.arange(t))
+    np.testing.assert_array_equal(order["held"], held[perm])
+    for j in range(k):          # rank j's prefix: the tokens with a j-th
+        assert np.all(held[perm[:more[j]]] > j)
+        np.testing.assert_array_equal(
+            np.asarray(order["pos"])[:more[j], j],
+            [pos[tok][valid[tok]][j] for tok in perm[:more[j]]])
+    assert bool(order["fits"])
+    for j in range(k):
+        short = list(more)
+        short[j] -= 1
+        assert not bool(dops._held_order(jnp.asarray(valid),
+                                         jnp.asarray(pos), short)["fits"])
+
+
+def _row_gathers(jaxpr, shape):
+    """Gathers of ``shape`` anywhere in a jaxpr, and its branch points
+    (the kernels' own ``pl.when`` apart)."""
+    gathers, branches = 0, []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            continue
+        if eqn.primitive.name == "gather" \
+                and eqn.outvars[0].aval.shape == shape:
+            gathers += 1
+        if eqn.primitive.name == "cond":
+            branches.append(len(eqn.params["branches"]))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            g, b = _row_gathers(sub, shape)
+            gathers, branches = gathers + g, branches + b
+    return gathers, branches
+
+
+@pytest.mark.parametrize("form", ["whole", "decode", "share"])
+def test_where_every_expert_is_held_the_combine_is_todays_k_gathers(form):
+    """Mellum's prompt pass and the whole Laguna model hold every expert,
+    and so does a decode step: ``_plan`` keeps the k-slot form (k gathers of
+    ``[t, h]``, no branch point at all). A share small enough gets the one
+    switch of three arms: the chunks, the fast buffer with k gathers, the
+    fast buffer with a gather a prefix and the un-permute."""
+    t, h, f, k, experts, tm = 96, 32, 16, 4, 32, 8
+    held = 4 if form == "share" else experts
+    x = jnp.zeros((t, h), jnp.float32)
+    weight = jnp.zeros((t, k), jnp.float32)
+    idx = jnp.zeros((t, k), jnp.int32)
+    w13 = jnp.zeros((held, h, 2 * f), jnp.float32)
+    w2 = jnp.zeros((held, f, h), jnp.float32)
+    if form == "decode":
+        jaxpr = jax.make_jaxpr(dops.moe_experts_decode)(x, weight, idx, w13,
+                                                        w2)
+    else:
+        jaxpr = jax.make_jaxpr(lambda *a: dops.moe_experts(
+            *a, 0, experts, tm))(x, weight, idx, w13, w2)
+    gathers, branches = _row_gathers(jaxpr.jaxpr, (t, h))
+    sizes = dops._plan(t, k, held, experts, tm)[2]
+    if form == "share":
+        assert sizes == (88, 24, 8, 8)
+        # two arms with k gathers of [t, h] (the chunks gather [t / 4, h]);
+        # the prefix arm's one [t, h] gather is its un-permute
+        assert (gathers, branches) == (k + 1, [3])
+    else:
+        assert sizes == () and (gathers, branches) == (k, [])
 
 
 @pytest.mark.parametrize("pairs", [1024, 96], ids=["blocked", "plain"])
