@@ -11,14 +11,21 @@ exports:
   built where the harness calls it (inside ``weights.recording``);
 - ``train_job(cfg, job)`` -> ``(model, optimizer, train function,
   retain_grads)`` for a configuration and a training job file;
-- ``forward(params, ids, cfg)`` -> logits and ``loss(params, ids, labels,
-  cfg)``: the plain reference, float32 at ``highest``, weights keyed by the
-  program's parameter names;
+- ``hidden(params, ids, cfg)`` -> the final normed hidden state
+  ``[b, s, h]`` and ``head(params, cfg)`` -> the output matrix
+  ``[h, vocabulary]`` (the tied embedding transposed where the head is
+  tied): the plain reference in its two halves, float32 at ``highest``,
+  weights keyed by the program's parameter names. The serving check
+  multiplies them a block of rows at a time (``serve.deficits_fn``), so its
+  memory does not grow with ``max_len x vocabulary``;
+- ``forward(params, ids, cfg)`` -> logits, their product whole (tests and
+  ``study/`` compare whole logits at sizes that hold them), and
+  ``loss(params, ids, labels, cfg)``;
 - ``train_flops_per_token(cfg, seq)``: model FLOPs of one trained token
   (for a model with experts, of the experts a token is routed to);
 - ``kernel_counts(name, cfg, job)`` -> ``(flops, bytes)`` of ONE call of
-  the named kernel at the cell's shapes, or ``None`` for a kernel the
-  family has no count for.
+  the named kernel at the cell's shapes (a training job's or a serving
+  job's), or ``None`` for a kernel the family has no count for.
 
 The runners reach the architecture only through these, so a second
 architecture is ``families/<name>``, ``configs/<name>.json``, its twin
@@ -31,8 +38,8 @@ import importlib
 import pkgutil
 
 DEFAULT = "gpt2"
-EXPORTS = ("model_config", "serving_model", "train_job", "forward", "loss",
-           "train_flops_per_token", "kernel_counts")
+EXPORTS = ("model_config", "serving_model", "train_job", "hidden", "head",
+           "forward", "loss", "train_flops_per_token", "kernel_counts")
 
 
 def known() -> list:

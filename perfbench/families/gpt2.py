@@ -65,6 +65,15 @@ def _shape(cfg: dict) -> dict:
                 vocab_size=cfg["program"]["vocab_rows"])
 
 
+def hidden(params: dict, ids, cfg: dict):
+    return reference.hidden(params, ids, num_layers=cfg["n_layer"],
+                            num_heads=cfg["n_head"])
+
+
+def head(params: dict, cfg: dict):
+    return reference.head(params, vocab_size=cfg["program"]["vocab_rows"])
+
+
 def forward(params: dict, ids, cfg: dict):
     return reference.forward(params, ids, **_shape(cfg))
 

@@ -34,8 +34,9 @@ reason), ``deployment``, ``engine`` / ``engine_why`` (the harness's
 ``final_norm_init`` (mean and std of the recurrence's leaves and of the
 final norm's gain; see ``assumed``), ``tokens_a_dispatch``.
 
-**The plain reference** (``forward`` / ``loss``): the equations above in
-``jax.numpy``, float32 under ``jax.default_matmul_precision("highest")``,
+**The plain reference** (``hidden`` x ``head`` = ``forward``; ``loss``):
+the equations above in ``jax.numpy``, float32 under
+``jax.default_matmul_precision("highest")``,
 no cache, no kernel, no batching; weights keyed by the program's parameter
 names (linear weights ``[in, out]``; ``attn.qkv_proj`` holds the 20 query
 heads, then K, then V along its output axis; ``mlp.gate_up`` gate first)
@@ -186,11 +187,11 @@ def _mamba(u, params: dict, pre: str, cfg: dict, keep=None):
     return (y * jax.nn.silu(z)) @ _f32(params[pre + "out_proj.weight"])
 
 
-def forward(params: dict, ids, cfg: dict, collect=None, keep=None):
-    """``ids`` int [b, s] -> logits float32 [b, s, vocab], one request at a
-    time. ``collect``, a list, receives the hidden state after every layer;
-    ``keep``, a dict with a row ``"at"``, every Mamba layer's state at
-    that row (of the last request)."""
+def hidden(params: dict, ids, cfg: dict, collect=None, keep=None):
+    """``ids`` int [b, s] -> the final normed hidden state float32
+    [b, s, h], one request at a time. ``collect``, a list, receives the
+    hidden state after every layer; ``keep``, a dict with a row ``"at"``,
+    every Mamba layer's state at that row (of the last request)."""
     with jax.default_matmul_precision("highest"):
         hq, kv, d = (cfg["num_attention_heads"], cfg["num_key_value_heads"],
                      _head_dim(cfg))
@@ -221,12 +222,24 @@ def forward(params: dict, ids, cfg: dict, collect=None, keep=None):
                     @ _f32(params[pre + "mlp.down.weight"])
                 if collect is not None:
                     kept.append(x)
-            x = _rms(x, params["model.norm.weight"], eps)
-            outs.append(x @ _f32(params["model.embed.weight"]).T)
+            outs.append(_rms(x, params["model.norm.weight"], eps))
         if collect is not None:
             collect.extend(jnp.stack(kept[i::n_layers])
                            for i in range(n_layers))
         return jnp.stack(outs)
+
+
+def head(params: dict, cfg: dict):
+    """The output matrix float32 [h, vocab]: the embedding, tied,
+    transposed."""
+    return _f32(params["model.embed.weight"]).T
+
+
+def forward(params: dict, ids, cfg: dict, collect=None, keep=None):
+    """``ids`` int [b, s] -> logits float32 [b, s, vocab]: :func:`hidden`
+    times :func:`head`."""
+    with jax.default_matmul_precision("highest"):
+        return hidden(params, ids, cfg, collect, keep) @ head(params, cfg)
 
 
 def loss(params: dict, ids, labels, cfg: dict):

@@ -35,8 +35,9 @@ out of the sum, absent heads are not computed, the loss is the
 cross-entropy over the held vocabulary rows. Nothing stands in for the
 absent chips.
 
-**The plain reference** (``forward`` / ``loss``): ``jax.numpy`` float32 at
-``highest`` precision, no kernels, a loop over the held experts with a mask
+**The plain reference** (``hidden`` x ``head`` = ``forward``; ``loss``):
+``jax.numpy`` float32 at ``highest`` precision, no kernels, a loop over
+the held experts with a mask
 of the tokens routed to each, attention computed in query blocks so that a
 row of 8192 fits. Departures from the published model, shared with the
 program and listed in the configuration's ``assumed``: SiLU (the config has
@@ -143,8 +144,9 @@ def model_config(cfg: dict):
 
 def serving_model(cfg: dict):
     raise SystemExit(
-        "the laguna family has no serving model: the engine is bound to "
-        "models/gpt.py (PERF.md, Open questions)")
+        "the laguna family has no serving model: no cell serves it (the "
+        "engine reads a model through serving/seam.py; the same decoder "
+        "is served as the mellum family)")
 
 
 def train_job(cfg: dict, job: dict):
@@ -266,9 +268,10 @@ def _experts(u, router, w13, w2, cfg: dict):
     return out
 
 
-def forward(params: dict, ids, cfg: dict, collect=None):
-    """``ids`` int [b, s] -> logits float32 [b, s, held vocabulary rows].
-    ``collect``, a list, receives the hidden state after every layer."""
+def hidden(params: dict, ids, cfg: dict, collect=None):
+    """``ids`` int [b, s] -> the final normed hidden state float32
+    [b, s, h]. ``collect``, a list, receives the hidden state after every
+    layer."""
     with jax.default_matmul_precision("highest"):
         p = {k: jnp.asarray(v, jnp.float32) for k, v in params.items()}
         _, (klo, khi), (vlo, vhi) = _held(cfg)
@@ -306,8 +309,19 @@ def forward(params: dict, ids, cfg: dict, collect=None):
                               p[pre + "moe.shared.down.weight"])
             if collect is not None:
                 collect.append(x)
-        x = _rms(x, p["model.norm.weight"], eps)
-        return x @ p["lm_head.weight"]
+        return _rms(x, p["model.norm.weight"], eps)
+
+
+def head(params: dict, cfg: dict):
+    """The output matrix float32 [h, held vocabulary rows]."""
+    return jnp.asarray(params["lm_head.weight"], jnp.float32)
+
+
+def forward(params: dict, ids, cfg: dict, collect=None):
+    """``ids`` int [b, s] -> logits float32 [b, s, held vocabulary rows]:
+    :func:`hidden` times :func:`head`."""
+    with jax.default_matmul_precision("highest"):
+        return hidden(params, ids, cfg, collect) @ head(params, cfg)
 
 
 def loss(params: dict, ids, labels, cfg: dict):

@@ -16,8 +16,9 @@ call), and the keys that say how the program runs it: ``dtype`` (the served
 precision), ``embed_init_std`` / ``router_init_std`` (see ``assumed``), and
 for a toy twin ``moe_tile_m`` / ``moe_chunk_rows``.
 
-**The plain reference** (``forward`` / ``loss``): the equations of ISSUE 31
-in ``jax.numpy``, float32 under ``jax.default_matmul_precision("highest")``,
+**The plain reference** (``hidden`` x ``head`` = ``forward``; ``loss``):
+the equations of ISSUE 31 in ``jax.numpy``, float32 under
+``jax.default_matmul_precision("highest")``,
 no cache, no kernels, no batching; weights keyed by the program's parameter
 names (linear weights ``[in, out]``; ``qkv_proj`` holds the 32 query heads,
 then the 4 K heads, then V along its output axis; ``experts_gate_up`` is
@@ -259,10 +260,10 @@ def _experts(u, router, w13, w2, top_k: int):
     return out
 
 
-def forward(params: dict, ids, cfg: dict, collect=None):
-    """``ids`` int [b, s] -> logits float32 [b, s, vocab], one request at a
-    time. ``collect``, a list, receives the hidden state after every
-    layer."""
+def hidden(params: dict, ids, cfg: dict, collect=None):
+    """``ids`` int [b, s] -> the final normed hidden state float32
+    [b, s, h], one request at a time. ``collect``, a list, receives the
+    hidden state after every layer."""
     with jax.default_matmul_precision("highest"):
         hq, kv, d = (cfg["num_attention_heads"], cfg["num_key_value_heads"],
                      cfg["head_dim"])
@@ -295,12 +296,23 @@ def forward(params: dict, ids, cfg: dict, collect=None):
                                  cfg["num_experts_per_tok"])
                 if collect is not None:
                     kept.append(x)
-            x = _rms(x, params["model.norm.weight"], eps)
-            outs.append(x @ _f32(params["lm_head.weight"]))
+            outs.append(_rms(x, params["model.norm.weight"], eps))
         if collect is not None:
             n = cfg["num_hidden_layers"]
             collect.extend(jnp.stack(kept[i::n]) for i in range(n))
         return jnp.stack(outs)
+
+
+def head(params: dict, cfg: dict):
+    """The output matrix float32 [h, vocab] (untied)."""
+    return _f32(params["lm_head.weight"])
+
+
+def forward(params: dict, ids, cfg: dict, collect=None):
+    """``ids`` int [b, s] -> logits float32 [b, s, vocab]: :func:`hidden`
+    times :func:`head`."""
+    with jax.default_matmul_precision("highest"):
+        return hidden(params, ids, cfg, collect) @ head(params, cfg)
 
 
 def loss(params: dict, ids, labels, cfg: dict):

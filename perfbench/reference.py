@@ -32,9 +32,9 @@ def _gelu_tanh(x):
         0.7978845608028654 * (x + 0.044715 * x ** 3)))
 
 
-def forward(params: dict, ids, *, num_layers: int, num_heads: int,
-            vocab_size: int):
-    """``ids`` int [b, s] -> logits float32 [b, s, vocab_size]."""
+def hidden(params: dict, ids, *, num_layers: int, num_heads: int):
+    """``ids`` int [b, s] -> the final normed hidden state float32
+    [b, s, h], which :func:`head` turns into logits."""
     with jax.default_matmul_precision("highest"):
         p = {k: jnp.asarray(v, jnp.float32) for k, v in params.items()}
         b, s = ids.shape
@@ -59,8 +59,22 @@ def forward(params: dict, ids, *, num_layers: int, num_heads: int,
             y = _ln(x, p[pre + "ln2.weight"], p[pre + "ln2.bias"])
             y = _gelu_tanh(y @ p[pre + "fc1.weight"] + p[pre + "fc1.bias"])
             x = x + y @ p[pre + "fc2.weight"] + p[pre + "fc2.bias"]
-        x = _ln(x, p["gpt.ln_f.weight"], p["gpt.ln_f.bias"])
-        return (x @ p["gpt.wte.weight"].T)[:, :, :vocab_size]
+        return _ln(x, p["gpt.ln_f.weight"], p["gpt.ln_f.bias"])
+
+
+def head(params: dict, *, vocab_size: int):
+    """The output matrix float32 [h, vocab_size]: the embedding, tied,
+    transposed and cut to the vocabulary's rows."""
+    wte = jnp.asarray(params["gpt.wte.weight"], jnp.float32)
+    return wte.T[:, :vocab_size]
+
+
+def forward(params: dict, ids, *, num_layers: int, num_heads: int,
+            vocab_size: int):
+    """``ids`` int [b, s] -> logits float32 [b, s, vocab_size]."""
+    with jax.default_matmul_precision("highest"):
+        x = hidden(params, ids, num_layers=num_layers, num_heads=num_heads)
+        return x @ head(params, vocab_size=vocab_size)
 
 
 def loss(params: dict, ids, labels, **cfg):

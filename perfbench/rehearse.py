@@ -29,6 +29,18 @@ COUNTS_AND_CORRECTNESS = (
     "check_loss_diff", "steps_in_window", "wrong_length_requests")
 
 
+def run_twin(bench: dict, cell: dict, args) -> dict:
+    """One run of ``cell``'s twin -> the result line, values and all: the
+    same cell name, the family's toy configuration, the toy traffic."""
+    from perfbench import families, run as harness
+    entry = next(c for c in bench["configs"] if c["name"] == cell["config"])
+    family = families.name_of(harness.load_json(ROOT, entry["file"]))
+    bench["configs"] = [{"name": cell["config"],
+                         "file": f"perfbench/rehearsal/{family}-tiny.json"}]
+    return harness.run_cell(bench, args, rehearsal=True,
+                            traffic_dir="rehearsal")
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
@@ -37,20 +49,14 @@ def main(argv=None) -> int:
     ap.add_argument("--trace", type=int, default=0, choices=(0, 1))
     args = ap.parse_args(argv)
     sys.path.insert(0, ROOT)
-    from perfbench import families, run as harness
+    from perfbench import run as harness
     bench = harness.load_json(ROOT, "BENCHMARK.json")
     cell = harness.find_cell(bench, args.workload)
     if int(cell["chips"]) > 1 and os.environ.get("JAX_PLATFORMS") == "cpu":
         os.environ["XLA_FLAGS"] = (
             os.environ.get("XLA_FLAGS", "")
             + f" --xla_force_host_platform_device_count={cell['chips']}")
-    # the twin: same cell name, the family's toy configuration, toy traffic
-    entry = next(c for c in bench["configs"] if c["name"] == cell["config"])
-    family = families.name_of(harness.load_json(ROOT, entry["file"]))
-    bench["configs"] = [{"name": cell["config"],
-                         "file": f"perfbench/rehearsal/{family}-tiny.json"}]
-    line = harness.run_cell(bench, args, rehearsal=True,
-                            traffic_dir="rehearsal")
+    line = run_twin(bench, cell, args)
     dev = line["device"]
     print(f"rehearsal of {args.workload} ran on {dev['count']} x "
           f"{dev['platform']} ({dev['kind']})", flush=True)

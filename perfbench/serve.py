@@ -40,6 +40,12 @@ SAMPLE_REQUESTS = 8     # completed requests checked against the reference
 #: 0.015. What the tolerance can and cannot tell apart, with the runs
 #: behind each statement (study/check_power.py): study/correctness.md.
 LOGIT_TOLERANCE = 0.05
+#: Rows of logits the check holds at once: it takes the reference's hidden
+#: state times its head this many rows at a time and keeps each row's best
+#: logit and the emitted token's, so its memory is CHECK_ROWS x vocabulary
+#: x 4 bytes whatever ``max_len`` is (0.63 GB at 154,880 rows of
+#: vocabulary, where ``max_len`` 16384 at once would be 10.15 GB).
+CHECK_ROWS = 1024
 
 
 class Stream:
@@ -429,15 +435,51 @@ def queue_waits(fol: Follower, lo: float, hi: float) -> List[float]:
     return out
 
 
+def block_deficits(x, w, nxt, rows_a_block: int):
+    """``x`` [rows, h] times ``w`` [h, vocabulary], ``rows_a_block`` rows at
+    a time (the last block padded up) -> float32 [rows]: each row's best
+    logit less its logit of ``nxt``. The logits are never held whole."""
+    import jax
+    import jax.numpy as jnp
+    rows, width = x.shape
+    block = min(rows_a_block, rows)
+    fill = -rows % block
+    xs = jnp.pad(x, ((0, fill), (0, 0))).reshape(-1, block, width)
+    ns = jnp.pad(nxt, (0, fill)).reshape(-1, block)
+
+    def one(block_of):
+        xb, nb = block_of
+        with jax.default_matmul_precision("highest"):
+            logits = xb @ w
+        got = jnp.take_along_axis(logits, nb[:, None], axis=-1)[:, 0]
+        return jnp.max(logits, axis=-1) - got
+    return jax.lax.map(one, (xs, ns)).reshape(-1)[:rows]
+
+
+def deficits_fn(family, cfg: dict, rows_a_block: Optional[int] = None):
+    """The check's one program: jitted ``(params, ids [1, rows], nxt
+    [rows]) -> float32 [rows]``, by how much the reference's logit of
+    ``nxt[i]`` at row ``i`` lies below that row's best: the family's
+    ``hidden`` times its ``head``, ``rows_a_block`` (``CHECK_ROWS``) rows at
+    a time. A row's logits do not depend on the rows beside it."""
+    import jax
+    rows_a_block = rows_a_block or CHECK_ROWS
+
+    @jax.jit
+    def deficits(params, ids, nxt):
+        return block_deficits(family.hidden(params, ids, cfg)[0],
+                              family.head(params, cfg), nxt, rows_a_block)
+    return deficits
+
+
 def check(model, engine, cfg, fol: Follower, seed: int, lo: float,
           hi: float) -> dict:
     """Outside the window: a seeded sample of completed requests against
     the plain reference, teacher-forced on the engine's own tokens; every
     request the schedule fixed in length has that length; no block leaks."""
-    import jax
     import jax.numpy as jnp
     from . import families
-    family = families.load(cfg)
+    deficits = deficits_fn(families.load(cfg), cfg)
     done = [s for s in fol.done if s.req.state == "done"
             and s.finished is not None and lo <= s.finished <= hi]
     notes = {}
@@ -449,13 +491,6 @@ def check(model, engine, cfg, fol: Follower, seed: int, lo: float,
         notes["wrong_length_requests"] = short[:5]
     pad = int(cfg["engine"]["max_len"])
     params = {n: p.value for n, p in model.named_parameters()}
-
-    @jax.jit
-    def deficits(params, ids, nxt):
-        logits = family.forward(params, ids, cfg)[0]
-        got = jnp.take_along_axis(logits, nxt[:, None], axis=-1)[:, 0]
-        return jnp.max(logits, axis=-1) - got
-
     rng = np.random.default_rng([int(seed), 9])
     picks = rng.choice(len(done), size=min(SAMPLE_REQUESTS, len(done)),
                        replace=False) if done else []

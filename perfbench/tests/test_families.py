@@ -63,15 +63,13 @@ def test_gpt2_train_flops_per_token_is_flops_py_on_the_files_numbers(
 
 # ---- a second family, reached with no edit to run.py / serve.py / readers.py
 
-def fixture_family(monkeypatch, name, forward=None):
+def fixture_family(monkeypatch, name, **replaced):
     """A family module under ``name`` that builds the program's gpt2-tiny;
-    its reference is GPT-2's unless ``forward`` replaces it."""
+    its reference is GPT-2's but for the exports ``replaced``."""
     gpt2 = importlib.import_module("perfbench.families.gpt2")
     mod = types.ModuleType("perfbench.families." + name)
     for export in families.EXPORTS:
-        setattr(mod, export, getattr(gpt2, export))
-    if forward is not None:
-        mod.forward = forward
+        setattr(mod, export, replaced.get(export, getattr(gpt2, export)))
     monkeypatch.setitem(sys.modules, mod.__name__, mod)
     return mod
 
@@ -92,13 +90,22 @@ def rehearse(tmp_path, family, workload="docs_offline"):
                             traffic_dir="rehearsal")
 
 
-def off_by_one(params, ids, cfg):
-    """GPT-2's logits with one unit added to every odd token: a reference
-    that is wrong by one logit unit where the engine emitted an even one."""
+def hidden_and_one(params, ids, cfg):
+    """GPT-2's hidden state with one more feature, a constant 1 ..."""
     import jax.numpy as jnp
     from perfbench.families import gpt2
-    logits = gpt2.forward(params, ids, cfg)
-    return logits + (jnp.arange(logits.shape[-1]) % 2).astype(logits.dtype)
+    x = gpt2.hidden(params, ids, cfg)
+    return jnp.concatenate([x, jnp.ones_like(x[..., :1])], axis=-1)
+
+
+def head_and_odd(params, cfg):
+    """... which this head weighs 1 for every odd token: a reference that is
+    wrong by one logit unit where the engine emitted an even one."""
+    import jax.numpy as jnp
+    from perfbench.families import gpt2
+    w = gpt2.head(params, cfg)
+    odd = (jnp.arange(w.shape[1]) % 2).astype(w.dtype)
+    return jnp.concatenate([w, odd[None]], axis=0)
 
 
 def test_the_check_goes_through_the_family_named_true_reference(
@@ -117,7 +124,8 @@ def test_the_check_goes_through_the_family_named_true_reference(
 
 def test_the_check_goes_through_the_family_named_wrong_reference(
         monkeypatch, tmp_path):
-    fixture_family(monkeypatch, "fixture_off_by_one", forward=off_by_one)
+    fixture_family(monkeypatch, "fixture_off_by_one", hidden=hidden_and_one,
+                   head=head_and_odd)
     line = rehearse(tmp_path, "fixture_off_by_one")
     assert line["correct"] is False
     # the best odd token gained a unit over the even token emitted
