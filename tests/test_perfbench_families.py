@@ -1,7 +1,9 @@
-"""The benchmark's family seam, where the driver counts it: the checks of
-``perfbench/tests/test_families.py`` as cases over both families, every
-kernel's operation counts against a hand count written here, and the new
-cell's toy twin rehearsed to its end."""
+"""The benchmark's family seam and its listing, where the driver counts them:
+the checks of ``perfbench/tests/test_families.py`` as cases over every family,
+every kernel's operation counts against a hand count written here, the
+listing walked a case an ``(entry, cell)`` and a ``(metric file, cell)``, and
+the cells' toy twins rehearsed to their end. A family adds rows to the
+tables; nothing here names a metric, a suffix or a count of the listing."""
 
 import json
 import os
@@ -11,10 +13,10 @@ import types
 
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, ROOT)
-
-from perfbench import families, flops          # noqa: E402
+from perfbench_listing import (BENCH, CELLS, FIELDS, FILE_PAIRS, LISTED,
+                               PAIRS, ROOT, SPECS, counts, files_reading,
+                               kernel_of, load, named, reports)
+from perfbench import families, flops, readers, run
 
 CONFIGS = {"cgpt-1p3b": "gpt2", "cgpt-1p3b-d20": "gpt2",
            "laguna-xs2-share8": "laguna", "mellum2-12b-d8": "mellum",
@@ -23,11 +25,8 @@ JOBS = {"gpt2": "pretrain_1chip", "laguna": "laguna_pretrain_8k",
         "mellum": "mellum_code_16k", "jamba": "jamba_reasoning_6k"}
 FAMILY_CONFIG = {"gpt2": "cgpt-1p3b-d20", "laguna": "laguna-xs2-share8",
                  "mellum": "mellum2-12b-d8", "jamba": "jamba2-3b"}
-
-
-def load(*parts):
-    with open(os.path.join(ROOT, "perfbench", *parts)) as f:
-        return json.load(f)
+RATE = {"gpt2": "train_tok_s_chip", "laguna": "train_tok_s_chip",
+        "mellum": "serve_tok_s", "jamba": "serve_tok_s"}
 
 
 def config(name):
@@ -385,61 +384,94 @@ def test_a_kernel_the_family_has_no_count_for_is_none(family):
                    else "open_loop"}) is None
 
 
-def test_every_metric_of_the_new_cell_has_its_file_and_its_kernel():
-    bench = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
-    mine = [m for m in bench["per_layer"]
-            if m.get("workloads") == ["laguna_pretrain_8k"]]
-    assert len(mine) == 6 + 12 + 6
-    kernels = {k for f, k in KERNELS if f == "laguna"}
-    for m in mine:
-        spec = load("metrics", m["name"] + ".json")
-        assert m["name"].endswith(".moe") and spec["unit"] == m["unit"]
-        assert spec["moves"] == m["moves"] == "train_tok_s_chip"
-        stem = m["name"][:-len(".moe")]
-        if stem.endswith("_roofline_pct"):
-            kernel = stem[:-len("_roofline_pct")]
-            assert kernel in kernels
-            assert spec["reader"]["name"] == "kernel_floor_s." + kernel
-            assert spec["reader"]["over"] == "trace.kernel_s." + kernel
-        if stem.endswith("_busy_pct"):
-            assert stem[:-len("_busy_pct")] in kernels
-            assert spec["reader"]["over"] == "trace.busy_s"
+# ---- the listing, walked: whatever BENCHMARK.json and metrics/ hold today
+
+E2E = {m["name"] for m in BENCH["end_to_end"]}
+LAYERS = {m["layer"] for m in LISTED.values()}
+NOTHING = dict.fromkeys(readers.GROUPS, {})
 
 
-def test_every_metric_of_the_mellum_cell_has_its_file_and_its_kernel():
-    bench = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
-    cell = next(c for c in bench["workloads"]
-                if c["name"] == "mellum_code_16k")
+@pytest.mark.parametrize("family", sorted(JOBS))
+def test_a_familys_cell_is_its_configuration_under_its_traffic(family):
+    cell = CELLS[JOBS[family]]
     assert (cell["config"], cell["traffic"], cell["chips"]) == \
-        ("mellum2-12b-d8", "mellum_code_16k", 1)
-    assert "mellum_code_16k" in next(
-        m for m in bench["end_to_end"]
-        if m["name"] == "serve_tok_s")["workloads"]
-    mine = [m for m in bench["per_layer"]
-            if m.get("workloads") == ["mellum_code_16k"]]
-    assert len(mine) == 13 + 4 + 12     # the four twins after the review
-    kernels = {k for f, k in KERNELS if f == "mellum"}
-    for m in mine:
-        spec = load("metrics", m["name"] + ".json")
-        assert m["name"].endswith(".mellum") and spec["unit"] == m["unit"]
-        assert spec["moves"] == m["moves"] == "serve_tok_s"
-        assert spec["layer"] == m["layer"]
-        stem = m["name"][:-len(".mellum")]
-        if stem.endswith("_roofline_pct"):
-            kernel = stem[:-len("_roofline_pct")]
-            assert kernel in kernels
-            assert spec["reader"]["name"] == "kernel_floor_s." + kernel
-            assert spec["reader"]["over"] == "trace.kernel_s." + kernel
-        if stem.endswith("_busy_pct"):
-            assert stem[:-len("_busy_pct")] in kernels
-            assert spec["reader"]["over"] == "trace.busy_s"
-    over = {m["name"]: load("metrics", m["name"] + ".json")["reader"]
-            for m in mine}
-    assert over["experts_touched_per_step.mellum"]["over"] == \
-        "counters.engine.sampler_dispatches"
-    assert over["window_blocks_freed_per_request.mellum"]["over"] == \
-        "counters.engine.completed"
+        (FAMILY_CONFIG[family], JOBS[family], 1)
+    assert reports(cell["name"], RATE[family])
 
+
+@pytest.mark.parametrize("name,cell", PAIRS)
+def test_an_entry_agrees_with_its_file_in_a_cell_that_can_report_it(name,
+                                                                    cell):
+    entry, spec = LISTED[name], SPECS[name]
+    assert set(entry) == {"name", *FIELDS, "workloads"}
+    assert all(spec[f] == entry[f] for f in FIELDS)
+    assert cell in CELLS and reports(cell, entry["moves"])
+    assert set(spec.get("what_in", {})) <= set(entry["workloads"])
+
+
+@pytest.mark.parametrize("name,cell", FILE_PAIRS)
+def test_a_metric_file_is_whole_and_its_kernel_is_one_its_cell_counts(
+        name, cell):
+    """Every file, listed or waiting for room: it is whole, a run that
+    observed nothing leaves it out, and a kernel's share is over that
+    kernel's time, the kernel one that the family of the cell counts for
+    the cell's own job (of some cell, while the file waits)."""
+    spec = SPECS[name]
+    assert set(spec) >= {"reader", "what", *FIELDS} and spec["what"]
+    assert {"from", "name"} <= set(spec["reader"])
+    assert spec["moves"] in E2E and spec["better"] in ("lower", "higher")
+    assert spec["layer"] in LAYERS
+    assert readers.read(name, NOTHING) is None
+    kernel, r = kernel_of(spec), spec["reader"]
+    if kernel:
+        floor = r["name"].startswith("kernel_floor_s.")
+        assert r["over"] == ("trace.kernel_s." + kernel if floor
+                             else "trace.busy_s")
+        assert any(counts(kernel, c) for c in ([cell] if cell else CELLS))
+
+
+#: reader name -> what its files divide it by (None: nothing)
+OVER = {"engine.experts_touched": "counters.engine.sampler_dispatches",
+        "engine.window_blocks_freed": "counters.engine.completed",
+        "engine.prefill_tokens_live":
+            "counters.engine.prefill_tokens_computed",
+        "engine.state_bytes.close": None}
+
+
+@pytest.mark.parametrize("reader", sorted(OVER))
+def test_an_engine_counter_is_read_over_the_count_it_is_a_share_of(reader):
+    files = files_reading(reader)
+    assert files and all(
+        SPECS[n]["reader"]["from"] == "counters"
+        and SPECS[n]["reader"].get("over") == OVER[reader] for n in files)
+
+
+#: the part's counter -> the whole's (PR 28, PR 30, PR 32)
+COUNTER_SHARES = {"engine.sampler_skipped": "engine.sampler_dispatches",
+                  "engine.inputs_resident": "engine.inputs_dispatches",
+                  "engine.prefill_rows_live": "engine.prefill_rows_computed"}
+
+
+@pytest.mark.parametrize("name", [
+    n for part in COUNTER_SHARES for n in files_reading(part)])
+def test_a_counter_share_reads_both_engine_counters(name):
+    part = SPECS[name]["reader"]["name"]
+    whole = COUNTER_SHARES[part]
+    # its fields are those of the in-place share that moves the same metric
+    twins = [SPECS[n] for n in files_reading("engine.pool_inplace")
+             if SPECS[n]["moves"] == SPECS[name]["moves"]]
+    assert twins and all(SPECS[name][k] == twin[k]
+                         for twin in twins for k in FIELDS)
+    obs = {"counters": {part: 1500.0, whole: 1600.0}}
+    assert readers.read(name, obs) == pytest.approx(93.75)
+    # the parent's program has neither counter: nothing to read, no metric
+    assert readers.read(name, {"counters": {
+        "engine.pool_dispatches": 1600.0}}) is None
+    # no decode dispatch in the window: no share
+    assert readers.read(name, {"counters": {part: 0, whole: 0}}) is None
+
+
+# ---- the cells' toy twins, rehearsed to their end
 
 REHEARSE_ON_A_STEPPED_CLOCK = """
 import sys
@@ -464,199 +496,110 @@ class Stepped:
 serve.time = Stepped()
 sys.exit(rehearse.main({argv!r}))
 """
+#: cell -> (the window, the seed, the readers its twin must have read)
+TWINS = {
+    "mellum_code_16k": (9, 2**31 + 77, (
+        "engine.experts_touched", "engine.window_blocks_freed",
+        "live_slot_share", "engine.inputs_resident", "serving.decode_step")),
+    "jamba_reasoning_6k": (6, 2**31 + 33, (
+        "serving.decode_step", "engine.prefill_tokens_live"))}
 
 
-def rehearse_on_a_stepped_clock(workload, seconds, seed):
-    """``rehearse.py --workload <a serving cell> --trace 1`` in its own
-    process, the harness's window counted in steps and not in wall
-    seconds: on the wall clock a 2 s window had to hold 8 completions of
-    the toy twin, which a CPU shared by six workers did not always give
-    (the take-up run of PR 33)."""
-    argv = ["--workload", workload, "--seconds", str(seconds), "--trace",
-            "1", "--seed", str(seed)]
-    out = subprocess.run(
+def last_line(out):
+    assert out.returncode == 0, out.stderr[-2000:]
+    return json.loads(out.stdout.strip().splitlines()[-1])
+
+
+@pytest.mark.parametrize("cell", sorted(TWINS))
+def test_a_serving_cells_toy_twin_rehearses_to_its_end(cell):
+    """``rehearse.py --workload <cell> --trace 1`` in its own process exits
+    0: the harness found every file by name, built the family's served
+    model behind the engine's seam (contexts that cross the toy window;
+    blocks for one layer and rows of state for three), ran the closed loop
+    and checked 8 requests against the family's reference: correct, nothing
+    leaked, and the counters' metrics were read. The harness's window is
+    counted in steps and not in wall seconds: on the wall clock a 2 s
+    window had to hold 8 completions of the toy twin, which a CPU shared
+    by six workers did not always give (the take-up run of PR 33)."""
+    seconds, seed, read = TWINS[cell]
+    argv = ["--workload", cell, "--seconds", str(seconds), "--trace", "1",
+            "--seed", str(seed)]
+    line = last_line(subprocess.run(
         [sys.executable, "-c",
          REHEARSE_ON_A_STEPPED_CLOCK.format(root=ROOT, argv=argv)],
         env=dict(os.environ, JAX_PLATFORMS="cpu"), cwd=ROOT,
-        capture_output=True, text=True, timeout=900)
-    assert out.returncode == 0, out.stderr[-2000:]
-    line = json.loads(out.stdout.strip().splitlines()[-1])
+        capture_output=True, text=True, timeout=900))
     assert line["correct"] is True and line["failed"] == 0
     assert line["notes"]["leaked_kv_blocks"] == 0
     assert line["notes"]["checked_requests"] == 8
     assert line["notes"]["max_logit_deficit"] <= 0.05
+    assert line["notes"]["completed_in_window"] >= 8
     assert all(m["value"] is None for m in line["metrics"].values())
-    return line
+    # the cell's own entries and no other, none of them the device trace's
+    mine = {m["name"]: m for m in run.metrics_of(BENCH, "per_layer", cell)}
+    assert set(line["metrics"]) <= set(mine), sorted(line["metrics"])
+    assert all(mine[n]["source"] != "device_trace" for n in line["metrics"])
+    assert {named(cell, r) for r in read} <= set(line["metrics"])
 
 
-def test_the_mellum_cells_toy_twin_rehearses_to_its_end():
-    """``rehearse.py --workload mellum_code_16k --trace 1`` exits 0: the
-    harness found every file by name, built the family's served model
-    behind the engine's seam, ran the closed loop with contexts that cross
-    the toy window, and checked 8 requests against the family's reference:
-    correct, nothing leaked, and the counters' metrics were read."""
-    line = rehearse_on_a_stepped_clock("mellum_code_16k", 9, 2**31 + 77)
-    assert line["notes"]["completed_in_window"] >= 8
-    for name in ("experts_touched_per_step.mellum",
-                 "window_blocks_freed_per_request.mellum",
-                 "sched_occupancy_pct.mellum",
-                 "inputs_resident_share_pct.mellum",
-                 "decode_step_inside_p50_ms.mellum"):
-        assert name in line["metrics"], sorted(line["metrics"])
-
-
-def test_every_metric_of_the_jamba_cell_has_its_file_and_its_kernel():
-    bench = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
-    cell = next(c for c in bench["workloads"]
-                if c["name"] == "jamba_reasoning_6k")
-    assert (cell["config"], cell["traffic"], cell["chips"]) == \
-        ("jamba2-3b", "jamba_reasoning_6k", 1)
-    assert "jamba_reasoning_6k" in next(
-        m for m in bench["end_to_end"]
-        if m["name"] == "serve_tok_s")["workloads"]
-    mine = [m for m in bench["per_layer"]
-            if m.get("workloads") == ["jamba_reasoning_6k"]]
-    # BENCHMARK.json holds at most 128 per-layer metrics and held 123: five
-    # of ISSUE 33's twenty-two are listed, the files of all are there
-    assert len(bench["per_layer"]) == 128 and len(mine) == 5
-    kernels = {k for f, k in KERNELS if f == "jamba"}
-    files = sorted(f[:-len(".json")] for f in os.listdir(
-        os.path.join(ROOT, "perfbench", "metrics"))
-        if f.endswith(".jamba.json"))
-    # ... and PR 35's six (the step account's three, the ahead share, the
-    # stalled time, the flight's turnaround): files that wait for room too
-    assert len(files) == 28 and {m["name"] for m in mine} <= set(files)
-    for name in files:
-        spec = load("metrics", name + ".json")
-        assert spec["moves"] == "serve_tok_s"
-        stem = name[:-len(".jamba")]
-        if stem.endswith("_roofline_pct"):
-            kernel = stem[:-len("_roofline_pct")]
-            assert kernel in kernels
-            assert spec["reader"]["name"] == "kernel_floor_s." + kernel
-            assert spec["reader"]["over"] == "trace.kernel_s." + kernel
-        if stem.endswith("_busy_pct"):
-            assert stem[:-len("_busy_pct")] in kernels
-            assert spec["reader"]["over"] == "trace.busy_s"
-    for m in mine:
-        spec = load("metrics", m["name"] + ".json")
-        assert all(spec[k] == m[k] for k in ("unit", "better", "source",
-                                             "layer", "moves"))
-    over = {n: load("metrics", n + ".json")["reader"] for n in files}
-    assert over["prefill_live_tokens_share_pct.jamba"]["over"] == \
-        "counters.engine.prefill_tokens_computed"
-    assert over["state_gib.jamba"]["name"] == "engine.state_bytes.close"
-
-
-def test_the_jamba_cells_toy_twin_rehearses_to_its_end():
-    """``rehearse.py --workload jamba_reasoning_6k --trace 1`` exits 0: the
-    harness found every file by name, built the family's served model
-    behind the engine's seam (blocks for one layer, rows of state for
-    three), ran the closed loop, and checked 8 requests against the
-    family's reference: correct, nothing leaked, the new counter read."""
-    line = rehearse_on_a_stepped_clock("jamba_reasoning_6k", 6, 2**31 + 33)
-    assert line["notes"]["completed_in_window"] >= 8
-    assert set(line["metrics"]) == {"decode_step_inside_p50_ms.jamba",
-                                    "prefill_live_tokens_share_pct.jamba"}
-
-
-def test_the_new_cells_toy_twin_rehearses_to_its_end():
+def test_the_training_cells_toy_twin_rehearses_to_its_end():
     """``rehearse.py --workload laguna_pretrain_8k`` exits 0: the harness
     found every file by name, built the family's train job, ran the window
     and the check against the family's reference."""
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    out = subprocess.run(
+    cell = JOBS["laguna"]
+    line = last_line(subprocess.run(
         [sys.executable, os.path.join(ROOT, "perfbench", "rehearse.py"),
-         "--workload", "laguna_pretrain_8k", "--seconds", "1"],
-        env=env, capture_output=True, text=True, timeout=600)
-    assert out.returncode == 0, out.stderr[-2000:]
-    line = json.loads(out.stdout.strip().splitlines()[-1])
+         "--workload", cell, "--seconds", "1"],
+        env=dict(os.environ, JAX_PLATFORMS="cpu"), capture_output=True,
+        text=True, timeout=600))
     assert line["failed"] == 0 and line["device"]["platform"] == "cpu"
-    assert set(line["metrics"]) == {"train_tok_s_chip", "setup_s"}
+    assert set(line["metrics"]) == {
+        m["name"] for m in run.metrics_of(BENCH, "end_to_end", cell)}
     assert all(m["value"] is None for m in line["metrics"].values())
     assert line["notes"]["check_loss_diff"] <= 0.05
-
-
-# ---- PR 28, PR 30, PR 32: the engine's counters through the counter channel
-
-COUNTER_SHARES = {
-    "sampler_skipped_share_pct": ("engine.sampler_skipped",
-                                  "engine.sampler_dispatches"),
-    "inputs_resident_share_pct": ("engine.inputs_resident",
-                                  "engine.inputs_dispatches"),
-    "prefill_live_rows_share_pct": ("engine.prefill_rows_live",
-                                    "engine.prefill_rows_computed")}
-SHARE_CELLS = {"chat": "chat_steady", "docs": "docs_offline",
-               "decode": "decode_heavy"}
-
-
-@pytest.mark.parametrize("suffix", sorted(SHARE_CELLS))
-@pytest.mark.parametrize("metric", sorted(COUNTER_SHARES))
-def test_a_counter_share_reads_both_engine_counters(metric, suffix):
-    from perfbench import readers
-    name = f"{metric}.{suffix}"
-    part, whole = COUNTER_SHARES[metric]
-    bench = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
-    entry = next(m for m in bench["per_layer"] if m["name"] == name)
-    spec = load("metrics", name + ".json")
-    twin = load("metrics", f"pool_inplace_share_pct.{suffix}.json")
-    assert entry["workloads"] == [SHARE_CELLS[suffix]]
-    assert all(spec[k] == entry[k] == twin[k]
-               for k in ("layer", "unit", "better", "source", "moves"))
-    obs = {"counters": {part: 1500.0, whole: 1600.0}}
-    assert readers.read(name, obs) == pytest.approx(93.75)
-    # the parent's program has neither counter: nothing to read, no metric
-    assert readers.read(name, {"counters": {
-        "engine.pool_dispatches": 1600.0}}) is None
-    # no decode dispatch in the window: no share
-    assert readers.read(name, {"counters": {part: 0, whole: 0}}) is None
 
 
 REHEARSE_WITH_VALUES = """
 import argparse, json, sys
 sys.path.insert(0, {root!r})
-from perfbench import run as harness
-bench = harness.load_json({root!r}, "BENCHMARK.json")
-bench["configs"] = [{{"name": "cgpt-1p3b",
-                     "file": "perfbench/rehearsal/gpt2-tiny.json"}}]
+from perfbench import rehearse, run
+bench = run.load_json({root!r}, "BENCHMARK.json")
 args = argparse.Namespace(workload="decode_heavy", seed=2147483659,
                           seconds=1.5, trace=1)
-print(json.dumps(harness.run_cell(bench, args, rehearsal=True,
-                                  traffic_dir="rehearsal")))
+print(json.dumps(rehearse.run_twin(
+    bench, run.find_cell(bench, "decode_heavy"), args)))
 """
 
 
 @pytest.fixture(scope="module")
-def decode_heavy_rehearsed():
+def decode_heavy_read():
     """``decode_heavy``'s toy twin, traced, on a real engine (its own
-    process, as ``rehearse.py`` runs it, but with the values kept)."""
-    out = subprocess.run(
+    process, as ``rehearse.py`` runs it, but with the values kept) -> the
+    value the cell's entry over a reader got."""
+    line = last_line(subprocess.run(
         [sys.executable, "-c", REHEARSE_WITH_VALUES.format(root=ROOT)],
         env=dict(os.environ, JAX_PLATFORMS="cpu"), cwd=ROOT,
-        capture_output=True, text=True, timeout=600)
-    assert out.returncode == 0, out.stderr[-2000:]
-    line = json.loads(out.stdout.strip().splitlines()[-1])
+        capture_output=True, text=True, timeout=600))
     assert line["correct"] is True and line["failed"] == 0
-    return line
+    return lambda reader: \
+        line["metrics"][named("decode_heavy", reader)]["value"]
 
 
 def test_a_rehearsal_reports_every_decode_step_as_sampler_skipped(
-        decode_heavy_rehearsed):
+        decode_heavy_read):
     """The harness submits no decode parameters, so every decode dispatch
     of the window is all-greedy and the reader finds both counters."""
-    m = decode_heavy_rehearsed["metrics"]
-    assert m["sampler_skipped_share_pct.decode"]["value"] == 100.0
+    assert decode_heavy_read("engine.sampler_skipped") == 100.0
     # eight rows turn over about once in thirty steps of the toy twin
-    assert 80.0 <= m["inputs_resident_share_pct.decode"]["value"] < 100.0
-    assert m["pool_inplace_share_pct.decode"]["value"] == 100.0
-    assert m["compiles_in_window.decode"]["value"] == 0
+    assert 80.0 <= decode_heavy_read("engine.inputs_resident") < 100.0
+    assert decode_heavy_read("engine.pool_inplace") == 100.0
+    assert decode_heavy_read("compiles_in_window") == 0
 
 
 def test_a_rehearsal_reads_the_live_share_of_its_prefill_rows(
-        decode_heavy_rehearsed):
+        decode_heavy_read):
     """The toy twin's prompts (4-16 tokens) fall in its bucket of 16, whose
     one program has ``max_slots`` = 4 rows under GPT's 512 tokens a
     dispatch; a completion frees one slot, so a dispatch carries one live
     row of four, two where two requests ended in one step."""
-    m = decode_heavy_rehearsed["metrics"]
-    assert 25.0 <= m["prefill_live_rows_share_pct.decode"]["value"] <= 50.0
+    assert 25.0 <= decode_heavy_read("engine.prefill_rows_live") <= 50.0

@@ -28,6 +28,7 @@ import numpy as np
 import pytest
 
 import paddle_tpu as pt
+import perfbench_listing as listing
 from paddle_tpu import monitor, profiler
 from paddle_tpu.models.gpt import GPTConfig, GPTForCausalLM
 from paddle_tpu.serving import ServingEngine
@@ -495,9 +496,6 @@ def test_a_step_a_prefill_overtook_feeds_no_estimate(slow_engine):
 
 # ------------------------------------------- the metric files that read it
 
-FAMILIES = {"chat": "itl_mean_ms", "docs": "serve_tok_s",
-            "decode": "serve_tok_s", "mellum": "serve_tok_s",
-            "jamba": "serve_tok_s"}
 #: what a window of a change run leaves in the harness's observations
 OBS = {
     "spans": {"serving.flight": [0.026, 0.027, 0.013, 0.040],
@@ -512,47 +510,51 @@ OBS = {
                  "engine.stalled_ms": 0.0, "window_s": 45.0},
     "trace": {},
 }
-#: metric stem -> (families, the value OBS gives it)
-FILES = {
-    "decode_host_ms_per_step": (FAMILIES, 1.9),
-    "decode_wait_ms_per_step": (FAMILIES, 11.0),
-    "decode_device_ms_per_step": (FAMILIES, 13.1),
-    "ahead_share_pct": (FAMILIES, 98.8),
-    "stalled_ms": (FAMILIES, 0.0),
-    "flight_turnaround_p50_ms": (FAMILIES, 26.5),
-    "ttft_inside_p50_ms": (("chat",), 44.0),
-    "ttft_inside_p90_ms": (("chat",), 48.8),
-    "queue_wait_inside_p90_ms": (("chat",), 9.2),
+#: a reader (from, name, reduce) -> (what it is over, the value OBS gives it)
+ACCOUNT = {
+    ("counters", "engine.decode_host_ms", "value"):
+        ("counters.engine.decode_flights", 1.9),
+    ("counters", "engine.decode_wait_ms", "value"):
+        ("counters.engine.decode_flights", 11.0),
+    ("counters", "engine.decode_device_ms", "value"):
+        ("counters.engine.decode_flights", 13.1),
+    ("counters", "engine.ahead_dispatches", "value"):
+        ("counters.engine.sampler_dispatches", 98.8),
+    ("counters", "engine.stalled_ms", "value"): (None, 0.0),
+    ("spans", "serving.flight", "p50"): (None, 26.5),
+    ("spans", "serving.ttft", "p50"): (None, 44.0),
+    ("spans", "serving.ttft", "p90"): (None, 48.8),
+    ("span_args", "serving.ttft.queue_ms", "p90"): (None, 9.2),
 }
 
 
-@pytest.mark.parametrize("name", sorted(
-    f"{stem}.{fam}" for stem, (fams, _) in FILES.items() for fam in fams))
+def account_files(reader):
+    group, name, how = reader
+    return listing.files_reading(name, reduce=how, **{"from": group})
+
+
+def test_every_reader_of_the_account_has_a_file():
+    assert all(map(account_files, ACCOUNT))
+
+
+@pytest.mark.parametrize("name", [
+    n for reader in ACCOUNT for n in account_files(reader)])
 def test_a_metric_file_reads_the_account_and_nothing_from_the_parent(name):
-    """Data over the counter and span channels, no reader code: the file
-    reads a change run's observations to the number worked out by hand,
-    finds nothing (and does not raise) in a parent's, and is not listed:
-    ``BENCHMARK.json``'s ``per_layer`` is full (ROADMAP H9)."""
-    import os
-    import sys
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    sys.path.insert(0, root)
+    """Data over the counter and span channels, no reader code: whichever
+    files read the account (found by their reader, listed or not) read a
+    change run's observations to the number worked out by hand, and find
+    nothing (and do not raise) in a parent's."""
     from perfbench import readers
-    stem, fam = name.rsplit(".", 1)
-    assert readers.read(name, OBS) == pytest.approx(FILES[stem][1])
+    spec = listing.SPECS[name]
+    r = spec["reader"]
+    over, value = ACCOUNT[r["from"], r["name"], r["reduce"]]
+    assert r.get("over") == over
+    assert readers.read(name, OBS) == pytest.approx(value)
     parent = {"spans": {"serving.decode.fetch": [0.011]}, "span_args": {},
               "samples": {}, "trace": {},
               "counters": {"engine.sampler_dispatches": 3000,
                            "window_s": 45.0}}
     assert readers.read(name, parent) is None
-    with open(os.path.join(root, "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    spec = readers.spec(name)
-    assert spec["moves"] == FAMILIES[fam]
-    assert spec["layer"] in {m["layer"] for m in bench["per_layer"]}
-    assert spec["better"] in ("lower", "higher")
-    assert spec["source"] == ("program_span" if spec["reader"]["from"]
+    assert spec["source"] == ("program_span" if r["from"]
                               in ("spans", "span_args")
                               else "program_counter")
-    assert name not in {m["name"] for m in bench["per_layer"]}
-    assert len(bench["per_layer"]) == 128
