@@ -16,19 +16,18 @@ One chip (what the driver runs):
   attention gave way to the XLA-composed form. A batch that does not fit
   is reported with the compiler's words and halved, never in silence.
 - ``serve``: ``gpt2-1p3b`` in a ``ServingEngine`` (paged cache) behind
-  ``serving/http.py``, a handful of requests over two prefill buckets,
-  once with ``FLAGS_serving_attn_impl=xla`` and once with ``pallas``;
-  both agree token for token with ``generation.greedy_search`` on the
+  ``serving/http.py``, a handful of requests over two prefill buckets;
+  they agree token for token with ``generation.greedy_search`` on the
   same weights under float32 ``highest`` matmul precision (stated, not
   loosened: on the TPU float32 matmuls default to bf16 passes), zero
-  leaked blocks, zero exceptions, and the ``pallas`` decode step holds
-  the paged kernel.
+  leaked blocks, zero exceptions, and the decode step holds the paged
+  kernel.
 
 Four chips (``--chips 4``, run by the builder; ``gpt2-medium`` so both
 sides of each comparison fit): ``zero`` — ``zero_train_step(stage=2)``
 on a ``dp=4`` mesh against the one-chip ``jit.to_static`` step; ``tp``
 — one ``ServingEngine`` on ``serving_mesh(1, 4)`` against a one-device
-engine, ``xla`` and ``pallas``. Shards sit on four devices and every
+engine. Shards sit on four devices and every
 kernel takes its chip's own shard.
 
 Each phase is a child process and this parent imports neither jax nor
@@ -432,7 +431,6 @@ def build_server_model(ph, name):
 
 def phase_serve(ph):
     import numpy as np
-    import paddle_tpu as pt
     from paddle_tpu.models.generation import greedy_search
     a = ph.args
     cfg, model = build_server_model(ph, a.serve_model)
@@ -447,19 +445,13 @@ def phase_serve(ph):
             for p in prompts]
     ph.say(f"oracle (generation.greedy_search, one request at a time): "
            f"{time.time() - t:.1f}s")
-    seconds, kernels = {}, 0
-    for impl in ("xla", "pallas"):
-        pt.set_flags({"serving_attn_impl": impl})
-        eng, got, seconds[impl] = serve_http(ph, model, prompts,
-                                             a.new_tokens)
-        toks = sum(len(g) - len(p) for g, p in zip(got, prompts))
-        ph.say(f"attn_impl={impl}: {toks} tokens generated over HTTP in "
-               f"{seconds[impl]:.1f}s (compiles included)")
-        compare_tokens(ph, f"attn_impl={impl} vs greedy_search", got, want)
-        if impl == "pallas":
-            kernels = ph.check_kernel(decode_step_text(eng),
-                                      "pallas decode step")
-        del eng
+    eng, got, seconds = serve_http(ph, model, prompts, a.new_tokens)
+    toks = sum(len(g) - len(p) for g, p in zip(got, prompts))
+    ph.say(f"{toks} tokens generated over HTTP in {seconds:.1f}s "
+           f"(compiles included)")
+    compare_tokens(ph, "engine vs greedy_search", got, want)
+    kernels = ph.check_kernel(decode_step_text(eng), "decode step")
+    del eng
     ph.finish(model=a.serve_model, requests=len(prompts),
               new_tokens=a.new_tokens, serve_seconds=seconds,
               tpu_custom_calls=kernels, transport="http")
@@ -557,33 +549,24 @@ def phase_zero(ph):
 
 
 def phase_tp(ph):
-    import paddle_tpu as pt
     from paddle_tpu.distributed.sharding import serving_mesh
     a = ph.args
     cfg, model = build_server_model(ph, a.mesh_model)
     buckets = [int(b) for b in a.buckets.split(",")]
     prompts = make_prompts(cfg, buckets, a.requests, a.seed)
-    pt.set_flags({"serving_attn_impl": "xla"})
     eng, want, secs = serve_http(ph, model, prompts, a.new_tokens)
-    ph.say(f"one-device engine (xla): {secs:.1f}s")
+    ph.say(f"one-device engine: {secs:.1f}s")
     del eng
-    mesh = serving_mesh(1, a.chips)
-    seconds = {}
-    for impl in ("xla", "pallas"):
-        pt.set_flags({"serving_attn_impl": impl})
-        eng, got, seconds[impl] = serve_http(ph, model, prompts,
-                                             a.new_tokens, mesh=mesh)
-        ph.say(f"TP={a.chips} engine attn_impl={impl}: "
-               f"{seconds[impl]:.1f}s")
-        compare_tokens(ph, f"TP={a.chips} attn_impl={impl} vs the "
-                       "one-device engine", got, want)
-        on_all_devices(ph, "KV pools",
-                       [x for layer in eng.cache.arrays() for x in layer])
-        if impl == "pallas":
-            check_local_kernels(ph, decode_step_text(eng),
-                                "TP pallas decode step",
-                                cfg.num_heads // a.chips)
-        del eng
+    eng, got, seconds = serve_http(ph, model, prompts, a.new_tokens,
+                                   mesh=serving_mesh(1, a.chips))
+    ph.say(f"TP={a.chips} engine: {seconds:.1f}s")
+    compare_tokens(ph, f"TP={a.chips} engine vs the one-device engine",
+                   got, want)
+    on_all_devices(ph, "KV pools",
+                   [x for layer in eng.cache.arrays() for x in layer])
+    check_local_kernels(ph, decode_step_text(eng), "TP decode step",
+                        cfg.num_heads // a.chips)
+    del eng
     split = [p.value for n, p in model.named_parameters()
              if n.endswith(("qkv_proj.weight", "fc1.weight",
                             "fc2.weight", "out_proj.weight"))]

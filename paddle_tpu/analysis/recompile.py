@@ -123,8 +123,7 @@ def predict_serving_compiles(
         request_rounds: Iterable[Sequence[Tuple[Sequence[int], int]]], *,
         buckets: Sequence[int], max_len: int,
         block_size: int = 16, prefix_cache: bool = True,
-        spec_tokens: int = 0, attn_impl: str = "xla",
-        kv_dtype: str = "f32",
+        spec_tokens: int = 0, kv_dtype: str = "f32",
         mesh_shape: Optional[Tuple[int, int]] = None,
         n_replicas: int = 1,
         slo_ttft_ms: float = 0.0,
@@ -168,13 +167,12 @@ def predict_serving_compiles(
       takes the verify path exclusively, so the compile lands on
       ``verify_step_paged{k=K}`` instead.
 
-    ``attn_impl`` (``FLAGS_serving_attn_impl``) and ``kv_dtype``
-    (``FLAGS_serving_kv_dtype``) are part of the compiled steps' cache
-    key — the step caches are keyed on the flags version, and the int8
-    pool changes every step's input signature — but they do NOT change
-    the per-site compile counts *within* one settings phase: the same
-    sites trace the same number of times whichever lowering and pool
-    dtype they trace with. A workload that flips settings mid-run is
+    ``kv_dtype`` (``FLAGS_serving_kv_dtype``) is part of the compiled
+    steps' cache key — the step caches are keyed on the flags version,
+    and the int8 pool changes every step's input signature — but it does
+    NOT change the per-site compile counts *within* one settings phase:
+    the same sites trace the same number of times whichever pool dtype
+    they trace with. A workload that flips settings mid-run is
     two phases; predict each phase separately and sum the site counts
     with :func:`merge_compile_counts` (that is exactly how
     ``tracked_jit`` accumulates counts across retraces at one site).
@@ -183,7 +181,7 @@ def predict_serving_compiles(
     mesh an engine's steps compile under) and ``n_replicas``
     (``FLAGS_serving_replicas``: data-parallel engines behind a
     ReplicaRouter) are the two scale-out cache-key components. Like
-    ``attn_impl``/``kv_dtype``, neither changes per-site counts within
+    ``kv_dtype``, neither changes per-site counts within
     a phase: a mesh engine's entries live under a *new* unified-cache
     key (one extra compile per site — a separate phase to merge), while
     replicas share one model and therefore one step cache, so N
@@ -336,12 +334,9 @@ def predict_serving_compiles(
     same two entries — enqueueing megastep k+1 early replays the
     cached trace by construction.
     """
-    for val, ok, flag in ((attn_impl, ("xla", "pallas"),
-                           "attn_impl"),
-                          (kv_dtype, ("f32", "bf16", "int8"),
-                           "kv_dtype")):
-        if val not in ok:
-            raise ValueError(f"{flag} must be one of {ok}, got {val!r}")
+    if kv_dtype not in ("f32", "bf16", "int8"):
+        raise ValueError(f"kv_dtype must be one of ('f32', 'bf16', "
+                         f"'int8'), got {kv_dtype!r}")
     if mesh_shape is not None:
         dims = tuple(int(d) for d in mesh_shape)
         if len(dims) != 2 or any(d < 1 for d in dims):

@@ -312,18 +312,6 @@ define_flag("serving_num_blocks", 0,
             "oversubscribe memory and rely on short requests + "
             "prefix sharing (admission blocks head-of-line when the "
             "pool runs dry).")
-define_flag("serving_attn_impl", "xla",
-            "Paged decode/verify/prefill attention implementation: "
-            "'xla' gathers each request's blocks and composes the "
-            "masked softmax over them (ops.attention_ops."
-            "block_attention); 'pallas' runs the fused paged "
-            "decode-attention kernel (ops/pallas/paged_attention.py) "
-            "that walks each request's block table inside the kernel — "
-            "gather + QK^T + online softmax + V-accumulate in one "
-            "pass, never materializing the gathered cache. Greedy "
-            "output is token-identical either way (the tested "
-            "contract). On CPU backends the kernel runs in Pallas "
-            "interpreter mode.")
 define_flag("serving_kv_dtype", "f32",
             "Paged serving KV pool element type: 'f32', 'bf16' (half "
             "the bytes, plain cast), or 'int8' (quarter the bytes: "

@@ -178,7 +178,7 @@ def step_entry(model, key, build):
     ``verify_step*`` attributes here); they are unified behind this
     single ``model._step_compile_cache`` dict so a cache entry's
     identity is its full key — (step kind, geometry, bucket/K,
-    attn_impl, kv_dtype, mesh) — and "exactly one compile per key" is
+    kv_dtype, mesh) — and "exactly one compile per key" is
     one invariant instead of three. ``build()`` makes the entry (a dict
     with at least ``fn``/``traces``); entries are validated against the
     flag-plane version, so ``set_flags`` invalidates every step at once

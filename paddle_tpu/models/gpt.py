@@ -70,6 +70,13 @@ class GPTConfig:
         return n
 
 
+
+#: query rows a request up to which the paged branch reads through the
+#: kernel (``ops/pallas/paged_attention``): decode (1) and speculative
+#: verify (K+1) blocks. Read off the query block's own shape, not off the
+#: rows a dispatch holds: a lone bucket-64 prompt is a prefill.
+PAGED_KERNEL_MAX_ROWS = 8
+
 GPT_CONFIGS = {
     # name: (hidden, layers, heads, ffn)
     "gpt2-tiny": GPTConfig(hidden_size=128, num_layers=2, num_heads=4,
@@ -171,14 +178,11 @@ class GPTAttention(Layer):
             # absmax scales (written by block_scatter_write_quant; an
             # int8 step returns a 5th element, the max-abs dequant error
             # of the rows just written, which the engine surfaces as a
-            # drift metric). FLAGS_serving_attn_impl picks the read
-            # path: 'xla' composes gather (+ dequant) with the masked
-            # softmax — the correctness oracle — while 'pallas' streams
-            # blocks through the fused paged-attention kernel without
-            # materializing the gathered cache. Read at trace time: the
-            # compiled step caches key on the flags version, so
-            # flipping the flag retraces instead of going stale.
-            from .. import flags as _flags
+            # drift metric). The query block's shape picks the read: a
+            # decode or verify block (a few rows a request) goes through
+            # the paged kernel, which copies each request's live blocks
+            # and gathers nothing table-sized; a prefill bucket composes
+            # gather (+ dequant) with the masked softmax in XLA.
             from ..ops.attention_ops import (block_attention,
                                              block_gather_dequant,
                                              block_scatter_write,
@@ -208,7 +212,7 @@ class GPTAttention(Layer):
                 vp = block_scatter_write(vp, v.value, pos, tables)
                 cache = (Tensor(kp, stop_gradient=True),
                          Tensor(vp, stop_gradient=True))
-            if _flags.get_flag("serving_attn_impl") == "pallas":
+            if s <= PAGED_KERNEL_MAX_ROWS:
                 from ..ops.pallas.paged_attention import paged_attention
                 out = Tensor(paged_attention(q.value, kp, vp, tables, pos,
                                              k_scale=ksc, v_scale=vsc),

@@ -48,10 +48,6 @@ INSTRUMENT_DOCS = {
         "includes the trash block and prefix-cache holds)",
     "serving_kv_blocks_free{engine=...}":
         "gauge — physical KV blocks on the free list (paged serving)",
-    "serving_attn_impl{engine=..., impl=..., kv_dtype=...}":
-        "gauge — 1 on the attention-implementation/KV-dtype series an "
-        "engine traced with (pallas fused paged kernel vs XLA-composed "
-        "reference; f32/bf16/int8 pools)",
     "serving_kv_dequant_max_abs_err{engine=...}":
         "gauge — high-water max-abs int8 KV dequantization error over "
         "rows written by the compiled steps (quantization drift watch)",

@@ -187,7 +187,7 @@ _ACCOUNT_KEYS = (
     "decode_device_ms", "prefill_flights", "prefill_host_ms",
     "prefill_wait_ms", "rounds", "round_ms", "rounds_over_100ms",
     "rounds_over_1s", "stalled_ms", "first_tokens", "ttft_ms",
-    "queue_wait_ms")
+    "queue_wait_ms", "kv_blocks_live", "kv_blocks_table")
 
 
 class _Stamps:
@@ -452,7 +452,6 @@ class ServingEngine:
                               "serving_num_blocks",
                               "serving_prefix_cache",
                               "serving_kv_dtype",
-                              "serving_attn_impl",
                               "serving_mesh",
                               "serving_slo_ttft_ms",
                               "serving_slo_prefill_ms",
@@ -564,10 +563,6 @@ class ServingEngine:
             self.kv_dtype = spec.kv_dtype
         elif self.kv_dtype == "int8":
             spec.require("int8_pool", "kv_dtype='int8'")
-        # which attention lowering the compiled paged steps traced with;
-        # gpt.py re-reads the flag at trace time, so this attribute is
-        # observability (the gauge label + stats()), not the switch
-        self.attn_impl = str(g["serving_attn_impl"])
         if mesh is None:
             dims = parse_serving_mesh(g["serving_mesh"])
             if dims is not None:
@@ -742,14 +737,6 @@ class ServingEngine:
             ).labels(engine=eid, tier="device")
         self._blocks_used_g.set(self.cache.blocks_used)
         self._blocks_free_g.set(self.cache.blocks_free)
-        # which paged-attention lowering this engine runs (1 on the
-        # active impl/dtype series — the Prometheus idiom for enums)
-        _obs.gauge(
-            "serving_attn_impl",
-            "active serving attention implementation (1 on the "
-            "impl/kv_dtype series this engine traced with)"
-            ).labels(engine=eid, impl=self.attn_impl,
-                     kv_dtype=self.kv_dtype).set(1)
         _obs.gauge(
             "serving_mesh_devices",
             "devices this engine's compiled steps span (data x model "
@@ -1458,7 +1445,7 @@ class ServingEngine:
         only computes its unshared suffix; rows past the admitted
         count are padding the caller discards. Cached in the model's
         unified ``step_entry`` cache keyed by the rows, the full pool
-        geometry, attn impl, KV dtype, and mesh — one compile per key,
+        geometry, KV dtype, and mesh — one compile per key,
         so engine restarts with the same geometry (benchmark reruns,
         rolling deploys) reuse the executable. Under a mesh the
         pass runs with explicit in/out shardings: pools keep
@@ -1468,7 +1455,7 @@ class ServingEngine:
                self.spec.prefill_rows(bucket, self.max_slots),
                self.max_slots, self.max_len,
                self.cache.block_size, self.cache.num_blocks,
-               self.kv_dtype, self.attn_impl,
+               self.kv_dtype,
                mesh_cache_key(self.mesh))
         lora_shape = self._lora_shape
         if lora_shape is not None:
@@ -2099,6 +2086,8 @@ class ServingEngine:
             a["decode_host_ms"] += host
             a["decode_wait_ms"] += wait
             a["decode_device_ms"] += device
+            a["kv_blocks_live"] += self.cache.blocks_live()
+            a["kv_blocks_table"] += self.cache.tables.size
             if last_id < st.id and tokens_a_row:
                 self._note_tpot_ms(device / tokens_a_row)
         _monitor.stat_observe(stat, turnaround)
@@ -3147,7 +3136,14 @@ class ServingEngine:
           ``ttft_ms`` their submission-to-first-token time and
           ``queue_wait_ms`` the part of it before admission, on the
           engine's clock (``ttft_p50_ms`` above counts a request when it
-          completes, these when the token lands)."""
+          completes, these when the token lands).
+        - ``kv_blocks_live``: the table entries the committed decode
+          steps' rows stood on, ``ceil(length / block_size)`` summed over
+          the rows at each commit; ``kv_blocks_table``: the entries of
+          the whole table, ``slots x T`` a step. Their ratio is the share
+          of the table a decode step's attention had to read (the paged
+          kernel copies the live blocks and no others; a dead slot costs
+          it one block, which this leaves out)."""
         def pct(hist, q):
             v = hist.quantile(q)
             return None if v is None else round(v * 1e3, 3)
@@ -3251,7 +3247,6 @@ class ServingEngine:
         out["prefill_tokens_live"] = prefill_tokens_live
         # the step account (_ACCOUNT_KEYS; the docstring says what each is)
         out.update(account)
-        out["attn_impl"] = self.attn_impl
         out["kv_dtype"] = self.kv_dtype
         out["mesh_shape"] = (None if self.mesh_shape is None
                              else list(self.mesh_shape))

@@ -634,6 +634,12 @@ class BlockKVCache:
     def blocks_needed(self, length: int) -> int:
         return -(-int(length) // self.block_size)
 
+    def blocks_live(self) -> int:
+        """Table entries the rows' committed lengths stand on:
+        ``ceil(length / block_size)`` summed over the rows (a released
+        row's length is 0)."""
+        return int(np.sum(-(-self.lengths // self.block_size)))
+
     @property
     def blocks_free(self) -> int:
         return self.allocator.num_free

@@ -263,17 +263,27 @@ PAGED_CASES = {           # q_len, q dtype, pool dtype, int8 scales
     "decode_bf16_pool": (1, jnp.float32, jnp.bfloat16, False),
     "decode_int8_pool": (1, jnp.float32, jnp.int8, True),
     "verify_q5": (5, jnp.float32, jnp.float32, False),
+    # the GPT serving cells' own engine: 8 slots over 400 blocks
+    "decode_f32_cells": (1, jnp.float32, jnp.float32, False,
+                         dict(b=8, nb=400)),
+    # what S2 (b) will hand it (mellum_code_16k's read): 32 query heads
+    # on 4 KV heads, 256-row bfloat16 blocks, 16 slots over 801 blocks
+    "decode_gqa_bf16_bs256": (1, jnp.float32, jnp.bfloat16, False,
+                              dict(b=16, hq=32, hkv=4, bs=256, nb=801)),
 }
 
 
-def _paged_args(q_len, q_dt, pool_dt, quant, where):
+def _paged_args(q_len, q_dt, pool_dt, quant, geometry=None, *, where):
+    g = dict(dict(b=B, hq=H, hkv=H, bs=BS, nb=NB), **(geometry or {}))
+
     def s(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=where(len(shape)))
-    args = [s((B, H, q_len, D), q_dt), s((NB, H, BS, D), pool_dt),
-            s((NB, H, BS, D), pool_dt), s((B, T), jnp.int32),
-            s((B,), jnp.int32)]
+    pool = (g["nb"], g["hkv"], g["bs"], D)
+    args = [s((g["b"], g["hq"], q_len, D), q_dt), s(pool, pool_dt),
+            s(pool, pool_dt), s((g["b"], T), jnp.int32),
+            s((g["b"],), jnp.int32)]
     if quant:
-        args += [s((NB, H), jnp.float32)] * 2
+        args += [s(pool[:2], jnp.float32)] * 2
     return args
 
 
@@ -284,6 +294,10 @@ def _paged(q, k, v, tables, pos, ks=None, vs=None):
 
 @pytest.mark.parametrize("case", sorted(PAGED_CASES))
 def test_paged_attention_compiles_for_v5e(one_chip, mosaic, case):
+    """Mosaic takes the walk at the cells' shapes: the pools stay in HBM
+    (no operand of the kernel is gathered or copied whole) and the two
+    double buffers, eight blocks of K and of V each, fit the scoped VMEM
+    (a kernel over its limit is refused here, not on the chip)."""
     args = _paged_args(*PAGED_CASES[case], where=lambda nd: one_chip)
     text = jax.jit(_paged).lower(*args).compile().as_text()
     assert text.count(KERNEL) == 1
@@ -382,8 +396,14 @@ def decode_step_1p3b_width():
                 jax.tree_util.tree_map(
                     s, neutral_samp(STEP_SLOTS, cfg.vocab_size)))
         fn = decode_step_paged(model)["fn"]
-        with jax.enable_x64(False):
-            return fn.raw.lower(*args).compile().as_text()
+        # the step's KV read is the paged kernel: through Mosaic, as the
+        # chip compiles it (the backend here is still the CPU)
+        was, pa._interpret = pa._interpret, lambda: False
+        try:
+            with jax.enable_x64(False):
+                return fn.raw.lower(*args).compile().as_text()
+        finally:
+            pa._interpret = was
 
     return cfg, lower
 

@@ -49,8 +49,8 @@ def test_chip_smoke_rehearsal_on_cpu_never_reports_success(tmp_path):
     assert [p["phase"] for p in phases] == ["train", "serve"], err[-2000:]
     assert all(p["ok"] for p in phases)
     assert not [ln for ln in lines if "FAIL" in ln], lines
-    for needle in ("[train] pass: loss fell", "[serve] pass: attn_impl=xla",
-                   "[serve] pass: attn_impl=pallas",
+    for needle in ("[train] pass: loss fell",
+                   "[serve] pass: engine vs greedy_search",
                    "pass: leaked_kv_blocks == 0", "pass: exceptions == 0"):
         assert any(needle in ln for ln in lines), (needle, lines)
     # the cache went where the variable says, nowhere in the checkout

@@ -4,15 +4,19 @@ Three layers of contract, bottom-up:
 
 - kernel vs oracle: ``ops.pallas.paged_attention`` (interpret mode on
   CPU) against the XLA-composed ``paged_attention_reference`` across
-  block-boundary, ragged-length, trash-block-padded and verify-width
-  (spec-decode rollback) cases, f32 and int8;
+  block-boundary, ragged-length, dead-row, trash-block-padded and
+  verify-width (spec-decode rollback) cases, grouped KV heads, 16- and
+  256-row blocks, chunked walks that end on a short chunk, f32 / bf16 /
+  int8;
 - the quantizing scatter ``block_scatter_write_quant``: parity with the
   float write, requantization idempotence (committed codes never drift
   when quieter rows land later), window locality, overflow routing;
-- the engine: ``FLAGS_serving_attn_impl=pallas`` and
-  ``FLAGS_serving_kv_dtype=int8`` stay token-identical to the XLA/f32
-  engine AND to sequential ``greedy_search`` — including speculative
-  verify (K>0, rollback) and prefix-cache on/off.
+- the engine: its decode and verify steps read through the kernel (the
+  query block's shape decides, ``gpt.PAGED_KERNEL_MAX_ROWS``; no flag),
+  float32 and ``FLAGS_serving_kv_dtype=int8``, and stay token-identical
+  to the same engine traced with the XLA oracle where the kernel stands
+  AND to sequential ``greedy_search`` over a dense cache — including
+  speculative verify (K>0, rollback) and prefix-cache on/off.
 
 Plus the lane-width regression: head dims that are not a multiple of
 the 128-lane register width (e.g. 20) are padded inside the kernels via
@@ -21,12 +25,16 @@ the 128-lane register width (e.g. 20) are padded inside the kernels via
 
 from contextlib import contextmanager
 
+import importlib
+
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 import paddle_tpu as pt
 from paddle_tpu.models.generation import greedy_search
+from paddle_tpu.models import gpt as gpt_mod
 from paddle_tpu.models.gpt import GPTConfig, GPTForCausalLM
 from paddle_tpu.ops import attention_ops
 from paddle_tpu.ops.attention_ops import (block_scatter_write,
@@ -38,6 +46,9 @@ from paddle_tpu.ops.pallas.utils import pad_lane_dim, pick_block
 from paddle_tpu.ops.quant_ops import dequantize_int8
 from paddle_tpu.serving import ServingEngine
 
+# the package re-exports the function under the module's name
+pa = importlib.import_module("paddle_tpu.ops.pallas.paged_attention")
+
 
 @contextmanager
 def _serving_flags(**kw):
@@ -45,8 +56,26 @@ def _serving_flags(**kw):
     try:
         yield
     finally:
-        pt.set_flags({"serving_attn_impl": "xla",
-                      "serving_kv_dtype": "f32"})
+        pt.set_flags({"serving_kv_dtype": "f32"})
+
+
+@contextmanager
+def _oracle_read():
+    """The engine's steps traced with the XLA oracle where the kernel
+    stands (``gpt.py`` looks the function up when it traces; a flags
+    bump drops the steps compiled before, on the way in and out)."""
+    def oracle(q, k_pool, v_pool, tables, pos, k_scale=None, v_scale=None):
+        return paged_attention_reference(q, k_pool, v_pool, tables, pos,
+                                         k_scale=k_scale, v_scale=v_scale)
+    def drop_compiled_steps():       # any set_flags moves the version
+        pt.set_flags(pt.get_flags("serving_kv_dtype"))
+    real, pa.paged_attention = pa.paged_attention, oracle
+    drop_compiled_steps()
+    try:
+        yield
+    finally:
+        pa.paged_attention = real
+        drop_compiled_steps()
 
 
 # ---------------------------------------------------------------------------
@@ -197,6 +226,135 @@ def test_kernel_matches_reference_int8(s):
                                rtol=0.12, atol=0.12)
 
 
+def _pools(rng, nb, h_kv, bs, d, pool_dtype):
+    """Random K / V pools (trash block poisoned) and, for int8, scales."""
+    if pool_dtype == "int8":
+        k_pool, v_pool = (jnp.asarray(rng.randint(-127, 128,
+                                                  (nb, h_kv, bs, d)),
+                                      jnp.int8) for _ in range(2))
+        scales = dict(
+            k_scale=jnp.asarray(rng.uniform(0.5, 2.0, (nb, h_kv)),
+                                jnp.float32),
+            v_scale=jnp.asarray(rng.uniform(0.5, 2.0, (nb, h_kv)),
+                                jnp.float32))
+        return k_pool.at[0].set(127), v_pool.at[0].set(127), scales
+    k_pool, v_pool = (jnp.asarray(rng.randn(nb, h_kv, bs, d), pool_dtype)
+                      for _ in range(2))
+    return k_pool.at[0].set(100.0), v_pool.at[0].set(100.0), {}
+
+
+# (block size, query heads a KV head, query rows): 16-row blocks on the
+# vector unit (GPT's decode and verify, grouped heads), 256-row blocks on
+# the vector unit (one row) and on the matrix unit (16 rows a KV head)
+WALKS = [(16, 1, 1), (16, 1, 4), (16, 4, 1), (16, 4, 4),
+         (256, 1, 1), (256, 16, 1), (256, 4, 4)]
+
+
+def test_the_shape_picks_the_arithmetic():
+    """(query rows a KV head, block rows, keys a slice) of the cells:
+    GPT's decode and verify over 16-row float32 blocks multiply and
+    reduce on the vector unit, Mellum's 8 query heads a KV head over
+    256-row bfloat16 blocks go through the matrix unit, like the two
+    grouped walks over 256-row blocks below in every pool type."""
+    assert pa._rows_form(1, 16, 8) and pa._rows_form(5, 16, 8)
+    assert pa._rows_form(1, 256, 8)
+    assert not pa._rows_form(8, 256, 16)
+    for sub in (8, 16, 32):
+        assert not pa._rows_form(16, 256, sub)
+
+
+@pytest.mark.parametrize("pool_dtype", ["float32", "bfloat16", "int8"])
+@pytest.mark.parametrize("bs,group,s", WALKS)
+def test_kernel_walks_the_live_blocks(monkeypatch, bs, group, s,
+                                      pool_dtype):
+    """Two blocks a compute step over a table of five (not a multiple of
+    two): a dead row (``pos`` 0, one block, the trash block's), a row
+    whose last query row ends exactly on a block's edge (two live blocks,
+    one whole chunk), one a row further (a last chunk with one live block
+    of two), and one that fills the table. The trash block is poisoned."""
+    monkeypatch.setattr(pa, "BLOCKS_A_STEP", 2)
+    rng = np.random.RandomState(bs + 7 * group + s)
+    T, h_kv, d = 5, 2, 32
+    pos = [0, 2 * bs - s, 2 * bs - s + 1, T * bs - s]
+    tables, nb = _tables_for(pos, s, bs, T)
+    tables = tables.at[0].set(0)          # a released row's table
+    k_pool, v_pool, scales = _pools(rng, nb, h_kv, bs, d, pool_dtype)
+    q = jnp.asarray(rng.randn(len(pos), h_kv * group, s, d), jnp.float32)
+    posv = jnp.asarray(pos, jnp.int32)
+    out = paged_attention(q, k_pool, v_pool, tables, posv, **scales)
+    if group > 1:       # the oracle reads one KV head a query head
+        k_pool, v_pool = (jnp.repeat(p, group, axis=1)
+                          for p in (k_pool, v_pool))
+        scales = {n: jnp.repeat(x, group, axis=1)
+                  for n, x in scales.items()}
+    ref = paged_attention_reference(q, k_pool, v_pool, tables, posv,
+                                    **scales)
+    # a bfloat16 pool's products on the matrix unit take q and the
+    # probabilities in bfloat16 (block_attention_gqa's contract)
+    tol = 2e-2 if pool_dtype == "bfloat16" else 1e-4
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=tol, atol=tol)
+
+
+def test_kernel_default_chunk_over_a_table_it_does_not_divide():
+    """The default eight blocks a step over a table of 20: rows of 1, 8,
+    11 and 20 live blocks (a whole chunk, a chunk and three, two chunks
+    and four)."""
+    rng = np.random.RandomState(23)
+    bs, T, h, d = 4, 20, 2, 32
+    pos = [0, 8 * bs - 1, 11 * bs - 2, T * bs - 1]
+    tables, nb = _tables_for(pos, 1, bs, T)
+    k_pool, v_pool, _ = _pools(rng, nb, h, bs, d, "float32")
+    q = jnp.asarray(rng.randn(len(pos), h, 1, d), jnp.float32)
+    posv = jnp.asarray(pos, jnp.int32)
+    out = paged_attention(q, k_pool, v_pool, tables, posv)
+    ref = paged_attention_reference(q, k_pool, v_pool, tables, posv)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)
+
+
+def test_gpt_step_takes_the_kernel_by_the_query_blocks_shape():
+    """The paged branch of ``models/gpt.py`` at a decode shape (one row a
+    request), a verify shape and a prefill bucket: the first two hold the
+    kernel and gather nothing table-sized, the bucket composes the read
+    over the gathered ``[b, T, h, bs, d]`` table and holds no kernel. A
+    lone bucket-64 prompt is a prefill, whatever its rows."""
+    from paddle_tpu.dygraph.tape import no_grad
+    from paddle_tpu.dygraph.tensor import Tensor
+    pt.seed(3)
+    cfg = GPTConfig(vocab_size=97, max_position_embeddings=128,
+                    hidden_size=32, num_layers=1, num_heads=4,
+                    ffn_hidden_size=64)
+    model = GPTForCausalLM(cfg)
+    model.eval()
+    bs, T, nb = 4, 32, 40
+    pool = jnp.zeros((nb, cfg.num_heads, bs, cfg.head_dim), jnp.float32)
+
+    def text(b, s):
+        def step(ids, pos, tables, k_pool, v_pool):
+            with no_grad():
+                logits, _ = model(
+                    Tensor(ids, stop_gradient=True),
+                    cache=[(Tensor(k_pool, stop_gradient=True),
+                            Tensor(v_pool, stop_gradient=True))],
+                    cache_pos=pos, block_tables=tables)
+            return logits.value
+        return str(jax.make_jaxpr(step)(
+            jnp.zeros((b, s), jnp.int32), jnp.zeros((b,), jnp.int32),
+            jnp.zeros((b, T), jnp.int32), pool, pool))
+
+    def gathered(b):
+        return f"f32[{b},{T},{cfg.num_heads},{bs},{cfg.head_dim}]"
+
+    assert gpt_mod.PAGED_KERNEL_MAX_ROWS == 8
+    for b, s in ((8, 1), (8, 5), (2, gpt_mod.PAGED_KERNEL_MAX_ROWS)):
+        t = text(b, s)
+        assert "paged_decode_attn" in t and gathered(b) not in t, (b, s)
+    for b, s in ((1, 64), (8, 16)):
+        t = text(b, s)
+        assert "paged_decode_attn" not in t and gathered(b) in t, (b, s)
+
+
 # ---------------------------------------------------------------------------
 # quantizing scatter: parity, idempotence, locality, overflow
 # ---------------------------------------------------------------------------
@@ -326,13 +484,14 @@ def test_flash_attention_odd_head_dim():
 
 
 # ---------------------------------------------------------------------------
-# engine: pallas / int8 token parity with XLA / f32 / sequential greedy
+# engine: the kernel's read / int8 token parity with the oracle's read and
+# with sequential greedy over a dense cache
 #
-# These retrace prefill+decode per flags combination under the Pallas
+# These retrace prefill+decode per combination under the Pallas
 # interpreter, which is heavy inside the full tier-1 run — they carry
 # the `slow` marker and run in the ci.sh serving gate (step 6, which
 # invokes this file without the tier-1 `-m 'not slow'` filter) and in
-# tools/obs_smoke.py's pallas+int8 phase. The kernel-vs-oracle and
+# tools/obs_smoke.py's int8 phase. The kernel-vs-oracle and
 # quantizing-scatter tests above stay in tier-1.
 # ---------------------------------------------------------------------------
 
@@ -362,49 +521,52 @@ def _run(model, prompts, mnt=5, **eng_kw):
     return [r.output_ids for r in reqs], eng
 
 
+def _dense_greedy(model, prompt, mnt=5):
+    return greedy_search(model, np.asarray([prompt]), max_new_tokens=mnt,
+                         cache_len=32)[0].tolist()
+
+
 @pytest.mark.slow
 @pytest.mark.parametrize("kv_dtype", ["f32", "int8"])
-def test_engine_pallas_matches_xla_and_greedy(model, kv_dtype):
-    """The fused kernel (and the int8 pool under it) must not move a
-    single sampled token: pallas engine == xla engine == sequential
-    f32 greedy_search, prompts spanning slot reuse and both buckets."""
+def test_engine_kernel_read_matches_oracle_and_dense_greedy(model,
+                                                            kv_dtype):
+    """The kernel (and the int8 pool under it) must not move a single
+    sampled token: the engine == the engine traced with the oracle's
+    read == sequential f32 greedy_search over a dense cache, prompts
+    spanning slot reuse and both buckets."""
     prompts = _prompts((3, 7, 5, 11))
-    with _serving_flags(serving_attn_impl="xla",
-                        serving_kv_dtype=kv_dtype):
-        base, _ = _run(model, prompts)
-    with _serving_flags(serving_attn_impl="pallas",
-                        serving_kv_dtype=kv_dtype):
+    with _serving_flags(serving_kv_dtype=kv_dtype):
+        with _oracle_read():
+            base, _ = _run(model, prompts)
         fused, eng = _run(model, prompts)
     assert fused == base
-    assert eng.attn_impl == "pallas" and eng.kv_dtype == kv_dtype
+    assert eng.kv_dtype == kv_dtype
+    st = eng.stats()
+    assert 0 < st["kv_blocks_live"] < st["kv_blocks_table"]
     for p, out in zip(prompts, fused):
-        ref = greedy_search(model, np.asarray([p]), max_new_tokens=5,
-                            cache_len=32)[0].tolist()
-        assert out == ref, f"{p} diverged from f32 greedy"
+        assert out == _dense_greedy(model, p), \
+            f"{p} diverged from f32 greedy"
 
 
 @pytest.mark.slow
-def test_engine_pallas_int8_spec_decode_parity(model):
+def test_engine_kernel_int8_spec_decode_parity(model):
     """Speculative verify (K=2): the widened verify query and its
     rollback re-writes ride the same kernel/quantized pool and must
-    stay token-identical to plain greedy."""
+    stay token-identical to plain greedy over a dense cache."""
     prompts = _prompts((4, 9, 6), seed=3)
-    with _serving_flags(serving_attn_impl="pallas",
-                        serving_kv_dtype="int8"):
+    with _serving_flags(serving_kv_dtype="int8"):
         outs, eng = _run(model, prompts, spec_tokens=2)
     assert eng.spec_tokens == 2
     for p, out in zip(prompts, outs):
-        ref = greedy_search(model, np.asarray([p]), max_new_tokens=5,
-                            cache_len=32)[0].tolist()
-        assert out == ref, f"{p} diverged under spec decode"
+        assert out == _dense_greedy(model, p), \
+            f"{p} diverged under spec decode"
 
 
 @pytest.mark.slow
 @pytest.mark.parametrize("prefix_cache", [True, False])
-def test_engine_pallas_int8_prefix_cache_parity(model, prefix_cache):
+def test_engine_kernel_int8_prefix_cache_parity(model, prefix_cache):
     prompts = _prompts((7, 9), seed=5)
-    with _serving_flags(serving_attn_impl="pallas",
-                        serving_kv_dtype="int8"):
+    with _serving_flags(serving_kv_dtype="int8"):
         eng = ServingEngine(model, max_slots=2, max_len=32,
                             buckets=[8, 16], block_size=4,
                             prefix_cache=prefix_cache)
@@ -416,8 +578,9 @@ def test_engine_pallas_int8_prefix_cache_parity(model, prefix_cache):
         eng.run_until_idle()
     assert rep.state == "done"
     assert rep.output_ids == first[0].output_ids
+    assert rep.output_ids == _dense_greedy(model, prompts[0])
     st = eng.stats()
-    assert st["attn_impl"] == "pallas" and st["kv_dtype"] == "int8"
+    assert st["kv_dtype"] == "int8"
     assert st["kv_quant_max_abs_err"] > 0.0
 
 
@@ -432,7 +595,7 @@ def test_engine_int8_reports_quant_error(model):
 @pytest.mark.parametrize("s,pos", [(1, [3, 15, 4]), (3, [3, 13, 0]),
                                    (8, [0, 5, 8])])
 def test_block_attention_equals_the_gathered_reference(s, pos):
-    """The engine's XLA read path contracts over the blocks as gathered
+    """The engine's prefill read contracts over the blocks as gathered
     ([b, T, h, bs, d]); it is the reference's attention over the
     [b, h, T*bs, d] view, trash-block padding masked alike."""
     rng = np.random.RandomState(11)

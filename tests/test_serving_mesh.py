@@ -56,8 +56,7 @@ def _serving_flags(**kw):
     try:
         yield
     finally:
-        pt.set_flags({"serving_attn_impl": "xla",
-                      "serving_kv_dtype": "f32",
+        pt.set_flags({"serving_kv_dtype": "f32",
                       "serving_mesh": ""})
 
 
@@ -101,17 +100,28 @@ def test_mesh_1x1_engine_matches_sequential_greedy(
 @pytest.mark.slow
 @pytest.mark.parametrize("spec_tokens", [0, 2])
 @pytest.mark.parametrize("kv_dtype", ["f32", "int8"])
-def test_mesh_1x1_pallas_matches_greedy(model, kv_dtype, spec_tokens):
+def test_mesh_1x1_kernel_read_matches_oracle_and_dense_greedy(
+        model, kv_dtype, spec_tokens):
+    """The mesh engine's decode / verify steps read through the paged
+    kernel (inside its shard_map): token for token the same engine traced
+    with the XLA oracle where the kernel stands, and sequential greedy
+    over a dense cache."""
+    from tests.test_paged_attention import _oracle_read
     prompts = _prompts((4, 9, 6), seed=3)
-    with _serving_flags(serving_attn_impl="pallas"):
-        outs, eng = _run_mesh_engine(
-            model, serving_mesh(1, 1), prompts,
-            spec_tokens=spec_tokens, kv_dtype=kv_dtype)
-    assert eng.attn_impl == "pallas"
+    mesh = serving_mesh(1, 1)
+    with _oracle_read():
+        base, _ = _run_mesh_engine(model, mesh, prompts,
+                                   spec_tokens=spec_tokens,
+                                   kv_dtype=kv_dtype)
+    outs, eng = _run_mesh_engine(model, mesh, prompts,
+                                 spec_tokens=spec_tokens, kv_dtype=kv_dtype)
+    assert outs == base
+    st = eng.stats()
+    assert 0 < st["kv_blocks_live"] < st["kv_blocks_table"]
     for p, out in zip(prompts, outs):
         ref = greedy_search(model, np.asarray([p]), max_new_tokens=5,
                             cache_len=32)[0].tolist()
-        assert out == ref, f"{p} diverged (pallas, kv={kv_dtype})"
+        assert out == ref, f"{p} diverged (kv={kv_dtype})"
 
 
 def test_mesh_prefix_reuse_stays_exact(model):

@@ -328,6 +328,27 @@ def test_with_the_profiler_off_nothing_is_recorded_and_the_account_grows(
 
 
 @pytest.mark.parametrize("path", sorted(PATHS))
+def test_the_account_counts_the_table_entries_the_rows_stood_on(model, path):
+    """``kv_blocks_live`` / ``kv_blocks_table``: at every committed decode
+    flight the live rows' ``ceil(length / block_size)`` against the whole
+    table, from the lengths the host holds (PR 39: what the paged kernel
+    had to read of what the gathered table held)."""
+    eng = _warm(model, **PATHS[path])
+    before = _account(eng)
+    bs, table = eng.cache.block_size, eng.cache.tables.size
+    for p, m in _prompts():
+        eng.submit(p, max_new_tokens=m)
+    eng.run_until_idle()
+    grew = {k: v - before[k] for k, v in _account(eng).items()}
+    assert grew["kv_blocks_table"] == grew["decode_flights"] * table
+    assert 0 < grew["kv_blocks_live"] < grew["kv_blocks_table"]
+    # no row can stand on more entries than its longest context needs
+    longest = max(len(p) + m for p, m in _prompts())
+    assert grew["kv_blocks_live"] <= grew["decode_flights"] \
+        * eng.max_slots * -(-longest // bs)
+
+
+@pytest.mark.parametrize("path", sorted(PATHS))
 def test_every_key_of_the_account_is_monotone(model, path):
     eng = ServingEngine(model, **{**GEOM, **PATHS[path]})
     last = _account(eng)

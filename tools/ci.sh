@@ -22,9 +22,9 @@
 #      budget re-asserted on the paged step names, queue backpressure,
 #      block-pool exhaustion head-of-line; reduced in quick mode) plus
 #      the fused-attention oracle: the Pallas paged decode kernel with
-#      the int8 KV pool (FLAGS_serving_attn_impl=pallas +
-#      FLAGS_serving_kv_dtype=int8, interpret mode on CPU) must stay
-#      token-identical to the XLA/f32 engine and sequential greedy;
+#      the int8 KV pool (FLAGS_serving_kv_dtype=int8, interpret mode
+#      on CPU) must stay token-identical to the f32 engine, the dense
+#      reference and sequential greedy;
 #      plus the mesh-serving gate: tensor-parallel pjit steps
 #      (FLAGS_serving_mesh) and the data-parallel ReplicaRouter
 #      (FLAGS_serving_replicas) token-identical to greedy with the
@@ -173,7 +173,7 @@ else
     -k "matches_sequential or queue_full or block_allocator \
 or paged_engine_matches or prefix_reuse"
   JAX_PLATFORMS=cpu python -m pytest tests/test_paged_attention.py -q \
-    -k "engine_pallas_matches or kernel_matches_reference_int8"
+    -k "engine_kernel_read_matches or kernel_matches_reference_int8"
   echo "   mesh-sharded serving gate: reduced subset (quick mode)"
   python -m pytest tests/test_serving_mesh.py tests/test_serving_router.py \
     -q -m "not slow" \

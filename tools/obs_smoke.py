@@ -10,9 +10,9 @@ Asserts, end to end through the observability plane:
     (the PR 3/4 invariants, regression-locked via the new plane);
   - a repeated prompt scores a prefix-cache hit (STAT_serving_prefix_hits)
     without adding a single compile;
-  - rerunning the same workload with FLAGS_serving_attn_impl=pallas +
-    FLAGS_serving_kv_dtype=int8 (fused paged kernel in interpret mode,
-    quantized KV pool) stays token-identical, retraces each site exactly
+  - rerunning the same workload with FLAGS_serving_kv_dtype=int8 (the
+    paged kernel in interpret mode over the quantized KV pool) stays
+    token-identical, retraces each site exactly
     once (flags-version keying), and the merged two-phase recompile
     prediction still equals the live tracker;
   - the same workload through two ReplicaRouter replicas (shared model
@@ -41,7 +41,7 @@ Asserts, end to end through the observability plane:
     no-op claim;
   - mixed greedy / sampled / JSON-constrained / two-tenant-LoRA
     traffic on one engine (pool geometry via set_flags = one fresh
-    phase like pallas+int8): the json_mode row decodes to valid JSON,
+    phase like the int8 one): the json_mode row decodes to valid JSON,
     tenants diverge from base, a mid-flight ``load_adapter`` and the
     whole second wave add ZERO compiles, the per-phase compile delta
     equals the predictor's claim (``sampling`` recipes are validated
@@ -217,12 +217,11 @@ def main() -> int:
         f"  predicted {predicted}\n  observed  {observed}")
     print(f"   recompile predictor: {predicted} == observed")
 
-    # -- pallas + int8 phase: same workload, fused kernel + quantized
+    # -- int8 phase: same workload, the paged kernel over the quantized
     # KV pool. set_flags bumps the flags version, so each site retraces
     # exactly once; outputs must stay token-identical and the merged
     # two-phase prediction must equal the tracker.
-    pt.set_flags({"serving_attn_impl": "pallas",
-                  "serving_kv_dtype": "int8"})
+    pt.set_flags({"serving_kv_dtype": "int8"})
     try:
         eng2 = ServingEngine(model, max_slots=3, max_len=32,
                              buckets=[8, 16], max_queue=16, block_size=4)
@@ -232,16 +231,16 @@ def main() -> int:
         eng2.run_until_idle()
         for a, b in zip(reqs + [rep], reqs2 + [rep2]):
             assert a.output_ids == b.output_ids, (
-                f"pallas+int8 diverged on request {b.id}: "
+                f"int8 diverged on request {b.id}: "
                 f"{a.output_ids} vs {b.output_ids}")
         st2 = eng2.stats()
-        assert st2["attn_impl"] == "pallas" and st2["kv_dtype"] == "int8"
+        assert st2["kv_dtype"] == "int8"
         assert st2["kv_quant_max_abs_err"] > 0.0, st2
         writes = monitor.stat_get("STAT_serving_kv_quant_writes")
         assert writes >= 1, writes
         predicted2 = predict_serving_compiles(
             workload, buckets=[8, 16], max_len=32, block_size=4,
-            attn_impl="pallas", kv_dtype="int8")
+            kv_dtype="int8")
         merged = merge_compile_counts(predicted, predicted2)
         comp3 = observability.compiles()
         observed3 = {site: c["count"] for site, c in comp3.items()
@@ -250,12 +249,11 @@ def main() -> int:
         assert merged == observed3, (
             f"two-phase recompile prediction drifted:\n"
             f"  predicted {merged}\n  observed  {observed3}")
-        print(f"   pallas+int8: token-identical, max_abs_err="
+        print(f"   int8: token-identical, max_abs_err="
               f"{st2['kv_quant_max_abs_err']}, merged prediction == "
               f"observed")
     finally:
-        pt.set_flags({"serving_attn_impl": "xla",
-                      "serving_kv_dtype": "f32"})
+        pt.set_flags({"serving_kv_dtype": "f32"})
 
     # -- mesh + replica phase: the same workload on (a) two data-
     # parallel replicas behind the ReplicaRouter and (b) a 1x1
@@ -489,7 +487,7 @@ def main() -> int:
           f"weights, 0 new compiles (predicted == observed)")
 
     # -- decoding phase: sampling-as-data + multi-tenant paged LoRA ---
-    # set_flags bumps the flags version (like the pallas phase) and the
+    # set_flags bumps the flags version (like the int8 phase) and the
     # adapter pool joins the step cache key, so the lora-shaped steps
     # retrace exactly once; after that first wave, mixed greedy /
     # sampled / json-constrained / multi-tenant traffic — including a
@@ -574,7 +572,7 @@ def main() -> int:
     # clock, never a jit input. Reset the ring and run a traced burst
     # on a fresh engine at the warm geometry: the decoding phase's
     # finally bumped the flags version, so each site retraces exactly
-    # once (a fresh phase, like the pallas one) and the per-phase
+    # once (a fresh phase, like the int8 one) and the per-phase
     # delta must equal the predictor's claim WITH tracing=True — which
     # must itself equal the prediction without it (the no-op family).
     # Every finished request's blame components must sum exactly to
@@ -1036,7 +1034,7 @@ def main() -> int:
                    "STAT_guardian_skipped", "xla_compiles",
                    "serving_ttft_seconds", "serving_kv_blocks_used",
                    "serving_kv_blocks_free", "STAT_serving_prefix_hits",
-                   "serving_attn_impl", "serving_kv_dequant_max_abs_err",
+                   "serving_kv_dequant_max_abs_err",
                    "STAT_serving_kv_quant_writes", "serving_mesh_devices",
                    "serving_replicas", "serving_queue_depth",
                    "serving_slo_attainment", "serving_shed_total",

@@ -409,20 +409,26 @@ def render_serving_block():
         "(`STAT_serving_pool_rebuilds`) and keeps serving; engines that",
         "share the pool shed their rows when they see its epoch move.",
         "",
-        "The paged decode/verify hot path has two lowerings, selected",
-        "by `FLAGS_serving_attn_impl`: `xla` composes gather ->",
-        "masked-softmax attention from the block pool, while `pallas`",
-        "runs the fused `ops.pallas.paged_attention` kernel — the block",
-        "table is scalar-prefetched and each grid step streams ONE",
-        "physical KV block from the pool into VMEM through the table",
-        "lookup (flash-style online softmax; the `[b, h, capacity, d]`",
-        "gathered view is never materialized). Both lowerings are",
-        "token-identical by construction and CI oracle. Independently,",
-        "`FLAGS_serving_kv_dtype=int8` quantizes the KV pool to int8",
-        "codes with per-block-per-head absmax scales (~4x more KV",
-        "positions in the same pool bytes): writes go through a",
+        "The paged read is picked by the query block's shape, not by a",
+        "flag: a decode or verify block (at most",
+        "`gpt.PAGED_KERNEL_MAX_ROWS` = 8 rows a request) goes through",
+        "the `ops.pallas.paged_attention` kernel — the pools stay in",
+        "HBM, and for each request the kernel copies the blocks its",
+        "length stands on (`ceil((pos + rows) / block_size)` of its",
+        "table's entries, eight whole `[heads, block_size, d]` blocks a",
+        "compute step, the next step's copies in flight) and runs the",
+        "online softmax over them in float32; nothing table-sized is",
+        "gathered, a released slot costs one block. A prefill bucket",
+        "composes gather -> masked-softmax attention from the block",
+        "pool in XLA (`ops.attention_ops.block_attention`). The two",
+        "are token-identical by construction and CI oracle, and",
+        "`engine.stats()` gives `kv_blocks_live` / `kv_blocks_table`:",
+        "the share of the table the decode steps' rows stood on.",
+        "Independently, `FLAGS_serving_kv_dtype=int8` quantizes the KV",
+        "pool to int8 codes with per-block-per-head absmax scales (~4x",
+        "more KV positions in the same pool bytes): writes go through a",
         "quantizing scatter whose scales only grow — committed codes",
-        "never drift when quieter rows land later — and both lowerings",
+        "never drift when quieter rows land later — and both reads",
         "apply the identical `codes * scale / 127` dequantization.",
         "The engine reports the high-water dequantization error as",
         "`kv_quant_max_abs_err` in `stats()` and as the",
