@@ -8,6 +8,7 @@ from .gpt import (GPT_CONFIGS, GPTForCausalLM, GPTModel, gpt2_medium,
 from .laguna import LAGUNA_CONFIGS, LagunaConfig, LagunaForCausalLM
 from .mellum import MELLUM_CONFIGS, MellumConfig, MellumForCausalLM
 from .jamba import JAMBA_CONFIGS, JambaConfig, JambaForCausalLM
+from .lfm2 import LFM2_CONFIGS, Lfm2Config, Lfm2ForCausalLM
 from . import generation
 from .generation import (beam_search, decode_step, decode_step_paged,
                          draft_ngram, greedy_search, sample,

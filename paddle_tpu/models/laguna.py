@@ -40,7 +40,11 @@ all-reduce, which this process does not run.
 Laguna and its relatives is configuration: ``attention_gate`` off, no
 shared expert (``shared_expert_intermediate_size`` 0), ``router_score``
 ``"softmax"`` (over all experts, renormalised over the chosen), one head
-count for every layer (``models/mellum.py`` is such a configuration).
+count for every layer (``models/mellum.py`` is such a configuration); a
+per-head RMSNorm on q and k before the rotary (``qk_norm``) and a
+selection bias on the router's choice (``router_bias``), both off here and
+in Mellum (``models/lfm2.py`` turns them on and reuses
+:class:`LagunaAttention` and :class:`LagunaMoE` beside a mixer of its own).
 
 **Serving.** With ``cache`` / ``cache_pos`` / ``block_tables`` the forward
 is the serving engine's: K and V go through per-request block tables into
@@ -76,12 +80,16 @@ from ..nn import functional as F
 from ..nn.layers_common import Embedding, Linear
 from ..ops.attention_ops import block_attention_gqa, block_scatter_write
 from ..ops.decoder_ops import (DECODE_TILE_M, _moe_router, moe_experts,
-                               moe_experts_decode, rotary_inv_freq,
+                               moe_experts_decode_counts, rotary_inv_freq,
                                rotary_tables)
 from ..param_attr import ParamAttr
 from ..profiler import RecordEvent
 
 _PERIOD = ("full_attention",) + ("sliding_attention",) * 3
+#: what a decode step's expert layers count on the device, summed over the
+#: layers (``LagunaMoE.served``): a served model's seam names a prefix of
+#: these as its ``counters``
+DECODE_COUNTERS = ("experts_touched", "expert_rows_max")
 #: rotary parameters by layer kind, as Laguna-XS.2 publishes them
 _ROPE = {
     "full_attention": {
@@ -132,6 +140,15 @@ class LagunaConfig:
     # ("sigmoid", or "softmax" over all experts)
     attention_gate: bool = True
     router_score: str = "sigmoid"
+    # a per-head RMSNorm (its gain over head_dim) on q and on k, before
+    # the rotary
+    qk_norm: bool = False
+    # the router chooses by ``s + expert_bias`` and weighs by the chosen
+    # ``s`` (``expert_bias`` [experts] float32, a parameter); what is added
+    # to the chosen scores' sum before the division
+    router_bias: bool = False
+    router_bias_init_std: float = 0.0
+    router_renorm_eps: float = 0.0
     # the parameters' dtype (and the served pools')
     dtype: str = "float32"
     # the std of the embedding's rows and of the routers' weights where a
@@ -177,6 +194,25 @@ class LagunaConfig:
     @property
     def vocab(self):
         return self._range(self.held_vocab, self.vocab_size)
+
+    @property
+    def kv_pack(self) -> int:
+        """KV heads that share one row of the served pool, side by side on
+        its last axis (1: a head a row). On the chip an array's last axis
+        is laid out in whole tiles of 128 lanes, so a pool of rows
+        narrower than that takes up to twice its bytes and the paged
+        kernel cannot copy a block of it: where no layer has a window, as
+        many heads as fill the 128 (and divide the held heads) go in a row,
+        ``[blocks, kv / pack, block, pack x d]``. Nothing sets this: it
+        follows from ``head_dim``, and a head of 128 or more keeps a row
+        to itself. A packed pool's decode read is the paged kernel's
+        (:meth:`LagunaAttention._paged_read`); a head a row keeps the
+        composed read."""
+        if "sliding_attention" in self.layer_types:
+            return 1
+        kv = self.kv_heads[1] - self.kv_heads[0]
+        return max(p for p in range(1, kv + 1)
+                   if kv % p == 0 and p * self.head_dim <= 128)
 
     def query_heads(self, layer: int) -> int:
         """Query heads of ``layer`` this share holds."""
@@ -224,6 +260,11 @@ def _w(std):
 def _linear(n_in, n_out, std, dtype="float32"):
     return Linear(n_in, n_out, weight_attr=_w(std), bias_attr=False,
                   dtype=dtype)
+
+
+def no_counts():
+    """What a layer with no experts, or a prompt's pass, counts."""
+    return jnp.zeros((len(DECODE_COUNTERS),), jnp.int32)
 
 
 def _matmul_in(x, dtype: str):
@@ -275,6 +316,9 @@ class LagunaAttention(Layer):
         if cfg.attention_gate:
             self.g_proj = _linear(h, self.q, cfg.init_std, cfg.dtype)
         self.o_proj = _linear(self.q * d, h, out_std, cfg.dtype)
+        if cfg.qk_norm:
+            self.q_norm = RMSNorm(d, cfg.rms_norm_eps, cfg.dtype)
+            self.k_norm = RMSNorm(d, cfg.rms_norm_eps, cfg.dtype)
         rope = cfg.rope_parameters[self.kind]
         self.rot_dim = int(d * float(rope.get("partial_rotary_factor", 1.0)))
         self.rope = rope
@@ -292,20 +336,25 @@ class LagunaAttention(Layer):
 
     def _heads(self, qkv, cos, sin):
         """The projection's columns as q [b, heads, s, d] and k, v
-        [b, kv, s, d]; q and k rotated."""
+        [b, kv, s, d]; q and k normed a head (``qk_norm``) and rotated."""
         d = self.cfg.head_dim
         b, s, _ = qkv.shape
 
-        def heads(lo, n, rotate):
+        def heads(lo, n):
             x = qkv[:, :, lo * d:(lo + n) * d].reshape([b, s, n, d])
-            x = x.transpose([0, 2, 1, 3])
-            if rotate:
-                x = run_op("rotary_embedding",
-                           {"X": [x], "Cos": [cos], "Sin": [sin]},
-                           {})["Out"][0]
-            return x
-        return (heads(0, self.q, True), heads(self.q, self.kv, True),
-                heads(self.q + self.kv, self.kv, False))
+            return x.transpose([0, 2, 1, 3])
+
+        def rotated(x, norm):
+            if norm is not None:
+                x = norm(x)
+            return run_op("rotary_embedding",
+                          {"X": [x], "Cos": [cos], "Sin": [sin]},
+                          {})["Out"][0]
+        normed = self.cfg.qk_norm
+        return (rotated(heads(0, self.q), self.q_norm if normed else None),
+                rotated(heads(self.q, self.kv),
+                        self.k_norm if normed else None),
+                heads(self.q + self.kv, self.kv))
 
     def _attend(self, q, k, v):
         """Causal (and windowed) attention of a call's rows over
@@ -363,7 +412,16 @@ class LagunaAttention(Layer):
         cos, sin = (Tensor(f(ang) * att, stop_gradient=True)
                     for f in (jnp.cos, jnp.sin))
         q, k, v = self._heads(self.qkv_proj(h), cos, sin)
-        kw, vw, wpos = k.value, v.value, pos
+        pack = cfg.kv_pack
+
+        def rows_of(x):
+            """[b, kv, s, d] -> the pool's rows [b, kv / pack, s, pack x
+            d]: ``pack`` neighbouring heads side by side."""
+            if pack == 1:
+                return x
+            return x.reshape(b, self.kv // pack, pack, s, d).transpose(
+                0, 1, 3, 2, 4).reshape(b, self.kv // pack, s, pack * d)
+        kw, vw, wpos = rows_of(k.value), rows_of(v.value), pos
         keep = (-(-self.window // bs) + 1) * bs
         if self.window and s > keep:
             # a prompt longer than the window keeps the rows its last
@@ -381,6 +439,10 @@ class LagunaAttention(Layer):
         vp = block_scatter_write(vp, vw, wpos, tables)
         if s > 1:
             o = self._attend(q, k, v)
+        elif pack > 1:
+            # rows of several heads: the gather reads a head a row
+            o = Tensor(self._paged_read(q.value, kp, vp, tables, pos),
+                       stop_gradient=True).transpose([0, 2, 1, 3])
         else:
             o = Tensor(block_attention_gqa(q.value, kp, vp, tables, pos,
                                            self.window).astype(q.dtype),
@@ -390,6 +452,26 @@ class LagunaAttention(Layer):
         return (self.o_proj(o.reshape([b, s, self.q * d])),
                 (Tensor(kp, stop_gradient=True),
                  Tensor(vp, stop_gradient=True)))
+
+    def _paged_read(self, q, kp, vp, tables, pos):
+        """One decode row a request through ``paged_decode_attn``: ``q``
+        [b, hq, 1, d] -> [b, hq, 1, d]. Where the pool packs ``pack`` KV
+        heads in a row, a query head's ``d`` values go to its KV head's
+        lanes of the row (zeros elsewhere, which add nothing to its
+        logits) and its output is read back from the same lanes."""
+        from ..ops.pallas.paged_attention import paged_attention
+        cfg, d = self.cfg, self.cfg.head_dim
+        pack = cfg.kv_pack
+        scale = 1.0 / math.sqrt(d)
+        if pack == 1:
+            return paged_attention(q, kp, vp, tables, pos, scale=scale)
+        b, hq = q.shape[0], q.shape[1]
+        lanes = jax.nn.one_hot(
+            (jnp.arange(hq) // (hq // self.kv)) % pack, pack,
+            dtype=q.dtype)[None, :, None, :, None]      # [1, hq, 1, pack, 1]
+        wide = (q[:, :, :, None, :] * lanes).reshape(b, hq, 1, pack * d)
+        o = paged_attention(wide, kp, vp, tables, pos, scale=scale)
+        return jnp.sum(o.reshape(b, hq, 1, pack, d) * lanes, axis=3)
 
 
 class LagunaMoE(Layer):
@@ -418,20 +500,31 @@ class LagunaMoE(Layer):
             [hi - lo, h, 2 * f], attr=_w(cfg.init_std), dtype=cfg.dtype)
         self.experts_down = self.create_parameter(
             [hi - lo, f, h], attr=_w(out_std), dtype=cfg.dtype)
+        if cfg.router_bias:
+            # a buffer of the load balancer's in the published model: it
+            # takes no gradient (the op's ``Bias`` slot has none)
+            self.expert_bias = self.create_parameter(
+                [cfg.num_experts], attr=_w(cfg.router_bias_init_std),
+                dtype="float32")
         if cfg.shared_expert_intermediate_size:
             self.shared = SwiGLU(h, cfg.shared_expert_intermediate_size,
                                  cfg.init_std, out_std, cfg.dtype)
 
     def _router_attrs(self):
         cfg = self.cfg
-        return {"top_k": cfg.num_experts_per_tok,
-                "scale": cfg.moe_routed_scaling_factor,
-                "score": cfg.router_score}
+        attrs = {"top_k": cfg.num_experts_per_tok,
+                 "scale": cfg.moe_routed_scaling_factor,
+                 "score": cfg.router_score}
+        if cfg.router_renorm_eps:
+            attrs["renorm_eps"] = cfg.router_renorm_eps
+        return attrs
 
     def forward(self, u):
         cfg = self.cfg
-        r = run_op("moe_router", {"X": [u], "W": [self.router.weight]},
-                   self._router_attrs())
+        ins = {"X": [u], "W": [self.router.weight]}
+        if cfg.router_bias:
+            ins["Bias"] = [self.expert_bias]
+        r = run_op("moe_router", ins, self._router_attrs())
         e = run_op("moe_experts",
                    {"X": [u], "TopkIdx": r["TopkIdx"],
                     "TopkWeight": r["TopkWeight"],
@@ -448,28 +541,34 @@ class LagunaMoE(Layer):
     def served(self, u, live=None):
         """The serving engine's call, on arrays: ``u`` float32 [b, s, h]
         (the norm's output; the router reads it as it is, the experts in
-        the parameters' dtype) -> (output float32 [b, s, h], experts
-        touched, int32: counted where ``s`` is 1). One row a request
-        (``live`` [b]: which are requests at all) takes the few-rows form;
-        a prompt takes the training path's, ``moe_chunk_rows`` rows a
-        pass."""
+        the parameters' dtype) -> (output float32 [b, s, h], int32 [2]:
+        the experts some live row chose and the largest expert's rows,
+        both counted where ``s`` is 1: :data:`DECODE_COUNTERS`). One row a
+        request (``live`` [b]: which are requests at all) takes the
+        few-rows form; a prompt takes the training path's,
+        ``moe_chunk_rows`` rows a pass."""
         cfg = self.cfg
         b, s, h = u.shape
         dt = jnp.dtype(cfg.dtype)
         w13, w2 = self.experts_gate_up.value, self.experts_down.value
         router = self.router.weight.value
 
+        ins = {"W": [router]}
+        if cfg.router_bias:
+            ins["Bias"] = [self.expert_bias.value]
+
         def route(x):
-            r = _moe_router(None, {"X": [x], "W": [router]},
-                            self._router_attrs())
+            r = _moe_router(None, dict(ins, X=[x]), self._router_attrs())
             return r["TopkIdx"][0], r["TopkWeight"][0].astype(dt)
 
         flat = u.reshape(b * s, h)
         if s == 1:
             idx, weight = route(flat)
-            out, touched = moe_experts_decode(
+            out, rows = moe_experts_decode_counts(
                 flat.astype(dt), weight, idx, w13, w2, live,
                 min(cfg.moe_tile_m, DECODE_TILE_M))
+            counted = jnp.stack([jnp.sum(rows > 0), jnp.max(rows)]
+                                ).astype(jnp.int32)
         else:
             def one(x):
                 idx, weight = route(x)
@@ -481,12 +580,12 @@ class LagunaMoE(Layer):
                 out = jax.lax.map(one, flat.reshape(-1, chunk, h))
             else:
                 out = one(flat)
-            touched = jnp.zeros((), jnp.int32)
+            counted = no_counts()
         out = out.reshape(b, s, h).astype(jnp.float32)
         if cfg.shared_expert_intermediate_size:
             sh = self.shared(Tensor(u.astype(dt), stop_gradient=True))
             out = out + sh.value.astype(jnp.float32)
-        return out, touched
+        return out, counted
 
 
 class LagunaBlock(Layer):
@@ -519,7 +618,7 @@ class LagunaBlock(Layer):
 
     def served(self, x, cache, cache_pos, tables, ctx_len, live):
         """The serving engine's call: ``x`` the float32 residual stream
-        -> (x, this layer's pools, experts touched)."""
+        -> (x, this layer's pools, the expert layer's counts)."""
         dt = self.cfg.dtype
         a, cache = self.attn(_matmul_in(self.attn_norm(x), dt), cache,
                              cache_pos, tables, ctx_len)
@@ -527,9 +626,9 @@ class LagunaBlock(Layer):
         u = self.mlp_norm(x)
         if not self.sparse:
             y = self.mlp(_matmul_in(u, dt)).astype("float32")
-            return x + y, cache, jnp.zeros((), jnp.int32)
-        y, touched = self.moe.served(u.value, live)
-        return x + Tensor(y, stop_gradient=True), cache, touched
+            return x + y, cache, no_counts()
+        y, counted = self.moe.served(u.value, live)
+        return x + Tensor(y, stop_gradient=True), cache, counted
 
 
 class LagunaModel(Layer):
@@ -589,9 +688,9 @@ class LagunaModel(Layer):
     def served(self, input_ids, cache, cache_pos, block_tables,
                last=None, collect=None):
         """The serving engine's call -> (the final norm's output float32
-        [b, s, h], the caches, the experts some live row chose summed over
-        the layers: an int32 scalar). ``cache``: one (k, v) pool pair a
-        layer. ``block_tables``: one
+        [b, s, h], the caches, the expert layers' counts summed over the
+        layers: int32 [2], :data:`DECODE_COUNTERS`). ``cache``: one (k, v)
+        pool pair a layer. ``block_tables``: one
         table a layer kind in ``cfg.cache_kinds()``'s order (the table
         itself where there is one kind). ``last`` [b]: each prompt's last
         row in this call (None: every row is one)."""
@@ -611,7 +710,7 @@ class LagunaModel(Layer):
                          else jnp.asarray(last, jnp.int32) + 1)
         # a slot with no request has no row yet: it routes nowhere
         live = pos > 0 if s == 1 else None
-        caches, touched = [], jnp.zeros((), jnp.int32)
+        caches, touched = [], no_counts()
         for i, blk in enumerate(self.layers):
             x, c, t = blk.served(x, cache[i], pos,
                                  table_of[cfg.layer_types[i]], ctx_len, live)
@@ -658,8 +757,10 @@ class LagunaForCausalLM(Layer):
                     input_ids, cache, cache_pos, block_tables, last, collect)
             if counters is None:
                 return logits, caches
-            # the decode step's device counters (``serving_spec``'s names)
-            return logits, caches, counters + touched.astype(counters.dtype)
+            # the decode step's device counters (``serving_spec``'s names:
+            # a prefix of DECODE_COUNTERS)
+            return logits, caches, counters \
+                + touched[:counters.shape[0]].astype(counters.dtype)
         with span:
             logits = self.lm_head(self.model(input_ids, collect))
         if labels is None:
@@ -679,7 +780,7 @@ class LagunaForCausalLM(Layer):
                 collect):
         """-> (logits float32 [b, s, vocab], or [b, 1, vocab] of each
         prompt's ``last`` row: the head never multiplies a bucket's
-        padding; the caches; the experts touched)."""
+        padding; the caches; the expert layers' counts)."""
         h, caches, touched = self.model.served(
             input_ids, cache, cache_pos, block_tables, last, collect)
         h = h.value

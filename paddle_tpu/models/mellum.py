@@ -17,7 +17,8 @@ experts, the router op, the dropless expert layer and its grouped
 products, the untied head, the build / first-trace spans. **Configured
 off**: the per-head output gate, the shared expert, the sigmoid scores and
 their 2.5 scale, the dense leading layer, head counts that differ by
-layer. **What could not be shared** is what serving adds and training has
+layer, the per-head norm on q and k (``qk_norm``) and the router's
+selection bias (``router_bias``; ``models/lfm2.py`` turns both on). **What could not be shared** is what serving adds and training has
 not: the paged cache by layer kind, positions applied at a row's own
 offset, the few-rows form of the expert layer; that lives in the same
 classes (``LagunaAttention._served``, ``LagunaMoE.served``), so Laguna is
@@ -102,7 +103,8 @@ class MellumForCausalLM(LagunaForCausalLM):
         kinds = tuple(
             CacheKind({"full_attention": "full",
                        "sliding_attention": "window"}[name], layers,
-                      cfg.num_key_value_heads, cfg.head_dim, window)
+                      cfg.num_key_value_heads // cfg.kv_pack,
+                      cfg.head_dim * cfg.kv_pack, window)
             for name, layers, window in cfg.cache_kinds())
         return ServedModel(
             model=self, family="mellum",

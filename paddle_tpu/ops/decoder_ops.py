@@ -122,13 +122,18 @@ def _rotary_embedding(ctx, ins, attrs):
 
 # ---------------------------------------------------------------- router
 
-@register("moe_router", no_grad_slots=(), nondiff_outputs=("TopkIdx",))
+@register("moe_router", no_grad_slots=("Bias",),
+          nondiff_outputs=("TopkIdx",))
 def _moe_router(ctx, ins, attrs):
     """X [.., h] x W [h, experts] -> the ``top_k`` experts of every token
     (TopkIdx int32 [.., k]) and their weights (TopkWeight float32 [.., k]):
     ``scale * s_e / sum of the chosen s``, ``s = sigmoid(x W)`` (attr
     ``score`` ``"softmax"``: the softmax over all experts) in float32 at
-    full precision (under AMP the op is on the float32 list)."""
+    full precision (under AMP the op is on the float32 list). With the
+    optional input ``Bias`` [experts] the choice is ``top_k(s + Bias)`` and
+    the weights are the chosen experts' *unbiased* ``s``, renormalised: a
+    selection bias steers the load and never the mixture. Attr
+    ``renorm_eps`` is added to the chosen scores' sum (0: none)."""
     x, w = ins["X"][0], ins["W"][0]
     k = int(attrs["top_k"])
     score = {"sigmoid": jax.nn.sigmoid,
@@ -137,9 +142,18 @@ def _moe_router(ctx, ins, attrs):
     scores = score(jnp.matmul(
         x.astype(jnp.float32), w.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST))
-    top, idx = jax.lax.top_k(scores, k)
-    weight = float(attrs.get("scale", 1.0)) * top \
-        / jnp.sum(top, axis=-1, keepdims=True)
+    bias = (ins.get("Bias") or [None])[0]
+    if bias is None:
+        top, idx = jax.lax.top_k(scores, k)
+    else:
+        _, idx = jax.lax.top_k(scores + bias.astype(jnp.float32), k)
+        top = jnp.take_along_axis(scores, idx, axis=-1)
+    scaled = float(attrs.get("scale", 1.0)) * top
+    total = jnp.sum(top, axis=-1, keepdims=True)
+    eps = float(attrs.get("renorm_eps", 0.0))
+    if eps:
+        total = total + eps
+    weight = scaled / total
     return {"TopkIdx": [idx.astype(jnp.int32)], "TopkWeight": [weight]}
 
 
@@ -549,12 +563,13 @@ moe_experts.defvjp(_experts_fwd, _experts_bwd)
 DECODE_TILE_M = 16
 
 
-def moe_experts_decode(x, weight, idx, w13, w2, live=None,
-                       tm: int = DECODE_TILE_M):
+def moe_experts_decode_counts(x, weight, idx, w13, w2, live=None,
+                              tm: int = DECODE_TILE_M):
     """The expert layer at a few rows (a decode step: one row a request),
     forward only, every expert held. x [t, h], weight / idx [t, k], w13
     [e, h, 2f], w2 [e, f, h], ``live`` bool [t] (rows of empty slots route
-    nowhere) -> (out float32 [t, h], experts touched int32 []).
+    nowhere) -> (out float32 [t, h], the live rows every expert got, int32
+    [e]).
 
     Where training has thousands of rows a call, tiles of 128 rows and is
     bound by compute, a step of 16 rows x 8 choices touches most of the
@@ -574,7 +589,16 @@ def moe_experts_decode(x, weight, idx, w13, w2, live=None,
     route = _route(idx, valid, groups, tm, tiles, min_tiles=0)
     out, _ = _set_fwd(x, weight, route, valid, w13, w2, tm,
                       names=("moe_up_dec", "moe_down_dec"))
-    return out, jnp.sum(route["counts"] > 0).astype(jnp.int32)
+    return out, route["counts"].astype(jnp.int32)
+
+
+def moe_experts_decode(x, weight, idx, w13, w2, live=None,
+                       tm: int = DECODE_TILE_M):
+    """:func:`moe_experts_decode_counts` -> (out float32 [t, h], experts
+    touched int32 [])."""
+    out, counts = moe_experts_decode_counts(x, weight, idx, w13, w2, live,
+                                            tm)
+    return out, jnp.sum(counts > 0).astype(jnp.int32)
 
 
 @register("moe_experts", no_grad_slots=("TopkIdx",),

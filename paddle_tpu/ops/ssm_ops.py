@@ -1,4 +1,7 @@
-"""The arithmetic of a selective state-space (Mamba) mixer, on arrays.
+"""The arithmetic of a selective state-space (Mamba) mixer, on arrays; its
+causal convolution and the tail it carries (:func:`causal_conv`,
+:func:`conv_tail`) are also the whole recurrence of a gated
+short-convolution mixer (``models/lfm2.py``: K = 3, no bias).
 
 A mixer carries two things from token to token, per request: the scan
 state ``s`` ``[d_state, d_inner]`` (float32: the recurrence multiplies it
@@ -34,11 +37,11 @@ from .pallas.utils import interpret_mode, pick_block
 CHUNK = 64
 
 
-def causal_conv(xp, weight, bias, tail=None):
+def causal_conv(xp, weight, bias=None, tail=None):
     """Depthwise causal convolution: ``xp`` [b, T, d] (any float dtype),
-    ``weight`` [d, K], ``bias`` [d], ``tail`` [b, K - 1, d] the rows before
-    row 0 (None: zeros) -> float32 [b, T, d],
-    ``bias + sum_j weight[:, j] * xp[t - (K - 1) + j]``."""
+    ``weight`` [d, K], ``bias`` [d] (None: a convolution with none),
+    ``tail`` [b, K - 1, d] the rows before row 0 (None: zeros) -> float32
+    [b, T, d], ``bias + sum_j weight[:, j] * xp[t - (K - 1) + j]``."""
     b, t, d = xp.shape
     k = weight.shape[1]
     if tail is None:
@@ -46,7 +49,7 @@ def causal_conv(xp, weight, bias, tail=None):
     rows = jnp.concatenate([tail.astype(xp.dtype), xp], axis=1)
     rows = rows.astype(jnp.float32)
     w = weight.astype(jnp.float32)
-    out = bias.astype(jnp.float32)[None, None, :]
+    out = 0.0 if bias is None else bias.astype(jnp.float32)[None, None, :]
     for j in range(k):
         out = out + rows[:, j:j + t] * w[None, None, :, j]
     return out

@@ -12,9 +12,10 @@ from one :class:`ServedModel`, which the model builds
   last ``window`` rows of a request (``BlockKVCache`` then bounds that
   kind's pool and frees its blocks behind the window);
 - its recurrent state, one :class:`StateKind` a kind of layer that carries
-  a fixed-size record from token to token whatever the context (a
-  state-space layer's scan state and its convolution's tail): which
-  layers, and the shape and dtype of each array a request keeps. The one
+  a fixed-size record from token to token whatever the context: which
+  layers, and the shape and dtype of each array a request keeps, one array
+  or several (a state-space layer's scan state and its convolution's tail
+  are two; a gated short convolution's tail alone is one). The one
   cache manager holds them beside the blocks, ``[max_slots, ...]`` a layer
   and indexed by the request's row; a kind's entry of the block tables is
   the row index of each row of the dispatch (``max_slots`` for a row that
@@ -37,6 +38,11 @@ from one :class:`ServedModel`, which the model builds
   argument and returns as its last result; the engine carries it from
   step to step beside the step's tokens and keys, so the step's fetch
   stays what it is and only ``engine.stats()`` reads them).
+
+A model may declare blocks, a recurrent kind, experts' device counters and
+any number of rows at once (``models/lfm2.py`` does: 128 rows a decode
+step); nothing in the engine or the cache manager is sized by a model's
+name or by another model's arrays.
 
 The defaults are the generic paged forward every model of this repo with a
 ``model(ids, cache=, cache_pos=, block_tables=, lora=)`` call shares, so
@@ -78,7 +84,8 @@ class CacheKind:
 
 @dataclass(frozen=True)
 class StateKind:
-    """One kind of layer that keeps a fixed-size record a request."""
+    """One kind of layer that keeps a fixed-size record a request: any
+    number of arrays of any shape and dtype."""
     name: str
     layers: Tuple[int, ...]     # the model's layer indices, ascending
     #: (shape, dtype name) of each array one request keeps in one layer
@@ -167,7 +174,8 @@ def served(model) -> ServedModel:
             f"{type(model).__name__} is not a served model: the serving "
             f"plane reads a model through model.serving_spec() -> "
             f"paddle_tpu.serving.seam.ServedModel (GPTForCausalLM, "
-            f"MellumForCausalLM and JambaForCausalLM have one)")
+            f"MellumForCausalLM, JambaForCausalLM and Lfm2ForCausalLM "
+            f"have one)")
     return spec()
 
 

@@ -182,7 +182,25 @@ def test_served_grouped_products_compile_for_v5e(one_chip, mosaic, name,
     k, n = (2304, 1792) if "up" in name else (896, 2304)
     assert gm._column_tile(n, 1024) == (896 if "up" in name else 768)
     assert gm._column_tile(1024, 1024) == gm._column_tile(2048, 1024) == 1024
+    _grouped_product_compiles(one_chip, name, rows, tm, k, n)
 
+
+# LFM2's expert layer at published widths (64 experts of 1536 under hidden
+# 2048): a decode step's 128 rows x 4 choices (512 / 16 + 64 tiles), and
+# the longest prefill dispatch's 2048 rows x 4 choices in tiles of 128
+@pytest.mark.parametrize("name,rows,tm", [("moe_up_dec", 96 * 16, 16),
+                                          ("moe_down_dec", 96 * 16, 16),
+                                          ("moe_up", 8192 + 64 * 128, 128),
+                                          ("moe_down", 8192 + 64 * 128,
+                                           128)])
+def test_lfm2_grouped_products_compile_for_v5e(one_chip, mosaic, name, rows,
+                                               tm):
+    k, n = (2048, 3072) if "up" in name else (1536, 2048)
+    assert gm._column_tile(n, 1024) == 1024
+    _grouped_product_compiles(one_chip, name, rows, tm, k, n)
+
+
+def _grouped_product_compiles(one_chip, name, rows, tm, k, n):
     def s(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
     text = jax.jit(lambda x, w, tg, na: gm.gmm(
@@ -270,6 +288,12 @@ PAGED_CASES = {           # q_len, q dtype, pool dtype, int8 scales
     # on 4 KV heads, 256-row bfloat16 blocks, 16 slots over 801 blocks
     "decode_gqa_bf16_bs256": (1, jnp.float32, jnp.bfloat16, False,
                               dict(b=16, hq=32, hkv=4, bs=256, nb=801)),
+    # lfm2_agents_3k's read: 128 rows, 32 query heads of 64 over 8 KV
+    # heads packed two a row of 128 (LagunaConfig.kv_pack: a pool of
+    # 64-wide rows is refused, "slice shape ... must be aligned to tiling
+    # (128)"), 256-row bfloat16 blocks, 1537 blocks
+    "decode_packed_d64_b128": (1, jnp.bfloat16, jnp.bfloat16, False,
+                               dict(b=128, hq=32, hkv=4, bs=256, nb=1537)),
 }
 
 

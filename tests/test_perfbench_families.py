@@ -20,13 +20,16 @@ from perfbench import families, flops, readers, run
 
 CONFIGS = {"cgpt-1p3b": "gpt2", "cgpt-1p3b-d20": "gpt2",
            "laguna-xs2-share8": "laguna", "mellum2-12b-d8": "mellum",
-           "jamba2-3b": "jamba"}
+           "jamba2-3b": "jamba", "lfm2-24b-a2b-d9": "lfm2"}
 JOBS = {"gpt2": "pretrain_1chip", "laguna": "laguna_pretrain_8k",
-        "mellum": "mellum_code_16k", "jamba": "jamba_reasoning_6k"}
+        "mellum": "mellum_code_16k", "jamba": "jamba_reasoning_6k",
+        "lfm2": "lfm2_agents_3k"}
 FAMILY_CONFIG = {"gpt2": "cgpt-1p3b-d20", "laguna": "laguna-xs2-share8",
-                 "mellum": "mellum2-12b-d8", "jamba": "jamba2-3b"}
+                 "mellum": "mellum2-12b-d8", "jamba": "jamba2-3b",
+                 "lfm2": "lfm2-24b-a2b-d9"}
 RATE = {"gpt2": "train_tok_s_chip", "laguna": "train_tok_s_chip",
-        "mellum": "serve_tok_s", "jamba": "serve_tok_s"}
+        "mellum": "serve_tok_s", "jamba": "serve_tok_s",
+        "lfm2": "serve_tok_s"}
 
 
 def config(name):
@@ -37,7 +40,7 @@ def test_an_unknown_family_is_an_error_that_lists_the_known_ones():
     with pytest.raises(SystemExit) as e:
         families.load({"name": "some-model", "family": "no_such_family"})
     assert "no_such_family" in str(e.value)
-    assert families.known() == ["gpt2", "jamba", "laguna", "mellum"]
+    assert families.known() == ["gpt2", "jamba", "laguna", "lfm2", "mellum"]
     assert all(name in str(e.value) for name in families.known())
 
 
@@ -227,6 +230,89 @@ def test_any_64_requests_of_jamba_reasoning_6k_fit_the_pool():
     assert e["prefix_cache"] is False
 
 
+def test_the_lfm2_file_holds_the_published_widths_uncut():
+    """Every key of the catalog's ``config`` but the two in ``reduced``
+    equals the file's: ``layer_types`` and ``num_dense_layers`` stay as
+    published and the cut is read from ``first_layer``."""
+    cfg = config("lfm2-24b-a2b-d9")
+    catalog = "/opt/skills/guides/model-configs/architectures.jsonl"
+    if os.path.exists(catalog):     # the catalog's row, where there is one
+        with open(catalog) as f:
+            row = next(r for r in map(json.loads, f)
+                       if r["name"] == "LFM2-24B-A2B")
+        changed = {k for k, v in row["config"].items() if cfg.get(k, 0) != v}
+        assert changed == {"num_hidden_layers", "max_position_embeddings"}
+        assert cfg["source"] == row["source_url"]
+    assert cfg["reduced"] == list(cfg["reduced_why"]) == \
+        ["num_hidden_layers", "max_position_embeddings"]
+    assert cfg["published"]["num_hidden_layers"] == 40
+    assert (cfg["hidden_size"], cfg["intermediate_size"],
+            cfg["num_attention_heads"], cfg["num_key_value_heads"],
+            cfg["head_dim"], cfg["num_experts"], cfg["num_experts_per_tok"],
+            cfg["moe_intermediate_size"], cfg["conv_L_cache"],
+            cfg["vocab_size"], cfg["num_hidden_layers"],
+            cfg["first_layer"]) == \
+        (2048, 11776, 32, 8, 64, 64, 4, 1536, 3, 65536, 9, 1)
+    assert all(cfg.get(k) for k in ("assumed", "published", "deployment",
+                                    "engine_why"))
+    family = families.load(cfg)
+    # published layers 1-9: the second dense layer, then two whole periods
+    assert family.layer_plan(cfg) == \
+        [("conv", True), ("full_attention", False)] \
+        + [("conv", False)] * 3 + [("full_attention", False)] \
+        + [("conv", False)] * 3
+    mc = family.model_config(cfg)
+    experts = 64 * 3 * 2048 * 1536 + 2048 * 64 + 64
+    conv = 4 * 2048 * 2048 + 3 * 2048
+    attn = 2048 * (32 + 16) * 64 + 32 * 64 * 2048 + 2 * 64
+    dense = 3 * 2048 * 11776
+    assert (experts, conv, attn, dense) == \
+        (604_110_912, 16_783_360, 10_485_888, 72_351_744)
+    assert mc.num_params() == cfg["params_held"] == \
+        8 * experts + 7 * conv + 2 * attn + dense + 9 * 2 * 2048 \
+        + 65536 * 2048 + 2048 == 5_177_950_976
+    assert (mc.qk_norm, mc.router_bias, mc.router_score, mc.dtype,
+            mc.kv_pack) == (True, True, "sigmoid", "bfloat16", 2)
+    # the deployment's bytes: the pool (two heads of 64 in a row of 128),
+    # the tails
+    e = cfg["engine"]
+    spec_pool = e["num_blocks"] * e["block_size"] * 2 * 2 * 4 * 128 * 2
+    assert spec_pool == 1_611_661_312
+    assert e["max_slots"] * 7 * 2 * 2048 * 2 == 7_340_032
+
+
+def test_the_lfm2_family_refuses_a_training_job_by_name():
+    cfg = config("lfm2-24b-a2b-d9")
+    with pytest.raises(SystemExit) as e:
+        families.load(cfg).train_job(cfg, {"kind": "train"})
+    assert "the lfm2 family has no training job" in str(e.value)
+
+
+def test_any_128_requests_of_lfm2_agents_3k_fit_the_pool():
+    """The file is held to what its ``lengths_why`` says: ``max_total``
+    rows 128 times over fit the attention layers' pool, and every prompt
+    reaches a bucket whose dispatch computes the model's budget."""
+    from perfbench import traffic as T
+    cfg, tr = config("lfm2-24b-a2b-d9"), load("traffic",
+                                              "lfm2_agents_3k.json")
+    e = cfg["engine"]
+    pairs = T.multiset(tr)
+    assert len(pairs) == 32 and e["max_slots"] == 128
+    assert tr["queue_depth_slots"] == 1 and tr["preroll_completions"] == 128
+    assert (min(p for p, _ in pairs), max(p for p, _ in pairs),
+            min(a for _, a in pairs), max(a for _, a in pairs)) == \
+        (134, 1961, 262, 1002)
+    assert all(p + a <= tr["multiset"]["max_total"] == 3072 <= e["max_len"]
+               for p, a in pairs)
+    need = -(-tr["multiset"]["max_total"] // e["block_size"])
+    assert need == 12 and 128 * need == e["num_blocks"] - 1
+    assert T.buckets_used(tr, e["buckets"]) == [256, 512, 1024, 2048]
+    mc = families.load(cfg).model_config(cfg)
+    assert [max(1, min(128, mc.tokens_a_dispatch // b))
+            for b in e["buckets"]] == [2, 1, 1, 1]
+    assert e["prefix_cache"] is False
+
+
 # per layer 8*2048^2 + 4*2048*8192 + 2*1024*2048 = 104,857,600; head
 # 2*2048*50304 = 206,045,184; x3 for the backward.
 # Laguna share, forward a token at s 8192: a window layer's projections
@@ -347,6 +433,68 @@ KERNELS.update({
         sum((2 * 20 + 2) * s * 128 * 2.0 + 20 * s * 4.0
             for s in JAMBA_FLASH) / 3),
 })
+
+
+# LFM2's served kernels, one call. A decode step's grouped products: 128
+# rows x 4 choices = 512 pairs and 64 * (1 - (60/64)^128) = 63.98 experts'
+# weights, x [2048 -> 3072] and [1536 -> 2048]. A prefill dispatch computes
+# 512 // bucket rows, at least one (2 x 256, 512, 1024, 2048 positions, 8
+# prompts each: mean 1024 positions = 4096 pairs) against the whole stack.
+# Flash, 32 query heads over 8 KV heads, d 64, over the dispatches that
+# reach the kernel (1024 and 2048; 256 and 512 are under
+# FLAGS_pallas_min_seq). The decode read without counters: the
+# least any call reads, one block a slot at one byte a value.
+TOUCHED_LFM2 = 64 * (1 - (60 / 64) ** 128)
+LFM2_FLASH = ((1, 1024), (1, 2048))
+KERNELS.update({
+    ("lfm2", "moe_up_dec"): (2.0 * 512 * 2048 * 3072,
+                             2.0 * (512 * (2048 + 3072)
+                                    + TOUCHED_LFM2 * 2048 * 3072)),
+    ("lfm2", "moe_down_dec"): (2.0 * 512 * 1536 * 2048,
+                               2.0 * (512 * (1536 + 2048)
+                                      + TOUCHED_LFM2 * 1536 * 2048)),
+    ("lfm2", "moe_up"): (2.0 * 4096 * 2048 * 3072,
+                         2.0 * (4096 * (2048 + 3072) + 64 * 2048 * 3072)),
+    ("lfm2", "moe_down"): (2.0 * 4096 * 1536 * 2048,
+                           2.0 * (4096 * (1536 + 2048) + 64 * 1536 * 2048)),
+    ("lfm2", "flash_fwd_full"): (
+        sum(r * 4.0 * 32 * 64 * s * (s + 1) / 2.0 for r, s in LFM2_FLASH) / 2,
+        sum(r * ((2 * 32 + 2 * 8) * s * 64 * 2.0 + 32 * s * 4.0)
+            for r, s in LFM2_FLASH) / 2),
+    ("lfm2", "paged_decode_attn"): (4.0 * 32 * 256 * 64 * 128,
+                                    2.0 * 8 * 256 * 64 * 128),
+})
+
+
+def test_lfm2_counts_its_data_dependent_kernels_from_the_runs_counters():
+    """The decode read at the live blocks a flight and the decode products
+    at the experts a step, both over the traced interval; a run that read
+    no flight there gives no count, and a share cannot pass 100 when the
+    trace's calls equal the counters' flights."""
+    cfg, job = config("lfm2-24b-a2b-d9"), load("traffic",
+                                               "lfm2_agents_3k.json")
+    counts = families.load(cfg).kernel_counts
+    counters = {"engine.kv_blocks_live.traced": 300 * 512.0,
+                "engine.decode_flights.traced": 300.0, "kv_item_bytes": 2,
+                "engine.experts_touched.traced": 300 * 8 * 60.0,
+                "engine.sampler_dispatches.traced": 300.0}
+    assert counts("paged_decode_attn", cfg, job, counters=counters) == \
+        (4.0 * 32 * 256 * 64 * 512, 2.0 * 8 * 256 * 64 * 2 * 512)
+    assert counts("moe_up_dec", cfg, job, counters=counters)[1] == \
+        2.0 * (512 * (2048 + 3072) + 60 * 2048 * 3072)
+    assert counts("paged_decode_attn", cfg, job, counters={}) is None
+    assert counts("moe_up_dx", cfg, job) is None    # another family's name
+    assert counts("moe_up", cfg, {"kind": "train"}) is None
+    # 600 calls (2 layers x 300 flights) at the memory's speed: 100%
+    flights, got = 300, counts("paged_decode_attn", cfg, job,
+                               counters=counters)
+    floor = flops.kernel_floors({"kernel_calls.paged_decode_attn":
+                                 2.0 * flights}, lambda n: got,
+                                "TPU v5 lite")["kernel_floor_s."
+                                               "paged_decode_attn"]
+    peak = flops.peaks("TPU v5 lite")
+    assert floor == pytest.approx(2 * flights * got[1]
+                                  / peak["hbm_bytes_per_s"])
 
 
 @pytest.mark.parametrize("family,kernel", sorted(KERNELS))
@@ -502,7 +650,11 @@ TWINS = {
         "engine.experts_touched", "engine.window_blocks_freed",
         "live_slot_share", "engine.inputs_resident", "serving.decode_step")),
     "jamba_reasoning_6k": (6, 2**31 + 33, (
-        "serving.decode_step", "engine.prefill_tokens_live"))}
+        "serving.decode_step", "engine.prefill_tokens_live")),
+    "lfm2_agents_3k": (6, 2**31 + 42, (
+        "serving.decode_step", "engine.experts_touched",
+        "engine.expert_rows_max", "engine.state_bytes.close",
+        "engine.prefill_tokens_live"))}
 
 
 def last_line(out):
