@@ -269,10 +269,12 @@ def decode_step(model):
 #: Every paged step entry takes ``pools`` as argument 4 of its jitted
 #: function (after params, two per-row inputs and the tables) and owns
 #: it: the caller's arrays are deleted by the call and the returned
-#: pools take their place. With the in-place row write of
-#: ``ops.attention_ops.block_scatter_write`` that is what keeps a decode
-#: step from copying every pool; a caller that still needs the old
-#: pools (nobody does on the serving path) copies them first.
+#: pools take their place. With the in-place writes of
+#: ``ops.attention_ops.block_scatter_write`` (row updates at 64 rows and
+#: under, one kernel over the touched chunks above: both in the layout
+#: the pool arrived in) that is what keeps a decode step AND a prompt
+#: from copying every pool; a caller that still needs the old pools
+#: (nobody does on the serving path) copies them first.
 POOLS_DONATED = {"donate_argnums": (4,)}
 
 

@@ -84,11 +84,21 @@ def cache_scatter_write(buf, new, pos):
 
 
 #: most rows (batch x rows per request) a KV write lands as unrolled
-#: in-place row updates; above it the one fused scatter is cheaper. On
-#: the v5e an update of one [h, d] row costs ~1.2 us against ~320 us a
-#: pool for the scatter's two layout copies, and 160 rows a pool in 48
-#: pools take 47 s to compile (PERF.md, PR 25).
+#: in-place row updates: one ``dynamic_update_slice`` a row, ~0.9 us each
+#: on the v5e at float32, but a program of its own a row (160 rows a pool
+#: in 48 pools took 47 s to compile: PERF.md, PR 25). Above it the write
+#: is one kernel over the chunks of blocks it touches.
 INPLACE_WRITE_MAX_ROWS = 64
+
+
+def _chunk_rows(s: int, bs: int, dtype) -> int:
+    """Rows of the aligned pieces of a block a many-row write updates:
+    the whole block where the rows span blocks, else the fewest whole
+    sublane tiles of the pool's dtype (8 rows of 32 bits) that hold
+    ``s`` rows and divide the block."""
+    tile = 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
+    c = -(-s // tile) * tile
+    return c if c < bs and bs % c == 0 else bs
 
 
 def block_scatter_write(pool, new, pos, tables, overflow_block=0):
@@ -99,14 +109,23 @@ def block_scatter_write(pool, new, pos, tables, overflow_block=0):
     paged generalization of :func:`cache_scatter_write`, at one fixed
     signature for the compiled decode/verify/prefill steps.
 
-    The form follows the static shape. A few rows (decode: one per
-    request; verify: K+1) are written as one in-place
-    ``dynamic_update_slice`` of ``[1, h, 1, d]`` each, which keeps the
-    pool in the layout it arrived in: with the pool donated to the
-    step nothing pool-sized is copied. Many rows (the prefill buckets)
-    go through one fused scatter, for which XLA's TPU layout assignment
-    moves the whole pool to ``[block, row, head, d]`` and back — a fixed
-    cost that only a large write repays.
+    The form follows the static shape, and both forms keep the pool in
+    the layout it arrived in: with the pool donated to the step nothing
+    pool-sized is copied. A few rows (decode: one per request; verify:
+    K+1) are written as one unrolled in-place ``dynamic_update_slice``
+    of ``[1, h, 1, d]`` each. Many rows (the prefill buckets, a decode
+    step of more than :data:`INPLACE_WRITE_MAX_ROWS` requests) are
+    written by (request, touched chunk): a chunk is an aligned
+    ``[h, c, d]`` piece of one block (:func:`_chunk_rows`: the whole
+    block for a prompt), ``(s + c - 2) // c + 1`` chunks a request
+    whatever ``pos``; the request's rows are laid out chunk by chunk
+    where they land (one gather of what ``new`` holds) and one kernel
+    with the pool aliased in and out merges each chunk with them
+    (``ops/pallas/pool_write.py``). No live block is two work items', so
+    no chunk is read after it was written; only a chunk of the trash
+    block may be. (One fused scatter, the form to PR 42, made XLA's TPU
+    layout assignment move the WHOLE pool to ``[block, row, head, d]``
+    and back, whatever the rows written: PERF.md, PR 43.)
 
     Positions whose logical block falls outside the table (bucketed
     prefill's suffix padding rows, beyond a short request's
@@ -119,18 +138,22 @@ def block_scatter_write(pool, new, pos, tables, overflow_block=0):
     through a position mask.
     """
     pos = jnp.asarray(pos, jnp.int32)
+    tables = jnp.asarray(tables, jnp.int32)
     b, h, s, d = new.shape
     bs = pool.shape[2]
     T = tables.shape[1]
-    rowpos = pos[:, None] + jnp.arange(s, dtype=jnp.int32)[None]  # [b, s]
-    logical = rowpos // bs
-    phys = jnp.take_along_axis(
-        jnp.asarray(tables, jnp.int32),
-        jnp.minimum(logical, T - 1), axis=1)                      # [b, s]
-    phys = jnp.where(logical < T, phys, jnp.int32(overflow_block))
-    offset = rowpos % bs
     new = new.astype(pool.dtype)
+
+    def physical(at):
+        """[b, n] logical positions -> their physical blocks."""
+        logical = at // bs
+        phys = jnp.take_along_axis(tables, jnp.minimum(logical, T - 1),
+                                   axis=1)
+        return jnp.where(logical < T, phys, jnp.int32(overflow_block))
+
     if b * s <= INPLACE_WRITE_MAX_ROWS:
+        rowpos = pos[:, None] + jnp.arange(s, dtype=jnp.int32)[None]
+        phys, offset = physical(rowpos), rowpos % bs
         # all start indices must share a dtype (x64 mode makes a bare
         # python 0 an int64)
         z = jnp.zeros((), jnp.int32)
@@ -140,10 +163,21 @@ def block_scatter_write(pool, new, pos, tables, overflow_block=0):
                     pool, new[i:i + 1, :, j:j + 1],
                     (phys[i, j], z, offset[i, j], z))
         return pool
-    # advanced indices (flat rows) are separated from the heads slice,
-    # so they broadcast to the FRONT: value rows are [b*s, h, d]
-    rows = jnp.swapaxes(new, 1, 2).reshape(b * s, h, d)
-    return pool.at[phys.reshape(-1), :, offset.reshape(-1)].set(rows)
+    c = _chunk_rows(s, bs, pool.dtype)
+    n = (s + c - 2) // c + 1
+    # the first position of each chunk a request may touch, and the row
+    # of ``new`` that lands there (negative: the chunk starts before pos)
+    start = pos[:, None] // c * c + c * jnp.arange(n, dtype=jnp.int32)[None]
+    first = start - pos[:, None]
+    padded = jnp.pad(new, ((0, 0), (0, 0), (c, c), (0, 0)))
+    # [b * n, h, c, d]: each chunk's rows of its request, where they land
+    mine = jax.vmap(lambda rows, at: jax.vmap(
+        lambda f: jax.lax.dynamic_slice_in_dim(rows, f + c, c, axis=1))(at)
+    )(padded, first).reshape(b * n, h, c, d)
+    from .pallas.pool_write import pool_chunk_write
+    return pool_chunk_write(pool, mine, physical(start).reshape(-1),
+                            (start % bs // c).reshape(-1),
+                            first.reshape(-1), s)
 
 
 def block_scatter_write_quant(pool, scales, new, pos, tables,

@@ -37,6 +37,7 @@ fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
 pa = importlib.import_module("paddle_tpu.ops.pallas.paged_attention")
 gm = importlib.import_module("paddle_tpu.ops.pallas.grouped_matmul")
 ss = importlib.import_module("paddle_tpu.ops.pallas.selective_scan")
+pw = importlib.import_module("paddle_tpu.ops.pallas.pool_write")
 
 KERNEL = chip_smoke.KERNEL      # a Mosaic kernel in a compiled program
 
@@ -76,6 +77,7 @@ def mosaic(monkeypatch):
     monkeypatch.setattr(pa, "_interpret", lambda: False)
     monkeypatch.setattr(gm, "_interpret", lambda: False)
     monkeypatch.setattr(ss, "_interpret", lambda: False)
+    monkeypatch.setattr(pw, "_interpret", lambda: False)
     with jax.enable_x64(False):
         yield
 
@@ -376,11 +378,58 @@ def test_paged_attention_runs_on_the_heads_shard_of_each_chip(
     assert {x[1] for x in _kernel_shapes(text) if len(x) == 4} == {H // 4}
 
 
+def test_a_many_row_pool_write_runs_on_the_heads_shard_of_each_chip(
+        topo, one_chip, mosaic):
+    """Tensor-parallel serving: a prompt's KV write (8 x 64 rows into the
+    GPT cells' k and v pools) is one ``pool_chunk_write`` kernel a pool
+    and chip over that chip's heads of the pool and of the rows, the
+    pools donated and aliased, with no collective and no copy of a
+    pool."""
+    from paddle_tpu.ops.attention_ops import block_scatter_write
+    mesh = Mesh(np.array(topo.devices).reshape(1, 4), ("data", "model"))
+    heads = NamedSharding(mesh, P(None, "model"))
+    whole = NamedSharding(mesh, P())
+    pool = jax.ShapeDtypeStruct((400, H, 16, D), jnp.float32, sharding=heads)
+    new = jax.ShapeDtypeStruct((8, H, 64, D), jnp.float32, sharding=heads)
+    pos = jax.ShapeDtypeStruct((8,), jnp.int32, sharding=whole)
+    tables = jax.ShapeDtypeStruct((8, 64), jnp.int32, sharding=whole)
+
+    def step(kp, vp, k, v, pos, tables):
+        with kernel_sharding(mesh, heads="model"):
+            return (block_scatter_write(kp, k, pos, tables),
+                    block_scatter_write(vp, v, pos, tables))
+
+    text = jax.jit(step, out_shardings=(heads, heads),
+                   donate_argnums=(0, 1)).lower(
+        pool, pool, new, new, pos, tables).compile().as_text()
+    assert text.count(KERNEL) == 2
+    for collective in ("all-gather", "all-reduce", "all-to-all",
+                       "collective-permute"):
+        assert collective not in text, collective
+    assert not _pool_copies(text, f"f32[400,{H // 4},16,{D}]")
+    assert _aliased_outputs(text) == {0, 1}
+
+
 # the serving cells' decode step (PERF.md section 4): 8 slots, pool
 # [400, 16, 16, 128], 64 table slots, at the 1.3B width. Two layers and
 # a small vocabulary suffice: every layer writes and reads its pools
 # alike, and the logits are not what is asserted.
 STEP_SLOTS, STEP_BLOCKS = 8, 400
+
+
+def _zero_weights(build):
+    """``build()`` with every parameter drawn as zeros (drawing a real
+    width's parameters on the host is not the test)."""
+    from paddle_tpu.dygraph import layers
+    real = layers.eager_init
+    layers.eager_init = lambda init, shape, dtype, rng: jnp.zeros(
+        tuple(int(d) for d in shape), dtype)
+    try:
+        model = build()
+    finally:
+        layers.eager_init = real
+    model.eval()
+    return model
 
 
 @pytest.fixture(scope="module")
@@ -390,21 +439,13 @@ def decode_step_1p3b_width():
     and the compiled text of its paged decode step for one described
     chip at a given pool dtype."""
     import dataclasses
-    from paddle_tpu.dygraph import layers
     from paddle_tpu.models import GPT_CONFIGS, GPTForCausalLM
     from paddle_tpu.models.generation import (decode_step_paged,
                                               param_leaves)
     from paddle_tpu.serving.decoding import neutral_samp
     cfg = dataclasses.replace(GPT_CONFIGS["gpt2-1p3b"], num_layers=2,
                               vocab_size=1024)
-    real = layers.eager_init
-    layers.eager_init = lambda init, shape, dtype, rng: jnp.zeros(
-        tuple(int(d) for d in shape), dtype)
-    try:
-        model = GPTForCausalLM(cfg)
-    finally:
-        layers.eager_init = real
-    model.eval()
+    model = _zero_weights(lambda: GPTForCausalLM(cfg))
 
     def lower(one_chip, pool_dtype):
         def s(x):
@@ -432,6 +473,26 @@ def decode_step_1p3b_width():
     return cfg, lower
 
 
+def _pool_copies(text, shape):
+    """The ``copy`` / ``copy-start`` ops of a compiled program whose
+    result holds an array of ``shape`` (``"f32[400,16,16,128]"``): a
+    move of a whole pool, to another layout or to another memory."""
+    return [ln.strip()[:160] for ln in text.splitlines()
+            if re.search(r"\) copy-start\(| copy\(", ln)
+            and shape in ln.split(" copy", 1)[0]]
+
+
+def _aliased_outputs(text):
+    """The indices of the compiled program's outputs that alias a
+    (donated) input."""
+    header = text[:text.index("\n\n")] if "\n\n" in text else text
+    m = re.search(r"input_output_alias=\{(.*?)\}, entry_computation",
+                  header, re.S) or re.search(
+                      r"input_output_alias=\{(.*)\}", header)
+    assert m, "the compiled step aliases nothing"
+    return {int(i) for i in re.findall(r"\{(\d+)\}: \(", m.group(1))}
+
+
 @pytest.mark.parametrize("pool_dtype", ["float32", "bfloat16"])
 def test_decode_step_writes_its_kv_rows_in_place(
         one_chip, decode_step_1p3b_width, pool_dtype):
@@ -440,23 +501,103 @@ def test_decode_step_writes_its_kv_rows_in_place(
     used to move every pool to ``{3,1,2,0}`` for the scatter and back:
     96 copies of 52 MB a step at 24 layers), and every pool leaf's
     output aliases its donated input."""
-    import re
     cfg, lower = decode_step_1p3b_width
     text = lower(one_chip, jnp.dtype(pool_dtype))
     short = {"float32": "f32", "bfloat16": "bf16"}[pool_dtype]
     shape = f"{short}[{STEP_BLOCKS},{cfg.num_heads},{BS},{cfg.head_dim}]"
-    copies = [ln.strip()[:160] for ln in text.splitlines()
-              if re.search(r"\) copy-start\(| copy\(", ln)
-              and shape in ln.split(" copy", 1)[0]]
-    assert not copies, copies
+    assert not _pool_copies(text, shape)
     # outputs: next tokens, logits, then (k, v) per layer, qerr, keys
-    header = text[:text.index("\n\n")] if "\n\n" in text else text
-    m = re.search(r"input_output_alias=\{(.*?)\}, entry_computation",
-                  header, re.S) or re.search(
-                      r"input_output_alias=\{(.*)\}", header)
-    assert m, "the compiled step aliases nothing"
-    aliased = {int(i) for i in re.findall(r"\{(\d+)\}: \(", m.group(1))}
-    assert set(range(2, 2 + 2 * cfg.num_layers)) <= aliased, m.group(1)
+    assert set(range(2, 2 + 2 * cfg.num_layers)) <= _aliased_outputs(text)
+
+
+def _gpt_prefill_program(rows, bucket):
+    """A 2-layer model of ``gpt2-1p3b``'s width behind the GPT serving
+    cells' engine (8 slots, 64 table entries of 16 rows): the prefill
+    entry of ``bucket`` and its arguments' shapes at the cells' pool of
+    400 blocks -> (model, fn, args, the pools among them, a pool's shape
+    and how the text prints it, the outputs before the pools)."""
+    import dataclasses
+    from paddle_tpu.models import GPT_CONFIGS, GPTForCausalLM
+    from paddle_tpu.serving import ServingEngine
+    cfg = dataclasses.replace(GPT_CONFIGS["gpt2-1p3b"], num_layers=2,
+                              vocab_size=1024)
+    model = _zero_weights(lambda: GPTForCausalLM(cfg))
+    engine = ServingEngine(model, max_slots=STEP_SLOTS, max_len=1024,
+                           buckets=[64], block_size=BS, num_blocks=16,
+                           prefix_cache=False)
+    assert engine.spec.prefill_rows(bucket, STEP_SLOTS) == rows
+    args = (jnp.zeros((rows, bucket), jnp.int32),
+            jnp.zeros(rows, jnp.int32), jnp.zeros(rows, jnp.int32),
+            jnp.zeros((rows, 1024 // BS), jnp.int32), engine.cache.arrays())
+    pool = (STEP_BLOCKS, cfg.num_heads, BS, cfg.head_dim)
+    return (model, engine._prefill_entry(bucket)["fn"], args, args[4], pool,
+            f"f32[{','.join(map(str, pool))}]", 1)
+
+
+def _lfm2_decode_program(rows, _):
+    """A 2-layer cut of LFM2-24B-A2B (a convolution layer and an
+    attention layer at the published widths, dense MLPs cut to 1024)
+    behind ``lfm2_agents_3k``'s engine: the decode step of 128 slots and
+    its arguments' shapes at the cell's pool, 1537 blocks of 256 packed
+    rows."""
+    import dataclasses
+    from paddle_tpu.models.lfm2 import LFM2_CONFIGS, Lfm2ForCausalLM
+    from paddle_tpu.serving import ServingEngine
+    cfg = dataclasses.replace(
+        LFM2_CONFIGS["lfm2-24b-a2b"], num_hidden_layers=2,
+        layer_types=("conv", "full_attention"), num_dense_layers=2,
+        num_attention_heads_per_layer=(), mlp_layer_types=(),
+        intermediate_size=1024, vocab_size=1024,
+        max_position_embeddings=4096)
+    model = _zero_weights(lambda: Lfm2ForCausalLM(cfg))
+    engine = ServingEngine(model, max_slots=rows, max_len=4096,
+                           buckets=[256], block_size=256, num_blocks=4,
+                           prefix_cache=False)
+    with engine._step_lock:
+        args = engine._step_args(engine._stamps()) + (engine._counted,)
+    fn = engine.spec.decode_entry(None, engine.kv_dtype, None)["fn"]
+    pool = (1537, cfg.num_key_value_heads // cfg.kv_pack, 256,
+            cfg.head_dim * cfg.kv_pack)
+    assert pool[1:] == (4, 256, 128)
+    return (model, fn, args, args[3], pool,
+            f"bf16[{','.join(map(str, pool))}]", 2)
+
+
+@pytest.mark.parametrize("program,rows,bucket", [
+    (_lfm2_decode_program, 128, 1),
+    (_gpt_prefill_program, 1, 1024),
+    (_gpt_prefill_program, 8, 64),
+], ids=["lfm2_decode_128x1", "gpt_prefill_1x1024", "gpt_prefill_8x64"])
+def test_a_many_row_step_writes_its_kv_rows_in_place(
+        one_chip, monkeypatch, program, rows, bucket):
+    """What PR 43 is for: a KV write of more than 64 rows (a decode step
+    of 128 requests over ``bf16[1537, 4, 256, 128]`` pools, GPT's
+    prompts over ``f32[400, 16, 16, 128]``) compiles, for the described
+    chip, to one ``pool_chunk_write`` kernel a pool and no ``copy`` of a
+    pool-shaped array (the fused scatter it replaced had two a pool,
+    403 MB each in the first; a kernel whose result is not held to HBM
+    had one at 8 x 64, where the compiler moved a whole pool into the
+    chip's alternate memory and back around it: no array of a pool's
+    shape lives anywhere but in HBM), every pool leaf's output aliased
+    to its donated input."""
+    for kernels in (pa, fa, pw):
+        monkeypatch.setattr(kernels, "_interpret", lambda: False)
+    from paddle_tpu.models.generation import param_leaves
+    model, fn, args, pools, pool, shape, before = program(rows, bucket)
+
+    def struct(x):
+        dims = pool if x.shape[1:] == pool[1:] else x.shape
+        return jax.ShapeDtypeStruct(dims, x.dtype, sharding=one_chip)
+    leaves = jax.tree_util.tree_map(struct, (param_leaves(model), *args))
+    with jax.enable_x64(False):
+        text = fn.raw.lower(*leaves).compile().as_text()
+    flat = jax.tree_util.tree_leaves(pools)
+    at = {before + i for i, x in enumerate(flat)
+          if x.shape[1:] == pool[1:]}
+    assert len(re.findall(r"%pool_chunk_write\S* = ", text)) == len(at) > 0
+    assert not _pool_copies(text, shape)
+    assert not re.findall(re.escape(shape) + r"\{[^}]*S\(\d\)", text)
+    assert at <= _aliased_outputs(text)
 
 
 def test_decode_step_keeps_the_sampler_under_a_conditional(

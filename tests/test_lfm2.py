@@ -362,10 +362,11 @@ def test_a_fault_in_the_hand_over_fails_the_served_comparison(
 
 
 def test_a_decode_batch_over_the_inplace_rows_is_the_same_rows_8_at_a_time():
-    """72 rows a step take the scatter form of the pool write (over
-    ``INPLACE_WRITE_MAX_ROWS``) and the state write of 72 rows; the same
-    requests decoded 8 at a time take the in-place form: row by row the
-    decode logits agree to the reduction order."""
+    """72 rows a step take the many-row form of the pool write (over
+    ``INPLACE_WRITE_MAX_ROWS``: the kernel over the touched chunks) and
+    the state write of 72 rows; the same requests decoded 8 at a time
+    take the row updates: row by row the decode logits agree to the
+    reduction order."""
     rows = attention_ops.INPLACE_WRITE_MAX_ROWS + 8
     model, _ = build()
     rng = np.random.default_rng(6)

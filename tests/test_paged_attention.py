@@ -40,6 +40,7 @@ from paddle_tpu.ops import attention_ops
 from paddle_tpu.ops.attention_ops import (block_scatter_write,
                                           block_scatter_write_quant,
                                           paged_attention_reference)
+from paddle_tpu.ops.pallas import pool_write
 from paddle_tpu.ops.pallas.flash_attention import flash_attention
 from paddle_tpu.ops.pallas.paged_attention import paged_attention
 from paddle_tpu.ops.pallas.utils import pad_lane_dim, pick_block
@@ -96,18 +97,42 @@ def _tables_for(pos, s, bs, T):
     return jnp.asarray(tables), nxt
 
 
+def _written_by_hand(pool, new, pos, tables, bs):
+    """``block_scatter_write``'s contract as a plain loop over numpy
+    arrays: row ``j`` of request ``i`` lands at position ``pos[i] + j``
+    of the request's table, on the trash block (0) past the table."""
+    pool = np.array(pool)
+    T = tables.shape[1]
+    for i in range(new.shape[0]):
+        for j in range(new.shape[2]):
+            at = pos[i] + j
+            phys = tables[i, at // bs] if at // bs < T else 0
+            pool[phys, :, at % bs] = new[i, :, j]
+    return pool
+
+
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-@pytest.mark.parametrize("b,s", [(8, 1), (4, 5), (3, 2)])
-def test_inplace_row_write_equals_the_scatter(monkeypatch, b, s, dtype):
-    """The two forms of ``block_scatter_write`` (row-wise in-place
-    updates for a few rows, one scatter for many) leave the same pool,
-    bit for bit, over random tables: rows that straddle blocks, rows
-    past the table routed to the trash block, and several requests
-    whose overflow rows collide there (where either form may keep any
-    one of the colliding rows)."""
+@pytest.mark.parametrize("b,s,bs,T", [
+    (8, 1, 4, 6), (4, 5, 4, 6), (3, 2, 4, 6),   # 64 rows and under
+    (128, 1, 32, 3),    # a decode step of 128 requests: a piece of a block
+    (32, 4, 32, 3),     # a verify step of 32 slots x K+1 = 4 rows
+    (2, 40, 16, 4),     # prompts that straddle blocks
+    (1, 96, 16, 5),     # a prompt that overflows its table
+])
+def test_inplace_row_write_equals_the_scatter(monkeypatch, b, s, bs, T,
+                                              dtype):
+    """Every form of ``block_scatter_write`` (unrolled in-place row
+    updates for a few rows, one kernel over the touched chunks of
+    blocks for many; the fused scatter they replaced is gone) leaves the
+    pool a plain loop over its contract leaves, bit for bit off the
+    trash block, over random tables: rows that straddle blocks at
+    unaligned positions, rows past the table routed to the trash block,
+    and several requests whose overflow rows collide there (where a
+    form may keep any one of the colliding rows, or the old one)."""
     rng = np.random.RandomState(100 * b + s)
-    bs, T, h, d = 4, 6, 2, 8
-    for trial in range(8):
+    h, d = 2, 8
+    few = b * s <= attention_ops.INPLACE_WRITE_MAX_ROWS
+    for trial in range(8 if few else 3):
         nb = b * T + 1
         perm = rng.permutation(np.arange(1, nb))
         # each request reserves a random number of blocks; the rest of
@@ -120,28 +145,43 @@ def test_inplace_row_write_equals_the_scatter(monkeypatch, b, s, dtype):
         # a request at the end overflow the table; two requests are
         # pinned there so that their overflow rows collide
         pos = rng.randint(0, T * bs, size=b)
-        pos[:2] = T * bs - 1
-        pos[-1] = 0           # and one surely writes a block it owns
+        if b > 2:
+            pos[:2] = T * bs - 1
+            pos[-1] = 0       # and one surely writes a block it owns
+        else:
+            # prompts: one from an unaligned position near the table's
+            # end, the last from row 3 of a table it owns whole
+            pos[0], pos[-1] = T * bs - 7, 3
+            tables[-1] = perm[-T:]
         pool = jnp.asarray(rng.randn(nb, h, bs, d), dtype)
         new = jnp.asarray(rng.randn(b, h, s, d), jnp.float32)
         args = (pool, new, jnp.asarray(pos, jnp.int32),
                 jnp.asarray(tables))
-        assert b * s <= attention_ops.INPLACE_WRITE_MAX_ROWS
-        rowwise = np.asarray(block_scatter_write(*args), np.float32)
-        monkeypatch.setattr(attention_ops, "INPLACE_WRITE_MAX_ROWS", 0)
-        scatter = np.asarray(block_scatter_write(*args), np.float32)
-        monkeypatch.undo()
-        np.testing.assert_array_equal(rowwise[1:], scatter[1:])
-        assert not np.array_equal(rowwise[1:],
-                                  np.asarray(pool, np.float32)[1:])
-        # the trash block: every row is the old row or one of the rows
-        # routed there, in both forms
+        old = np.asarray(pool, np.float32)
         cand = np.asarray(jnp.asarray(new, dtype), np.float32)
-        for got in (rowwise[0], scatter[0]):
+        want = _written_by_hand(old, cand, pos, tables, bs)
+        assert not np.array_equal(want[1:], old[1:])
+        kernels = []    # the calls that took the many-row form
+        real = pool_write.pool_chunk_write
+        monkeypatch.setattr(
+            pool_write, "pool_chunk_write",
+            lambda *a: kernels.append(len(a[2])) or real(*a))
+        got = [np.asarray(block_scatter_write(*args), np.float32)]
+        assert len(kernels) == (0 if few else 1)
+        if few:     # the many-row form at a few rows too
+            monkeypatch.setattr(attention_ops,
+                                "INPLACE_WRITE_MAX_ROWS", 0)
+            got.append(np.asarray(block_scatter_write(*args),
+                                  np.float32))
+            assert len(kernels) == 1
+        monkeypatch.undo()
+        for pool_after in got:
+            np.testing.assert_array_equal(pool_after[1:], want[1:])
+            # the trash block: every row is the old row or one of the
+            # rows routed there
             for off in range(bs):
-                row = got[:, off]
-                ok = np.array_equal(
-                    row, np.asarray(pool, np.float32)[0, :, off]) or any(
+                row = pool_after[0][:, off]
+                ok = np.array_equal(row, old[0, :, off]) or any(
                     np.array_equal(row, cand[i, :, j])
                     for i in range(b) for j in range(s))
                 assert ok, (trial, off)

@@ -393,9 +393,11 @@ def render_serving_block():
         "",
         "The KV pools have one owner at a time. Every paged entry",
         "(prefill, decode, megastep, verify) is handed the pools",
-        "donated: it writes its KV rows in place — a decode or verify",
-        "step as one `dynamic_update_slice` per row, in the layout the",
-        "pool arrived in, a prefill bucket as one scatter — the arrays",
+        "donated: it writes its KV rows in place, in the layout the",
+        "pool arrived in — 64 rows and under (a decode or verify step)",
+        "as one `dynamic_update_slice` per row, more (a prompt, a decode",
+        "step of 128 requests) as one kernel over the chunks of blocks",
+        "the rows touch (`ops/pallas/pool_write.py`) — the arrays",
         "handed in are deleted by the call, and the engine binds the",
         "returned pools (`cache.set_arrays`) before anything reads the",
         "cache again; nobody keeps a pool array across a step.",
