@@ -23,9 +23,11 @@ MLP 8192, vocabulary 65536. A layer, on ``T`` rows::
 **Shared with Laguna / Mellum**, called and not copied: ``RMSNorm`` and
 ``SwiGLU`` (``models/laguna.py``), the flash forward with grouped KV
 (``fused_attention_qkv``, 20 query heads on one KV head through the index
-map), the paged GQA read and the pool write of a decode step
-(``block_attention_gqa`` / ``block_scatter_write``), the head on a
-prompt's last row, the build / first-trace spans, bfloat16 pools.
+map), the paged read and the pool write of a decode step
+(``paged_attention``, the kernel that walks a row's live blocks, its 20
+query rows on the one KV head through the matrix unit /
+``block_scatter_write``), the head on a prompt's last row, the build /
+first-trace spans, bfloat16 pools.
 **What could not be shared**: Laguna's attention module is built around
 its rotary tables, its window and its gate, none of which exist here, so
 the attention layer is its own small class over the same ops; and the
@@ -56,7 +58,7 @@ from ..dygraph.tensor import Tensor
 from ..initializer import ConstantInitializer, NormalInitializer
 from ..nn.layers_common import Embedding
 from ..ops import ssm_ops
-from ..ops.attention_ops import block_attention_gqa, block_scatter_write
+from ..ops.attention_ops import block_scatter_write
 from ..param_attr import ParamAttr
 from ..profiler import RecordEvent
 from .laguna import RMSNorm, SwiGLU, _linear, _matmul_in, _w
@@ -283,6 +285,7 @@ class JambaAttention(Layer):
         (float32 output, the pools with the call's K and V written). A
         prompt attends over its own rows (no prefix is ever shared), one
         token over its paged rows."""
+        from ..ops.pallas.paged_attention import paged_attention
         q, k, v = self._heads(u)
         kp = block_scatter_write(cache[0].value, k.value, pos, tables)
         vp = block_scatter_write(cache[1].value, v.value, pos, tables)
@@ -290,8 +293,8 @@ class JambaAttention(Layer):
             out = self._attend(q, k, v)
         else:
             out = self._project(Tensor(
-                block_attention_gqa(q.value, kp, vp, tables, pos)
-                .astype(q.dtype), stop_gradient=True))
+                paged_attention(q.value, kp, vp, tables, pos),
+                stop_gradient=True))
         return out, (Tensor(kp, stop_gradient=True),
                      Tensor(vp, stop_gradient=True))
 

@@ -54,9 +54,11 @@ its window still covers. A call of more than one row a request is a prompt
 (positions from 0, attention by the training path's kernels over the rows
 of the call itself, the rows the window still covers written to the pool);
 a call of one row is a decode step (K rotated at its position and written,
-Q rotated and read against the pool through the table, on a window layer
-through the table entries the window covers only; the expert layer in its
-few-rows form, ``ops.decoder_ops.moe_experts_decode``). With ``dtype``
+Q rotated and read against the pool through the table: a full layer by the
+paged kernel, which copies the blocks the row's length stands on
+(``ops/pallas/paged_attention.py``), a window layer by the composed read of
+the table entries its window covers; the expert layer in its few-rows
+form, ``ops.decoder_ops.moe_experts_decode``). With ``dtype``
 ``"bfloat16"`` the parameters, the pools and the matmuls' inputs are
 bfloat16; the residual stream, the norms, the router, the softmax and the
 logits stay float32.
@@ -205,9 +207,11 @@ class LagunaConfig:
         many heads as fill the 128 (and divide the held heads) go in a row,
         ``[blocks, kv / pack, block, pack x d]``. Nothing sets this: it
         follows from ``head_dim``, and a head of 128 or more keeps a row
-        to itself. A packed pool's decode read is the paged kernel's
-        (:meth:`LagunaAttention._paged_read`); a head a row keeps the
-        composed read."""
+        to itself. It is the pool's layout only: a full layer's decode
+        read is the paged kernel's either way
+        (:meth:`LagunaAttention._paged_read`, which spreads a packed
+        pool's query heads over their lanes), a window layer's the
+        composed read of the entries its window covers."""
         if "sliding_attention" in self.layer_types:
             return 1
         kv = self.kv_heads[1] - self.kv_heads[0]
@@ -439,14 +443,14 @@ class LagunaAttention(Layer):
         vp = block_scatter_write(vp, vw, wpos, tables)
         if s > 1:
             o = self._attend(q, k, v)
-        elif pack > 1:
-            # rows of several heads: the gather reads a head a row
-            o = Tensor(self._paged_read(q.value, kp, vp, tables, pos),
-                       stop_gradient=True).transpose([0, 2, 1, 3])
         else:
-            o = Tensor(block_attention_gqa(q.value, kp, vp, tables, pos,
-                                           self.window).astype(q.dtype),
-                       stop_gradient=True).transpose([0, 2, 1, 3])
+            # a window layer gathers the table entries its window covers:
+            # the kernel's walk starts at a row's first block
+            read = block_attention_gqa(
+                q.value, kp, vp, tables, pos, self.window).astype(q.dtype) \
+                if self.window \
+                else self._paged_read(q.value, kp, vp, tables, pos)
+            o = Tensor(read, stop_gradient=True).transpose([0, 2, 1, 3])
         if cfg.attention_gate:
             o = self._gate(h, o)
         return (self.o_proj(o.reshape([b, s, self.q * d])),

@@ -300,7 +300,16 @@ def block_attention_gqa(q, k_pool, v_pool, tables, pos, window=0):
     the window reaches are gathered, so a window layer reads its window
     whatever the context's length (the entries behind it are the trash
     block's anyway: ``BlockKVCache`` frees them). Products take the pools'
-    dtype in and accumulate in float32; the softmax is float32."""
+    dtype in and accumulate in float32; the softmax is float32.
+
+    The served steps call it for their window layers only (the paged
+    kernel's walk starts at a request's first block). Without a window it
+    gathers the whole table, ``[b, T, hkv, bs, d]`` of K and of V whatever
+    the requests' lengths: no served step takes that arm since PR 44 (a
+    full layer reads through ``ops.pallas.paged_attention``), and it
+    stays as the composed read the kernel is held against
+    (``tests/test_paged_attention.py``, ``perfbench/study/paged_read*.py``).
+    """
     tables = jnp.asarray(tables, jnp.int32)
     pos = jnp.asarray(pos, jnp.int32)
     b, hq, s, d = q.shape

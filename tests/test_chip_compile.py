@@ -286,7 +286,7 @@ PAGED_CASES = {           # q_len, q dtype, pool dtype, int8 scales
     # the GPT serving cells' own engine: 8 slots over 400 blocks
     "decode_f32_cells": (1, jnp.float32, jnp.float32, False,
                          dict(b=8, nb=400)),
-    # what S2 (b) will hand it (mellum_code_16k's read): 32 query heads
+    # mellum_code_16k's full layers' read (S2 (b), PR 44): 32 query heads
     # on 4 KV heads, 256-row bfloat16 blocks, 16 slots over 801 blocks
     "decode_gqa_bf16_bs256": (1, jnp.float32, jnp.bfloat16, False,
                               dict(b=16, hq=32, hkv=4, bs=256, nb=801)),
@@ -296,17 +296,23 @@ PAGED_CASES = {           # q_len, q dtype, pool dtype, int8 scales
     # (128)"), 256-row bfloat16 blocks, 1537 blocks
     "decode_packed_d64_b128": (1, jnp.bfloat16, jnp.bfloat16, False,
                                dict(b=128, hq=32, hkv=4, bs=256, nb=1537)),
+    # jamba_reasoning_6k's read: 64 rows, 20 query heads on the one KV
+    # head of 128 (20 query rows a product, not a multiple of a tile's
+    # 8 or 16 sublanes), 256-row bfloat16 blocks, 1537 blocks
+    "decode_gqa_20on1_b64": (1, jnp.bfloat16, jnp.bfloat16, False,
+                             dict(b=64, hq=20, hkv=1, bs=256, nb=1537,
+                                  t=32)),
 }
 
 
 def _paged_args(q_len, q_dt, pool_dt, quant, geometry=None, *, where):
-    g = dict(dict(b=B, hq=H, hkv=H, bs=BS, nb=NB), **(geometry or {}))
+    g = dict(dict(b=B, hq=H, hkv=H, bs=BS, nb=NB, t=T), **(geometry or {}))
 
     def s(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=where(len(shape)))
     pool = (g["nb"], g["hkv"], g["bs"], D)
     args = [s((g["b"], g["hq"], q_len, D), q_dt), s(pool, pool_dt),
-            s(pool, pool_dt), s((g["b"], T), jnp.int32),
+            s(pool, pool_dt), s((g["b"], g["t"]), jnp.int32),
             s((g["b"],), jnp.int32)]
     if quant:
         args += [s(pool[:2], jnp.float32)] * 2
@@ -598,6 +604,89 @@ def test_a_many_row_step_writes_its_kv_rows_in_place(
     assert not _pool_copies(text, shape)
     assert not re.findall(re.escape(shape) + r"\{[^}]*S\(\d\)", text)
     assert at <= _aliased_outputs(text)
+
+
+def _mellum_decode_cut():
+    """A 2-layer cut of Mellum2-12B-A2.5B at the published attention
+    widths (a window layer and a full layer, 32 query heads on 4 KV heads
+    of 128; dense MLPs of 1024 where the experts stand, which this read
+    does not touch) behind ``mellum_code_16k``'s engine: 16 slots, tables
+    of 64 entries of 256 rows, the full layers' pool of 801 blocks."""
+    import dataclasses
+    from paddle_tpu.models import MELLUM_CONFIGS, MellumForCausalLM
+    cfg = dataclasses.replace(
+        MELLUM_CONFIGS["mellum2-12b-a2p5b"], num_hidden_layers=2,
+        layer_types=("sliding_attention", "full_attention"),
+        mlp_layer_types=("dense", "dense"), intermediate_size=1024,
+        vocab_size=1024, max_position_embeddings=16384)
+    return MellumForCausalLM, cfg, 16, 16384, 801
+
+
+def _jamba_decode_cut():
+    """A Mamba layer and an attention layer of AI21-Jamba2-3B at the
+    published widths (20 query heads on 1 KV head of 128; the MLPs cut to
+    1024) behind ``jamba_reasoning_6k``'s engine: 64 slots, tables of 32
+    entries of 256 rows, a pool of 1537 blocks."""
+    import dataclasses
+    from paddle_tpu.models import JAMBA_CONFIGS, JambaForCausalLM
+    cfg = dataclasses.replace(
+        JAMBA_CONFIGS["jamba2-3b"], num_hidden_layers=2,
+        attn_layer_period=2, attn_layer_offset=1, intermediate_size=1024,
+        vocab_size=1024, max_position_embeddings=8192)
+    return JambaForCausalLM, cfg, 64, 8192, 1537
+
+
+@pytest.mark.parametrize("cut", [_mellum_decode_cut, _jamba_decode_cut],
+                         ids=["mellum_16x64", "jamba_64x32"])
+def test_a_grouped_decode_step_reads_its_full_layers_through_the_kernel(
+        one_chip, monkeypatch, cut):
+    """What PR 44 is for: the decode step of ``mellum_code_16k`` and of
+    ``jamba_reasoning_6k`` compiles, for the described chip, with one
+    ``paged_decode_attn`` a layer without a window over pools that stay
+    where they are (every pool leaf's output aliased to its donated
+    input) and no array of the gathered table's shape
+    (``bf16[16,64,4,256,128]``, 268 MB, K and V a full layer;
+    ``bf16[64,32,1,256,128]``, 134 MB); Mellum's window layer keeps its
+    gather of the five entries its window of 1024 covers."""
+    from paddle_tpu.models.generation import param_leaves
+    from paddle_tpu.ops import ssm_ops
+    from paddle_tpu.serving import ServingEngine
+    for kernels in (pa, fa, pw, gm, ss):
+        monkeypatch.setattr(kernels, "_interpret", lambda: False)
+    monkeypatch.setattr(ssm_ops, "interpret_mode", lambda: False)
+    build, cfg, rows, max_len, blocks = cut()
+    model = _zero_weights(lambda: build(cfg))
+    engine = ServingEngine(model, max_slots=rows, max_len=max_len,
+                           buckets=[256], block_size=256, num_blocks=4,
+                           prefix_cache=False)
+    with engine._step_lock:
+        args = engine._step_args(engine._stamps())
+    if engine.spec.counters:
+        args += (engine._counted,)
+    fn = engine.spec.decode_entry(None, engine.kv_dtype, None)["fn"]
+    small = (4, cfg.num_key_value_heads, 256, cfg.head_dim)
+    pool = (blocks,) + small[1:]
+
+    def struct(x):
+        return jax.ShapeDtypeStruct(pool if x.shape == small else x.shape,
+                                    x.dtype, sharding=one_chip)
+    leaves = jax.tree_util.tree_map(struct, (param_leaves(model), *args))
+    with jax.enable_x64(False):
+        text = fn.raw.lower(*leaves).compile().as_text()
+    flat = jax.tree_util.tree_leaves(args[3])
+    full = [i for i, x in enumerate(flat) if x.shape == small]
+    assert len(full) == 2                   # K and V of the one full layer
+    assert len(re.findall(r"%paged_decode_attn\S* = ", text)) == 1
+    table = max_len // 256
+    assert f"bf16[{rows},{table},{','.join(map(str, small[1:]))}]" \
+        not in text
+    assert not _pool_copies(text, f"bf16[{','.join(map(str, pool))}]")
+    # outputs: next tokens, logits, then the cache's arrays
+    assert {2 + i for i in range(len(flat))} <= _aliased_outputs(text)
+    windows = [x for x in flat if x.ndim == 4 and x.shape[1:] == small[1:]
+               and x.shape != small]
+    if windows:
+        assert len(windows) == 2 and f"bf16[{rows},5,4,256,128]" in text
 
 
 def test_decode_step_keeps_the_sampler_under_a_conditional(

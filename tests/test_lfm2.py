@@ -424,15 +424,16 @@ def test_the_served_precision_is_bfloat16_where_the_configuration_says():
 @pytest.mark.parametrize("name,pack,read", [
     ("lfm2-24b-a2b", 2, "kernel"),      # 8 KV heads of 64: two a row of 128
     ("lfm2-tiny", 2, "kernel"),         # 2 KV heads of 16: both in a row
-    ("mellum", 1, "gather"),            # window layers: a head a row
-    ("full-d128", 1, "gather"),         # a head of 128 fills the lanes
+    ("mellum", 1, "by layer"),          # window layers: a head a row
+    ("full-d128", 1, "kernel"),         # a head of 128 fills the lanes
 ])
 def test_the_pool_rows_packing_follows_from_the_head_size(name, pack, read,
                                                           monkeypatch):
-    """No option picks the served decode read: heads narrower than the 128
-    lanes share a pool row where no layer has a window, and a packed row is
-    the paged kernel's to read; everything else keeps the composed gather
-    (``mellum_code_16k``'s path, unchanged)."""
+    """No option picks the pool's layout or the served decode read: heads
+    narrower than the 128 lanes share a pool row where no layer has a
+    window, and a layer without a window reads its decode row through the
+    paged kernel whatever the packing (Mellum's full layers too:
+    ``tests/test_paged_attention.py`` reads its step's jaxpr)."""
     from paddle_tpu.models import MELLUM_CONFIGS
     pa = sys.modules["paddle_tpu.ops.pallas.paged_attention"]
     mc = {"mellum": lambda: MELLUM_CONFIGS["mellum-tiny"],

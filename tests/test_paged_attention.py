@@ -395,6 +395,117 @@ def test_gpt_step_takes_the_kernel_by_the_query_blocks_shape():
         assert "paged_decode_attn" not in t and gathered(b) in t, (b, s)
 
 
+@pytest.mark.parametrize("pool_dtype", ["bfloat16", "float32"])
+def test_kernel_reads_twenty_query_heads_on_one_kv_head(pool_dtype):
+    """Jamba's read at a toy size: 20 query heads on the one KV head (20
+    rows a product: the matrix-unit form, and no multiple of a tile's
+    sublanes) over 256-row blocks, the default blocks a step over a table
+    they do not divide, a dead row among the rows. The kernel, the
+    reference and the composed read it replaced in the served step
+    (``block_attention_gqa`` without a window) agree."""
+    rng = np.random.RandomState(44)
+    bs, T, d, hq = 256, 9, 32, 20
+    assert not pa._rows_form(hq, bs, 16)
+    pos = [0, 3 * bs - 1, 3 * bs, T * bs - 1, 700]
+    tables, nb = _tables_for(pos, 1, bs, T)
+    tables = tables.at[0].set(0)
+    k_pool, v_pool, _ = _pools(rng, nb, 1, bs, d, pool_dtype)
+    q = jnp.asarray(rng.randn(len(pos), hq, 1, d), jnp.float32)
+    posv = jnp.asarray(pos, jnp.int32)
+    out = paged_attention(q, k_pool, v_pool, tables, posv)
+    ref = paged_attention_reference(
+        q, *(jnp.repeat(p, hq, axis=1) for p in (k_pool, v_pool)), tables,
+        posv)
+    composed = attention_ops.block_attention_gqa(q, k_pool, v_pool, tables,
+                                                 posv)
+    assert out.shape == ref.shape == composed.shape == (len(pos), hq, 1, d)
+    tol = 2e-2 if pool_dtype == "bfloat16" else 1e-4
+    for other in (ref, composed):
+        np.testing.assert_allclose(np.asarray(out), np.asarray(other),
+                                   rtol=tol, atol=tol)
+
+
+def _program_facts(jaxpr):
+    """(calls of ``paged_decode_attn``, the shape of every value a
+    primitive of the program gives, by primitive), nested programs
+    included: an inner ``jit`` (the kernel's walk is one), a loop's body."""
+    kernels, shapes = 0, []
+
+    def walk(jp):
+        nonlocal kernels
+        for eqn in jp.eqns:
+            if eqn.primitive.name == "pallas_call":
+                kernels += "paged_decode_attn" in str(
+                    eqn.params.get("name_and_src_info", eqn.params))
+                continue            # the kernel's body is not the program's
+            shapes.extend((eqn.primitive.name, tuple(v.aval.shape))
+                          for v in eqn.outvars)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+    walk(jaxpr.jaxpr)
+    return kernels, shapes
+
+
+def _mellum_toy():
+    """-> (model, its layers without a window, its window layers)."""
+    import dataclasses
+    from paddle_tpu.models import MELLUM_CONFIGS, MellumForCausalLM
+    kinds = ("sliding_attention", "full_attention") * 2
+    return MellumForCausalLM(dataclasses.replace(
+        MELLUM_CONFIGS["mellum-tiny"], layer_types=kinds)), 2, 2
+
+
+def _jamba_toy():
+    from paddle_tpu.models import JAMBA_CONFIGS, JambaForCausalLM
+    return JambaForCausalLM(JAMBA_CONFIGS["jamba-tiny"]), 1, 0
+
+
+@pytest.mark.parametrize("rows", [1, 32], ids=["decode", "prompt"])
+@pytest.mark.parametrize("toy", [_mellum_toy, _jamba_toy],
+                         ids=["mellum", "jamba"])
+def test_a_full_layers_decode_row_reads_through_the_kernel(toy, rows):
+    """What a layer sees of itself picks its decode read, and no option: a
+    decode step of Mellum (two window layers of 16 rows, two full layers)
+    and of Jamba (one attention layer) holds ``paged_decode_attn`` once a
+    layer without a window and no array of the gathered table's shape
+    ``[b, T, hkv, bs, d]``; Mellum's window layers gather the ``ceil(window
+    / bs) + 1`` entries their window covers, K and V, and hold no kernel; a
+    prompt holds no kernel at all and gathers nothing."""
+    from paddle_tpu.dygraph.tape import no_grad
+    from paddle_tpu.dygraph.tensor import Tensor
+    from paddle_tpu.models.generation import _wrap_pools
+    pt.seed(3)
+    model, full, window = toy()
+    model.eval()
+    b, bs, max_len = 2, 8, 128
+    engine = ServingEngine(model, max_slots=b, max_len=max_len,
+                           buckets=[32], block_size=bs, num_blocks=0,
+                           prefix_cache=False, eos_token_id=None)
+
+    def call(ids, pos, tables, pools):
+        with no_grad():
+            logits, _ = model(
+                Tensor(ids, stop_gradient=True), cache=_wrap_pools(pools),
+                cache_pos=pos, block_tables=tables,
+                last=None if rows == 1 else jnp.zeros((b,), jnp.int32))
+        return logits.value
+    kernels, shapes = _program_facts(jax.make_jaxpr(call)(
+        jnp.zeros((b, rows), jnp.int32), jnp.ones((b,), jnp.int32),
+        jax.tree_util.tree_map(jnp.asarray, engine.cache.tables_arg()),
+        engine.cache.arrays()))
+    cfg = model.cfg
+    hkv, d = cfg.num_key_value_heads, cfg.head_dim
+    gathered = [s for name, s in shapes if name == "gather"
+                and len(s) == 5 and s[2:] == (hkv, bs, d)]
+    assert (b, max_len // bs, hkv, bs, d) not in [s for _, s in shapes]
+    if rows > 1:
+        assert kernels == 0 and not gathered
+        return
+    assert kernels == full
+    slots = -(-cfg.sliding_window // bs) + 1 if window else 0
+    assert gathered == [(b, slots, hkv, bs, d)] * (2 * window)
+
+
 # ---------------------------------------------------------------------------
 # quantizing scatter: parity, idempotence, locality, overflow
 # ---------------------------------------------------------------------------
