@@ -15,7 +15,7 @@ dygraph-to-static (one XLA computation: forward, program-level backward,
 AdamW update, all state donated) with AMP O2 bf16 so matmuls hit the MXU.
 Model FLOPs are counted analytically (fwd matmul FLOPs x3 for fwd+bwd),
 the standard MFU accounting; peak is the chip's bf16 rating from the
-one table keyed by device_kind (observability.devprof.TPU_PEAKS; v5e:
+one table keyed by device_kind (paddle_tpu.utils.chip.TPU_PEAKS; v5e:
 197 TFLOP/s; override with BENCH_PEAK_FLOPS).
 
 Measurement discipline (each item burned a previous round):
@@ -63,13 +63,14 @@ def is_oom(e: Exception) -> bool:
 
 def detect_peak_flops(device) -> float:
     """Peak bf16 FLOP/s the MFU divides by. On a TPU: the device_kind's
-    row of the one peak table (an unlisted kind raises — no default).
+    row of the one peak table, ``paddle_tpu.utils.chip.TPU_PEAKS`` (an
+    unlisted kind raises — no default).
     Off the TPU there is no chip to rate, and the rehearsal's "MFU"
     keeps the v5e figure it always used (ROADMAP D5's remainder)."""
     if "BENCH_PEAK_FLOPS" in os.environ:
         return float(os.environ["BENCH_PEAK_FLOPS"])
     if device.platform == "tpu":
-        from paddle_tpu.observability.devprof import tpu_peaks
+        from paddle_tpu.utils.chip import tpu_peaks
         return tpu_peaks(device.device_kind)[0]
     return 197e12
 

@@ -139,7 +139,6 @@ def predict_serving_compiles(
         sampling: Optional[Sequence[Tuple[float, int, float]]] = None,
         lora: Optional[Tuple[int, int]] = None,
         tracing: Optional[float] = None,
-        devprof: Optional[float] = None,
         sanitize: bool = False,
         host_tier: bool = False,
         sessions: int = 0,
@@ -280,19 +279,6 @@ def predict_serving_compiles(
     near the step cache. Tracing every request predicts the same
     counts as tracing none.
 
-    ``devprof`` (``FLAGS_serving_devprof`` + the
-    ``FLAGS_serving_devprof_sample`` fraction in [0, 1], or True for
-    flag-default sampling) is a validated no-op with one subtlety
-    worth stating: the observatory's cost capture DOES lower XLA
-    computations — but on a **fresh** ``jax.jit`` of the raw step
-    function, out-of-band, never through the tracked wrapper, so the
-    per-site retrace counters and ``xla_compiles`` this predictor is
-    checked against never move. The sampled ``block_until_ready``
-    timer is pure host-side timing around already-compiled dispatches.
-    Profiling every dispatch predicts the same counts as profiling
-    none (``tools/obs_smoke.py`` asserts predicted == observed with
-    the flag on).
-
     ``sanitize`` (``FLAGS_sanitize_locks``: the concurrency
     sanitizer) is a validated no-op like ``tracing``: the sanitizer
     swaps host-side ``threading`` locks for instrumented wrappers and
@@ -392,14 +378,6 @@ def predict_serving_compiles(
             raise ValueError(
                 f"tracing must be a sampling fraction in [0, 1] (or "
                 f"True = 1.0), got {tracing!r}")
-    if devprof is not None:
-        frac = (1.0 if devprof is True else
-                0.0 if devprof is False else float(devprof))
-        if not (0.0 <= frac <= 1.0):
-            raise ValueError(
-                f"devprof must be a sampling fraction in [0, 1] (or "
-                f"a bool for FLAGS_serving_devprof on/off), got "
-                f"{devprof!r}")
     if sanitize not in (True, False):
         raise ValueError(
             f"sanitize must be a bool (FLAGS_sanitize_locks is "

@@ -534,33 +534,3 @@ define_flag("serving_trace_keep", 512,
             "queryable via GET /v1/requests/<id> and the exporters; "
             "older ids 404. Active (in-flight) traces are never "
             "evicted.")
-define_flag("serving_devprof", False,
-            "Device-cost observatory (observability/devprof.py): on "
-            "every tracked_jit compile, capture the lowered entry's "
-            "XLA cost_analysis() (flops, HBM bytes, output bytes) "
-            "into devprof.cost_table() and the xla_cost{fn,metric} "
-            "gauges, and arm the engine's sampled device timer "
-            "(FLAGS_serving_devprof_sample). Cost capture lowers the "
-            "raw step function out-of-band, so the tracked compile "
-            "counters never move — predict_serving_compiles("
-            "devprof=True) is a validated no-op.")
-define_flag("serving_devprof_sample", 0.1,
-            "Device-timing sampling fraction under "
-            "FLAGS_serving_devprof: a deterministic hash of the "
-            "engine's dispatch counter picks which step dispatches "
-            "get a block_until_ready timer (device ms histograms, "
-            "roofline MFU/HBM-utilization gauges, host/device blame "
-            "split). Skipped dispatches keep the PR 19 async/"
-            "dispatch-ahead path untouched; 0 samples nothing (bit-"
-            "identical to devprof off on the step path).")
-define_flag("devprof_peak_flops", 0.0,
-            "Roofline peak compute (FLOP/s) the MFU gauge divides by. "
-            "0 (default): on a TPU the device_kind's row of "
-            "observability.devprof.TPU_PEAKS (v5e: 197e12; an unlisted "
-            "TPU is an error), else a per-platform nominal: 312e12 "
-            "(GPU), 1e11 (CPU).")
-define_flag("devprof_peak_hbm_gbps", 0.0,
-            "Roofline peak memory bandwidth (GB/s) the HBM-"
-            "utilization gauge divides by. 0 (default): on a TPU the "
-            "device_kind's row of observability.devprof.TPU_PEAKS "
-            "(v5e: 819), else a nominal: 2000 (GPU), 50 (CPU).")

@@ -5,9 +5,6 @@ grown into a production-style plane):
 
 - :mod:`.metrics`          typed Counter/Gauge/Histogram registry
 - :mod:`.compile_tracker`  ``tracked_jit`` XLA compile accounting
-- :mod:`.devprof`          device-cost observatory: XLA cost_analysis
-  capture per compile, sampled device timing, roofline/MFU gauges and
-  the decode device/host blame split
 - :mod:`.runlog`           structured JSONL run-log emitter
 - :mod:`.export`           Prometheus text + JSON snapshot exporters
 - :mod:`.tracing`          per-request span traces, blame attribution,
@@ -20,7 +17,7 @@ reports into the same plane that ``GET /metrics`` scrapes.
 
 from __future__ import annotations
 
-from . import compile_tracker, devprof, export, metrics, runlog, tracing
+from . import compile_tracker, export, metrics, runlog, tracing
 from .compile_tracker import (RecompileWarning, compiles, reset_compiles,
                               tracked_jit)
 from .export import prometheus_text, snapshot, validate_prometheus_text
@@ -124,33 +121,6 @@ INSTRUMENT_DOCS = {
         "the engine clock whose spans decompose TTFT/E2E into "
         "queue | prefill | decode | handoff | rehome components — an "
         "accounting identity, see observability/tracing.py)",
-    "xla_cost{fn=..., metric=...}":
-        "gauge — the latest compile's XLA cost_analysis() per "
-        "tracked_jit site (metric: flops | hbm_bytes | out_bytes), "
-        "captured by the device-cost observatory "
-        "(FLAGS_serving_devprof) with zero extra compiles — the raw "
-        "step function is lowered out-of-band, never the tracked "
-        "wrapper",
-    "serving_mfu{engine=...}":
-        "gauge — model FLOPs utilization of sampled step dispatches: "
-        "captured cost_analysis flops / (sampled device seconds * "
-        "peak FLOP/s, FLAGS_devprof_peak_flops or a per-platform "
-        "nominal)",
-    "serving_hbm_util{engine=...}":
-        "gauge — HBM bandwidth utilization of sampled step "
-        "dispatches: cost_analysis bytes accessed / (sampled device "
-        "seconds * peak bytes/s, FLAGS_devprof_peak_hbm_gbps or a "
-        "per-platform nominal)",
-    "serving_host_overhead_share{engine=...}":
-        "gauge — host share of sampled step wall time (host_s / "
-        "(host_s + device_s)): the number decode megasteps exist to "
-        "shrink, continuously measured at FLAGS_serving_devprof_"
-        "sample rate",
-    "serving_device_step_ms{fn=...}":
-        "histogram — sampled block_until_ready device ms per step "
-        "dispatch, per compiled entry (decode_step_paged, "
-        "decode_megastep_paged{n=...}, verify_step_paged{k=...}, "
-        "serving_prefill_paged{bucket=...})",
     "sanitizer_lock_acquires":
         "counter — lock acquisitions instrumented by the concurrency "
         "sanitizer (FLAGS_sanitize_locks): every outermost acquire of "
@@ -293,10 +263,6 @@ EVENT_DOCS = {
                               "conversation (session, stored_tokens, "
                               "prompt_tokens) — only the unshared "
                               "suffix re-prefills, token-identically",
-    "devprof_cost": "device-cost observatory captured a compiled "
-                    "entry's XLA cost_analysis (fn, flops, hbm_bytes, "
-                    "out_bytes) — one event per tracked_jit compile "
-                    "under FLAGS_serving_devprof",
     "fault_injected": "deterministic fault fired (site, fault_kind)",
     "recompile_warning": "tracked function exceeded "
                          "FLAGS_warn_recompiles (fn, signature)",
@@ -319,8 +285,7 @@ def histogram(name: str, help_str: str = "", buckets=None) -> Histogram:
 
 
 __all__ = [
-    "metrics", "compile_tracker", "devprof", "runlog", "export",
-    "tracing",
+    "metrics", "compile_tracker", "runlog", "export", "tracing",
     "MetricsRegistry", "Counter", "Gauge", "Histogram", "DEFAULT_BUCKETS",
     "tracked_jit", "compiles", "reset_compiles", "RecompileWarning",
     "log_event", "recent",

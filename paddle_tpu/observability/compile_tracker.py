@@ -134,13 +134,6 @@ def tracked_jit(name: str, fn, *, labels: Optional[Dict[str, str]] = None,
         if traces["count"] != seen[0]:
             _note_compiles(rec, traces, seen, seen_lock, args, kwargs,
                            (time.perf_counter() - t0) * 1e3)
-            # device-cost observatory: capture this entry's XLA cost
-            # analysis (no-op unless FLAGS_serving_devprof). Lowers the
-            # RAW fn out-of-band so traces["count"] / xla_compiles
-            # never move — devprof is a validated zero-compile add-on.
-            from . import devprof as _devprof
-            _devprof.note_compile(name, labels, fn, jit_kwargs,
-                                  args, kwargs)
         return out
 
     call.traces = traces

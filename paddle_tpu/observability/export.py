@@ -21,10 +21,10 @@ from . import metrics as _metrics
 
 _NAME_OK = re.compile(r"^[a-zA-Z_:][a-zA-Z0-9_:]*$")
 _LABEL_OK = re.compile(r"^[a-zA-Z_][a-zA-Z0-9_]*$")
-# label VALUES may contain any escaped text — including '}' (the
-# devprof entry labels are qualified tracked_jit names like
-# fn="decode_megastep_paged{n=4}"), so the label block must be parsed
-# quote-aware, not up-to-the-first-brace
+# label VALUES may contain any escaped text — including '}' (a
+# qualified tracked_jit name like fn="decode_megastep_paged{n=4}"),
+# so the label block must be parsed quote-aware, not
+# up-to-the-first-brace
 _SAMPLE_RE = re.compile(
     r'^([a-zA-Z_:][a-zA-Z0-9_:]*)'
     r'(\{(?:[a-zA-Z_][a-zA-Z0-9_]*="(?:[^"\\]|\\.)*",?)*\})?'
@@ -172,9 +172,4 @@ def snapshot(registry: Optional[_metrics.MetricsRegistry] = None
         qual: {"count": rec["count"], "total_ms": rec["total_ms"],
                "last_signature": rec["last_signature"]}
         for qual, rec in _ct.compiles().items()}
-    # the devprof cost table rides every snapshot (empty dict when the
-    # observatory is off): bench artifacts and the stop_profiler()
-    # summary get device costs without a second collection path
-    from . import devprof as _devprof
-    out["device_costs"] = _devprof.cost_table()
     return out

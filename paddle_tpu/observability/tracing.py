@@ -81,16 +81,8 @@ _PHASE_AFTER = {
     "kill": "rehome",         # crash -> re-admission on a survivor
 }
 
-#: every component name blame() can emit, in display order. The
-#: ``decode_device`` / ``decode_host`` pair appears instead of
-#: ``decode`` on traces annotated with a sampled device fraction
-#: (``meta["decode_device_frac"]``, written by the engine's device-cost
-#: observatory — see observability/devprof.py): decode_device =
-#: decode * frac and decode_host = decode - decode_device, so the
-#: accounting identity sum(components) == e2e survives the split
-#: exactly, by construction.
-COMPONENTS = ("queue", "prefill", "decode", "decode_device",
-              "decode_host", "handoff", "rehome")
+#: every component name blame() can emit, in display order
+COMPONENTS = ("queue", "prefill", "decode", "handoff", "rehome")
 
 #: point-in-time annotations, not span boundaries: these marks record
 #: lifecycle *events* (a cancel landing, a hedge firing/resolving) on
@@ -105,32 +97,6 @@ ANNOTATION_KINDS = frozenset({"cancel", "hedge", "hedge_win",
 def _span_marks(marks):
     """Marks that bound spans: the timeline minus pure annotations."""
     return [m for m in marks if m[0] not in ANNOTATION_KINDS]
-
-
-def _device_frac(meta: dict) -> Optional[float]:
-    """The sampled decode device fraction, if the engine's devprof
-    annotated one onto this trace; clamped to [0, 1]. None (no split)
-    when absent or non-numeric — e.g. a virtual-clock run whose
-    samples all measured zero never annotates, keeping its exports
-    byte-identical to a devprof-off run."""
-    v = meta.get("decode_device_frac")
-    if not isinstance(v, (int, float)):
-        return None
-    return min(1.0, max(0.0, float(v)))
-
-
-def _split_decode(comp: Dict[str, float], frac: Optional[float]):
-    """Replace the ``decode`` component with the ``decode_device`` +
-    ``decode_host`` pair. Exact by construction: device = decode *
-    frac, host = decode - device, so the pair sums to the original
-    float bit-for-bit and the blame identity telescopes unchanged."""
-    if frac is None or "decode" not in comp:
-        return comp
-    decode = comp.pop("decode")
-    device = decode * frac
-    comp["decode_device"] = device
-    comp["decode_host"] = decode - device
-    return comp
 
 
 class Trace:
@@ -166,7 +132,6 @@ def blame(trace: Trace) -> dict:
         elapsed += t1 - t0
         if k1 == "first_token":
             ttft = t1 - marks[0][1]
-    _split_decode(comp, _device_frac(trace.meta))
     return {
         "components": comp,
         "e2e_s": marks[-1][1] - marks[0][1],
@@ -242,19 +207,6 @@ class TraceStore:
             if tr is None:
                 return False
             tr.marks.append((str(kind), float(t), str(track)))
-            return True
-
-    def annotate(self, rid: int, **meta) -> bool:
-        """Merge metadata onto an active trace — point data that is
-        not a timeline mark (e.g. the devprof-sampled
-        ``decode_device_frac`` the engine writes just before finish,
-        which blame() uses to split ``decode``). No-op (False) for
-        unsampled/unknown ids, like :meth:`mark`."""
-        with self._lock:
-            tr = self._active.get(int(rid))
-            if tr is None:
-                return False
-            tr.meta.update(meta)
             return True
 
     def has_mark(self, rid: int, kind: str) -> bool:
@@ -386,33 +338,11 @@ class TraceStore:
 
     @staticmethod
     def _trace_spans(tr: Trace) -> List[Tuple[str, float, float, str]]:
-        """One trace's ``(component, t0, t1, track)`` spans, with
-        ``decode`` spans split at ``t0 + (t1 - t0) * frac`` into the
-        ``decode_device`` / ``decode_host`` pair when the trace
-        carries a devprof device fraction — the export-side mirror of
-        :func:`_split_decode`, so rendered timelines and blame()
-        totals tell one story."""
+        """One trace's ``(component, t0, t1, track)`` spans."""
         smarks = _span_marks(tr.marks)
-        frac = _device_frac(tr.meta)
-        spans: List[Tuple[str, float, float, str]] = []
-        for (k0, t0, _tr0), (k1, t1, trk1) in zip(smarks, smarks[1:]):
-            name = _PHASE_AFTER.get(k0, k0)
-            if name == "decode" and frac is not None:
-                t_mid = t0 + (t1 - t0) * frac
-                spans.append(("decode_device", t0, t_mid, trk1))
-                spans.append(("decode_host", t_mid, t1, trk1))
-            else:
-                spans.append((name, t0, t1, trk1))
-        return spans
-
-    @staticmethod
-    def _devprof_entries() -> List[dict]:
-        """Roofline rows of every live sampled profiler — embedded in
-        both export formats so ``tools/trace_summary.py --blame`` can
-        print the per-entry verdict next to the blame table. Empty
-        when devprof never sampled, leaving export bytes untouched."""
-        from . import devprof as _devprof
-        return _devprof.roofline_entries()
+        return [(_PHASE_AFTER.get(k0, k0), t0, t1, trk1)
+                for (k0, t0, _tr0), (_k1, t1, trk1)
+                in zip(smarks, smarks[1:])]
 
     @staticmethod
     def _track_names(rows) -> Dict[str, str]:
@@ -483,9 +413,6 @@ class TraceStore:
                                "name": "request", "pid": 1,
                                "tid": tracks[trk], "ph": "f",
                                "bp": "e", "ts": us(spans[0][2])})
-        for entry in self._devprof_entries():
-            events.append({"ph": "M", "name": "devprof", "pid": 1,
-                           "tid": 0, "args": entry})
         doc = {"displayTimeUnit": "ms", "traceEvents": events}
         if path:
             with open(path, "w", encoding="utf-8") as f:
@@ -511,13 +438,6 @@ class TraceStore:
                      "dur_ms": round((t1 - t0) * 1e3, 6),
                      "outcome": tr.outcome or "?"},
                     sort_keys=True, separators=(",", ":")))
-        # devprof roofline rows ride along as bare {"devprof": ...}
-        # lines — no "span"/"trace" keys, so blame collectors that key
-        # on those skip them without special-casing
-        for entry in self._devprof_entries():
-            lines.append(json.dumps({"devprof": entry},
-                                    sort_keys=True,
-                                    separators=(",", ":")))
         text = "\n".join(lines) + ("\n" if lines else "")
         if path:
             with open(path, "w", encoding="utf-8") as f:
@@ -605,10 +525,6 @@ def begin(rid: int, t: float, track: str, **meta) -> bool:
 
 def mark(rid: int, kind: str, t: float, track: str) -> bool:
     return _STORE.mark(rid, kind, t, track)
-
-
-def annotate(rid: int, **meta) -> bool:
-    return _STORE.annotate(rid, **meta)
 
 
 def finish(rid: int, t: float, track: str, outcome: str,

@@ -204,21 +204,6 @@ def _print_metrics_summary():
             c = comp[qual]
             print(f"  {qual}: {c['count']} "
                   f"({c['total_ms']:.1f} ms traced)")
-    # the devprof cost table: before this merge the summary silently
-    # omitted device costs even when FLAGS_serving_devprof had
-    # captured them — the report ended at host events + compiles
-    costs = snap.get("device_costs") or {}
-    if costs:
-        print("XLA device costs (per compiled entry):")
-        for qual in sorted(costs):
-            c = costs[qual]
-
-            def _fmt(v):
-                return "n/a" if v is None else f"{v:.4g}"
-
-            print(f"  {qual}: flops={_fmt(c.get('flops'))} "
-                  f"hbm_bytes={_fmt(c.get('hbm_bytes'))} "
-                  f"out_bytes={_fmt(c.get('out_bytes'))}")
 
 
 def summarize(events: List[dict], sorted_key: Optional[str] = None):

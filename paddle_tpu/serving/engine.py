@@ -18,9 +18,9 @@ Compile surfaces, all fixed-shape:
   waits for k, so fetch, commit and launch run under the device's
   time. A step's rows are committed or dropped one by one (a request
   that finished, was cancelled or shed meanwhile has its row dropped)
-  and no step is dispatched twice; a step after an admission, with a
-  grammar row, or sampled by devprof is built from the host once the
-  step before it landed (``ServingEngine._decode``; ``stats()``:
+  and no step is dispatched twice; a step after an admission or with
+  a grammar row is built from the host once the step before it
+  landed (``ServingEngine._decode``; ``stats()``:
   ``ahead_dispatches`` / ``ahead_rows_committed`` /
   ``ahead_rows_dropped``). Tokens are counted when the host has them.
   A dispatch of any kind is stamped once at each of its edges
@@ -126,7 +126,6 @@ from ..analysis import concurrency as _ccz
 from .. import observability as _obs
 from .. import profiler as _profiler
 from ..observability import compile_tracker as _ct
-from ..observability import devprof as _devprof
 from ..observability import runlog as _runlog
 from ..observability import tracing as _tracing
 from ..dygraph.tape import no_grad
@@ -436,9 +435,7 @@ class ServingEngine:
                  lora_max_adapters: Optional[int] = None,
                  lora_pool=None, grammar=None, kv_tier=None,
                  megastep: Optional[int] = None,
-                 dispatch_ahead: Optional[bool] = None,
-                 devprof: Optional[bool] = None,
-                 devprof_sample: Optional[float] = None):
+                 dispatch_ahead: Optional[bool] = None):
         g = _flags.get_flags(["serving_max_slots", "serving_max_len",
                               "serving_max_queue",
                               "serving_prefill_buckets",
@@ -460,9 +457,7 @@ class ServingEngine:
                               "serving_lora_rank",
                               "serving_lora_max_adapters",
                               "serving_host_tier",
-                              "serving_host_blocks",
-                              "serving_devprof",
-                              "serving_devprof_sample"])
+                              "serving_host_blocks"])
         self.model = model
         # everything the engine knows of the model: serving/seam.py
         spec = self.spec = served(model)
@@ -784,9 +779,6 @@ class ServingEngine:
         self._ahead_dispatches = 0        # guarded-by: _step_lock
         self._ahead_rows_committed = 0    # guarded-by: _step_lock
         self._ahead_rows_dropped = 0      # guarded-by: _step_lock
-        # devprof sampled the step after the one in flight: it was not
-        # dispatched ahead, and the next dispatch carries the timer
-        self._sample_next = False
         # paged dispatches, and those after which the pools handed in
         # were deleted (donated: the KV rows were written in place)
         self._pool_dispatches = 0         # guarded-by: _step_lock
@@ -835,21 +827,6 @@ class ServingEngine:
                 "rows written by this engine's compiled steps"
                 ).labels(engine=eid)
             self._qerr_gauge.set(0.0)
-        # Device-cost observatory (observability/devprof.py): sampled
-        # block_until_ready timing around step dispatches, on the
-        # ENGINE clock so virtual-clock replays stay deterministic.
-        # Constructor/flag state like the SLO knobs — never set_flags
-        # mid-run. The cost-capture half rides tracked_jit's compile
-        # branch and needs no engine state; sampling decisions hash
-        # the dispatch counter, so the async dispatch-ahead path is
-        # untouched on every skipped (1 - sample rate) dispatch.
-        self._devprof = None
-        if bool(devprof if devprof is not None
-                else g["serving_devprof"]):
-            self._devprof = _devprof.DevProfiler(
-                sample=(devprof_sample if devprof_sample is not None
-                        else float(g["serving_devprof_sample"])),
-                gauge_labels={"engine": eid})
         # dynamic half of the `# guarded-by:` declarations above: under
         # FLAGS_sanitize_locks a rebinding write to any of these without
         # the named lock held raises GuardedStateError. Construction
@@ -1703,14 +1680,11 @@ class ServingEngine:
         """One group of an admission round, from building its inputs
         to its first tokens committed: one dispatch (:class:`_Stamps`), whose
         dispatch-to-fetched time is the bucket's sample for the SLO
-        estimate (:meth:`_landed`; a devprof sample blocks on the device
-        inside that interval and moves nothing). Returns rows admitted."""
+        estimate (:meth:`_landed`). Returns rows admitted."""
         t_adm = self._clock()
         for g_req, _row, _shared in group:
             _tracing.mark(g_req.id, "admit", t_adm, self.trace_track)
             g_req.admitted_at = t_adm
-        timer = self._devprof_timer(
-            f"serving_prefill_paged{{bucket={bucket}}}")
         # queued on the device behind the decode step in flight, if any
         st, behind = self._stamps(), self._flight is not None
         try:
@@ -1725,8 +1699,6 @@ class ServingEngine:
                 self._shed(req, e)
             return 0
         st.t_dispatch, st.t_launched = ev.t0, ev.t1
-        if timer is not None and out is not None:
-            timer.device_done(out)
         for (req, row, _), err in shed:
             self.cache.release_row(row)
             self._shed(req, err)
@@ -1774,8 +1746,6 @@ class ServingEngine:
                     req, self._take_first(req, first, lg, i), now)
         st.t_committed = ev.t1
         self._landed(st, len(live), ahead=behind, bucket=bucket)
-        if timer is not None:
-            timer.finish()
         return len(live)
 
     def _take_first(self, req: Request, first: np.ndarray, lg,
@@ -2171,10 +2141,9 @@ class ServingEngine:
         admitted since has its token on the host, and joins the step
         after), none decodes under a grammar (its mask for the next
         position is built from the token ``fl`` has not delivered),
-        some request goes on past ``fl`` (one that reaches its budget
-        there does not: its row is computed and dropped), and devprof
-        has not sampled the step (it blocks on purpose). Nothing here is
-        configured: it is read off the batch."""
+        and some request goes on past ``fl`` (one that reaches its
+        budget there does not: its row is computed and dropped). Nothing
+        here is configured: it is read off the batch."""
         held = {slot: (req, n) for slot, req, n in fl.rows}
         rows = []
         for slot, req in self._active.items():
@@ -2186,12 +2155,9 @@ class ServingEngine:
                 rows.append((slot, req, len(req.tokens) + 1))
         if not rows:
             return None
-        if self._devprof is not None and self._devprof.tick():
-            self._sample_next = True
-            return None
         return tuple(rows)
 
-    def _decode_attempt(self, sampled: bool = False):  # holds: _step_lock
+    def _decode_attempt(self):  # holds: _step_lock
         """Dispatch what this round can: the step to commit, unless it
         is in flight already, and the step after it, ahead of the fetch.
         A retry after a raise finds the first in ``_flight`` and does
@@ -2202,7 +2168,7 @@ class ServingEngine:
         first = self._flight is None
         if first:
             self._flight = self._launch()
-        rows = None if sampled else self._rows_ahead(self._flight)
+        rows = self._rows_ahead(self._flight)
         if rows is None:
             return None
         ahead = self._launch(self._flight, rows)
@@ -2230,25 +2196,6 @@ class ServingEngine:
                 _runlog.log_event("serving_kv_quant",
                                   max_abs_err=round(e, 6), rows=int(rows))
 
-    def _devprof_timer(self, entry):  # holds: _step_lock
-        """A StepTimer when devprof is on AND this dispatch hashed
-        into the sample, else None. The tick consumes one counter
-        increment either way, so two same-seed runs sample the same
-        step indices; a None costs nothing further — the async /
-        dispatch-ahead structure of a skipped dispatch is untouched.
-        Timestamps come off the ENGINE clock: virtual-clock replays
-        measure deterministic (zero-wall) splits and stay
-        byte-identical."""
-        dp = self._devprof
-        if dp is None:
-            return None
-        # a step that was held back from dispatch-ahead because it
-        # sampled in has consumed its tick already (_rows_ahead)
-        sampled, self._sample_next = self._sample_next or dp.tick(), False
-        if not sampled:
-            return None
-        return _devprof.StepTimer(dp, entry, self._clock)
-
     def _decode(self) -> int:  # holds: _step_lock
         """One batched decode over every occupied slot, dispatched one
         ahead of its fetch: the step this round commits is in flight
@@ -2265,14 +2212,11 @@ class ServingEngine:
         were produced (0 when idle/skipped)."""
         if not self._active:
             return 0
-        timer = None
-        if self._flight is None:
-            timer = self._devprof_timer("decode_step_paged")
         seq = self._dispatch_seq
         try:
             with _profiler.RecordEvent("serving.decode") as ev:
                 ahead = RetryPolicy.from_flags("serving.step").call(
-                    self._decode_attempt, timer is not None)
+                    self._decode_attempt)
                 # known only now, so kept in the in-process event alone
                 ev.args = {"launches": self._dispatch_seq - seq}
         except (_SkipStep, _PoolsLost):
@@ -2289,11 +2233,7 @@ class ServingEngine:
             last = self._flight if ahead is None else ahead
             last.stamps.t_launched = ev.t1
         fl, self._flight = self._flight, ahead
-        if timer is not None:
-            timer.device_done((fl.nxt, fl.keys))  # block + stamp
         produced = self._land(fl)
-        if timer is not None:
-            timer.finish()   # host_s = the commit loop above
         if not self._active:
             self._drain()    # nobody is left for the step in flight
         return produced
@@ -2570,7 +2510,6 @@ class ServingEngine:
         if not self._active:
             return 0
         n_active = len(self._active)
-        timer = self._devprof_timer(f"decode_megastep_paged{{n={n}}}")
         seq = self._dispatch_seq
         try:
             with _profiler.RecordEvent("serving.decode") as ev:
@@ -2588,12 +2527,6 @@ class ServingEngine:
             st.t_launched = ev.t1
         (toks, finish, tok_f, _pos_f, pools_f, keys_f, _live_f,
          _rem_f, _st_f, qerr) = out
-        if timer is not None:
-            # the one documented sampling cost: block on megastep k
-            # BEFORE enqueuing k+1, so the measured device time is
-            # k's alone. The (1 - sample rate) majority of megasteps
-            # skip this and keep the dispatch-ahead overlap intact.
-            timer.device_done(out)
         if self.dispatch_ahead:
             # enqueue k+1 behind k on the device BEFORE the host
             # blocks on k's results: commit work below overlaps it.
@@ -2641,8 +2574,6 @@ class ServingEngine:
         # by tokens, not steps, so SLO admission stays calibrated at
         # megastep > 1)
         self._landed(st, n_active, produced / n_active, ahead=taken)
-        if timer is not None:
-            timer.finish()
         if _runlog.enabled():
             _runlog.log_event("serving_megastep", n=n, active=n_active,
                               produced=produced)
@@ -2710,7 +2641,6 @@ class ServingEngine:
             tokens[slot, 0] = req.tokens[-1]
             tokens[slot, 1:] = d
         n_active = len(self._active)
-        timer = self._devprof_timer(f"verify_step_paged{{k={K}}}")
         try:
             with _profiler.RecordEvent("serving.verify") as ev:
                 st, out = RetryPolicy.from_flags(
@@ -2722,8 +2652,6 @@ class ServingEngine:
             self._shed_active(e)
             return 0
         st.t_launched = ev.t1
-        if timer is not None:
-            timer.device_done(out)
         nxt, _, arrays, qerr, accept, new_keys = out
         with _profiler.RecordEvent("serving.decode.fetch",
                                    {"flight": st.id}) as fetch:
@@ -2772,8 +2700,6 @@ class ServingEngine:
         # per-output-token pace: the step's time on the device spread
         # over the tokens each slot actually committed this step
         self._landed(st, n_active, produced / n_active)
-        if timer is not None:
-            timer.finish()
         return produced
 
     # -------------------------------------------------------- lifecycle
@@ -2885,15 +2811,6 @@ class ServingEngine:
             if req._session_counted:
                 req._session_counted = False
                 self.kv_tier.session_released(req.session)
-        if self._devprof is not None:
-            # annotate the sampled device share so blame() splits this
-            # trace's decode into decode_device + decode_host. None
-            # (no samples yet, or a virtual-clock run whose samples
-            # are zero-width) leaves the trace — and its exported
-            # bytes — exactly as without devprof.
-            frac = self._devprof.device_frac()
-            if frac is not None:
-                _tracing.annotate(req.id, decode_device_frac=frac)
         _tracing.finish(req.id, req.finished_at, self.trace_track,
                         "done")
         req._done.set()
@@ -3274,11 +3191,6 @@ class ServingEngine:
             # fleet-shared numbers when the tier is shared: every
             # attached engine reports the same store/session totals
             out["kv_tier"] = self.kv_tier.stats()
-        if self._devprof is not None:
-            # sampled roofline view (device/host split, per-entry
-            # MFU/HBM utilization and verdicts) — flows into
-            # GET /v1/stats with the rest of this dict
-            out["devprof"] = self._devprof.stats()
         c = self.cache
         # blocks held now by layer kind, and the blocks window layers
         # returned behind their windows while their request lived

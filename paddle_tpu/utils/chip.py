@@ -1,5 +1,6 @@
 """What an entry point settles before it touches the chip: where JAX's
-persistent compilation cache lives, and what the device calls itself.
+persistent compilation cache lives, what the device calls itself, and
+which peaks a device of that kind is rated at.
 
 Every call to the chip starts a fresh machine, and compiling a
 billion-parameter step takes longer than running it. The entry points
@@ -62,3 +63,32 @@ def device_info() -> dict:
     devs = jax.devices()
     return {"platform": devs[0].platform, "kind": devs[0].device_kind,
             "count": len(devs)}
+
+
+#: Per-chip peaks of the TPUs this repository may be measured on,
+#: keyed by ``jax.devices()[0].device_kind``: (dense bf16 FLOP/s, HBM
+#: bytes/s). Source: Google Cloud TPU documentation, the "System
+#: architecture" page of each generation (v5e: 197 TFLOP/s bf16,
+#: 819 GB/s; v6e: 918 TFLOP/s, 1640 GB/s; v5p: 459 TFLOP/s, 2765 GB/s;
+#: v4: 275 TFLOP/s, 1200 GB/s). The ONE table: ``bench.py`` reads it
+#: too. A TPU that is not listed is an error (:func:`tpu_peaks`), never
+#: a default — a wrong peak makes every utilisation wrong silently.
+TPU_PEAKS = {
+    "TPU v5 lite": (197e12, 819e9),
+    "TPU v6 lite": (918e12, 1640e9),
+    "TPU v5": (459e12, 2765e9),
+    "TPU v4": (275e12, 1200e9),
+}
+
+
+def tpu_peaks(device_kind: str):
+    """``(peak bf16 FLOP/s, peak HBM bytes/s)`` of one TPU chip by its
+    ``device_kind``; raises for a kind outside :data:`TPU_PEAKS`."""
+    try:
+        return TPU_PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no peak FLOP/s / HBM bandwidth known for TPU device_kind "
+            f"{device_kind!r}; add it (with its source) to "
+            f"utils.chip.TPU_PEAKS — known: "
+            f"{sorted(TPU_PEAKS)}") from None

@@ -87,12 +87,33 @@ def test_compile_cache_dir_is_the_variable_or_one_path_in_the_checkout(
 
 def test_tpu_peaks_come_from_one_table_and_an_unknown_tpu_raises():
     import bench
-    from paddle_tpu.observability import devprof
-    assert devprof.tpu_peaks("TPU v5 lite") == (197e12, 819e9)
+    from paddle_tpu.utils import chip
+    assert chip.tpu_peaks("TPU v5 lite") == (197e12, 819e9)
     with pytest.raises(KeyError, match="TPU v9"):
-        devprof.tpu_peaks("TPU v9")
+        chip.tpu_peaks("TPU v9")
     dev = types.SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
     assert bench.detect_peak_flops(dev) == 197e12
     dev.device_kind = "TPU v9"
     with pytest.raises(KeyError, match="TPU v9"):
         bench.detect_peak_flops(dev)
+
+
+def _benchmark_peaks():
+    with open(os.path.join(REPO, "perfbench", "peaks.json")) as f:
+        return {k: v for k, v in json.load(f).items()
+                if not k.startswith("_")}
+
+
+@pytest.mark.parametrize("kind", sorted(_benchmark_peaks()))
+def test_the_programs_peaks_equal_the_benchmarks(kind):
+    """Two tables in two owners (``utils/chip.py`` the program's,
+    ``perfbench/peaks.json`` the benchmark's) may not drift."""
+    from paddle_tpu.utils import chip
+    row = _benchmark_peaks()[kind]
+    assert chip.tpu_peaks(kind) == (row["bf16_flops"],
+                                    row["hbm_bytes_per_s"])
+
+
+def test_the_two_peak_tables_list_the_same_kinds():
+    from paddle_tpu.utils import chip
+    assert sorted(chip.TPU_PEAKS) == sorted(_benchmark_peaks())

@@ -6,11 +6,45 @@ Capability parity: platform/flags.cc + pybind/global_value_getter_setter.cc
 framework/details/nan_inf_utils_detail.cc hooked at operator.cc:1056.
 """
 
+import os
+import re
+
 import numpy as np
 import pytest
 
 from paddle_tpu import flags
 from paddle_tpu.framework import Executor, Program, Scope
+
+FLAGS_PY = os.path.abspath(flags.__file__)
+
+
+def _defined_flags():
+    """The names ``flags.py`` defines, read from its source: the same
+    list in every worker, whatever a test has defined since."""
+    with open(FLAGS_PY) as f:
+        return re.findall(r'^define_flag\("(\w+)"', f.read(), re.M)
+
+
+@pytest.fixture(scope="module")
+def program_sources():
+    """Every module of the program but ``flags.py``, as text."""
+    out = []
+    for root, _dirs, files in os.walk(os.path.dirname(FLAGS_PY)):
+        for name in files:
+            path = os.path.join(root, name)
+            if name.endswith(".py") and path != FLAGS_PY:
+                with open(path) as f:
+                    out.append(f.read())
+    return out
+
+
+@pytest.mark.parametrize("name", _defined_flags())
+def test_a_flag_does_not_outlive_its_reader(name, program_sources):
+    """A defined flag is read somewhere in the program: its quoted name
+    or ``FLAGS_<name>`` stands in a module other than ``flags.py``."""
+    needles = (f'"{name}"', f"'{name}'", f"FLAGS_{name}")
+    assert any(n in src for src in program_sources for n in needles), \
+        f"flag {name!r} is defined in flags.py and read nowhere"
 
 
 def test_flags_get_set_and_unknown():

@@ -330,13 +330,12 @@ def test_ahead_and_synchronous_runs_agree(scenario, gpt):
 
 # ------------------------------------------------------- what forces a sync
 
-@pytest.mark.parametrize("kw", [dict(spec_tokens=2), dict(megastep=3),
-                                dict(devprof=True, devprof_sample=1.0)],
-                         ids=["spec_tokens", "megastep", "devprof_all"])
+@pytest.mark.parametrize("kw", [dict(spec_tokens=2), dict(megastep=3)],
+                         ids=["spec_tokens", "megastep"])
 def test_what_decodes_another_way_dispatches_nothing_ahead(gpt, kw):
-    """Drafts come from host tokens, a megastep has its own pipelining
-    (behind its flag), and a step devprof samples blocks on purpose: the
-    counters read 0, and are there all the same."""
+    """Drafts come from host tokens, and a megastep has its own
+    pipelining (behind its flag): the counters read 0, and are there all
+    the same."""
     eng = _engine(gpt, **kw)
     reqs = [eng.submit(p, max_new_tokens=9) for p in _prompts((5, 6), 9)]
     eng.run_until_idle()
@@ -349,24 +348,6 @@ def test_what_decodes_another_way_dispatches_nothing_ahead(gpt, kw):
         assert r.output_ids == greedy_search(
             gpt, np.asarray([p]), max_new_tokens=9,
             cache_len=eng.max_len)[0].tolist()
-
-
-def test_a_sampled_step_of_devprof_is_synchronous_and_the_rest_ahead(gpt):
-    """A step that hashes into devprof's sample is held back (its timer
-    starts at its own dispatch) and consumes one tick, as before."""
-    eng = _engine(gpt, devprof=True, devprof_sample=0.3)
-    ref = _engine(gpt, True, devprof=True, devprof_sample=0.3)
-    for e in (eng, ref):
-        reqs = [e.submit(p, max_new_tokens=16) for p in _prompts((5, 6), 9)]
-        e.run_until_idle()
-        e.reqs = reqs
-    assert [r.tokens for r in eng.reqs] == [r.tokens for r in ref.reqs]
-    st, st0 = eng.stats(), ref.stats()
-    assert 0 < st["ahead_dispatches"] < st["sampler_dispatches"]
-    assert st["devprof"]["dispatches"] == st0["devprof"]["dispatches"]
-    assert 0 < st["devprof"]["samples"] == st0["devprof"]["samples"]
-    assert st["sampler_dispatches"] - st["ahead_dispatches"] >= \
-        st["devprof"]["samples"]
 
 
 # --------------------------------------------------------------- the counter

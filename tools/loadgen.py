@@ -912,12 +912,6 @@ class LoadGen:
             h["win_rate"] = (round(int(h.get("wins", 0)) / fired, 4)
                              if fired else None)
             report["hedges"] = h
-        if "devprof" in st:
-            # device-cost observatory section: sampled device/host
-            # split, per-entry rooflines/MFU — informational on wall
-            # clocks, deterministic zeros on a VirtualClock run (the
-            # perf ledger stores it alongside the goodput numbers)
-            report["devprof"] = st["devprof"]
         if "prefill_workers" in st:
             report["disagg"] = {k: st[k] for k in (
                 "prefill_workers", "decode_workers", "colocated",
@@ -1152,22 +1146,6 @@ def main(argv=None) -> int:
                     "of requests carrying a distributed trace "
                     "(deterministic id-hash sampling; 1.0 = all, "
                     "0 = off). Host-side only — zero new compiles")
-    ap.add_argument("--devprof", action="store_true",
-                    help="turn on the device-cost observatory "
-                         "(FLAGS_serving_devprof) for every engine "
-                         "this run constructs: XLA cost capture, "
-                         "sampled device timing, roofline gauges, "
-                         "decode blame split")
-    ap.add_argument("--devprof-sample", type=float, default=None,
-                    metavar="FRAC",
-                    help="override FLAGS_serving_devprof_sample "
-                         "(fraction of step dispatches that pay a "
-                         "block_until_ready timer; default keeps the "
-                         "flag's 0.1)")
-    ap.add_argument("--ledger", default="", metavar="PATH",
-                    help="append this run's headline metrics (+ "
-                         "devprof roofline summary and cost digest) "
-                         "as one tools/perf_ledger.py JSONL row")
     ap.add_argument("--span-trace-out", default="", metavar="PATH",
                     help="export the sampled requests' span traces as "
                     "Perfetto-loadable chrome-trace JSON after the run")
@@ -1285,18 +1263,6 @@ def main(argv=None) -> int:
     if args.trace_sample is not None:
         from paddle_tpu import flags as _fl
         _fl.set_flags({"serving_trace": args.trace_sample})
-    if args.devprof_sample is not None and not args.devprof:
-        print("FAIL: --devprof-sample needs --devprof",
-              file=sys.stderr)
-        return 1
-    if args.devprof:
-        # flag write (not an engine kwarg) so router- and
-        # disagg-constructed engines profile too
-        from paddle_tpu import flags as _fl
-        dp_flags = {"serving_devprof": True}
-        if args.devprof_sample is not None:
-            dp_flags["serving_devprof_sample"] = args.devprof_sample
-        _fl.set_flags(dp_flags)
     from paddle_tpu.observability import tracing as _tracing
     _tracing.reset()
     vc = (VirtualClock() if args.virtual_step_ms > 0 else None)
@@ -1394,10 +1360,6 @@ def main(argv=None) -> int:
     blame = _tracing.blame_summary()
     if blame["requests"]:
         report["blame"] = blame
-    if args.ledger:
-        from tools import perf_ledger
-        report["ledger_row"] = perf_ledger.append_report(
-            args.ledger, report, run="loadgen")
     if args.json:
         print(json.dumps(report))
     else:

@@ -83,19 +83,9 @@ Asserts, end to end through the observability plane:
     the single-token fallback a caps-exceeding stop list forces), no
     KV blocks leak, and predict_serving_compiles(megastep=4) equals
     the live tracker;
-  - a device-cost-observatory episode (FLAGS_serving_devprof,
-    sample=1.0): every compile's XLA cost_analysis is captured into
-    the cost table / ``xla_cost`` gauges by an out-of-band lowering
-    that adds ZERO compiles (``predict_serving_compiles(devprof=
-    True)`` is a validated no-op, predicted == observed), every
-    sampled dispatch feeds the roofline/MFU gauges, each traced
-    request's blame splits ``decode`` into ``decode_device`` +
-    ``decode_host`` with the reconciliation identity intact, and
-    ``/v1/stats`` serves the devprof section;
   - GET /metrics on ServingHTTPServer parses as Prometheus text and
     carries serving, fault, compile, KV block-pool, attention-impl,
-    int8-quantization, SLO-admission, tracing and device-cost
-    (xla_cost / MFU / HBM-utilization / host-overhead) metrics;
+    int8-quantization, SLO-admission and tracing metrics;
   - tools/trace_summary.py consumes the emitted JSONL run log.
 
 Run from the repo root:  JAX_PLATFORMS=cpu python tools/obs_smoke.py
@@ -937,87 +927,6 @@ def main() -> int:
         pt.set_flags({"serving_megastep": 1,
                       "serving_dispatch_ahead": False})
 
-    # -- devprof phase: the device-cost observatory is a validated ----
-    # no-op. FLAGS_serving_devprof bumps the flags version (a fresh
-    # phase), then every compile's XLA cost_analysis is captured by an
-    # out-of-band lowering of the RAW function — so the per-phase
-    # delta must equal the PLAIN prediction and
-    # predict_serving_compiles(devprof=True) must agree devprof never
-    # compiles. Sampling at 1.0 on the wall clock, every dispatch pays
-    # one block_until_ready: the cost table fills, the roofline/MFU
-    # gauges go live for the /metrics scrape below, every traced
-    # request's blame decomposes decode into decode_device +
-    # decode_host with the reconciliation identity intact, and
-    # /v1/stats serves the devprof section.
-    from paddle_tpu.observability import devprof
-    tracing.reset()
-    baseD = {site: c["count"]
-             for site, c in observability.compiles().items()
-             if site.startswith(("serving_", "decode_", "verify_"))}
-    pt.set_flags({"serving_devprof": True})
-    try:
-        engD = ServingEngine(model, max_slots=3, max_len=32,
-                             buckets=[8, 16], max_queue=16,
-                             block_size=4, devprof_sample=1.0)
-        reqsD = [engD.submit(p, max_new_tokens=4) for p in prompts]
-        engD.run_until_idle()
-        assert all(r.state == "done" for r in reqsD)
-    finally:
-        pt.set_flags({"serving_devprof": False})
-    stD = engD.stats()["devprof"]
-    assert stD["sample"] == 1.0, stD
-    assert stD["dispatches"] > 0 and \
-        stD["samples"] == stD["dispatches"], stD
-    assert stD["device_frac"] is not None, stD
-    assert any(e["entry"] == "decode_step_paged"
-               for e in stD["entries"]), stD
-    costsD = devprof.cost_table()
-    assert "decode_step_paged" in costsD, sorted(costsD)
-    assert devprof.cost_digest(), costsD
-    cD = costsD["decode_step_paged"]
-    assert cD["flops"] and cD["hbm_bytes"], cD
-    assert stD["mfu"] is not None and stD["mfu"] > 0.0, stD
-    for r in reqsD:
-        infoD = tracing.get(r.id)
-        bl = infoD["blame_ms"]
-        assert "decode" not in bl and "decode_device" in bl and \
-            "decode_host" in bl, bl
-        gapD = abs(sum(bl.values()) - infoD["e2e_ms"])
-        assert gapD < 1e-6, (
-            f"devprof blame split broke the identity on request "
-            f"{r.id}: {bl} vs e2e {infoD['e2e_ms']} (gap {gapD})")
-    afterD = {site: c["count"]
-              for site, c in observability.compiles().items()
-              if site.startswith(("serving_", "decode_", "verify_"))}
-    deltaD = {site: n - baseD.get(site, 0)
-              for site, n in afterD.items() if n - baseD.get(site, 0)}
-    burstD = [[(p, 4) for p in prompts]]
-    predD = predict_serving_compiles(
-        burstD, buckets=[8, 16], max_len=32, block_size=4,
-        devprof=True)
-    assert predD == predict_serving_compiles(
-        burstD, buckets=[8, 16], max_len=32, block_size=4), \
-        "devprof must be a predictor no-op"
-    assert deltaD == predD, (
-        f"devprof-phase recompile prediction drifted:\n"
-        f"  predicted {predD}\n  observed  {deltaD}")
-    srvD = ServingHTTPServer(engD, port=0)
-    srvD.start()
-    try:
-        with urllib.request.urlopen(
-                f"http://127.0.0.1:{srvD.port}/v1/stats",
-                timeout=10) as r:
-            assert r.status == 200
-            statsD = json.loads(r.read().decode())
-        assert statsD["devprof"]["samples"] == stD["samples"], statsD
-    finally:
-        srvD.stop()
-    print(f"   devprof: {stD['samples']}/{stD['dispatches']} dispatches "
-          f"sampled, device_frac {stD['device_frac']}, mfu "
-          f"{stD['mfu']}, {len(costsD)} costed sites (digest "
-          f"{devprof.cost_digest()}), blame split exact, "
-          f"{deltaD} == predicted (ZERO devprof compiles)")
-
     # -- /metrics scrape ----------------------------------------------
     srv = ServingHTTPServer(eng, port=0)
     srv.start()
@@ -1055,12 +964,7 @@ def main() -> int:
                    "serving_kv_migrations",
                    "serving_sessions_resident",
                    "serving_sessions_host",
-                   "serving_sessions_resumed",
-                   "xla_cost",
-                   "serving_device_step_ms",
-                   "serving_mfu",
-                   "serving_hbm_util",
-                   "serving_host_overhead_share"):
+                   "serving_sessions_resumed"):
         assert needle in text, f"/metrics missing {needle}"
     print(f"   /metrics: {n} samples, valid Prometheus text")
 

@@ -247,9 +247,6 @@ def main(argv=None) -> int:
     ap.add_argument("--out", default="",
                     help="write the soak record (windows + frontier) "
                     "here")
-    ap.add_argument("--ledger", default="", metavar="PATH",
-                    help="append the primary arm's headline metrics "
-                    "as one tools/perf_ledger.py JSONL row")
     ap.add_argument("--trace-out", default="", metavar="PATH",
                     help="export the primary arm's per-request span "
                     "traces as Perfetto-loadable chrome-trace JSON "
@@ -436,10 +433,6 @@ def main(argv=None) -> int:
     }
     if args.trace_out:
         out["trace_out"] = args.trace_out
-    if args.ledger:
-        from tools import perf_ledger
-        out["ledger_row"] = perf_ledger.append_report(
-            args.ledger, report, run="soak")
     if args.out:
         with open(args.out, "w") as f:
             json.dump(out, f, indent=1, sort_keys=True)

@@ -847,9 +847,7 @@ def sync_trainserve_block(text, check):
 _OBS_BEGIN = "<!-- BEGIN GENERATED: observability -->"
 _OBS_END = "<!-- END GENERATED: observability -->"
 _OBS_FLAGS = ("warn_recompiles", "runlog_dir", "runlog_max_mb",
-              "serving_trace", "serving_trace_keep",
-              "serving_devprof", "serving_devprof_sample",
-              "devprof_peak_flops", "devprof_peak_hbm_gbps")
+              "serving_trace", "serving_trace_keep")
 
 
 def render_observability_block():
@@ -914,39 +912,6 @@ def render_observability_block():
         "(`(1 - attainment) / (1 - target)`) — the",
         "`serving_slo_burn_rate` gauge and the per-window report of",
         "`tools/soak.py --trace-out`.",
-        "",
-        "The device-cost observatory",
-        "(`paddle_tpu.observability.devprof`, off by default behind",
-        "`FLAGS_serving_devprof`) adds the device half: every compile",
-        "of a tracked serving entry records the lowered computation's",
-        "XLA `cost_analysis()` (flops / HBM bytes / output bytes) into",
-        "`devprof.cost_table()` and the `xla_cost{fn,metric}` gauges",
-        "(a re-lowering of the raw function, so the compile counters",
-        "never move — `predict_serving_compiles(..., devprof=True)` is",
-        "a validated no-op), and a",
-        "`FLAGS_serving_devprof_sample`-rate `block_until_ready` timer",
-        "around step dispatch (deterministic Knuth hash of the",
-        "dispatch counter; skipped dispatches keep the async and",
-        "dispatch-ahead paths untouched) feeds the per-entry",
-        "`serving_device_step_ms` histogram, per-step roofline",
-        "verdicts (compute-bound / hbm-bound / host-bound, against",
-        "`FLAGS_devprof_peak_flops` / `FLAGS_devprof_peak_hbm_gbps` or",
-        "per-platform nominals) and the live `serving_mfu`,",
-        "`serving_hbm_util` and `serving_host_overhead_share` gauges.",
-        "The sampled device fraction splits `tracing.blame()`'s",
-        "`decode` component into `decode_device` + `decode_host` with",
-        "the exact-reconciliation identity preserved",
-        "(`tools/trace_summary.py --blame` renders the split and the",
-        "roofline table). `tools/perf_ledger.py` appends every",
-        "`bench.py` / `tools/loadgen.py --ledger` /",
-        "`tools/soak.py --ledger` run as one schema'd JSONL row",
-        "(goodput, TTFT/TPOT p95, MFU, host-overhead share,",
-        "cost-table digest, git rev) and",
-        "`python tools/perf_regress.py LEDGER --baseline",
-        "tools/perf_baseline.json` gates the latest row against the",
-        "committed baseline with per-metric noise tolerance (exit",
-        "nonzero on regression — the ci.sh perf gate; refresh the",
-        "baseline with `--write-baseline`).",
         "",
         "Instruments:",
         "",

@@ -21,12 +21,7 @@ spans JSONL (``tracing.export_spans_jsonl``: one
 prints the latency-component blame table: per-component total ms,
 share of summed E2E, p95 ms, and which component dominates the E2E
 p95 tail (see paddle_tpu/observability/tracing.py for the accounting
-identity behind the numbers). Runs traced under FLAGS_serving_devprof
-split ``decode`` into ``decode_device`` / ``decode_host`` rows and
-carry embedded roofline entries (chrome ``devprof`` metadata events /
-JSONL ``{"devprof": ...}`` lines); ``--blame`` then also prints the
-per-compiled-entry roofline table with the verdict — compute-bound,
-hbm-bound, or host-bound.
+identity behind the numbers).
 """
 
 from __future__ import annotations
@@ -152,49 +147,7 @@ def collect_blame(fmt: str, events: List[dict]) -> Dict[int, dict]:
     return per
 
 
-def collect_devprof(fmt: str, events: List[dict]) -> List[dict]:
-    """Device-cost observatory roofline rows embedded in a tracing
-    export (FLAGS_serving_devprof): chrome metadata events named
-    ``devprof``, or bare ``{"devprof": {...}}`` JSONL lines. Empty
-    list when the run profiled nothing."""
-    out = []
-    for e in events:
-        if fmt == "chrome":
-            if e.get("ph") == "M" and e.get("name") == "devprof" and \
-                    isinstance(e.get("args"), dict):
-                out.append(e["args"])
-        elif isinstance(e.get("devprof"), dict) and "span" not in e:
-            out.append(e["devprof"])
-    return out
-
-
-def print_roofline(entries: List[dict]):
-    """The per-compiled-entry roofline table: sampled device/host ms,
-    MFU / HBM utilization from the captured XLA costs, and the
-    verdict — compute-bound, hbm-bound, host-bound, or unattributed
-    (sampled but never cost-captured)."""
-    if not entries:
-        return
-
-    def fm(v, spec="{:.3f}"):
-        return "-" if v is None else spec.format(v)
-
-    name_w = max(len(str(e.get("entry", "?"))) for e in entries)
-    name_w = max(name_w, len("Entry"))
-    print(f"{'Entry':{name_w}s}  {'Samples':>7s}  {'Dev(ms)':>9s}  "
-          f"{'Host(ms)':>9s}  {'MFU':>8s}  {'HBM':>8s}  Verdict")
-    for e in sorted(entries, key=lambda e: str(e.get("entry", "?"))):
-        print(f"{str(e.get('entry', '?')):{name_w}s}  "
-              f"{e.get('samples', 0):7d}  "
-              f"{fm(e.get('device_ms_mean')):>9s}  "
-              f"{fm(e.get('host_ms_mean')):>9s}  "
-              f"{fm(e.get('mfu'), '{:.2%}'):>8s}  "
-              f"{fm(e.get('hbm_util'), '{:.2%}'):>8s}  "
-              f"{e.get('verdict', '?')}")
-
-
-def print_blame(per: Dict[int, dict], path: str,
-                devprof: List[dict] = ()) -> int:
+def print_blame(per: Dict[int, dict], path: str) -> int:
     if not per:
         print(f"{path}: no per-request serving spans "
               "(need tracing chrome-trace X events with args.request, "
@@ -222,7 +175,6 @@ def print_blame(per: Dict[int, dict], path: str,
               f"{_pctl(vals, 95):10.3f}  {tmean:12.3f}")
     dominant = max(names, key=lambda n: tail_means[n])
     print(f"tail blame: {dominant} dominates the E2E p95 tail")
-    print_roofline(list(devprof))
     return 0
 
 
@@ -241,8 +193,7 @@ def main(argv=None) -> int:
 
     fmt, events = load_events(args.path)
     if args.blame:
-        return print_blame(collect_blame(fmt, events), args.path,
-                           collect_devprof(fmt, events))
+        return print_blame(collect_blame(fmt, events), args.path)
     rows = (summarize_chrome(events) if fmt == "chrome"
             else summarize_runlog(events))
     if not rows:
