@@ -80,7 +80,10 @@ from ..dygraph.tensor import Tensor
 from ..initializer import ConstantInitializer, NormalInitializer
 from ..nn import functional as F
 from ..nn.layers_common import Embedding, Linear
-from ..ops.attention_ops import block_attention_gqa, block_scatter_write
+from ..ops.attention_ops import (block_attention_gqa, block_scatter_write,
+                                 index_pool_write, index_scores_paged,
+                                 sparse_decode_attention,
+                                 sparse_prompt_attention)
 from ..ops.decoder_ops import (DECODE_TILE_M, _moe_router, moe_experts,
                                moe_experts_decode_counts, rotary_inv_freq,
                                rotary_tables)
@@ -92,6 +95,12 @@ _PERIOD = ("full_attention",) + ("sliding_attention",) * 3
 #: layers (``LagunaMoE.served``): a served model's seam names a prefix of
 #: these as its ``counters``
 DECODE_COUNTERS = ("experts_touched", "expert_rows_max")
+#: what a decode step of a model with an indexer (``sa_config``) counts
+#: after ``experts_touched``, summed over its live rows and its layers by
+#: the selected read itself (``ops.attention_ops.sparse_decode_attention``):
+#: the keys that were eligible for a row's selection, and the keys its
+#: read kept (the chosen set, as the mask the paged walk was given)
+SPARSE_COUNTERS = ("sparse_keys_live", "sparse_keys_read")
 #: rotary parameters by layer kind, as Laguna-XS.2 publishes them
 _ROPE = {
     "full_attention": {
@@ -163,6 +172,11 @@ class LagunaConfig:
     # call), so that its grouped products have one shape whatever the
     # prompt's bucket and its buffers stay bounded
     moe_chunk_rows: int = 0
+    # learned sparse attention: None, or the published ``sa_config``
+    # (``indexer_num_heads`` of ``indexer_head_dim`` over
+    # ``indexer_num_kv_heads`` = 1 key head, ``topk``): every layer gains
+    # an indexer and every query reads only the ``topk`` keys it picks
+    sa_config: Optional[dict] = None
 
     def __post_init__(self):
         n = self.num_hidden_layers
@@ -177,6 +191,31 @@ class LagunaConfig:
             if h % self.num_key_value_heads:
                 raise ValueError(f"{h} query heads over "
                                  f"{self.num_key_value_heads} KV heads")
+        if self.sa_config is not None:
+            if int(self.sa_config.get("indexer_num_kv_heads", 1)) != 1:
+                raise ValueError("the indexer has one key head; sa_config "
+                                 f"says {self.sa_config}")
+            if "sliding_attention" in self.layer_types:
+                raise ValueError("an indexer picks among every key of a "
+                                 "request: no layer of a model with "
+                                 "sa_config has a window")
+
+    @property
+    def indexer(self):
+        """None, or the indexer's (heads, head size, keys a query
+        reads)."""
+        sa = self.sa_config
+        return None if sa is None else (
+            int(sa["indexer_num_heads"]), int(sa["indexer_head_dim"]),
+            int(sa["topk"]))
+
+    @property
+    def decode_counters(self):
+        """What a decode step of this model counts on the device, in the
+        order of :meth:`LagunaModel.served`'s vector."""
+        if self.sa_config is None:
+            return DECODE_COUNTERS
+        return DECODE_COUNTERS[:1] + SPARSE_COUNTERS
 
     @staticmethod
     def _range(held, whole):
@@ -212,7 +251,8 @@ class LagunaConfig:
         (:meth:`LagunaAttention._paged_read`, which spreads a packed
         pool's query heads over their lanes), a window layer's the
         composed read of the entries its window covers."""
-        if "sliding_attention" in self.layer_types:
+        if "sliding_attention" in self.layer_types or self.sa_config:
+            # (the selected read's mask is a value a key, not a lane)
             return 1
         kv = self.kv_heads[1] - self.kv_heads[0]
         return max(p for p in range(1, kv + 1)
@@ -235,6 +275,11 @@ class LagunaConfig:
             n += h * (q + 2 * kv) * d + q * d * h + 2 * h
             if self.attention_gate:
                 n += h * q
+            if self.qk_norm:
+                n += 2 * d
+            if self.indexer:
+                hi, di, _ = self.indexer
+                n += h * (hi * di + di + hi) + 2 * di
             if self.mlp_layer_types[i] == "dense":
                 n += 3 * h * self.intermediate_size
             else:
@@ -269,6 +314,19 @@ def _linear(n_in, n_out, std, dtype="float32"):
 def no_counts():
     """What a layer with no experts, or a prompt's pass, counts."""
     return jnp.zeros((len(DECODE_COUNTERS),), jnp.int32)
+
+
+def _rotate_half(x, rows, theta: float):
+    """``x`` float32 [b, s, .., d] rotated (rotate-half over all ``d``) at
+    the positions ``rows`` [b, s]."""
+    d = x.shape[-1]
+    inv, _ = rotary_inv_freq(d, theta)
+    ang = rows.astype(jnp.float32)[..., None] * jnp.asarray(inv, jnp.float32)
+    ang = ang.reshape(ang.shape[:2] + (1,) * (x.ndim - 3) + (d // 2,))
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    x1, x2 = x[..., :d // 2], x[..., d // 2:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1)
 
 
 def _matmul_in(x, dtype: str):
@@ -329,6 +387,17 @@ class LagunaAttention(Layer):
         self.window = cfg.sliding_window \
             if self.kind == "sliding_attention" else 0
         self._tables = {}
+        if cfg.indexer:
+            # the indexer: 16 small query heads over ONE key head, a weight
+            # a head; its key passes a LayerNorm (with bias)
+            hi, di, _ = cfg.indexer
+            self.index_q = _linear(h, hi * di, cfg.init_std, cfg.dtype)
+            self.index_k = _linear(h, di, cfg.init_std, cfg.dtype)
+            self.index_w = _linear(h, hi, cfg.init_std, cfg.dtype)
+            self.index_k_norm_weight = self.create_parameter(
+                [di], attr=ParamAttr(initializer=ConstantInitializer(1.0)))
+            self.index_k_norm_bias = self.create_parameter(
+                [di], attr=ParamAttr(initializer=ConstantInitializer(0.0)))
 
     def _rotary(self, seq: int):
         if seq not in self._tables:
@@ -369,6 +438,35 @@ class LagunaAttention(Layer):
                    )["Out"][0]
         return o.transpose([0, 2, 1, 3])
 
+    def _index(self, u, rows):
+        """The indexer's view of the rows ``u`` [b, s, h] (the layer's
+        normed input) at positions ``rows`` [b, s] -> (its queries
+        [b, s, heads, di] and its keys [b, s, di], both rotated over all
+        ``di`` values and in the parameters' dtype, and a weight a head
+        float32 [b, s, heads], scaled by ``1 / sqrt(heads x di)``).
+        Projections accumulate in float32; the key's LayerNorm and the
+        rotary are float32."""
+        hi, di, _ = self.cfg.indexer
+        u = u.value if isinstance(u, Tensor) else u
+        b, s, _ = u.shape
+        theta = float(self.rope["rope_theta"])
+
+        def proj(layer):
+            w = layer.weight.value
+            return jnp.einsum("bsh,hn->bsn", u.astype(w.dtype), w,
+                              preferred_element_type=jnp.float32)
+        kx = proj(self.index_k)
+        mean = jnp.mean(kx, axis=-1, keepdims=True)
+        var = jnp.mean(jnp.square(kx - mean), axis=-1, keepdims=True)
+        kx = (kx - mean) * jax.lax.rsqrt(var + self.cfg.rms_norm_eps) \
+            * self.index_k_norm_weight.value.astype(jnp.float32) \
+            + self.index_k_norm_bias.value.astype(jnp.float32)
+        dt = self.index_q.weight.value.dtype
+        q = _rotate_half(proj(self.index_q).reshape(b, s, hi, di), rows,
+                         theta)
+        return (q.astype(dt), proj(self.index_w) / math.sqrt(hi * di),
+                _rotate_half(kx, rows, theta).astype(dt))
+
     def forward(self, h, cache=None, cache_pos=None, block_tables=None,
                 ctx_len=None):
         cfg, d = self.cfg, self.cfg.head_dim
@@ -380,7 +478,18 @@ class LagunaAttention(Layer):
                              f"max_position_embeddings="
                              f"{cfg.max_position_embeddings}")
         cos, sin = self._rotary(s)
-        o = self._attend(*self._heads(self.qkv_proj(h), cos, sin))
+        if cfg.indexer:
+            # every query reads the keys its indexer picks. No gradient
+            # passes the selection: a model with an indexer is served, not
+            # trained (``LagunaForCausalLM.forward`` refuses labels)
+            q, k, v = self._heads(self.qkv_proj(h), cos, sin)
+            rows = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
+            o = sparse_prompt_attention(
+                q.value, k.value, v.value, *self._index(h, rows),
+                cfg.indexer[2])
+            o = Tensor(o, stop_gradient=True).transpose([0, 2, 1, 3])
+        else:
+            o = self._attend(*self._heads(self.qkv_proj(h), cos, sin))
         if cfg.attention_gate:
             o = self._gate(h, o)
         return self.o_proj(o.reshape([b, s, self.q * d]))
@@ -393,10 +502,14 @@ class LagunaAttention(Layer):
 
     def _served(self, h, cache, cache_pos, tables, ctx_len):
         """The serving engine's call: ``cache`` this layer's (k, v) pool
-        pair ``[blocks, kv, block_size, d]``, ``tables`` [b, T] its kind's
+        pair ``[blocks, kv, block_size, d]`` (with an indexer a third
+        array after them, its keys' pool ``[blocks, di, block_size]``),
+        ``tables`` [b, T] its kind's
         block tables, ``cache_pos`` [b] each request's first row of this
         call, ``ctx_len`` [b] its rows once the call is done. -> (output,
-        the pools with the call's K and V written)."""
+        the pools with the call's K and V written, and what a decode row's
+        selected read counted, int32 [b, 2]: the keys eligible and the
+        keys read; None for a prompt and for a layer without an indexer)."""
         cfg, d = self.cfg, self.cfg.head_dim
         b, s, _ = h.shape
         kp, vp = cache[0].value, cache[1].value
@@ -441,7 +554,25 @@ class LagunaAttention(Layer):
             kw, vw, wpos = tail(kw), tail(vw), pos + start
         kp = block_scatter_write(kp, kw, wpos, tables)
         vp = block_scatter_write(vp, vw, wpos, tables)
-        if s > 1:
+        pools, reads = (kp, vp), None
+        if cfg.indexer:
+            # the indexer's keys go to their own pool under the same table;
+            # a prompt's rows pick among themselves, a decode row among
+            # the rows its table holds up to its own
+            qi, wi, ki = self._index(h, rows)
+            ip = index_pool_write(cache[2].value, ki, pos, tables)
+            pools = (kp, vp, ip)
+            if s > 1:
+                read = sparse_prompt_attention(
+                    q.value, k.value, v.value, qi, wi, ki, cfg.indexer[2])
+            else:
+                read, reads = sparse_decode_attention(
+                    q.value, kp, vp, tables, pos,
+                    index_scores_paged(qi[:, 0], wi[:, 0], ip, tables),
+                    cfg.indexer[2])
+                read = read.astype(q.dtype)
+            o = Tensor(read, stop_gradient=True).transpose([0, 2, 1, 3])
+        elif s > 1:
             o = self._attend(q, k, v)
         else:
             # a window layer gathers the table entries its window covers:
@@ -454,8 +585,7 @@ class LagunaAttention(Layer):
         if cfg.attention_gate:
             o = self._gate(h, o)
         return (self.o_proj(o.reshape([b, s, self.q * d])),
-                (Tensor(kp, stop_gradient=True),
-                 Tensor(vp, stop_gradient=True)))
+                tuple(Tensor(p, stop_gradient=True) for p in pools), reads)
 
     def _paged_read(self, q, kp, vp, tables, pos):
         """One decode row a request through ``paged_decode_attn``: ``q``
@@ -622,17 +752,18 @@ class LagunaBlock(Layer):
 
     def served(self, x, cache, cache_pos, tables, ctx_len, live):
         """The serving engine's call: ``x`` the float32 residual stream
-        -> (x, this layer's pools, the expert layer's counts)."""
+        -> (x, this layer's pools, the expert layer's counts, the selected
+        read's counts a row or None: :meth:`LagunaAttention._served`)."""
         dt = self.cfg.dtype
-        a, cache = self.attn(_matmul_in(self.attn_norm(x), dt), cache,
-                             cache_pos, tables, ctx_len)
+        a, cache, reads = self.attn(_matmul_in(self.attn_norm(x), dt), cache,
+                                    cache_pos, tables, ctx_len)
         x = x + a.astype("float32")
         u = self.mlp_norm(x)
         if not self.sparse:
             y = self.mlp(_matmul_in(u, dt)).astype("float32")
-            return x + y, cache, no_counts()
+            return x + y, cache, no_counts(), reads
         y, counted = self.moe.served(u.value, live)
-        return x + Tensor(y, stop_gradient=True), cache, counted
+        return x + Tensor(y, stop_gradient=True), cache, counted, reads
 
 
 class LagunaModel(Layer):
@@ -693,8 +824,11 @@ class LagunaModel(Layer):
                last=None, collect=None):
         """The serving engine's call -> (the final norm's output float32
         [b, s, h], the caches, the expert layers' counts summed over the
-        layers: int32 [2], :data:`DECODE_COUNTERS`). ``cache``: one (k, v)
-        pool pair a layer. ``block_tables``: one
+        layers: int32 [2], :data:`DECODE_COUNTERS`; with an indexer int32
+        [3], ``cfg.decode_counters``: the selected reads' counts of the
+        step's live rows after the experts touched). ``cache``: one (k, v)
+        pool pair a layer (with an indexer (k, v, its keys)).
+        ``block_tables``: one
         table a layer kind in ``cfg.cache_kinds()``'s order (the table
         itself where there is one kind). ``last`` [b]: each prompt's last
         row in this call (None: every row is one)."""
@@ -715,13 +849,24 @@ class LagunaModel(Layer):
         # a slot with no request has no row yet: it routes nowhere
         live = pos > 0 if s == 1 else None
         caches, touched = [], no_counts()
+        reads = jnp.zeros((b, 2), jnp.int32)
         for i, blk in enumerate(self.layers):
-            x, c, t = blk.served(x, cache[i], pos,
-                                 table_of[cfg.layer_types[i]], ctx_len, live)
+            x, c, t, r = blk.served(x, cache[i], pos,
+                                    table_of[cfg.layer_types[i]], ctx_len,
+                                    live)
             caches.append(c)
             touched = touched + t
+            if r is not None:
+                reads = reads + r
             if collect is not None:
                 collect.append(x)
+        if cfg.indexer:
+            # what the selected reads counted, of a decode step's live rows
+            # (a prompt counts nothing; a step's sum is ~1e6, far inside
+            # int32)
+            if live is not None:
+                reads = jnp.where(live[:, None], reads, 0)
+            touched = jnp.concatenate([touched[:1], jnp.sum(reads, axis=0)])
         return self.norm(x), caches, touched
 
 
@@ -749,6 +894,11 @@ class LagunaForCausalLM(Layer):
     def forward(self, input_ids, labels=None, collect=None, cache=None,
                 cache_pos=None, block_tables=None, lora=None, last=None,
                 counters=None):
+        if labels is not None and self.cfg.indexer:
+            raise ValueError(
+                f"{type(self).__name__} with an indexer (sa_config) is "
+                f"served, not trained: no gradient passes the selection, so "
+                f"a loss would train neither q, k, v nor the indexer")
         # the first forward is the one a compiled step traces
         span = contextlib.nullcontext() if self._traced \
             else RecordEvent(f"{self.span_prefix}.first_trace")
@@ -762,7 +912,7 @@ class LagunaForCausalLM(Layer):
             if counters is None:
                 return logits, caches
             # the decode step's device counters (``serving_spec``'s names:
-            # a prefix of DECODE_COUNTERS)
+            # a prefix of ``cfg.decode_counters``)
             return logits, caches, counters \
                 + touched[:counters.shape[0]].astype(counters.dtype)
         with span:

@@ -251,8 +251,8 @@ class Lfm2Block(Layer):
         if self.kind == "conv":
             y, cache = self.conv.served(u, cache, tables, last)
         else:
-            y, cache = self.attn(_matmul_in(u, self.cfg.dtype), cache, pos,
-                                 tables, ctx_len)
+            y, cache, _ = self.attn(_matmul_in(u, self.cfg.dtype), cache,
+                                    pos, tables, ctx_len)
             y = y.astype("float32")
         x, counted = self._ffn(x + y, live)
         return x, cache, counted

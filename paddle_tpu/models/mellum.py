@@ -18,7 +18,9 @@ products, the untied head, the build / first-trace spans. **Configured
 off**: the per-head output gate, the shared expert, the sigmoid scores and
 their 2.5 scale, the dense leading layer, head counts that differ by
 layer, the per-head norm on q and k (``qk_norm``) and the router's
-selection bias (``router_bias``; ``models/lfm2.py`` turns both on). **What could not be shared** is what serving adds and training has
+selection bias (``router_bias``; ``models/lfm2.py`` turns both on), and the
+learned sparse attention's indexer (``sa_config``; ``models/keye.py`` turns
+it on). **What could not be shared** is what serving adds and training has
 not: the paged cache by layer kind, positions applied at a row's own
 offset, the few-rows form of the expert layer; that lives in the same
 classes (``LagunaAttention._served``, ``LagunaMoE.served``), so Laguna is

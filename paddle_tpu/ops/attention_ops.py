@@ -101,6 +101,15 @@ def _chunk_rows(s: int, bs: int, dtype) -> int:
     return c if c < bs and bs % c == 0 else bs
 
 
+def _physical_blocks(tables, at, bs: int, overflow_block):
+    """``at`` [b, n] logical positions -> their physical blocks through
+    ``tables`` [b, T]; positions past the table go to ``overflow_block``."""
+    T = tables.shape[1]
+    logical = at // bs
+    phys = jnp.take_along_axis(tables, jnp.minimum(logical, T - 1), axis=1)
+    return jnp.where(logical < T, phys, jnp.int32(overflow_block))
+
+
 def block_scatter_write(pool, new, pos, tables, overflow_block=0):
     """Write ``new`` [b, h, s, d] rows into the block-paged KV pool
     ``pool`` [num_blocks, h, block_size, d], routing each batch row's
@@ -141,15 +150,10 @@ def block_scatter_write(pool, new, pos, tables, overflow_block=0):
     tables = jnp.asarray(tables, jnp.int32)
     b, h, s, d = new.shape
     bs = pool.shape[2]
-    T = tables.shape[1]
     new = new.astype(pool.dtype)
 
     def physical(at):
-        """[b, n] logical positions -> their physical blocks."""
-        logical = at // bs
-        phys = jnp.take_along_axis(tables, jnp.minimum(logical, T - 1),
-                                   axis=1)
-        return jnp.where(logical < T, phys, jnp.int32(overflow_block))
+        return _physical_blocks(tables, at, bs, overflow_block)
 
     if b * s <= INPLACE_WRITE_MAX_ROWS:
         rowpos = pos[:, None] + jnp.arange(s, dtype=jnp.int32)[None]
@@ -469,3 +473,245 @@ def _fused_attention_qkv(ctx, ins, attrs):
                     "(O(s^2) memory)", *shape, e)
     return {"Out": [_composed_attention(q, k, v, mask, causal, scale,
                                         window)]}
+
+
+# ------------------------------------------------- learned sparse attention
+#
+# A layer with an indexer keeps, beside K and V, one small key a token (the
+# indexer's, ``d`` values, no head axis) and every query reads K and V only
+# at the ``topk`` keys whose index score is largest. The pool of indexer
+# keys is ``[blocks, d, block_size]``: a token a LANE, so that a ``d`` under
+# 128 pads nothing on the chip (``[blocks, block_size, 64]`` is laid out in
+# rows of 128 lanes, twice the bytes) and a block is the ``[d, keys]``
+# operand the scores' product wants. Everything here is plain XLA but a
+# decode row's read, which is ``paged_decode_attn``'s walk under the set's
+# mask.
+#
+# Both paths want their sets as a mask, and take it from two exact
+# selections, each where it is the faster by its seconds on a v5e
+# (``perfbench/study/select_forms_keye.py``, PR 46): a prompt takes
+# :func:`topk_mask`'s bisection (256 queries over 24576 keys: 0.58 ms,
+# against 7.1 by ``lax.top_k``'s sort and a mask of the cut's ties); a
+# decode row takes ``lax.top_k`` and masks by its cut (8 rows of 25600:
+# 0.21 ms; the bisection's 32 dependent counts over so few rows read 1.1
+# with a scatter behind them).
+
+#: queries of one chunk of a prompt's selected read: a prompt's bucket is a
+#: multiple of it, and a chunk's scores ``[chunk, heads, keys]`` float32 and
+#: one KV head's logits ``[group, chunk, keys]`` are what the read holds
+#: (read when the read is traced)
+SPARSE_QUERY_CHUNK = 256
+
+
+def index_pool_write(pool, new, pos, tables, overflow_block=0):
+    """Write ``new`` [b, s, d], the indexer's keys of rows
+    ``pos[b]..pos[b]+s-1``, into ``pool`` [blocks, d, block_size] through
+    ``tables`` [b, T]: :func:`block_scatter_write` for a pool with no head
+    axis and its tokens along the last one (that function's pieces are
+    ``[1, h, 1, d]`` rows and, in ``pool_chunk_write``'s kernel, ``[h, c,
+    d]`` chunks with ``d`` on 128 lanes; here a token is a lane and ``d``
+    is under 128, so only the route through the table,
+    :func:`_physical_blocks`, is shared). A few rows are in-place updates
+    of one ``[1, d, 1]`` column each; many rows go a whole block at a
+    time, the rows of a touched block that are not the call's merged from
+    what the pool holds. Rows past the table go to ``overflow_block``."""
+    pos = jnp.asarray(pos, jnp.int32)
+    tables = jnp.asarray(tables, jnp.int32)
+    b, s, d = new.shape
+    bs = pool.shape[2]
+    new = new.astype(pool.dtype)
+
+    def physical(at):
+        return _physical_blocks(tables, at, bs, overflow_block)
+
+    if b * s <= INPLACE_WRITE_MAX_ROWS:
+        rowpos = pos[:, None] + jnp.arange(s, dtype=jnp.int32)[None]
+        phys, offset = physical(rowpos), rowpos % bs
+        z = jnp.zeros((), jnp.int32)
+        for i in range(b):
+            for j in range(s):
+                pool = jax.lax.dynamic_update_slice(
+                    pool, new[i, j][None, :, None],
+                    (phys[i, j], z, offset[i, j]))
+        return pool
+    n = (s + bs - 2) // bs + 1
+    start = pos[:, None] // bs * bs + bs * jnp.arange(n, dtype=jnp.int32)[None]
+    first = start - pos[:, None]                    # row of `new` at a
+    padded = jnp.pad(new, ((0, 0), (bs, bs), (0, 0)))   # block's first lane
+    mine = jax.vmap(lambda rows, at: jax.vmap(
+        lambda f: jax.lax.dynamic_slice_in_dim(rows, f + bs, bs, axis=0))(at)
+    )(padded, first)                                # [b, n, bs, d]
+    row = first[:, :, None] + jnp.arange(bs, dtype=jnp.int32)   # [b, n, bs]
+    written = jnp.logical_and(row >= 0, row < s)
+    phys = physical(start).reshape(-1)
+    merged = jnp.where(written.reshape(b * n, 1, bs),
+                       mine.reshape(b * n, bs, d).transpose(0, 2, 1),
+                       pool[phys])
+    return pool.at[phys].set(merged)
+
+
+def index_scores(q_idx, w, k_idx):
+    """The indexer's score of every key for every query:
+    ``I[.., t, s] = sum_j w[.., t, j] relu(q_idx[.., t, j] . k_idx[.., s])``
+    with ``q_idx`` [.., t, heads, d], ``w`` [.., t, heads] float32 and
+    ``k_idx`` [.., s, d] (one key head for all the indexer's heads). The
+    products accumulate in float32; ReLU, weights and the sum are
+    float32."""
+    dots = jnp.einsum("...tjd,...sd->...tjs", q_idx, k_idx,
+                      preferred_element_type=jnp.float32)
+    return jnp.sum(jax.nn.relu(dots) * w[..., None].astype(jnp.float32),
+                   axis=-2)
+
+
+def index_scores_paged(q_idx, w, pool, tables):
+    """:func:`index_scores` of one decode row a request over its paged
+    indexer keys: ``q_idx`` [b, heads, d], ``w`` [b, heads], ``pool``
+    [blocks, d, block_size], ``tables`` [b, T] -> float32 [b, T x
+    block_size] (entries past a request's rows score garbage: the caller
+    masks by position)."""
+    kg = pool[jnp.asarray(tables, jnp.int32)]           # [b, T, d, bs]
+    b, T, _, bs = kg.shape
+    dots = jnp.einsum("bjd,btdk->bjtk", q_idx.astype(kg.dtype), kg,
+                      preferred_element_type=jnp.float32)
+    return jnp.sum(jax.nn.relu(dots)
+                   * w[:, :, None, None].astype(jnp.float32),
+                   axis=1).reshape(b, T * bs)
+
+
+def _sortable(x):
+    """float32 -> uint32 whose unsigned order is the floats' order (-0.0
+    as +0.0: the two are one score, a tie)."""
+    x = x.astype(jnp.float32)
+    u = jax.lax.bitcast_convert_type(jnp.where(x == 0, 0.0, x), jnp.uint32)
+    return jnp.where(u >> 31 == 1, ~u, u | jnp.uint32(1 << 31))
+
+
+def topk_mask(scores, valid, k: int):
+    """The EXACT set of the ``k`` largest ``scores`` [.., n] among the
+    ``valid`` [.., n] keys of each row, as a boolean mask (all the valid
+    keys where they are ``k`` or fewer); ties go to the lower index, as
+    ``lax.top_k`` breaks them. No sort: the ``k``-th largest value is found
+    by bisection on the scores' bits (32 counts over the row), and the keys
+    equal to it are admitted in index order up to ``k``."""
+    n = scores.shape[-1]
+    if k >= n:
+        return valid
+    key = jnp.where(valid, _sortable(scores), jnp.uint32(0))
+
+    def bit(i, t):
+        cand = t | (jnp.uint32(1) << (jnp.uint32(31) - i.astype(jnp.uint32)))
+        enough = jnp.sum(key >= cand[..., None], axis=-1,
+                         dtype=jnp.int32) >= k
+        return jnp.where(enough, cand, t)
+    kth = jax.lax.fori_loop(0, 32, bit,
+                            jnp.zeros(scores.shape[:-1], jnp.uint32))
+    above = key > kth[..., None]
+    room = k - jnp.sum(above, axis=-1, dtype=jnp.int32)
+    tie = key == kth[..., None]
+    first = jnp.cumsum(tie, axis=-1, dtype=jnp.int32) <= room[..., None]
+    return jnp.logical_and(
+        valid, jnp.logical_or(above, jnp.logical_and(tie, first)))
+
+
+def sparse_prompt_attention(q, k, v, q_idx, w, k_idx, topk: int):
+    """A prompt's rows over themselves where every query reads only the
+    keys its indexer picks: ``q`` [b, hq, s, d], ``k`` / ``v`` [b, hkv, s,
+    d] (grouped: query head ``j`` reads KV head ``j // (hq / hkv)``),
+    ``q_idx`` [b, s, heads, di], ``w`` [b, s, heads], ``k_idx`` [b, s, di]
+    -> [b, hq, s, d] in ``v``'s dtype. Query ``t`` scores keys ``s <= t``
+    (:func:`index_scores`), keeps its own ``topk`` largest
+    (:func:`topk_mask`: a set for EVERY row, shared by its heads) and takes
+    the softmax over those. :data:`SPARSE_QUERY_CHUNK` queries at a time,
+    one KV head at a time, over all the keys under the chosen set's mask:
+    neither the ``[s, s]`` scores nor a head's logits exist whole. (The
+    chosen keys are not gathered: a gather a query would move ``topk`` rows
+    of K and V for every row of the prompt, so the selection is wanted as
+    a mask, which :func:`topk_mask` gives without a sort.)"""
+    b, hq, s, d = q.shape
+    hkv = k.shape[1]
+    g = hq // hkv
+    c = SPARSE_QUERY_CHUNK
+    c = c if s > c and s % c == 0 else s
+    scale = 1.0 / math.sqrt(d)
+    col = jnp.arange(s, dtype=jnp.int32)
+
+    def one(args):
+        lo, qc, qic, wc = args      # [b, hq, c, d] [b, c, j, di] [b, c, j]
+        row = lo + jnp.arange(c, dtype=jnp.int32)
+        causal = col[None, :] <= row[:, None]               # [c, s]
+        chosen = topk_mask(index_scores(qic, wc, k_idx),
+                           jnp.broadcast_to(causal, (b, c, s)), topk)
+
+        def head(args):
+            qh, kh, vh = args       # [b, g, c, d] [b, s, d] [b, s, d]
+            logits = jnp.einsum("bgqd,bkd->bgqk", qh, kh,
+                                preferred_element_type=jnp.float32) * scale
+            logits = jnp.where(chosen[:, None], logits,
+                               jnp.finfo(jnp.float32).min)
+            probs = jax.nn.softmax(logits, axis=-1).astype(vh.dtype)
+            return jnp.einsum("bgqk,bkd->bgqd", probs, vh,
+                              preferred_element_type=jnp.float32)
+        out = jax.lax.map(head, (
+            qc.reshape(b, hkv, g, c, d).transpose(1, 0, 2, 3, 4),
+            k.transpose(1, 0, 2, 3), v.transpose(1, 0, 2, 3)))
+        return out.transpose(1, 0, 2, 3, 4).reshape(b, hq, c, d).astype(
+            v.dtype)
+
+    n = s // c
+    out = jax.lax.map(one, (
+        jnp.arange(n, dtype=jnp.int32) * c,
+        q.reshape(b, hq, n, c, d).transpose(2, 0, 1, 3, 4),
+        q_idx.reshape(b, n, c, *q_idx.shape[2:]).transpose(1, 0, 2, 3, 4),
+        w.reshape(b, n, c, -1).transpose(1, 0, 2, 3)))
+    return out.transpose(1, 2, 0, 3, 4).reshape(b, hq, s, d)
+
+
+def sparse_decode_attention(q, k_pool, v_pool, tables, pos, scores,
+                            topk: int):
+    """One decode row a request over the keys its indexer picks: ``q``
+    [b, hq, 1, d] (rotated; its own K and V are in the pools already),
+    pools [blocks, hkv, block_size, d], ``tables`` [b, T], ``pos`` [b] the
+    row's position, ``scores`` float32 [b, T x block_size] its index score
+    of every table position (:func:`index_scores_paged`) -> (float32
+    [b, hq, 1, d], what the read counted a row, int32 [b, 2]: the keys
+    that were eligible and the keys the read kept). The ``topk`` largest
+    scores among positions ``<= pos`` are the chosen keys (``lax.top_k``:
+    exact, ties to the lower position; its last value is the cut and the
+    highest position it chose at the cut ends the ties, so the set comes
+    out as a mask with no scatter). ``paged_decode_attn`` walks the row's
+    live blocks under that mask (``keep``): the softmax is over the chosen
+    keys and no others. A context of ``topk`` rows or fewer keeps all of
+    them.
+
+    Why a walk of EVERY live block and not a gather of the chosen rows: on
+    a v5e an XLA gather of 2 x ``hkv`` x ``topk`` single rows of ``d`` a
+    request costs 9 ns a row whatever the bytes, 1.52 ms a call at 8
+    requests of 4k-16k rows where this walk reads 0.60 with five times the
+    bytes, and 1.57 against 0.74 at 4k-24k
+    (``perfbench/study/decode_read_forms_keye.py`` keeps the gather as its
+    oracle). The fewer bytes win only at contexts past what one chip's
+    pools hold; a walk that skips the blocks with no chosen key is ROADMAP
+    R10's."""
+    from .pallas.paged_attention import paged_attention
+    tables = jnp.asarray(tables, jnp.int32)
+    pos = jnp.asarray(pos, jnp.int32)
+    b, hq, _, d = q.shape
+    bs = k_pool.shape[2]
+    n = scores.shape[1]
+    at = jnp.arange(n, dtype=jnp.int32)[None]
+    eligible = at <= pos[:, None]
+    # (-0.0 as +0.0: one score, a tie, whatever the sort's order of them)
+    masked = jnp.where(eligible, jnp.where(scores == 0, 0.0, scores),
+                       -jnp.inf)
+    vals, idx = jax.lax.top_k(masked, min(int(topk), n))    # [b, k]
+    cut = vals[:, -1:]
+    last_tie = jnp.max(jnp.where(vals == cut, idx.astype(jnp.int32), -1),
+                       axis=1, keepdims=True)
+    chosen = jnp.logical_and(eligible, jnp.logical_or(
+        masked > cut, jnp.logical_and(masked == cut, at <= last_tie)))
+    out = paged_attention(q, k_pool, v_pool, tables, pos,
+                          keep=chosen.reshape(b, n // bs, bs),
+                          scale=1.0 / math.sqrt(d))
+    return out.astype(jnp.float32), jnp.stack(
+        [jnp.sum(eligible, axis=1, dtype=jnp.int32),
+         jnp.sum(chosen, axis=1, dtype=jnp.int32)], axis=1)

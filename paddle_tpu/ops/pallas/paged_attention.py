@@ -44,6 +44,13 @@ the product.
 Masking mirrors :func:`~paddle_tpu.ops.attention_ops.decode_attention_mask`:
 key position ``j`` is valid for query row ``i`` iff ``j <= pos[b] + i``.
 Key 0 is valid for every row, so the normalizer is strictly positive.
+With ``keep`` (a selected read: the keys a request's row may read, a
+value a table position) a key also has to be kept; the walk is the same
+and a block's dropped keys are masked like the ones past ``pos``. Until a
+row has met its first kept key its state is the masked logits' (``m`` at
+``_MASKED``, weights ``exp(0)``); the first kept key's ``alpha`` is
+``exp(_MASKED - m) = 0`` and wipes it, so a row needs ONE kept key among
+its live positions, not key 0.
 
 Runs under the Pallas interpreter on CPU backends (same
 ``interpret_mode`` policy as ``flash_attention``), compiled via Mosaic
@@ -87,9 +94,12 @@ def _rows_form(rows: int, bs: int, sub: int) -> bool:
 
 
 def _kernel(tbl_ref, pos_ref, q_ref, k_hbm, v_hbm, *rest, n: int, T: int,
-            q_len: int, sub: int, vector: bool, quant: bool):
+            q_len: int, sub: int, vector: bool, quant: bool,
+            select: bool):
     if quant:
         ksc_ref, vsc_ref, *rest = rest
+    if select:
+        keep_ref, *rest = rest
     o_ref, k_buf, v_buf, sem, m_ref, l_ref, acc_ref = rest
     B, h_kv, rows, dp = q_ref.shape
     bs = k_buf.shape[3]
@@ -160,6 +170,8 @@ def _kernel(tbl_ref, pos_ref, q_ref, k_hbm, v_hbm, *rest, n: int, T: int,
         qpos = pos_b + jax.lax.broadcasted_iota(
             jnp.int32, (rows, bs), 0) % q_len
         seen = kpos <= qpos
+        if select:
+            seen = jnp.logical_and(seen, keep_ref[b, pl.ds(t, 1), :] != 0)
         for h in range(h_kv):
             k, v = k_buf[slot, i, h], v_buf[slot, i, h]       # [bs, dp]
             if quant:
@@ -241,7 +253,7 @@ def _kernel(tbl_ref, pos_ref, q_ref, k_hbm, v_hbm, *rest, n: int, T: int,
 
 
 def paged_attention(q, k_pool, v_pool, tables, pos, *,
-                    k_scale=None, v_scale=None, scale=None,
+                    k_scale=None, v_scale=None, keep=None, scale=None,
                     interpret=None):
     """Fused paged decode/verify attention over the block pool.
 
@@ -257,6 +269,12 @@ def paged_attention(q, k_pool, v_pool, tables, pos, *,
         absolute position ``pos[b] + i``.
       k_scale / v_scale: optional [num_blocks, h_kv] f32 absmax scales
         — both present selects the int8 dequantizing path.
+      keep: optional [batch, T, block_size] (any integer or boolean
+        type): nonzero where the request's rows may read the key at that
+        table position. A key is read iff it is kept AND at or before
+        the row's position; every request keeps at least one such key.
+        The same set for every query row of a request (a decode step
+        has one). Float pools only.
       scale: logit scale, default ``1/sqrt(head_dim)`` (the original,
         pre-padding head_dim).
       interpret: force the Pallas interpreter; default follows
@@ -287,7 +305,18 @@ def paged_attention(q, k_pool, v_pool, tables, pos, *,
 
     tables = jnp.asarray(tables, jnp.int32)
     pos = jnp.asarray(pos, jnp.int32)
-    if k_scale is None:
+    if keep is not None:
+        if k_scale is not None:
+            raise ValueError("keep is for float pools: an int8 pool's "
+                             "read takes no selection")
+        if keep.shape != (b, tables.shape[1], bs):
+            raise ValueError(f"keep {keep.shape} is not [batch, T, "
+                             f"block_size] of tables {tables.shape} over "
+                             f"blocks of {bs} rows")
+        out = _paged_keep_call(q, k_pool, v_pool, tables, pos,
+                               keep.astype(jnp.int32),
+                               float(scale), bool(interpret))
+    elif k_scale is None:
         out = _paged_call(q, k_pool, v_pool, tables, pos,
                           float(scale), bool(interpret))
     else:
@@ -300,16 +329,16 @@ def paged_attention(q, k_pool, v_pool, tables, pos, *,
 
 
 def _paged_local(q, k_pool, v_pool, tables, pos, k_scale, v_scale,
-                 scale, interpret):
+                 scale, interpret, keep=None):
     """The kernel call on what one chip holds: q [b, h_q, s, dp] (dp
     lane-aligned), pools [nb, h_kv, bs, dp], tables [b, T], pos [b],
-    optional scales [nb, h_kv]."""
+    optional scales [nb, h_kv], optional keep int32 [b, T, bs]."""
     _, h_kv, bs, dp = k_pool.shape
     block_bytes = h_kv * bs * dp * k_pool.dtype.itemsize
     n = max(1, min(BLOCKS_A_STEP, tables.shape[1],
                    _BUFFER_BYTES // (4 * block_bytes)))
     return _paged_walk(q, k_pool, v_pool, tables, pos, k_scale, v_scale,
-                       scale=scale, interpret=interpret, n=n)
+                       keep, scale=scale, interpret=interpret, n=n)
 
 
 # One traced program a shape: a step's layers call the kernel on operands
@@ -317,9 +346,9 @@ def _paged_local(q, k_pool, v_pool, tables, pos, k_scale, v_scale,
 # lowering it once a program instead of once a layer is most of what a
 # CPU trace of a step costs.
 @functools.partial(jax.jit, static_argnames=("scale", "interpret", "n"))
-def _paged_walk(q, k_pool, v_pool, tables, pos, k_scale, v_scale, *,
-                scale, interpret, n):
-    quant = k_scale is not None
+def _paged_walk(q, k_pool, v_pool, tables, pos, k_scale, v_scale, keep,
+                *, scale, interpret, n):
+    quant, select = k_scale is not None, keep is not None
     b, hq, s, dp = q.shape
     _, h_kv, bs, _ = k_pool.shape
     T = tables.shape[1]
@@ -327,7 +356,10 @@ def _paged_walk(q, k_pool, v_pool, tables, pos, k_scale, v_scale, *,
     item = k_pool.dtype.itemsize
     # the keys a slice: one native tile of the pool's type on the sublanes
     sub = 32 // item if bs % (32 // item) == 0 else bs
-    vector = _rows_form(rows, bs, sub)
+    # a selected read takes the matrix form whatever its shape: one
+    # form reads ``keep``, and the served one (grouped heads over long
+    # blocks) is this
+    vector = _rows_form(rows, bs, sub) and not select
 
     # the query rows of one KV head together, scaled once
     qf = (q.astype(jnp.float32) * scale).reshape(b, h_kv, rows, dp)
@@ -340,10 +372,13 @@ def _paged_walk(q, k_pool, v_pool, tables, pos, k_scale, v_scale, *,
         operands += [k_scale[tables].reshape(-1),
                      v_scale[tables].reshape(-1)]
         in_specs += [smem, smem]
+    if select:
+        operands.append(keep)
+        in_specs.append(vmem)
     state = (rows, h_kv, sub) if vector else (h_kv, rows)
     out = pl.pallas_call(
         functools.partial(_kernel, n=n, T=T, q_len=s, sub=sub,
-                          vector=vector, quant=quant),
+                          vector=vector, quant=quant, select=select),
         name="paged_decode_attn",
         in_specs=in_specs,
         out_specs=vmem,
@@ -366,12 +401,19 @@ def _paged_float(q, k_pool, v_pool, tables, pos, scale, interpret):
                         scale, interpret)
 
 
+def _paged_keep(q, k_pool, v_pool, tables, pos, keep, scale, interpret):
+    return _paged_local(q, k_pool, v_pool, tables, pos, None, None,
+                        scale, interpret, keep)
+
+
 # Every (batch row, head) pair is independent, so a tensor-parallel
 # engine (heads on the mesh's model axis) runs the kernel on each chip's
 # own heads of q and of the pools; the pool's block axis, the block rows
 # and head_dim stay whole per chip (see utils.shard_parallel).
 _paged_call = shard_parallel(
     _paged_float, ("bh--", "-h--", "-h--", "b-", "b"), ("bh--",))
+_paged_keep_call = shard_parallel(
+    _paged_keep, ("bh--", "-h--", "-h--", "b-", "b", "b--"), ("bh--",))
 _paged_quant_call = shard_parallel(
     _paged_local,
     ("bh--", "-h--", "-h--", "b-", "b", "-h", "-h"), ("bh--",))

@@ -3200,6 +3200,10 @@ class ServingEngine:
         # request's now
         out["state_bytes"] = c.state_bytes
         out["state_rows_live"] = c.state_rows_live
+        # what a token keeps beside K and V (``seam.CacheKind.extra``),
+        # by name: a model with none leaves these out
+        out.update({f"{name}_bytes": n
+                    for name, n in c.pool.extra_bytes.items()})
         if self.spec.counters:
             # the model's counters, kept on the device by its steps and
             # fetched here only (the steps' own fetch is their tokens)

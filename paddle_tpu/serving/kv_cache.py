@@ -32,6 +32,14 @@ hands the steps one pool pair a *model* layer, in the model's order, the
 tables go as one array a kind, and ``allocator`` / ``blocks_free`` /
 ``blocks_used`` answer for all kinds together.
 
+More than K and V a token. The kind that keeps every row may keep further
+arrays a token beside the pair (``seam.CacheKind.extra``: a learned sparse
+attention's indexer keys): one more array a layer, ``[num_blocks, width,
+block_size]`` with no head axis, after (k, v) in the layer's tuple
+(:class:`BlockPool`). It has no table and no allocator of its own: a block
+id means the same rows in all three, so admission, release, the trash
+block, donation, ``set_arrays`` and ``rebuild`` carry it with the pair.
+
 Recurrent state. A kind of layer that keeps a fixed-size record a request
 whatever its context (``seam.StateKind``) has no blocks at all
 (:class:`_StateKind`): ``[max_slots, ...]`` arrays a layer, indexed by the
@@ -209,7 +217,7 @@ class BlockPool:
 
     def __init__(self, num_layers: int, num_heads: int, head_dim: int,
                  block_size: int = 16, num_blocks: int = 2,
-                 dtype=None, kv_dtype: str = "f32"):
+                 dtype=None, kv_dtype: str = "f32", extra=()):
         import jax.numpy as jnp
         if kv_dtype not in ("f32", "bf16", "int8"):
             raise ValueError(
@@ -230,6 +238,14 @@ class BlockPool:
         self.block_size = int(block_size)
         self.num_blocks = int(num_blocks)
         shape = (self.num_blocks, num_heads, self.block_size, head_dim)
+        # what a token keeps beside K and V (``seam.CacheKind.extra``):
+        # ``(name, width)`` -> one more array a layer, ``[blocks, width,
+        # block_size]``, after the pair; every method here that walks a
+        # layer's tuple (COW, adoption, rebuild) takes them along
+        self.extra = tuple((str(n), int(w)) for n, w in extra)
+        if self.extra and kv_dtype == "int8":
+            raise ValueError("an int8 pool keeps (k, v, scales) only: a "
+                             "kind with extra arrays has a float pool")
         if kv_dtype == "int8":
             # 4-tuple layers: int8 code pools + per-block-per-head
             # absmax scales (ops.attention_ops.block_scatter_write_quant
@@ -244,6 +260,8 @@ class BlockPool:
         else:
             self.layers = [
                 (jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
+                + tuple(jnp.zeros((self.num_blocks, w, self.block_size),
+                                  dtype) for _, w in self.extra)
                 for _ in range(num_layers)]
         self.epoch = 0
         self.allocator = BlockAllocator(self.num_blocks)
@@ -254,6 +272,13 @@ class BlockPool:
         self.prefix_hits = 0       # token-weighted: shared tokens reused
         self.prefix_misses = 0     # prompt tokens prefilled from scratch
         self.blocks_allocated_total = 0  # fresh allocs (bench: bytes/request)
+
+    @property
+    def extra_bytes(self) -> Dict[str, int]:
+        """Bytes each extra array takes over all layers, by name, as the
+        device holds them."""
+        return {name: sum(int(layer[2 + i].nbytes) for layer in self.layers)
+                for i, (name, _) in enumerate(self.extra)}
 
     def alloc_block(self) -> Optional[int]:
         """Fresh block, evicting idle prefix-cache entries if needed."""
@@ -474,7 +499,7 @@ class BlockKVCache:
                  dtype=None, kv_dtype: str = "f32",
                  pool: Optional[BlockPool] = None,
                  window_kinds: Sequence = (), layer_order=None,
-                 state_kinds: Sequence = ()):
+                 state_kinds: Sequence = (), extra=()):
         self.max_slots = int(max_slots)
         self.max_len = int(max_len)
         if pool is not None:
@@ -501,6 +526,10 @@ class BlockKVCache:
                 raise ValueError(
                     f"shared pool kv_dtype={pool.kv_dtype!r} != "
                     f"requested {kv_dtype!r}")
+            if tuple(extra) != pool.extra:
+                raise ValueError(
+                    f"shared pool keeps {pool.extra} beside K and V; "
+                    f"cache wants {tuple(extra)}")
             self.pool = pool
         else:
             block_size = int(block_size)
@@ -514,7 +543,7 @@ class BlockKVCache:
             self.pool = BlockPool(num_layers, num_heads, head_dim,
                                   block_size=block_size,
                                   num_blocks=num_blocks, dtype=dtype,
-                                  kv_dtype=kv_dtype)
+                                  kv_dtype=kv_dtype, extra=extra)
         self.blocks_per_row = -(-self.max_len // self.pool.block_size)
         self.tables = np.full((self.max_slots, self.blocks_per_row),
                               self.TRASH, np.int32)
@@ -556,6 +585,9 @@ class BlockKVCache:
         if first.window or any(not k.window for k in rest):
             raise ValueError("the first cache kind keeps every row, the "
                              "others have a window")
+        if any(k.extra for k in rest):
+            raise ValueError("only the kind that keeps every row keeps "
+                             "arrays beside K and V")
         order = {}
         for ki, kind in enumerate(spec.cache_kinds + spec.state_kinds):
             for i, layer in enumerate(kind.layers):
@@ -565,7 +597,7 @@ class BlockKVCache:
                    num_blocks=num_blocks, prefix_cache=prefix_cache,
                    kv_dtype=kv_dtype, pool=pool, window_kinds=rest,
                    layer_order=[order[l] for l in sorted(order)],
-                   state_kinds=spec.state_kinds)
+                   state_kinds=spec.state_kinds, extra=first.extra)
 
     # -- pool delegation ---------------------------------------------
     # the physical state lives in self.pool so sharing caches observe

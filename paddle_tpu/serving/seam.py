@@ -10,7 +10,12 @@ from one :class:`ServedModel`, which the model builds
 - its paged cache, one :class:`CacheKind` a layer kind: which layers, KV
   heads and head size, and ``window`` > 0 for a kind that needs only the
   last ``window`` rows of a request (``BlockKVCache`` then bounds that
-  kind's pool and frees its blocks behind the window);
+  kind's pool and frees its blocks behind the window). A kind's token may
+  keep arrays BESIDE K and V (``extra``: a name and a width each, no head
+  axis; a learned sparse attention's indexer keys are one): each is one
+  more pool a layer, ``[blocks, width, block_size]``, after the (k, v)
+  pair in the layer's tuple, under the SAME block table and allocator, so
+  it is allocated, freed, donated, rebound and zeroed with them;
 - its recurrent state, one :class:`StateKind` a kind of layer that carries
   a fixed-size record from token to token whatever the context: which
   layers, and the shape and dtype of each array a request keeps, one array
@@ -41,8 +46,9 @@ from one :class:`ServedModel`, which the model builds
 
 A model may declare blocks, a recurrent kind, experts' device counters and
 any number of rows at once (``models/lfm2.py`` does: 128 rows a decode
-step); nothing in the engine or the cache manager is sized by a model's
-name or by another model's arrays.
+step), or blocks whose token keeps a third array (``models/keye.py``);
+nothing in the engine or the cache manager is sized by a model's name or
+by another model's arrays.
 
 The defaults are the generic paged forward every model of this repo with a
 ``model(ids, cache=, cache_pos=, block_tables=, lora=)`` call shares, so
@@ -74,12 +80,19 @@ FEATURES = frozenset({
 
 @dataclass(frozen=True)
 class CacheKind:
-    """One kind of layer of a paged cache."""
+    """One kind of layer of a paged cache: per layer a (k, v) pair of
+    ``[blocks, kv_heads, block_size, head_dim]`` pools and, for each
+    ``extra`` entry ``(name, width)``, one more pool ``[blocks, width,
+    block_size]`` of the pools' dtype after them: ``width`` values a token
+    with no head axis, a token a lane (a width under 128 then pads
+    nothing on the chip), in the same blocks as the token's K and V. Only
+    the kind that keeps every row may have them, and only a float pool."""
     name: str
     layers: Tuple[int, ...]     # the model's layer indices, ascending
     kv_heads: int
     head_dim: int
     window: int = 0             # 0: every row of a request is kept
+    extra: Tuple[Tuple[str, int], ...] = ()
 
 
 @dataclass(frozen=True)
@@ -174,8 +187,8 @@ def served(model) -> ServedModel:
             f"{type(model).__name__} is not a served model: the serving "
             f"plane reads a model through model.serving_spec() -> "
             f"paddle_tpu.serving.seam.ServedModel (GPTForCausalLM, "
-            f"MellumForCausalLM, JambaForCausalLM and Lfm2ForCausalLM "
-            f"have one)")
+            f"MellumForCausalLM, JambaForCausalLM, Lfm2ForCausalLM and "
+            f"KeyeForCausalLM have one)")
     return spec()
 
 

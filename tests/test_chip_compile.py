@@ -335,6 +335,26 @@ def test_paged_attention_compiles_for_v5e(one_chip, mosaic, case):
     assert text.count(KERNEL) == 1
 
 
+def test_the_selected_read_compiles_for_v5e(one_chip, mosaic):
+    """keye_longdoc_24k's decode read: 8 rows, 32 query heads on 4 KV
+    heads, 256-row bfloat16 blocks, tables of 68 entries over 545 blocks,
+    and the chosen set as ``keep`` [8, 68, 256] in VMEM beside the walk's
+    double buffers (one row of it a block, a dynamic sublane slice):
+    ``ops.attention_ops.sparse_decode_attention`` as the step calls it,
+    the sort's cut to the mask and the one kernel."""
+    from paddle_tpu.ops.attention_ops import sparse_decode_attention
+
+    def s(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    pool = s((545, 4, 256, 128), jnp.bfloat16)
+    text = jax.jit(lambda *a: sparse_decode_attention(*a, 2048)).lower(
+        s((8, 32, 1, 128), jnp.bfloat16), pool, pool, s((8, 68), jnp.int32),
+        s((8,), jnp.int32), s((8, 68 * 256), jnp.float32)
+    ).compile().as_text()
+    assert text.count(KERNEL) == 1
+    assert "paged_decode_attn" in text
+
+
 def _kernel_shapes(text):
     """Operand and result shapes of the program's Mosaic kernels."""
     return [s for res, ops in chip_smoke.tpu_custom_calls(text)

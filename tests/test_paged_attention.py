@@ -210,6 +210,62 @@ def test_kernel_matches_reference_f32(s, pos):
                                rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.parametrize("pool_dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("s,group", [(1, 1), (1, 4), (3, 2)])
+def test_kernel_reads_only_the_kept_keys(pool_dtype, s, group):
+    """``keep``: a request's rows read the keys that are kept AND at or
+    before the row, and no others: grouped heads or not, whatever form
+    the shape alone would pick, over a row whose first block keeps
+    nothing (its state is wiped by the first kept key) and a row that
+    keeps one key."""
+    rng = np.random.RandomState(11)
+    bs, T, h, d = 4, 5, 2, 32
+    pos = [9, 15, 4]
+    tables, nb = _tables_for(pos, s, bs, T)
+    dtype = jnp.dtype(pool_dtype)
+    k_pool = jnp.asarray(rng.randn(nb, h, bs, d), dtype).at[0].set(100.0)
+    v_pool = jnp.asarray(rng.randn(nb, h, bs, d), dtype).at[0].set(100.0)
+    q = jnp.asarray(rng.randn(len(pos), h * group, s, d), jnp.float32)
+    keep = rng.rand(len(pos), T * bs) < 0.5
+    keep[0, :bs] = False                # nothing of the first block
+    keep[0, bs + 1] = True
+    keep[2] = False                     # one key in all
+    keep[2, 2] = True
+    posv = jnp.asarray(pos, jnp.int32)
+    out = paged_attention(q, k_pool, v_pool, tables, posv,
+                          keep=jnp.asarray(keep).reshape(len(pos), T, bs))
+    kg = np.asarray(k_pool[tables], np.float32).transpose(0, 2, 1, 3, 4) \
+        .reshape(len(pos), h, T * bs, d)
+    vg = np.asarray(v_pool[tables], np.float32).transpose(0, 2, 1, 3, 4) \
+        .reshape(len(pos), h, T * bs, d)
+    for r, p0 in enumerate(pos):
+        for j in range(h * group):
+            for i in range(s):
+                seen = keep[r] & (np.arange(T * bs) <= p0 + i)
+                lg = kg[r, j // group, seen] @ np.asarray(q[r, j, i]) \
+                    / np.sqrt(d)
+                w = np.exp(lg - lg.max())
+                want = (w / w.sum()) @ vg[r, j // group, seen]
+                np.testing.assert_allclose(
+                    np.asarray(out[r, j, i]), want,
+                    **(dict(rtol=2e-5, atol=2e-5) if pool_dtype == "float32"
+                       else dict(rtol=2e-2, atol=2e-2)))
+
+
+def test_keep_is_a_value_a_table_position_of_a_float_pool():
+    pool = jnp.zeros((6, 2, 4, 32))
+    q, tables = jnp.zeros((1, 2, 1, 32)), jnp.ones((1, 5), jnp.int32)
+    pos = jnp.zeros((1,), jnp.int32)
+    with pytest.raises(ValueError, match="block_size"):
+        paged_attention(q, pool, pool, tables, pos,
+                        keep=jnp.ones((1, 20), bool))
+    with pytest.raises(ValueError, match="float pools"):
+        paged_attention(q, pool.astype(jnp.int8), pool.astype(jnp.int8),
+                        tables, pos, k_scale=jnp.ones((6, 2)),
+                        v_scale=jnp.ones((6, 2)),
+                        keep=jnp.ones((1, 5, 4), bool))
+
+
 def _written_int8_pools(rng, tables, bs, T, h, d, widths):
     """Build int8 + mirror f32 pools through the real write path: the
     incremental decode/verify write sequence ``widths`` (mixed decode

@@ -20,16 +20,18 @@ from perfbench import families, flops, readers, run
 
 CONFIGS = {"cgpt-1p3b": "gpt2", "cgpt-1p3b-d20": "gpt2",
            "laguna-xs2-share8": "laguna", "mellum2-12b-d8": "mellum",
-           "jamba2-3b": "jamba", "lfm2-24b-a2b-d9": "lfm2"}
+           "jamba2-3b": "jamba", "lfm2-24b-a2b-d9": "lfm2",
+           "keye-vl2-30b-a3b-stage0": "keye"}
 JOBS = {"gpt2": "pretrain_1chip", "laguna": "laguna_pretrain_8k",
         "mellum": "mellum_code_16k", "jamba": "jamba_reasoning_6k",
-        "lfm2": "lfm2_agents_3k"}
+        "lfm2": "lfm2_agents_3k", "keye": "keye_longdoc_24k"}
 FAMILY_CONFIG = {"gpt2": "cgpt-1p3b-d20", "laguna": "laguna-xs2-share8",
                  "mellum": "mellum2-12b-d8", "jamba": "jamba2-3b",
-                 "lfm2": "lfm2-24b-a2b-d9"}
+                 "lfm2": "lfm2-24b-a2b-d9",
+                 "keye": "keye-vl2-30b-a3b-stage0"}
 RATE = {"gpt2": "train_tok_s_chip", "laguna": "train_tok_s_chip",
         "mellum": "serve_tok_s", "jamba": "serve_tok_s",
-        "lfm2": "serve_tok_s"}
+        "lfm2": "serve_tok_s", "keye": "serve_tok_s"}
 
 
 def config(name):
@@ -40,7 +42,8 @@ def test_an_unknown_family_is_an_error_that_lists_the_known_ones():
     with pytest.raises(SystemExit) as e:
         families.load({"name": "some-model", "family": "no_such_family"})
     assert "no_such_family" in str(e.value)
-    assert families.known() == ["gpt2", "jamba", "laguna", "lfm2", "mellum"]
+    assert families.known() == ["gpt2", "jamba", "keye", "laguna", "lfm2",
+                                "mellum"]
     assert all(name in str(e.value) for name in families.known())
 
 
@@ -313,6 +316,105 @@ def test_any_128_requests_of_lfm2_agents_3k_fit_the_pool():
     assert e["prefix_cache"] is False
 
 
+def test_the_keye_file_holds_the_published_widths_uncut():
+    """Every key of the catalog's ``config``, ``sa_config`` and
+    ``rope_scaling`` among them, but the two in ``reduced`` equals the
+    file's."""
+    cfg = config("keye-vl2-30b-a3b-stage0")
+    catalog = "/opt/skills/guides/model-configs/architectures.jsonl"
+    if os.path.exists(catalog):     # the catalog's row, where there is one
+        with open(catalog) as f:
+            row = next(r for r in map(json.loads, f)
+                       if r["name"] == "Keye-VL-2.0-30B-A3B")
+        changed = {k for k, v in row["config"].items() if cfg.get(k, 0) != v}
+        assert changed == {"num_hidden_layers", "max_position_embeddings"}
+        assert "sa_config" in row["config"]
+        assert cfg["source"].startswith(row["source_url"])
+        entry = next(c for c in BENCH["configs"]
+                     if c["name"] == cfg["name"])
+        assert entry["source"] == row["source_url"]
+    assert cfg["reduced"] == list(cfg["reduced_why"]) == \
+        ["num_hidden_layers", "max_position_embeddings"]
+    assert cfg["published"]["num_hidden_layers"] == 48
+    assert (cfg["hidden_size"], cfg["num_attention_heads"],
+            cfg["num_key_value_heads"], cfg["head_dim"], cfg["num_experts"],
+            cfg["moe_intermediate_size"], cfg["num_experts_per_tok"],
+            cfg["vocab_size"], cfg["num_hidden_layers"]) == \
+        (2048, 32, 4, 128, 128, 768, 8, 151936, 6)
+    assert cfg["sa_config"] == {
+        "indexer_head_dim": 64, "indexer_num_heads": 16,
+        "indexer_num_kv_heads": 1, "kv_chunk_size": 512,
+        "q_chunk_size": 512, "topk": 2048}
+    assert cfg["num_hidden_layers"] >= 4            # the guide's floor
+    assert all(cfg.get(k) for k in ("assumed", "published", "deployment",
+                                    "engine_why"))
+    assert {"qk_norm", "rotary", "indexer", "sa_chunks", "intermediate_size",
+            "router", "dtype", "embed_init_std", "indexer_init_std"} \
+        <= set(cfg["assumed"])
+    mc = families.load(cfg).model_config(cfg)
+    # 18.87M attention (+ the norms), the indexer's three projections and
+    # its LayerNorm (2.26M, with the q/k norms' gains), 0.26M router,
+    # 604.0M experts; 622.3M in embedding and head (and the final norm)
+    layer = 2048 * (32 + 8) * 128 + 32 * 128 * 2048 + 2 * 2048 \
+        + 2048 * (16 * 64 + 64 + 16) + 2 * 64 + 2 * 128 \
+        + 2048 * 128 + 128 * 3 * 2048 * 768
+    assert layer == 625_381_760
+    assert mc.num_params() == cfg["params_held"] \
+        == 6 * layer + 2 * 151936 * 2048 + 2048 == 4_374_622_464
+    assert (mc.attention_gate, mc.router_score, mc.qk_norm, mc.dtype,
+            mc.kv_pack, mc.indexer) == \
+        (False, "softmax", True, "bfloat16", 1, (16, 64, 2048))
+    assert mc.shared_expert_intermediate_size == 0
+    assert [(n, len(l), w) for n, l, w in mc.cache_kinds()] == \
+        [("full_attention", 6, 0)]
+    # the deployment's bytes: K and V, and the third array, a token a lane
+    e = cfg["engine"]
+    assert e["num_blocks"] * 4 * e["block_size"] * 128 * 2 * 2 * 6 \
+        == 1_714_421_760
+    assert e["num_blocks"] * 64 * e["block_size"] * 2 * 6 == 107_151_360
+
+
+def test_the_keye_family_refuses_a_training_job_by_name():
+    cfg = config("keye-vl2-30b-a3b-stage0")
+    with pytest.raises(SystemExit) as e:
+        families.load(cfg).train_job(cfg, {"kind": "train"})
+    assert "the keye family has no training job" in str(e.value)
+
+
+def test_any_8_requests_of_keye_longdoc_24k_fit_the_pool():
+    """The file is held to what its ``lengths_why`` says: the largest pair
+    eight times over fits the pool (one table and allocator for K, V and
+    the indexer's keys), every prompt reaches a bucket, every bucket is
+    whole passes of the expert layer and whole chunks of the sparse
+    attention, and every request is past ``topk`` from its first token
+    on."""
+    from paddle_tpu.models.keye import PROMPT_CHUNK_ROWS
+    from paddle_tpu.ops.attention_ops import SPARSE_QUERY_CHUNK
+    from perfbench import traffic as T
+    cfg, tr = config("keye-vl2-30b-a3b-stage0"), load(
+        "traffic", "keye_longdoc_24k.json")
+    e = cfg["engine"]
+    pairs = T.multiset(tr)
+    assert len(pairs) == 16 and e["max_slots"] == 8
+    assert tr["queue_depth_slots"] == 1 and tr["preroll_completions"] == 8
+    assert (min(p for p, _ in pairs), max(p for p, _ in pairs),
+            min(a for _, a in pairs), max(a for _, a in pairs)) == \
+        (4277, 15689, 267, 981)
+    assert all(p + a <= tr["multiset"]["max_total"] == e["max_len"] == 17408
+               for p, a in pairs)
+    assert min(p for p, _ in pairs) > cfg["sa_config"]["topk"]
+    need = max(-(-(p + a) // e["block_size"]) for p, a in pairs)
+    assert need == 63 and 8 * need <= e["num_blocks"] - 1 == 8 * 68
+    assert e["max_len"] == 68 * e["block_size"] \
+        == cfg["max_position_embeddings"]
+    assert T.buckets_used(tr, e["buckets"]) == e["buckets"] \
+        == [8192, 12288, 16384]
+    assert len(e["buckets"]) <= 5 and all(
+        b % PROMPT_CHUNK_ROWS == 0 and b % SPARSE_QUERY_CHUNK == 0
+        for b in e["buckets"])
+    assert e["prefix_cache"] is False
+
+
 # per layer 8*2048^2 + 4*2048*8192 + 2*1024*2048 = 104,857,600; head
 # 2*2048*50304 = 206,045,184; x3 for the backward.
 # Laguna share, forward a token at s 8192: a window layer's projections
@@ -497,6 +599,67 @@ def test_lfm2_counts_its_data_dependent_kernels_from_the_runs_counters():
                                   / peak["hbm_bytes_per_s"])
 
 
+# Keye's served kernels are the expert layer's four: a prompt's pass of
+# 4096 rows x 8 choices over the whole stack of 128 experts [2048 -> 1536]
+# / [768 -> 2048]; a decode step's 8 rows x 8 choices over the experts 8
+# uniform rows touch, 128 x (1 - (15/16)^8) = 51.6; and a decode row's
+# selected read, the paged walk under the chosen set's mask: without a
+# run's counters one block a slot at one byte a value (32 query heads on
+# 4 KV heads of 128, 256-row blocks, 8 slots). The selection and a
+# prompt's read are XLA ops and have no count.
+TOUCHED_KEYE = 128 * (1 - (15 / 16) ** 8)
+KERNELS.update({
+    ("keye", "moe_up"): (2.0 * 32768 * 2048 * 1536,
+                         2.0 * (32768 * (2048 + 1536) + 128 * 2048 * 1536)),
+    ("keye", "moe_down"): (2.0 * 32768 * 768 * 2048,
+                           2.0 * (32768 * (768 + 2048) + 128 * 768 * 2048)),
+    ("keye", "moe_up_dec"): (2.0 * 64 * 2048 * 1536,
+                             2.0 * (64 * (2048 + 1536)
+                                    + TOUCHED_KEYE * 2048 * 1536)),
+    ("keye", "moe_down_dec"): (2.0 * 64 * 768 * 2048,
+                               2.0 * (64 * (768 + 2048)
+                                      + TOUCHED_KEYE * 768 * 2048)),
+    ("keye", "paged_decode_attn"): (4.0 * 32 * 256 * 128 * 8,
+                                    2.0 * 4 * 256 * 128 * 8),
+})
+
+
+def test_keye_counts_its_decode_products_from_the_runs_counters():
+    """The decode products' expert stack is the engine's own count over
+    the traced interval, over the 6 layers."""
+    cfg, job = config("keye-vl2-30b-a3b-stage0"), load(
+        "traffic", "keye_longdoc_24k.json")
+    counts = families.load(cfg).kernel_counts
+    counters = {"engine.experts_touched.traced": 6 * 40.0 * 117,
+                "engine.sampler_dispatches.traced": 117.0}
+    assert counts("moe_up_dec", cfg, job, counters=counters) == pytest.approx(
+        (2.0 * 64 * 2048 * 1536,
+         2.0 * (64 * (2048 + 1536) + 40.0 * 2048 * 1536)), rel=1e-12)
+    assert TOUCHED_KEYE == pytest.approx(51.620, abs=1e-3)
+    assert KERNELS["keye", "moe_up"] == (206_158_430_208.0, 1_040_187_392.0)
+
+
+def test_keye_counts_its_selected_read_from_the_runs_live_blocks():
+    """The decode rows' read walks every live block under the chosen
+    set's mask: its count is the live blocks a flight over the traced
+    interval times one block's K and V at the pool's item size (the
+    mask's bytes left out: a floor); a run that read no flight there
+    gives none, and at the memory's speed the share is 100."""
+    cfg, job = config("keye-vl2-30b-a3b-stage0"), load(
+        "traffic", "keye_longdoc_24k.json")
+    counts = families.load(cfg).kernel_counts
+    counters = {"engine.kv_blocks_live.traced": 117 * 290.0,
+                "engine.decode_flights.traced": 117.0, "kv_item_bytes": 2}
+    got = counts("paged_decode_attn", cfg, job, counters=counters)
+    assert got == (4.0 * 32 * 256 * 128 * 290, 2.0 * 4 * 256 * 128 * 2 * 290)
+    assert counts("paged_decode_attn", cfg, job, counters={}) is None
+    floor = flops.kernel_floors(
+        {"kernel_calls.paged_decode_attn": 6.0 * 117}, lambda n: got,
+        "TPU v5 lite")["kernel_floor_s.paged_decode_attn"]
+    assert floor == pytest.approx(
+        6 * 117 * got[1] / flops.peaks("TPU v5 lite")["hbm_bytes_per_s"])
+
+
 @pytest.mark.parametrize("family,kernel", sorted(KERNELS))
 def test_kernel_counts_are_the_hand_count(family, kernel):
     name = FAMILY_CONFIG[family]
@@ -516,19 +679,23 @@ def test_kernel_counts_are_the_hand_count(family, kernel):
                   got[1] / peak["hbm_bytes_per_s"]))
 
 
-@pytest.mark.parametrize("family", ["gpt2", "laguna", "mellum", "jamba"])
+@pytest.mark.parametrize("family", ["gpt2", "laguna", "mellum", "jamba",
+                                    "keye"])
 def test_a_kernel_the_family_has_no_count_for_is_none(family):
     name = FAMILY_CONFIG[family]
     cfg, job = config(name), load("traffic", JOBS[family] + ".json")
     counts = families.load(cfg).kernel_counts
-    assert counts("paged_decode_attn", cfg, job) is None
+    # (keye's decode rows read through the paged kernel, and it counts it)
+    assert (counts("paged_decode_attn", cfg, job) is None) \
+        == (family != "keye")
     other = {"gpt2": "flash_fwd_win", "laguna": "flash_fwd",
-             "mellum": "moe_up_dx", "jamba": "flash_fwd_win"}[family]
+             "mellum": "moe_up_dx", "jamba": "flash_fwd_win",
+             "keye": "flash_fwd_full"}[family]
     assert counts(other, cfg, job) is None        # another family's name
     # and none for a job of the other kind (serving for the families that
     # train, training for the one that serves)
     assert counts(sorted(k for f, k in KERNELS if f == family)[0], cfg,
-                  {"kind": "train" if family in ("mellum", "jamba")
+                  {"kind": "train" if family in ("mellum", "jamba", "keye")
                    else "open_loop"}) is None
 
 
@@ -583,7 +750,10 @@ OVER = {"engine.experts_touched": "counters.engine.sampler_dispatches",
         "engine.window_blocks_freed": "counters.engine.completed",
         "engine.prefill_tokens_live":
             "counters.engine.prefill_tokens_computed",
-        "engine.state_bytes.close": None}
+        "engine.state_bytes.close": None,
+        "engine.sparse_keys_read": "counters.engine.sparse_keys_live",
+        "engine.sparse_keys_live": "counters.engine.sampler_dispatches",
+        "engine.index_cache_bytes.close": None}
 
 
 @pytest.mark.parametrize("reader", sorted(OVER))
@@ -654,7 +824,11 @@ TWINS = {
     "lfm2_agents_3k": (6, 2**31 + 42, (
         "serving.decode_step", "engine.experts_touched",
         "engine.expert_rows_max", "engine.state_bytes.close",
-        "engine.prefill_tokens_live"))}
+        "engine.prefill_tokens_live")),
+    "keye_longdoc_24k": (9, 2**31 + 46, (
+        "serving.decode_step", "engine.sparse_keys_read",
+        "engine.sparse_keys_live", "engine.index_cache_bytes.close",
+        "engine.inputs_resident", "engine.prefill_tokens_live"))}
 
 
 def last_line(out):
