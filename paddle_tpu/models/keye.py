@@ -36,6 +36,7 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 from typing import Optional, Tuple
 
+from ..ops.attention_ops import sparse_prompt_pairs
 from .laguna import LagunaConfig, LagunaForCausalLM
 
 #: the one rotary of every layer (M-RoPE at text positions)
@@ -112,6 +113,14 @@ class KeyeForCausalLM(LagunaForCausalLM):
                              "serving path holds every head, expert and "
                              "vocabulary row")
         (_, layers, _), = cfg.cache_kinds()
+
+        def prompt_counts(bucket, rows, live):
+            # every layer's selected read of one dispatch: the (query,
+            # key) pairs its loops multiply (their own bounds) and the
+            # pairs of the whole ``rows x bucket`` rectangle
+            read, rect = sparse_prompt_pairs(rows, bucket, live)
+            return {"sparse_prompt_keys_read": len(layers) * read,
+                    "sparse_prompt_keys_rect": len(layers) * rect}
         return ServedModel(
             model=self, family="keye",
             max_positions=cfg.max_position_embeddings,
@@ -123,7 +132,8 @@ class KeyeForCausalLM(LagunaForCausalLM):
             features=frozenset(), counters=cfg.decode_counters,
             # one prompt a dispatch: a 16384-row prompt's program holds
             # 2.7 GB of temporaries beside 10.6 GB of weights and pools
-            tokens_a_dispatch=1, head_on_last_row=True)
+            tokens_a_dispatch=1, head_on_last_row=True,
+            prompt_counts=prompt_counts)
 
 
 KEYE_CONFIGS = {

@@ -484,7 +484,7 @@ class LagunaAttention(Layer):
             # trained (``LagunaForCausalLM.forward`` refuses labels)
             q, k, v = self._heads(self.qkv_proj(h), cos, sin)
             rows = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
-            o = sparse_prompt_attention(
+            o, _ = sparse_prompt_attention(
                 q.value, k.value, v.value, *self._index(h, rows),
                 cfg.indexer[2])
             o = Tensor(o, stop_gradient=True).transpose([0, 2, 1, 3])
@@ -563,8 +563,10 @@ class LagunaAttention(Layer):
             ip = index_pool_write(cache[2].value, ki, pos, tables)
             pools = (kp, vp, ip)
             if s > 1:
-                read = sparse_prompt_attention(
-                    q.value, k.value, v.value, qi, wi, ki, cfg.indexer[2])
+                # the call's own rows, as data: the read stops at them
+                read, _ = sparse_prompt_attention(
+                    q.value, k.value, v.value, qi, wi, ki, cfg.indexer[2],
+                    live=jnp.max(ctx_len - pos))
             else:
                 read, reads = sparse_decode_attention(
                     q.value, kp, vp, tables, pos,

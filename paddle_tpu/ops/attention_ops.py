@@ -19,6 +19,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .registry import register
 from .. import flags
@@ -497,10 +498,18 @@ def _fused_attention_qkv(ctx, ins, attrs):
 # with a scatter behind them).
 
 #: queries of one chunk of a prompt's selected read: a prompt's bucket is a
-#: multiple of it, and a chunk's scores ``[chunk, heads, keys]`` float32 and
-#: one KV head's logits ``[group, chunk, keys]`` are what the read holds
-#: (read when the read is traced)
+#: multiple of it. A chunk holds its scores and its chosen set over the keys
+#: up to its frontier (``[chunk, heads, keys]`` float32 a span, ``[chunk,
+#: keys]`` a set) and, a key tile at a time, the logits of all its heads
+#: ``[hq, chunk, tile]`` float32 (read when the read is traced)
 SPARSE_QUERY_CHUNK = 256
+#: keys of one tile of that read: a chunk multiplies the tiles up to its
+#: frontier and no others, under a running softmax (read when traced)
+SPARSE_KEY_TILE = 512
+#: static slices of the keys a chunk's selection is taken over (the first
+#: 1..n of n equal spans, whichever first holds the chunk's frontier: the
+#: bisection's 32 dependent counts want a row of static length)
+SPARSE_SELECT_SPANS = 4
 
 
 def index_pool_write(pool, new, pos, tables, overflow_block=0):
@@ -613,16 +622,68 @@ def topk_mask(scores, valid, k: int):
         valid, jnp.logical_or(above, jnp.logical_and(tie, first)))
 
 
-def sparse_prompt_attention(q, k, v, q_idx, w, k_idx, topk: int):
+def sparse_prompt_tiling(s: int):
+    """-> (queries a chunk, keys a tile, the selection's static spans) of a
+    prompt's selected read over ``s`` rows: the module's sizes where ``s``
+    is a multiple of them, else one chunk / one tile / one span."""
+    c, kt, spans = SPARSE_QUERY_CHUNK, SPARSE_KEY_TILE, SPARSE_SELECT_SPANS
+    c = c if s > c and s % c == 0 else s
+    kt = kt if s > kt and s % kt == 0 else s
+    return c, kt, spans if s % (spans * kt) == 0 else 1
+
+
+def sparse_prompt_tiles(s: int, live=None):
+    """THE bounds of :func:`sparse_prompt_attention`'s loops, for the
+    program and for whoever counts what it reads: int32 [chunks], the key
+    tiles chunk ``i`` of queries multiplies. Chunk ``[lo, lo + c)`` reads
+    the keys ``[0, f)``, ``f`` = ``min(lo + c, live)`` rounded up to the
+    tile (no row of it can choose a key past its own last live row), and a
+    chunk with no row under ``live`` reads none and is not run (the chunks
+    that run are a prefix). ``live``: the call's own rows, an int or a
+    traced scalar (None: all ``s``, the causal bound alone); numpy out for
+    an int, a traced array for a traced length."""
+    c, kt, _ = sparse_prompt_tiling(s)
+    xp = jnp if isinstance(live, jax.Array) else np
+    end = xp.arange(1, s // c + 1, dtype=xp.int32) * c
+    live = s if live is None else live
+    return xp.where(end - c < live,
+                    (xp.minimum(end, live) + kt - 1) // kt, 0).astype(xp.int32)
+
+
+def sparse_prompt_pairs(b: int, s: int, live=None):
+    """-> (the (query, key) pairs :func:`sparse_prompt_attention` multiplies
+    for ``b`` prompts of ``s`` rows whose longest has ``live``, the pairs of
+    the whole ``[s, s]`` rectangle), host integers from the loop's own
+    bounds (:func:`sparse_prompt_tiles`)."""
+    c, kt, _ = sparse_prompt_tiling(s)
+    return b * c * kt * int(sparse_prompt_tiles(s, live).sum()), b * s * s
+
+
+def sparse_prompt_attention(q, k, v, q_idx, w, k_idx, topk: int, live=None):
     """A prompt's rows over themselves where every query reads only the
     keys its indexer picks: ``q`` [b, hq, s, d], ``k`` / ``v`` [b, hkv, s,
     d] (grouped: query head ``j`` reads KV head ``j // (hq / hkv)``),
     ``q_idx`` [b, s, heads, di], ``w`` [b, s, heads], ``k_idx`` [b, s, di]
-    -> [b, hq, s, d] in ``v``'s dtype. Query ``t`` scores keys ``s <= t``
-    (:func:`index_scores`), keeps its own ``topk`` largest
-    (:func:`topk_mask`: a set for EVERY row, shared by its heads) and takes
-    the softmax over those. :data:`SPARSE_QUERY_CHUNK` queries at a time,
-    one KV head at a time, over all the keys under the chosen set's mask:
+    -> ([b, hq, s, d] in ``v``'s dtype, the key tiles the loop multiplied,
+    int32). Query ``t`` scores keys ``s <= t`` (:func:`index_scores`),
+    keeps its own ``topk`` largest (:func:`topk_mask`: a set for EVERY row,
+    shared by its heads) and takes the softmax over those.
+
+    The read follows the prompt's live causal triangle
+    (:func:`sparse_prompt_tiles`): :data:`SPARSE_QUERY_CHUNK` queries at a
+    time, and a chunk scores, selects over, multiplies and normalises over
+    the keys up to its own last live row and no others. ``live`` (the
+    call's own rows, the longest of a batch; a traced scalar, so a bucket
+    has one program whatever its prompts' lengths) ends the chunks: one
+    with no live row is not run and rows past ``live`` come out as zeros,
+    which nothing reads (no live row sees them, and the head is taken on
+    the last live row). None: every row is live and the causal bound alone
+    holds. A chunk's selection is taken over the first of
+    :data:`SPARSE_SELECT_SPANS` static slices of the keys that holds its
+    frontier; its products go a tile of :data:`SPARSE_KEY_TILE` keys at a
+    time, all heads at once, under the chosen set's mask and a running
+    softmax (the row's maximum, sum and accumulator carried from tile to
+    tile in float32; a tile with none of a row's keys adds nothing to it):
     neither the ``[s, s]`` scores nor a head's logits exist whole. (The
     chosen keys are not gathered: a gather a query would move ``topk`` rows
     of K and V for every row of the prompt, so the selection is wanted as
@@ -630,40 +691,66 @@ def sparse_prompt_attention(q, k, v, q_idx, w, k_idx, topk: int):
     b, hq, s, d = q.shape
     hkv = k.shape[1]
     g = hq // hkv
-    c = SPARSE_QUERY_CHUNK
-    c = c if s > c and s % c == 0 else s
+    c, kt, spans = sparse_prompt_tiling(s)
+    n, span = s // c, s // spans
     scale = 1.0 / math.sqrt(d)
+    tiles = jnp.asarray(sparse_prompt_tiles(s, live))
+    live = s if live is None else live
     col = jnp.arange(s, dtype=jnp.int32)
+    low = jnp.finfo(jnp.float32).min
 
-    def one(args):
-        lo, qc, qic, wc = args      # [b, hq, c, d] [b, c, j, di] [b, c, j]
-        row = lo + jnp.arange(c, dtype=jnp.int32)
-        causal = col[None, :] <= row[:, None]               # [c, s]
-        chosen = topk_mask(index_scores(qic, wc, k_idx),
-                           jnp.broadcast_to(causal, (b, c, s)), topk)
+    def select(upto):
+        def over(row, qic, wc):
+            valid = jnp.broadcast_to(col[None, :upto] <= row[:, None],
+                                     (b, c, upto))
+            chosen = topk_mask(index_scores(qic, wc, k_idx[:, :upto]), valid,
+                               topk)
+            return jnp.pad(chosen, ((0, 0), (0, 0), (0, s - upto)))
+        return over
+    selects = [select((i + 1) * span) for i in range(spans)]
+    chunks = (q.reshape(b, hkv, g, n, c, d).transpose(3, 0, 1, 2, 4, 5),
+              q_idx.reshape(b, n, c, *q_idx.shape[2:]).transpose(
+                  1, 0, 2, 3, 4),
+              w.reshape(b, n, c, -1).transpose(1, 0, 2, 3))
 
-        def head(args):
-            qh, kh, vh = args       # [b, g, c, d] [b, s, d] [b, s, d]
-            logits = jnp.einsum("bgqd,bkd->bgqk", qh, kh,
+    def chunk(i, carry):
+        out, ran = carry
+        qc, qic, wc = (x[i] for x in chunks)    # [b, hkv, g, c, d] ..
+        row = i * c + jnp.arange(c, dtype=jnp.int32)
+        chosen = jax.lax.switch((tiles[i] * kt - 1) // span, selects,
+                                row, qic, wc)               # [b, c, s]
+
+        def tile(j, state):
+            top, total, acc, ran = state
+            kj, vj, keep = (jax.lax.dynamic_slice_in_dim(x, j * kt, kt, 2)
+                            for x in (k, v, chosen))
+            keep = keep[:, None, None]                      # [b, 1, 1, c, kt]
+            logits = jnp.einsum("bhgqd,bhkd->bhgqk", qc, kj,
                                 preferred_element_type=jnp.float32) * scale
-            logits = jnp.where(chosen[:, None], logits,
-                               jnp.finfo(jnp.float32).min)
-            probs = jax.nn.softmax(logits, axis=-1).astype(vh.dtype)
-            return jnp.einsum("bgqk,bkd->bgqd", probs, vh,
-                              preferred_element_type=jnp.float32)
-        out = jax.lax.map(head, (
-            qc.reshape(b, hkv, g, c, d).transpose(1, 0, 2, 3, 4),
-            k.transpose(1, 0, 2, 3), v.transpose(1, 0, 2, 3)))
-        return out.transpose(1, 0, 2, 3, 4).reshape(b, hq, c, d).astype(
-            v.dtype)
+            logits = jnp.where(keep, logits, low)
+            new = jnp.maximum(top, jnp.max(logits, axis=-1))
+            # (a row with no chosen key in this tile, or none yet: its
+            # masked logits equal its maximum, and are no weights)
+            probs = jnp.where(keep, jnp.exp(logits - new[..., None]), 0.0)
+            shrink = jnp.exp(top - new)
+            return (new, total * shrink + jnp.sum(probs, axis=-1),
+                    acc * shrink[..., None] + jnp.einsum(
+                        "bhgqk,bhkd->bhgqd", probs.astype(vj.dtype), vj,
+                        preferred_element_type=jnp.float32), ran + 1)
+        _, total, acc, ran = jax.lax.fori_loop(0, tiles[i], tile, (
+            jnp.full((b, hkv, g, c), low, jnp.float32),
+            jnp.zeros((b, hkv, g, c), jnp.float32),
+            jnp.zeros((b, hkv, g, c, d), jnp.float32), ran))
+        # (a row past ``live`` in the last live chunk may have chosen no
+        # key under the frontier: no 0 / 0 on its way to zero)
+        read = acc / jnp.where(total > 0, total, 1.0)[..., None]
+        read = jnp.where((row < live)[:, None], read, 0.0)
+        return out.at[i].set(read.reshape(b, hq, c, d).astype(v.dtype)), ran
 
-    n = s // c
-    out = jax.lax.map(one, (
-        jnp.arange(n, dtype=jnp.int32) * c,
-        q.reshape(b, hq, n, c, d).transpose(2, 0, 1, 3, 4),
-        q_idx.reshape(b, n, c, *q_idx.shape[2:]).transpose(1, 0, 2, 3, 4),
-        w.reshape(b, n, c, -1).transpose(1, 0, 2, 3)))
-    return out.transpose(1, 2, 0, 3, 4).reshape(b, hq, s, d)
+    out, ran = jax.lax.fori_loop(
+        0, jnp.sum(tiles > 0, dtype=jnp.int32), chunk,
+        (jnp.zeros((n, b, hq, c, d), v.dtype), jnp.zeros((), jnp.int32)))
+    return out.transpose(1, 2, 0, 3, 4).reshape(b, hq, s, d), ran
 
 
 def sparse_decode_attention(q, k_pool, v_pool, tables, pos, scores,

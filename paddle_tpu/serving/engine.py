@@ -112,7 +112,7 @@ from __future__ import annotations
 import itertools
 import threading
 import time
-from collections import deque
+from collections import Counter, deque
 from typing import Dict, List, NamedTuple, Optional, Sequence
 
 import numpy as np
@@ -818,6 +818,10 @@ class ServingEngine:
         # padded position, which attention's mask does not
         self._prefill_tokens_live = 0     # guarded-by: _step_lock
         self._prefill_tokens_computed = 0  # guarded-by: _step_lock
+        # what the model says a prefill dispatch read (seam:
+        # ``prompt_counts``), by name; added to in place under the step
+        # lock, and empty for a model that declares none
+        self._prompt_counted = Counter()
         self._qerr_max = 0.0              # guarded-by: _step_lock
         self._qerr_gauge = None
         if self.kv_dtype == "int8":
@@ -1529,6 +1533,10 @@ class ServingEngine:
         _monitor.stat_add("STAT_serving_prefill_tokens_live", tokens)
         _monitor.stat_add("STAT_serving_prefill_tokens_computed",
                           n * bucket)
+        if self.spec.prompt_counts is not None:
+            self._prompt_counted.update(self.spec.prompt_counts(
+                bucket, n, max(len(req.context) - shared
+                               for req, _, shared in live)))
         ids = np.zeros((n, bucket), np.int32)
         last = np.zeros(n, np.int32)
         pos = np.zeros(n, np.int32)
@@ -3091,6 +3099,7 @@ class ServingEngine:
             prefill_rows_computed = self._prefill_rows_computed
             prefill_tokens_live = self._prefill_tokens_live
             prefill_tokens_computed = self._prefill_tokens_computed
+            prompt_counted = dict(self._prompt_counted)
             account = dict(self._account)
         with self._lock:
             completed = self._completed
@@ -3162,6 +3171,9 @@ class ServingEngine:
         # those of them that were a prompt's tokens
         out["prefill_tokens_computed"] = prefill_tokens_computed
         out["prefill_tokens_live"] = prefill_tokens_live
+        # what the model counts of those dispatches (the seam's
+        # ``prompt_counts``: none for most models)
+        out.update(prompt_counted)
         # the step account (_ACCOUNT_KEYS; the docstring says what each is)
         out.update(account)
         out["kv_dtype"] = self.kv_dtype

@@ -42,7 +42,10 @@ from one :class:`ServedModel`, which the model builds
   the entries of one float32 vector that the step takes as its last
   argument and returns as its last result; the engine carries it from
   step to step beside the step's tokens and keys, so the step's fetch
-  stays what it is and only ``engine.stats()`` reads them).
+  stays what it is and only ``engine.stats()`` reads them);
+- what a prefill dispatch of it reads, where the model counts that
+  (``prompt_counts``: host arithmetic from the model's own loop bounds,
+  added up by the engine under the names the model gives).
 
 A model may declare blocks, a recurrent kind, experts' device counters and
 any number of rows at once (``models/lfm2.py`` does: 128 rows a decode
@@ -58,7 +61,7 @@ The defaults are the generic paged forward every model of this repo with a
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Optional, Tuple
+from typing import Callable, Dict, Optional, Tuple
 
 import jax.numpy as jnp
 
@@ -127,6 +130,12 @@ class ServedModel:
     #: the model's call takes ``last=`` and multiplies the head on each
     #: prompt's last row only (else the seam gathers that row's logits)
     head_on_last_row: bool = False
+    #: None, or ``(bucket, rows, live) -> {name: count}``: what one
+    #: prefill dispatch of ``rows`` x ``bucket`` positions whose longest
+    #: prompt has ``live`` rows adds to ``engine.stats()`` under those
+    #: names, reckoned on the host from the bounds of the model's own
+    #: loops (a prompt's program returns logits and pools, no counters)
+    prompt_counts: Optional[Callable[[int, int, int], Dict[str, int]]] = None
 
     @property
     def num_layers(self) -> int:

@@ -355,6 +355,31 @@ def test_the_selected_read_compiles_for_v5e(one_chip, mosaic):
     assert "paged_decode_attn" in text
 
 
+@pytest.mark.parametrize("bucket", [8192, 12288, 16384])
+def test_the_prompts_selected_read_compiles_for_v5e(one_chip, bucket):
+    """keye_longdoc_24k's prompt read at its three buckets and the
+    published widths, the call's own length a traced scalar: one program a
+    bucket. A key tile's float32 logits, all heads at once ``[4, 8, 256,
+    512]`` (16 MB), are given the chip's fast memory (``S(1)`` in the
+    compiled layout) between the product that makes them and the one that
+    reads them, which is why a pair costs less here than on the rectangle
+    whose ``[8, 256, keys]`` a head went through HBM (PERF.md section 6,
+    PR 47); the program's temporaries stay under half a GiB."""
+    from paddle_tpu.ops.attention_ops import sparse_prompt_attention
+
+    def s(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    compiled = jax.jit(lambda *a: sparse_prompt_attention(
+        *a[:-1], 2048, live=a[-1])[0]).lower(
+        s((1, 32, bucket, 128)), s((1, 4, bucket, 128)),
+        s((1, 4, bucket, 128)), s((1, bucket, 16, 64)),
+        s((1, bucket, 16), jnp.float32), s((1, bucket, 64)),
+        s((), jnp.int32)).compile()
+    assert re.search(r"f32\[(1,)?4,8,256,512\]\{[^}]*S\(1\)\}",
+                     compiled.as_text())
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 29
+
+
 def _kernel_shapes(text):
     """Operand and result shapes of the program's Mosaic kernels."""
     return [s for res, ops in chip_smoke.tpu_custom_calls(text)

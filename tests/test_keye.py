@@ -19,6 +19,7 @@ assert (at toy size two indexer heads leave a quarter of the scores exactly
 exercised)."""
 
 import dataclasses
+import math
 import os
 import sys
 
@@ -164,16 +165,19 @@ def program_sets(model, ids, states):
         for blk, x in zip(model.model.layers, entered)]
 
 
-@pytest.mark.parametrize("chunk", [A.SPARSE_QUERY_CHUNK, 16])
+@pytest.mark.parametrize("chunk,tile", [
+    (A.SPARSE_QUERY_CHUNK, A.SPARSE_KEY_TILE), (16, 8)])
 def test_the_forward_and_the_chosen_sets_match_the_reference(
-        tiny, monkeypatch, chunk):
+        tiny, monkeypatch, chunk, tile):
     """Whole-sequence logits, and THE SETS: every layer's chosen keys of
     every row equal the reference's (rows under ``topk`` keep all their
-    keys, rows past it exactly ``topk``). With 16 queries a chunk the 64
-    rows pass the read's loop over chunks four times, as a served prompt
-    does at 256 a chunk."""
+    keys, rows past it exactly ``topk``). With 16 queries a chunk and 8
+    keys a tile the 64 rows pass the read's loop over chunks four times
+    and its loop over key tiles two to eight times a chunk, as a served
+    prompt does at 256 a chunk and 512 a tile."""
     model, params = tiny
     monkeypatch.setattr(A, "SPARSE_QUERY_CHUNK", chunk)
+    monkeypatch.setattr(A, "SPARSE_KEY_TILE", tile)
     ids = np.random.default_rng(0).integers(1, 512, (2, 64))
     states, theirs = [], []
     got = model(ids, collect=states).value
@@ -253,6 +257,167 @@ def test_the_counter_of_keys_read_follows_what_the_program_reads(tiny):
     engine, _, _ = serve(model, prompts_of(1, REQUESTS[:3]))
     stats = engine.stats()
     assert stats["sparse_keys_read"] == stats["sparse_keys_live"] > 0
+
+
+# ---- a prompt's read follows its live causal triangle (PR 47)
+
+@pytest.fixture
+def toy_tiling(monkeypatch):
+    """16 queries a chunk, 8 keys a tile: 64 rows are four chunks, eight
+    tiles and four selection slices of 16 keys (read when a read is
+    traced, so a test under it builds its own model)."""
+    monkeypatch.setattr(A, "SPARSE_QUERY_CHUNK", 16)
+    monkeypatch.setattr(A, "SPARSE_KEY_TILE", 8)
+
+
+def read_operands(seed, s=64, b=2):
+    """A read's operands at toy widths: 4 query / 2 KV heads of ``s``
+    values, the indexer's view from the toy model's own ``_index()`` of
+    random rows, and V the IDENTITY, so that a row's output is its
+    softmax weights over the ``s`` keys and its chosen set the keys whose
+    weight is not 0."""
+    rng = np.random.default_rng(seed)
+    attn = build(seed=seed)[0].model.layers[0].attn
+    rows = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
+    qi, w, ki = attn._index(
+        jnp.asarray(rng.normal(size=(b, s, TINY.hidden_size)), jnp.float32),
+        rows)
+    q = jnp.asarray(rng.normal(size=(b, 4, s, s)), jnp.float32)
+    k = jnp.asarray(rng.normal(size=(b, 2, s, s)), jnp.float32)
+    v = jnp.broadcast_to(jnp.eye(s, dtype=jnp.float32), (b, 2, s, s))
+    return q, k, v, qi, w, ki
+
+
+def rectangle(q, k, v, chosen):
+    """The read as the whole ``[s, s]`` rectangle under the chosen sets'
+    mask: every query times every key, one softmax a row."""
+    g = q.shape[1] // k.shape[1]
+    logits = jnp.einsum("bhqd,bhkd->bhqk", q, jnp.repeat(k, g, axis=1)) \
+        / math.sqrt(q.shape[-1])
+    probs = jax.nn.softmax(jnp.where(chosen[:, None], logits, -jnp.inf), -1)
+    return jnp.einsum("bhqk,bhkd->bhqd", probs, jnp.repeat(v, g, axis=1))
+
+
+def chosen_sets(qi, w, ki):
+    """Every row's chosen set over ALL the keys, bool [b, s, s], as the
+    rectangle took them: the scores and the selection under the causal
+    mask alone."""
+    b, s = w.shape[:2]
+    at = jnp.arange(s)
+    return A.topk_mask(A.index_scores(qi, w, ki), jnp.broadcast_to(
+        at[None, :] <= at[:, None], (b, s, s)), TOPK)
+
+
+#: the call's own rows in a bucket of 64 -> the key tiles its four chunks
+#: multiply (16 queries a chunk, 8 keys a tile)
+LIVES = {"the_bucket": (64, [2, 4, 6, 8]),
+         "under_one_chunk": (10, [2, 0, 0, 0]),
+         "one_row_past_a_chunks_edge": (33, [2, 4, 5, 0]),
+         "one_row_past_a_tiles_edge": (25, [2, 4, 0, 0]),
+         "under_topk": (5, [1, 0, 0, 0])}
+
+
+@pytest.mark.parametrize("case", sorted(LIVES))
+def test_the_bounded_read_is_the_rectangles_on_every_live_row(toy_tiling,
+                                                              case):
+    """The read that stops at a chunk's frontier and at the call's own
+    rows against the rectangle written here: the live rows' weights within
+    the float32 tolerance, their chosen sets EQUAL (read off the weights:
+    V is the identity), every head of a row the same set, rows past
+    ``live`` zeros, and the tiles the loop counted the ones
+    ``sparse_prompt_tiles`` gives it."""
+    live, tiles = LIVES[case]
+    q, k, v, qi, w, ki = read_operands(11)
+    want = chosen_sets(qi, w, ki)
+    got, ran = jax.jit(lambda n: A.sparse_prompt_attention(
+        q, k, v, qi, w, ki, TOPK, live=n))(live)
+    got = np.asarray(got)
+    assert got.shape == (2, 4, 64, 64)
+    assert np.abs(got - np.asarray(rectangle(q, k, v, want)))[:, :, :live] \
+        .max() < LOGITS
+    assert ((got[:, :, :live] > 0)
+            == np.asarray(want)[:, None, :live]).all()
+    assert (np.asarray(want).sum(-1)[:, :live]
+            == np.minimum(np.arange(live) + 1, TOPK)).all()
+    assert (got[:, :, live:] == 0).all()
+    assert A.sparse_prompt_tiles(64, live).tolist() == tiles
+    assert int(ran) == sum(tiles)
+    assert A.sparse_prompt_pairs(2, 64, live) \
+        == (2 * 16 * 8 * sum(tiles), 2 * 64 * 64)
+
+
+def test_a_tile_with_none_of_a_rows_keys_adds_no_weight(toy_tiling):
+    """8 chosen keys over up to 64: most rows choose NO key in some tile
+    they pass, the first tile among them (nothing chosen yet, the running
+    maximum still at its start). Such a tile's masked logits equal the
+    running maximum and must not become weights: exactly 0 there, and the
+    row's weights sum to 1 over its chosen keys. Without a length
+    (``forward()``'s call) the causal bound alone holds: every chunk
+    runs."""
+    q, k, v, qi, w, ki = read_operands(12)
+    want = np.asarray(chosen_sets(qi, w, ki))
+    got, ran = A.sparse_prompt_attention(q, k, v, qi, w, ki, TOPK)
+    got = np.asarray(got)
+    assert int(ran) == 2 + 4 + 6 + 8
+    by_tile = want.reshape(2, 64, 8, 8).any(-1)             # [b, row, tile]
+    passed = np.arange(8)[None, :] * 8 <= np.arange(64)[:, None]
+    empty = np.logical_and(~by_tile, passed[None])
+    assert empty[:, :, 0].sum() >= 10 and empty[:, :, 1:].sum() > 100
+    weights = got.reshape(2, 4, 64, 8, 8)
+    assert (weights[np.broadcast_to(empty[:, None], (2, 4, 64, 8))]
+            == 0).all()
+    np.testing.assert_allclose(got.sum(-1), 1.0, atol=1e-6)
+    assert ((got > 0) == want[:, None]).all()
+
+
+@pytest.mark.parametrize("lengths", [(37, 60), (64, 17)])
+def test_two_lengths_share_a_buckets_one_program(toy_tiling, lengths):
+    """Rows past ``live`` are read by nothing: two prompts of one bucket,
+    one after the other through ONE compiled prefill program (the length
+    is data), each with the reference's logits on its last live row and
+    the reference's tokens decoded after it."""
+    model, params = build(seed=5)
+    engine = ServingEngine(model, max_slots=2, max_len=128, buckets=[64],
+                           block_size=8, num_blocks=0, prefix_cache=False,
+                           eos_token_id=None)
+    tap = Tap(engine)
+    for n, (prompt, new) in zip(lengths, prompts_of(
+            5, [(n, 12) for n in lengths])):
+        req = engine.submit(prompt, max_new_tokens=new)
+        engine.step()                       # the prompt's own dispatch
+        ids = np.zeros((1, 128), np.int32)
+        ids[0, :n] = prompt
+        ref = np.asarray(reference(file_of(TINY))(params, jnp.asarray(ids)))
+        assert np.abs(tap.pending[0] - ref[0, n - 1]).max() < LOGITS
+        engine.run_until_idle()
+        worst_logit, worst_deficit = served_against_the_reference(
+            params, [req], tap, file_of(TINY))
+        assert worst_logit < LOGITS and worst_deficit == 0.0
+    assert engine._prefill_fns[64]["traces"]["count"] == 1
+    assert engine.stats()["sparse_prompt_keys_rect"] == 2 * 4 * 64 * 64
+
+
+def test_the_prompt_counters_are_the_loops_own_count(toy_tiling):
+    """``sparse_prompt_keys_read`` / ``_rect`` of ``engine.stats()``: a
+    dispatch and layer, the pairs the read multiplies as the loop itself
+    counts its tiles (the op called here at each prompt's bucket and
+    length), and ``rows x bucket`` of them."""
+    model, _ = build(seed=5)
+    lengths = [5, 25, 33, 64, 10, 32]
+    engine, _, _ = serve(model, prompts_of(2, [(n, 2) for n in lengths]))
+    stats = engine.stats()
+    ran = {bucket: jax.jit(lambda live, ops=read_operands(
+        13, s=bucket, b=1): A.sparse_prompt_attention(
+            *ops, TOPK, live=live)[1]) for bucket in (32, 64)}
+    read = rect = 0
+    for n in lengths:
+        bucket = 32 if n <= 32 else 64
+        assert A.sparse_prompt_tiling(bucket)[:2] == (16, 8)
+        read += 4 * 16 * 8 * int(ran[bucket](n))
+        rect += 4 * bucket * bucket
+    assert (stats["sparse_prompt_keys_read"],
+            stats["sparse_prompt_keys_rect"]) == (read, rect)
+    assert 0.2 * rect < read < 0.5 * rect
 
 
 def test_the_ops_follow_a_table_whose_blocks_are_not_contiguous():

@@ -753,6 +753,8 @@ OVER = {"engine.experts_touched": "counters.engine.sampler_dispatches",
         "engine.state_bytes.close": None,
         "engine.sparse_keys_read": "counters.engine.sparse_keys_live",
         "engine.sparse_keys_live": "counters.engine.sampler_dispatches",
+        "engine.sparse_prompt_keys_read":
+            "counters.engine.sparse_prompt_keys_rect",
         "engine.index_cache_bytes.close": None}
 
 
@@ -828,7 +830,8 @@ TWINS = {
     "keye_longdoc_24k": (9, 2**31 + 46, (
         "serving.decode_step", "engine.sparse_keys_read",
         "engine.sparse_keys_live", "engine.index_cache_bytes.close",
-        "engine.inputs_resident", "engine.prefill_tokens_live"))}
+        "engine.inputs_resident", "engine.prefill_tokens_live",
+        "engine.sparse_prompt_keys_read"))}
 
 
 def last_line(out):
