@@ -52,6 +52,7 @@ exactly one source of sampling math in the tree.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional, Sequence, Tuple
 
 import numpy as np
@@ -139,13 +140,24 @@ class DecodeParams:
                 and not self.json_mode)
 
 
+@functools.lru_cache(maxsize=4096)
+def _root_key(seed: int) -> Tuple[int, ...]:
+    import jax
+    return tuple(np.asarray(jax.random.PRNGKey(seed),
+                            dtype=np.uint32).tolist())
+
+
 def request_key(seed: int) -> np.ndarray:
     """The request-local PRNG root: a raw ``[2] uint32`` threefry key.
 
     Derived from the request's seed alone — never from slot index or
-    engine identity — so restarts and re-routing replay the stream."""
-    import jax
-    return np.asarray(jax.random.PRNGKey(int(seed)), dtype=np.uint32)
+    engine identity — so restarts and re-routing replay the stream.
+    ``jax.random.PRNGKey`` is a program on the device and its fetch
+    waits behind whatever the device was given (the decode step in
+    flight: a step's time in every ``submit``, with the scheduler
+    standing still behind it), so a seed's key is computed once a
+    process: every greedy request carries seed 0."""
+    return np.array(_root_key(int(seed)), dtype=np.uint32)
 
 
 def neutral_samp(rows: int, vocab: int):

@@ -18,11 +18,17 @@ Compile surfaces, all fixed-shape:
   waits for k, so fetch, commit and launch run under the device's
   time. A step's rows are committed or dropped one by one (a request
   that finished, was cancelled or shed meanwhile has its row dropped)
-  and no step is dispatched twice; a step after an admission or with
-  a grammar row is built from the host once the step before it
-  landed (``ServingEngine._decode``; ``stats()``:
-  ``ahead_dispatches`` / ``ahead_rows_committed`` /
-  ``ahead_rows_dropped``). Tokens are counted when the host has them.
+  and no step is dispatched twice. An admission does not break the
+  chain: a prefill group is a flight too, the step after it is
+  dispatched behind it from its device outputs (the prompt program
+  merges its rows' first tokens and keys into what the step in flight
+  left), and only then does the host fetch and commit its first
+  tokens; a step with a grammar row, or after a group with a row
+  whose first token the host draws (sampled, grammar), is built from
+  the host once what was before it landed (``ServingEngine._decode``;
+  ``stats()``: ``ahead_dispatches`` / ``ahead_rows_committed`` /
+  ``ahead_rows_dropped`` / ``admit_ahead_dispatches``). Tokens are
+  counted when the host has them.
   A dispatch of any kind is stamped once at each of its edges
   (:class:`_Stamps`: the readings its spans take anyway), and the
   stamps feed its spans (``flight=<id>``), the step account in
@@ -227,6 +233,26 @@ class _Flight(NamedTuple):
     qerr: object
     ahead: bool         # dispatched before the step before it was fetched
     stamps: _Stamps     # its id and the readings of its edges
+
+
+class _Prefill(NamedTuple):
+    """One prefill group the device was given and whose first tokens the
+    host has not fetched: a flight like a decode step. The pools it
+    returns are bound when it is dispatched, and its rows stand in
+    ``_active`` from then on; these are the outputs its commit reads."""
+    bucket: int
+    live: tuple         # (request, cache row, shared prompt tokens) a row
+    lg: object          # [rows, vocab] logits at each row's last token
+    #: the tokens and keys of every slot as the step behind this group
+    #: takes them, on the device: what the group was given (the outputs of
+    #: the dispatch before it) with its rows' first tokens and its
+    #: requests' keys written over their slots by the prompt program
+    nxt: object         # [b] i32
+    keys: object        # [b, 2] u32
+    qerr: object
+    behind: bool        # dispatched behind a decode step in flight
+    followed: bool      # a decode step was dispatched behind it, unfetched
+    stamps: _Stamps
 
 
 class Request:
@@ -767,6 +793,11 @@ class ServingEngine:
         # steps dispatched before the step before them was fetched, and
         # their rows that were committed / computed for nobody
         self._flight = None               # guarded-by: _step_lock
+        # the prefill groups of this round whose first tokens are not
+        # fetched yet (_Prefill), in dispatch order: the decode step is
+        # dispatched behind them first. Empty between two rounds
+        self._prefills: List[_Prefill] = []   # guarded-by: _step_lock
+        self._admit_ahead_dispatches = 0  # guarded-by: _step_lock
         # the engine's one sequence of dispatches (a _Stamps' id), and
         # when the last of them to be fetched was: the device was busy
         # with it until then, whatever was dispatched behind it
@@ -850,6 +881,8 @@ class ServingEngine:
             "_ahead_hits": "_step_lock",
             "_ahead_misses": "_step_lock",
             "_flight": "_step_lock",
+            "_prefills": "_step_lock",
+            "_admit_ahead_dispatches": "_step_lock",
             "_ahead_dispatches": "_step_lock",
             "_ahead_rows_committed": "_step_lock",
             "_ahead_rows_dropped": "_step_lock",
@@ -1419,9 +1452,18 @@ class ServingEngine:
         dispatches in admission order. It writes KV through per-row
         block tables into the shared pools. Maps
         ``(ids [rows, bucket] i32, last [rows] i32,
-        pos [rows] i32, tables [rows, T] i32, pools)``
-        to each row's logits at its true last token plus the updated
-        pools; ``pos`` is each row's write offset (its shared-prefix
+        pos [rows] i32, tables [rows, T] i32, pools, merge)``
+        to each row's logits at its true last token, the updated pools
+        and, merged by the program itself, what the decode step behind
+        it takes: ``merge`` is ``(tokens [max_slots] i32, keys
+        [max_slots, 2] u32, slots [rows] i32, row_keys [rows, 2] u32)``,
+        the next tokens and keys the dispatch before this one left on
+        the device and each row's cache slot and request key; the last
+        two results are those tokens with each row's greedy first token
+        at its slot and those keys with each row's key at its slot, so
+        the step behind a prefill is built without a fetch and without
+        a program of its own (:meth:`_rows_ahead`). ``pos`` is each
+        row's write offset (its shared-prefix
         length — 0 without a prefix hit), so a prefix-cached prompt
         only computes its unshared suffix; rows past the admitted
         count are padding the caller discards. Cached in the model's
@@ -1449,7 +1491,7 @@ class ServingEngine:
                                              _inject_params)
 
             def _prefill(params, ids, last, pos, tables, pools,
-                         lora=None):
+                         merge=None, lora=None):
                 from ..models.generation import (_kernel_layout,
                                                  _unwrap_pools)
                 with no_grad(), _borrowed_params(model, params), \
@@ -1457,7 +1499,17 @@ class ServingEngine:
                     lg, newp = spec.prefill_logits(ids, last, pos, tables,
                                                    pools, lora)
                 pools_out, qerr = _unwrap_pools(newp)
-                return lg, pools_out, qerr
+                if merge is None:
+                    return lg, pools_out, qerr
+                # each row's greedy first token over its slot of the
+                # tokens the dispatch before this one left, its request's
+                # key over its slot of the keys (a padding row's slot is
+                # out of range: dropped)
+                tokens, keys, slots, row_keys = merge
+                first = jnp.argmax(lg, axis=-1).astype(tokens.dtype)
+                return (lg, pools_out, qerr,
+                        tokens.at[slots].set(first, mode="drop"),
+                        keys.at[slots].set(row_keys, mode="drop"))
 
             # the entry owns ``pools`` (argument 5 here), like the
             # step entries: generation.POOLS_DONATED
@@ -1468,12 +1520,12 @@ class ServingEngine:
                 repl, pools_sh = _mesh_step_shardings(model, mesh,
                                                       kv_dtype)
                 in_sh = (_mesh_param_shardings(model, mesh),
-                         repl, repl, repl, repl, pools_sh)
+                         repl, repl, repl, repl, pools_sh, repl)
                 if lora_shape is not None:
                     in_sh = in_sh + (repl,)
                 jit_kwargs.update(
                     in_shardings=in_sh,
-                    out_shardings=(repl, pools_sh, repl))
+                    out_shardings=(repl, pools_sh, repl, repl, repl))
             fn = _inject_params(
                 model, _ct.tracked_jit("serving_prefill_paged", _prefill,
                                        labels={"bucket": str(bucket)},
@@ -1510,8 +1562,11 @@ class ServingEngine:
         ``group`` rows are ``(req, row, shared)``. The
         fault site fires once per request per attempt (preserving the
         per-request `skip`-sheds-one semantics); surviving requests
-        share one dispatch of the bucket's compiled function. Returns
-        ``(live, shed, (logits, new_pools, qerr) | None)``."""
+        share one dispatch of the bucket's compiled function, which
+        merges their first tokens and keys into what the dispatch
+        before it left on the device (:meth:`_head`). Returns
+        ``(live, shed, (logits, new_pools, qerr, tokens, keys) |
+        None)``."""
         live, shed = [], []
         for rec in group:
             kind = fault_point("serving.step")
@@ -1542,18 +1597,23 @@ class ServingEngine:
         pos = np.zeros(n, np.int32)
         tables = self.cache.table_rows([row for _, row, _ in live], n)
         pages = np.zeros(n, np.int32)
+        slots = np.full(n, self.max_slots, np.int32)  # padding: dropped
+        row_keys = np.zeros((n, 2), np.uint32)
         for i, (req, row, shared) in enumerate(live):
             suffix = req.context[shared:]
             ids[i, :len(suffix)] = suffix
             last[i] = len(suffix) - 1
             pos[i] = shared
+            slots[i] = row
+            row_keys[i] = req._key
             if self.lora_pool is not None and req.tenant:
                 pages[i] = self.lora_pool.page_of(req.tenant)
         fn = self._prefill_entry(bucket)["fn"]
         args = (jnp.asarray(ids), jnp.asarray(last),
                 jnp.asarray(pos), jax.tree_util.tree_map(jnp.asarray,
                                                          tables),
-                self.cache.arrays())
+                self.cache.arrays(),
+                self._head() + (jnp.asarray(slots), jnp.asarray(row_keys)))
         if self.lora_pool is not None:
             args = args + ((jnp.asarray(pages), self.lora_pool.arrays),)
         return live, shed, self._call_paged(fn, args, args[4])
@@ -1677,18 +1737,23 @@ class ServingEngine:
                     part = [rec for rec in part if rec not in stale]
                     if not part:
                         continue
-                with _profiler.RecordEvent(
-                        "serving.prefill_step",
-                        {"bucket": bucket, "rows": len(part)}):
-                    admitted += self._prefill_group(bucket, part)
+                admitted += self._prefill_group(bucket, part)
         return consumed, admitted
 
     def _prefill_group(self, bucket: int,
                        group) -> int:  # holds: _step_lock
-        """One group of an admission round, from building its inputs
-        to its first tokens committed: one dispatch (:class:`_Stamps`), whose
-        dispatch-to-fetched time is the bucket's sample for the SLO
-        estimate (:meth:`_landed`). Returns rows admitted."""
+        """Dispatch one group of an admission round (:class:`_Stamps`):
+        the pools it returns are bound at once, as every entry's are, and
+        its rows stand in ``_active`` from here, so whatever is
+        dispatched next queues behind it on the device and counts them
+        in. Its first tokens are fetched and committed by
+        :meth:`_land_prefill`: at once where a row's first token is the
+        host's to draw from the logits (a sampled or grammar row) or
+        where a step decodes several tokens (drafts, a megastep: both
+        are built from tokens the host holds), else after the decode
+        step behind the round's groups is on the device
+        (:meth:`_decode_attempt`). Nothing of this is configured: it is
+        read off the admitted requests. Returns rows admitted."""
         t_adm = self._clock()
         for g_req, _row, _shared in group:
             _tracing.mark(g_req.id, "admit", t_adm, self.trace_track)
@@ -1712,24 +1777,65 @@ class ServingEngine:
             self._shed(req, err)
         if not live:
             return 0
-        lg, pools, qerr = out
+        lg, pools, qerr, nxt, keys = out
+        self.cache.set_arrays(pools)
+        for req, row, _ in live:
+            req.slot = row
+            req.state = "running"
+            self._active[row] = req
+        pf = _Prefill(bucket, tuple(live), lg, nxt, keys, qerr, behind,
+                      False, st)
+        if not self.spec_tokens and self.megastep <= 1 and all(
+                req.decode.is_greedy and req._cursor is None
+                for req, _, _ in live):
+            self._prefills.append(pf)
+        else:
+            self._land_prefills()   # the groups before it, in their order
+            self._land_prefill(pf)
+        return len(live)
+
+    def _land_prefills(self):  # holds: _step_lock
+        """Fetch and commit the round's prefill groups, in the order
+        they were dispatched in."""
+        while self._prefills:
+            self._land_prefill(self._prefills.pop(0))
+        if not self._active:
+            self._drain()    # nobody is left for the step behind them
+
+    def _land_prefill(self, pf: _Prefill):  # holds: _step_lock
+        """Fetch one dispatched prefill group's first tokens and commit
+        them, row by row: a row stands iff its slot still holds the
+        request it was admitted for (one shed since, with the pools an
+        earlier fetch lost, is dropped). The dispatch-to-fetched time is
+        the bucket's sample for the SLO estimate (:meth:`_landed`), and
+        ``serving.prefill_step`` is the group from its dispatch to its
+        first tokens committed, whatever the host did between them. A
+        group that failed on the device is found here, at its fetch: its
+        pools were bound at its dispatch and went with it, and with them
+        whatever was dispatched behind it (:meth:`_pools_lost`)."""
+        st = pf.stamps
+        live = [(i, req, row, shared)
+                for i, (req, row, shared) in enumerate(pf.live)
+                if self._active.get(row) is req]
+        if not live:
+            return
         with _profiler.RecordEvent("serving.prefill.fetch",
                                    {"flight": st.id}) as ev:
-            first = np.asarray(jnp.argmax(lg, axis=-1))
+            try:
+                first = np.asarray(pf.nxt)   # the host waits for the device
+            except Exception as e:
+                self._pools_lost(e)
+                return
         st.t_fetch, st.t_fetched = ev.t0, ev.t1
         with _profiler.RecordEvent("serving.prefill.commit",
                                    {"flight": st.id}) as ev:
             now = self._clock()     # the commit's one stamp
-            self.cache.set_arrays(pools)
-            self._note_qerr(qerr, sum(len(req.context) - shared
-                                      for req, _, shared in live))
-            for i, (req, row, shared) in enumerate(live):
+            self._note_qerr(pf.qerr, sum(len(req.context) - shared
+                                         for _, req, _, shared in live))
+            for i, req, row, shared in live:
                 ctx = req.context
                 self.cache.commit_prefill(row, len(ctx))
                 self.cache.insert_prefix(row, ctx)
-                req.slot = row
-                req.state = "running"
-                self._active[row] = req
                 if shared:
                     self._prefix_hit_reqs += 1
                     _monitor.stat_add("STAT_serving_prefix_hits")
@@ -1738,7 +1844,7 @@ class ServingEngine:
                     _monitor.stat_add("STAT_serving_prefix_misses")
                 _monitor.stat_add("STAT_serving_prefills")
                 _runlog.log_event("serving_admit", request=req.id,
-                                  bucket=bucket, slot=row,
+                                  bucket=pf.bucket, slot=row,
                                   prompt_tokens=len(req.prompt),
                                   shared_tokens=shared)
                 if req.first_token_at is not None:
@@ -1751,21 +1857,28 @@ class ServingEngine:
                 # logits (same argmax greedy_search takes after ITS
                 # prefill; sampled/masked rows draw from them instead)
                 self._append_token(
-                    req, self._take_first(req, first, lg, i), now)
+                    req, self._take_first(req, first, pf.lg, i), now)
         st.t_committed = ev.t1
-        self._landed(st, len(live), ahead=behind, bucket=bucket)
-        return len(live)
+        if pf.followed:
+            self._admit_ahead_dispatches += 1
+            _monitor.stat_add("STAT_serving_admit_ahead_dispatches")
+        self._landed(st, len(live), ahead=pf.behind, bucket=pf.bucket)
+        _profiler.record_span(
+            "serving.prefill_step", st.t_dispatch / 1e9,
+            (st.t_committed - st.t_dispatch) / 1e9,
+            {"bucket": pf.bucket, "rows": len(live), "flight": st.id})
 
     def _take_first(self, req: Request, first: np.ndarray, lg,
                     i: int) -> int:
         """The request's first generated token from its prefill-logits
-        row: the batch argmax for plain greedy rows (the oracle's fast
-        path), a host-side :func:`sample_first` draw for sampled or
+        row ``i``: the program's argmax for plain greedy rows, read at
+        the request's slot of the merged tokens ``first`` (the oracle's
+        fast path), a host-side :func:`sample_first` draw for sampled or
         grammar-masked rows — same law the compiled steps apply, so a
         restart replays identically."""
         p = req.decode
         if p.is_greedy and req._cursor is None:
-            return int(first[i])
+            return int(first[req.slot])
         mask_row = None
         if req._cursor is not None:
             mask_row = req._cursor.mask_row(
@@ -1774,17 +1887,41 @@ class ServingEngine:
                                      mask_row)
         return tok
 
-    def _admit(self) -> int:
+    def _admit(self, commit: bool = True) -> int:
         """Fill free slots from the queue (batched, one prefill
         dispatch per bucket per round). Returns how many requests were
         admitted; keeps going while progress frees more slots (e.g. a
-        request that finishes on its prefill token)."""
+        request that finishes on its prefill token). With ``commit``
+        off the groups whose first tokens can wait stay in
+        ``_prefills`` for the caller to land (:meth:`step`: behind the
+        decode step, which is dispatched first), and a slot such a
+        commit frees is refilled a round later."""
         admitted = 0
         while True:
             popped, n = self._admit_round()
+            if commit:
+                self._land_prefills()
             admitted += n
             if not popped:
                 return admitted
+
+    def _unfetched(self):  # holds: _step_lock
+        """The last dispatch whose outputs the host has not fetched: a
+        prefill group of this round, else the decode step in flight,
+        else None."""
+        return self._prefills[-1] if self._prefills else self._flight
+
+    def _head(self):  # holds: _step_lock
+        """``(tokens [max_slots] i32, keys [max_slots, 2] u32)`` on the
+        device, as the next dispatch finds them: the outputs of the last
+        dispatch that is not fetched yet (:meth:`_unfetched`), else what
+        the last commit carried, else built from the requests and sent.
+        A slot none of these decoded for holds what nothing reads."""
+        after = self._unfetched()
+        if after is not None:
+            return after.nxt, after.keys
+        last, keys = self._carried()
+        return self._tokens_arg(last), self._keys_arg(keys)
 
     # ------------------------------------------------------------ decode
     def _send(self, host):  # holds: _step_lock
@@ -1855,6 +1992,19 @@ class ServingEngine:
             tokens[slot] = req.tokens[-1]
         return self._send(tokens)
 
+    def _keys_arg(self, carried):  # holds: _step_lock
+        """Each row's key: the last step's ``new_keys`` (``carried``)
+        while the batch stands as its commit left it, else gathered from
+        the requests, whose host-side key is the authoritative one, and
+        sent."""
+        if carried is not None:
+            return carried
+        keys = np.zeros((self.max_slots, 2), np.uint32)
+        for slot, req in self._active.items():
+            keys[slot] = req._key
+        self._resent = True
+        return self._send(keys)
+
     def _tables_arg(self):  # holds: _step_lock
         """The block tables, sent again only after the cache wrote one
         (bind, release, handoff: ``tables_version``); a copy, because
@@ -1893,12 +2043,7 @@ class ServingEngine:
             return temp, tk, tp
 
         temp, tk, tp = self._resident("samp", self._batch(), consts)
-        if keys is None:
-            keys = np.zeros((b, 2), np.uint32)
-            for slot, req in self._active.items():
-                keys[slot] = req._key
-            keys = self._send(keys)
-            self._resent = True
+        keys = self._keys_arg(keys)
         cursored = [(slot, req) for slot, req in self._active.items()
                     if req._cursor is not None]
         if cursored:
@@ -1984,10 +2129,11 @@ class ServingEngine:
 
     def _pools_lost(self, err: BaseException):  # holds: _step_lock
         """The pools' contents went with a failed step: shed what was
-        running (and the step in flight, whose tokens are nobody's
-        now), and start from zeroed pools with the prefix cache
-        flushed."""
+        running (and the step in flight and the prefill groups not
+        fetched yet, whose tokens are nobody's now), and start from
+        zeroed pools with the prefix cache flushed."""
         self._ahead = self._flight = None
+        self._prefills = []
         self._shed_active(err)
         self.cache.rebuild_pools()
         self._pool_epoch = self.cache.pool.epoch
@@ -2033,12 +2179,13 @@ class ServingEngine:
         decode step took the device from ``max(t_launched, the last
         fetch's return)`` to ``t_fetched``: exactly, if the host waited
         for it; at most, if its tokens were there already (the account
-        takes the bound as it is). A step overtaken, because a prefill
-        dispatched behind it was fetched before it, ended unobserved and
-        feeds no estimate. The TPOT sample is that time over the tokens
-        the step committed a row; the prefill sample is dispatch to
-        fetched, the wait behind a step in flight included, which is what
-        the next arrival pays too."""
+        takes the bound as it is). Flights are fetched in the order of
+        their ids but for a prefill group fetched at once (a row's first
+        token is the host's to draw): the step in flight it overtook
+        ended unobserved and feeds no estimate. The TPOT sample is that
+        time over the tokens the step committed a row; the prefill sample
+        is dispatch to fetched, the time behind a step in flight
+        included, which is what the next arrival pays too."""
         a = self._account
         host = (st.t_launched - st.t_dispatch
                 + st.t_committed - st.t_fetched) / 1e6
@@ -2084,11 +2231,13 @@ class ServingEngine:
         the cache advances its array in place); everything else is
         resident (:meth:`_resident`) and re-sent only when changed.
 
-        ``after``: the step in flight that this one is dispatched
-        behind, before the host has fetched it (:meth:`_rows_ahead`
-        said it can be). Its tokens and keys are that step's outputs as
-        the device holds them, and every live row stands one further
-        than the cache has committed (``ahead_lengths``, which moves
+        ``after``: the last dispatch that this one is queued behind
+        before the host has fetched it, the step in flight or the last
+        prefill group of the round (:meth:`_rows_ahead` said it can
+        be). Its tokens and keys are that dispatch's outputs as the
+        device holds them; a row of the step in flight stands one
+        further than the cache has committed and a row of a prefill
+        group at its context's length (``ahead_lengths``, which moves
         the window kinds first: a table that moved is re-sent here)."""
         with _profiler.RecordEvent(
                 "serving.decode.inputs",
@@ -2099,7 +2248,13 @@ class ServingEngine:
                 lengths = self.cache.lengths.copy()
             else:
                 last, keys = after.nxt, after.keys
-                lengths = self.cache.ahead_lengths(list(self._active))
+                fl = self._flight
+                lengths = self.cache.ahead_lengths(
+                    [slot for slot, req, _ in (fl.rows if fl else ())
+                     if self._active.get(slot) is req],
+                    [(row, len(req.context)) for pf in self._prefills
+                     for req, row, _ in pf.live
+                     if self._active.get(row) is req])
             args = (self._tokens_arg(last) if tokens is None
                     else self._send(tokens),
                     self._send(lengths), self._tables_arg(),
@@ -2111,7 +2266,9 @@ class ServingEngine:
 
     def _launch(self, after=None, rows=None):  # holds: _step_lock
         """Dispatch one decode step, for every running request or, behind
-        the step in flight ``after``, for ``rows`` of it. The step owns
+        the dispatch ``after`` whose outputs the host has not fetched
+        (the step in flight, or the round's last prefill group), for
+        ``rows``. The step owns
         the pools (and the model's counters) from here: what it returns
         of them is bound at once, so whatever is dispatched next, a
         prefill or the step after, queues behind it on the device.
@@ -2141,49 +2298,73 @@ class ServingEngine:
             _monitor.stat_add("STAT_serving_ahead_misses", dropped)
         return _Flight(rows, nxt, new_keys, qerr, after is not None, st)
 
-    def _rows_ahead(self, fl: _Flight):  # holds: _step_lock
-        """The rows of the step after ``fl``, if that step can be
-        dispatched before ``fl`` is fetched; else None. It can when
+    def _rows_ahead(self, fl: Optional[_Flight]):  # holds: _step_lock
+        """The rows of the step after ``fl`` (the step in flight, or
+        None) and the round's prefill groups, if that step can be
+        dispatched before they are fetched; else None. It can when
         everything it needs is on the device or known to the host: every
-        running request is one of ``fl``'s rows (one that a prefill
-        admitted since has its token on the host, and joins the step
-        after), none decodes under a grammar (its mask for the next
-        position is built from the token ``fl`` has not delivered),
-        and some request goes on past ``fl`` (one that reaches its
-        budget there does not: its row is computed and dropped). Nothing
-        here is configured: it is read off the batch."""
-        held = {slot: (req, n) for slot, req, n in fl.rows}
+        running request's last token is where :meth:`_head` reads it, as
+        a row of ``fl``, a row of a prefill group not fetched yet, or,
+        with no step in flight, on the host, from which the head was
+        then built (one a prefill committed with ``fl`` in flight has
+        its token on the host alone, and joins the step after), none
+        decodes under a grammar (its mask for the next position is built
+        from the token not delivered yet), and some request goes on (one
+        that reaches its budget with the token in flight does not: its
+        row is computed and dropped). Nothing here is configured: it is
+        read off the batch."""
+        held = {} if fl is None else {slot: (req, n)
+                                      for slot, req, n in fl.rows}
+        for pf in self._prefills:
+            held.update((row, (req, len(req.tokens)))
+                        for req, row, _ in pf.live)
         rows = []
         for slot, req in self._active.items():
-            was, n = held.get(slot, (None, 0))
-            if was is not req or n != len(req.tokens) \
-                    or req._cursor is not None:
+            if req._cursor is not None:
                 return None
-            if len(req.tokens) + 1 < req.max_new_tokens:
-                rows.append((slot, req, len(req.tokens) + 1))
+            n = len(req.tokens)
+            if slot in held:
+                if held[slot] != (req, n):
+                    return None
+                n += 1          # the token in flight comes first
+            elif fl is not None:
+                return None
+            if n < req.max_new_tokens:
+                rows.append((slot, req, n))
         if not rows:
             return None
         return tuple(rows)
 
     def _decode_attempt(self):  # holds: _step_lock
         """Dispatch what this round can: the step to commit, unless it
-        is in flight already, and the step after it, ahead of the fetch.
-        A retry after a raise finds the first in ``_flight`` and does
-        not dispatch it again. -> the step dispatched ahead, or None."""
+        is in flight already, and the step after it (and after the
+        round's prefill groups), ahead of their fetches. Where that
+        step cannot be built from what the device holds and none is in
+        flight, the step is the host's to build: the round's prefill
+        groups are committed first, for their tokens. A retry after a
+        raise finds the first in ``_flight`` and does not dispatch it
+        again. -> the step dispatched ahead, or None."""
         kind = fault_point("serving.step")
         if kind == "skip":
             raise _SkipStep("injected skip of one decode iteration")
-        first = self._flight is None
-        if first:
-            self._flight = self._launch()
-        rows = self._rows_ahead(self._flight)
+        first = rows = None
+        if self._flight is not None or self._prefills:
+            rows = self._rows_ahead(self._flight)
+        if rows is None and self._flight is None:
+            self._land_prefills()
+            if not self._active:
+                return None
+            first = self._flight = self._launch()
+            rows = self._rows_ahead(first)
         if rows is None:
             return None
-        ahead = self._launch(self._flight, rows)
-        if first:
+        ahead = self._launch(self._unfetched(), rows)
+        self._prefills = [pf._replace(followed=True)
+                          for pf in self._prefills]
+        if first is not None:
             # two launches under one serving.decode: the first had
             # returned by the time the second's inputs were begun
-            self._flight.stamps.t_launched = ahead.stamps.t_dispatch
+            first.stamps.t_launched = ahead.stamps.t_dispatch
         return ahead
 
     def _note_qerr(self, qerr, rows: int):  # holds: _step_lock
@@ -2208,12 +2389,14 @@ class ServingEngine:
         """One batched decode over every occupied slot, dispatched one
         ahead of its fetch: the step this round commits is in flight
         since the last round (or is dispatched now, from the host's
-        state, when none is: the first step, the step after an
-        admission, a batch with a grammar row), the step after it is
-        dispatched from its device outputs **before** the host waits
-        for it (:meth:`_rows_ahead`), and fetch and commit then run
-        under the device's time. Each step is dispatched once and each
-        of its rows committed or dropped on its own (:meth:`_land`), so
+        state, when none is and the round's prefill groups do not carry
+        one: a batch with a grammar row, an admission whose first token
+        the host drew), the step after it and after the round's prefill
+        groups is dispatched from their device outputs **before** the
+        host waits for them (:meth:`_rows_ahead`), and fetch and commit
+        then run under the device's time. Each step is dispatched once
+        and each of its rows committed or dropped on its own
+        (:meth:`_land`), so
         a request's state, KV rows or recurrent record, advances once a
         committed token. An injected skip dispatches and commits
         nothing: what is in flight stays there. Returns how many tokens
@@ -2234,6 +2417,7 @@ class ServingEngine:
             # requests, keep the engine alive for new submissions
             self._shed_active(e)
             self._flight = None
+            self._prefills = []
             return 0
         if self._dispatch_seq > seq:
             # the span's close is the first reading after the round's
@@ -2241,7 +2425,7 @@ class ServingEngine:
             last = self._flight if ahead is None else ahead
             last.stamps.t_launched = ev.t1
         fl, self._flight = self._flight, ahead
-        produced = self._land(fl)
+        produced = 0 if fl is None else self._land(fl)
         if not self._active:
             self._drain()    # nobody is left for the step in flight
         return produced
@@ -2966,8 +3150,11 @@ class ServingEngine:
                 # since the last step is canceled within one step and
                 # its slot is free for this step's admissions
                 reaped = self._reap_expired()
-                admitted = self._admit()
+                admitted = self._admit(commit=False)
                 produced = self._decode_any()
+                # the round's prefill groups, fetched under the time of
+                # the decode step dispatched behind them
+                self._land_prefills()
                 if self.kv_tier is not None:
                     self._demote_sweep()
                 self._blocks_used_g.set(self.cache.blocks_used)
@@ -3050,8 +3237,12 @@ class ServingEngine:
           device, adds its bound, nearly 0, so the mean a step is then a
           floor.
         - ``prefill_flights``, ``prefill_host_ms``, ``prefill_wait_ms``:
-          the same for prefill groups (the wait holds the decode step
-          that was in flight ahead of the group).
+          the same for prefill groups. A group's fetch comes after the
+          fetch of the decode step that was in flight ahead of it, so
+          its wait is the prompt's own time on the device (a group
+          fetched at once, for a first token the host draws, waits for
+          that step too); ``admit_ahead_dispatches`` of them were
+          fetched with the decode step behind them on the device.
         - ``rounds``: calls of :meth:`step` that did work; ``round_ms``
           their time; ``rounds_over_100ms`` / ``rounds_over_1s`` those
           that took longer, and ``stalled_ms`` the time of the rounds
@@ -3088,6 +3279,7 @@ class ServingEngine:
             ahead_hits = self._ahead_hits
             ahead_misses = self._ahead_misses
             ahead_dispatches = self._ahead_dispatches
+            admit_ahead_dispatches = self._admit_ahead_dispatches
             ahead_rows_committed = self._ahead_rows_committed
             ahead_rows_dropped = self._ahead_rows_dropped
             pool_dispatches = self._pool_dispatches
@@ -3152,6 +3344,9 @@ class ServingEngine:
         # and their rows: committed, or computed for a request that had
         # finished or left by then
         out["ahead_dispatches"] = ahead_dispatches
+        # prefill groups (of ``prefill_flights``) whose first tokens were
+        # fetched after the decode step behind them was dispatched
+        out["admit_ahead_dispatches"] = admit_ahead_dispatches
         out["ahead_rows_committed"] = ahead_rows_committed
         out["ahead_rows_dropped"] = ahead_rows_dropped
         # decode/verify dispatches, and those whose batch was all

@@ -1002,21 +1002,29 @@ class BlockKVCache:
         self.lengths[row] = ln
         self._slide(row)
 
-    def ahead_lengths(self, rows: Sequence[int]) -> np.ndarray:
-        """The lengths a step takes that is dispatched while the step
-        before it is not committed yet: ``rows``, the rows that step
-        writes, stand one further than :attr:`lengths` says, and the
-        window kinds are moved to that position first, as
-        :meth:`advance` would have left them (it then finds them there).
-        A block they return here may still be read by the uncommitted
-        step: it was dispatched with the table that held it, and whoever
-        takes the block next writes it behind that step on the device."""
+    def ahead_lengths(self, rows: Sequence[int],
+                      prefilled: Sequence[Tuple[int, int]] = ()
+                      ) -> np.ndarray:
+        """The lengths a step takes that is dispatched while what was
+        dispatched before it is not committed yet: ``rows``, the rows
+        the step before it writes, stand one further than
+        :attr:`lengths` says, and the window kinds are moved to that
+        position first, as :meth:`advance` would have left them (it then
+        finds them there). A block they return here may still be read by
+        the uncommitted step: it was dispatched with the table that held
+        it, and whoever takes the block next writes it behind that step
+        on the device. ``prefilled`` is ``(row, length)`` of each row a
+        prefill dispatch writes whose :meth:`commit_prefill` is still to
+        come: it stands at that length, where :meth:`acquire` left its
+        window kinds."""
         lengths = self.lengths.copy()
         for row in rows:
             lengths[row] += 1
             for w in self._windows:
                 if w.hold(row, int(lengths[row])):
                     self.tables_version += 1
+        for row, length in prefilled:
+            lengths[row] = length
         return lengths
 
     def _slide(self, row: int):

@@ -830,12 +830,9 @@ def test_a_step_that_fails_after_consuming_the_pools_keeps_serving(
 
     monkeypatch.setitem(ent, "fn", consume_then_raise)
     second = eng.submit(prompts[1], max_new_tokens=8)
+    # (the round that admits the second dispatches the step behind its
+    # prefill: the failing decode is met in that round, PR 48)
     eng.step()
-    if where == "decode":
-        # the round that admitted the second committed the step that was
-        # in flight and dispatched none; the next one dispatches
-        assert first.state == second.state == "running"
-        eng.step()
     monkeypatch.undo()
 
     assert first.state == "shed"

@@ -209,8 +209,12 @@ def test_hedge_fires_wins_and_beats_unhedged_ttft(model):
     canceled leak-free (reason="hedge_lose"), and the rescue lands the
     first token in strictly fewer router steps than the identical
     unhedged run — at a fired volume inside the budget envelope."""
+    # (3 tokens: the clone's result is mirrored when it retires, and since
+    # PR 48 the round of a prefill commits no decode step (the step is
+    # dispatched behind the prefill and fetched a round later), so a
+    # request of n tokens retires in n rounds, not n - 1)
     prompt = _prompts((4,), seed=5)[0]
-    ref = greedy_search(model, np.asarray([prompt]), max_new_tokens=4,
+    ref = greedy_search(model, np.asarray([prompt]), max_new_tokens=3,
                         cache_len=32)[0].tolist()
 
     def run(hedge_ms):
@@ -218,7 +222,7 @@ def test_hedge_fires_wins_and_beats_unhedged_ttft(model):
                            max_len=32, buckets=[8, 16], max_queue=16,
                            block_size=4, hedge_ms=hedge_ms)
         _straggler(rt.engines[0])
-        req = rt.submit(prompt, max_new_tokens=4)
+        req = rt.submit(prompt, max_new_tokens=3)
         steps = _steps_to_first_token(rt, req)
         rt.run_until_idle()
         return rt, req, steps

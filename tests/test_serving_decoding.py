@@ -324,6 +324,23 @@ def test_request_key_ignores_everything_but_seed():
     assert not np.array_equal(request_key(42), request_key(43))
 
 
+def test_a_seeds_key_touches_the_device_once_a_process(monkeypatch):
+    """``submit`` builds a request's key, and ``jax.random.PRNGKey`` is a
+    program whose fetch waits behind the decode step in flight: the second
+    request of a seed (every greedy one has seed 0) computes nothing, and
+    gets an array of its own."""
+    import jax
+    calls, real = [], jax.random.PRNGKey
+    monkeypatch.setattr(jax.random, "PRNGKey",
+                        lambda seed: (calls.append(seed), real(seed))[1])
+    a, b = request_key(987654321), request_key(987654321)
+    assert calls == [987654321]
+    assert a is not b and np.array_equal(a, b)
+    assert np.array_equal(a, np.asarray(real(987654321), np.uint32))
+    a[0] ^= 1
+    assert np.array_equal(request_key(987654321), b)
+
+
 # ------------------------------- greedy steps skip the sampler (PR 28)
 
 def _oracle_sample_tokens(logits, samp):

@@ -388,10 +388,12 @@ def test_a_steady_step_copies_its_lengths_and_nothing_else(
     # tables go again, the mask does not
     eng.submit(_prompts((4,), seed=21)[0], max_new_tokens=4)
     del sent[:]
-    eng.step()      # its prefill, and the commit of the step in flight
-    assert sent == []
-    eng.step()      # the step it joins is built from the host
+    eng.step()      # its prefill and, behind it, the step it joins (its
+    #                 first token and key are merged on the device: PR 48)
     assert 1 < len(sent) and max(sent) < eng.max_slots * VOCAB * 4
+    del sent[:]
+    eng.step()      # and the batch stands again
+    assert sent == [eng.max_slots * 4], sent
     # a verify's tree of K+1 tokens comes from the host, its keys do not
     spec = _engine(model, spec_tokens=2)
     sent = _count_sends(spec, monkeypatch)

@@ -25,6 +25,7 @@ import json
 import time
 
 import numpy as np
+import jax
 import pytest
 
 import paddle_tpu as pt
@@ -80,9 +81,12 @@ def _account(eng):
     return {k: st[k] for k in _ACCOUNT_KEYS}
 
 
-def _traced_run(model, tmp_path, **kw):
+def _traced_run(model, tmp_path, last=None, **kw):
     """Serve WORK with the profiler on -> (requests, the flights in the
-    order they were committed, events, the account's growth)."""
+    order they were committed, events, the account's growth). ``last``:
+    decode parameters of the last request (a sampled one draws its first
+    token on the host: its prefill is fetched at once and the step after
+    it is the host's to build)."""
     eng = _warm(model, **kw)
     flights, landed = [], eng._landed
 
@@ -91,12 +95,19 @@ def _traced_run(model, tmp_path, **kw):
         return landed(fl, *a, **k)
     eng._landed = keep
     before = _account(eng)
+    followed = eng.stats()["admit_ahead_dispatches"]
     profiler.start_profiler()
-    reqs = [eng.submit(p, max_new_tokens=m) for p, m in _prompts()]
+    work = _prompts()
+    reqs = [eng.submit(p, max_new_tokens=m,
+                       **(last or {} if i == len(work) - 1 else {}))
+            for i, (p, m) in enumerate(work)]
     eng.run_until_idle()
     events = _stop_profiler(tmp_path)
     after = _account(eng)
-    return reqs, flights, events, {k: after[k] - before[k] for k in after}
+    grew = {k: after[k] - before[k] for k in after}
+    grew["admit_ahead_dispatches"] = \
+        eng.stats()["admit_ahead_dispatches"] - followed
+    return reqs, flights, events, grew
 
 
 @pytest.fixture(scope="module", params=sorted(PATHS))
@@ -189,7 +200,10 @@ def test_a_flights_spans_carry_its_id_and_one_flight_span_joins_them(traced):
 
 
 def test_a_step_built_by_the_host_and_one_dispatched_ahead(model, tmp_path):
-    _, flights, events, _ = _traced_run(model, tmp_path)
+    # (all greedy, every step is dispatched behind a step or a prefill
+    # since PR 48: the sampled request's admission leaves one to the host)
+    _, flights, events, _ = _traced_run(
+        model, tmp_path, last=dict(seed=3, temperature=0.8, top_k=12))
     joined = _by_flight(events, "serving.flight")
     prefill = {i for i, (s,) in joined.items() if s["args"]["prefill"]}
     ahead = {i for i, (s,) in joined.items() if s["args"]["ahead"]}
@@ -212,6 +226,50 @@ def test_a_step_built_by_the_host_and_one_dispatched_ahead(model, tmp_path):
                 assert fl.t_launched <= before.t_fetch
         (e,) = fetch[fl.id]
         assert e["ts"] == fl.t_fetch / 1e3
+
+
+def test_the_step_behind_a_prefill_is_dispatched_before_its_fetch(model,
+                                                                  tmp_path):
+    """A prefill group is a flight: its stamps are ordered like a step's,
+    the decode step behind it (the next id) is dispatched between its
+    launch and its fetch, and ``serving.prefill_step`` is drawn from its
+    stamps, dispatch to commit, around its own three spans."""
+    _, flights, events, grew = _traced_run(model, tmp_path)
+    joined = _by_flight(events, "serving.flight")
+    by_id = {fl.id: fl for fl in flights}
+    prefill = sorted(i for i, (s,) in joined.items() if s["args"]["prefill"])
+    assert prefill
+    followed = 0
+    for i in prefill:
+        pre = by_id[i]
+        stamps = [getattr(pre, s) for s in STAMPS]
+        assert stamps == sorted(stamps) and stamps[0] > 0
+        behind = by_id.get(i + 1)
+        if behind is None or (i + 1) in prefill:
+            continue        # a second group of its round follows it
+        assert joined[i + 1][0]["args"]["ahead"] == 1
+        assert pre.t_launched <= behind.t_dispatch <= behind.t_launched \
+            <= pre.t_fetch
+        # and the step is fetched after the prefill it queued behind
+        assert pre.t_fetched <= behind.t_fetch
+        followed += 1
+    assert followed >= 1
+    assert grew["admit_ahead_dispatches"] == len(prefill) == \
+        grew["prefill_flights"]
+    steps = _by_flight(events, "serving.prefill_step")
+    assert sorted(steps) == prefill
+    for i in prefill:
+        (e,), fl = steps[i], by_id[i]
+        assert e["parent"] is None and e["args"]["rows"] >= 1
+        assert e["ts"] == pytest.approx(fl.t_dispatch / 1e3, abs=1e-3)
+        assert e["dur"] == pytest.approx(
+            (fl.t_committed - fl.t_dispatch) / 1e3, abs=1e-3)
+        for name in ("serving.prefill", "serving.prefill.fetch",
+                     "serving.prefill.commit"):
+            (inner,) = _by_flight(events, name)[i]
+            # (the same readings through two float paths: to a nanosecond)
+            assert e["ts"] <= inner["ts"] + 1e-3 and \
+                inner["ts"] + inner["dur"] <= e["ts"] + e["dur"] + 1e-3
 
 
 def test_the_account_is_the_sum_of_the_matching_spans(traced):
@@ -414,8 +472,11 @@ class SlowDevice:
 
     def entry(self, fn, index: int):
         def call(*args):
-            args = tuple(a.real if isinstance(a, _Late) else a
-                         for a in args)
+            # (a prefill's ``merge`` operand holds the step in flight's
+            # tokens: unwrapped where it is nested too)
+            args = jax.tree_util.tree_map(
+                lambda a: a.real if isinstance(a, _Late) else a, args,
+                is_leaf=lambda a: isinstance(a, _Late))
             out = list(fn(*args))
             self.free_at = max(self.free_at, self.clock.ns) + self.step_ns
             out[index] = _Late(out[index], self.free_at, self.clock)
@@ -427,7 +488,7 @@ class SlowDevice:
         eng.spec.decode_entry = lambda *a: {
             "fn": self.entry(decode(*a)["fn"], 0)}       # the next tokens
         eng._prefill_entry = lambda bucket: {
-            "fn": self.entry(prefill(bucket)["fn"], 0)}  # the logits
+            "fn": self.entry(prefill(bucket)["fn"], 3)}  # the first tokens
 
 
 @pytest.fixture
@@ -484,12 +545,18 @@ def test_the_estimates_time_a_step_to_its_tokens_not_its_launch(slow_engine,
         st["decode_wait_ms"]
 
 
-def test_a_step_a_prefill_overtook_feeds_no_estimate(slow_engine):
-    """A prefill dispatched behind the step in flight is fetched before
+@pytest.mark.parametrize("late_kw", ({}, dict(seed=3, temperature=0.8)),
+                         ids=("fetched_in_order", "overtaken"))
+def test_a_step_a_prefill_overtook_feeds_no_estimate(slow_engine, late_kw):
+    """A prefill dispatched behind the step in flight. Its group all
+    greedy, that step is fetched first (then the prefill, then the step
+    behind it): every step's time on the device is observed and feeds
+    the estimate. A group with a sampled row is fetched at once, before
     that step: the step's tokens are there when the host comes, its time
     on the device was not observed (the account adds the bound, nearly
-    0), and the estimate does not take the sample. The prefill's own
-    sample holds its wait behind that step: what the next arrival pays."""
+    0), and the estimate does not take the sample. Either way the
+    prefill's own sample holds its wait behind that step: what the next
+    arrival pays."""
     eng = slow_engine(max_slots=2)
     samples, note = [], eng._note_tpot_ms
 
@@ -502,17 +569,56 @@ def test_a_step_a_prefill_overtook_feeds_no_estimate(slow_engine):
     eng.submit(first[0], max_new_tokens=first[1])
     for _ in range(4):
         eng.step()                  # a step is in flight when the next comes
-    eng.submit(late[0], max_new_tokens=late[1])
+    eng.submit(late[0], max_new_tokens=late[1], **late_kw)
     eng.run_until_idle()
     st = eng.stats()
     assert st["prefill_flights"] - before["prefill_flights"] == 2
-    # every decode step but the one the second prefill overtook
+    assert st["admit_ahead_dispatches"] - before["admit_ahead_dispatches"] \
+        == (1 if late_kw else 2)
+    # every decode step, but the one the host-drawn group overtook
     assert len(samples) == \
-        st["decode_flights"] - before["decode_flights"] - 1
+        st["decode_flights"] - before["decode_flights"] - bool(late_kw)
     assert min(samples) >= 15.0 and max(samples) <= 40.0, samples
     # the late prefill waited for the step ahead of it, then ran: ~40 ms
     # where the first took 20, and the EWMA stands between
     assert eng._prefill_cost_ms(8) == pytest.approx(26.0, abs=1.0)
+
+
+def test_a_prefill_flight_reads_what_the_synchronous_path_read(slow_engine):
+    """A late prompt behind a step in flight, its first token fetched
+    after the step behind it was dispatched (greedy) or at once, as every
+    group was before PR 48 (a sampled row: the host draws from the
+    logits). The bucket's SLO sample (dispatch to fetched) and the
+    request's TTFT read the same to within the host's time for a step
+    (here a few reads of a clock that costs 10 us a read); what the host
+    *waited* in the prefill's own fetch is the prompt's time on the
+    device alone, the rest of the step ahead of it having been waited for
+    in that step's fetch, where the synchronous fetch waited for both."""
+    reads = {}
+    for at_once in (False, True):
+        eng = slow_engine(
+            max_slots=2,
+            clock=lambda: profiler.time.perf_counter_ns() / 1e9)
+        first, late = _prompts(((4, 12), (6, 4)))
+        eng.submit(first[0], max_new_tokens=first[1])
+        for _ in range(4):
+            eng.step()
+        before = eng.stats()
+        eng.reset_cost_estimates()
+        req = eng.submit(late[0], max_new_tokens=late[1],
+                         **(dict(seed=3, temperature=0.8) if at_once else {}))
+        eng.run_until_idle()
+        st = {k: v - before[k] for k, v in eng.stats().items()
+              if k in _ACCOUNT_KEYS or k == "admit_ahead_dispatches"}
+        assert st["prefill_flights"] == 1
+        assert st["admit_ahead_dispatches"] == (not at_once)
+        reads[at_once] = (eng._prefill_cost_ms(8), req.ttft * 1e3,
+                          st["prefill_wait_ms"])
+    (cost, ttft, wait), (cost0, ttft0, wait0) = reads[False], reads[True]
+    assert cost == pytest.approx(cost0, abs=1.0) and 39.0 <= cost0 <= 41.0
+    assert ttft == pytest.approx(ttft0, abs=1.0) and ttft0 >= 39.0
+    assert wait == pytest.approx(20.0, abs=1.0)
+    assert wait0 == pytest.approx(40.0, abs=1.0)
 
 
 # ------------------------------------------- the metric files that read it
@@ -528,6 +634,8 @@ OBS = {
                  "engine.decode_device_ms": 39300,
                  "engine.ahead_dispatches": 2964,
                  "engine.sampler_dispatches": 3000,
+                 "engine.admit_ahead_dispatches": 540,
+                 "engine.prefill_flights": 545,
                  "engine.stalled_ms": 0.0, "window_s": 45.0},
     "trace": {},
 }
@@ -541,6 +649,8 @@ ACCOUNT = {
         ("counters.engine.decode_flights", 13.1),
     ("counters", "engine.ahead_dispatches", "value"):
         ("counters.engine.sampler_dispatches", 98.8),
+    ("counters", "engine.admit_ahead_dispatches", "value"):
+        ("counters.engine.prefill_flights", 54000 / 545),
     ("counters", "engine.stalled_ms", "value"): (None, 0.0),
     ("spans", "serving.flight", "p50"): (None, 26.5),
     ("spans", "serving.ttft", "p50"): (None, 44.0),
