@@ -10,6 +10,8 @@ from .mellum import MELLUM_CONFIGS, MellumConfig, MellumForCausalLM
 from .jamba import JAMBA_CONFIGS, JambaConfig, JambaForCausalLM
 from .lfm2 import LFM2_CONFIGS, Lfm2Config, Lfm2ForCausalLM
 from .keye import KEYE_CONFIGS, KeyeConfig, KeyeForCausalLM
+from .dotsvlm import (DOTSVLM_CONFIGS, DotsVlmConfig,
+                      DotsVlmForCausalLM)
 from . import generation
 from .generation import (beam_search, decode_step, decode_step_paged,
                          draft_ngram, greedy_search, sample,

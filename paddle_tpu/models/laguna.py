@@ -92,9 +92,12 @@ from ..profiler import RecordEvent
 
 _PERIOD = ("full_attention",) + ("sliding_attention",) * 3
 #: what a decode step's expert layers count on the device, summed over the
-#: layers (``LagunaMoE.served``): a served model's seam names a prefix of
-#: these as its ``counters``
+#: layers (``LagunaMoE.served``), unless the configuration names others
+#: (``LagunaConfig.expert_counters``, of :data:`EXPERT_COUNTS`)
 DECODE_COUNTERS = ("experts_touched", "expert_rows_max")
+#: what an expert layer can count of a decode step: the held experts some
+#: live row chose, the largest expert's rows, the (row, held expert) pairs
+EXPERT_COUNTS = DECODE_COUNTERS + ("expert_pairs",)
 #: what a decode step of a model with an indexer (``sa_config``) counts
 #: after ``experts_touched``, summed over its live rows and its layers by
 #: the selected read itself (``ops.attention_ops.sparse_decode_attention``):
@@ -160,6 +163,13 @@ class LagunaConfig:
     router_bias: bool = False
     router_bias_init_std: float = 0.0
     router_renorm_eps: float = 0.0
+    # the choice by groups: the experts in ``router_groups`` equal groups,
+    # of which the ``router_topk_groups`` best (by the sum of their 2
+    # largest selection scores) stay eligible (1 group: a plain top k)
+    router_groups: int = 1
+    router_topk_groups: int = 1
+    # what a decode step's expert layers count, by name (EXPERT_COUNTS)
+    expert_counters: Tuple[str, ...] = DECODE_COUNTERS
     # the parameters' dtype (and the served pools')
     dtype: str = "float32"
     # the std of the embedding's rows and of the routers' weights where a
@@ -210,12 +220,26 @@ class LagunaConfig:
             int(sa["topk"]))
 
     @property
+    def read_counters(self):
+        """What a decode row's read of its cache counts, by name: the
+        columns of the third result of the attention layer's served call
+        (:meth:`LagunaAttention._served`)."""
+        return SPARSE_COUNTERS if self.sa_config is not None else ()
+
+    @property
     def decode_counters(self):
         """What a decode step of this model counts on the device, in the
-        order of :meth:`LagunaModel.served`'s vector."""
-        if self.sa_config is None:
-            return DECODE_COUNTERS
-        return DECODE_COUNTERS[:1] + SPARSE_COUNTERS
+        order of :meth:`LagunaModel.served`'s vector: the expert layers'
+        counts (the first of them where an indexer counts beside it), then
+        the reads'."""
+        if self.sa_config is not None:
+            return self.expert_counters[:1] + SPARSE_COUNTERS
+        return self.expert_counters + self.read_counters
+
+    def attention(self, layer: int):
+        """The attention layer of ``layer`` (a family with another kind of
+        attention gives its own, with :class:`LagunaAttention`'s calls)."""
+        return LagunaAttention(self, layer)
 
     @staticmethod
     def _range(held, whole):
@@ -311,9 +335,9 @@ def _linear(n_in, n_out, std, dtype="float32"):
                   dtype=dtype)
 
 
-def no_counts():
+def no_counts(names=DECODE_COUNTERS):
     """What a layer with no experts, or a prompt's pass, counts."""
-    return jnp.zeros((len(DECODE_COUNTERS),), jnp.int32)
+    return jnp.zeros((len(names),), jnp.int32)
 
 
 def _rotate_half(x, rows, theta: float):
@@ -653,6 +677,9 @@ class LagunaMoE(Layer):
                  "score": cfg.router_score}
         if cfg.router_renorm_eps:
             attrs["renorm_eps"] = cfg.router_renorm_eps
+        if cfg.router_groups > 1:
+            attrs.update(n_group=cfg.router_groups,
+                         topk_group=cfg.router_topk_groups)
         return attrs
 
     def forward(self, u):
@@ -677,12 +704,12 @@ class LagunaMoE(Layer):
     def served(self, u, live=None):
         """The serving engine's call, on arrays: ``u`` float32 [b, s, h]
         (the norm's output; the router reads it as it is, the experts in
-        the parameters' dtype) -> (output float32 [b, s, h], int32 [2]:
-        the experts some live row chose and the largest expert's rows,
-        both counted where ``s`` is 1: :data:`DECODE_COUNTERS`). One row a
-        request (``live`` [b]: which are requests at all) takes the
-        few-rows form; a prompt takes the training path's,
-        ``moe_chunk_rows`` rows a pass."""
+        the parameters' dtype) -> (output float32 [b, s, h], int32
+        [len(cfg.expert_counters)]: those of :data:`EXPERT_COUNTS` the
+        configuration names, counted where ``s`` is 1). One row a request (``live`` [b]: which
+        are requests at all) takes the few-rows form over the experts this
+        share holds; a prompt takes the training path's, ``moe_chunk_rows``
+        rows a pass. Choices on experts that are not held add nothing."""
         cfg = self.cfg
         b, s, h = u.shape
         dt = jnp.dtype(cfg.dtype)
@@ -700,10 +727,19 @@ class LagunaMoE(Layer):
         flat = u.reshape(b * s, h)
         if s == 1:
             idx, weight = route(flat)
+            lo, hi = cfg.experts
+            held = None
+            if (lo, hi) != (0, cfg.num_experts):
+                # a share of the experts: only the choices among them count
+                idx = idx - lo
+                held = jnp.logical_and(idx >= 0, idx < hi - lo)
             out, rows = moe_experts_decode_counts(
                 flat.astype(dt), weight, idx, w13, w2, live,
-                min(cfg.moe_tile_m, DECODE_TILE_M))
-            counted = jnp.stack([jnp.sum(rows > 0), jnp.max(rows)]
+                min(cfg.moe_tile_m, DECODE_TILE_M), held=held)
+            counts = {"experts_touched": jnp.sum(rows > 0),
+                      "expert_rows_max": jnp.max(rows),
+                      "expert_pairs": jnp.sum(rows)}
+            counted = jnp.stack([counts[n] for n in cfg.expert_counters]
                                 ).astype(jnp.int32)
         else:
             def one(x):
@@ -716,7 +752,7 @@ class LagunaMoE(Layer):
                 out = jax.lax.map(one, flat.reshape(-1, chunk, h))
             else:
                 out = one(flat)
-            counted = no_counts()
+            counted = no_counts(cfg.expert_counters)
         out = out.reshape(b, s, h).astype(jnp.float32)
         if cfg.shared_expert_intermediate_size:
             sh = self.shared(Tensor(u.astype(dt), stop_gradient=True))
@@ -733,7 +769,7 @@ class LagunaBlock(Layer):
         self.sparse = cfg.mlp_layer_types[layer] == "sparse"
         self.attn_norm = RMSNorm(cfg.hidden_size, cfg.rms_norm_eps,
                                  cfg.dtype)
-        self.attn = LagunaAttention(cfg, layer)
+        self.attn = cfg.attention(layer)
         self.mlp_norm = RMSNorm(cfg.hidden_size, cfg.rms_norm_eps,
                                 cfg.dtype)
         if self.sparse:
@@ -763,7 +799,7 @@ class LagunaBlock(Layer):
         u = self.mlp_norm(x)
         if not self.sparse:
             y = self.mlp(_matmul_in(u, dt)).astype("float32")
-            return x + y, cache, no_counts(), reads
+            return x + y, cache, no_counts(self.cfg.expert_counters), reads
         y, counted = self.moe.served(u.value, live)
         return x + Tensor(y, stop_gradient=True), cache, counted, reads
 
@@ -826,10 +862,11 @@ class LagunaModel(Layer):
                last=None, collect=None):
         """The serving engine's call -> (the final norm's output float32
         [b, s, h], the caches, the expert layers' counts summed over the
-        layers: int32 [2], :data:`DECODE_COUNTERS`; with an indexer int32
-        [3], ``cfg.decode_counters``: the selected reads' counts of the
-        step's live rows after the experts touched). ``cache``: one (k, v)
-        pool pair a layer (with an indexer (k, v, its keys)).
+        layers: ``cfg.expert_counters``; where the reads count
+        too, ``cfg.decode_counters``: the reads' counts of the step's live
+        rows after the expert layers'). ``cache``: one (k, v) pool pair a
+        layer (with an indexer (k, v, its keys); whatever the layer's
+        attention keeps).
         ``block_tables``: one
         table a layer kind in ``cfg.cache_kinds()``'s order (the table
         itself where there is one kind). ``last`` [b]: each prompt's last
@@ -850,8 +887,9 @@ class LagunaModel(Layer):
                          else jnp.asarray(last, jnp.int32) + 1)
         # a slot with no request has no row yet: it routes nowhere
         live = pos > 0 if s == 1 else None
-        caches, touched = [], no_counts()
-        reads = jnp.zeros((b, 2), jnp.int32)
+        caches, touched = [], no_counts(cfg.expert_counters)
+        counted = cfg.read_counters
+        reads = jnp.zeros((b, len(counted)), jnp.int32)
         for i, blk in enumerate(self.layers):
             x, c, t, r = blk.served(x, cache[i], pos,
                                     table_of[cfg.layer_types[i]], ctx_len,
@@ -862,13 +900,15 @@ class LagunaModel(Layer):
                 reads = reads + r
             if collect is not None:
                 collect.append(x)
-        if cfg.indexer:
-            # what the selected reads counted, of a decode step's live rows
-            # (a prompt counts nothing; a step's sum is ~1e6, far inside
+        if counted:
+            # what the reads counted, of a decode step's live rows (a
+            # prompt counts nothing; a step's sum is ~1e6, far inside
             # int32)
             if live is not None:
                 reads = jnp.where(live[:, None], reads, 0)
-            touched = jnp.concatenate([touched[:1], jnp.sum(reads, axis=0)])
+            touched = jnp.concatenate(
+                [touched[:len(cfg.decode_counters) - len(counted)],
+                 jnp.sum(reads, axis=0)])
         return self.norm(x), caches, touched
 
 

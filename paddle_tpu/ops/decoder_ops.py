@@ -133,7 +133,12 @@ def _moe_router(ctx, ins, attrs):
     optional input ``Bias`` [experts] the choice is ``top_k(s + Bias)`` and
     the weights are the chosen experts' *unbiased* ``s``, renormalised: a
     selection bias steers the load and never the mixture. Attr
-    ``renorm_eps`` is added to the chosen scores' sum (0: none)."""
+    ``renorm_eps`` is added to the chosen scores' sum (0: none). Attrs
+    ``n_group`` / ``topk_group`` (the choice by groups): the experts are
+    ``n_group`` equal groups in index order, a group's score is the sum of
+    its 2 largest selection scores (``s + Bias``), only the ``topk_group``
+    best groups stay eligible and the ``top_k`` largest are taken inside
+    them (ties to the lower index, of groups and of experts)."""
     x, w = ins["X"][0], ins["W"][0]
     k = int(attrs["top_k"])
     score = {"sigmoid": jax.nn.sigmoid,
@@ -143,10 +148,22 @@ def _moe_router(ctx, ins, attrs):
         x.astype(jnp.float32), w.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST))
     bias = (ins.get("Bias") or [None])[0]
-    if bias is None:
+    groups = int(attrs.get("n_group", 1))
+    if bias is None and groups == 1:
         top, idx = jax.lax.top_k(scores, k)
     else:
-        _, idx = jax.lax.top_k(scores + bias.astype(jnp.float32), k)
+        chosen_by = scores if bias is None \
+            else scores + bias.astype(jnp.float32)
+        if groups > 1:
+            by_group = chosen_by.reshape(chosen_by.shape[:-1] + (groups, -1))
+            best = jnp.sum(jax.lax.top_k(by_group, 2)[0], axis=-1)
+            _, kept = jax.lax.top_k(best, int(attrs["topk_group"]))
+            eligible = jnp.any(
+                kept[..., None] == jnp.arange(groups, dtype=kept.dtype),
+                axis=-2)                                    # [.., groups]
+            chosen_by = jnp.where(eligible[..., None], by_group,
+                                  -jnp.inf).reshape(chosen_by.shape)
+        _, idx = jax.lax.top_k(chosen_by, k)
         top = jnp.take_along_axis(scores, idx, axis=-1)
     scaled = float(attrs.get("scale", 1.0)) * top
     total = jnp.sum(top, axis=-1, keepdims=True)
@@ -564,12 +581,14 @@ DECODE_TILE_M = 16
 
 
 def moe_experts_decode_counts(x, weight, idx, w13, w2, live=None,
-                              tm: int = DECODE_TILE_M):
+                              tm: int = DECODE_TILE_M, held=None):
     """The expert layer at a few rows (a decode step: one row a request),
-    forward only, every expert held. x [t, h], weight / idx [t, k], w13
-    [e, h, 2f], w2 [e, f, h], ``live`` bool [t] (rows of empty slots route
-    nowhere) -> (out float32 [t, h], the live rows every expert got, int32
-    [e]).
+    forward only. x [t, h], weight / idx [t, k] (``idx`` counted from the
+    first held expert), w13 [e, h, 2f], w2 [e, f, h], ``live`` bool [t]
+    (rows of empty slots route nowhere), ``held`` bool [t, k] (where a
+    share of the experts is held: which choices fell among them; the
+    others add nothing) -> (out float32 [t, h], the live rows every held
+    expert got, int32 [e]).
 
     Where training has thousands of rows a call, tiles of 128 rows and is
     bound by compute, a step of 16 rows x 8 choices touches most of the
@@ -586,6 +605,8 @@ def moe_experts_decode_counts(x, weight, idx, w13, w2, live=None,
     tiles = -(-pairs // tm) + min(pairs, groups)
     valid = jnp.ones((t, k), bool) if live is None \
         else jnp.broadcast_to(live[:, None], (t, k))
+    if held is not None:
+        valid = jnp.logical_and(valid, held)
     route = _route(idx, valid, groups, tm, tiles, min_tiles=0)
     out, _ = _set_fwd(x, weight, route, valid, w13, w2, tm,
                       names=("moe_up_dec", "moe_down_dec"))

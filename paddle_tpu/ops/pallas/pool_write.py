@@ -38,17 +38,20 @@ _BLOCK_BYTES = 1 << 20
 
 
 def _merge(phys_ref, chunk_ref, first_ref, mine_ref, pool_ref, out_ref, *,
-           s):
+           s, lanes=False):
     del phys_ref, chunk_ref
     c, d = out_ref.shape[-2:]
+    # a pool with its tokens along the last axis (``lanes``) takes the
+    # request's by column instead of by row
     t = first_ref[pl.program_id(0)] \
-        + jax.lax.broadcasted_iota(jnp.int32, (c, d), 0)
+        + jax.lax.broadcasted_iota(jnp.int32, (c, d), 1 if lanes else 0)
     lands = jnp.logical_and(t >= 0, t < s)
     out_ref[...] = jnp.where(lands[None], mine_ref[...], pool_ref[...])
 
 
-@functools.partial(jax.jit, static_argnames=("s", "interpret"))
-def _chunk_write(pool, mine, phys, chunk, first, *, s, interpret):
+@functools.partial(jax.jit, static_argnames=("s", "interpret", "lanes"))
+def _chunk_write(pool, mine, phys, chunk, first, *, s, interpret,
+                 lanes=False):
     n, h, c, d = mine.shape
     hb = h
     while hb > 1 and (hb * c * d * pool.dtype.itemsize > _BLOCK_BYTES
@@ -60,7 +63,7 @@ def _chunk_write(pool, mine, phys, chunk, first, *, s, interpret):
         (None, hb, c, d),
         lambda k, j, phys, chunk, first: (phys[k], j, chunk[k], 0))
     return pl.pallas_call(
-        functools.partial(_merge, s=s),
+        functools.partial(_merge, s=s, lanes=lanes),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3, grid=(n, h // hb),
             in_specs=[at_mine, at_pool], out_specs=at_pool),
@@ -81,14 +84,18 @@ def _chunk_write(pool, mine, phys, chunk, first, *, s, interpret):
     )(phys, chunk, first, mine, pool)
 
 
-def pool_chunk_write(pool, mine, phys, chunk, first, s: int):
+def pool_chunk_write(pool, mine, phys, chunk, first, s: int,
+                     lanes: bool = False):
     """``pool`` [blocks, h, bs, d] with, for each work item ``k``, rows
     ``r`` of its chunk (``c`` rows from row ``chunk[k] * c`` of block
     ``phys[k]``) replaced by ``mine[k, :, r]`` where ``0 <= first[k] + r <
-    s`` -> the pool, updated in place when the caller donates it. Under
-    :func:`~.utils.kernel_sharding` each chip writes its own heads."""
+    s`` -> the pool, updated in place when the caller donates it. With
+    ``lanes`` the pool is ``[blocks, h, d, bs]``, a token a lane, and a
+    chunk is a whole block: column ``r`` is replaced by ``mine[k, :, :,
+    r]``. Under :func:`~.utils.kernel_sharding` each chip writes its own
+    heads."""
     def local(pool, mine, phys, chunk, first):
         return _chunk_write(pool, mine, phys, chunk, first, s=int(s),
-                            interpret=bool(_interpret()))
+                            interpret=bool(_interpret()), lanes=bool(lanes))
     return shard_parallel(local, ("-h--", "-h--", "-", "-", "-"),
                           ("-h--",))(pool, mine, phys, chunk, first)

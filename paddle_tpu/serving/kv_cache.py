@@ -32,13 +32,16 @@ hands the steps one pool pair a *model* layer, in the model's order, the
 tables go as one array a kind, and ``allocator`` / ``blocks_free`` /
 ``blocks_used`` answer for all kinds together.
 
-More than K and V a token. The kind that keeps every row may keep further
-arrays a token beside the pair (``seam.CacheKind.extra``: a learned sparse
-attention's indexer keys): one more array a layer, ``[num_blocks, width,
-block_size]`` with no head axis, after (k, v) in the layer's tuple
-(:class:`BlockPool`). It has no table and no allocator of its own: a block
-id means the same rows in all three, so admission, release, the trash
-block, donation, ``set_arrays`` and ``rebuild`` carry it with the pair.
+More than K and V a token, or something else. The kind that keeps every
+row may keep further arrays a token beside the pair
+(``seam.CacheKind.extra``: a learned sparse attention's indexer keys): one
+more array a layer, ``[num_blocks, width, block_size]`` with no head axis,
+after (k, v) in the layer's tuple (:class:`BlockPool`). It has no table and
+no allocator of its own: a block id means the same rows in all three, so
+admission, release, the trash block, donation, ``set_arrays`` and
+``rebuild`` carry it with the pair. A kind with NO KV heads keeps no pair at
+all (a latent-attention layer's one vector a token): its layers' tuples
+are the ``extra`` arrays alone, under the same table and allocator.
 
 Recurrent state. A kind of layer that keeps a fixed-size record a request
 whatever its context (``seam.StateKind``) has no blocks at all
@@ -241,8 +244,14 @@ class BlockPool:
         # what a token keeps beside K and V (``seam.CacheKind.extra``):
         # ``(name, width)`` -> one more array a layer, ``[blocks, width,
         # block_size]``, after the pair; every method here that walks a
-        # layer's tuple (COW, adoption, rebuild) takes them along
+        # layer's tuple (COW, adoption, rebuild) takes them along. A kind
+        # with no KV heads has NO pair (a latent-attention layer's one
+        # vector a token): its layers' tuples are these arrays alone
         self.extra = tuple((str(n), int(w)) for n, w in extra)
+        self.pair = 2 if self.num_heads else 0
+        if not self.pair and not self.extra:
+            raise ValueError("a cache kind with no KV heads keeps at least "
+                             "one array a token (CacheKind.extra)")
         if self.extra and kv_dtype == "int8":
             raise ValueError("an int8 pool keeps (k, v, scales) only: a "
                              "kind with extra arrays has a float pool")
@@ -259,7 +268,7 @@ class BlockPool:
                 for _ in range(num_layers)]
         else:
             self.layers = [
-                (jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
+                tuple(jnp.zeros(shape, dtype) for _ in range(self.pair))
                 + tuple(jnp.zeros((self.num_blocks, w, self.block_size),
                                   dtype) for _, w in self.extra)
                 for _ in range(num_layers)]
@@ -277,7 +286,8 @@ class BlockPool:
     def extra_bytes(self) -> Dict[str, int]:
         """Bytes each extra array takes over all layers, by name, as the
         device holds them."""
-        return {name: sum(int(layer[2 + i].nbytes) for layer in self.layers)
+        return {name: sum(int(layer[self.pair + i].nbytes)
+                          for layer in self.layers)
                 for i, (name, _) in enumerate(self.extra)}
 
     def alloc_block(self) -> Optional[int]:

@@ -15,7 +15,11 @@ from one :class:`ServedModel`, which the model builds
   axis; a learned sparse attention's indexer keys are one): each is one
   more pool a layer, ``[blocks, width, block_size]``, after the (k, v)
   pair in the layer's tuple, under the SAME block table and allocator, so
-  it is allocated, freed, donated, rebound and zeroed with them;
+  it is allocated, freed, donated, rebound and zeroed with them. A kind
+  with ``kv_heads`` 0 has NO pair: its token's row is the ``extra`` arrays
+  alone (a latent-attention layer keeps ONE vector a token for all its
+  heads, the normed latent and the rotated key, ``models/dotsvlm.py``), in
+  the one pool under the one table and allocator like any other;
 - its recurrent state, one :class:`StateKind` a kind of layer that carries
   a fixed-size record from token to token whatever the context: which
   layers, and the shape and dtype of each array a request keeps, one array
@@ -49,7 +53,8 @@ from one :class:`ServedModel`, which the model builds
 
 A model may declare blocks, a recurrent kind, experts' device counters and
 any number of rows at once (``models/lfm2.py`` does: 128 rows a decode
-step), or blocks whose token keeps a third array (``models/keye.py``);
+step), blocks whose token keeps a third array (``models/keye.py``), or
+blocks whose token keeps one vector and no K and V (``models/dotsvlm.py``);
 nothing in the engine or the cache manager is sized by a model's name or
 by another model's arrays.
 
@@ -89,7 +94,9 @@ class CacheKind:
     block_size]`` of the pools' dtype after them: ``width`` values a token
     with no head axis, a token a lane (a width under 128 then pads
     nothing on the chip), in the same blocks as the token's K and V. Only
-    the kind that keeps every row may have them, and only a float pool."""
+    the kind that keeps every row may have them, and only a float pool.
+    ``kv_heads`` 0 (with ``head_dim`` 0) is a kind with no pair: a layer's
+    tuple is the ``extra`` arrays alone, the first of them first."""
     name: str
     layers: Tuple[int, ...]     # the model's layer indices, ascending
     kv_heads: int
@@ -196,8 +203,8 @@ def served(model) -> ServedModel:
             f"{type(model).__name__} is not a served model: the serving "
             f"plane reads a model through model.serving_spec() -> "
             f"paddle_tpu.serving.seam.ServedModel (GPTForCausalLM, "
-            f"MellumForCausalLM, JambaForCausalLM, Lfm2ForCausalLM and "
-            f"KeyeForCausalLM have one)")
+            f"MellumForCausalLM, JambaForCausalLM, Lfm2ForCausalLM, "
+            f"KeyeForCausalLM and DotsVlmForCausalLM have one)")
     return spec()
 
 

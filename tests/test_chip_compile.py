@@ -38,6 +38,7 @@ pa = importlib.import_module("paddle_tpu.ops.pallas.paged_attention")
 gm = importlib.import_module("paddle_tpu.ops.pallas.grouped_matmul")
 ss = importlib.import_module("paddle_tpu.ops.pallas.selective_scan")
 pw = importlib.import_module("paddle_tpu.ops.pallas.pool_write")
+ml = importlib.import_module("paddle_tpu.ops.pallas.mla_attention")
 
 KERNEL = chip_smoke.KERNEL      # a Mosaic kernel in a compiled program
 
@@ -78,6 +79,7 @@ def mosaic(monkeypatch):
     monkeypatch.setattr(gm, "_interpret", lambda: False)
     monkeypatch.setattr(ss, "_interpret", lambda: False)
     monkeypatch.setattr(pw, "_interpret", lambda: False)
+    monkeypatch.setattr(ml, "_interpret", lambda: False)
     with jax.enable_x64(False):
         yield
 
@@ -384,6 +386,70 @@ def _kernel_shapes(text):
     """Operand and result shapes of the program's Mosaic kernels."""
     return [s for res, ops in chip_smoke.tpu_custom_calls(text)
             for s in res + ops]
+
+
+def test_the_absorbed_latent_read_compiles_for_v5e(one_chip, mosaic):
+    """dotsvlm_docs_16k's decode read: 32 rows, 128 heads over ONE row of
+    512 + 64 values a token, a pool of 2177 blocks of [576, 256] (its
+    tokens along the lanes: the 576 pad nothing) under a table of 68
+    entries: one ``mla_decode_attn``, and no array of the gathered table's
+    shape."""
+    def struct(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def read(q_lat, q_rope, pool, tables, pos):
+        return ml.mla_paged_attention(q_lat, q_rope, pool, tables, pos,
+                                      scale=0.135)
+    text = jax.jit(read).lower(
+        struct((32, 128, 512)), struct((32, 128, 64)),
+        struct((2177, 576, 256)), struct((32, 68), jnp.int32),
+        struct((32,), jnp.int32)).compile().as_text()
+    assert len(re.findall(r"%mla_decode_attn\S* = ", text)) == 1
+    assert text.count(KERNEL) == 1
+    assert "bf16[32,68,576,256]" not in text
+
+
+@pytest.mark.parametrize("bucket", [6144, 16384])
+def test_the_materialised_latent_read_compiles_for_v5e(one_chip, mosaic,
+                                                       bucket):
+    """dotsvlm_docs_16k's prompt read at its shortest and longest bucket:
+    a pass of 32 heads, a key of 128 + 64 (the 64 ONE array for all
+    heads) beside a value of 128, K and V streamed by tile: 16384 rows
+    compile (the whole-sequence flash forward is refused there for
+    VMEM)."""
+    def struct(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def read(q_n, q_r, k_n, k_r, v, live):
+        return ml.mla_prompt_attention(q_n, q_r, k_n, k_r, v, scale=0.135,
+                                       live=live)
+    text = jax.jit(read).lower(
+        struct((1, 32, bucket, 128)), struct((1, 32, bucket, 64)),
+        struct((1, 32, bucket, 128)), struct((1, bucket, 64)),
+        struct((1, 32, bucket, 128)), struct((1,), jnp.int32)
+    ).compile().as_text()
+    assert len(re.findall(r"%mla_prompt_attn\S* = ", text)) == 1
+
+
+@pytest.mark.parametrize("rows", [1, 16384], ids=["decode", "prompt"])
+def test_the_latent_pool_write_compiles_for_v5e_in_place(one_chip, mosaic,
+                                                         rows):
+    """A prompt's 16384 rows go into the donated latent pool through the
+    chunk-write kernel (its tokens along the lanes), a decode step's 32
+    rows as in-place columns: neither copies the pool."""
+    from paddle_tpu.ops.attention_ops import latent_pool_write
+    b = 32 if rows == 1 else 1
+
+    def struct(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    text = jax.jit(latent_pool_write, donate_argnums=(0,)).lower(
+        struct((2177, 576, 256)), struct((b, rows, 576)),
+        struct((b,), jnp.int32), struct((b, 68), jnp.int32)
+    ).compile().as_text()
+    assert text.count(KERNEL) == (0 if rows == 1 else 1)
+    assert not _pool_copies(text, "bf16[2177,576,256]")
+    assert "bf16[2177,1,576,256]" not in text or \
+        not _pool_copies(text, "bf16[2177,1,576,256]")
 
 
 def test_flash_attention_runs_on_the_batch_shard_of_each_chip(

@@ -818,3 +818,100 @@ def test_block_attention_equals_the_gathered_reference(s, pos):
     ref = paged_attention_reference(q, k_pool, v_pool, tables, posv)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)
+
+
+# ------------------------------------------- the latent pool's two reads
+# (ops/pallas/mla_attention.py: one vector a token for all the heads, a
+# token a lane, no head axis)
+
+MLA_DECODE = {
+    # (block size, table entries, positions, heads, rank, rope, dtype)
+    "ragged_b8": (8, 6, [0, 13, 47, 7], 4, 32, 8, jnp.float32),
+    "one_block_b16": (16, 2, [3, 15, 16], 2, 16, 8, jnp.float32),
+    # five live blocks walk two chunks of four, the last one short
+    "two_chunks_b8": (8, 9, [39, 64, 1], 3, 24, 8, jnp.float32),
+    "bfloat16_b8": (8, 6, [5, 30, 47], 4, 32, 16, jnp.bfloat16),
+    "wide_b256": (256, 3, [700, 255, 256], 8, 128, 64, jnp.float32),
+}
+
+
+def latent_decode_reference(q_lat, q_rope, pool, tables, pos, *, scale):
+    """What ``ops.pallas.mla_attention.mla_paged_attention`` computes, by a
+    gather of the tables' blocks: ``q_lat`` [b, H, r], ``q_rope`` [b, H,
+    dr], ``pool`` [blocks, r + dr, bs] -> float32 [b, H, r], each head's
+    softmax over the keys ``0 .. pos`` of ``(q_lat . c + q_rope . k_r)
+    scale`` times the latents ``c``."""
+    r = q_lat.shape[-1]
+    g = pool[jnp.asarray(tables, jnp.int32)].astype(jnp.float32)
+    b, T, w, bs = g.shape
+    g = g.transpose(0, 1, 3, 2).reshape(b, T * bs, w)      # [b, keys, r + dr]
+    q = jnp.concatenate([q_lat, q_rope], axis=-1).astype(jnp.float32)
+    lg = jnp.einsum("bhw,bkw->bhk", q, g,
+                    precision=jax.lax.Precision.HIGHEST) * scale
+    seen = jnp.arange(T * bs)[None, None, :] <= jnp.asarray(pos)[:, None, None]
+    p = jax.nn.softmax(jnp.where(seen, lg, -jnp.inf), axis=-1)
+    return jnp.einsum("bhk,bkr->bhr", p, g[..., :r],
+                      precision=jax.lax.Precision.HIGHEST)
+
+
+@pytest.mark.parametrize("case", sorted(MLA_DECODE))
+def test_the_absorbed_latent_read_equals_the_gathered_reference(case):
+    """``mla_decode_attn`` under the interpreter against a gather of the
+    tables' blocks: ragged lengths, a dead slot (position 0), a walk of
+    several chunks, tables whose blocks are scattered, the trash block
+    (poisoned) behind every live one."""
+    from paddle_tpu.ops.pallas.mla_attention import mla_paged_attention
+    bs, T, pos, heads, r, dr, dtype = MLA_DECODE[case]
+    rng = np.random.RandomState(len(case))
+    tables, nb = _tables_for(pos, 1, bs, T)
+    pool = jnp.asarray(rng.randn(nb, r + dr, bs), jnp.float32)
+    pool = pool.at[0].set(100.0).astype(dtype)
+    q_lat = jnp.asarray(rng.randn(len(pos), heads, r), jnp.float32)
+    q_rope = jnp.asarray(rng.randn(len(pos), heads, dr), jnp.float32)
+    posv = jnp.asarray(pos, jnp.int32)
+    out = mla_paged_attention(q_lat, q_rope, pool, tables, posv, scale=0.21)
+    # the kernel multiplies the scaled query in the pool's type
+    ref = latent_decode_reference(
+        (q_lat * 0.21).astype(dtype), (q_rope * 0.21).astype(dtype), pool,
+        tables, posv, scale=1.0)
+    tol = 1e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=tol,
+                               atol=tol)
+
+
+@pytest.mark.parametrize("s,live,tiles", [(64, [64, 21], (16, 8)),
+                                          (48, [1, 48], (16, 16)),
+                                          (32, [32, 9], (32, 8))])
+def test_the_materialised_latent_read_equals_a_masked_softmax(
+        monkeypatch, s, live, tiles):
+    """``mla_prompt_attn`` under the interpreter: a key of two parts (a
+    head's own and the ONE rotated key of all heads) beside a value of
+    another width, causal by tiles; rows of query blocks past a prompt's
+    live rows come out zero and its live rows are untouched by the
+    padding."""
+    from paddle_tpu.ops.pallas import mla_attention as M
+    monkeypatch.setattr(M, "PROMPT_BLOCK_Q", tiles[0])
+    monkeypatch.setattr(M, "PROMPT_BLOCK_K", tiles[1])
+    rng = np.random.RandomState(s)
+    b, heads, dn, dr, dv = 2, 3, 16, 8, 24
+    q_n, k_n = (jnp.asarray(rng.randn(b, heads, s, dn), jnp.float32)
+                for _ in range(2))
+    q_r = jnp.asarray(rng.randn(b, heads, s, dr), jnp.float32)
+    k_r = jnp.asarray(rng.randn(b, s, dr), jnp.float32)
+    v = jnp.asarray(rng.randn(b, heads, s, dv), jnp.float32)
+    out = np.asarray(M.mla_prompt_attention(
+        q_n, q_r, k_n, k_r, v, scale=0.3, live=jnp.asarray(live, jnp.int32)))
+    lg = (jnp.einsum("bhqd,bhkd->bhqk", q_n, k_n)
+          + jnp.einsum("bhqd,bkd->bhqk", q_r, k_r)) * 0.3
+    seen = jnp.tril(jnp.ones((s, s), bool))
+    ref = np.asarray(jnp.einsum(
+        "bhqk,bhkd->bhqd",
+        jax.nn.softmax(jnp.where(seen, lg, -jnp.inf), -1), v))
+    for i, n in enumerate(live):
+        np.testing.assert_allclose(out[i, :, :n], ref[i, :, :n], rtol=1e-5,
+                                   atol=1e-5)
+        skipped = -(-n // tiles[0]) * tiles[0]
+        assert not out[i, :, skipped:].any()
+    # counted from the live triangle, never the tiles the kernel ran
+    assert M.prompt_pairs(1, s, live[0]) == live[0] * (live[0] + 1) // 2
+    assert M.prompt_pairs(2, s, s + 5) == s * (s + 1)

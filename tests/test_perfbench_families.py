@@ -21,17 +21,21 @@ from perfbench import families, flops, readers, run
 CONFIGS = {"cgpt-1p3b": "gpt2", "cgpt-1p3b-d20": "gpt2",
            "laguna-xs2-share8": "laguna", "mellum2-12b-d8": "mellum",
            "jamba2-3b": "jamba", "lfm2-24b-a2b-d9": "lfm2",
-           "keye-vl2-30b-a3b-stage0": "keye"}
+           "keye-vl2-30b-a3b-stage0": "keye",
+           "dots-vlm1-share32-d6": "dotsvlm"}
 JOBS = {"gpt2": "pretrain_1chip", "laguna": "laguna_pretrain_8k",
         "mellum": "mellum_code_16k", "jamba": "jamba_reasoning_6k",
-        "lfm2": "lfm2_agents_3k", "keye": "keye_longdoc_24k"}
+        "lfm2": "lfm2_agents_3k", "keye": "keye_longdoc_24k",
+        "dotsvlm": "dotsvlm_docs_16k"}
 FAMILY_CONFIG = {"gpt2": "cgpt-1p3b-d20", "laguna": "laguna-xs2-share8",
                  "mellum": "mellum2-12b-d8", "jamba": "jamba2-3b",
                  "lfm2": "lfm2-24b-a2b-d9",
-                 "keye": "keye-vl2-30b-a3b-stage0"}
+                 "keye": "keye-vl2-30b-a3b-stage0",
+                 "dotsvlm": "dots-vlm1-share32-d6"}
 RATE = {"gpt2": "train_tok_s_chip", "laguna": "train_tok_s_chip",
         "mellum": "serve_tok_s", "jamba": "serve_tok_s",
-        "lfm2": "serve_tok_s", "keye": "serve_tok_s"}
+        "lfm2": "serve_tok_s", "keye": "serve_tok_s",
+        "dotsvlm": "serve_tok_s"}
 
 
 def config(name):
@@ -42,8 +46,8 @@ def test_an_unknown_family_is_an_error_that_lists_the_known_ones():
     with pytest.raises(SystemExit) as e:
         families.load({"name": "some-model", "family": "no_such_family"})
     assert "no_such_family" in str(e.value)
-    assert families.known() == ["gpt2", "jamba", "keye", "laguna", "lfm2",
-                                "mellum"]
+    assert families.known() == ["dotsvlm", "gpt2", "jamba", "keye", "laguna",
+                                "lfm2", "mellum"]
     assert all(name in str(e.value) for name in families.known())
 
 
@@ -415,6 +419,101 @@ def test_any_8_requests_of_keye_longdoc_24k_fit_the_pool():
     assert e["prefix_cache"] is False
 
 
+def test_the_dotsvlm_file_holds_the_published_widths_uncut():
+    """Every key of the catalog's ``config``, ``rope_scaling`` whole, but
+    the five in ``reduced`` equals the file's; the share and the bytes are
+    what ISSUE 49 reckoned."""
+    cfg = config("dots-vlm1-share32-d6")
+    catalog = "/opt/skills/guides/model-configs/architectures.jsonl"
+    reduced = ["num_hidden_layers", "first_k_dense_replace",
+               "n_routed_experts", "vocab_size", "max_position_embeddings"]
+    if os.path.exists(catalog):     # the catalog's row, where there is one
+        with open(catalog) as f:
+            row = next(r for r in map(json.loads, f)
+                       if r["name"] == "dots.vlm1.inst")
+        changed = {k for k, v in row["config"].items() if cfg.get(k, 0) != v}
+        assert changed == set(reduced)
+        assert cfg["source"].startswith(row["source_url"])
+        entry = next(c for c in BENCH["configs"]
+                     if c["name"] == cfg["name"])
+        assert entry["source"] == row["source_url"]
+        assert entry["reduced"] == reduced
+    assert cfg["reduced"] == list(cfg["reduced_why"]) == reduced
+    assert [cfg["published"][k] for k in reduced] == \
+        [61, 3, 256, 129280, 163840]
+    assert (cfg["hidden_size"], cfg["q_lora_rank"], cfg["kv_lora_rank"],
+            cfg["qk_nope_head_dim"], cfg["qk_rope_head_dim"],
+            cfg["v_head_dim"], cfg["num_attention_heads"],
+            cfg["intermediate_size"], cfg["moe_intermediate_size"],
+            cfg["n_group"], cfg["topk_group"], cfg["num_experts_per_tok"],
+            cfg["routed_scaling_factor"]) == \
+        (7168, 1536, 512, 128, 64, 128, 128, 18432, 2048, 8, 4, 8, 2.5)
+    assert cfg["rope_scaling"] == {
+        "beta_fast": 32, "beta_slow": 1, "factor": 40, "mscale": 1,
+        "mscale_all_dim": 1, "original_max_position_embeddings": 4096,
+        "type": "yarn"}
+    # the share: 8 of 256 experts (the guide's floor), 1/8 of the rows,
+    # one dense layer and five expert layers (a period and four)
+    family = families.load(cfg)
+    assert (cfg["n_routed_experts"], cfg["expert_share"],
+            family.router_width(cfg)) == (8, 32, 256)
+    assert cfg["vocab_size"] * cfg["vocab_share"] == 129280
+    assert (cfg["num_hidden_layers"], cfg["first_k_dense_replace"]) == (6, 1)
+    assert all(cfg.get(k) for k in ("assumed", "published", "deployment",
+                                    "engine_why"))
+    assert {"rotary", "norm_placement", "dtype", "weights", "embed_init_std",
+            "router_bias_init_std", "router", "held_experts", "not_built"} \
+        <= set(cfg["assumed"])
+    mc = family.model_config(cfg)
+    attention = 7168 * 1536 + 1536 + 1536 * 128 * 192 + 7168 * 576 + 512 \
+        + 512 * 128 * 256 + 128 * 128 * 7168
+    expert = 3 * 7168 * 2048
+    sparse = attention + 2 * 7168 + 7168 * 256 + 256 + 9 * expert
+    dense = attention + 2 * 7168 + 3 * 7168 * 18432
+    assert (attention, expert) == (187_107_328, 44_040_192)
+    assert mc.num_params() == cfg["params_held"] \
+        == dense + 5 * sparse + 2 * 16160 * 7168 + 7168 == 3_741_753_600
+    assert (mc.experts, mc.vocab, mc.num_experts, mc.vocab_size) == \
+        ((0, 8), (0, 16160), 256, 129280)
+    assert (mc.router_score, mc.router_bias, mc.router_groups,
+            mc.router_topk_groups, mc.attention_gate, mc.dtype) == \
+        ("sigmoid", True, 8, 4, False, "bfloat16")
+    assert mc.mlp_layer_types == ("dense",) + ("sparse",) * 5
+    # the deployment's bytes: ONE array a layer, a token a lane
+    e = cfg["engine"]
+    assert e["num_blocks"] * 576 * e["block_size"] * 2 * 6 == 3_852_140_544
+
+
+def test_any_32_requests_of_dotsvlm_docs_16k_fit_the_pool():
+    """The largest pair 32 times over fits the pool, every prompt reaches
+    a bucket, and every bucket is whole passes of the expert layer and
+    whole query tiles of the prompt's read."""
+    from paddle_tpu.models.dotsvlm import PROMPT_CHUNK_ROWS
+    from paddle_tpu.ops.pallas.mla_attention import PROMPT_BLOCK_Q
+    from perfbench import traffic as T
+    cfg, tr = config("dots-vlm1-share32-d6"), load(
+        "traffic", "dotsvlm_docs_16k.json")
+    e = cfg["engine"]
+    pairs = T.multiset(tr)
+    assert len(pairs) == 16 and e["max_slots"] == 32
+    assert tr["queue_depth_slots"] == 1 and tr["preroll_completions"] == 32
+    assert tr["multiset"]["pairing_seed"] == 49
+    assert (min(p for p, _ in pairs), max(p for p, _ in pairs),
+            min(a for _, a in pairs), max(a for _, a in pairs)) == \
+        (4277, 15689, 267, 981)
+    assert all(p + a <= tr["multiset"]["max_total"] == e["max_len"] == 17408
+               for p, a in pairs)
+    need = max(-(-(p + a) // e["block_size"]) for p, a in pairs)
+    assert need <= 68 and 32 * 68 == e["num_blocks"] - 1
+    assert e["max_len"] == 68 * e["block_size"] \
+        == cfg["max_position_embeddings"]
+    assert T.buckets_used(tr, e["buckets"]) == e["buckets"] \
+        == [6144, 8192, 12288, 16384]
+    assert all(b % PROMPT_CHUNK_ROWS == 0 and b % PROMPT_BLOCK_Q == 0
+               for b in e["buckets"])
+    assert e["prefix_cache"] is False
+
+
 # per layer 8*2048^2 + 4*2048*8192 + 2*1024*2048 = 104,857,600; head
 # 2*2048*50304 = 206,045,184; x3 for the backward.
 # Laguna share, forward a token at s 8192: a window layer's projections
@@ -660,6 +759,99 @@ def test_keye_counts_its_selected_read_from_the_runs_live_blocks():
         6 * 117 * got[1] / flops.peaks("TPU v5 lite")["hbm_bytes_per_s"])
 
 
+# dots.vlm1's share: a prompt's pass of 2048 rows x 8 choices x 8 held of
+# 256 = 512 rows expected over the held stack of 8 (7168 -> 2 x 2048 ->
+# 7168); the decode read without a run's counters one block a slot: 32
+# blocks of 256 rows, every head's score over 576 values and its weighted
+# sum of 512, against the block's 576 values at 2 bytes: 242 FLOP a byte
+TOUCHED_DOTS = 8 * (1 - (7 / 8) ** 8)
+
+
+def _pairs_dots():
+    """The multiset's 16 prompts: each one's live causal triangle, n (n +
+    1) / 2 pairs a head, by hand; neither its bucket's rectangle nor the
+    tiles of 1024 x 1024 the kernel runs at or under the diagonal."""
+    lengths = [round(4096 * 4 ** ((i + 0.5) / 16)) for i in range(16)]
+    total = 0
+    for n in lengths:
+        assert 4096 < n <= 16384
+        total += n * (n + 1) // 2
+    return total / 16.0
+
+
+PAIRS_DOTS = _pairs_dots()
+KERNELS.update({
+    ("dotsvlm", "moe_up"): (2.0 * 512 * 7168 * 4096,
+                            2.0 * (512 * (7168 + 4096) + 8 * 7168 * 4096)),
+    ("dotsvlm", "moe_down"): (2.0 * 512 * 2048 * 7168,
+                              2.0 * (512 * (2048 + 7168) + 8 * 2048 * 7168)),
+    ("dotsvlm", "mla_decode_attn"): (2.0 * 128 * (512 + 64 + 512) * 256 * 32,
+                                     576.0 * 2 * 256 * 32),
+    # 32 rows x 8 x 8 / 256 = 8 pairs over 8 x (1 - (7/8)^8) experts
+    ("dotsvlm", "moe_up_dec"): (2.0 * 8 * 7168 * 4096,
+                                2.0 * (8 * (7168 + 4096)
+                                       + TOUCHED_DOTS * 7168 * 4096)),
+    ("dotsvlm", "moe_down_dec"): (2.0 * 8 * 2048 * 7168,
+                                  2.0 * (8 * (2048 + 7168)
+                                         + TOUCHED_DOTS * 2048 * 7168)),
+    # a pass of 32 heads over the multiset's mean live pairs
+    ("dotsvlm", "mla_prompt_attn"): (
+        2.0 * 32 * 320 * PAIRS_DOTS,
+        32 * (2.0 * PAIRS_DOTS) ** 0.5 * 576 * 2.0),
+})
+
+
+def test_dotsvlm_counts_its_latent_reads_from_the_runs_counters():
+    """A decode step's read by hand at a toy count: 3 live blocks a
+    flight: 3 x 256 keys x 128 heads x (576 + 512) x 2 FLOP against 3 x
+    256 x 576 x 2 B, 241.8 FLOP a byte: at the chip's speed the floor is
+    the products' (the v5e's ridge is 240.5) and a kernel that ran at it
+    reads 100, never more. A prompt's read from its live pairs, not the
+    bucket's rectangle; the decode products from the pairs and experts a
+    step a sparse layer."""
+    cfg, job = config("dots-vlm1-share32-d6"), load(
+        "traffic", "dotsvlm_docs_16k.json")
+    counts = families.load(cfg).kernel_counts
+    counters = {"engine.kv_blocks_live.traced": 117 * 3.0,
+                "engine.decode_flights.traced": 117.0, "kv_item_bytes": 2}
+    got = counts("mla_decode_attn", cfg, job, counters=counters)
+    assert got == (2.0 * 128 * 1088 * 768, 576.0 * 2 * 768)
+    assert got == (213_909_504.0, 884_736.0)
+    assert got[0] / got[1] == pytest.approx(241.78, abs=0.01)
+    assert counts("mla_decode_attn", cfg, job, counters={}) is None
+    peak = flops.peaks("TPU v5 lite")
+    floor = flops.kernel_floors(
+        {"kernel_calls.mla_decode_attn": 6.0 * 117}, lambda n: got,
+        "TPU v5 lite")["kernel_floor_s.mla_decode_attn"]
+    assert floor == pytest.approx(6 * 117 * got[0] / peak["bf16_flops"])
+    # the share of a kernel that took exactly its products' time is 100
+    assert 100.0 * floor / (6 * 117 * got[0] / peak["bf16_flops"]) \
+        == pytest.approx(100.0)
+    # a prompt of 4277 live rows in the 6144 bucket: its live triangle,
+    # 9.1M pairs a head, where the kernel's five query tiles of 1024 run
+    # 15.7M and the bucket's triangle is 18.9M
+    from paddle_tpu.ops.pallas.mla_attention import prompt_pairs
+    pairs = prompt_pairs(1, 6144, 4277)
+    assert pairs == 4277 * 4278 // 2 == 9_148_503
+    assert pairs < 1024 * 1024 * (1 + 2 + 3 + 4 + 5) < 6144 * 6144 // 2
+    counters = {"engine.mla_prompt_pairs.traced": 24.0 * pairs * 3,
+                "engine.mla_prompt_reads.traced": 24.0 * 3}
+    f, b = counts("mla_prompt_attn", cfg, job, counters=counters)
+    assert f == 2.0 * 32 * (192 + 128) * pairs      # a pass of 32 heads
+    assert b == pytest.approx(32 * (2.0 * pairs) ** 0.5 * (256 + 64 + 256)
+                              * 2)
+    assert counts("mla_prompt_attn", cfg, job, counters={}) is None
+    # the decode products: 7.5 pairs over 5.2 experts a step a layer
+    counters = {"engine.expert_pairs.traced": 5 * 7.5 * 117,
+                "engine.experts_touched.traced": 5 * 5.2 * 117,
+                "engine.sampler_dispatches.traced": 117.0}
+    assert counts("moe_up_dec", cfg, job, counters=counters) == \
+        pytest.approx((2.0 * 7.5 * 7168 * 4096,
+                       2.0 * (7.5 * (7168 + 4096) + 5.2 * 7168 * 4096)),
+                      rel=1e-12)
+    assert counts("moe_down_dec", cfg, job, counters={}) is None
+
+
 @pytest.mark.parametrize("family,kernel", sorted(KERNELS))
 def test_kernel_counts_are_the_hand_count(family, kernel):
     name = FAMILY_CONFIG[family]
@@ -680,7 +872,7 @@ def test_kernel_counts_are_the_hand_count(family, kernel):
 
 
 @pytest.mark.parametrize("family", ["gpt2", "laguna", "mellum", "jamba",
-                                    "keye"])
+                                    "keye", "dotsvlm"])
 def test_a_kernel_the_family_has_no_count_for_is_none(family):
     name = FAMILY_CONFIG[family]
     cfg, job = config(name), load("traffic", JOBS[family] + ".json")
@@ -690,12 +882,13 @@ def test_a_kernel_the_family_has_no_count_for_is_none(family):
         == (family != "keye")
     other = {"gpt2": "flash_fwd_win", "laguna": "flash_fwd",
              "mellum": "moe_up_dx", "jamba": "flash_fwd_win",
-             "keye": "flash_fwd_full"}[family]
+             "keye": "flash_fwd_full", "dotsvlm": "flash_fwd_full"}[family]
     assert counts(other, cfg, job) is None        # another family's name
     # and none for a job of the other kind (serving for the families that
     # train, training for the one that serves)
     assert counts(sorted(k for f, k in KERNELS if f == family)[0], cfg,
-                  {"kind": "train" if family in ("mellum", "jamba", "keye")
+                  {"kind": "train" if family in ("mellum", "jamba", "keye",
+                                                 "dotsvlm")
                    else "open_loop"}) is None
 
 
@@ -831,7 +1024,12 @@ TWINS = {
         "serving.decode_step", "engine.sparse_keys_read",
         "engine.sparse_keys_live", "engine.index_cache_bytes.close",
         "engine.inputs_resident", "engine.prefill_tokens_live",
-        "engine.sparse_prompt_keys_read"))}
+        "engine.sparse_prompt_keys_read")),
+    "dotsvlm_docs_16k": (9, 2**31 + 49, (
+        "serving.decode_step", "engine.latent_rows_read",
+        "engine.expert_pairs", "engine.experts_touched",
+        "engine.latent_cache_bytes.close", "engine.inputs_resident",
+        "engine.prefill_tokens_live"))}
 
 
 def last_line(out):
