@@ -26,8 +26,8 @@ MLP 8192, vocabulary 65536. A layer, on ``T`` rows::
 map), the paged read and the pool write of a decode step
 (``paged_attention``, the kernel that walks a row's live blocks, its 20
 query rows on the one KV head through the matrix unit /
-``block_scatter_write``), the head on a prompt's last row, the build /
-first-trace spans, bfloat16 pools.
+``block_scatter_write``), the head on a prompt's last row, the build
+span, bfloat16 pools.
 **What could not be shared**: Laguna's attention module is built around
 its rotary tables, its window and its gate, none of which exist here, so
 the attention layer is its own small class over the same ops; and the
@@ -44,7 +44,6 @@ row.
 
 from __future__ import annotations
 
-import contextlib
 import math
 from dataclasses import dataclass
 from typing import Optional, Tuple
@@ -401,7 +400,6 @@ class JambaForCausalLM(Layer):
                           "params": cfg.num_params()}):
             self.cfg = cfg
             self.model = JambaModel(cfg)
-        self._traced = False
 
     def _head(self, h):
         w = self.model.embed.weight.value
@@ -411,22 +409,18 @@ class JambaForCausalLM(Layer):
 
     def forward(self, input_ids, collect=None, cache=None, cache_pos=None,
                 block_tables=None, lora=None, last=None):
-        span = contextlib.nullcontext() if self._traced \
-            else RecordEvent(f"{self.span_prefix}.first_trace")
-        self._traced = True
-        with span:
-            if cache is None:
-                return self._head(self.model(input_ids, collect).value)
-            if lora is not None:
-                raise ValueError(f"{type(self).__name__} has no LoRA path")
-            h, caches = self.model.served(input_ids, cache, cache_pos,
-                                          block_tables, last, collect)
-            h = h.value
-            if last is not None:
-                # the head never multiplies a bucket's padding
-                h = jnp.take_along_axis(
-                    h, jnp.asarray(last, jnp.int32)[:, None, None], axis=1)
-            return self._head(h), caches
+        if cache is None:
+            return self._head(self.model(input_ids, collect).value)
+        if lora is not None:
+            raise ValueError(f"{type(self).__name__} has no LoRA path")
+        h, caches = self.model.served(input_ids, cache, cache_pos,
+                                      block_tables, last, collect)
+        h = h.value
+        if last is not None:
+            # the head never multiplies a bucket's padding
+            h = jnp.take_along_axis(
+                h, jnp.asarray(last, jnp.int32)[:, None, None], axis=1)
+        return self._head(h), caches
 
     def serving_spec(self):
         """One kind of blocks (the attention layers keep every row), one

@@ -66,7 +66,6 @@ logits stay float32.
 
 from __future__ import annotations
 
-import contextlib
 import math
 from dataclasses import dataclass, field
 from typing import Optional, Tuple
@@ -918,7 +917,7 @@ class LagunaForCausalLM(Layer):
     cross-entropy over them (labels outside the held rows are ignored);
     with ``cache`` the serving engine's call -> (float32 logits, caches)."""
 
-    #: the name the build's and the first trace's spans carry
+    #: the name the build's span carries
     span_prefix = "laguna"
 
     def __init__(self, cfg: LagunaConfig):
@@ -931,7 +930,6 @@ class LagunaForCausalLM(Layer):
             lo, hi = cfg.vocab
             self.lm_head = _linear(cfg.hidden_size, hi - lo, cfg.init_std,
                                    cfg.dtype)
-        self._traced = False
 
     def forward(self, input_ids, labels=None, collect=None, cache=None,
                 cache_pos=None, block_tables=None, lora=None, last=None,
@@ -941,24 +939,18 @@ class LagunaForCausalLM(Layer):
                 f"{type(self).__name__} with an indexer (sa_config) is "
                 f"served, not trained: no gradient passes the selection, so "
                 f"a loss would train neither q, k, v nor the indexer")
-        # the first forward is the one a compiled step traces
-        span = contextlib.nullcontext() if self._traced \
-            else RecordEvent(f"{self.span_prefix}.first_trace")
-        self._traced = True
         if cache is not None:
             if lora is not None:
                 raise ValueError(f"{type(self).__name__} has no LoRA path")
-            with span:
-                logits, caches, touched = self._served(
-                    input_ids, cache, cache_pos, block_tables, last, collect)
+            logits, caches, touched = self._served(
+                input_ids, cache, cache_pos, block_tables, last, collect)
             if counters is None:
                 return logits, caches
             # the decode step's device counters (``serving_spec``'s names:
             # a prefix of ``cfg.decode_counters``)
             return logits, caches, counters \
                 + touched[:counters.shape[0]].astype(counters.dtype)
-        with span:
-            logits = self.lm_head(self.model(input_ids, collect))
+        logits = self.lm_head(self.model(input_ids, collect))
         if labels is None:
             return logits
         lo, hi = self.cfg.vocab

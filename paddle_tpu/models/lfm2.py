@@ -49,7 +49,6 @@ decode step rewrites every row's.
 
 from __future__ import annotations
 
-import contextlib
 import math
 from dataclasses import dataclass, field
 from typing import Optional, Tuple
@@ -337,7 +336,6 @@ class Lfm2ForCausalLM(Layer):
                           "params": cfg.num_params()}):
             self.cfg = cfg
             self.model = Lfm2Model(cfg)
-        self._traced = False
 
     def _head(self, h):
         w = self.model.embed.weight.value
@@ -347,25 +345,21 @@ class Lfm2ForCausalLM(Layer):
 
     def forward(self, input_ids, collect=None, cache=None, cache_pos=None,
                 block_tables=None, lora=None, last=None, counters=None):
-        span = contextlib.nullcontext() if self._traced \
-            else RecordEvent(f"{self.span_prefix}.first_trace")
-        self._traced = True
-        with span:
-            if cache is None:
-                return self._head(self.model(input_ids, collect).value)
-            if lora is not None:
-                raise ValueError(f"{type(self).__name__} has no LoRA path")
-            h, caches, counted = self.model.served(
-                input_ids, cache, cache_pos, block_tables, last, collect)
-            h = h.value
-            if last is not None:
-                # the head never multiplies a bucket's padding
-                h = jnp.take_along_axis(
-                    h, jnp.asarray(last, jnp.int32)[:, None, None], axis=1)
-            if counters is None:
-                return self._head(h), caches
-            return self._head(h), caches, \
-                counters + counted.astype(counters.dtype)
+        if cache is None:
+            return self._head(self.model(input_ids, collect).value)
+        if lora is not None:
+            raise ValueError(f"{type(self).__name__} has no LoRA path")
+        h, caches, counted = self.model.served(
+            input_ids, cache, cache_pos, block_tables, last, collect)
+        h = h.value
+        if last is not None:
+            # the head never multiplies a bucket's padding
+            h = jnp.take_along_axis(
+                h, jnp.asarray(last, jnp.int32)[:, None, None], axis=1)
+        if counters is None:
+            return self._head(h), caches
+        return self._head(h), caches, \
+            counters + counted.astype(counters.dtype)
 
     def serving_spec(self):
         """One kind of blocks (the attention layers keep every row), one

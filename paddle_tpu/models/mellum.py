@@ -14,7 +14,7 @@ mellum2-12b-d8.json`` lists under ``assumed``.
 **Shared with Laguna** (one decoder module): RMSNorm, the rotary tables by
 layer kind with YaRN, grouped-query attention with its window, SwiGLU
 experts, the router op, the dropless expert layer and its grouped
-products, the untied head, the build / first-trace spans. **Configured
+products, the untied head, the build span. **Configured
 off**: the per-head output gate, the shared expert, the sigmoid scores and
 their 2.5 scale, the dense leading layer, head counts that differ by
 layer, the per-head norm on q and k (``qk_norm``) and the router's

@@ -18,8 +18,8 @@ reports into the same plane that ``GET /metrics`` scrapes.
 from __future__ import annotations
 
 from . import compile_tracker, export, metrics, runlog, tracing
-from .compile_tracker import (RecompileWarning, compiles, reset_compiles,
-                              tracked_jit)
+from .compile_tracker import (RecompileWarning, compile_totals, compiles,
+                              reset_compiles, tracked_jit)
 from .export import prometheus_text, snapshot, validate_prometheus_text
 from .metrics import (DEFAULT_BUCKETS, Counter, Gauge, Histogram,
                       MetricsRegistry)
@@ -34,8 +34,16 @@ INSTRUMENT_DOCS = {
         "parallel_executor_step, decode_step[_paged], "
         "verify_step_paged{k=...}, serving_prefill_paged{bucket=...}, "
         "to_static, to_static_multi_step, zero_train_step{stage=...})",
-    "xla_compile_ms":
-        "histogram — wall ms of calls that triggered an XLA compile",
+    "xla_trace_ms{fn=...} / xla_lower_ms{fn=...} / "
+    "xla_backend_compile_ms{fn=...}":
+        "counters — ms a tracked_jit site spent tracing its function "
+        "(exclusive of tracked sites traced inside it), lowering to an "
+        "MLIR module and in the backend's compilation or the persistent "
+        "cache's retrieval (JAX's own monitoring events; programs built "
+        "outside every site are fn=\"(untracked)\")",
+    "xla_cache_hits{fn=...} / xla_cache_misses{fn=...}":
+        "counters — programs of a site the persistent compile cache "
+        "served / compiled and wrote (a warm process reads 0 misses)",
     "serving_ttft_seconds{engine=...}":
         "histogram — time to first token of completed serving requests",
     "serving_tpot_seconds{engine=...}":
@@ -287,7 +295,8 @@ def histogram(name: str, help_str: str = "", buckets=None) -> Histogram:
 __all__ = [
     "metrics", "compile_tracker", "runlog", "export", "tracing",
     "MetricsRegistry", "Counter", "Gauge", "Histogram", "DEFAULT_BUCKETS",
-    "tracked_jit", "compiles", "reset_compiles", "RecompileWarning",
+    "tracked_jit", "compiles", "compile_totals", "reset_compiles",
+    "RecompileWarning",
     "log_event", "recent",
     "prometheus_text", "snapshot", "validate_prometheus_text",
     "counter", "gauge", "histogram",

@@ -170,6 +170,7 @@ def snapshot(registry: Optional[_metrics.MetricsRegistry] = None
                 out["counters"][k] = series.value
     out["compiles"] = {
         qual: {"count": rec["count"], "total_ms": rec["total_ms"],
+               **{stage: rec[stage] for stage in _ct.STAGES},
                "last_signature": rec["last_signature"]}
         for qual, rec in _ct.compiles().items()}
     return out

@@ -202,8 +202,12 @@ def _print_metrics_summary():
         print("XLA compiles:")
         for qual in sorted(comp):
             c = comp[qual]
-            print(f"  {qual}: {c['count']} "
-                  f"({c['total_ms']:.1f} ms traced)")
+            print(f"  {qual}: {c['count']} traces, {c['programs']} programs "
+                  f"({c['trace_ms']:.1f} ms tracing, "
+                  f"{c['lower_ms']:.1f} lowering, "
+                  f"{c['compile_ms']:.1f} compiling, "
+                  f"{c['cache_hits']} cache hits; "
+                  f"{c['total_ms']:.1f} ms in the calls that traced)")
 
 
 def summarize(events: List[dict], sorted_key: Optional[str] = None):

@@ -3259,7 +3259,17 @@ class ServingEngine:
           the whole table, ``slots x T`` a step. Their ratio is the share
           of the table a decode step's attention had to read (the paged
           kernel copies the live blocks and no others; a dead slot costs
-          it one block, which this leaves out)."""
+          it one block, which this leaves out).
+
+        **The program account** is the PROCESS's and not this engine's
+        alone (``observability.compile_totals()``: every ``tracked_jit``
+        site and what was built outside them), sums that only grow:
+        ``programs_built`` (executables compiled or retrieved),
+        ``programs_trace_ms``, ``programs_lower_ms``,
+        ``programs_compile_ms`` (the backend's compilation or the
+        persistent cache's retrieval), ``programs_cache_hits`` and
+        ``programs_cache_misses`` (compiled and written: a warm replica
+        reads 0). It moves only while a program is traced or built."""
         def pct(hist, q):
             v = hist.quantile(q)
             return None if v is None else round(v * 1e3, 3)
@@ -3371,6 +3381,12 @@ class ServingEngine:
         out.update(prompt_counted)
         # the step account (_ACCOUNT_KEYS; the docstring says what each is)
         out.update(account)
+        # the program account, the process's
+        built = _ct.compile_totals()
+        out["programs_built"] = built["programs"]
+        out.update({f"programs_{stage}": built[stage] for stage in (
+            "trace_ms", "lower_ms", "compile_ms", "cache_hits",
+            "cache_misses")})
         out["kv_dtype"] = self.kv_dtype
         out["mesh_shape"] = (None if self.mesh_shape is None
                              else list(self.mesh_shape))

@@ -870,8 +870,20 @@ def render_observability_block():
         "buckets, so p50/p95/p99 are derivable without storing",
         "samples), an XLA compile tracker wrapping every `jax.jit`",
         "entry point (`observability.compiles()` gives per-site compile",
-        "counts, wall time, and the abstract shape/dtype signature that",
-        "triggered each compile), a structured JSONL run log",
+        "counts, wall time, the abstract shape/dtype signature that",
+        "triggered each compile, and the account by stage: `trace_ms`,",
+        "`lower_ms`, `compile_ms`, `programs`, `cache_hits`,",
+        "`cache_misses`, `cache_retrieval_ms`, with what was built",
+        "outside every site under `(untracked)`;",
+        "`observability.compile_totals()` sums them over the process,",
+        "and `engine.stats()` / `GET /v1/stats` carries those sums as",
+        "`programs_built`, `programs_trace_ms`, `programs_lower_ms`,",
+        "`programs_compile_ms`, `programs_cache_hits`,",
+        "`programs_cache_misses`; with the profiler on a call that built",
+        "a program leaves one `program.build` span, args `site`,",
+        "`trace_ms`, `lower_ms`, `compile_ms`, `cache_hit`, and every",
+        "trace runs under a `program.trace` span, which the device trace",
+        "shows too), a structured JSONL run log",
         "(`observability.log_event(kind, **fields)`), and exporters:",
         "`observability.prometheus_text()` served at `GET /metrics` on",
         "`ServingHTTPServer`, `observability.snapshot()` embedded in",
