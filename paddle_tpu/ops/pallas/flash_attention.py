@@ -13,7 +13,11 @@ Layout: the kernels take q/k/v as [batch*heads, seq, head_dim]; the
 public entry accepts [b, h, s, d] and collapses the leading axes into the
 grid's first dim (per chip, when the program spans several).
 The only saved residuals are (o, lse) — the backward recomputes the
-probabilities blockwise, the standard flash-attention trade.
+probabilities blockwise, the standard flash-attention trade. The vjp
+names the pair (``utils.FLASH_RESIDUAL_NAMES``); a recompute segment
+(fleet.utils.recompute) keeps them beside its input, so its backward
+rebuilds q, k and v from that input but does not run the forward kernel
+again. Outside a checkpoint the names are the identity.
 
 Causal masking is block-skipped: a q block only loops over k blocks at or
 below its diagonal, halving causal FLOPs rather than masking dead work.
@@ -40,10 +44,11 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 
-from .utils import (interpret_mode as _interpret, pad_lane_dim, pick_block,
-                    shard_parallel)
+from .utils import (FLASH_RESIDUAL_NAMES, interpret_mode as _interpret,
+                    pad_lane_dim, pick_block, shard_parallel)
 
 NEG_INF = float("-inf")
 
@@ -316,7 +321,13 @@ def _flash(q, k, v, causal, scale, block_q, block_k, window, tag):
 
 
 def _flash_vjp_fwd(q, k, v, causal, scale, block_q, block_k, window, tag):
-    o, lse = _fwd_call(q, k, v, causal, scale, block_q, block_k, window, tag)
+    # JAX's jvp wraps the first scope it meets ("jvp(flash_fwd)") and a
+    # compiled program names a kernel by its last: one scope around the
+    # call takes the wrapper, and the kernel keeps the primal's name
+    with jax.named_scope("flash_attention"):
+        o, lse = _fwd_call(q, k, v, causal, scale, block_q, block_k, window,
+                           tag)
+    o, lse = map(checkpoint_name, (o, lse), FLASH_RESIDUAL_NAMES)
     return o, (q, k, v, o, lse)
 
 

@@ -24,6 +24,11 @@ def interpret_mode() -> bool:
 LANE = 128
 SUBLANE = 8
 
+#: ``jax.ad_checkpoint.checkpoint_name`` names of the flash forward's two
+#: outputs (o, lse), whatever its tag, window or grouping. The kernel's
+#: vjp places them; fleet.utils.recompute's checkpoint policy keeps them.
+FLASH_RESIDUAL_NAMES = ("flash_o", "flash_lse")
+
 
 def pick_block(n: int, preferred: int, minimum: int = 8) -> int:
     """Largest power-of-two divisor of ``n`` in [minimum, preferred]

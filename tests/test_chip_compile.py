@@ -814,3 +814,45 @@ def test_decode_step_keeps_the_sampler_under_a_conditional(
     assert len(re.findall(r" conditional\(", entry)) == 1
     assert not re.findall(r" sort\(", entry)
     assert re.findall(r" sort\(", text)
+
+
+def test_a_recomputed_train_step_runs_the_flash_forward_once_a_block(
+        one_chip, mosaic):
+    """``pretrain_1chip``'s step (batch 8 x 1024, AMP O2, bf16 moments,
+    grads inside) over a 2-block cut of ``gpt2-1p1b``, compiled for the
+    described chip: each block holds one ``flash_fwd`` and one of each
+    backward kernel (PR 53; under a bare checkpoint, or with the vjp taken
+    in the backward op, the forward kernel is there twice a block:
+    ``tests/test_recompute.py`` counts both in the jaxpr). Every kernel is
+    an instruction of the name the program gave it, the differentiated
+    forward too: the names are read as the benchmark's trace reader
+    reads them (``perfbench/xplane.py``, ``kernel_stem``), which finds a
+    kernel's seconds by that name."""
+    from perfbench import xplane
+    import dataclasses
+    from paddle_tpu import amp, jit
+    from paddle_tpu.models import GPT_CONFIGS, GPTForCausalLM
+    from paddle_tpu.optimizer import AdamW
+    cfg = dataclasses.replace(GPT_CONFIGS["gpt2-1p1b"], num_layers=2,
+                              vocab_size=1024, recompute=True)
+    model = _zero_weights(lambda: GPTForCausalLM(cfg))
+    model.train()
+    opt = AdamW(learning_rate=1e-4, parameters=model.parameters(),
+                moment_dtype="bfloat16")
+
+    def train_step(ids, labels):
+        with amp.auto_cast(level="O2"):
+            loss = model(ids, labels=labels)
+        model.clear_gradients()
+        loss.backward()
+        opt.step()
+        return loss
+    step = jit.to_static(train_step, layers=[model], optimizers=[opt],
+                         retain_grads=False)
+    ids = jnp.zeros((8, 1024), jnp.int32)
+    text = step.lower(ids, ids, place=lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=one_chip)).compile().as_text()
+    names = [xplane.kernel_stem(ln.strip()) for ln in text.splitlines()
+             if xplane.is_mosaic(ln)]
+    assert sorted(names) == sorted(
+        ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"] * 2)
