@@ -14,7 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..framework.program import convert_dtype
-from .registry import register
+from .registry import GRAD_SLOT_SUFFIX, _generic_grad_lowering, register
 
 
 # ---------------------------------------------------------------------------
@@ -186,33 +186,74 @@ def _log_softmax(ctx, ins, attrs):
     return {"Out": [jax.nn.log_softmax(ins["X"][0], axis=axis)]}
 
 
+def _hard_label_stats(logits, label, axis, ignore_index):
+    """What the hard-label cross-entropy needs of its logits, forward and
+    backward, from ONE read of them in the dtype they arrive in -> (z, lse,
+    onehot, valid): the logits in the statistics' dtype (float32; float64
+    for float64 logits), the log-sum-exp a row, the label's column and
+    which rows count, the last three with ``axis`` kept as 1. No array of
+    the logits' size is written: the convert, the exponent and the compare
+    fuse into the reductions (or the products) that read them."""
+    lbl = label if label.ndim == logits.ndim else jnp.expand_dims(label, axis)
+    # Mask label == ignore_index for ANY value (reference kernel semantics;
+    # conventional default is -100). Clamp so an out-of-range label picks
+    # a class that exists.
+    valid = lbl != ignore_index
+    safe_lbl = jnp.clip(jnp.where(valid, lbl, 0), 0,
+                        logits.shape[axis] - 1).astype(jnp.int32)
+    z = logits.astype(jnp.promote_types(logits.dtype, jnp.float32))
+    m = jnp.max(z, axis=axis, keepdims=True)
+    lse = m + jnp.log(jnp.sum(jnp.exp(z - m), axis=axis, keepdims=True))
+    column = jax.lax.broadcasted_iota(jnp.int32, z.shape, axis % z.ndim)
+    return z, lse, column == safe_lbl, valid
+
+
 @register("softmax_with_cross_entropy", no_grad_slots=("Label",),
           nondiff_outputs=("Softmax",))
 def _softmax_with_cross_entropy(ctx, ins, attrs):
-    """reference operators/softmax_with_cross_entropy_op.cu — fused for
-    numerical stability; here log_softmax + gather fuse under XLA."""
+    """reference operators/softmax_with_cross_entropy_op.cu. Hard labels:
+    one pass over the logits (``_hard_label_stats``), the loss in the
+    statistics' dtype; ``Softmax`` is computed from the logits for the
+    reference's contract and is dead code where nobody reads it."""
     logits, label = ins["Logits"][0], ins["Label"][0]
     axis = attrs.get("axis", -1)
-    soft_label = attrs.get("soft_label", False)
-    ignore_index = attrs.get("ignore_index", -100)
-    logp = jax.nn.log_softmax(logits, axis=axis)
-    softmax = jnp.exp(logp)
-    if soft_label:
+    if attrs.get("soft_label", False):
+        logp = jax.nn.log_softmax(logits, axis=axis)
         loss = -jnp.sum(label * logp, axis=axis, keepdims=True)
-    else:
-        lbl = label
-        if lbl.ndim == logits.ndim and lbl.shape[axis] == 1:
-            lbl = jnp.squeeze(lbl, axis)
-        # Mask label == ignore_index for ANY value (reference kernel semantics;
-        # conventional default is -100). Clamp before the gather so an
-        # out-of-range index never feeds take_along_axis.
-        valid = lbl != ignore_index
-        n_class = logits.shape[axis]
-        safe_lbl = jnp.clip(jnp.where(valid, lbl, 0), 0, n_class - 1)
-        picked = jnp.take_along_axis(
-            logp, jnp.expand_dims(safe_lbl, axis).astype(jnp.int32), axis=axis)
-        loss = jnp.where(jnp.expand_dims(valid, axis), -picked, 0.0)
+        return {"Softmax": [jnp.exp(logp)], "Loss": [loss]}
+    with jax.named_scope("vocab_loss"):
+        z, lse, onehot, valid = _hard_label_stats(
+            logits, label, axis, attrs.get("ignore_index", -100))
+        picked = jnp.sum(jnp.where(onehot, z, 0.0), axis=axis, keepdims=True)
+        loss = jnp.where(valid, lse - picked, 0.0)
+        softmax = jnp.exp(z - lse).astype(logits.dtype)
     return {"Softmax": [softmax], "Loss": [loss]}
+
+
+@register("softmax_with_cross_entropy_grad")
+def _softmax_with_cross_entropy_grad(ctx, ins, attrs):
+    """``Logits@GRAD = g * (softmax - onehot)`` in the logits' dtype,
+    rounded once; the log-sum-exp is the forward's expression again, so
+    inside one program XLA computes it once. Nothing of the logits' size
+    is written but the gradient itself (and that fuses into the products
+    that read it). Soft labels keep the generic vjp."""
+    if attrs.get("soft_label", False):
+        return _generic_grad_lowering(ctx, "softmax_with_cross_entropy",
+                                      ins, attrs)
+    if ins.get("Softmax" + GRAD_SLOT_SUFFIX):
+        raise NotImplementedError(
+            "softmax_with_cross_entropy_grad takes a cotangent on Loss "
+            "only: Softmax is a non-differentiable output of the op "
+            "(differentiate softmax + cross_entropy where its gradient "
+            "is wanted)")
+    logits, label = ins["Logits"][0], ins["Label"][0]
+    with jax.named_scope("vocab_loss"):
+        z, lse, onehot, valid = _hard_label_stats(
+            logits, label, attrs.get("axis", -1),
+            attrs.get("ignore_index", -100))
+        g = jnp.where(valid, ins["Loss" + GRAD_SLOT_SUFFIX][0], 0.0)
+        d = g.astype(z.dtype) * (jnp.exp(z - lse) - onehot.astype(z.dtype))
+    return {"Logits" + GRAD_SLOT_SUFFIX: [d.astype(logits.dtype)]}
 
 
 @register("cross_entropy", no_grad_slots=("Label",))

@@ -816,11 +816,48 @@ def test_decode_step_keeps_the_sampler_under_a_conditional(
     assert re.findall(r" sort\(", text)
 
 
-def test_a_recomputed_train_step_runs_the_flash_forward_once_a_block(
-        one_chip, mosaic):
+@pytest.fixture(scope="module")
+def gpt_cut_texts(one_chip):
     """``pretrain_1chip``'s step (batch 8 x 1024, AMP O2, bf16 moments,
-    grads inside) over a 2-block cut of ``gpt2-1p1b``, compiled for the
-    described chip: each block holds one ``flash_fwd`` and one of each
+    grads inside) over a 2-block cut of ``gpt2-1p1b`` with its whole
+    vocabulary, compiled for the described chip -> the optimised HLO of
+    the train step (``backward``) and of the loss alone (``forward``)."""
+    import dataclasses
+    from paddle_tpu import amp, jit
+    from paddle_tpu.models import GPT_CONFIGS, GPTForCausalLM
+    from paddle_tpu.optimizer import AdamW
+    cfg = dataclasses.replace(GPT_CONFIGS["gpt2-1p1b"], num_layers=2,
+                              recompute=True)
+    model = _zero_weights(lambda: GPTForCausalLM(cfg))
+    model.train()
+    opt = AdamW(learning_rate=1e-4, parameters=model.parameters(),
+                moment_dtype="bfloat16")
+
+    def loss_of(ids, labels):
+        with amp.auto_cast(level="O2"):
+            return model(ids, labels=labels)
+
+    def train_step(ids, labels):
+        loss = loss_of(ids, labels)
+        model.clear_gradients()
+        loss.backward()
+        opt.step()
+        return loss
+    steps = {"forward": jit.to_static(loss_of, layers=[model]),
+             "backward": jit.to_static(train_step, layers=[model],
+                                       optimizers=[opt], retain_grads=False)}
+    ids = jnp.zeros((8, 1024), jnp.int32)
+    with pytest.MonkeyPatch.context() as patch, jax.enable_x64(False):
+        patch.setattr(fa, "_interpret", lambda: False)
+        return {name: step.lower(
+            ids, ids, place=lambda a: jax.ShapeDtypeStruct(
+                a.shape, a.dtype, sharding=one_chip)).compile().as_text()
+            for name, step in steps.items()}
+
+
+def test_a_recomputed_train_step_runs_the_flash_forward_once_a_block(
+        gpt_cut_texts):
+    """Each block of the step holds one ``flash_fwd`` and one of each
     backward kernel (PR 53; under a bare checkpoint, or with the vjp taken
     in the backward op, the forward kernel is there twice a block:
     ``tests/test_recompute.py`` counts both in the jaxpr). Every kernel is
@@ -829,30 +866,50 @@ def test_a_recomputed_train_step_runs_the_flash_forward_once_a_block(
     reads them (``perfbench/xplane.py``, ``kernel_stem``), which finds a
     kernel's seconds by that name."""
     from perfbench import xplane
-    import dataclasses
-    from paddle_tpu import amp, jit
-    from paddle_tpu.models import GPT_CONFIGS, GPTForCausalLM
-    from paddle_tpu.optimizer import AdamW
-    cfg = dataclasses.replace(GPT_CONFIGS["gpt2-1p1b"], num_layers=2,
-                              vocab_size=1024, recompute=True)
-    model = _zero_weights(lambda: GPTForCausalLM(cfg))
-    model.train()
-    opt = AdamW(learning_rate=1e-4, parameters=model.parameters(),
-                moment_dtype="bfloat16")
-
-    def train_step(ids, labels):
-        with amp.auto_cast(level="O2"):
-            loss = model(ids, labels=labels)
-        model.clear_gradients()
-        loss.backward()
-        opt.step()
-        return loss
-    step = jit.to_static(train_step, layers=[model], optimizers=[opt],
-                         retain_grads=False)
-    ids = jnp.zeros((8, 1024), jnp.int32)
-    text = step.lower(ids, ids, place=lambda a: jax.ShapeDtypeStruct(
-        a.shape, a.dtype, sharding=one_chip)).compile().as_text()
-    names = [xplane.kernel_stem(ln.strip()) for ln in text.splitlines()
+    names = [xplane.kernel_stem(ln.strip())
+             for ln in gpt_cut_texts["backward"].splitlines()
              if xplane.is_mosaic(ln)]
     assert sorted(names) == sorted(
         ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"] * 2)
+
+
+@pytest.mark.parametrize("program", ["forward", "backward"])
+def test_a_gpt_step_writes_no_float32_array_of_its_logits(gpt_cut_texts,
+                                                          program):
+    """The loss reads the bfloat16 logits ``[8, 1024, 50304]`` through a
+    fused convert (the AMP black list's cast is an elementwise producer of
+    reductions and of the two gradient products' inputs): the step writes
+    no float32 array of their size, forward or backward (PR 56; the
+    lowerings to PR 55 wrote three, 1.65 GB each). Instructions inside a
+    fused computation keep their values in registers and do not count."""
+    from tools import train_step_ops
+    table = train_step_ops.instructions(gpt_cut_texts[program])
+    assert train_step_ops.written_float32(table, 8 * 1024 * 50304,
+                                          50304) == []
+    logits = [name for name, info in table.items() if not info["fused"]
+              and "bf16[8,1024,50304]" in info["results"]]
+    assert logits, "the head's product is not in the program"
+
+
+def test_head_and_loss_compile_to_three_products_and_one_pass(one_chip):
+    """The tied head and the loss, forward and backward, as the tape
+    records them (``matmul_v2``, the cast, ``softmax_with_cross_entropy``,
+    its grad, the cast's, ``matmul_v2_grad``) at the GPT cells' shape:
+    three products, no float32 array of the logits' size written, under
+    1 GiB of temporaries (0.77; the lowerings to PR 55: 3.07 GiB here,
+    3.84 with float32 master weights' casts; ``tools/head_loss_forms.py``
+    times both on the chip)."""
+    from tools import head_loss_forms, train_step_ops
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    with jax.enable_x64(False):
+        compiled = jax.jit(
+            head_loss_forms.op_chain("softmax_with_cross_entropy")).lower(
+                s((8, 1024, 2048)), s((50304, 2048)),
+                s((8, 1024), jnp.int32)).compile()
+    table = train_step_ops.instructions(compiled.as_text())
+    assert sum(info["convolutions"] for info in table.values()
+               if not info["fused"]) == 3
+    assert train_step_ops.written_float32(table, 8192 * 50304, 50304) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
