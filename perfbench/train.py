@@ -1,22 +1,32 @@
 """One training cell, once: the family's train job for the configuration
 (model, optimizer, train function) compiled as the job file says
 (``jit.to_static`` or ``zero_train_step``), a new seeded batch every step
-made on the device before the step that uses it, steps ended by a fetch of
-the loss.
+made on the device before the step that uses it, every step's loss fetched.
 
 The window opens after ``warm_steps`` (two of them compile: the optimizer's
-state appears after step 1) and closes at the end of the first step that
-ends at or after ``--seconds``: every step is whole, and the rate is all
-the window's tokens over all the window's time.
+state appears after step 1), each ended by a fetch of its loss, so nothing
+is in flight when it opens. Inside it the steps are dispatched ahead of the
+one whose loss is waited for, ``AHEAD_S`` seconds of them by the last warm
+step's time: the chip stays fed while the host stands still for less than
+that, and a loss is read that many steps late. When ``--seconds`` are up
+nothing more is sent, every step that was sent is waited for, and the clock
+is read after that wait: every step is whole, and the rate is all of those
+steps' tokens over all of that time.
 """
 
 from __future__ import annotations
 
+import collections
 import time
 
 import numpy as np
 
-TRACE_STEPS = 4
+TRACE_STEPS = 4     # the device trace covers TRACE_S seconds of steps, and
+TRACE_S = 4.0       # at least TRACE_STEPS of them (no more than are ahead)
+#: seconds of steps in flight ahead of the loss that is waited for: a host
+#: that stands still for less leaves the chip fed, and the last wait is no
+#: longer than this (PERF.md section 6, PR 57: stalls of 0.9 and 2.1 s seen)
+AHEAD_S = 5.0
 #: |program's loss - reference's loss| on the check batch. The program
 #: computes in bf16 (AMP O2) and hands back its loss as a bfloat16, whose
 #: step at ~10.9 is 0.0625: rounding alone moves it by up to 0.031. The
@@ -112,9 +122,12 @@ def run(cell: dict, cfg: dict, traffic: dict, seed: int, seconds: float,
     losses = []
     nxt = make(0)
     n = 0
+    clock = time.perf_counter
     for _ in range(int(traffic["warm_steps"])):
         cur, nxt = nxt, make(n + 1)
+        warm_t = clock()
         losses.append(fetch(step(*cur)))
+        warm_s = clock() - warm_t
         n += 1
         if n == 1 and traffic.get("rewrap_after_first_step"):
             # a program fault worked around (the traffic file's rewrap_why);
@@ -123,22 +136,36 @@ def run(cell: dict, cfg: dict, traffic: dict, seed: int, seconds: float,
     tiled(0)                      # compiled before the window, used after it
 
     before = serve.compile_count()
-    clock = time.perf_counter
-    step_s = []
-    # a step is ~0.5-0.7 s: the device trace covers the last TRACE_STEPS
-    tracing = serve.Tracing(trace, out_dir, seconds,
-                            last_s=TRACE_STEPS * 0.8)
+    ahead = max(1, int(AHEAD_S / warm_s))
+    step_s = []         # between one loss's arrival and the next one's
+    sent = collections.deque()
+    # the device trace covers the window's last TRACE_S seconds of steps:
+    # it starts during the last wait, when that many are still in flight
+    # (the host has nothing more to send, so starting it delays no step),
+    # and stops after the clock is read
+    tracing = serve.Tracing(trace, out_dir, seconds)
+    traced_steps = min(ahead, max(TRACE_STEPS, int(TRACE_S / warm_s)))
     t0 = clock()
     te = t0
-    while te - t0 < seconds:
-        tracing.tick(te - t0)
-        cur, nxt = nxt, make(n + 1)
+
+    def arrive():
+        nonlocal te
         with serve.span("bench.step"):
-            losses.append(fetch(step(*cur)))
-        n += 1
+            losses.append(fetch(sent.popleft()))
         now = clock()
         step_s.append(now - te)
         te = now
+
+    while clock() - t0 < seconds:       # time up: nothing more is sent
+        cur, nxt = nxt, make(n + 1)
+        sent.append(step(*cur))
+        n += 1
+        if len(sent) > ahead:
+            arrive()
+    while sent:
+        if trace and not tracing.device_on and len(sent) <= traced_steps:
+            tracing.start_device()
+        arrive()                # te: the clock after the last wait
     tracing.stop_device()
     window_s = te - t0
     compiles = serve.compile_count() - before
@@ -166,7 +193,8 @@ def run(cell: dict, cfg: dict, traffic: dict, seed: int, seconds: float,
     counts = {"attempted": len(step_s), "failed": 0, "correct": bool(ok),
               "loss_first": losses[0], "loss_last": losses[-1],
               "check_loss_program": got, "check_loss_reference": want,
-              "check_loss_diff": diff, "steps_in_window": len(step_s)}
+              "check_loss_diff": diff, "steps_in_window": len(step_s),
+              "steps_ahead": ahead, "step_gap_max_s": max(step_s)}
     e2e = {"train_tok_s_chip": rate, "setup_s": t0 - t_start}
     mfu = None
     if jax.devices()[0].platform == "tpu":
