@@ -12,6 +12,8 @@ from .lfm2 import LFM2_CONFIGS, Lfm2Config, Lfm2ForCausalLM
 from .keye import KEYE_CONFIGS, KeyeConfig, KeyeForCausalLM
 from .dotsvlm import (DOTSVLM_CONFIGS, DotsVlmConfig,
                       DotsVlmForCausalLM)
+from .qwen3next import (QWEN3NEXT_CONFIGS, Qwen3NextConfig,
+                        Qwen3NextForCausalLM)
 from . import generation
 from .generation import (beam_search, decode_step, decode_step_paged,
                          draft_ngram, greedy_search, sample,

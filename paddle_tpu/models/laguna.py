@@ -697,8 +697,13 @@ class LagunaMoE(Layer):
                     "tile_m": cfg.moe_tile_m})
         out = e["Out"][0]
         if cfg.shared_expert_intermediate_size:
-            out = out + self.shared(u)
+            out = out + self._shared(u)
         return out, e["Stats"][0]
+
+    def _shared(self, u):
+        """The shared expert's term for ``u`` (a Tensor in the parameters'
+        dtype); a family whose shared expert is gated scales it here."""
+        return self.shared(u)
 
     def served(self, u, live=None):
         """The serving engine's call, on arrays: ``u`` float32 [b, s, h]
@@ -754,7 +759,7 @@ class LagunaMoE(Layer):
             counted = no_counts(cfg.expert_counters)
         out = out.reshape(b, s, h).astype(jnp.float32)
         if cfg.shared_expert_intermediate_size:
-            sh = self.shared(Tensor(u.astype(dt), stop_gradient=True))
+            sh = self._shared(Tensor(u.astype(dt), stop_gradient=True))
             out = out + sh.value.astype(jnp.float32)
         return out, counted
 

@@ -46,11 +46,18 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .utils import (FLASH_RESIDUAL_NAMES, interpret_mode as _interpret,
                     pad_lane_dim, pick_block, shard_parallel)
 
 NEG_INF = float("-inf")
+#: the compiler's default scoped VMEM limit: a forward whose blocks and
+#: tiles (:func:`fwd_vmem_bytes`) fit it is compiled as it always was; past
+#: it the call asks for a limit of its own, while one head's K and V stay
+#: under :data:`_FWD_KV_MOST` (the chip has 128 MiB)
+_FWD_VMEM_DEFAULT = 16 << 20
+_FWD_KV_MOST = 64 << 20
 
 
 # ---------------------------------------------------------------- forward
@@ -111,6 +118,22 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
     lse_ref[0, 0] = (m + jnp.log(l))[:, 0]
 
 
+def fwd_kv_bytes(seq_k: int, d: int, dtype) -> int:
+    """VMEM the forward's whole K and V of one head take: two arrays,
+    double-buffered."""
+    return 2 * 2 * seq_k * d * jnp.dtype(dtype).itemsize
+
+
+def fwd_vmem_bytes(seq_k: int, d: int, dtype, block_q: int,
+                   block_k: int) -> int:
+    """VMEM a forward grid step takes, about: one head's K and V, the q and
+    o blocks (double-buffered), and the loop's float32 tiles (q and the
+    accumulator, a K and a V block, the logits and the probabilities)."""
+    item = jnp.dtype(dtype).itemsize
+    return fwd_kv_bytes(seq_k, d, dtype) + 2 * 2 * block_q * d * item \
+        + 4 * (2 * block_q * d + 2 * block_k * d + 2 * block_q * block_k)
+
+
 def _kv_map(group):
     """Index map of a k/v operand whose head serves ``group`` query heads."""
     if group == 1:
@@ -129,10 +152,15 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, window=0, tag=""):
     grid = (bh, seq_q // block_q)
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
                                block_k=block_k, seq_k=seq_k, window=window)
+    need = fwd_vmem_bytes(seq_k, d, k.dtype, block_q, block_k)
+    params = {} if need <= _FWD_VMEM_DEFAULT else {
+        "compiler_params": pltpu.CompilerParams(
+            vmem_limit_bytes=need + (16 << 20))}
     o, lse = pl.pallas_call(
         kernel,
         name=_name("flash_fwd", tag),
         grid=grid,
+        **params,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
             pl.BlockSpec((1, seq_k, d), kv),
@@ -354,11 +382,24 @@ def flash_attention(q, k, v, causal=False, scale=None,
 
     Sequence-length limit: every grid step keeps the WHOLE K and V of
     one head in VMEM (the ``(1, seq_k, d)`` blocks above; q, do, lse and
-    delta likewise in the dk/dv kernel). At d=128 bf16 the v5e compiler
-    accepts forward and backward through s=8192 and refuses s=16384
-    ("RESOURCE_EXHAUSTED: Ran out of memory in memory space vmem") —
-    at compile time under jit, so no ValueError fallback sees it. Longer
-    sequences need K/V streamed by block first (ROADMAP S5 / R8a).
+    delta likewise in the dk/dv kernel), double-buffered: ``8 s d`` bytes
+    in bfloat16 (:func:`fwd_kv_bytes`), beside the q / o blocks and the
+    loop's float32 tiles (:func:`fwd_vmem_bytes`: 3.5 MiB at d=128, 5 MiB
+    at d=256 with blocks of 512). Under the compiler's default scoped limit
+    (16 MiB) the v5e compiler accepts, forward-only, d=128 through s=12288
+    and d=256 through s=4096, and refuses d=128 at s=16384 and d=256 at
+    s=6144 and s=8192 ("RESOURCE_EXHAUSTED: Ran out of memory in memory
+    space vmem", at compile time under jit: described-chip compiles, PR
+    58). A forward past the default asks for a scoped limit of its own
+    (what it needs plus 16 MiB) and is then accepted at d=256 through
+    s=32768 and at d=128 through s=65536 (``tests/test_chip_compile.py``
+    holds the served shapes); a forward that fits the default is compiled
+    exactly as before. Past :data:`_FWD_KV_MOST` (64 MiB of K and V) the
+    call is refused HERE, a ``ValueError`` before anything is built, which
+    ``fused_attention_qkv`` catches and answers with the composed form.
+    The backward kernels keep the default limit (forward and backward are
+    accepted through s=8192 at d=128): training cells stay there. Longer
+    sequences still want K and V streamed by block (ROADMAP S5 / R8a).
 
     Inside a program that spans several chips (utils.kernel_sharding)
     the kernels run on each chip's own (batch, head) shard; sequence and
@@ -383,6 +424,13 @@ def flash_attention(q, k, v, causal=False, scale=None,
     if h % k.shape[1] or k.shape[1] != v.shape[1]:
         raise ValueError(
             f"flash_attention: {h} query heads over {k.shape[1]} KV heads")
+    resident = fwd_kv_bytes(seq_k, pad_lane_dim(d), k.dtype)
+    if resident > _FWD_KV_MOST:
+        raise ValueError(
+            f"flash_attention: one head's K and V at seq_k={seq_k}, d={d} "
+            f"({k.dtype}) take {resident >> 20} MiB of VMEM double-buffered, "
+            f"over the {_FWD_KV_MOST >> 20} MiB the forward may ask for: K "
+            f"and V are not streamed by block yet")
     # head_dim rides the lane axis whole; an unaligned width is padded
     # with zero columns (k's zero columns contribute nothing to the
     # logits, v's produce zero output columns sliced off below) rather

@@ -204,7 +204,8 @@ def served(model) -> ServedModel:
             f"plane reads a model through model.serving_spec() -> "
             f"paddle_tpu.serving.seam.ServedModel (GPTForCausalLM, "
             f"MellumForCausalLM, JambaForCausalLM, Lfm2ForCausalLM, "
-            f"KeyeForCausalLM and DotsVlmForCausalLM have one)")
+            f"KeyeForCausalLM, DotsVlmForCausalLM and "
+            f"Qwen3NextForCausalLM have one)")
     return spec()
 
 

@@ -39,6 +39,7 @@ gm = importlib.import_module("paddle_tpu.ops.pallas.grouped_matmul")
 ss = importlib.import_module("paddle_tpu.ops.pallas.selective_scan")
 pw = importlib.import_module("paddle_tpu.ops.pallas.pool_write")
 ml = importlib.import_module("paddle_tpu.ops.pallas.mla_attention")
+gd = importlib.import_module("paddle_tpu.ops.pallas.gated_delta")
 
 KERNEL = chip_smoke.KERNEL      # a Mosaic kernel in a compiled program
 
@@ -80,6 +81,7 @@ def mosaic(monkeypatch):
     monkeypatch.setattr(ss, "_interpret", lambda: False)
     monkeypatch.setattr(pw, "_interpret", lambda: False)
     monkeypatch.setattr(ml, "_interpret", lambda: False)
+    monkeypatch.setattr(gd, "_interpret", lambda: False)
     with jax.enable_x64(False):
         yield
 
@@ -170,6 +172,109 @@ def test_served_prompt_flash_attention_compiles_for_v5e(one_chip, mosaic,
             q, k, v, causal=True, window=window, tag=kind)).lower(
                 s(32), s(4), s(4)).compile().as_text()
     assert text.count(KERNEL) == 1 and "flash_fwd_" + kind in text
+
+
+# qwen3next_docs_8k's served prompts: one prompt of a bucket, 16 query heads
+# over 2 KV heads of 256, forward only. One head's K and V, double-buffered,
+# pass the compiler's default scoped limit at 8192 rows (as d=128 does at
+# 16384): the forward asks for a limit of its own there
+@pytest.mark.parametrize("bucket", [3072, 8192])
+def test_served_prompt_flash_attention_at_d256_compiles_for_v5e(
+        one_chip, mosaic, bucket):
+    def s(h):
+        return jax.ShapeDtypeStruct((1, h, bucket, 256), jnp.bfloat16,
+                                    sharding=one_chip)
+    assert (fa.fwd_vmem_bytes(bucket, 256, jnp.bfloat16, 512, 512)
+            > fa._FWD_VMEM_DEFAULT) == (bucket == 8192)
+    # the cells the benchmark had keep the forward they had: Mellum's
+    # longest prompt (12288 rows at d=128) fits the default
+    assert fa.fwd_vmem_bytes(12288, 128, jnp.bfloat16, 512, 512) \
+        <= fa._FWD_VMEM_DEFAULT < fa.fwd_vmem_bytes(6144, 256, jnp.bfloat16,
+                                                    512, 512)
+    with jax.default_matmul_precision("default"):
+        text = jax.jit(lambda q, k, v: fa.flash_attention(
+            q, k, v, causal=True, tag="full")).lower(
+                s(16), s(2), s(2)).compile().as_text()
+    assert text.count(KERNEL) == 1 and "flash_fwd_full" in text
+
+
+@pytest.mark.parametrize("d,rows", [(128, 16384), (256, 6144),
+                                    (256, 8192)])
+def test_the_flash_forward_past_the_default_limit_is_refused_without_its_own(
+        one_chip, mosaic, monkeypatch, d, rows):
+    """What the forward's own scoped limit is for: under the compiler's
+    default the same shapes are refused, at compile time under jit."""
+    monkeypatch.setattr(fa, "_FWD_VMEM_DEFAULT", 1 << 40)
+
+    def s(h):
+        return jax.ShapeDtypeStruct((1, h, rows, d), jnp.bfloat16,
+                                    sharding=one_chip)
+    with jax.default_matmul_precision("default"), \
+            pytest.raises(Exception, match="vmem"):
+        jax.jit(lambda q, k, v: fa.flash_attention(
+            q, k, v, causal=True, tag="full")).lower(
+                s(16), s(2), s(2)).compile()
+
+
+def test_the_flash_forward_refuses_what_it_may_not_ask_for_before_compile():
+    """Past 64 MiB of resident K and V the refusal is a ValueError the
+    caller reads before anything is built (``fused_attention_qkv`` then
+    takes the composed form), not a RESOURCE_EXHAUSTED under jit."""
+    q = jax.ShapeDtypeStruct((1, 16, 65536, 256), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((1, 2, 65536, 256), jnp.bfloat16)
+    with pytest.raises(ValueError, match="not streamed by block"):
+        jax.eval_shape(lambda q, k, v: fa.flash_attention(
+            q, k, v, causal=True), q, k, k)
+    # the largest it may: d=256 at 32768 rows, d=128 at 65536
+    assert fa.fwd_kv_bytes(32768, 256, jnp.bfloat16) == fa._FWD_KV_MOST \
+        == fa.fwd_kv_bytes(65536, 128, jnp.bfloat16)
+
+
+# and its decode read: 64 rows, 16 query heads over 2 KV heads of 256, 36
+# table entries of 256-row blocks
+def test_the_paged_read_at_d256_compiles_for_v5e(one_chip, mosaic):
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    pool = s((2241, 2, 256, 256))
+    text = jax.jit(lambda q, kp, vp, t, p: pa.paged_attention(
+        q, kp, vp, t, p, scale=1.0 / 16, interpret=False)).lower(
+            s((64, 16, 1, 256)), pool, pool, s((64, 36), jnp.int32),
+            s((64,), jnp.int32)).compile().as_text()
+    assert text.count(KERNEL) == 1 and "paged_decode_attn" in text
+
+
+# its delta rule: a prompt of a bucket (16 key heads serving 32 value heads
+# of 128 x 128), and a decode step's 64 rows against the state array
+@pytest.mark.parametrize("bucket", [3072, 8192])
+def test_the_chunked_delta_rule_compiles_for_v5e(one_chip, mosaic, bucket):
+    def s(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    key, value, gate = (s(1, bucket, 16, 128), s(1, bucket, 32, 128),
+                        s(1, bucket, 32))
+    compiled = jax.jit(gd.gdn_prefill).lower(
+        key, key, value, gate, gate, s(1, dtype=jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count(KERNEL) == 1 and "gdn_prefill" in text
+    # [T, 128, 128] a head never reaches HBM: the temporaries are the
+    # relayouts of q, k, v and o, not the states (32 x 64 KiB a row)
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < 8 * bucket * 4096 * 4
+
+
+def test_the_delta_rules_decode_step_rewrites_the_state_in_place(one_chip,
+                                                                 mosaic):
+    def s(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    compiled = jax.jit(gd.gdn_decode, donate_argnums=(5,)).lower(
+        s(64, 16, 128), s(64, 16, 128), s(64, 32, 128), s(64, 32),
+        s(64, 32), s(64, 32, 128, 128), s(64, dtype=jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count(KERNEL) == 1 and "gdn_decode" in text
+    mem = compiled.memory_analysis()
+    # the 128 MiB of state go in and come out as ONE buffer, and nothing
+    # state-sized is made beside it
+    assert mem.alias_size_in_bytes >= 64 * 32 * 128 * 128 * 4
+    assert mem.temp_size_in_bytes < 16 * 2 ** 20
 
 
 # and its expert layer at published widths (64 experts of 896 under hidden

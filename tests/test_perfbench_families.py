@@ -22,20 +22,22 @@ CONFIGS = {"cgpt-1p3b": "gpt2", "cgpt-1p3b-d20": "gpt2",
            "laguna-xs2-share8": "laguna", "mellum2-12b-d8": "mellum",
            "jamba2-3b": "jamba", "lfm2-24b-a2b-d9": "lfm2",
            "keye-vl2-30b-a3b-stage0": "keye",
-           "dots-vlm1-share32-d6": "dotsvlm"}
+           "dots-vlm1-share32-d6": "dotsvlm",
+           "qwen3-next-80b-a3b-ep4-d8": "qwen3next"}
 JOBS = {"gpt2": "pretrain_1chip", "laguna": "laguna_pretrain_8k",
         "mellum": "mellum_code_16k", "jamba": "jamba_reasoning_6k",
         "lfm2": "lfm2_agents_3k", "keye": "keye_longdoc_24k",
-        "dotsvlm": "dotsvlm_docs_16k"}
+        "dotsvlm": "dotsvlm_docs_16k", "qwen3next": "qwen3next_docs_8k"}
 FAMILY_CONFIG = {"gpt2": "cgpt-1p3b-d20", "laguna": "laguna-xs2-share8",
                  "mellum": "mellum2-12b-d8", "jamba": "jamba2-3b",
                  "lfm2": "lfm2-24b-a2b-d9",
                  "keye": "keye-vl2-30b-a3b-stage0",
-                 "dotsvlm": "dots-vlm1-share32-d6"}
+                 "dotsvlm": "dots-vlm1-share32-d6",
+                 "qwen3next": "qwen3-next-80b-a3b-ep4-d8"}
 RATE = {"gpt2": "train_tok_s_chip", "laguna": "train_tok_s_chip",
         "mellum": "serve_tok_s", "jamba": "serve_tok_s",
         "lfm2": "serve_tok_s", "keye": "serve_tok_s",
-        "dotsvlm": "serve_tok_s"}
+        "dotsvlm": "serve_tok_s", "qwen3next": "serve_tok_s"}
 
 
 def config(name):
@@ -47,7 +49,7 @@ def test_an_unknown_family_is_an_error_that_lists_the_known_ones():
         families.load({"name": "some-model", "family": "no_such_family"})
     assert "no_such_family" in str(e.value)
     assert families.known() == ["dotsvlm", "gpt2", "jamba", "keye", "laguna",
-                                "lfm2", "mellum"]
+                                "lfm2", "mellum", "qwen3next"]
     assert all(name in str(e.value) for name in families.known())
 
 
@@ -514,6 +516,111 @@ def test_any_32_requests_of_dotsvlm_docs_16k_fit_the_pool():
     assert e["prefix_cache"] is False
 
 
+def test_the_qwen3next_file_holds_the_published_widths_uncut():
+    """Every key of the catalog's ``config`` but the four in ``reduced``
+    equals the file's; the share and the bytes are what ISSUE 58 reckoned."""
+    cfg = config("qwen3-next-80b-a3b-ep4-d8")
+    catalog = "/opt/skills/guides/model-configs/architectures.jsonl"
+    reduced = ["num_hidden_layers", "num_experts", "vocab_size",
+               "max_position_embeddings"]
+    if os.path.exists(catalog):     # the catalog's row, where there is one
+        with open(catalog) as f:
+            row = next(r for r in map(json.loads, f)
+                       if r["name"] == "Qwen3-Next-80B-A3B-Instruct")
+        changed = {k for k, v in row["config"].items()
+                   if cfg.get(k, 0) != v}
+        assert changed == set(reduced)
+        entry = next(c for c in BENCH["configs"]
+                     if c["name"] == cfg["name"])
+        assert cfg["source"] == entry["source"] == row["source_url"]
+        assert entry["reduced"] == reduced
+    assert cfg["reduced"] == list(cfg["reduced_why"]) == reduced
+    assert [cfg["published"][k] for k in reduced] == \
+        [48, 512, 151936, 262144]
+    assert (cfg["hidden_size"], cfg["head_dim"], cfg["num_attention_heads"],
+            cfg["num_key_value_heads"], cfg["linear_key_head_dim"],
+            cfg["linear_value_head_dim"], cfg["linear_num_key_heads"],
+            cfg["linear_num_value_heads"], cfg["linear_conv_kernel_dim"],
+            cfg["moe_intermediate_size"],
+            cfg["shared_expert_intermediate_size"],
+            cfg["num_experts_per_tok"], cfg["partial_rotary_factor"],
+            cfg["rope_theta"], cfg["full_attention_interval"]) == \
+        (2048, 256, 16, 2, 128, 128, 16, 32, 4, 512, 512, 10, 0.25,
+         10_000_000, 4)
+    # the share: 128 of 512 experts, 1/4 of the rows, two whole periods
+    family = families.load(cfg)
+    assert (cfg["num_experts"], cfg["expert_share"],
+            family.router_width(cfg)) == (128, 4, 512)
+    assert cfg["vocab_size"] * cfg["vocab_share"] == 151936
+    assert cfg["num_hidden_layers"] == 2 * cfg["full_attention_interval"]
+    assert all(cfg.get(k) for k in ("assumed", "published", "deployment",
+                                    "engine_why"))
+    assert {"column_order", "multi_token_prediction", "state", "dtype",
+            "weights", "recurrence_init", "embed_init_std"} \
+        <= set(cfg["assumed"])
+    mc = family.model_config(cfg)
+    linear, full, rest, expert = (33_718_464, 27_263_488,
+                                  4_196_352 + 4_096, 3_145_728)
+    assert mc.num_params() == cfg["params_held"] \
+        == 6 * linear + 2 * full + 8 * (rest + 128 * expert) \
+        + 2 * 37984 * 2048 + 2048 == 3_667_251_328
+    assert (mc.experts, mc.num_experts, mc.vocab_size) == \
+        ((0, 128), 512, 37984)
+    assert (mc.router_score, mc.router_bias, mc.attention_gate, mc.dtype,
+            mc.moe_routed_scaling_factor) == \
+        ("softmax", False, False, "bfloat16", 1.0)
+    assert mc.layers_of("full_attention") == (3, 7)
+    spec_state = mc.state_arrays()
+    assert spec_state == (((3, 8192), "bfloat16"),
+                          ((32, 128, 128), "float32"))
+    # the deployment's bytes: the pool and the recurrent state
+    e = cfg["engine"]
+    assert e["num_blocks"] * e["block_size"] * 2 * 2048 == 2_349_858_816
+    assert e["max_slots"] * 6 * (32 * 128 * 128 * 4 + 3 * 8192 * 2) \
+        == 64 * 6 * 2_146_304 == 824_180_736
+
+
+def test_the_qwen3next_family_refuses_a_training_job_by_name():
+    cfg = config("qwen3-next-80b-a3b-ep4-d8")
+    family = families.load(cfg)
+    with pytest.raises(SystemExit, match="qwen3next family has no training"):
+        family.train_job(cfg, {"kind": "train"})
+    with pytest.raises(SystemExit, match="qwen3next family has no loss"):
+        family.loss({}, None, None, cfg)
+
+
+def test_any_64_requests_of_qwen3next_docs_8k_fit_the_pool():
+    """The longest prompt with the longest answer 64 times over fits the
+    pool, every prompt reaches a bucket, and every bucket is whole chunks
+    of the delta rule and whole blocks of the flash forward."""
+    from paddle_tpu.ops.pallas.gated_delta import CHUNK
+    from perfbench import traffic as T
+    cfg, tr = config("qwen3-next-80b-a3b-ep4-d8"), load(
+        "traffic", "qwen3next_docs_8k.json")
+    e = cfg["engine"]
+    pairs = T.multiset(tr)
+    assert len(pairs) == 16 and e["max_slots"] == 64
+    assert tr["queue_depth_slots"] == 1 and tr["preroll_completions"] == 64
+    assert tr["multiset"]["pairing_seed"] == 58
+    assert (min(p for p, _ in pairs), max(p for p, _ in pairs),
+            min(a for _, a in pairs), max(a for _, a in pairs)) == \
+        (2139, 7845, 267, 981)
+    assert all(p + a <= tr["multiset"]["max_total"] == e["max_len"] == 9216
+               for p, a in pairs)
+    need = -(-(7845 + 981) // e["block_size"])
+    assert need == 35 and 64 * need == e["num_blocks"] - 1
+    assert e["max_len"] == 36 * e["block_size"] \
+        == cfg["max_position_embeddings"]
+    assert T.buckets_used(tr, e["buckets"]) == e["buckets"] \
+        == [3072, 4096, 6144, 8192]
+    assert [sum(T.bucket_for(p, e["buckets"]) == b for p, _ in pairs)
+            for b in e["buckets"]] == [5, 3, 5, 3]
+    assert all(b % CHUNK == 0 and b % 512 == 0 for b in e["buckets"])
+    # one prompt a dispatch, whatever the bucket
+    assert all(cfg["tokens_a_dispatch"] // b <= 1 for b in e["buckets"])
+    assert e["prefix_cache"] is False
+
+
 # per layer 8*2048^2 + 4*2048*8192 + 2*1024*2048 = 104,857,600; head
 # 2*2048*50304 = 206,045,184; x3 for the backward.
 # Laguna share, forward a token at s 8192: a window layer's projections
@@ -531,7 +638,15 @@ LAGUNA_FORWARD = (3 * (7_364_608 + 12_584_448) + 6 * (9_469_952 + 2_031_744)
 @pytest.mark.parametrize("name,seq,by_hand", [
     ("cgpt-1p3b", 1024, 3 * (24 * 104_857_600 + 206_045_184)),
     ("cgpt-1p3b-d20", 1024, 3 * (20 * 104_857_600 + 206_045_184)),
-    ("laguna-xs2-share8", 8192, 3 * LAGUNA_FORWARD)])
+    ("laguna-xs2-share8", 8192, 3 * LAGUNA_FORWARD),
+    # Qwen3-Next's share, forward a token at s 4096: a DeltaNet mixer
+    # 2*2048*12352 + 2*4096*2048 + 8*32*128*128 = 71,565,312; an attention
+    # mixer 2*2048*36*256 + 2*4096*2048 + 4*16*256*2048.5 = 88,088,576; an
+    # expert layer 2*2048*512 + 2*2048 + 6*2048*512 (shared) + 2.5 *
+    # 6*2048*512 (routed, held) = 24,121,344; the head 2*2048*37984
+    ("qwen3-next-80b-a3b-ep4-d8", 4096,
+     3 * (6 * 71_565_312 + 2 * 88_088_576 + 8 * 24_121_344
+          + 155_582_464))])
 def test_train_flops_per_token_is_the_hand_count(name, seq, by_hand):
     cfg = config(name)
     assert families.load(cfg).train_flops_per_token(cfg, seq) == by_hand
@@ -665,6 +780,74 @@ KERNELS.update({
     ("lfm2", "paged_decode_attn"): (4.0 * 32 * 256 * 64 * 128,
                                     2.0 * 8 * 256 * 64 * 128),
 })
+
+
+# Qwen3-Next's served kernels, one call, at the MEAN call of the 16
+# prompts' dispatches (one prompt a dispatch: 3072 x 5, 4096 x 3, 6144 x 5,
+# 8192 x 3; mean 5184 positions = 81 chunks of 64). The chunked rule: a
+# chunk a head 2 x (2 x 64^2 x 128 + 10 x 64^3 + 3 x 64 x 128^2 + 2 x 64^2
+# x 128) = 15,728,640 over 32 heads; bytes float32 q, k [5184, 2048], v, o
+# [5184, 4096], g, beta [5184, 32] and the state [32, 128, 128]. A decode
+# step's: 64 rows x 32 x 128 x 128 state elements, 8 operations each; read
+# and written once, with q, k, v, o, g, beta. Flash, 16 query heads over 2
+# KV heads, d 256. The decode read without counters: one block a slot at
+# one byte a value. The experts: 5184 x 10 x 128/512 = 12960 pairs against
+# the held stack of 128; a step's 160 pairs over 128 (1 - (127/128)^160).
+QWEN_BUCKETS = ((3072, 5), (4096, 3), (6144, 5), (8192, 3))
+TOUCHED_QWEN = 128 * (1 - (127 / 128) ** 160)
+KERNELS.update({
+    ("qwen3next", "gdn_prefill"): (
+        81 * 32 * 15_728_640.0,
+        4.0 * (5184 * (2 * 2048 + 2 * 4096 + 2 * 32) + 32 * 128 * 128)),
+    ("qwen3next", "gdn_decode"): (
+        64 * 8.0 * 32 * 128 * 128,
+        4.0 * 64 * (2 * 32 * 128 * 128 + 2 * 2048 + 2 * 4096 + 2 * 32)),
+    ("qwen3next", "flash_fwd_full"): (
+        4.0 * 16 * 256 * sum(n * s * (s + 1) / 2.0
+                             for s, n in QWEN_BUCKETS) / 16,
+        (2 * 16 + 2 * 2) * 5184.0 * 256 * 2.0 + 16 * 5184.0 * 4.0),
+    ("qwen3next", "paged_decode_attn"): (4.0 * 16 * 256 * 256 * 64,
+                                         2.0 * 2 * 256 * 256 * 64),
+    ("qwen3next", "moe_up"): (2.0 * 12960 * 2048 * 1024,
+                              2.0 * (12960 * (2048 + 1024)
+                                     + 128 * 2048 * 1024)),
+    ("qwen3next", "moe_down"): (2.0 * 12960 * 512 * 2048,
+                                2.0 * (12960 * (512 + 2048)
+                                       + 128 * 512 * 2048)),
+    ("qwen3next", "moe_up_dec"): (2.0 * 160 * 2048 * 1024,
+                                  2.0 * (160 * (2048 + 1024)
+                                         + TOUCHED_QWEN * 2048 * 1024)),
+    ("qwen3next", "moe_down_dec"): (2.0 * 160 * 512 * 2048,
+                                    2.0 * (160 * (512 + 2048)
+                                           + TOUCHED_QWEN * 512 * 2048)),
+})
+
+
+def test_qwen3next_counts_its_decode_kernels_from_the_runs_counters():
+    cfg, job = config("qwen3-next-80b-a3b-ep4-d8"), load(
+        "traffic", "qwen3next_docs_8k.json")
+    counts = families.load(cfg).kernel_counts
+    # the decode products: 150 pairs over 88 experts a step a layer
+    counters = {"engine.expert_pairs.traced": 8 * 150.0 * 117,
+                "engine.experts_touched.traced": 8 * 88.0 * 117,
+                "engine.sampler_dispatches.traced": 117.0}
+    assert counts("moe_up_dec", cfg, job, counters=counters) == \
+        pytest.approx((2.0 * 150 * 2048 * 1024,
+                       2.0 * (150 * (2048 + 1024) + 88 * 2048 * 1024)),
+                      rel=1e-12)
+    assert counts("moe_down_dec", cfg, job, counters={}) is None
+    # the paged read: 1300 live blocks a flight of bfloat16 rows
+    counters = {"engine.kv_blocks_live.traced": 1300.0 * 50,
+                "engine.decode_flights.traced": 50.0, "kv_item_bytes": 2}
+    assert counts("paged_decode_attn", cfg, job, counters=counters) == \
+        pytest.approx((4.0 * 16 * 256 * 256 * 1300,
+                       2.0 * 2 * 256 * 256 * 2 * 1300), rel=1e-12)
+    assert counts("paged_decode_attn", cfg, job, counters={}) is None
+    # the delta rule's kernels count by shape, whatever the run read
+    assert counts("gdn_decode", cfg, job, counters=counters) == \
+        counts("gdn_decode", cfg, job)
+    assert counts("selective_scan", cfg, job) is None
+    assert counts("gdn_prefill", cfg, {"kind": "train"}) is None
 
 
 def test_lfm2_counts_its_data_dependent_kernels_from_the_runs_counters():
@@ -1029,7 +1212,11 @@ TWINS = {
         "serving.decode_step", "engine.latent_rows_read",
         "engine.expert_pairs", "engine.experts_touched",
         "engine.latent_cache_bytes.close", "engine.inputs_resident",
-        "engine.prefill_tokens_live"))}
+        "engine.prefill_tokens_live")),
+    "qwen3next_docs_8k": (6, 2**31 + 58, (
+        "serving.decode_step", "engine.experts_touched",
+        "engine.expert_pairs", "engine.state_bytes.close",
+        "engine.inputs_resident", "engine.prefill_tokens_live"))}
 
 
 def last_line(out):
