@@ -151,6 +151,8 @@ def to_static(function: Optional[Callable] = None, *, layers=None,
 
         def get_spec():
             if "spec" not in spec_holder:
+                if mesh is None:
+                    _make_state_before_the_first_step(optimizers or [])
                 spec_holder["spec"] = _StateSpec(layers or [],
                                                  optimizers or [])
             return spec_holder["spec"]
@@ -242,6 +244,26 @@ def to_static(function: Optional[Callable] = None, *, layers=None,
     if function is not None:
         return deco(function)
     return deco
+
+
+def _make_state_before_the_first_step(optimizers):
+    """On one device a step's optimizers get their accumulators before the
+    step is first traced (``Optimizer.init_state``: the values the first
+    ``step()`` would give them). The state then has one structure from
+    the first call on and the step is ONE program: made inside the first
+    call, the accumulators were outputs of a program of their own that
+    ran once, and a second program was traced, lowered and compiled for
+    the state as it then was (at ``gpt2-1p1b`` a minute of set-up cold,
+    20 s warm, and a second 65 MB entry in the persistent cache: PERF.md
+    section 6, PR 60). Across a mesh they stay lazy: made inside the
+    first step they are born with the layout the rules give them, and a
+    copy of every moment on one device is what ZeRO and tensor
+    parallelism are there to avoid. An optimizer with no eager step path,
+    or with no parameter list, is left as it is."""
+    for opt in optimizers:
+        if getattr(opt, "_eager_op", None) is not None \
+                and getattr(opt, "_parameter_list", None) is not None:
+            opt.init_state()
 
 
 def batch_axis(arg_specs):

@@ -434,7 +434,8 @@ class Optimizer:
         """Make every trainable parameter's accumulators now, at their
         initial values, instead of in the first ``step()``. A step compiled
         by ``jit.to_static`` then has the same state before and after its
-        first call, so it compiles once and not twice."""
+        first call, so it compiles once and not twice; where there is no
+        mesh ``to_static`` calls this itself before its first trace."""
         if self._eager_op is None:
             raise NotImplementedError(
                 f"{type(self).__name__} has no eager step path")

@@ -99,6 +99,41 @@ FLASH_SHAPES = {"d128_s1024": (8, 16, 1024, 128),
                 "d128_s8192": (1, 16, 8192, 128)}
 
 
+# characters of the module ``jax.grad`` of one call lowers to at the parent
+# of PR 60 (066054e: one masked tile a grid step, no plan). What a layer
+# adds to a step's module is what every start traces, lowers and hashes for
+# the persistent cache's key: PR 59's plan read 30.1 k at GPT's shape and
+# its forms of four tiles a step in every kernel four times that, which
+# `mellum_code_16k`'s set-up paid on every start
+PARENT_LOWERED = {"d128_s1024": 23981, "d64_s1024": 23924,
+                  "d128_s8192": 23946, "win": 28622, "full": 26610}
+#: the most the plan's module may take of the parent's: 1.5, and 1.7 where
+#: a window gives every tile two edges (read 1.33-1.37 and 1.55 from a
+#: script, 1.41-1.46 and 1.64 here: a kernel's payload holds the Python
+#: stack it was traced under, and pytest's is deeper)
+LOWERED_MOST = {"win": 1.7}
+
+
+def _lowered_twice(fn, args, kind):
+    """``jax.jit(fn).lower(*args)``, made twice from fresh traces (every
+    cache of JAX's dropped, the once-traced kernels' too). What refused PR
+    59 was set-up, and what a start pays for a kernel is its tracing, its
+    lowering and a cache that finds the program again: the module is the
+    same text both times, character for character (the persistent cache
+    keys on it), and no longer than the stated multiple of the parent's.
+    Both are lowered from ONE line: a kernel's payload holds the lines of
+    the Python stack it was called from."""
+    lowered = []
+    for _ in range(2):
+        jax.clear_caches()
+        lowered.append(jax.jit(fn).lower(*args))
+    text, again = (low.as_text() for low in lowered)
+    assert again == text
+    most = LOWERED_MOST.get(kind, 1.5) * PARENT_LOWERED[kind]
+    assert len(text) <= most, (kind, len(text), most)
+    return lowered[0]
+
+
 @pytest.mark.parametrize("direction", ["forward", "backward"])
 @pytest.mark.parametrize("shape", sorted(FLASH_SHAPES))
 def test_flash_attention_compiles_for_v5e(one_chip, mosaic, shape,
@@ -107,7 +142,9 @@ def test_flash_attention_compiles_for_v5e(one_chip, mosaic, shape,
                              sharding=one_chip)
     fn = (_flash_loss if direction == "forward"
           else jax.grad(_flash_loss, argnums=(0, 1, 2)))
-    text = jax.jit(fn).lower(q, q, q).compile().as_text()
+    lowered = (jax.jit(fn).lower(q, q, q) if direction == "forward"
+               else _lowered_twice(fn, (q, q, q), shape))
+    text = lowered.compile().as_text()
     # forward: one kernel; backward: forward + dq + dk/dv
     assert text.count(KERNEL) == (1 if direction == "forward" else 3)
 
@@ -130,11 +167,39 @@ def test_windowed_grouped_flash_attention_compiles_for_v5e(one_chip, mosaic,
     def loss(q, k, v):
         return fa.flash_attention(q, k, v, causal=True, window=window,
                                   tag=kind).astype(jnp.float32).sum()
-    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
-        s(heads), s(1), s(1)).compile().as_text()
+    text = _lowered_twice(jax.grad(loss, argnums=(0, 1, 2)),
+                          (s(heads), s(1), s(1)), kind).compile().as_text()
     assert text.count(KERNEL) == 3
     for stem in ("flash_fwd_", "flash_bwd_dq_", "flash_bwd_dkv_"):
         assert stem + kind in text
+
+
+# what block_plan gives the dq kernel beyond the cells' own shapes: three
+# and four query tiles a grid step (s 1536, 2048), and two where four
+# would stand at the default VMEM limit (s 9216: 15 MiB by the estimate, the
+# most it takes without a limit of its own); under the suite's `highest`
+# precision, whose float32 products take the most VMEM; each kernel still
+# one call under its name
+@pytest.mark.parametrize("window", [0, 512])
+@pytest.mark.parametrize("seq,dq_tiles", [(1536, 3), (2048, 4), (9216, 2)])
+def test_the_flash_block_plan_s_dq_steps_compile_for_v5e(
+        one_chip, mosaic, seq, dq_tiles, window):
+    plan = fa.block_plan(seq, seq, 128, True, window)
+    assert (plan.tiles, plan.dq_tiles, plan.dq_sub) == (1, dq_tiles, 256)
+
+    def s(h):
+        return jax.ShapeDtypeStruct((1, h, seq, 128), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    def loss(q, k, v):
+        return fa.flash_attention(q, k, v, causal=True, window=window,
+                                  tag="t").astype(jnp.float32).sum()
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        s(8), s(2), s(2)).compile().as_text()
+    calls = [line for line in text.splitlines() if KERNEL in line]
+    assert len(calls) == 3
+    for stem in ("flash_fwd_t", "flash_bwd_dq_t", "flash_bwd_dkv_t"):
+        assert sum(stem in line for line in calls) == 1, stem
 
 
 # mellum_code_16k's served prompts: one prompt of the longest bucket, 32
@@ -185,12 +250,14 @@ def test_served_prompt_flash_attention_at_d256_compiles_for_v5e(
         return jax.ShapeDtypeStruct((1, h, bucket, 256), jnp.bfloat16,
                                     sharding=one_chip)
     assert (fa.fwd_vmem_bytes(bucket, 256, jnp.bfloat16, 512, 512)
-            > fa._FWD_VMEM_DEFAULT) == (bucket == 8192)
+            > fa._VMEM_DEFAULT) == (bucket == 8192)
     # the cells the benchmark had keep the forward they had: Mellum's
     # longest prompt (12288 rows at d=128) fits the default
     assert fa.fwd_vmem_bytes(12288, 128, jnp.bfloat16, 512, 512) \
-        <= fa._FWD_VMEM_DEFAULT < fa.fwd_vmem_bytes(6144, 256, jnp.bfloat16,
+        <= fa._VMEM_DEFAULT < fa.fwd_vmem_bytes(6144, 256, jnp.bfloat16,
                                                     512, 512)
+    # and the plan gives the forward of so long a sequence one tile a step
+    assert fa.block_plan(bucket, bucket, 256, True).tiles == 1
     with jax.default_matmul_precision("default"):
         text = jax.jit(lambda q, k, v: fa.flash_attention(
             q, k, v, causal=True, tag="full")).lower(
@@ -204,7 +271,7 @@ def test_the_flash_forward_past_the_default_limit_is_refused_without_its_own(
         one_chip, mosaic, monkeypatch, d, rows):
     """What the forward's own scoped limit is for: under the compiler's
     default the same shapes are refused, at compile time under jit."""
-    monkeypatch.setattr(fa, "_FWD_VMEM_DEFAULT", 1 << 40)
+    monkeypatch.setattr(fa, "_VMEM_DEFAULT", 1 << 40)
 
     def s(h):
         return jax.ShapeDtypeStruct((1, h, rows, d), jnp.bfloat16,
@@ -952,12 +1019,16 @@ def gpt_cut_texts(one_chip):
              "backward": jit.to_static(train_step, layers=[model],
                                        optimizers=[opt], retain_grads=False)}
     ids = jnp.zeros((8, 1024), jnp.int32)
+    texts = {}
     with pytest.MonkeyPatch.context() as patch, jax.enable_x64(False):
         patch.setattr(fa, "_interpret", lambda: False)
-        return {name: step.lower(
-            ids, ids, place=lambda a: jax.ShapeDtypeStruct(
-                a.shape, a.dtype, sharding=one_chip)).compile().as_text()
-            for name, step in steps.items()}
+        for name, step in steps.items():
+            lowered = step.lower(
+                ids, ids, place=lambda a: jax.ShapeDtypeStruct(
+                    a.shape, a.dtype, sharding=one_chip))
+            texts[name] = lowered.compile().as_text()
+            texts[name + "_lowered"] = lowered.as_text()
+    return texts
 
 
 def test_a_recomputed_train_step_runs_the_flash_forward_once_a_block(
@@ -976,6 +1047,21 @@ def test_a_recomputed_train_step_runs_the_flash_forward_once_a_block(
              if xplane.is_mosaic(ln)]
     assert sorted(names) == sorted(
         ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"] * 2)
+
+
+def test_a_step_of_many_blocks_holds_each_flash_kernel_s_payload_once(
+        gpt_cut_texts):
+    """The kernels are traced once a shape (``_traced_once``): the module
+    the step lowers to, which every start builds again and hashes for the
+    persistent cache's key, holds ONE custom call a kernel whatever the
+    depth, each block calling the function around it, while the compiled
+    step has its call a kernel a block (the test above). The 2-block cut's
+    module is shorter than the parent's (066054e: 295,050 characters, a
+    payload a kernel a block; PR 59's plan 305,610), though a kernel's own
+    text is a third longer."""
+    lowered = gpt_cut_texts["backward_lowered"]
+    assert lowered.count("@tpu_custom_call") == 3
+    assert len(lowered) < 295050
 
 
 @pytest.mark.parametrize("program", ["forward", "backward"])

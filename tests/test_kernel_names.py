@@ -67,9 +67,10 @@ CASES = {
 }
 
 
-def kernel_locations(fn, shapes):
+def kernel_locations(fn, shapes, module=False):
     """The location of every ``tpu_custom_call`` of ``fn`` lowered for
-    the TPU, e.g. ``jit(f)/checkpoint/flash_fwd/pallas_call``."""
+    the TPU, e.g. ``jit(f)/checkpoint/flash_fwd/pallas_call``
+    (``module``: and the module's text)."""
     # as the chip runs them: without the suite's x64, under which the
     # literal zeros of the BlockSpec index maps lower as i64 and Mosaic
     # refuses them
@@ -83,7 +84,7 @@ def kernel_locations(fn, shapes):
         if "tpu_custom_call" in line:
             ref = re.search(r"loc\((#loc\d+)\)\s*$", line)
             out.append(locs[ref.group(1)] if ref else line)
-    return out
+    return (out, text) if module else out
 
 
 @pytest.mark.parametrize("kernel", sorted(CASES))
@@ -105,17 +106,20 @@ def test_wrappers_do_not_rename_a_kernel():
     """What PR 23's ledger showed: under ``jax.checkpoint`` the flash
     kernel was ``checkpoint_...`` and under ``shard_map``
     ``shard_map_...``. The component before ``pallas_call`` is now the
-    kernel's own name whatever wraps it."""
+    kernel's own name whatever wraps it; and since PR 60 the kernels are
+    traced once a shape under a ``jax.jit`` of their own, so the wrapper is
+    in the module (``checkpoint``) and a kernel's location is the one it
+    was first traced at."""
     def wrapped(q, k, v):
         return jax.checkpoint(_flash_loss)(q, k, v)
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(fa, "_interpret", lambda: False)
-        plain = kernel_locations(
-                jax.grad(_flash_loss, argnums=(0, 1, 2)), _QKV)
-        remat = kernel_locations(
-                jax.grad(wrapped, argnums=(0, 1, 2)), _QKV)
-    assert any("checkpoint" in loc for loc in remat)
-    assert not any("checkpoint" in loc for loc in plain)
+        plain, plain_module = kernel_locations(
+                jax.grad(_flash_loss, argnums=(0, 1, 2)), _QKV, module=True)
+        remat, remat_module = kernel_locations(
+                jax.grad(wrapped, argnums=(0, 1, 2)), _QKV, module=True)
+    assert "checkpoint" in remat_module
+    assert "checkpoint" not in plain_module
     for locations in (plain, remat):
         kernels = sorted(re.sub(r"^.*\((\w+)\)+$", r"\1",
                                 loc.split("/")[-2]) for loc in locations)

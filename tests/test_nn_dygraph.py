@@ -76,6 +76,57 @@ def test_jit_train_step_matches_eager():
         np.testing.assert_allclose(p1.numpy(), p2.numpy(), atol=1e-5)
 
 
+@pytest.mark.parametrize("optimizer", ["AdamW", "Adam", "Momentum"])
+def test_a_compiled_train_step_is_one_program_and_matches_eager(optimizer):
+    """``to_static`` makes its optimizers' accumulators before the first
+    trace (PR 60): with the grads kept inside the step the state has one
+    structure from the first call on and the site builds ONE program (to
+    PR 59: one for the call that made the moments, one for every call
+    after it), and the parameters are the eager steps', a layer that gets
+    no gradient and so no update among them."""
+    from paddle_tpu import observability
+    from paddle_tpu.observability import compile_tracker as ct
+
+    def build():
+        paddle.seed(11)
+        m = nn.Sequential(nn.Linear(6, 16), nn.Tanh(), nn.Linear(16, 2))
+        idle = nn.Linear(3, 3)          # in the state, never in the loss
+        o = getattr(paddle.optimizer, optimizer)(
+            learning_rate=0.05, parameters=m.parameters() + idle.parameters())
+        return m, idle, o
+
+    lossfn = nn.MSELoss()
+    rng = np.random.RandomState(2)
+    batches = [(rng.randn(16, 6).astype(np.float32),
+                rng.randn(16, 2).astype(np.float32)) for _ in range(4)]
+    m1, idle1, o1 = build()
+    for x, y in batches:
+        loss = lossfn(m1(paddle.to_tensor(x)), paddle.to_tensor(y))
+        loss.backward()
+        o1.step()
+        o1.clear_grad()
+
+    m2, idle2, o2 = build()
+
+    def one_program_step(x, y):
+        loss = lossfn(m2(x), y)
+        loss.backward()
+        o2.step()
+        o2.clear_grad()
+        return loss
+    one_program_step.__name__ += "_" + optimizer
+    step = jit.to_static(one_program_step, layers=[m2, idle2],
+                         optimizers=[o2], retain_grads=False)
+    for x, y in batches:
+        step(x, y)
+    site = observability.compiles()[
+        ct._qualname("to_static", {"py_fn": one_program_step.__name__})]
+    assert site["count"] == 1 and site["programs"] == 1
+    for p1, p2 in zip(m1.parameters() + idle1.parameters(),
+                      m2.parameters() + idle2.parameters()):
+        np.testing.assert_allclose(p1.numpy(), p2.numpy(), atol=1e-5)
+
+
 def test_transformer_encoder_backward():
     enc = nn.TransformerEncoder(
         nn.TransformerEncoderLayer(16, 4, 32, dropout=0.0), num_layers=2)

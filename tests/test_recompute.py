@@ -407,9 +407,10 @@ def test_every_segment_is_checkpointed_under_the_one_policy_object(
 
 def test_eight_recomputed_blocks_cost_one_trace_a_program_at_their_site(
         policy, monkeypatch):
-    """The step's site traces once for each of its two programs (the
-    optimizer's state appears after the first step) whatever the policy,
-    and a segment's python runs twice a trace: the probe for its
+    """The step's site traces once, for its one program (since PR 60 the
+    optimizer's state is made before the first trace: to PR 59 it appeared
+    after the first step and a second program was built) whatever the
+    policy, and a segment's python runs twice a trace: the probe for its
     parameters and the checkpoint (the parent's backward op, ``jax.vjp``
     over the forward lowering, entered the checkpoint a second time)."""
     from paddle_tpu.models.gpt import GPTBlock
@@ -436,7 +437,7 @@ def test_eight_recomputed_blocks_cost_one_trace_a_program_at_their_site(
     assert losses[-1] < losses[0]
     site = observability.compiles()[
         ct._qualname("to_static", {"py_fn": train.__name__})]
-    assert site["count"] == 2 and site["programs"] == 2
+    assert site["count"] == 1 and site["programs"] == 1
     assert len(runs) == 2 * 8 * site["count"]
 
 
