@@ -6,7 +6,7 @@
   ``shapes.infer`` verifier check and ``FLAGS_check_shapes``;
 - :mod:`recompile` — static prediction of XLA compile counts for the
   executor and serving entry points, cross-checked against the live
-  compile tracker in ``tools/obs_smoke.py``;
+  compile tracker in the serving tests;
 - :mod:`lifecycle` — static resource-lifecycle (KV rows / LoRA pins:
   release-on-all-paths, export/adopt ownership transfer) and
   lock-discipline (``# guarded-by``) checks over the serving

@@ -304,8 +304,8 @@ def violations() -> List[dict]:
 
 
 def report() -> dict:
-    """One snapshot of everything the sanitizer knows — the soak and
-    obs_smoke gates assert on this."""
+    """One snapshot of everything the sanitizer knows — the soak gate
+    asserts on this."""
     with _graph_lock:
         return {
             "enabled": enabled(),

@@ -13,10 +13,10 @@ mirroring the two compile-cache keying disciplines in the codebase:
   prefix cache's effect on which bucket a prompt's unshared suffix
   lands in — :func:`predict_serving_compiles`.
 
-``tools/obs_smoke.py`` cross-checks a prediction against the live
-``observability.compiles()`` counts (predicted == observed is a CI
-invariant), so drift between this model and the engine's real
-admission logic fails the gate rather than rotting silently.
+The serving tests cross-check a prediction against the live
+``observability.compiles()`` counts (predicted == observed, e.g.
+``tests/test_abstract_interp.py``), so drift between this model and the
+engine's real admission logic fails a test rather than rotting silently.
 """
 
 from __future__ import annotations
@@ -141,8 +141,7 @@ def predict_serving_compiles(
         tracing: Optional[float] = None,
         sanitize: bool = False,
         host_tier: bool = False,
-        sessions: int = 0,
-        megastep: int = 1) -> Dict[str, int]:
+        sessions: int = 0) -> Dict[str, int]:
     """Predict the engine's ``tracked_jit`` compile counts for a
     serving workload, before running it.
 
@@ -286,8 +285,7 @@ def predict_serving_compiles(
     control flow around the compiled dispatches, with no tensor,
     shape, dtype or donation anywhere near the step cache. Running
     the whole fleet under the sanitizer predicts the same counts as
-    running it bare (and ``tools/obs_smoke.py`` asserts exactly
-    that, predicted == observed, with the flag on).
+    running it bare.
 
     ``host_tier`` / ``sessions`` (``FLAGS_serving_host_tier``: the
     host-RAM KV block tier, and the number of distinct
@@ -303,22 +301,6 @@ def predict_serving_compiles(
     already warmed, by construction. A million sessions tiered
     through host RAM therefore predict the same counts as none —
     the concurrent-session capacity contract, statically.
-
-    ``megastep`` (``FLAGS_serving_megastep``: N decode iterations per
-    compiled dispatch, ``lax.scan`` device-resident) is the one knob
-    in this family that ADDS a compile surface instead of being a
-    no-op: with N > 1 the decode plane has exactly TWO entries —
-    ``decode_megastep_paged{n=N}`` for slots the scheduler can run N
-    ahead, and the single-token ``decode_step_paged`` fallback the
-    engine drops to whenever a megastep is unsafe for the whole batch
-    (a grammar cursor that must observe every token, stop sequences
-    beyond the device-table caps, a hard deadline with room for fewer
-    than N tokens). Both compile once; ``_choose_megastep`` never
-    picks an intermediate N, so no third surface exists. Requires
-    ``spec_tokens == 0`` (the engine rejects the combination).
-    ``dispatch_ahead`` and threaded routers reuse the
-    same two entries — enqueueing megastep k+1 early replays the
-    cached trace by construction.
     """
     if kv_dtype not in ("f32", "bf16", "int8"):
         raise ValueError(f"kv_dtype must be one of ('f32', 'bf16', "
@@ -388,13 +370,6 @@ def predict_serving_compiles(
             f"on/off), got {host_tier!r}")
     if int(sessions) < 0:
         raise ValueError(f"sessions must be >= 0, got {sessions}")
-    megastep = int(megastep)
-    if megastep < 1:
-        raise ValueError(f"megastep must be >= 1, got {megastep}")
-    if megastep > 1 and spec_tokens > 0:
-        raise ValueError(
-            "megastep > 1 is mutually exclusive with spec_tokens > 0 "
-            "(the engine rejects the combination)")
     if sessions and not host_tier:
         raise ValueError(
             "sessions requires host_tier=True (submit(session=...) "
@@ -438,8 +413,6 @@ def predict_serving_compiles(
             counts[f"verify_step_paged{{k={spec_tokens}}}"] = 1
         else:
             counts["decode_step_paged"] = 1
-            if megastep > 1:
-                counts[f"decode_megastep_paged{{n={megastep}}}"] = 1
     return counts
 
 

@@ -260,36 +260,6 @@ define_flag("serving_spec_ngram", 3,
             "prompt+generated context when proposing draft tokens "
             "(falls back to shorter n-grams, then to repeating the "
             "last token).")
-define_flag("serving_megastep", 1,
-            "Device-resident decode megasteps: decode iterations run "
-            "inside one compiled lax.scan entry per step() call, with "
-            "EOS / budget / stop-sequence early-exit carried as "
-            "per-slot data (finished slots freeze behind a live-mask) "
-            "and one host commit per megastep instead of per token. "
-            "Output is byte-identical to megastep=1; incompatible with "
-            "serving_spec_tokens > 0. Requests the device stop tables "
-            "cannot hold (decoding.STOP_MAX_SEQS/STOP_MAX_LEN) or "
-            "that decode under a JSON grammar fall back to single "
-            "steps, as does a step whose tightest hard deadline could "
-            "not absorb a whole megastep. 1 (default) keeps the "
-            "per-token host loop.")
-define_flag("serving_dispatch_ahead", False,
-            "Megastep pipelining only (serving_megastep > 1): after "
-            "committing megastep k, dispatch k+1 from k's device-carry "
-            "outputs before syncing, so host commit work overlaps "
-            "device execution. The speculative dispatch is consumed "
-            "only if the scheduler state it assumed is unchanged (no "
-            "finishes, no admissions, no weight/flag changes); "
-            "otherwise its tokens are discarded and the megastep runs "
-            "again, which is why a model with recurrent state is "
-            "refused it. It has consumed step k's pools like any paged "
-            "step, so the cache holds the pools it returned: the rows "
-            "it wrote lie at or beyond every slot's committed length "
-            "and are written again before anything reads them. The "
-            "single decode step (serving_megastep = 1) needs no flag: "
-            "it is always dispatched one ahead of its fetch, row by "
-            "row valid (ServingEngine._decode). Requires "
-            "serving_megastep > 1.")
 define_flag("serving_dispatch_threads", 0,
             "Router dispatch concurrency: ReplicaRouter / DisaggRouter "
             "step their replicas from a bounded thread pool of this "

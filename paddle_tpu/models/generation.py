@@ -54,7 +54,7 @@ def model_trace_lock(model) -> threading.RLock:
     only hold it for the instantaneous param snapshot, so compiled
     steps on sibling replicas still overlap. Reentrant because nested
     borrows happen inside one trace (prefill tracing the shared
-    sampler, megastep tracing the per-iteration step)."""
+    sampler)."""
     lk = getattr(model, "_step_trace_lock", None)
     if lk is None:
         with _TRACE_LOCK_GUARD:
@@ -323,7 +323,7 @@ def decode_step_paged(model, mesh=None, kv_dtype: str = "f32",
     step (0.0 for float pools).
 
     The step **owns** ``pools`` (:data:`POOLS_DONATED`, as do the
-    megastep, verify and the engine's paged prefill entries): the
+    verify and the engine's paged prefill entries): the
     arrays passed in are deleted by the call, the rows are written in
     place, and ``new_pools`` is what the caller holds from then on.
 
@@ -406,153 +406,6 @@ def decode_step_paged(model, mesh=None, kv_dtype: str = "f32",
         key = key + ("lora", tuple(lora_shape))
     if counters:
         key = key + ("counters",)
-    return step_entry(model, key, _build)
-
-
-def decode_megastep_paged(model, n: int, mesh=None, kv_dtype: str = "f32",
-                          lora_shape=None):
-    """``n`` paged decode iterations inside ONE compiled entry.
-
-    The serving hot loop used to round-trip to Python once per token
-    per replica; this is the device-resident replacement: a
-    ``lax.scan`` over ``n`` iterations of the exact
-    :func:`decode_step_paged` body, with the early-exit conditions the
-    host used to check between steps — EOS, remaining token budget,
-    stop-sequence matching — carried *into* the step as per-slot data
-    (the JSON-grammar constraint-as-data trick applied to control
-    flow). The host commits once per megastep instead of once per
-    token.
-
-    Returns ``{"fn": jitted, "traces": {"count": c}}`` where ``fn``
-    maps ``(tokens [b] i32, pos [b] i32, tables [b, T] i32, pools,
-    samp, live [b] bool, budget [b] i32, eos [b] i32,
-    stop = (pat [b, J, L] i32, plen [b, J] i32, fail [b, J, L+1] i32,
-    state [b, J] i32)[, lora])`` to::
-
-        (toks   [n, b] i32,   # token committed at each iteration
-         finish [b] i32,      # first iteration whose token finished
-                              # the slot, or -1 (still live after n)
-         tokens_f, pos_f, pools_f, keys_f, live_f, rem_f, state_f,
-         max_qerr)
-
-    Per-slot semantics, iteration ``i`` (proved identical to ``n``
-    single steps in the engine's token-identity oracles):
-
-    - a **live** slot feeds its carried token at its carried position
-      (writing that token's KV row), samples the next token with its
-      own functionally-split RNG key, decrements its budget, advances
-      its KMP stop states (``decoding.stops_advance``), and *finishes*
-      — drops out of ``live`` — when the sampled token equals its
-      ``eos`` (-1 = none), matches a stop sequence, or exhausts the
-      budget;
-    - a **finished/empty** slot freezes: it re-feeds its last token at
-      its frozen position every remaining iteration. The stray KV
-      writes are idempotent, land past the slot's committed length in
-      its own worst-case-reserved private blocks (never in a published
-      prefix block), and are invisible under the position mask — the
-      same contract empty slots already rely on at megastep 1;
-    - RNG keys advance for *every* row every iteration (fixed per-row
-      split fan-out — a row's stream depends only on its own seed and
-      its live step count; the engine discards frozen rows' keys at
-      commit).
-
-    The commit contract: a slot with ``finish[s] = f >= 0`` committed
-    ``f + 1`` tokens (``toks[:f+1, s]``); a slot still live committed
-    all ``n``. The host replays them through its ordinary per-token
-    append path, so finish *reasons*, tracing marks and session state
-    are re-derived exactly.
-
-    Stop tables are fixed-shape (``decoding.STOP_MAX_SEQS`` x
-    ``STOP_MAX_LEN``); requests whose stops don't fit take the
-    engine's megastep-1 fallback. Compiled once per (model, n, mesh,
-    kv_dtype[, lora geometry]) in the unified :func:`step_entry`
-    cache; ``mesh`` / ``kv_dtype`` / ``lora_shape`` behave exactly as
-    in :func:`decode_step_paged`.
-    """
-    from ..distributed.sharding import mesh_cache_key
-    from ..observability import compile_tracker as _ct
-    from ..serving.decoding import (sample_tokens, stops_advance,
-                                    stops_matched)
-    n = int(n)
-    if n < 2:
-        raise ValueError(
-            f"decode_megastep_paged needs n >= 2, got {n}; use "
-            "decode_step_paged for single steps")
-    mkey = mesh_cache_key(mesh)
-
-    def _build():
-        def _impl(params, tokens, pos, tables, pools, samp, live,
-                  budget, eos, stop, lora):
-            temp, tk, tp, keys0, mask = samp
-            pat, plen, fail, state0 = stop
-
-            def body(carry, _):
-                tok, p, pl, keys, lv, rem, st, qerr = carry
-                with no_grad(), _borrowed_params(model, params), \
-                        _kernel_layout(model, mesh):
-                    logits, newp = model(_t(tok[:, None]),
-                                         cache=_wrap_pools(pl),
-                                         cache_pos=p, block_tables=tables,
-                                         lora=lora)
-                lg = logits.value[:, -1]
-                nxt, new_keys = sample_tokens(lg, (temp, tk, tp, keys,
-                                                   mask))
-                pools2, q2 = _unwrap_pools(newp)
-                nxt = jnp.where(lv, nxt, tok)
-                ns = stops_advance(nxt, pat, plen, fail, st)
-                ns = jnp.where(lv[:, None], ns, st)
-                rem2 = jnp.where(lv, rem - 1, rem)
-                fin = lv & (((eos >= 0) & (nxt == eos)) |
-                            stops_matched(ns, plen) | (rem2 <= 0))
-                carry2 = (nxt, jnp.where(lv, p + 1, p), pools2,
-                          new_keys, lv & ~fin, rem2, ns,
-                          jnp.maximum(qerr, q2))
-                return carry2, (nxt, fin)
-
-            carry0 = (tokens, pos, pools, keys0, live,
-                      budget, state0, jnp.zeros((), jnp.float32))
-            carry, (toks, fins) = jax.lax.scan(body, carry0, None,
-                                               length=n)
-            tok_f, pos_f, pools_f, keys_f, live_f, rem_f, st_f, qerr = \
-                carry
-            idx = jnp.arange(n, dtype=jnp.int32)[:, None]
-            finish = jnp.min(jnp.where(fins, idx, n), axis=0)
-            finish = jnp.where(finish >= n, -1, finish).astype(jnp.int32)
-            return (toks, finish, tok_f, pos_f, pools_f, keys_f,
-                    live_f, rem_f, st_f, qerr)
-
-        if lora_shape is None:
-            def _step(params, tokens, pos, tables, pools, samp, live,
-                      budget, eos, stop):
-                return _impl(params, tokens, pos, tables, pools, samp,
-                             live, budget, eos, stop, None)
-        else:
-            def _step(params, tokens, pos, tables, pools, samp, live,
-                      budget, eos, stop, lora):
-                return _impl(params, tokens, pos, tables, pools, samp,
-                             live, budget, eos, stop, lora)
-
-        jit_kwargs = dict(POOLS_DONATED)
-        if mesh is not None:
-            repl, pools_sh = _mesh_step_shardings(model, mesh, kv_dtype)
-            in_sh = (_mesh_param_shardings(model, mesh),
-                     repl, repl, repl, pools_sh, repl, repl, repl,
-                     repl, repl)
-            if lora_shape is not None:
-                in_sh = in_sh + (repl,)
-            jit_kwargs.update(
-                in_shardings=in_sh,
-                out_shardings=(repl, repl, repl, repl, pools_sh, repl,
-                               repl, repl, repl, repl))
-        fn = _inject_params(
-            model, _ct.tracked_jit("decode_megastep_paged", _step,
-                                   labels={"n": str(n)}, **jit_kwargs))
-        return {"fn": fn, "traces": fn.traces}
-
-    key = (("decode_mega", n) if mkey is None
-           else ("decode_mega", n, mkey, kv_dtype))
-    if lora_shape is not None:
-        key = key + ("lora", tuple(lora_shape))
     return step_entry(model, key, _build)
 
 

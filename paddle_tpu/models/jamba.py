@@ -427,8 +427,7 @@ class JambaForCausalLM(Layer):
         kind of recurrent state (a Mamba layer's convolution tail and scan
         state), pools in the parameters' dtype, none of the engine's
         optional features yet: prefix reuse needs a snapshot of the state
-        at a block's edge, speculation a way to roll it back, megasteps a
-        scan whose carry holds it."""
+        at a block's edge, speculation a way to roll it back."""
         from ..serving.seam import CacheKind, ServedModel, StateKind
         cfg = self.cfg
         d, n, k = cfg.d_inner, cfg.mamba_d_state, cfg.mamba_d_conv

@@ -367,8 +367,7 @@ class Lfm2ForCausalLM(Layer):
         pools and tails in the parameters' dtype, the expert layers'
         device counters, none of the engine's optional features yet:
         prefix reuse needs a snapshot of the tail at a block's edge
-        (ROADMAP R4), speculation a way to roll it back, megasteps a scan
-        whose carry holds it."""
+        (ROADMAP R4), speculation a way to roll it back."""
         from ..serving.seam import CacheKind, ServedModel, StateKind
         cfg = self.cfg
         return ServedModel(

@@ -578,8 +578,7 @@ class Qwen3NextForCausalLM(Layer):
         and its matrix state), pools in the parameters' dtype, the expert
         layers' device counters, none of the engine's optional features:
         prefix reuse needs a snapshot of ``S`` at a block's edge (2 MB a
-        layer: ROADMAP R4), speculation a way to roll it back, megasteps a
-        scan whose carry holds it."""
+        layer: ROADMAP R4), speculation a way to roll it back."""
         from ..serving.seam import CacheKind, ServedModel, StateKind
         cfg = self.cfg
         return ServedModel(

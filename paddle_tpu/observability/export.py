@@ -22,7 +22,7 @@ from . import metrics as _metrics
 _NAME_OK = re.compile(r"^[a-zA-Z_:][a-zA-Z0-9_:]*$")
 _LABEL_OK = re.compile(r"^[a-zA-Z_][a-zA-Z0-9_]*$")
 # label VALUES may contain any escaped text — including '}' (a
-# qualified tracked_jit name like fn="decode_megastep_paged{n=4}"),
+# qualified tracked_jit name like fn="verify_step_paged{k=4}"),
 # so the label block must be parsed quote-aware, not
 # up-to-the-first-brace
 _SAMPLE_RE = re.compile(

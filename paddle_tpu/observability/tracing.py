@@ -22,20 +22,6 @@ mark is exactly the measured TTFT. ``blame()`` decomposes one request;
 component dominates the E2E p95 tail — the question ROADMAP items 2–3
 keep asking of TTFT p95.
 
-**Mark granularity under decode megasteps.** With
-``FLAGS_serving_megastep`` N > 1 the engine commits tokens once per
-megastep, so marks land at *commit boundaries*, not per device token:
-``first_token`` is untouched (the first output token comes from the
-prefill dispatch and is marked at prefill commit — TTFT has megastep-
-independent granularity), but a request that finishes on token k of a
-megastep is marked finished when that megastep's batch commits, up to
-N-1 token-times after the device-side early-exit froze its slot. The
-blame identity is unaffected — it telescopes over whatever marks
-exist — and every timestamp still comes off the engine clock at the
-commit, so seeded replays stay byte-identical at any fixed N. The
-decode component simply has coarser resolution at larger N; compare
-like with like when diffing blame summaries across megastep settings.
-
 Everything here is host-side bookkeeping: no compiled surface is
 touched (``analysis.recompile.predict_serving_compiles(tracing=...)``
 is a validated no-op), timestamps come only from the engine clock so a

@@ -58,11 +58,10 @@ from typing import Optional, Sequence, Tuple
 import numpy as np
 
 __all__ = [
-    "DecodeParams", "JsonGrammar", "NEG_MASK", "STOP_MAX_LEN",
-    "STOP_MAX_SEQS", "StopMatcher", "json_token_strings",
-    "neutral_samp", "process_logits", "request_key", "sample_first",
-    "sample_tokens", "split_keys", "stop_table_rows", "stops_advance",
-    "stops_fit", "stops_matched", "verify_tokens",
+    "DecodeParams", "JsonGrammar", "NEG_MASK", "StopMatcher",
+    "json_token_strings", "neutral_samp", "process_logits",
+    "request_key", "sample_first", "sample_tokens", "split_keys",
+    "verify_tokens",
 ]
 
 # Additive-mask value for banned tokens.  Large enough that softmax
@@ -358,36 +357,24 @@ def sample_first(logits_row, params: DecodeParams, key: np.ndarray,
 
 
 # --------------------------------------------------------------------
-# Stop sequences: incremental KMP matching, host- and device-side
+# Stop sequences: incremental KMP matching on the host
 # --------------------------------------------------------------------
 #
 # Stop matching used to be a naive suffix scan over the whole generated
 # tail after every committed token — O(len^2) per request over its
-# lifetime.  Both fixes below share one automaton: the classic KMP
+# lifetime.  The matcher below is the classic KMP automaton: the
 # failure function, whose state after feeding tokens t_1..t_k is the
 # length of the longest prefix of the pattern that is a suffix of the
 # fed stream.  state == len(pattern) therefore holds exactly when
 # ``t[-len(s):] == list(s)`` — the old check, token for token — but
 # each ``feed`` is O(1) amortized.
-#
-# The same automaton compiles to fixed-shape device tables (pattern
-# rows, lengths, failure arrays, states) so the decode *megastep* can
-# advance stop matching inside the compiled scan — the JSON-grammar
-# trick (constraint-as-data) applied to stops.  Capacity is capped at
-# STOP_MAX_SEQS patterns of STOP_MAX_LEN tokens per request; requests
-# beyond the caps simply take the host-side (still incremental) path.
-
-#: device stop tables hold at most this many patterns per request
-STOP_MAX_SEQS = 4
-#: ... of at most this many tokens each
-STOP_MAX_LEN = 8
 
 
 def _kmp_fail(pat):
     """KMP failure function as a length ``m+1`` table: ``fail[s]`` is
     the longest proper prefix of ``pat[:s]`` that is also its suffix
-    (``fail[0] = fail[1] = 0``).  ``fail[s] < s`` for s >= 1, which is
-    what bounds the device fail-loop at ``len(pat)`` iterations."""
+    (``fail[0] = fail[1] = 0``).  ``fail[s] < s`` for s >= 1, so the
+    fail-chase of :meth:`StopMatcher.feed` ends."""
     m = len(pat)
     fail = [0] * (m + 1)
     k = 0
@@ -405,11 +392,9 @@ class StopMatcher:
 
     One KMP automaton per stop pattern; ``feed(token)`` advances all of
     them in O(total pattern length) worst case, O(1) amortized, and
-    latches ``hit`` on the first match.  The per-pattern ``states``
-    tuple is the exact device representation the megastep's stop
-    tables carry, so host and compiled matching can never disagree —
-    and a request re-homed onto another engine rebuilds its state by
-    replaying its committed tokens (``feed_all``)."""
+    latches ``hit`` on the first match.  A request re-homed onto
+    another engine rebuilds its state by replaying its committed
+    tokens (``feed_all``)."""
 
     __slots__ = ("patterns", "fails", "states", "hit")
 
@@ -440,87 +425,6 @@ class StopMatcher:
         for t in tokens:
             self.feed(t)
         return self.hit
-
-
-def stops_fit(stop_sequences: Sequence[Sequence[int]],
-              max_seqs: int = STOP_MAX_SEQS,
-              max_len: int = STOP_MAX_LEN) -> bool:
-    """Whether a request's stop sequences fit the fixed-shape device
-    stop tables (the megastep's eligibility check; oversized requests
-    fall back to host-side matching at megastep = 1)."""
-    return (len(stop_sequences) <= max_seqs and
-            all(len(s) <= max_len for s in stop_sequences))
-
-
-def stop_table_rows(matcher: Optional[StopMatcher],
-                    max_seqs: int = STOP_MAX_SEQS,
-                    max_len: int = STOP_MAX_LEN):
-    """One request's device stop tables from its live host matcher:
-    ``(pat [J, L] i32, plen [J] i32, fail [J, L+1] i32, state [J]
-    i32)``, zero/-1 padded.  Pattern rows pad with -1 (no token id is
-    negative, so padding never matches); unused pattern slots have
-    ``plen == 0`` and are ignored by :func:`stops_matched`.  ``None``
-    (no stops) returns the all-inert tables an empty batch slot uses."""
-    pat = np.full((max_seqs, max_len), -1, np.int32)
-    plen = np.zeros(max_seqs, np.int32)
-    fail = np.zeros((max_seqs, max_len + 1), np.int32)
-    state = np.zeros(max_seqs, np.int32)
-    if matcher is None:
-        return pat, plen, fail, state
-    if len(matcher.patterns) > max_seqs or \
-            any(len(p) > max_len for p in matcher.patterns):
-        raise ValueError(
-            f"stop sequences exceed the device table caps "
-            f"({max_seqs} patterns x {max_len} tokens); gate on "
-            "stops_fit() first")
-    for j, p in enumerate(matcher.patterns):
-        pat[j, :len(p)] = p
-        plen[j] = len(p)
-        fail[j, :len(p) + 1] = matcher.fails[j]
-        state[j] = matcher.states[j]
-    return pat, plen, fail, state
-
-
-def stops_advance(tokens, pat, plen, fail, state):
-    """Advance per-slot KMP stop states over one committed token each —
-    the device mirror of :meth:`StopMatcher.feed`, pure jnp, traced
-    inside the decode megastep's scan.
-
-    ``tokens`` is ``[b] i32`` (this iteration's committed token per
-    slot); the tables are ``pat [b, J, L]``, ``plen [b, J]``,
-    ``fail [b, J, L+1]``, ``state [b, J]``.  The KMP fail-chase — a
-    data-dependent ``while`` on the host — runs as a fixed ``L``-
-    iteration loop: each applied failure transition strictly decreases
-    the state, so ``L`` iterations always reach the fixpoint.  Returns
-    the new ``[b, J]`` states."""
-    import jax
-    import jax.numpy as jnp
-    L = pat.shape[-1]
-    tokb = tokens[:, None]
-
-    def _char_at(s):
-        # pat[b, j, s] with the matched state (s == plen) clamped in
-        # range; a matched pattern's char is padding (-1), which never
-        # equals a real token, so the clamp cannot fabricate a match
-        return jnp.take_along_axis(
-            pat, jnp.minimum(s, L - 1)[..., None], axis=2)[..., 0]
-
-    def _body(_, s):
-        chase = (s > 0) & (_char_at(s) != tokb)
-        f = jnp.take_along_axis(fail, s[..., None], axis=2)[..., 0]
-        return jnp.where(chase, f, s)
-
-    s = jax.lax.fori_loop(0, L, _body, state)
-    return jnp.where(_char_at(s) == tokb, s + 1,
-                     jnp.zeros_like(s))
-
-
-def stops_matched(state, plen):
-    """``[b] bool`` — whether any (real) stop pattern of each slot has
-    matched: ``state == plen`` with ``plen > 0`` (unused pattern slots
-    sit at plen 0 and can never fire)."""
-    import jax.numpy as jnp
-    return jnp.any((state == plen) & (plen > 0), axis=1)
 
 
 # --------------------------------------------------------------------

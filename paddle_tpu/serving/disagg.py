@@ -441,8 +441,8 @@ class DecodeEngine(ServingEngine):
 
     def decode_step(self) -> bool:
         """The compute half of :meth:`step`: one decode dispatch
-        (megastep-aware via ``_decode_any``) plus the host-tier demote
-        sweep and pool gauges. Only touches this engine's own pool and
+        (``_decode_any``) plus the host-tier demote sweep and pool
+        gauges. Only touches this engine's own pool and
         internally-locked shared planes (LoRA pool, tier manager,
         metrics), so the threaded router may run decode halves of
         workers with *distinct* pools concurrently."""

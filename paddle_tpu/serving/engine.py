@@ -139,13 +139,12 @@ from ..dygraph.tensor import Tensor
 from ..distributed.sharding import (SERVING_TP_RULES, kv_pool_shardings,
                                     mesh_cache_key, parse_serving_mesh,
                                     serving_mesh)
-from ..models.generation import (decode_megastep_paged, draft_ngram,
-                                 step_entry, verify_step_paged)
+from ..models.generation import (draft_ngram, step_entry,
+                                 verify_step_paged)
 from ..resilience.injector import fault_point
 from ..resilience.retry import RetryError, RetryPolicy
-from .decoding import (STOP_MAX_LEN, STOP_MAX_SEQS, DecodeParams,
-                       StopMatcher, request_key, sample_first,
-                       stop_table_rows, stops_fit)
+from .decoding import (DecodeParams, StopMatcher, request_key,
+                       sample_first)
 from .kv_cache import BlockKVCache
 from .seam import served
 from .kv_tier import HostBlockStore, TierManager
@@ -196,8 +195,8 @@ _ACCOUNT_KEYS = (
 
 
 class _Stamps:
-    """One dispatch the device was given, a decode step (single, megastep
-    or verify) or a prefill group, from its dispatch to its commit.
+    """One dispatch the device was given, a decode step (single or
+    verify) or a prefill group, from its dispatch to its commit.
 
     ``id`` is its place in the engine's one sequence of dispatches, which
     is the order the device runs them in; the spans of one dispatch carry
@@ -300,14 +299,10 @@ class Request:
         self._key = request_key(self.decode.seed)
         # incremental stop-sequence automaton, fed once per committed
         # token in _append_token (O(1) amortized; replaces the old
-        # O(len^2) full-suffix scan). Its per-pattern states are the
-        # exact device representation the decode megastep carries, and
-        # it travels with the object through adopts and re-homes.
+        # O(len^2) full-suffix scan). It travels with the object
+        # through adopts and re-homes.
         self._stop = (StopMatcher(self.decode.stop_sequences)
                       if self.decode.stop_sequences else None)
-        # whether the stops fit the fixed-shape device stop tables
-        # (megastep eligibility, computed once)
-        self._stops_fit = stops_fit(self.decode.stop_sequences)
         self._cursor = None        # JsonCursor when json_mode is on
         self._lora_held = False    # this request pins its tenant page
         self.rehomed = False       # recovered from a killed replica
@@ -337,9 +332,9 @@ class Request:
         self.admitted_at: Optional[float] = None
         self.first_token_at: Optional[float] = None
         # engine-clock stamp of every committed token, one clock read
-        # per commit shared by all the tokens it commits (so the n
-        # tokens of one megastep share a stamp); token_at[0] is
-        # first_token_at, the same float
+        # per commit shared by all the tokens it commits (so the
+        # accepted drafts of one verify step share a stamp);
+        # token_at[0] is first_token_at, the same float
         self.token_at: List[float] = []
         self.finished_at: Optional[float] = None
         self._done = threading.Event()
@@ -459,9 +454,7 @@ class ServingEngine:
                  clock=None, kv_pool=None,
                  lora_rank: Optional[int] = None,
                  lora_max_adapters: Optional[int] = None,
-                 lora_pool=None, grammar=None, kv_tier=None,
-                 megastep: Optional[int] = None,
-                 dispatch_ahead: Optional[bool] = None):
+                 lora_pool=None, grammar=None, kv_tier=None):
         g = _flags.get_flags(["serving_max_slots", "serving_max_len",
                               "serving_max_queue",
                               "serving_prefill_buckets",
@@ -469,8 +462,6 @@ class ServingEngine:
                               "serving_idle_wait",
                               "serving_spec_tokens",
                               "serving_spec_ngram",
-                              "serving_megastep",
-                              "serving_dispatch_ahead",
                               "serving_block_size",
                               "serving_num_blocks",
                               "serving_prefix_cache",
@@ -542,29 +533,6 @@ class ServingEngine:
             raise ValueError(
                 f"spec_tokens {self.spec_tokens} leaves no room in "
                 f"max_len={self.max_len} slots")
-        # Device-resident decode megasteps: N decode iterations per
-        # compiled dispatch, one host commit per megastep. Constructor/
-        # flag state like the SLO knobs — never set_flags mid-run.
-        self.megastep = int(megastep if megastep is not None
-                            else g["serving_megastep"])
-        if self.megastep < 1:
-            raise ValueError(
-                f"megastep must be >= 1, got {self.megastep}")
-        if self.megastep > 1:
-            spec.require("megastep", f"megastep={self.megastep}")
-        if self.megastep > 1 and self.spec_tokens > 0:
-            raise ValueError(
-                "megastep > 1 cannot combine with speculative decoding "
-                "(FLAGS_serving_spec_tokens > 0): the draft-verify "
-                "round-trip is inherently per-host-step")
-        self.dispatch_ahead = bool(
-            dispatch_ahead if dispatch_ahead is not None
-            else g["serving_dispatch_ahead"])
-        if self.dispatch_ahead and self.megastep <= 1:
-            raise ValueError(
-                "dispatch_ahead requires megastep > 1 "
-                "(FLAGS_serving_megastep); there is no megastep "
-                "pipeline to fill at N=1")
         self.buckets = (_parse_buckets(g["serving_prefill_buckets"],
                                        self.max_len)
                         if buckets is None else
@@ -781,13 +749,7 @@ class ServingEngine:
             "live weight hot-swaps applied to this engine's model "
             "(0 = the weights it was built with)").labels(engine=eid)
         self._weight_version_g.set(0)
-        # dispatch-ahead speculation: megastep k+1's un-synced device
-        # result, enqueued while k's commit ran; consumed by the next
-        # decode only when the scheduler state it assumed is unchanged
         self._step_no = 0                 # guarded-by: _step_lock
-        self._ahead = None                # guarded-by: _step_lock
-        self._ahead_hits = 0              # guarded-by: _step_lock
-        self._ahead_misses = 0            # guarded-by: _step_lock
         # the single decode step is dispatched one ahead of its fetch:
         # the step in flight between two _decode calls (_Flight), the
         # steps dispatched before the step before them was fetched, and
@@ -877,9 +839,6 @@ class ServingEngine:
             "_prefix_miss_reqs": "_step_lock",
             "_weight_version": "_step_lock",
             "_qerr_max": "_step_lock",
-            "_ahead": "_step_lock",
-            "_ahead_hits": "_step_lock",
-            "_ahead_misses": "_step_lock",
             "_flight": "_step_lock",
             "_prefills": "_step_lock",
             "_admit_ahead_dispatches": "_step_lock",
@@ -1749,8 +1708,8 @@ class ServingEngine:
         in. Its first tokens are fetched and committed by
         :meth:`_land_prefill`: at once where a row's first token is the
         host's to draw from the logits (a sampled or grammar row) or
-        where a step decodes several tokens (drafts, a megastep: both
-        are built from tokens the host holds), else after the decode
+        where a step decodes several tokens (drafts: they are built
+        from tokens the host holds), else after the decode
         step behind the round's groups is on the device
         (:meth:`_decode_attempt`). Nothing of this is configured: it is
         read off the admitted requests. Returns rows admitted."""
@@ -1785,7 +1744,7 @@ class ServingEngine:
             self._active[row] = req
         pf = _Prefill(bucket, tuple(live), lg, nxt, keys, qerr, behind,
                       False, st)
-        if not self.spec_tokens and self.megastep <= 1 and all(
+        if not self.spec_tokens and all(
                 req.decode.is_greedy and req._cursor is None
                 for req, _, _ in live):
             self._prefills.append(pf)
@@ -1925,9 +1884,9 @@ class ServingEngine:
 
     # ------------------------------------------------------------ decode
     def _send(self, host):  # holds: _step_lock
-        """The one host->device copy of a decode / verify / megastep
-        operand (an array, or a tuple of arrays sent together), placed
-        as the entries' ``in_shardings`` name it: replicated over the
+        """The one host->device copy of a decode / verify operand (an
+        array, or a tuple of arrays sent together), placed as the
+        entries' ``in_shardings`` name it: replicated over the
         serving mesh, so that no call re-shards it, else on the default
         device. Everything else a dispatch is given is an array the
         device already holds."""
@@ -1958,7 +1917,7 @@ class ServingEngine:
 
     def _batch(self):  # holds: _step_lock
         """Which request sits in which slot: what the per-request
-        operands (sampling parameters, stop tables, LoRA pages) are
+        operands (sampling parameters, LoRA pages) are
         valid against."""
         return tuple((slot, req.id) for slot, req in self._active.items())
 
@@ -2132,7 +2091,7 @@ class ServingEngine:
         running (and the step in flight and the prefill groups not
         fetched yet, whose tokens are nobody's now), and start from
         zeroed pools with the prefix cache flushed."""
-        self._ahead = self._flight = None
+        self._flight = None
         self._prefills = []
         self._shed_active(err)
         self.cache.rebuild_pools()
@@ -2141,8 +2100,8 @@ class ServingEngine:
         _runlog.log_event("serving_pool_rebuild", error=str(err))
 
     def _note_dispatch(self):  # holds: _step_lock
-        """Count one decode / verify / megastep dispatch, whether every
-        live row was greedy, and whether its inputs were resident.
+        """Count one decode / verify dispatch, whether every live row
+        was greedy, and whether its inputs were resident.
         The first is what the step's ``lax.cond`` on "does any row
         sample" reads on the device from the ``samp`` this batch was
         given: ``sampler_skipped / sampler_dispatches`` in
@@ -2150,8 +2109,8 @@ class ServingEngine:
         alone, without the processor chain and the draws. The second:
         ``inputs_resident / inputs_dispatches`` is the share that
         copied nothing to the device but the step's own small arrays
-        (tokens, lengths, the megastep's live / budget / stop state),
-        every other operand being the array the device held."""
+        (tokens, lengths), every other operand being the array the
+        device held."""
         self._sampler_dispatches += 1
         if all(req.decode.is_greedy for req in self._active.values()):
             self._sampler_skipped += 1
@@ -2495,291 +2454,10 @@ class ServingEngine:
         fl, self._flight = self._flight, None
         return 0 if fl is None else self._land(fl)
 
-    # ------------------------------------------------ decode megasteps
-    def _choose_megastep(self) -> int:  # holds: _step_lock
-        """The megastep N this decode runs at: the configured
-        ``megastep`` unless the active batch needs the per-token host
-        loop — a grammar-cursored row (the mask is recomputed host-side
-        every token), stops beyond the fixed device-table caps, or a
-        hard deadline too tight to absorb a whole megastep (the budget
-        caps N so a dying client is reaped within one step, never a
-        megastep late). Falls all the way back to 1, never to an
-        intermediate N: the engine owns exactly two decode compile
-        surfaces — ``decode_megastep_paged{n=N}`` and the
-        ``decode_step_paged`` fallback — which is what
-        ``predict_serving_compiles(megastep=N)`` emits."""
-        n = self.megastep
-        if n <= 1 or not self._active:
-            return 1
-        tpot = self._tpot_cost_ms()
-        now = None
-        for req in self._active.values():
-            if req._cursor is not None or not req._stops_fit:
-                return 1
-            if req.hard_deadline is not None and tpot > 0:
-                if now is None:
-                    now = self._clock()
-                if (req.hard_deadline - now) * 1e3 < n * tpot:
-                    return 1
-        return n
-
-    def _stop_consts(self):  # holds: _step_lock
-        """The megastep's per-request constants ``(eos [b], pat
-        [b, J, L], plen [b, J], fail [b, J, L+1])``, resident while
-        the batch's membership stands (a request's EOS id and stop
-        patterns never change; its matcher's *state* does, and goes
-        with every dispatch)."""
-        b, J, L = self.max_slots, STOP_MAX_SEQS, STOP_MAX_LEN
-
-        def build():
-            eos = np.full(b, -1, np.int32)
-            pat = np.full((b, J, L), -1, np.int32)
-            plen = np.zeros((b, J), np.int32)
-            fail = np.zeros((b, J, L + 1), np.int32)
-            for slot, req in self._active.items():
-                if req.eos_token_id is not None:
-                    eos[slot] = int(req.eos_token_id)
-                if req._stop is not None:
-                    pat[slot], plen[slot], fail[slot], _ = \
-                        stop_table_rows(req._stop)
-            return eos, pat, plen, fail
-
-        return self._resident("stops", self._batch(), build)
-
-    def _megastep_fn(self, n: int):
-        """The compiled megastep entry this engine dispatches."""
-        return decode_megastep_paged(self.model, n, self.mesh,
-                                     self.kv_dtype,
-                                     self._lora_shape)["fn"]
-
-    def _megastep_inputs(self, n: int,
-                         st: _Stamps):  # holds: _step_lock
-        """One megastep dispatch's fixed-shape inputs. The tokens and
-        keys are the last dispatch's carried outputs while the batch
-        stands as its commit left it; tables, sampling parameters,
-        stop tables and LoRA pages are the step entries' resident
-        operands (one state, :meth:`_resident`: a dispatch-ahead
-        re-dispatch feeds the same arrays); lengths, ``live``, the
-        budgets and the stop matchers' states are this dispatch's own
-        and are sent with it. Empty slots are frozen from iteration 0
-        (``live=False``) and write their strays into the trash block
-        exactly as the single step does."""
-        b = self.max_slots
-        with _profiler.RecordEvent("serving.decode.inputs",
-                                   {"flight": st.id, "ahead": 0}) as ev:
-            self._resent = False
-            last, keys = self._carried()
-            live = np.zeros(b, bool)
-            budget = np.ones(b, np.int32)
-            state = np.zeros((b, STOP_MAX_SEQS), np.int32)
-            for slot, req in self._active.items():
-                live[slot] = True
-                budget[slot] = req.max_new_tokens - len(req.tokens)
-                if req._stop is not None:
-                    state[slot] = stop_table_rows(req._stop)[3]
-            eos, pat, plen, fail = self._stop_consts()
-            args = (self._tokens_arg(last),
-                    self._send(self.cache.lengths.copy()),
-                    self._tables_arg(), self.cache.arrays(),
-                    self._build_samp(keys), self._send(live),
-                    self._send(budget), eos,
-                    (pat, plen, fail, self._send(state)))
-            if self._lora_shape is not None:
-                args = args + (self._lora_args(),)
-        st.t_dispatch = st.t_launched = ev.t0
-        return args
-
-    def _ahead_snapshot(self, n: int, extra_tokens: int = 0):
-        """The scheduler state a speculative dispatch assumes: the
-        megastep N, the weight and flag-plane versions, and each active
-        slot's (slot, request id, committed length) — with
-        ``extra_tokens`` added per slot when snapshotting the
-        *post-commit* state a pre-commit dispatch runs against."""
-        return (n, self._weight_version, _flags.version(),
-                tuple(sorted(
-                    (slot, req.id, len(req.tokens) + extra_tokens)
-                    for slot, req in self._active.items())))
-
-    def _dispatch_ahead(self, n: int, out,
-                        t_dispatch: int):  # holds: _step_lock
-        """Enqueue megastep k+1 from k's still-un-synced device carry
-        outputs, before the host blocks on k's results — the device
-        queue stays fed while the host commits. The dispatch assumes
-        k commits with no finishes, no admissions, no reaps and no
-        weight/flag/pool changes; :meth:`_take_ahead` validates all of
-        that before consuming.
-
-        The speculative step consumes k's pools like any paged entry,
-        so from here on the KV lives in **its** returned pools, which
-        this returns for k's commit to bind. A discarded speculation
-        therefore leaves its rows behind: each live slot's next ``n``
-        rows, all at or beyond the slot's committed length, where
-        nothing reads before the re-dispatched step writes them again
-        (the invariant that already covers bucket padding, rejected
-        drafts and the trash block). Only its tokens are dropped.
-
-        No span bounds this dispatch alone: its flight is dispatched at
-        ``t_dispatch``, the close of k's ``serving.decode``, and launched
-        by the entry of k's ``serving.decode.fetch``."""
-        (_toks, _finish, tok_f, pos_f, pools_f, keys_f, live_f,
-         rem_f, st_f, _qerr) = out
-        st = self._stamps()
-        st.t_dispatch = st.t_launched = t_dispatch
-        self._resent = False
-        # the operands k was dispatched with, as the device holds
-        # them: k's carry for what a step advances, the resident
-        # constants of k's batch for the rest (no copy is made)
-        eos, spat, splen, sfail = self._stop_consts()
-        args = (tok_f, pos_f, self._tables_arg(), pools_f,
-                self._build_samp(keys_f), live_f, rem_f,
-                eos, (spat, splen, sfail, st_f))
-        if self._lora_shape is not None:
-            args = args + (self._lora_args(),)
-        ahead_out = self._call_paged(self._megastep_fn(n), args, pools_f)
-        self._note_dispatch()
-        self._ahead = {
-            "n": n,
-            "stamps": st,
-            "snap": self._ahead_snapshot(n, extra_tokens=n),
-            "leaf": ahead_out[4][0][0],
-            "lora_arrays": (None if self._lora_shape is None
-                            else self.lora_pool.arrays),
-            "out": ahead_out,
-        }
-        return ahead_out[4]
-
-    def _take_ahead(self, n: int):  # holds: _step_lock
-        """Consume the stored speculative megastep iff the live
-        scheduler state matches what it assumed — same N, same
-        (slot, request, length) composition, same weight/flag
-        versions, and the cache still holds *the arrays* the
-        speculation returned (identity check on a pool leaf: any
-        prefill, demotion, promotion or adoption rebinds them).
-        Single-shot: hit or miss, the slot clears."""
-        ah, self._ahead = self._ahead, None
-        if ah is None:
-            return None
-        ok = (ah["n"] == n and
-              ah["snap"] == self._ahead_snapshot(n) and
-              self.cache.arrays()[0][0] is ah["leaf"] and
-              (self._lora_shape is None or
-               ah["lora_arrays"] is self.lora_pool.arrays))
-        if not ok:
-            self._ahead_misses += 1
-            _monitor.stat_add("STAT_serving_ahead_misses",
-                              len(ah["snap"][3]))   # in rows, as _land
-            return None
-        self._ahead_hits += 1
-        _monitor.stat_add("STAT_serving_ahead_hits", len(self._active))
-        return ah["stamps"], ah["out"]
-
-    def _megastep_attempt(self, n: int):
-        """One megastep dispatch attempt (the serving.step fault
-        site). The fault check fires BEFORE the speculation is
-        consumed, so an injected skip leaves the stored dispatch valid
-        for the next attempt — the state it assumed is untouched.
-        Returns the dispatch's flight and the entry's outputs."""
-        kind = fault_point("serving.step")
-        if kind == "skip":
-            raise _SkipStep("injected skip of one decode megastep")
-        taken = self._take_ahead(n)
-        if taken is not None:
-            return taken
-        st = self._stamps()
-        args = self._megastep_inputs(n, st)
-        out = self._call_paged(self._megastep_fn(n), args, args[3])
-        self._note_dispatch()
-        return st, out
-
-    def _decode_megastep(self, n: int) -> int:  # holds: _step_lock
-        """One device-resident megastep over every occupied slot: N
-        decode iterations inside one compiled dispatch, then ONE host
-        commit — each slot's committed tokens replayed through the
-        ordinary :meth:`_append_token` path (finish reasons, tracing
-        marks and session state re-derived exactly; the device and
-        host early-exit conditions are equivalent by construction, the
-        token-identity oracle). Returns tokens produced."""
-        if not self._active:
-            return 0
-        n_active = len(self._active)
-        seq = self._dispatch_seq
-        try:
-            with _profiler.RecordEvent("serving.decode") as ev:
-                st, out = RetryPolicy.from_flags(
-                    "serving.step").call(self._megastep_attempt, n)
-                # 0: the step was dispatched ahead, a round ago
-                ev.args = {"launches": self._dispatch_seq - seq}
-        except (_SkipStep, _PoolsLost):
-            return 0
-        except RetryError as e:
-            self._shed_active(e)
-            return 0
-        taken = self._dispatch_seq == seq
-        if not taken:
-            st.t_launched = ev.t1
-        (toks, finish, tok_f, _pos_f, pools_f, keys_f, _live_f,
-         _rem_f, _st_f, qerr) = out
-        if self.dispatch_ahead:
-            # enqueue k+1 behind k on the device BEFORE the host
-            # blocks on k's results: commit work below overlaps it.
-            # k+1 consumed k's pools; the cache binds what it returned
-            try:
-                pools_f = self._dispatch_ahead(n, out, ev.t1)
-            except _PoolsLost:
-                return 0
-        with _profiler.RecordEvent("serving.decode.fetch",
-                                   {"flight": st.id}) as fetch:
-            toks = np.asarray(toks)          # syncs megastep k
-            finish = np.asarray(finish)
-            keys_arr = np.asarray(keys_f)
-        st.t_fetch, st.t_fetched = fetch.t0, fetch.t1
-        if self._ahead is not None:
-            self._ahead["stamps"].t_launched = fetch.t0
-        with _profiler.RecordEvent("serving.decode.commit",
-                                   {"flight": st.id}) as commit:
-            now = self._clock()              # the commit's one stamp
-            self.cache.set_arrays(pools_f)
-            self._note_qerr(qerr, n * n_active)
-            produced = 0
-            for slot, req in list(self._active.items()):
-                f = int(finish[slot])
-                ncommit = (f + 1) if f >= 0 else n
-                # iteration i wrote its token's KV at pos0 + i; a slot
-                # finishing at iteration f committed f+1 tokens, a live
-                # slot all n — lengths stay prompt + generated - 1, the
-                # same invariant the single step keeps
-                self.cache.advance(slot, ncommit)
-                for i in range(ncommit):
-                    self._append_token(req, int(toks[i, slot]), now)
-                    produced += 1
-                    if req.state != "running":
-                        break
-                if req.state == "running":
-                    req._key = keys_arr[slot].copy()
-            commit.args = {"tokens": produced, "flight": st.id}
-            # what a miss of the speculation, or the single step a
-            # fallback takes, feeds next (a frozen row's are never read)
-            self._carry = (self._progress(), tok_f, keys_f)
-        st.t_committed = commit.t1
-        # per-token pace: the megastep's time on the device spread over
-        # the tokens each slot actually committed (TPOT samples divide
-        # by tokens, not steps, so SLO admission stays calibrated at
-        # megastep > 1)
-        self._landed(st, n_active, produced / n_active, ahead=taken)
-        if _runlog.enabled():
-            _runlog.log_event("serving_megastep", n=n, active=n_active,
-                              produced=produced)
-        return produced
-
     def _decode_any(self) -> int:  # holds: _step_lock
         """Route one decode round: the draft-verify step when
-        speculation is on, else the device-resident megastep when
-        eligible, else the per-token single step (megastep=1, grammar
-        rows, oversized stops, tight deadlines). A fallback round
-        drops any stored speculation — its snapshot could never match
-        a state the single step advanced — and a megastep round first
-        commits the single step in flight, which is dispatched one
-        ahead of its fetch (:meth:`_decode`). Whatever runs is one
+        speculation is on, else the single step, which is dispatched
+        one ahead of its fetch (:meth:`_decode`). Whatever runs is one
         ``serving.decode_step`` span, from building the step's tokens
         to its last commit; an idle engine records none."""
         if self.cache.pool.epoch != self._pool_epoch:
@@ -2789,19 +2467,13 @@ class ServingEngine:
             self._shed_active(_PoolsLost(
                 "shared KV pool rebuilt by a co-located engine"))
         if not self._active:
-            self._ahead = None
             self._drain()
             return 0
-        n = (self.spec_tokens + 1 if self.spec_tokens
-             else self._choose_megastep())
         with _profiler.RecordEvent(
                 "serving.decode_step",
-                {"active": len(self._active), "n": n}):
+                {"active": len(self._active), "n": self.spec_tokens + 1}):
             if self.spec_tokens:
                 return self._spec_decode()
-            if n > 1:
-                return self._drain() + self._decode_megastep(n)
-            self._ahead = None
             return self._decode()
 
     # ------------------------------------------------- speculative decode
@@ -3221,8 +2893,7 @@ class ServingEngine:
         anyway (:class:`_Stamps`, :meth:`_landed`): counts, and sums in
         ms that only grow, so the difference between two calls is what
         happened between them. A flight is one dispatch that was
-        committed (a decode step: single, megastep or verify; a prefill
-        group).
+        committed (a decode step, single or verify; a prefill group).
 
         - ``decode_flights``; ``decode_host_ms``: the host's own work on
           them, dispatch to launched plus fetched to committed;
@@ -3286,8 +2957,6 @@ class ServingEngine:
             qerr_max = self._qerr_max
             prefix_hit_reqs = self._prefix_hit_reqs
             prefix_miss_reqs = self._prefix_miss_reqs
-            ahead_hits = self._ahead_hits
-            ahead_misses = self._ahead_misses
             ahead_dispatches = self._ahead_dispatches
             admit_ahead_dispatches = self._admit_ahead_dispatches
             ahead_rows_committed = self._ahead_rows_committed
@@ -3343,12 +3012,6 @@ class ServingEngine:
             out["spec_acceptance_rate"] = (
                 round(spec_accepted / spec_proposed, 4)
                 if spec_proposed else None)
-        if self.megastep > 1:
-            out["megastep"] = self.megastep
-            out["dispatch_ahead"] = self.dispatch_ahead
-            if self.dispatch_ahead:
-                out["ahead_hits"] = ahead_hits
-                out["ahead_misses"] = ahead_misses
         # single decode steps dispatched before the step before them was
         # fetched (of ``sampler_dispatches`` when nothing else decodes),
         # and their rows: committed, or computed for a request that had

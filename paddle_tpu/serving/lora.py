@@ -12,7 +12,7 @@ freely in the same batch of the same compiled executable, and loading
 or evicting an adapter is a functional ``.at[:, page].set`` write on
 the pool arrays (the ``swap_weights`` data-not-constants mechanism):
 ZERO new compiles, an invariant ``predict_serving_compiles(lora=...)``
-encodes and obs_smoke asserts.
+encodes and the serving tests assert.
 
 Page bookkeeping reuses the KV plane's ref-counted
 :class:`~paddle_tpu.serving.kv_cache.BlockAllocator` verbatim: a
@@ -225,7 +225,7 @@ class LoRAPool:
 
 def make_adapter(cfg, rank: int, seed: int = 0,
                  scale: float = 0.05) -> Dict[str, np.ndarray]:
-    """A seeded random adapter state dict for tests/loadgen/obs_smoke.
+    """A seeded random adapter state dict for tests and loadgen.
 
     Both factors are drawn non-zero (classic LoRA zero-inits B, which
     would make every output base-identical — useless for asserting

@@ -73,10 +73,6 @@ import jax.numpy as jnp
 #: what an engine can be asked for beyond the plain paged loop
 FEATURES = frozenset({
     "prefix_cache",     # prefix reuse (prefix_cache=True)
-    "megastep",         # megastep > 1 and its pipelining (dispatch_ahead=:
-                        # a missed speculation runs again). The single
-                        # step dispatched one ahead of its fetch is no
-                        # feature: every model's engine decodes that way
     "speculative",      # spec_tokens > 0
     "lora",             # lora_rank > 0 / lora_pool=
     "mesh",             # mesh= / FLAGS_serving_mesh

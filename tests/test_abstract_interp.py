@@ -13,8 +13,7 @@ Three legs:
 - verifier integration: `shapes.infer` is a registered check, gated
   behind FLAGS_check_shapes unless explicitly selected;
 - the recompile predictor: executor cache-key mirror and the serving
-  bucket/prefix model (the live cross-check against the compile
-  tracker is tools/obs_smoke.py's predicted==observed gate).
+  bucket/prefix model, cross-checked against the live compile tracker.
 """
 
 import os
@@ -444,8 +443,7 @@ def test_serving_predictor_spec_tokens_take_verify_path():
 
 
 def test_serving_predictor_matches_live_engine():
-    """In-process predicted == observed (the CI-gate version of this
-    cross-check runs in tools/obs_smoke.py)."""
+    """In-process predicted == observed."""
     from paddle_tpu import observability
     from paddle_tpu.models.gpt import GPTConfig, GPTForCausalLM
     from paddle_tpu.serving import ServingEngine

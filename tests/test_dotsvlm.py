@@ -360,7 +360,6 @@ def test_the_latent_kind_allocates_frees_zeroes_and_leaks_nothing(tiny):
 
 REFUSED = {
     "prefix_cache": dict(prefix_cache=True),
-    "megastep": dict(megastep=2),
     "speculative": dict(spec_tokens=2),
     "lora": dict(lora_rank=4),
     "int8_pool": dict(kv_dtype="int8"),

@@ -278,8 +278,7 @@ def test_prefill_then_decode_through_the_engine_matches_the_reference(tiny):
     assert engine.cache.allocator.leaked() == 1      # the one trash block
     assert stats["pool_inplace"] == stats["pool_dispatches"] > 20
     for feature, kw in (("prefix_cache", {"prefix_cache": True}),
-                        ("speculative", {"spec_tokens": 2}),
-                        ("megastep", {"megastep": 4})):
+                        ("speculative", {"spec_tokens": 2})):
         with pytest.raises(ValueError, match=f"lfm2 is not served with "
                                              f"{feature}"):
             engine_of(model, **kw)

@@ -697,8 +697,8 @@ def test_flash_attention_odd_head_dim():
 # These retrace prefill+decode per combination under the Pallas
 # interpreter, which is heavy inside the full tier-1 run — they carry
 # the `slow` marker and run in the ci.sh serving gate (step 6, which
-# invokes this file without the tier-1 `-m 'not slow'` filter) and in
-# tools/obs_smoke.py's int8 phase. The kernel-vs-oracle and
+# invokes this file without the tier-1 `-m 'not slow'` filter). The
+# kernel-vs-oracle and
 # quantizing-scatter tests above stay in tier-1.
 # ---------------------------------------------------------------------------
 

@@ -751,14 +751,13 @@ def test_http_broken_pipe_cancels_inflight_request(model):
 
 # ------------------------------------------------- the pools' ownership
 @pytest.mark.parametrize("mode", [
-    {}, {"spec_tokens": 2}, {"megastep": 4, "dispatch_ahead": True},
-    {"kv_dtype": "int8"}])
+    {}, {"spec_tokens": 2}, {"kv_dtype": "int8"}])
 def test_every_paged_dispatch_consumes_its_pools(model, mode):
     """The paged entries own the pools they are handed: after a step
     the arrays the cache held before it are deleted (their rows were
     written in place), the cache holds their successors, and
     ``STAT_serving_pool_inplace`` counts every dispatch — prefill,
-    decode, verify, megastep and the dispatch-ahead alike."""
+    decode and verify alike."""
     monitor.reset()
     eng = ServingEngine(model, max_slots=2, max_len=32, buckets=[8, 16],
                         max_queue=16, block_size=4, **mode)
@@ -788,12 +787,10 @@ def test_every_paged_dispatch_consumes_its_pools(model, mode):
     assert st["pool_inplace_share"] == 1.0
     assert monitor.stat_get("STAT_serving_pool_inplace") == \
         st["pool_dispatches"]
-    if not mode.get("dispatch_ahead"):
-        # one dispatch for each timed call (a speculative megastep is
-        # dispatched inside the decode call that it follows)
-        assert st["pool_dispatches"] == sum(
-            monitor.stat_get(f"STAT_serving_{k}_calls")
-            for k in ("prefill", "decode", "verify"))
+    # one dispatch for each timed call
+    assert st["pool_dispatches"] == sum(
+        monitor.stat_get(f"STAT_serving_{k}_calls")
+        for k in ("prefill", "decode", "verify"))
     for p, r in zip(_prompts((3, 6, 11), seed=21), reqs):
         if mode.get("kv_dtype") == "int8":
             continue        # int8 KV is not token-identical to greedy

@@ -7,8 +7,7 @@ The contracts under test:
 - ``ServingEngine.cancel`` terminates a request at whatever stage it
   has reached (queued / in a slot mid-decode) releasing its KV row and
   LoRA pin; it is idempotent (double-cancel and unknown ids are
-  no-ops, never double-releases) and pure host-side (zero compiles —
-  the predictor claim is re-proven end to end in tools/obs_smoke.py);
+  no-ops, never double-releases) and pure host-side (zero compiles);
 - a ``deadline_ms`` hard deadline expires a request *between decode
   steps*: the slot is reclaimed in the very step that notices, and is
   reusable for admission within that same step;

@@ -256,7 +256,6 @@ def test_the_second_models_engine_reports_both_kinds(mellum):
 
 REFUSED = {
     "prefix_cache": dict(prefix_cache=True),
-    "megastep": dict(megastep=4),
     "speculative": dict(spec_tokens=2),
     "lora": dict(lora_rank=4),
     "mesh": "mesh",

@@ -1,8 +1,8 @@
 """The decode step's inputs stay on the device (PR 30).
 
-``ServingEngine`` keeps every operand of a paged decode / verify /
-megastep dispatch resident and re-sends one only when the host state it
-mirrors changed: sampling parameters, stop tables and LoRA pages on a
+``ServingEngine`` keeps every operand of a paged decode / verify
+dispatch resident and re-sends one only when the host state it
+mirrors changed: sampling parameters and LoRA pages on a
 change of the batch's membership, the block tables on the cache's
 ``tables_version``, a real mask only while a grammar cursor is live, and
 tokens and keys not at all while the batch stands as the last commit
@@ -33,7 +33,6 @@ import jax
 import paddle_tpu as pt
 from paddle_tpu import monitor
 from paddle_tpu.models.generation import (decode_step_paged,
-                                          decode_megastep_paged,
                                           verify_step_paged)
 from paddle_tpu.models.gpt import GPTConfig, GPTForCausalLM
 from paddle_tpu.resilience import fault_scope
@@ -223,32 +222,6 @@ def speculative_verify_with_rollback(model, forget, monkeypatch):
     return d
 
 
-def _megastep(ahead):
-    def megastep_with_fallback(model, forget, monkeypatch):
-        """Megasteps of 3, the single step while a grammar row forces
-        the fallback, megasteps again after it left."""
-        grammar = JsonGrammar(json_token_strings(VOCAB))
-        pa, pb, pj = _prompts((5, 6, 4), seed=9)
-        eng = _engine(model, megastep=3, dispatch_ahead=ahead,
-                      grammar=grammar)
-        d = Driver(eng, forget)
-        a = d.submit(pa, max_new_tokens=20)
-        d.submit(pb, max_new_tokens=17, seed=2, **SAMPLED)
-        d.step(3)
-        mega0 = decode_megastep_paged(model, 3)["traces"]["count"]
-        assert mega0 == 1
-        j = d.submit(pj, max_new_tokens=5, json_mode=True)
-        d.step(2)
-        assert j.state == "running"
-        d.until_idle()
-        assert a.state == j.state == "done" and len(a.tokens) == 20
-        if ahead:
-            assert eng.stats()["ahead_hits"] > 0
-        return d
-    megastep_with_fallback.__name__ += "_ahead" if ahead else ""
-    return megastep_with_fallback
-
-
 def lora_row_and_a_load(model, forget, monkeypatch):
     cfg = model.gpt.cfg
     eng = _engine(model, lora_rank=2, lora_max_adapters=2)
@@ -326,8 +299,7 @@ def pools_lost(model, forget, monkeypatch):
 SCENARIOS = [admit_finish_by_length_and_stop, cancel_mid_decode,
              shed_at_prefill, sampled_beside_greedy,
              json_row_arrives_and_leaves, prefix_hit_with_copy_on_write,
-             speculative_verify_with_rollback, _megastep(False),
-             _megastep(True), lora_row_and_a_load,
+             speculative_verify_with_rollback, lora_row_and_a_load,
              swap_weights_between_steps, retry_after_a_step_fault,
              pools_lost]
 
@@ -342,14 +314,13 @@ def test_resident_and_rebuilt_inputs_are_one_behaviour(
         assert x == y, f"the two runs part at step {n}"
     # the kept run had resident dispatches (or the case shows nothing);
     # the rebuilt run none but a step dispatched ahead in the round of a
-    # step built from the host (or a megastep dispatched ahead), which
-    # feeds on the device's arrays by construction
+    # step built from the host, which feeds on the device's arrays by
+    # construction
     st, st0 = kept.eng.stats(), rebuilt.eng.stats()
     assert st["inputs_dispatches"] == st0["inputs_dispatches"] > 0
     assert st["inputs_resident"] > st0["inputs_resident"]
-    if not rebuilt.eng.dispatch_ahead:
-        assert st0["inputs_resident"] <= st0["ahead_dispatches"]
-        assert st0["inputs_resident"] < st0["inputs_dispatches"] / 2
+    assert st0["inputs_resident"] <= st0["ahead_dispatches"]
+    assert st0["inputs_resident"] < st0["inputs_dispatches"] / 2
     kept.eng.cache.flush_prefix_cache()
     assert kept.eng.cache.allocator.leaked() == 1    # trash block only
 
@@ -404,13 +375,12 @@ def test_a_steady_step_copies_its_lengths_and_nothing_else(
     assert sorted(sent) == [spec.max_slots * 4, spec.max_slots * 3 * 4]
 
 
-@pytest.mark.parametrize("kind", ["decode", "verify", "megastep"])
+@pytest.mark.parametrize("kind", ["decode", "verify"])
 def test_the_counter_reads_what_the_scenario_implies(model, kind):
     """Two requests admitted together, then a steady batch until both
     end on the same step: only the first dispatch sends its operands."""
     monitor.reset()
-    kw = {"decode": {}, "verify": dict(spec_tokens=2),
-          "megastep": dict(megastep=3)}[kind]
+    kw = {"decode": {}, "verify": dict(spec_tokens=2)}[kind]
     eng = _engine(model, **kw)
     for p in _prompts((5, 7), seed=30):
         eng.submit(p, max_new_tokens=7)
@@ -420,8 +390,6 @@ def test_the_counter_reads_what_the_scenario_implies(model, kind):
     assert st["inputs_resident"] == st["inputs_dispatches"] - 1
     if kind == "decode":
         assert st["inputs_dispatches"] == 6      # 7 tokens, 1 by prefill
-    if kind == "megastep":
-        assert st["inputs_dispatches"] == 2      # 6 tokens by 3
     assert monitor.stat_get("STAT_serving_inputs_resident") == \
         st["inputs_resident"]
     # a fresh batch: one more dispatch that sends
@@ -433,14 +401,12 @@ def test_the_counter_reads_what_the_scenario_implies(model, kind):
 
 def test_resident_operands_cost_no_second_trace_or_executable():
     """Host arrays on the first dispatch of a batch, the device's own
-    arrays after: one trace and one executable an entry, single step,
-    verify and megastep alike."""
+    arrays after: one trace and one executable an entry, single step
+    and verify alike."""
     m = _make_model(5)      # entries of its own, so the counts are these
     for kw, entry in (({}, lambda: decode_step_paged(m)),
                       (dict(spec_tokens=2),
-                       lambda: verify_step_paged(m, 2)),
-                      (dict(megastep=3),
-                       lambda: decode_megastep_paged(m, 3))):
+                       lambda: verify_step_paged(m, 2))):
         eng = _engine(m, **kw)
         eng.submit(_prompts((5,), seed=40)[0], max_new_tokens=9)
         eng.submit(_prompts((6,), seed=41)[0], max_new_tokens=9,

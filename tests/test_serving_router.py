@@ -14,11 +14,13 @@ import numpy as np
 import pytest
 
 import paddle_tpu as pt
-from paddle_tpu import monitor
+from paddle_tpu import flags, monitor
+from paddle_tpu.analysis import concurrency as ccz
 from paddle_tpu.models.generation import decode_step_paged, greedy_search
 from paddle_tpu.models.gpt import GPTConfig, GPTForCausalLM
 from paddle_tpu.resilience import RetryError, fault_scope
-from paddle_tpu.serving import QueueFullError, ReplicaRouter, ServingEngine
+from paddle_tpu.serving import (DisaggRouter, QueueFullError, ReplicaRouter,
+                                ServingEngine)
 
 
 @pytest.fixture(scope="module")
@@ -458,3 +460,62 @@ def test_chaos_serving_replica_skip_kills_without_restart(model):
     assert st["replicas"] == 1
     assert st["kills"] == 1 and st["restarts"] == 0
     rt.run_until_idle()
+
+
+# ------------------------------------- FLAGS_serving_dispatch_threads
+def _assert_no_leaks(router):
+    """Every paged engine behind ``router`` holds only its trash block
+    once the prefix cache is flushed."""
+    seen = set()
+    for eng in router.engines:
+        alloc = eng.cache.allocator
+        if id(alloc) not in seen:
+            seen.add(id(alloc))
+            eng.cache.flush_prefix_cache()
+            assert alloc.leaked() <= 1, alloc.leaked()
+
+
+@pytest.mark.parametrize("fleet", ["replicas", "disagg"])
+def test_a_fleet_stepped_from_a_thread_pool_matches_greedy(model, fleet):
+    """Replicas (or a prefill / decode role split) stepped from a
+    bounded worker pool == the greedy oracle per request; no kills, no
+    leaked blocks."""
+    prompts = _prompts((3, 7, 5, 9, 4, 6), seed=7)
+    kw = dict(dispatch_threads=2, max_slots=2, max_len=32,
+              buckets=[8, 16], max_queue=32, block_size=4)
+    rt = (ReplicaRouter(model, n_replicas=2, **kw) if fleet == "replicas"
+          else DisaggRouter(model, n_prefill=1, n_decode=1, **kw))
+    try:
+        reqs = [rt.submit(p, max_new_tokens=6) for p in prompts]
+        rt.run_until_idle()
+        assert all(r.state == "done" for r in reqs)
+        for p, r in zip(prompts, reqs):
+            ref = greedy_search(model, np.asarray([p]), max_new_tokens=6,
+                                cache_len=32)[0].tolist()
+            assert r.output_ids == ref, f"request {r.id} diverged"
+        assert rt.stats().get("replica_kills", 0) == 0
+        _assert_no_leaks(rt)
+    finally:
+        rt.stop()
+
+
+def test_the_sanitizer_is_clean_under_a_threaded_router(model):
+    """The trace lock / step lock / router locks hold their declared
+    order under concurrent replica stepping: no lock-graph cycles, no
+    guarded-state violations."""
+    old = flags.get_flag("sanitize_locks")
+    flags.set_flags({"sanitize_locks": True})
+    ccz.reset()
+    try:
+        rt = _router(model, dispatch_threads=2, max_queue=32)
+        try:
+            for p in _prompts((3, 5, 4, 6), seed=9):
+                rt.submit(p, max_new_tokens=6)
+            rt.run_until_idle()
+        finally:
+            rt.stop()
+        assert ccz.cycles() == [], ccz.cycles()
+        assert ccz.violations() == [], ccz.violations()
+    finally:
+        flags.set_flags({"sanitize_locks": old})
+        ccz.reset()

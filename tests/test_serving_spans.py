@@ -14,8 +14,8 @@ The contracts under test, on ``gpt2-tiny`` on the CPU:
   the first one ``first_token_at`` itself, and each gap between two of
   them is one ``serving.token_gap`` event — with the profiler off the
   stamps are still there and no event is;
-- the single step (f32, int8 pools, with a LoRA pool), the megastep
-  and the speculative step all speak the same names;
+- the single step (f32, int8 pools, with a LoRA pool) and the
+  speculative step speak the same names;
 - nothing of this reaches ``observability/tracing.py``: a seeded
   virtual-clock run exports the same bytes with the profiler on and off
   and marks nothing new.
@@ -40,7 +40,6 @@ PATHS = {
     "paged": dict(block_size=8, num_blocks=40),
     "int8": dict(block_size=8, num_blocks=40, kv_dtype="int8"),
     "lora": dict(block_size=8, num_blocks=40, lora_rank=2),
-    "megastep2": dict(block_size=8, num_blocks=40, megastep=2),
     "spec2": dict(block_size=8, num_blocks=40, spec_tokens=2),
 }
 WORK = ((5, 6), (9, 4), (20, 7))      # (prompt tokens, new tokens)
@@ -190,7 +189,7 @@ def test_token_stamps_and_gap_events(paged_run):
         assert mine[0]["ts"] == pytest.approx(r.token_at[0] * 1e6)
 
 
-@pytest.mark.parametrize("path", ["int8", "lora", "megastep2", "spec2"])
+@pytest.mark.parametrize("path", ["int8", "lora", "spec2"])
 def test_every_path_speaks_the_same_names(model, tmp_path, path):
     reqs, events, summary = _run(model, path, tmp_path)
     # the parentless spans, recorded from stamps (PR 35 added two), and
@@ -208,7 +207,7 @@ def test_every_path_speaks_the_same_names(model, tmp_path, path):
         if e["name"] == "serving.decode.inputs":
             assert by_id[e["parent"]]["name"] in ("serving.decode",
                                                   "serving.verify")
-    n = {"megastep2": 2, "spec2": 3}.get(path, 1)
+    n = 3 if path == "spec2" else 1
     assert {e["args"]["n"] for e in events
             if e["name"] == "serving.decode_step"} == {n}
     gaps = sum(e["name"] == "serving.token_gap" for e in events)

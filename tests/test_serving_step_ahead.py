@@ -567,13 +567,10 @@ def test_ahead_and_synchronous_runs_agree(scenario, gpt):
 
 # ------------------------------------------------------- what forces a sync
 
-@pytest.mark.parametrize("kw", [dict(spec_tokens=2), dict(megastep=3)],
-                         ids=["spec_tokens", "megastep"])
-def test_what_decodes_another_way_dispatches_nothing_ahead(gpt, kw):
-    """Drafts come from host tokens, and a megastep has its own
-    pipelining (behind its flag): the counters read 0, and are there all
-    the same."""
-    eng = _engine(gpt, **kw)
+def test_what_decodes_another_way_dispatches_nothing_ahead(gpt):
+    """Drafts come from host tokens: the counters read 0, and are there
+    all the same."""
+    eng = _engine(gpt, spec_tokens=2)
     reqs = [eng.submit(p, max_new_tokens=9) for p in _prompts((5, 6), 9)]
     eng.run_until_idle()
     assert all(r.state == "done" for r in reqs)

@@ -16,12 +16,20 @@ the profiler on or off), so the spans, the always-on step account in
   (``SlowDevice``; the parent commit of PR 35 closed its clock at the
   launch and read the launch).
 
-CPU, ``gpt2-tiny``-sized models; nothing here is a device metric.
+The checks that take a ``path`` run over the engines the cells serve
+(PR 61): GPT's single and verify steps, and one engine for each other
+served family, built as the harness builds it
+(``perfbench.serve.build_engine``) from the family's toy twin
+(``perfbench/rehearsal/<family>-tiny.json``): a window, recurrent state,
+convolution tails, an indexer, a latent row, a matrix state.
+
+CPU, toy models; nothing here is a device metric.
 """
 
 import contextlib
 import io
 import json
+import os
 import time
 
 import numpy as np
@@ -34,12 +42,16 @@ from paddle_tpu import monitor, profiler
 from paddle_tpu.models.gpt import GPTConfig, GPTForCausalLM
 from paddle_tpu.serving import ServingEngine
 from paddle_tpu.serving.engine import _ACCOUNT_KEYS
+from perfbench import serve
 
 VOCAB = 97
 GEOM = dict(max_slots=3, max_len=48, buckets=[8, 16])
-PATHS = {"single": {}, "megastep2": {"megastep": 2},
-         "megastep2_ahead": {"megastep": 2, "dispatch_ahead": True},
-         "spec2": {"spec_tokens": 2}}
+#: GPT's two decode steps, by the engine's keywords
+GPT_PATHS = {"single": {}, "spec2": {"spec_tokens": 2}}
+#: the other served families: one engine each, the toy twin's own geometry
+FAMILIES = ("dotsvlm", "jamba", "keye", "lfm2", "mellum", "qwen3next")
+PATHS = sorted(GPT_PATHS) + list(FAMILIES)
+REHEARSAL = os.path.join(listing.ROOT, "perfbench", "rehearsal")
 WORK = ((3, 9), (5, 7), (7, 8), (9, 6))   # (prompt tokens, new tokens)
 STAMPS = ("t_dispatch", "t_launched", "t_fetch", "t_fetched", "t_committed")
 
@@ -59,14 +71,41 @@ def _prompts(work=WORK, seed=5):
     return [(rng.integers(1, VOCAB, size=n).tolist(), m) for n, m in work]
 
 
-def _warm(model, **kw):
-    """An engine whose shapes are compiled and whose estimates are fresh."""
-    eng = ServingEngine(model, **{**GEOM, **kw})
+def _warmed(eng):
     for p, m in _prompts(seed=11):
         eng.submit(p, max_new_tokens=m)
     eng.run_until_idle()
     eng.reset_cost_estimates()
     return eng
+
+
+def _engine(model, **kw):
+    return ServingEngine(model, **{**GEOM, **kw})
+
+
+def _warm(model, **kw):
+    """An engine whose shapes are compiled and whose estimates are fresh."""
+    return _warmed(_engine(model, **kw))
+
+
+@pytest.fixture(scope="module")
+def built(model):
+    """-> path -> (an engine of that path, its account as it was built).
+    GPT's are new at every call (their programs are the model's); a
+    family's is built once and shared, so a check reads its account as
+    growth."""
+    shared = {}
+
+    def get(path):
+        if path in GPT_PATHS:
+            eng = _engine(model, **GPT_PATHS[path])
+            return eng, _account(eng)
+        if path not in shared:
+            with open(os.path.join(REHEARSAL, f"{path}-tiny.json")) as f:
+                _, eng = serve.build_engine(json.load(f), seed=0)
+            shared[path] = eng, _account(eng)
+        return shared[path]
+    return get
 
 
 def _stop_profiler(tmp_path):
@@ -81,13 +120,12 @@ def _account(eng):
     return {k: st[k] for k in _ACCOUNT_KEYS}
 
 
-def _traced_run(model, tmp_path, last=None, **kw):
-    """Serve WORK with the profiler on -> (requests, the flights in the
-    order they were committed, events, the account's growth). ``last``:
-    decode parameters of the last request (a sampled one draws its first
-    token on the host: its prefill is fetched at once and the step after
-    it is the host's to build)."""
-    eng = _warm(model, **kw)
+def _traced_run(eng, tmp_path, last=None):
+    """Serve WORK on a warm engine with the profiler on -> (requests, the
+    flights in the order they were committed, events, the account's
+    growth). ``last``: decode parameters of the last request (a sampled
+    one draws its first token on the host: its prefill is fetched at once
+    and the step after it is the host's to build)."""
     flights, landed = [], eng._landed
 
     def keep(fl, *a, **k):
@@ -103,6 +141,7 @@ def _traced_run(model, tmp_path, last=None, **kw):
             for i, (p, m) in enumerate(work)]
     eng.run_until_idle()
     events = _stop_profiler(tmp_path)
+    del eng._landed         # (a family's engine is shared)
     after = _account(eng)
     grew = {k: after[k] - before[k] for k in after}
     grew["admit_ahead_dispatches"] = \
@@ -110,11 +149,10 @@ def _traced_run(model, tmp_path, last=None, **kw):
     return reqs, flights, events, grew
 
 
-@pytest.fixture(scope="module", params=sorted(PATHS))
-def traced(request, model, tmp_path_factory):
-    return (request.param,) + _traced_run(
-        model, tmp_path_factory.mktemp(request.param),
-        **PATHS[request.param])
+@pytest.fixture(scope="module", params=PATHS)
+def traced(request, built, tmp_path_factory):
+    return _traced_run(_warmed(built(request.param)[0]),
+                       tmp_path_factory.mktemp(request.param))
 
 
 def _by_flight(events, name):
@@ -156,7 +194,7 @@ def test_stat_observe_is_what_stat_time_records():
 # ------------------------------------------------------ flights and spans
 
 def test_flight_ids_are_one_sequence_and_stamps_are_monotone(traced):
-    _, _, flights, _, grew = traced
+    _, flights, _, grew = traced
     ids = [fl.id for fl in flights]
     assert len(set(ids)) == len(ids) and min(ids) > 0
     for fl in flights:
@@ -166,7 +204,7 @@ def test_flight_ids_are_one_sequence_and_stamps_are_monotone(traced):
 
 
 def test_a_flights_spans_carry_its_id_and_one_flight_span_joins_them(traced):
-    path, _, flights, events, _ = traced
+    _, flights, events, _ = traced
     joined = _by_flight(events, "serving.flight")
     assert sorted(joined) == sorted(fl.id for fl in flights)
     for fl in flights:
@@ -185,13 +223,9 @@ def test_a_flights_spans_carry_its_id_and_one_flight_span_joins_them(traced):
     for name in ("serving.decode.fetch", "serving.decode.commit"):
         assert {i: len(v) for i, v in _by_flight(events, name).items()} == \
             dict.fromkeys(decode, 1)
-    # a megastep dispatched ahead has no inputs span (no span bounds that
-    # dispatch): every other decode flight has exactly one
     inputs = _by_flight(events, "serving.decode.inputs")
-    ahead_mega = {i for i in decode if joined[i][0]["args"]["ahead"]
-                  and path.startswith("megastep")}
     assert {i: len(v) for i, v in inputs.items()} == \
-        dict.fromkeys(decode - ahead_mega, 1)
+        dict.fromkeys(decode, 1)
     for fl in flights:
         if fl.id in inputs:
             (e,) = inputs[fl.id]
@@ -203,7 +237,7 @@ def test_a_step_built_by_the_host_and_one_dispatched_ahead(model, tmp_path):
     # (all greedy, every step is dispatched behind a step or a prefill
     # since PR 48: the sampled request's admission leaves one to the host)
     _, flights, events, _ = _traced_run(
-        model, tmp_path, last=dict(seed=3, temperature=0.8, top_k=12))
+        _warm(model), tmp_path, last=dict(seed=3, temperature=0.8, top_k=12))
     joined = _by_flight(events, "serving.flight")
     prefill = {i for i, (s,) in joined.items() if s["args"]["prefill"]}
     ahead = {i for i, (s,) in joined.items() if s["args"]["ahead"]}
@@ -234,7 +268,7 @@ def test_the_step_behind_a_prefill_is_dispatched_before_its_fetch(model,
     the decode step behind it (the next id) is dispatched between its
     launch and its fetch, and ``serving.prefill_step`` is drawn from its
     stamps, dispatch to commit, around its own three spans."""
-    _, flights, events, grew = _traced_run(model, tmp_path)
+    _, flights, events, grew = _traced_run(_warm(model), tmp_path)
     joined = _by_flight(events, "serving.flight")
     by_id = {fl.id: fl for fl in flights}
     prefill = sorted(i for i, (s,) in joined.items() if s["args"]["prefill"])
@@ -278,7 +312,7 @@ def test_the_account_is_the_sum_of_the_matching_spans(traced):
     entry under the same ``serving.decode`` / ``.verify``, else that
     span's close), plus fetch entry to commit close. The same readings:
     equal to the microsecond (they differ by float rounding only)."""
-    path, _, flights, events, grew = traced
+    _, flights, events, grew = traced
     by_id = {e["id"]: e for e in events}
     inputs = _by_flight(events, "serving.decode.inputs")
     fetch = _by_flight(events, "serving.decode.fetch")
@@ -288,8 +322,8 @@ def test_the_account_is_the_sum_of_the_matching_spans(traced):
         siblings.setdefault(e["parent"], []).append(e["ts"])
     total_us = wait_us = 0.0
     for fl in flights:
-        if fl.id not in fetch or fl.id not in inputs:
-            continue
+        if fl.id not in fetch:
+            continue        # a prefill group
         (i,), (f,), (c,) = inputs[fl.id], fetch[fl.id], commit[fl.id]
         parent = by_id[i["parent"]]
         assert parent["name"] in ("serving.decode", "serving.verify")
@@ -297,12 +331,6 @@ def test_the_account_is_the_sum_of_the_matching_spans(traced):
         launched = min(later) if later else parent["ts"] + parent["dur"]
         total_us += (launched - i["ts"]) + (c["ts"] + c["dur"] - f["ts"])
         wait_us += f["dur"]
-    if path == "megastep2_ahead":
-        # the flights dispatched ahead have no inputs span to rebuild from
-        wait_all = sum(f["dur"] for (f,) in fetch.values())
-        assert grew["decode_wait_ms"] == pytest.approx(wait_all / 1e3,
-                                                       abs=1e-3)
-        return
     assert grew["decode_wait_ms"] == pytest.approx(wait_us / 1e3, abs=1e-3)
     assert grew["decode_host_ms"] + grew["decode_wait_ms"] == \
         pytest.approx(total_us / 1e3, abs=1e-3)
@@ -317,7 +345,7 @@ def test_the_account_is_the_sum_of_the_matching_spans(traced):
 
 
 def test_the_device_bound_lies_between_the_wait_and_the_round(traced):
-    _, _, _, _, grew = traced
+    _, _, _, grew = traced
     assert 0 < grew["decode_wait_ms"] <= grew["decode_device_ms"] + 1e-6
     assert grew["decode_device_ms"] <= grew["round_ms"]
     assert grew["rounds"] > 0 and grew["round_ms"] > 0
@@ -343,7 +371,7 @@ def test_the_phase_timers_count_one_observation_a_flight(model):
 # ------------------------------------------------------------------- TTFT
 
 def test_one_ttft_span_a_request_split_at_its_admission(traced):
-    _, reqs, _, events, grew = traced
+    reqs, _, events, grew = traced
     spans = {e["args"]["request"]: e for e in events
              if e["name"] == "serving.ttft"}
     assert sorted(spans) == sorted(r.id for r in reqs)
@@ -367,10 +395,10 @@ def test_one_ttft_span_a_request_split_at_its_admission(traced):
 
 # ------------------------------------------------- profiler off, monotone
 
-@pytest.mark.parametrize("path", sorted(PATHS))
+@pytest.mark.parametrize("path", PATHS)
 def test_with_the_profiler_off_nothing_is_recorded_and_the_account_grows(
-        model, path, tmp_path):
-    eng = _warm(model, **PATHS[path])
+        built, path, tmp_path):
+    eng = _warmed(built(path)[0])
     before = _account(eng)
     for p, m in _prompts():
         eng.submit(p, max_new_tokens=m)
@@ -385,13 +413,13 @@ def test_with_the_profiler_off_nothing_is_recorded_and_the_account_grows(
     assert after["first_tokens"] - before["first_tokens"] == len(WORK)
 
 
-@pytest.mark.parametrize("path", sorted(PATHS))
-def test_the_account_counts_the_table_entries_the_rows_stood_on(model, path):
+@pytest.mark.parametrize("path", PATHS)
+def test_the_account_counts_the_table_entries_the_rows_stood_on(built, path):
     """``kv_blocks_live`` / ``kv_blocks_table``: at every committed decode
     flight the live rows' ``ceil(length / block_size)`` against the whole
     table, from the lengths the host holds (PR 39: what the paged kernel
     had to read of what the gathered table held)."""
-    eng = _warm(model, **PATHS[path])
+    eng = _warmed(built(path)[0])
     before = _account(eng)
     bs, table = eng.cache.block_size, eng.cache.tables.size
     for p, m in _prompts():
@@ -406,11 +434,11 @@ def test_the_account_counts_the_table_entries_the_rows_stood_on(model, path):
         * eng.max_slots * -(-longest // bs)
 
 
-@pytest.mark.parametrize("path", sorted(PATHS))
-def test_every_key_of_the_account_is_monotone(model, path):
-    eng = ServingEngine(model, **{**GEOM, **PATHS[path]})
+@pytest.mark.parametrize("path", PATHS)
+def test_every_key_of_the_account_is_monotone(built, path):
+    eng, new = built(path)
+    assert set(new) == set(_ACCOUNT_KEYS) and not any(new.values())
     last = _account(eng)
-    assert set(last) == set(_ACCOUNT_KEYS) and not any(last.values())
     for p, m in _prompts():
         eng.submit(p, max_new_tokens=m)
     while not eng.idle:

@@ -36,17 +36,7 @@
 #      nothing
 #   8. speculative-decoding gate (FLAGS_serving_spec_tokens>0 engine
 #      token-identical to sequential greedy, compile counts pinned)
-#   9. observability gate (train + serving smoke under the run log;
-#      /metrics parses as Prometheus text, compile tracker pins the
-#      decode/prefill compile budget, run-log events feed
-#      tools/trace_summary.py; per-request tracing blame identity +
-#      Perfetto export + /v1/requests/<id> debug endpoint, with the
-#      recompile predictor proving tracing never compiles; plus the
-#      host-KV-tier session phase: a two-turn session demoted to
-#      host RAM and resumed token-identically, migration/session
-#      metrics and run-log events minted, predictor agreeing
-#      host_tier/sessions are validated no-ops)
-#  10. loadgen SLO gate (seeded open-loop traffic through the
+#   9. loadgen SLO gate (seeded open-loop traffic through the
 #      SLO-admitting gpt2-tiny engine: goodput > 0 with attainment
 #      reported and zero leaked KV blocks, then the chaos crossover —
 #      submit/alloc faults injected, degradation must stay graceful —
@@ -64,20 +54,19 @@
 #      device pool has KV blocks (idle chains demoted to the pinned
 #      host pool, promoted back token-identically on resume), at
 #      zero leaks in both tiers and zero new compiles after warmup)
-#  11. chaos soak gate (hours of seeded diurnal traffic on the virtual
+#  10. chaos soak gate (hours of seeded diurnal traffic on the virtual
 #      clock with replica kills injected at virtual instants and
 #      auto-restart healing the fleet: goodput > 0 in every window,
 #      completed + rehomed + shed == offered, zero leaks, zero new
 #      compiles after warmup — kill/restart/re-home proven no-ops),
 #      then the same seeded soak under FLAGS_sanitize_locks=1 (lock
 #      order graph acyclic, zero guarded-state violations)
-#  12. op coverage gate (>= 80% of the reference forward-op surface)
-#  13. API-freeze check (public signature snapshot diff)
-#  14. multi-chip dry-run (GSPMD train step on N virtual devices)
-#  15. train->serve loop gate (ZeRO parity on 1x1 + virtual dp=2 with
+#  11. op coverage gate (>= 80% of the reference forward-op surface)
+#  12. multi-chip dry-run (GSPMD train step on N virtual devices)
+#  13. train->serve loop gate (ZeRO parity on 1x1 + virtual dp=2 with
 #      per-device optimizer bytes ~1/dp, then checkpoint publish ->
 #      live hot-swap into a running engine with zero new compiles)
-#  16. README generated fragments vs their registries (no drift)
+#  14. README generated fragments vs their registries (no drift)
 #
 # Usage: tools/ci.sh [quick]   — `quick` skips the full suite and runs
 # a reduced chaos subset; lint and the other static gates still run
@@ -85,7 +74,7 @@
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
-echo "== 1/16 import smoke"
+echo "== 1/14 import smoke"
 JAX_PLATFORMS=cpu python -c "
 import paddle_tpu
 from paddle_tpu.ops import registry
@@ -94,11 +83,11 @@ assert n > 350, n
 print(f'   paddle_tpu imports, {n} op lowerings registered')
 "
 
-echo "== 2/16 lint (program verifier + shape inference + op-desc compat)"
+echo "== 2/14 lint (program verifier + shape inference + op-desc compat)"
 JAX_PLATFORMS=cpu python tools/lint_program.py --books --shapes
 JAX_PLATFORMS=cpu python tools/check_op_desc.py --diff tools/op_desc_baseline.json
 
-echo "== 3/16 sharding-rule lint (GSPMD pre-flight)"
+echo "== 3/14 sharding-rule lint (GSPMD pre-flight)"
 # the GPT TP table, the ZeRO-style fully-sharded merge, and the serving
 # TP table (the mesh-sharded engine's placement rules on its
 # ("data","model") mesh) against the GPT benchmark model: no unknown
@@ -111,7 +100,7 @@ JAX_PLATFORMS=cpu python tools/lint_sharding.py --preset gpt_tp --mesh dp=2,mp=2
 JAX_PLATFORMS=cpu python tools/lint_sharding.py --preset serving_tp --mesh data=1,model=2 --strict
 JAX_PLATFORMS=cpu python tools/lint_sharding.py --preset gpt_tp+fully_sharded --mesh dp=2,mp=2 --json > /dev/null
 
-echo "== 4/16 serving concurrency/lifecycle lint"
+echo "== 4/14 serving concurrency/lifecycle lint"
 # static resource-obligation dataflow (acquire/release/export/adopt)
 # plus guarded-state discipline over the serving modules; --strict
 # fails on warnings too, and the baseline ships empty — every real
@@ -119,26 +108,26 @@ echo "== 4/16 serving concurrency/lifecycle lint"
 JAX_PLATFORMS=cpu python tools/lint_serving.py --strict
 
 if [[ "${1:-}" != "quick" ]]; then
-  echo "== 5/16 test suite (virtual 8-device CPU mesh)"
+  echo "== 5/14 test suite (virtual 8-device CPU mesh)"
   if python -c 'import pytest_timeout' 2>/dev/null; then
     python -m pytest tests/ -q -x --timeout=1200
   else
     python -m pytest tests/ -q -x
   fi
 else
-  echo "== 5/16 test suite: SKIPPED (quick mode)"
+  echo "== 5/14 test suite: SKIPPED (quick mode)"
 fi
 
 if [[ "${1:-}" != "quick" ]]; then
-  echo "== 6/16 chaos suite (deterministic fault injection)"
+  echo "== 6/14 chaos suite (deterministic fault injection)"
   python -m pytest tests/ -q -m chaos
 else
-  echo "== 6/16 chaos suite: reduced subset (quick mode)"
+  echo "== 6/14 chaos suite: reduced subset (quick mode)"
   python -m pytest tests/test_resilience.py -q
 fi
 
 if [[ "${1:-}" != "quick" ]]; then
-  echo "== 7/16 serving plane (incl. paged-KV equivalence)"
+  echo "== 7/14 serving plane (incl. paged-KV equivalence)"
   # the full file carries the paged oracle: engine output token-identical
   # to sequential greedy with the prefix cache on AND off, plus the
   # paged compile-count pins
@@ -162,7 +151,7 @@ if [[ "${1:-}" != "quick" ]]; then
   # serving.replica + serving.migrate leaks zero blocks on either tier
   JAX_PLATFORMS=cpu python -m pytest tests/test_kv_tier.py -q
 else
-  echo "== 7/16 serving plane: reduced subset (quick mode)"
+  echo "== 7/14 serving plane: reduced subset (quick mode)"
   JAX_PLATFORMS=cpu python -m pytest tests/test_serving.py -q \
     -k "matches_sequential or queue_full or block_allocator \
 or paged_engine_matches or prefix_reuse"
@@ -184,18 +173,10 @@ or flag_parsing"
 or all_or_nothing or evicts_lru or session_store"
 fi
 
-echo "== 8/16 speculative decoding gate"
+echo "== 8/14 speculative decoding gate"
 JAX_PLATFORMS=cpu python -m pytest tests/test_serving.py -q -k "spec"
 
-echo "== 9/16 observability gate"
-# tiny train + serving smoke under the run log: /metrics parses as
-# Prometheus text (incl. KV block-pool gauges), compile tracker pins
-# decode_step_paged==1 compile and one batched prefill dispatch, a
-# repeated prompt scores a prefix-cache hit, JSONL events feed
-# trace_summary
-JAX_PLATFORMS=cpu python tools/obs_smoke.py
-
-echo "== 10/16 loadgen SLO gate (goodput under real traffic)"
+echo "== 9/14 loadgen SLO gate (goodput under real traffic)"
 # seeded open-loop traffic through the gpt2-tiny engine with SLO-aware
 # admission: goodput > 0 with attainment reported, zero leaked KV
 # blocks, zero unhandled exceptions — then the chaos crossover: the
@@ -391,7 +372,7 @@ print(f\"   sessions: {s['sessions_peak']} peak on \"
       f\"blocks demoted/promoted, 0 leaks both tiers, 0 new compiles\")
 "
 
-echo "== 11/16 chaos soak gate (virtual-clock fleet fault tolerance)"
+echo "== 10/14 chaos soak gate (virtual-clock fleet fault tolerance)"
 # hours of seeded diurnal traffic compressed into seconds on the
 # virtual clock, with replica kills injected at virtual instants
 # (serving.replica:error@t>Ns, one FLAGS_fault_spec string — the
@@ -465,33 +446,14 @@ print(f\"   sanitized soak: {san['lock_acquires']} acquires over \"
       f\"edges, 0 cycles, 0 violations\")
 "
 
-echo "== 12/16 op coverage gate"
+echo "== 11/14 op coverage gate"
 if [[ -d /root/reference ]]; then
   JAX_PLATFORMS=cpu python tools/op_coverage.py --json
 else
   echo "   reference tree absent — skipped"
 fi
 
-echo "== 13/16 API freeze"
-SNAP=tools/api_signatures.txt
-API_NOW=$(mktemp)
-API_DIFF=$(mktemp)
-trap 'rm -f "$API_NOW" "$API_DIFF"' EXIT
-JAX_PLATFORMS=cpu python tools/print_signatures.py > "$API_NOW"
-if [[ -f "$SNAP" ]]; then
-  if ! diff -u "$SNAP" "$API_NOW" > "$API_DIFF"; then
-    echo "   PUBLIC API CHANGED vs snapshot:"
-    head -40 "$API_DIFF"
-    echo "   (intentional? refresh with: python tools/print_signatures.py > $SNAP)"
-    exit 1
-  fi
-  echo "   public API matches snapshot ($(wc -l < "$SNAP") symbols)"
-else
-  cp "$API_NOW" "$SNAP"
-  echo "   snapshot created ($(wc -l < "$SNAP") symbols) — commit it"
-fi
-
-echo "== 14/16 multi-chip dry run"
+echo "== 12/14 multi-chip dry run"
 # needs the jax_num_cpu_devices config option to carve out virtual CPU
 # devices; older jax builds (0.4.x) don't have it
 if JAX_PLATFORMS=cpu python -c "
@@ -507,7 +469,7 @@ else
   echo "   installed jax has no jax_num_cpu_devices — skipped"
 fi
 
-echo "== 15/16 train->serve loop gate (ZeRO + live hot-swap)"
+echo "== 13/14 train->serve loop gate (ZeRO + live hot-swap)"
 # 2-step ZeRO train runs match the unsharded baseline loss-for-loss on
 # a 1x1 mesh and again on a subprocess-carved dp=2 mesh (per-device
 # optimizer bytes asserted ~1/2 of total from live shards), then the
@@ -516,7 +478,7 @@ echo "== 15/16 train->serve loop gate (ZeRO + live hot-swap)"
 # zero new compiles
 JAX_PLATFORMS=cpu python tools/zero_smoke.py
 
-echo "== 16/16 README generated-fragment sync"
+echo "== 14/14 README generated-fragment sync"
 JAX_PLATFORMS=cpu python tools/sync_readme.py --check
 
 echo "CI PASSED"

@@ -33,7 +33,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 # the tiny-but-structurally-faithful GPT used across CI gates
-# (tools/obs_smoke.py, the serving tests): every TP rule family
+# (the serving tests): every TP rule family
 # (qkv/out_proj/fc1/fc2/wte) has a live target. vocab_pad_to=2 pads the
 # deliberately-awkward 97-row vocab to 98 so the vocab-parallel wte
 # rule divides cleanly — `--preset gpt_tp --strict` runs warning-free

@@ -47,9 +47,7 @@ Two execution modes:
   with ``clock=vc.now`` and *pinned* predictor costs): the loop
   advances time by ``step_cost_ms`` per scheduler step and jumps
   across idle gaps. Fully deterministic — timestamps, TTFTs, admit
-  and shed decisions replay exactly; used by the determinism tests
-  and the obs_smoke loadgen phase (where it also proves admission
-  adds zero XLA compiles).
+  and shed decisions replay exactly; used by the determinism tests.
 
 Two release disciplines:
 
@@ -1110,18 +1108,6 @@ def main(argv=None) -> int:
                     help="replay a recorded arrival trace (from "
                     "tools/trace_convert.py or a prior --trace file) "
                     "instead of sampling a schedule")
-    ap.add_argument("--megastep", type=int, default=1, metavar="N",
-                    help="> 1 runs device-resident decode megasteps "
-                    "(FLAGS_serving_megastep): N decode iterations per "
-                    "compiled dispatch with one host commit per "
-                    "megastep; tokens are byte-identical to N=1. "
-                    "Plumbs through engines, replica routers and "
-                    "disagg decode workers alike")
-    ap.add_argument("--dispatch-ahead", action="store_true",
-                    help="with --megastep > 1: enqueue megastep k+1 "
-                    "while k executes (FLAGS_serving_dispatch_ahead); "
-                    "the host commit validates the speculation and "
-                    "discards it on any roster/sampling change")
     ap.add_argument("--dispatch-threads", type=int, default=0,
                     metavar="T", help="> 0 steps router replicas / "
                     "disagg workers from a bounded pool of T threads "
@@ -1241,25 +1227,14 @@ def main(argv=None) -> int:
         _fl.set_flags({"serving_lora_rank": args.lora_rank,
                        "serving_lora_max_adapters":
                            max(len(lora_tenants), 1)})
-    if args.megastep < 1:
-        print("FAIL: --megastep must be >= 1", file=sys.stderr)
-        return 1
-    if args.dispatch_ahead and args.megastep <= 1:
-        print("FAIL: --dispatch-ahead needs --megastep > 1",
-              file=sys.stderr)
-        return 1
     if args.dispatch_threads < 0:
         print("FAIL: --dispatch-threads must be >= 0", file=sys.stderr)
         return 1
-    if args.megastep > 1 or args.dispatch_threads > 0:
-        # one flag write covers every construction path below: engines
-        # built directly, inside ReplicaRouter, and inside DisaggRouter
-        # decode workers all read these flags when no kwarg overrides
+    if args.dispatch_threads > 0:
+        # one flag write covers every construction path below: the
+        # routers read this flag when no kwarg overrides
         from paddle_tpu import flags as _fl
-        _fl.set_flags({
-            "serving_megastep": args.megastep,
-            "serving_dispatch_ahead": bool(args.dispatch_ahead),
-            "serving_dispatch_threads": args.dispatch_threads})
+        _fl.set_flags({"serving_dispatch_threads": args.dispatch_threads})
     if args.trace_sample is not None:
         from paddle_tpu import flags as _fl
         _fl.set_flags({"serving_trace": args.trace_sample})

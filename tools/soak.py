@@ -226,11 +226,6 @@ def main(argv=None) -> int:
     ap.add_argument("--max-len", type=int, default=64)
     ap.add_argument("--max-queue", type=int, default=32)
     ap.add_argument("--buckets", default="16,32")
-    ap.add_argument("--megastep", type=int, default=1, metavar="N",
-                    help="> 1 soaks with device-resident decode "
-                    "megasteps (FLAGS_serving_megastep): N decode "
-                    "iterations per dispatch, one host commit per "
-                    "megastep; tokens stay byte-identical to N=1")
     ap.add_argument("--dispatch-threads", type=int, default=0,
                     metavar="T", help="> 0 steps the fleet from a "
                     "bounded pool of T threads "
@@ -287,17 +282,13 @@ def main(argv=None) -> int:
     from paddle_tpu.models.gpt import GPT_CONFIGS, GPTForCausalLM
     from tools.loadgen import LoadGen
 
-    if args.megastep < 1:
-        print("FAIL: --megastep must be >= 1", file=sys.stderr)
-        return 1
     if args.dispatch_threads < 0:
         print("FAIL: --dispatch-threads must be >= 0", file=sys.stderr)
         return 1
-    if args.megastep > 1 or args.dispatch_threads > 0:
-        # flags reach every engine the arms construct, including
+    if args.dispatch_threads > 0:
+        # the flag reaches every router the arms construct, including
         # watchdog-restarted replicas mid-soak
-        pt.set_flags({"serving_megastep": args.megastep,
-                      "serving_dispatch_threads": args.dispatch_threads})
+        pt.set_flags({"serving_dispatch_threads": args.dispatch_threads})
 
     duration = args.hours * 3600.0
     cfg = GPT_CONFIGS[args.model]
@@ -356,8 +347,7 @@ def main(argv=None) -> int:
                     for a in lg.schedule()]]
     pkw = dict(buckets=[int(b) for b in args.buckets.split(",")],
                max_len=args.max_len, n_replicas=args.replicas,
-               slo_ttft_ms=args.slo_ttft_ms,
-               megastep=args.megastep)
+               slo_ttft_ms=args.slo_ttft_ms)
     plain_pred = predict_serving_compiles(lg_workload, **pkw)
     hedges_fired = int(report.get("hedges", {}).get("fired", 0))
     chaos_pred = predict_serving_compiles(

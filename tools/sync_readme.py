@@ -392,7 +392,7 @@ def render_serving_block():
         "`STAT_serving_prefix_hits` / `_misses`.",
         "",
         "The KV pools have one owner at a time. Every paged entry",
-        "(prefill, decode, megastep, verify) is handed the pools",
+        "(prefill, decode, verify) is handed the pools",
         "donated: it writes its KV rows in place, in the layout the",
         "pool arrived in — 64 rows and under (a decode or verify step)",
         "as one `dynamic_update_slice` per row, more (a prompt, a decode",
@@ -457,7 +457,7 @@ def render_serving_block():
         "replicas compile each step once, total, and a mesh engine pays",
         "exactly one extra compile per step kind (its entries are keyed",
         "on the mesh), an invariant `analysis.recompile` predicts and",
-        "`tools/obs_smoke.py` asserts against observed counts.",
+        "the serving tests assert against observed counts.",
         "`engine.stats()` reports `mesh_shape`; `router.stats()` adds",
         "per-replica queue depths and free blocks; `GET /metrics` grows",
         "`serving_mesh_devices`, `serving_replicas` and per-replica",
@@ -567,8 +567,8 @@ def render_serving_block():
         "dispatches whose batch was all greedy, and `engine.stats()`",
         "gives `sampler_dispatches` / `sampler_skipped`.",
         "The step's inputs stay on the device: sampling parameters,",
-        "the zero mask, block tables, LoRA pages and the megastep's",
-        "stop tables are sent again only when the batch's membership,",
+        "the zero mask, block tables and LoRA pages are sent again",
+        "only when the batch's membership,",
         "a table (`cache.tables_version`) or a grammar cursor changed,",
         "and the next step's tokens and keys are the last step's own",
         "outputs while the batch stands (the request's host-side key",
